@@ -70,7 +70,9 @@ class Config:
     compute_dtype: str = "bfloat16"
     # LSTM core: auto | xla | pallas — auto picks the fused Pallas
     # unroll (ops/lstm_pallas.py) on a single-device TPU mesh, the
-    # nn.scan path elsewhere.  Param trees are identical either way.
+    # nn.scan path elsewhere (parallel/mesh.py
+    # fused_kernels_profitable — the one rule both kernel "auto"s
+    # follow).  Param trees are identical either way.
     core_impl: str = "auto"
     # Pallas-core matmul operand precision: auto | float32 | bfloat16.
     # "auto" follows the ONE dtype policy: the pallas core's matmuls
@@ -81,9 +83,11 @@ class Config:
     # Stem-conv grad-W lowering: auto | xla | pallas.  "pallas" swaps
     # ONLY the stem's weight gradient for the im2col MXU kernel
     # (ops/conv_pallas.py) — the named worst kernel in the roofline
-    # ledger (conv0_gradw, 0.107 MFU).  "auto" = pallas on TPU, xla
-    # elsewhere (the lstm_pallas precedent; off-TPU the kernel would
-    # run interpreted).  Param trees are identical either way.
+    # ledger (conv0_gradw, 0.107 MFU).  "auto" follows core_impl's
+    # rule — pallas on a single-device TPU mesh, xla elsewhere — and
+    # only for a stem the kernel takes (the shallow 8x8/stride-4 stem;
+    # the ResNet stem stays on XLA: driver.resolve_conv_backend).
+    # Param trees are identical either way.
     conv_backend: str = "auto"
     # Fused single-forward loss (runtime/learner.py): one unroll feeds
     # both the behaviour-comparison quantities and the differentiated
@@ -125,11 +129,11 @@ class Config:
     # groups (minimum RTTs, right co-located); 2 lets one shard's
     # upload + env stepping overlap the other's link round trip —
     # measured 1.6-1.8x e2e on bandwidth-constrained links
-    # (BENCH_NOTES r4 sweep; 3 shards regressed).  Default 0 = AUTO:
+    # (the r4 shard sweep; 3 shards regressed).  Default 0 = AUTO:
     # the pool probes the link at startup (RTT + H2D bandwidth) and
     # picks the predicted-best count from the RTT-floor model
     # (runtime/linktune.py) — so co-located chips get 1 and degraded
-    # tunnels get 2 without per-deployment tuning.  The pool clamps
+    # links get 2 without per-deployment tuning.  The pool clamps
     # explicit values to the group count.
     accum_fused_shards: int = 0
     # Host actor runtime: "grouped" (the ActorPool — one thread per env
@@ -332,14 +336,6 @@ class Config:
     # soak engine, runtime/soak.py, writes the lines).  Propagates to
     # relaunched elastic workers like any other flag.
     chaos_channel: bool = False
-    # JAX persistent compilation cache directory ('' = disabled).  MTTR
-    # engineering: an elastic relaunch's recovery time is dominated by
-    # the fresh process's first compile; with the cache armed, epoch 0
-    # populates it and every relaunch (and every restart of the same
-    # config) compiles from disk.  Wired through both driver backends;
-    # safe to share across fleet processes (the cache is keyed by
-    # program fingerprint and written atomically).
-    compile_cache_dir: str = ""
     # -- fleet fault domains (runtime/fleet.py, docs/robustness.md) ------
     # Peer heartbeat deadline: in a multi-process run, a peer whose
     # KV-store heartbeat stops advancing for this long (local monotonic

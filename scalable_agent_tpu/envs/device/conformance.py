@@ -239,9 +239,9 @@ def materialization_spy():
     tests in tests/test_device_telemetry.py and tests/test_replay.py
     delegate here), so a jaxlib upgrade that moves the materialization
     surface is fixed in one place."""
-    import jaxlib.xla_extension as xe
+    from jaxlib import _jax
 
-    cls = xe.ArrayImpl
+    cls = _jax.ArrayImpl
     calls: List[str] = []
     orig_value = cls.__dict__["_value"]
     orig_array = cls.__array__

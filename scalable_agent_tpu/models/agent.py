@@ -127,13 +127,13 @@ class _PallasCore(nn.Module):
         # Lazy like vtrace.py's pallas path: XLA-only consumers never
         # pay (or depend on) the Pallas TPU imports.
         from scalable_agent_tpu.ops import lstm_pallas
+        from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
         wi, wh, b = _PallasCoreParams(
             self.features, x.shape[-1], name="lstm")()
         ys, (ct, ht) = lstm_pallas.lstm_unroll(
             jnp.asarray(x, jnp.float32), done, carry[0], carry[1],
-            wi, wh, b, jax.default_backend() != "tpu",
-            self.matmul_dtype)
+            wi, wh, b, pallas_interpret(), self.matmul_dtype)
         return (ct, ht), ys
 
 
@@ -172,7 +172,7 @@ class ImpalaAgent(nn.Module):
     # rate, f32 accumulation).  Ignored by the xla core.
     core_matmul_dtype: str = "float32"
     # Stem-conv grad-W lowering: "xla" (plain nn.Conv) or "pallas"
-    # (ops/conv_pallas.py im2col MXU kernel; interpret mode off-TPU).
+    # (ops/conv_pallas.py im2col MXU kernel).
     # Identical parameter trees — checkpoints are interchangeable.
     conv_backend: str = "xla"
     # Rematerialize the torso in the backward pass (jax.checkpoint via

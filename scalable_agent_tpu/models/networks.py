@@ -28,8 +28,17 @@ from flax import linen as nn
 # is deliberately NOT in this registry: it stays reachable via
 # ``ShallowConvTorso(space_to_depth=True)`` as documentation of the
 # measurement (see _SpaceToDepthFirstConv), but it is retired from
-# the flag surface — BENCH_NOTES' round-5 conv table is why.
+# the flag surface.
 CONV_BACKENDS = ("xla", "pallas")
+
+# Each torso's stem conv as (features, kernel_size, stride) — read by
+# the torsos below and by the driver's conv_backend policy, which asks
+# ops/conv_pallas.gradw_batch_tile whether the Pallas grad-W kernel
+# takes that geometry at the run's frame size and dtype.
+STEM_GEOMETRY = {
+    "shallow": (32, 8, 4),
+    "resnet": (16, 3, 1),
+}
 
 
 def _normalize_frame(frame, dtype):
@@ -69,7 +78,7 @@ class _SpaceToDepthFirstConv(nn.Module):
     """The torso's 8x8/stride-4 stem conv, computed as space-to-depth(4)
     + a 2x2/stride-1 conv — the classic TPU reformulation for
     small-channel strided stems.  Measured on v5e at the bench shapes
-    (BENCH_NOTES round-5 conv table), it is a NEGATIVE result for THIS
+    (0.047 vs 0.107 MFU — ROADMAP D5), it is a NEGATIVE result for THIS
     architecture and stays off by default: the win only exists when the
     conv's input gradient is computed (3.4x there), but the stem's
     input is the uint8 frame — a gradient-free leaf — and with
@@ -115,9 +124,9 @@ class PallasStemConv(nn.Module):
     are interchangeable both ways (the _SpaceToDepthFirstConv
     contract, tests/test_conv_pallas.py pins it).
 
-    Runs the identical kernel under the Pallas interpreter off-TPU, so
-    CPU tier-1 exercises the same code path (the lstm_pallas.py
-    precedent).  MXU operand precision follows ``dtype``: a bfloat16
+    Interpreted or compiled is parallel/mesh.py ``pallas_interpret``'s
+    call (the one home of that decision), so CPU tier-1 exercises the
+    same kernel body.  MXU operand precision follows ``dtype``: a bfloat16
     module runs bf16 operands with f32 accumulation; override with
     ``matmul_dtype`` to decouple them."""
 
@@ -132,6 +141,7 @@ class PallasStemConv(nn.Module):
         # Lazy like _PallasCore: XLA-only consumers never pay (or
         # depend on) the Pallas TPU imports.
         from scalable_agent_tpu.ops import conv_pallas
+        from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
         c = x.shape[-1]
         kernel = self.param(
@@ -144,8 +154,7 @@ class PallasStemConv(nn.Module):
             "bfloat16" if jnp.dtype(self.dtype) == jnp.dtype(jnp.bfloat16)
             else "float32")
         out = conv_pallas.stem_conv(
-            x, k, self.stride, jax.default_backend() != "tpu",
-            matmul_dtype)
+            x, k, self.stride, pallas_interpret(), matmul_dtype)
         return out + b
 
 
@@ -181,7 +190,7 @@ class ShallowConvTorso(nn.Module):
         pallas_stem = _stem_backend(self.conv_backend)
         x = _normalize_frame(frame, self.dtype)
         for i, (num_ch, filter_size, stride) in enumerate(
-                [(32, 8, 4), (64, 4, 2), (128, 3, 2)]):
+                [STEM_GEOMETRY["shallow"], (64, 4, 2), (128, 3, 2)]):
             if i == 0 and pallas_stem:
                 x = PallasStemConv(
                     num_ch, filter_size, stride, dtype=self.dtype,
@@ -232,8 +241,11 @@ class ResNetTorso(nn.Module):
 
     ``conv_backend="pallas"`` routes the stem (``downscale_0`` — like
     the shallow torso's conv_0, its input is the gradient-free frame)
-    through the Pallas grad-W kernel; 3x3/stride-1 satisfies the
-    kernel's K % S == 0 layout, so both torsos honor the one flag.
+    through the Pallas grad-W kernel, so both torsos honor the one
+    flag — where the kernel takes the geometry: 3x3/stride-1 over
+    3-channel 72x96 frames pads 3 lanes to 128 and does not fit VMEM
+    (ops/conv_pallas.gradw_batch_tile), so the driver's ``auto`` keeps
+    this stem on XLA and an explicit ``pallas`` is refused there.
     """
 
     dtype: Any = jnp.float32
@@ -245,7 +257,8 @@ class ResNetTorso(nn.Module):
         x = _normalize_frame(frame, self.dtype)
         for i, (num_ch, num_blocks) in enumerate([(16, 2), (32, 2), (32, 2)]):
             if i == 0 and pallas_stem:
-                x = PallasStemConv(num_ch, 3, 1, dtype=self.dtype,
+                x = PallasStemConv(*STEM_GEOMETRY["resnet"],
+                                   dtype=self.dtype,
                                    name="downscale_0")(x)
             else:
                 x = nn.Conv(num_ch, (3, 3), padding="SAME",

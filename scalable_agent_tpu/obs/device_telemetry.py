@@ -290,7 +290,7 @@ def _materialize_leaves(mine: Dict) -> Dict[str, np.ndarray]:
     transfer when possible: the f32 leaves are device-concatenated
     into a single vector, copied once, and split back on the host.
     Per-leaf ``np.asarray`` would pay one round trip per leaf — on a
-    remote-tunnel device that is a full link RTT each, turning the
+    remote device that is a full link RTT each, turning the
     "few hundred bytes" fetch into ~a second of serial latency.  The
     per-leaf path remains as the fallback for host arrays and
     non-fully-addressable (multi-process) leaves, which read their

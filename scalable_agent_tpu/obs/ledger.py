@@ -198,7 +198,8 @@ PEAK_FLOPS = [
 
 def peak_flops_per_chip(device_kind: str) -> Optional[float]:
     """Roofline peak for a jax ``device_kind`` string; None when the
-    chip is unknown (CPU fallback — the MFU gauge then stays at 0)."""
+    kind is not in the table — fine for a CPU device, an error for a
+    TPU (driver._resolve_roofline_peak raises there)."""
     for prefix, peak in PEAK_FLOPS:
         if device_kind.startswith(prefix):
             return peak

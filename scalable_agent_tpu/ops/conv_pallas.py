@@ -2,8 +2,8 @@
 
 The per-kernel roofline ledger names ``conv0_gradw`` as the learner's
 worst kernel: XLA lowers the 8x8/stride-4 stem's weight gradient to a
-kernel that runs at 0.107 MFU for ~13 ms at the B=256 merged batch
-(BENCH_NOTES round-5 conv table), and the space-to-depth reformulation
+kernel last measured at 0.107 MFU for ~13 ms at the B=256 merged batch
+(ROADMAP, "What the record says"), and the space-to-depth reformulation
 made it WORSE (0.047) because it only helps the input gradient — which
 the stem, fed by the gradient-free uint8 frame, never computes.  This
 module attacks the weight gradient directly.
@@ -31,15 +31,20 @@ MXU.  Here the contraction is a single [K*K*Cin, N*OH*OW] x
 [N*OH*OW, Cout] matmul with the huge merged batch as the contracting
 dimension, which is the shape the MXU was built for.
 
-Requires ``K % S == 0`` (true for the 8/4 stem; D = K/S).  Any other
-kernel/stride pair silently falls back to XLA's own grad-W — the
-wrapper is then semantically inert, and the parity tests pin that.
+Which geometries the kernel takes is decided in ONE place,
+``gradw_batch_tile``: it needs ``K % S == 0`` (D = K/S; true for the
+8/4 stem) and one image's working set inside the VMEM budget (true for
+the shallow stem in either dtype; false for the ResNet 3x3/stride-1
+stem at 72x96, whose 3-channel taps pad 3 -> 128 lanes).  The driver's
+``conv_backend=auto`` policy asks it before routing a stem here;
+``conv_gradw`` itself REFUSES an unsupported geometry rather than
+quietly handing XLA the derivative — a run that says Pallas runs
+Pallas.
 
-Like ops/lstm_pallas.py: ``interpret=True`` runs the identical kernel
-under the Pallas interpreter so CPU tier-1 exercises the same code
-path, and ``matmul_dtype`` picks the MXU operand precision ("float32"
-bit-parity / "bfloat16" 2x rate, f32 accumulation either way via
-``preferred_element_type``).
+``interpret`` comes from parallel/mesh.py ``pallas_interpret`` (the one
+home of that decision); ``matmul_dtype`` picks the MXU operand
+precision ("float32" bit-parity / "bfloat16" 2x rate, f32 accumulation
+either way via ``preferred_element_type``).
 """
 
 import functools
@@ -55,11 +60,15 @@ from jax.experimental.pallas import tpu as pltpu
 # instruction's op_name metadata — change them together.
 GRADW_KERNEL_NAME = "pallas_conv0_gradw"
 
-# VMEM budget for one grid tile's working set (inputs + patch matrix);
-# the batch tile BN shrinks to fit.  Conservative: ~half of a v5e
-# core's 16 MB, leaving room for the pipeline's double buffering.
-_TILE_BYTES_BUDGET = 8 << 20
+# Scoped-VMEM budget for one grid step, out of the 16 MiB Mosaic grants
+# a kernel on a v5e by default.  What the step holds is modelled by
+# _image_vmem_bytes below — deliberately the worst case (every tap
+# gather live at once): AOT compiles for v5e showed the shallow stem
+# compiling up to BN=17 (bf16) / BN=8 (f32) where this model stops at
+# 11 / 5, and the ResNet stem failing even at BN=1 where it says so.
+_VMEM_BUDGET_BYTES = 14 << 20
 _MAX_BATCH_TILE = 32
+_LANES, _SUBLANES = 128, 8
 
 
 def _resolve_matmul_dtype(matmul_dtype):
@@ -117,28 +126,74 @@ def _gradw_kernel(xs_ref, g_ref, dw_ref, acc_s, *, depth, out_h, out_w,
     dw_ref[...] = acc_s[...]
 
 
-def _batch_tile(n, per_image_floats):
-    bn = max(1, _TILE_BYTES_BUDGET // max(1, per_image_floats * 4))
-    return max(1, min(n, _MAX_BATCH_TILE, bn))
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _image_vmem_bytes(tile_h, tile_w, s2c, out_h, out_w, f, depth,
+                      itemsize, mm_itemsize):
+    """Scoped VMEM one image costs a grid step, in the (8, 128)-tiled
+    layout Mosaic allocates (minor dim padded to 128 lanes, second-minor
+    to 8 sublanes — what a float count misses by 2.7x on a 48-lane
+    block and 42x on a 3-lane one): both input blocks double-buffered
+    by the pipeline, the D*D tap gathers, the concatenated patch matrix
+    and the flattened cotangent."""
+    inputs = 2 * itemsize * (
+        tile_h * _round_up(tile_w, _SUBLANES) * _round_up(s2c, _LANES)
+        + out_h * _round_up(out_w, _SUBLANES) * _round_up(f, _LANES))
+    rows = out_h * out_w
+    taps = depth * depth
+    gathers = taps * rows * _round_up(s2c, _LANES) * mm_itemsize
+    patches = rows * _round_up(taps * s2c, _LANES) * mm_itemsize
+    cotangent = rows * _round_up(f, _LANES) * mm_itemsize
+    return inputs + gathers + patches + cotangent
+
+
+def gradw_batch_tile(x_shape, features, kernel_size, stride, dtype,
+                     matmul_dtype=None) -> int:
+    """Images per grid step for the grad-W kernel at this geometry, or
+    0 when the kernel does not take it: ``kernel_size % stride != 0``
+    (the D-slice gather needs every tap on the s2d lattice), or a
+    single image's working set already exceeds the VMEM budget.  The
+    ONE support predicate — the driver's ``conv_backend`` policy and
+    ``conv_gradw`` both ask here.  ``dtype`` is the activations' (x and
+    its cotangent); ``matmul_dtype`` the MXU operands', ``dtype``'s
+    own when omitted (PallasStemConv's default)."""
+    n, h, w_in, c = x_shape
+    k, s = int(kernel_size), int(stride)
+    if k % s != 0:
+        return 0
+    depth = k // s
+    out_h, _ = _same_pads(h, k, s)
+    out_w, _ = _same_pads(w_in, k, s)
+    per_image = _image_vmem_bytes(
+        out_h + depth - 1, out_w + depth - 1, s * s * c, out_h, out_w,
+        features, depth, jnp.dtype(dtype).itemsize,
+        jnp.dtype(matmul_dtype or dtype).itemsize)
+    # The [K*K*C, F] f32 accumulator: output block (double-buffered)
+    # plus the scratch copy.
+    fixed = 3 * _round_up(k * k * c, _SUBLANES) * _round_up(
+        features, _LANES) * 4
+    return max(0, min(n, _MAX_BATCH_TILE,
+                      (_VMEM_BUDGET_BYTES - fixed) // per_image))
 
 
 def conv_gradw(x, g, kernel_size, stride, interpret=False,
                matmul_dtype="float32"):
     """Weight gradient of the SAME-padded ``kernel_size``/``stride``
-    conv: x [N,H,W,C], g [N,OH,OW,F] -> dW [K,K,C,F] float32.  Pallas
-    when ``kernel_size % stride == 0``, XLA's own grad-W otherwise."""
+    conv: x [N,H,W,C], g [N,OH,OW,F] -> dW [K,K,C,F] float32.  Raises
+    ValueError for a geometry ``gradw_batch_tile`` does not take."""
     matmul_dtype = _resolve_matmul_dtype(matmul_dtype)
     n, h, w_in, c = x.shape
     _, out_h, out_w, f = g.shape
     k, s = int(kernel_size), int(stride)
-    if k % s != 0:
-        # The D-slice gather needs every tap on the s2d lattice; other
-        # geometries take XLA's derivative (already fine off the stem).
-        w_shape = (k, k, c, f)
-        _, vjp_w = jax.vjp(
-            lambda ww: _forward(x, ww, s),
-            jnp.zeros(w_shape, x.dtype))
-        return vjp_w(g)[0].astype(jnp.float32)
+    bn = gradw_batch_tile(x.shape, f, k, s, x.dtype, matmul_dtype)
+    if bn == 0:
+        raise ValueError(
+            f"the Pallas grad-W kernel does not take a {k}x{k}/stride-"
+            f"{s} conv over {h}x{w_in}x{c} {x.dtype} frames "
+            f"(kernel_size % stride must be 0 and one image's tiles "
+            f"must fit VMEM); use conv_backend=xla or auto")
 
     depth = k // s
     _, (ph_lo, ph_hi) = _same_pads(h, k, s)
@@ -153,9 +208,6 @@ def conv_gradw(x, g, kernel_size, stride, interpret=False,
         n, hp // s, wp // s, s * s * c)
     tile_h, tile_w = out_h + depth - 1, out_w + depth - 1
     s2c = s * s * c
-    per_image = (tile_h * tile_w * s2c + out_h * out_w * f
-                 + out_h * out_w * depth * depth * s2c)
-    bn = _batch_tile(n, per_image)
     n_pad = -(-n // bn) * bn
     if n_pad != n:
         # Zero-padded images contribute zero cotangent rows — exact.
@@ -192,7 +244,8 @@ def stem_conv(x, w, stride=4, interpret=False, matmul_dtype="float32"):
     and input gradient are XLA's — numerically this op IS
     ``lax.conv_general_dilated(..., "SAME")``; only d/dW's lowering
     differs.  ``interpret`` and ``matmul_dtype`` follow
-    ops/lstm_pallas.py's contract."""
+    ops/lstm_pallas.py's contract.  Only for geometries
+    ``gradw_batch_tile`` takes: the backward pass raises otherwise."""
     return _forward(x, w, stride)
 
 

@@ -256,6 +256,7 @@ def from_importance_weights(
         # rank-2 [T, B]; extra trailing value dims are flattened into the
         # batch (lane) axis — the recurrence is independent per column.
         from scalable_agent_tpu.ops import vtrace_pallas
+        from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
         shape = log_rhos.shape
         # Stop gradients at the kernel INPUTS: the outputs are
@@ -268,7 +269,7 @@ def from_importance_weights(
             bootstrap_value.reshape(-1),
             clip_rho_threshold=clip_rho_threshold,
             clip_pg_rho_threshold=clip_pg_rho_threshold,
-            interpret=jax.default_backend() != "tpu")
+            interpret=pallas_interpret())
         return VTraceReturns(
             vs=lax.stop_gradient(vs.reshape(shape)),
             pg_advantages=lax.stop_gradient(pg.reshape(shape)),

@@ -4,6 +4,7 @@ from scalable_agent_tpu.parallel.mesh import (
     fused_kernels_profitable,
     make_mesh,
     model_parallel_shardings,
+    pallas_interpret,
     replicated_sharding,
 )
 from scalable_agent_tpu.parallel.sequence import (

@@ -77,11 +77,7 @@ def _enable_cpu_gloo_collectives():
     later backend init in this process with an unrelated-looking
     ``make_gloo_tcp_collectives`` error."""
     flag, value = "jax_cpu_collectives_implementation", "gloo"
-    try:
-        prev = getattr(jax.config, flag)
-    except AttributeError:  # pre-rename jax spelling
-        flag, value = "jax_cpu_enable_gloo_collectives", True
-        prev = getattr(jax.config, flag, False)
+    prev = getattr(jax.config, flag)
     try:
         jax.config.update(flag, value)
     except Exception:

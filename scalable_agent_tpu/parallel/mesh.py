@@ -120,23 +120,34 @@ def model_parallel_shardings(mesh: Mesh, tree):
     return jax.tree_util.tree_map(shard, tree)
 
 
+def pallas_interpret() -> bool:
+    """The ONE home of every kernel's ``interpret=`` decision: Pallas
+    kernels run under the interpreter exactly when the backend is not a
+    TPU (the CPU test rig), and are compiled by Mosaic otherwise — a
+    TPU run never interprets.  Interpret mode proves the kernel body's
+    arithmetic; it never sees a VMEM limit, a tiling rule, or the SPMD
+    partitioner (tests/test_chip_bringup.py AOT-compiles for that)."""
+    return jax.default_backend() != "tpu"
+
+
 def fused_kernels_profitable(mesh: Optional[Mesh] = None,
                              num_devices: Optional[int] = None) -> bool:
-    """THE policy behind the ``"auto"`` LSTM-core choice (Config/driver
-    core_impl, bench): the fused Pallas LSTM core (ops/lstm_pallas.py,
-    1.6-2.2x over nn.scan on-chip — BENCH_NOTES r4) wins only on a
-    single-device TPU mesh — ``pallas_call`` has no SPMD partitioning
-    rule, so a multi-device mesh would replicate the call (correct but
-    wasteful), and non-TPU backends only have the interpreter.  (The
-    V-trace scan_impl="auto" no longer consults this: at production
-    shapes both V-trace impls are ~2-5 us, and the associative scan is
-    the shardable one, so auto always picks it.)
+    """THE policy behind every ``"auto"`` Pallas-kernel choice — the
+    fused LSTM core (``core_impl``, ops/lstm_pallas.py) and the stem
+    grad-W kernel (``conv_backend``, ops/conv_pallas.py) alike: Pallas
+    only on a single-device TPU mesh.  ``pallas_call`` has no SPMD
+    partitioning rule, so on a multi-device mesh the update does not
+    lower at all ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map."), and non-TPU backends only
+    have the interpreter.  (V-trace's scan_impl="auto" does not consult
+    this: the associative scan is the shardable form and auto always
+    picks it.)
 
     Pass the actual ``mesh`` when one exists; ``num_devices`` when only
     the intended mesh size is known (e.g. from Config before the mesh is
     built); neither to ask about the whole process.
     """
-    if jax.default_backend() != "tpu":
+    if pallas_interpret():
         return False
     if mesh is not None and getattr(mesh, "devices", None) is not None:
         return mesh.devices.size == 1

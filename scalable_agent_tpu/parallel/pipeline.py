@@ -45,8 +45,6 @@ def gpipe_spmd(mesh, stage_fn, stage_params, microbatches,
     replicated over the mesh.  Differentiable in ``stage_params`` and
     ``microbatches``.
     """
-    from scalable_agent_tpu.parallel._compat import mark_varying, shard_map
-
     num_stages = mesh.shape[axis]
     num_micro = microbatches.shape[0]
     for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -77,7 +75,7 @@ def gpipe_spmd(mesh, stage_fn, stage_params, microbatches,
 
         # The carry must be typed as device-varying over the pipeline
         # axis (ppermute's output is), or the scan carry types mismatch.
-        zero = mark_varying(jnp.zeros_like(xs[0]), axis)
+        zero = lax.pcast(jnp.zeros_like(xs[0]), axis, to="varying")
         _, ys = lax.scan(tick, zero, jnp.arange(num_stages + num_micro - 1))
 
         # The last stage emits microbatch m at tick t = (S-1) + m; mask
@@ -95,7 +93,7 @@ def gpipe_spmd(mesh, stage_fn, stage_params, microbatches,
     stage_sharded = jax.tree_util.tree_map(
         lambda p: PartitionSpec(axis, *([None] * (p.ndim - 1))),
         stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(stage_sharded, PartitionSpec()),
         out_specs=PartitionSpec(),

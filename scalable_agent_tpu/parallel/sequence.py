@@ -90,8 +90,6 @@ def from_importance_weights_sharded(
     must divide evenly by the axis size.  Numerics match the
     single-device associative path (same composition order).
     """
-    from scalable_agent_tpu.parallel._compat import shard_map
-
     log_rhos = jnp.asarray(log_rhos, jnp.float32)
     discounts = jnp.asarray(discounts, jnp.float32)
     rewards = jnp.asarray(rewards, jnp.float32)
@@ -122,7 +120,7 @@ def from_importance_weights_sharded(
         time_sharded = PartitionSpec(seq_axis, batch_axis, *trailing)
     else:
         time_sharded = PartitionSpec(seq_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_chunk_recurrence, axis_name=seq_axis),
         mesh=mesh,
         in_specs=(time_sharded, time_sharded),

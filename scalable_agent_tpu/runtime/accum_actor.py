@@ -5,7 +5,7 @@ agent output to the host every step and re-uploads the assembled
 trajectory to the device for the learner — the host↔device link carries
 every observation TWICE plus per-step logits/baselines, and the host pays
 a blocking fetch latency for each of them.  On hardware where that link
-is expensive (any TPU, and catastrophically so over a remote-tunnel
+is expensive (any TPU, and catastrophically so over a remote
 attachment), the actor loop becomes link-bound, not compute-bound.
 
 This module inverts the data flow, which is the idiomatic JAX answer:
@@ -396,7 +396,7 @@ class GroupedAccumActor:
     per-step link cost is ~1 RTT regardless of k.  The trade: groups
     step in lockstep (the slowest group's env gates the batch), which
     is the right trade exactly when the link RTT, not env variance,
-    dominates (any remote TPU attachment; BENCH_NOTES r3 measured
+    dominates (any remote TPU attachment; the r3 rig measured
     70-120 ms blocking fetches).
 
     Trajectory layout, rng streams, and math are identical to

@@ -31,8 +31,9 @@ in PAPERS.md):
   metrics row) lands in ``fleet/mttr_s`` and ``fleet_epochs.jsonl``,
   decomposed into detect/relaunch/compile/restore segments via the
   driver's ``mttr_breakdown.json`` startup beacon (the compile
-  segment also lands in ``fleet/mttr_compile_s``; arm
-  ``--compile_cache_dir`` to flatten it).
+  segment also lands in ``fleet/mttr_compile_s``; the persistent
+  compile cache — utils/compile_cache.py, inherited by every relaunch
+  through ``JAX_COMPILATION_CACHE_DIR`` — is what flattens it).
 
 - **Rejoin.**  When the lost host comes back (locally:
   ``--elastic_rejoin_delay_s`` elapsed, or an operator touched
@@ -104,8 +105,8 @@ SUPERVISOR_PROM_NAME = "metrics.supervisor.prom"
 # by the relaunched coordinator after its first dispatch.  The
 # supervisor joins it (epoch-matched) into the epochs-log ``mttr``
 # record so the recovery time decomposes into detect / relaunch /
-# compile / restore segments — the evidence behind the
-# --compile_cache_dir MTTR engineering (docs/robustness.md).
+# compile / restore segments — the evidence behind the compile-cache
+# MTTR engineering (docs/robustness.md).
 MTTR_BREAKDOWN_NAME = "mttr_breakdown.json"
 
 # Exit-code policy (the supervisor side of runtime/exit_codes.py).
@@ -173,10 +174,15 @@ class DriverLauncher:
     """Spawn one epoch's worker fleet: N copies of the driver CLI on
     this machine, sharing a fresh coordinator port.  Workers inherit
     the supervisor's stdout/stderr (nothing buffers, nothing
-    deadlocks) and environment — the CPU test rig sets JAX_PLATFORMS
-    / XLA_FLAGS there.  Real multi-host deployments replace this class
-    (one worker per host via the cluster scheduler); the supervisor's
-    state machine doesn't change."""
+    deadlocks) and environment.
+
+    CPU rig only, and it says so: N drivers on ONE machine each claim
+    every accelerator they can see, and a TPU chip belongs to one
+    process — the second worker would fail or hang at backend init.
+    ``launch`` therefore refuses unless the workers' environment pins
+    ``JAX_PLATFORMS=cpu``.  Real multi-host deployments replace this
+    class (one worker per host via the cluster scheduler — ROADMAP
+    R7); the supervisor's state machine doesn't change."""
 
     # Supervisor-owned fields the workers must not inherit verbatim.
     EXCLUDE = ("elastic", "fleet_epoch", "distributed_coordinator",
@@ -188,6 +194,16 @@ class DriverLauncher:
 
     def launch(self, epoch: int, num_processes: int,
                port: int) -> List[subprocess.Popen]:
+        env = os.environ if self._env is None else self._env
+        platforms = env.get("JAX_PLATFORMS", "")
+        if platforms.split(",")[0].strip() != "cpu":
+            raise RuntimeError(
+                f"DriverLauncher starts {num_processes} driver "
+                f"process(es) on this one machine and has only ever run "
+                f"on the CPU rig: with JAX_PLATFORMS={platforms!r} every "
+                f"worker would claim every TPU chip, and a chip belongs "
+                f"to one process.  Set JAX_PLATFORMS=cpu, or launch one "
+                f"worker per host with your scheduler (ROADMAP R7)")
         base = self._config.to_argv(exclude=self.EXCLUDE)
         workers = []
         for proc_id in range(num_processes):
@@ -273,8 +289,8 @@ class ElasticSupervisor:
         self._mttr_compile_gauge = registry.gauge(
             "fleet/mttr_compile_s",
             "compile segment of the last reshard's MTTR (the relaunched "
-            "coordinator's first dispatch) — near-zero when "
-            "--compile_cache_dir turns it into a disk read")
+            "coordinator's first dispatch) — near-zero when the "
+            "persistent compile cache turns it into a disk read")
         self._restarts = registry.counter(
             "fleet/supervisor_restarts_total",
             "fleet relaunches after a non-clean epoch exit")

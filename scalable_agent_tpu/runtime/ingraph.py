@@ -39,6 +39,10 @@ from scalable_agent_tpu.obs.device_telemetry import (
     fetch_merged,
     merge_init,
 )
+from scalable_agent_tpu.parallel.mesh import (
+    batch_sharding,
+    replicated_sharding,
+)
 from scalable_agent_tpu.runtime.faults import get_fault_injector
 from scalable_agent_tpu.runtime.learner import Learner, Trajectory
 from scalable_agent_tpu.types import AgentOutput, AgentState
@@ -142,8 +146,6 @@ class InGraphTrainer:
         # on the carry propagates through the scan, so env transitions
         # and agent inference compute on their batch shard's device
         # (PartitionSpec("data") shards axis 0 at any rank).
-        from scalable_agent_tpu.parallel.mesh import batch_sharding
-
         self._batch_sharding = batch_sharding(
             learner.mesh, batch_axis_index=0)
         self._env_tel_spec = env_telemetry_spec()
@@ -183,6 +185,18 @@ class InGraphTrainer:
             # pre-peak checkpointed runs byte-for-byte.
             streak_peak=(jnp.float32(0.0)
                          if self._learner._finite_guard else None))
+        # Commit the carry to the placement the fused step hands back
+        # (batch-sharded rollout state, replicated scalars): an
+        # uncommitted first carry has different input types from every
+        # later one, and the whole fused program compiled TWICE.
+        replicated = replicated_sharding(self._learner.mesh)
+        carry = jax.device_put(carry, TrainCarry(
+            rollout=jax.tree_util.tree_map(
+                lambda x: (self._batch_sharding if np.ndim(x)
+                           else replicated), carry.rollout),
+            telemetry=replicated,
+            streak_peak=(None if carry.streak_peak is None
+                         else replicated)))
         example = Trajectory(
             agent_state=core_state,
             env_outputs=_stack_first(

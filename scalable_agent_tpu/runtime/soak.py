@@ -46,8 +46,9 @@ flags, forces ``--chaos_channel``, launches the elastic supervisor
 single-process driver, appends the schedule's channel lines at their
 sampled times, SIGTERMs the run at the wall budget (the preemption
 grace protocol drains to one final verified checkpoint), then grades.
-Pair it with ``--compile_cache_dir`` so mid-soak relaunches compile
-from disk — the MTTR engineering half of the story
+Mid-soak relaunches compile from disk: the persistent compile cache
+(utils/compile_cache.py) is always armed and every child inherits
+``JAX_COMPILATION_CACHE_DIR`` — the MTTR engineering half of the story
 (docs/robustness.md, "Running a chaos soak").
 
 The schedule is deterministic in (seed, faults, budget, points):

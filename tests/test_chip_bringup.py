@@ -1,0 +1,358 @@
+"""Chip bring-up contracts that the CPU rig can hold (ISSUE 21).
+
+Nothing here touches a TPU.  What it pins instead:
+
+- the compile-cache helper: the environment variable wins and nothing
+  else is set; unset means the fixed in-checkout path; never a
+  temporary directory;
+- the ONE kernel policy: ``core_impl`` and ``conv_backend`` agree for 1
+  and 4 devices, the interpret decision has one home, a stem the
+  grad-W kernel does not take is routed (auto) or refused (explicit);
+- no CPU fallback: ``chip_smoke.py`` and ``bench.py`` exit non-zero
+  without a TPU, before doing any work, printing no result;
+- the roofline peak and the one-machine launcher fail loudly;
+- and the class of fault interpret mode can never see — a VMEM limit,
+  the SPMD partitioner — via an AOT compile of the default learner
+  update for a real v5e topology (``libtpu`` compiles without a chip),
+  at production shapes, on 1 and on 4 devices, plus the float32 and
+  ResNet configurations.
+"""
+
+import dataclasses
+import os
+import re
+import subprocess
+import sys
+import tempfile
+
+import jax
+import pytest
+
+from scalable_agent_tpu import driver
+from scalable_agent_tpu.config import Config
+from scalable_agent_tpu.parallel import mesh as mesh_lib
+from scalable_agent_tpu.utils import compile_cache
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# -- the compile cache ------------------------------------------------------
+
+
+@pytest.fixture
+def cache_config():
+    """Restore jax's cache config after a helper call."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {name: getattr(jax.config, name) for name in names}
+    yield
+    for name, value in before.items():
+        jax.config.update(name, value)
+
+
+class TestCompileCache:
+    def test_env_var_wins_and_nothing_else_is_set(
+            self, monkeypatch, tmp_path, cache_config):
+        placed = str(tmp_path / "placed_from_outside")
+        monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.setup_compile_cache() == placed
+        # JAX reads the variable itself; the helper names no directory
+        # in code (and creates none).
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not os.path.exists(
+            os.path.join(REPO_ROOT, ".jax_cache", "placed_from_outside"))
+
+    def test_unset_means_the_fixed_in_checkout_path(
+            self, monkeypatch, cache_config):
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        path = compile_cache.setup_compile_cache()
+        assert path == os.path.join(REPO_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert os.path.isdir(path)
+        assert not path.startswith(tempfile.gettempdir())
+        # Same answer every time: no pid, no timestamp.
+        assert compile_cache.setup_compile_cache() == path
+
+    def test_the_path_is_ignored_by_git(self):
+        ignored = open(os.path.join(REPO_ROOT, ".gitignore")).read()
+        assert ".jax_cache/" in ignored.split()
+
+    def test_config_has_no_cache_flag(self):
+        fields = {f.name for f in dataclasses.fields(Config)}
+        assert not [name for name in fields if "cache" in name]
+
+
+# -- the kernel policy ------------------------------------------------------
+
+
+def _as_tpu(monkeypatch):
+    """Trace-time stand-in for a TPU backend: every policy decision
+    reads ``jax.default_backend()`` (user-level attribute; jax's own
+    internals do not go through it)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+class TestKernelPolicy:
+    @pytest.mark.parametrize("devices,want", [(1, "pallas"), (4, "xla")])
+    def test_core_and_conv_agree_on_tpu(self, monkeypatch, devices, want):
+        _as_tpu(monkeypatch)
+        config = Config(mesh_data=devices)
+        assert driver.resolve_core_impl(config) == want
+        assert driver.resolve_conv_backend(config) == want
+
+    def test_off_tpu_both_are_xla(self):
+        config = Config(mesh_data=1)
+        assert driver.resolve_core_impl(config) == "xla"
+        assert driver.resolve_conv_backend(config) == "xla"
+        assert mesh_lib.pallas_interpret() is True
+
+    def test_a_tpu_run_never_interprets(self, monkeypatch):
+        _as_tpu(monkeypatch)
+        assert mesh_lib.pallas_interpret() is False
+
+    def test_the_interpret_decision_has_one_home(self):
+        """No module but parallel/mesh.py derives ``interpret=`` (or
+        anything else) from a ``default_backend() != "tpu"`` test, and
+        every kernel call site asks ``pallas_interpret``."""
+        pattern = re.compile(r"default_backend\(\)\s*!=")
+        offenders = []
+        package = os.path.join(REPO_ROOT, "scalable_agent_tpu")
+        for root, _, files in os.walk(package):
+            for name in files:
+                path = os.path.join(root, name)
+                if not name.endswith(".py"):
+                    continue
+                if pattern.search(open(path).read()):
+                    offenders.append(os.path.relpath(path, REPO_ROOT))
+        assert offenders == ["scalable_agent_tpu/parallel/mesh.py"]
+        for relative in ("models/agent.py", "models/networks.py",
+                         "ops/vtrace.py"):
+            source = open(os.path.join(package, relative)).read()
+            assert "pallas_interpret()" in source, relative
+
+    def test_float32_stem_gets_a_smaller_tile_not_a_mosaic_error(self):
+        from scalable_agent_tpu.ops.conv_pallas import gradw_batch_tile
+
+        shape = (101 * 32, 72, 96, 3)
+        bf16 = gradw_batch_tile(shape, 32, 8, 4, "bfloat16", "bfloat16")
+        f32 = gradw_batch_tile(shape, 32, 8, 4, "float32", "float32")
+        # AOT compiles for v5e: bf16 fits up to 17 images, f32 up to 8.
+        assert 1 <= bf16 <= 17
+        assert 1 <= f32 <= 8
+        assert f32 < bf16
+
+    def test_resnet_stem_is_routed_to_xla_loudly(self, monkeypatch):
+        from scalable_agent_tpu.ops.conv_pallas import gradw_batch_tile
+
+        _as_tpu(monkeypatch)
+        assert gradw_batch_tile((101 * 32, 72, 96, 3), 16, 3, 1,
+                                "bfloat16", "bfloat16") == 0
+        warned = []
+        monkeypatch.setattr(
+            driver.log, "warning",
+            lambda message, *args: warned.append(message % args))
+        config = Config(mesh_data=1, torso_type="resnet")
+        assert driver.resolve_conv_backend(config) == "xla"
+        assert warned and "resnet stem" in warned[0]
+        # The core is not dragged along: one rule, two kernels, each
+        # where it fits.
+        assert driver.resolve_core_impl(config) == "pallas"
+        with pytest.raises(ValueError, match="does not take"):
+            driver.resolve_conv_backend(
+                dataclasses.replace(config, conv_backend="pallas"))
+
+
+# -- no CPU fallback --------------------------------------------------------
+
+
+def _run(argv, cwd, timeout=120):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    return subprocess.run([sys.executable, *argv], cwd=cwd, env=env,
+                          capture_output=True, text=True,
+                          timeout=timeout)
+
+
+class TestNoCpuFallback:
+    def test_chip_smoke_refuses_without_a_tpu(self, tmp_path):
+        proc = _run(["chip_smoke.py"], REPO_ROOT)
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == ""       # no result, no training
+        assert "no TPU" in proc.stderr
+        assert not list(tmp_path.iterdir())
+
+    def test_chip_smoke_alone_in_a_directory_fails(self, tmp_path):
+        """The script without the program (the driver's negative
+        control) must fail too — not print an ok line."""
+        import shutil
+
+        shutil.copy(os.path.join(REPO_ROOT, "chip_smoke.py"), tmp_path)
+        proc = _run(["chip_smoke.py"], str(tmp_path))
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == ""
+
+    def test_bench_refuses_without_a_tpu(self):
+        proc = _run(["bench.py", "--suites=bench_obs"], REPO_ROOT)
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == ""
+        assert "no TPU" in proc.stderr
+
+    def test_bench_has_no_backend_probe_or_cpu_switch(self):
+        source = open(os.path.join(REPO_ROOT, "bench.py")).read()
+        assert "_probe_backend" not in source
+        assert 'os.environ["JAX_PLATFORMS"]' not in source
+        assert '"jax_platforms"' not in source
+
+
+# -- loud failures on the chip path -----------------------------------------
+
+
+class _FakeTpu:
+    platform = "tpu"
+    device_kind = "TPU v9 imaginary"
+
+
+class TestLoudFailures:
+    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        monkeypatch.delenv("SCALABLE_AGENT_LEDGER_MFU_PEAK",
+                           raising=False)
+        monkeypatch.setattr(jax, "local_devices", lambda: [_FakeTpu()])
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            driver._resolve_roofline_peak()
+
+    def test_unknown_cpu_kind_is_not(self, monkeypatch):
+        monkeypatch.delenv("SCALABLE_AGENT_LEDGER_MFU_PEAK",
+                           raising=False)
+        assert driver._resolve_roofline_peak() is None
+
+    def test_unparsable_peak_override_is_an_error(self, monkeypatch):
+        monkeypatch.setenv("SCALABLE_AGENT_LEDGER_MFU_PEAK", "fast")
+        with pytest.raises(ValueError, match="not a number"):
+            driver._resolve_roofline_peak()
+
+    def test_disarmed_mfu_gauge_is_a_warning(self, monkeypatch):
+        monkeypatch.setenv("SCALABLE_AGENT_LEDGER_MFU_PEAK", "1e12")
+        warned = []
+        monkeypatch.setattr(
+            driver.log, "warning",
+            lambda message, *args: warned.append(message % args))
+
+        def lower_fn():
+            raise RuntimeError("no cost model for this custom call")
+
+        driver._configure_live_mfu(object(), lower_fn, 1)
+        assert warned and "DISARMED" in warned[0]
+
+    @pytest.mark.parametrize("platforms", ["", "tpu", "tpu,cpu"])
+    def test_one_machine_launcher_refuses_off_the_cpu_rig(
+            self, monkeypatch, platforms):
+        from scalable_agent_tpu.runtime import elastic
+
+        monkeypatch.setattr(
+            elastic.subprocess, "Popen",
+            lambda *a, **k: pytest.fail("a worker was started"))
+        launcher = elastic.DriverLauncher(
+            Config(), env={"JAX_PLATFORMS": platforms})
+        with pytest.raises(RuntimeError, match="CPU rig"):
+            launcher.launch(epoch=0, num_processes=2, port=1)
+
+
+# -- AOT compile for a real v5e topology ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e_topology():
+    try:
+        from jax.experimental import topologies
+
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu in this install: skip, not pass
+        pytest.skip(f"cannot build a v5e topology here: {exc}")
+
+
+def _compile_default_update(monkeypatch, topology, devices, **overrides):
+    """Lower + compile the learner update the driver would build for
+    ``devices`` v5e chips at the production shapes (T=100, B=32, 72x96
+    uint8), against abstract arguments placed on the topology's
+    devices.  Returns ``(agent, lowered_text)``; compile errors (VMEM,
+    partitioning) propagate."""
+    from scalable_agent_tpu.parallel import (
+        MeshSpec,
+        batch_sharding,
+        make_mesh,
+        replicated_sharding,
+    )
+    from scalable_agent_tpu.runtime.learner import Trajectory
+
+    _as_tpu(monkeypatch)
+    # per_leaf: the transport is not part of the update program, and
+    # the packed one would build its staging layout for nothing.
+    config = Config(mesh_data=devices, logdir="/tmp/unused",
+                    transport="per_leaf", **overrides)
+    observation_spec, action_space, _ = driver.probe_env(config)
+    agent = driver.build_agent(config, action_space,
+                               observation_spec.frame.shape)
+    learner = driver.build_training_learner(config, agent)
+    example = driver.zero_trajectory(
+        config, observation_spec, agent, batch=config.batch_size,
+        t_plus_1=config.unroll_length + 1)
+    state = jax.eval_shape(learner.init, jax.random.key(0), example)
+
+    mesh = make_mesh(MeshSpec(data=devices),
+                     devices=topology.devices[:devices])
+    replicated = replicated_sharding(mesh)
+
+    def abstract(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    time_major = batch_sharding(mesh, 1)
+    trajectory = Trajectory(
+        agent_state=abstract(example.agent_state,
+                             batch_sharding(mesh, 0)),
+        env_outputs=abstract(example.env_outputs, time_major),
+        agent_outputs=abstract(example.agent_outputs, time_major))
+    lowered = learner.lower_update(
+        abstract(state, replicated), trajectory,
+        abstract(learner.device_telemetry, replicated))
+    lowered.compile()
+    return agent, lowered.as_text()
+
+
+class TestAotCompileForV5e:
+    def test_one_chip_default_keeps_all_three_kernels(
+            self, monkeypatch, v5e_topology):
+        agent, text = _compile_default_update(monkeypatch, v5e_topology, 1)
+        assert (agent.core_impl, agent.conv_backend) == ("pallas",
+                                                         "pallas")
+        # LSTM forward, LSTM backward, stem grad-W.
+        assert text.count("tpu_custom_call") == 3
+
+    def test_four_chip_default_lowers_with_no_mosaic_call(
+            self, monkeypatch, v5e_topology):
+        """The plain default command on a four-chip host: before the
+        one-policy fix the stem stayed Pallas on the data=4 mesh and
+        lowering died with "Mosaic kernels cannot be automatically
+        partitioned"."""
+        agent, text = _compile_default_update(monkeypatch, v5e_topology, 4)
+        assert (agent.core_impl, agent.conv_backend) == ("xla", "xla")
+        assert text.count("tpu_custom_call") == 0
+
+    def test_float32_compiles_with_the_kernels(
+            self, monkeypatch, v5e_topology):
+        """Was: pallas_conv0_gradw scoped VMEM 17.36M > 16.00M."""
+        agent, text = _compile_default_update(
+            monkeypatch, v5e_topology, 1, compute_dtype="float32")
+        assert agent.conv_backend == "pallas"
+        assert text.count("tpu_custom_call") == 3
+
+    def test_resnet_compiles_with_its_stem_on_xla(
+            self, monkeypatch, v5e_topology):
+        """Was: pallas_conv0_gradw scoped VMEM 42.81M > 16.00M."""
+        agent, text = _compile_default_update(
+            monkeypatch, v5e_topology, 1, torso_type="resnet")
+        assert (agent.core_impl, agent.conv_backend) == ("pallas", "xla")
+        assert text.count("tpu_custom_call") == 2

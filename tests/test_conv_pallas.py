@@ -1,5 +1,5 @@
 """Pallas grad-W stem kernel (ops/conv_pallas.py): parity against
-XLA's own derivative across geometry edges, the K % S fallback, the
+XLA's own derivative across geometry edges, the K % S refusal, the
 bf16 MXU-operand mode, batch-tile padding, and checkpoint
 interchangeability of the agent-facing PallasStemConv module.
 
@@ -15,7 +15,9 @@ import pytest
 
 from scalable_agent_tpu.ops import conv_pallas
 
-_INTERPRET = jax.default_backend() != "tpu"
+from scalable_agent_tpu.parallel.mesh import pallas_interpret
+
+_INTERPRET = pallas_interpret()
 
 
 def _conv(x, w, s):
@@ -74,14 +76,17 @@ class TestGradWParity:
         np.testing.assert_allclose(dw, ref, rtol=3e-2,
                                    atol=3e-2 * scale)
 
-    def test_k_not_multiple_of_stride_falls_back_exact(self):
-        """K % S != 0 breaks the space-to-depth tap lattice, so the op
-        routes to XLA's own derivative — bit-identical by
-        construction."""
+    def test_k_not_multiple_of_stride_is_refused_not_rerouted(self):
+        """K % S != 0 breaks the space-to-depth tap lattice.  The
+        kernel does not take it — and says so: the support predicate
+        answers 0, and conv_gradw raises instead of quietly handing
+        XLA the derivative (which geometry runs where is the kernel
+        policy's decision, made once, in the open)."""
         x, g = _random_case(11, 3, 10, 13, 3, 8, 2)
-        dw = conv_pallas.conv_gradw(x, g, 3, 2, interpret=_INTERPRET)
-        ref = _reference_gradw(x, g, 3, 2)
-        np.testing.assert_array_equal(np.asarray(dw), np.asarray(ref))
+        assert conv_pallas.gradw_batch_tile(
+            x.shape, 8, 3, 2, x.dtype, "float32") == 0
+        with pytest.raises(ValueError, match="does not take"):
+            conv_pallas.conv_gradw(x, g, 3, 2, interpret=_INTERPRET)
 
     def test_batch_tile_padding_remainder(self, monkeypatch):
         """N not divisible by the batch tile zero-pads the grid's last

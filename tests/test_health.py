@@ -170,11 +170,11 @@ class TestDetectorGoldens:
         assert record["baseline_source"] == "BENCH_r07.json"
         assert record["z"] is None
 
-    def test_prime_from_committed_rounds(self):
-        """The real repo root carries parseable BENCH rounds with the
-        throughput keys — 'auto' priming must find them."""
+    def test_prime_from_committed_rounds(self, bench_history):
+        """A bench dir of parseable BENCH rounds with the throughput
+        keys — priming must find them, across every wrapper format."""
         mon = _monitor(default_detectors())
-        assert mon.prime_from_bench(REPO_ROOT) is not None
+        assert mon.prime_from_bench(bench_history) is not None
 
     def test_nonfinite_rate_detector(self):
         clock = _FakeClock()
@@ -473,12 +473,13 @@ class TestWatchConsole:
         assert "loss_grad_fusion" in text
         assert "anomalies  2 total (1 open" in text
 
-    def test_vs_baseline_uses_committed_rounds(self, tmp_path):
+    def test_vs_baseline_uses_committed_rounds(self, tmp_path,
+                                               bench_history):
         from scalable_agent_tpu.obs import watch
 
         logdir = str(tmp_path / "run")
         _write_synthetic_logdir(logdir)
-        payload = watch.build_payload(logdir, bench_dir=REPO_ROOT)
+        payload = watch.build_payload(logdir, bench_dir=bench_history)
         assert payload["baseline"] is not None
         assert payload["fps"]["vs_baseline"] is not None
 

@@ -292,15 +292,16 @@ class TestReportKernels:
         assert "worst kernel: loss_grad_fusion" in out
 
     def test_report_names_conv0_gradw_from_bench_artifact(
-            self, tmp_path, capsys):
-        """The committed BENCH_r05 artifact carries the hand-measured
-        kernel rooflines; the report must surface them automatically
-        and name conv0_gradw (0.107 MFU) as the worst kernel."""
+            self, tmp_path, capsys, bench_history):
+        """The history's newest artifact (r05, truncated) carries the
+        hand-measured kernel rooflines; the report must surface them
+        automatically and name conv0_gradw (0.107 MFU) as the worst
+        kernel."""
         from scalable_agent_tpu.obs import report
 
         logdir = str(tmp_path / "run")
         self._write_minimal_prom(logdir)
-        payload = report.build_report(logdir, bench_dir=REPO_ROOT)
+        payload = report.build_report(logdir, bench_dir=bench_history)
         bench_kernels = payload["bench_kernels"]
         assert bench_kernels is not None
         assert bench_kernels["worst"] == "conv0_gradw"
@@ -308,13 +309,13 @@ class TestReportKernels:
         names = {row["name"] for row in bench_kernels["rows"]}
         assert "conv0_gradw" in names
 
-        assert report.main([logdir, "--bench_dir", REPO_ROOT]) == 0
+        assert report.main([logdir, "--bench_dir", bench_history]) == 0
         out = capsys.readouterr().out
         assert "worst kernels (newest bench artifact)" in out
         assert "worst kernel: conv0_gradw" in out
 
         assert report.main(["--json", logdir,
-                            "--bench_dir", REPO_ROOT]) == 0
+                            "--bench_dir", bench_history]) == 0
         machine = json.loads(capsys.readouterr().out)
         assert machine["bench_kernels"]["worst"] == "conv0_gradw"
 

@@ -5,10 +5,10 @@ The chooser's RTT-floor model is validated two ways:
 1. Against an INDEPENDENT discrete-event simulation of the sharded
    lockstep pipeline (shards as loops serializing their uploads on one
    link): across link profiles spanning co-located chips to collapsed
-   tunnels, the chosen shard count must land within 10% of the
-   simulation's sweep optimum (round-4 VERDICT item 3's bar).
+   remote links, the chosen shard count must land within 10% of the
+   simulation's sweep optimum.
 2. Against the round-4 measured sweep facts: 2 shards beat 1 and 3 on
-   the degraded tunnel; 1 shard wins co-located (round-4 ADVICE: a
+   the degraded link; 1 shard wins co-located (round-4 ADVICE: a
    static default of 2 regresses co-located deployments).
 """
 
@@ -27,11 +27,11 @@ from scalable_agent_tpu.runtime.linktune import (
 # The bench fleet: 5 groups x 256 envs, 72x96x3 uint8 frames.
 GROUPS, GROUP_SIZE, FRAME_BYTES = 5, 256, 72 * 96 * 3
 
-TUNNEL_R4 = LinkProfile(rtt_s=0.085, h2d_bytes_per_s=95e6)
-TUNNEL_COLLAPSED = LinkProfile(rtt_s=0.09, h2d_bytes_per_s=30e6)
-TUNNEL_R3 = LinkProfile(rtt_s=0.10, h2d_bytes_per_s=800e6)
+REMOTE_R4 = LinkProfile(rtt_s=0.085, h2d_bytes_per_s=95e6)
+REMOTE_COLLAPSED = LinkProfile(rtt_s=0.09, h2d_bytes_per_s=30e6)
+REMOTE_R3 = LinkProfile(rtt_s=0.10, h2d_bytes_per_s=800e6)
 COLOCATED = LinkProfile(rtt_s=0.0002, h2d_bytes_per_s=20e9)
-ALL_PROFILES = [TUNNEL_R4, TUNNEL_COLLAPSED, TUNNEL_R3, COLOCATED]
+ALL_PROFILES = [REMOTE_R4, REMOTE_COLLAPSED, REMOTE_R3, COLOCATED]
 
 
 def simulate_fps(shards, num_groups, group_size, frame_bytes, link,
@@ -70,7 +70,7 @@ class TestChooserVsSimulation:
             f"sweep optimum is {best:.0f}: {sims}")
 
     @pytest.mark.parametrize("groups,link", [
-        (2, TUNNEL_R4), (3, TUNNEL_R4), (8, TUNNEL_R3),
+        (2, REMOTE_R4), (3, REMOTE_R4), (8, REMOTE_R3),
         (4, COLOCATED),
     ])
     def test_other_fleet_shapes(self, groups, link):
@@ -84,27 +84,27 @@ class TestChooserVsSimulation:
 class TestMeasuredFacts:
     """The r4 sweep's qualitative facts must hold in the model."""
 
-    def test_two_shards_beat_one_on_degraded_tunnel(self):
+    def test_two_shards_beat_one_on_degraded_link(self):
         one = predicted_fused_fps(
-            1, GROUPS, GROUP_SIZE, FRAME_BYTES, TUNNEL_R4)
+            1, GROUPS, GROUP_SIZE, FRAME_BYTES, REMOTE_R4)
         two = predicted_fused_fps(
-            2, GROUPS, GROUP_SIZE, FRAME_BYTES, TUNNEL_R4)
+            2, GROUPS, GROUP_SIZE, FRAME_BYTES, REMOTE_R4)
         assert two > 1.1 * one
 
     def test_three_shards_do_not_beat_two(self):
         two = predicted_fused_fps(
-            2, GROUPS, GROUP_SIZE, FRAME_BYTES, TUNNEL_R4)
+            2, GROUPS, GROUP_SIZE, FRAME_BYTES, REMOTE_R4)
         three = predicted_fused_fps(
-            3, GROUPS, GROUP_SIZE, FRAME_BYTES, TUNNEL_R4)
+            3, GROUPS, GROUP_SIZE, FRAME_BYTES, REMOTE_R4)
         assert three <= two
 
     def test_colocated_picks_one_shard(self):
         assert choose_fused_shards(
             GROUPS, GROUP_SIZE, FRAME_BYTES, COLOCATED) == 1
 
-    def test_degraded_tunnel_picks_two(self):
+    def test_degraded_link_picks_two(self):
         assert choose_fused_shards(
-            GROUPS, GROUP_SIZE, FRAME_BYTES, TUNNEL_R4) == 2
+            GROUPS, GROUP_SIZE, FRAME_BYTES, REMOTE_R4) == 2
 
 
 class TestResolve:
@@ -124,9 +124,9 @@ class TestResolve:
     def test_auto_probes_and_chooses(self):
         shards, link = resolve_fused_shards(
             0, GROUPS, GROUP_SIZE, FRAME_BYTES,
-            probe=lambda device: TUNNEL_R4)
+            probe=lambda device: REMOTE_R4)
         assert shards == 2
-        assert link == TUNNEL_R4
+        assert link == REMOTE_R4
 
     def test_actor_pool_auto_resolves_from_probe(self, monkeypatch):
         """ActorPool(accum_fused, fused_shards=0) probes the link and
@@ -144,7 +144,7 @@ class TestResolve:
         probed = []
         monkeypatch.setattr(
             linktune, "probe_link",
-            lambda device=None, **kw: probed.append(1) or TUNNEL_R4)
+            lambda device=None, **kw: probed.append(1) or REMOTE_R4)
         # Pin the wiring, not the model (tiny test fleets are legitimately
         # RTT-bound -> 1 shard): force a 2-shard choice and check the
         # pool builds exactly that many lockstep drivers.

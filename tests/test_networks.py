@@ -3,8 +3,8 @@ map as the direct 8x8/stride-4 nn.Conv it can replace.
 
 The s2d form (models/networks.py _SpaceToDepthFirstConv) is an MXU
 layout experiment — measured SLOWER for this torso and off by default
-(the stem input needs no gradient; see the module docstring and
-BENCH_NOTES round-5 conv table) — but whenever it is enabled, any
+(the stem input needs no gradient; see the module docstring) — but
+whenever it is enabled, any
 numerical divergence beyond contraction-order noise would silently
 change the model.  Both forms share one parameter tree, so a single
 init drives both and checkpoints must be interchangeable both ways.

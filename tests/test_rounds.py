@@ -3,17 +3,17 @@ and longitudinal trajectory/scoreboard (ISSUE 14).
 
 Four suites, all tier-1 and jax-free on the module under test:
 
-- **golden parse/trajectory** over the repo's own committed
-  BENCH_r*.json / MULTICHIP_r*.json — r01's failed round, r02-r04's
-  wrapper formats, r05's TRUNCATED tail (regex-salvaged with zero
-  hand-editing of the committed JSON), the e2e 12.6k fps / 0.42x
-  headline, the conv0_gradw worst-kernel series, and the r05 learning
-  curve;
+- **golden parse/trajectory** over the synthetic five-round history
+  (tests/bench_history.py, the ``bench_history`` fixture) — r01's
+  failed round, r02-r04's wrapper formats, r05's TRUNCATED tail
+  (regex-salvaged with zero hand-editing of the artifact JSON), the
+  e2e 12.6k fps / 0.42x headline, the conv0_gradw worst-kernel series,
+  and the r05 learning curve;
 - **scoreboard** met/unmet/unmeasured unit tests against the encoded
   ROADMAP r06 targets;
-- **validate** over the committed artifacts (the CI tripwire: a future
-  truncated-tail commit fails fast) plus hermetic truncation/sidecar/
-  schema-violation cases in tmp dirs;
+- **validate** over that history (the tripwire: a truncated-tail
+  artifact without its sidecar fails fast) plus hermetic truncation/
+  sidecar/schema-violation cases in tmp dirs;
 - **round-runner stage isolation** against a stub bench: a hard-crashed
   suite and a hung suite both land as failed/timeout stage records
   while every other suite's numbers survive in a schema-valid artifact,
@@ -71,28 +71,28 @@ class TestSalvage:
         assert rounds.salvage_metrics(text) == {}
 
 
-# -- parse kinds over the committed artifacts -------------------------------
+# -- parse kinds over the five-round history --------------------------------
 
 
 class TestParseCommitted:
-    def test_every_round_discovered_in_numeric_order(self):
-        found = rounds.discover_artifacts(REPO_ROOT)
+    def test_every_round_discovered_in_numeric_order(self, bench_history):
+        found = rounds.discover_artifacts(bench_history)
         assert [number for number, _ in found] == [1, 2, 3, 4, 5]
         assert all(not path.endswith(rounds.SALVAGE_SUFFIX)
                    for _, path in found)
 
-    def test_kinds_across_schema_drift(self):
+    def test_kinds_across_schema_drift(self, bench_history):
         kinds = {}
-        for number, path in rounds.discover_artifacts(REPO_ROOT):
+        for number, path in rounds.discover_artifacts(bench_history):
             kinds[number] = rounds.parse_bench_artifact(path).kind
         assert kinds[1] == "wrapper_failed"
         assert kinds[2] == "wrapper_parsed"
         assert kinds[4] == "wrapper_parsed"
         assert kinds[5] == "wrapper_salvaged"
 
-    def test_r05_salvage_recovers_the_surviving_tail(self):
+    def test_r05_salvage_recovers_the_surviving_tail(self, bench_history):
         art = rounds.parse_bench_artifact(
-            os.path.join(REPO_ROOT, "BENCH_r05.json"))
+            os.path.join(bench_history, "BENCH_r05.json"))
         assert art.salvaged
         assert art.sidecar is not None
         assert art.metrics["e2e_env_frames_per_sec"] == 8613.0
@@ -105,8 +105,8 @@ class TestParseCommitted:
         assert "value" not in art.metrics
         assert "platform" not in art.metrics
 
-    def test_newest_artifact_is_r05(self):
-        art = rounds.newest_artifact(REPO_ROOT)
+    def test_newest_artifact_is_r05(self, bench_history):
+        art = rounds.newest_artifact(bench_history)
         assert art.name == "BENCH_r05.json"
         assert art.metrics  # salvaged, not empty
 
@@ -116,8 +116,8 @@ class TestParseCommitted:
 
 class TestTrajectoryGolden:
     @pytest.fixture(scope="class")
-    def trajectory(self):
-        return rounds.build_trajectory(REPO_ROOT)
+    def trajectory(self, bench_history):
+        return rounds.build_trajectory(bench_history)
 
     def test_all_rounds_present(self, trajectory):
         assert [r["round"] for r in trajectory["rounds"]] == [1, 2, 3, 4, 5]
@@ -184,10 +184,10 @@ class TestTrajectoryGolden:
         assert "150:10.94" in text        # the learning curve tail
         assert "acceptance scoreboard" in text
 
-    def test_report_cli_json_is_machine_readable(self):
+    def test_report_cli_json_is_machine_readable(self, bench_history):
         proc = subprocess.run(
             [sys.executable, "-m", "scalable_agent_tpu.obs.rounds",
-             "report", "--json", f"--bench_dir={REPO_ROOT}"],
+             "report", "--json", f"--bench_dir={bench_history}"],
             capture_output=True, text=True, timeout=60, cwd=REPO_ROOT)
         assert proc.returncode == 0, proc.stderr
         payload = json.loads(proc.stdout)
@@ -259,11 +259,11 @@ def _truncated_wrapper(**overrides):
 
 
 class TestValidate:
-    def test_committed_artifacts_pass(self):
-        """The CI tripwire (ISSUE 14 satellite): every artifact in the
-        repo validates — r05 only because its salvage sidecar is
-        committed and still matches a fresh salvage."""
-        result = rounds.validate_artifacts(REPO_ROOT)
+    def test_committed_artifacts_pass(self, bench_history):
+        """The tripwire (ISSUE 14 satellite): every artifact of the
+        five-round history validates — r05 only because its salvage
+        sidecar is present and still matches a fresh salvage."""
+        result = rounds.validate_artifacts(bench_history)
         assert result["ok"], result["errors"]
         statuses = {entry["name"]: entry["status"]
                     for entry in result["artifacts"]}
@@ -327,7 +327,7 @@ class TestValidate:
         assert any("MULTICHIP_r01" in error
                    for error in result["errors"])
 
-    def test_cli_exit_codes(self, tmp_path):
+    def test_cli_exit_codes(self, tmp_path, bench_history):
         (tmp_path / "BENCH_r07.json").write_text(
             json.dumps(_truncated_wrapper()))
         proc = subprocess.run(
@@ -337,7 +337,7 @@ class TestValidate:
         assert proc.returncode == 1
         proc = subprocess.run(
             [sys.executable, "-m", "scalable_agent_tpu.obs.rounds",
-             "validate", f"--bench_dir={REPO_ROOT}"],
+             "validate", f"--bench_dir={bench_history}"],
             capture_output=True, text=True, timeout=60, cwd=REPO_ROOT)
         assert proc.returncode == 0, proc.stdout + proc.stderr
 
@@ -559,18 +559,21 @@ class TestBenchCLI:
     def test_crash_injection_is_stage_isolated(self, tmp_path,
                                                monkeypatch, capsys):
         """--crash=<suite> poisons exactly that suite: its failure is
-        recorded, the sibling suite's numbers land, and the JSON-line
-        contract (stdout + --json_out) holds."""
-        monkeypatch.setattr(
-            bench, "_probe_backend",
-            lambda: ({"platform": "cpu", "kind": "cpu", "n": 1}, None))
+        recorded, the sibling suite's numbers land, the JSON-line
+        contract (stdout + --json_out) holds — and the exit code is
+        non-zero, because ``errors`` is not empty."""
+        # The chip requirement is the one thing stubbed: these two
+        # suites are jax-free, and bench.py refuses to start without a
+        # TPU (tests/test_chip_bringup.py pins that).
+        monkeypatch.setattr(bench, "_require_chip",
+                            lambda: ("cpu", "cpu", 1))
         context = tmp_path / "ctx.json"
         context.write_text('{"sec_per_update": 0.005}')
         json_out = tmp_path / "out.json"
         rc = bench.main([
             "--suites=bench_obs,bench_ledger", "--crash=bench_obs",
             f"--context={context}", f"--json_out={json_out}"])
-        assert rc == 0
+        assert rc == 1
         emitted = json.loads(json_out.read_text())
         assert any("bench_obs failed" in error
                    and "injected crash" in error
