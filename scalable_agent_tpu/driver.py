@@ -681,6 +681,48 @@ def _harvest_kernel_ledger(config: Config, lower_fn,
     return table
 
 
+# Scopes every fused step's text holds (runtime/ingraph.py): where a
+# compiled step's text lacks one, it was compiled from an older version
+# of the program.
+_STEP_SCOPES = ("rollout", "learner_update")
+
+
+def _write_op_scopes(trace_path: Optional[str], trainer, state, carry):
+    """After a ``--trace`` run of the fused loop: the table that gives
+    each instruction of the compiled step its scope path, beside the
+    run's trace (obs/kernels.write_op_scopes; the benchmark's scope
+    reader joins a device trace to it).  The running step's own
+    executable is at hand (nothing is traced or compiled again) —
+    unless the persistent cache handed back one compiled from an older
+    version of the program, whose text names that version's scopes:
+    then the step is compiled afresh for its names
+    (``InGraphTrainer.compile_step_afresh``), which is why this runs
+    last, after every deadline of the run is disarmed.  Never raises: it
+    is forensics, not the training path."""
+    from scalable_agent_tpu.obs import kernels as kernels_lib
+
+    if trace_path is None:
+        return
+    t0 = time.monotonic()
+    try:
+        text = trainer.train_step.lower(
+            state, carry, np.int32(0)).compile().as_text()
+        if not all(kernels_lib.holds_scope(text, scope)
+                   for scope in _STEP_SCOPES):
+            log.warning(
+                "op scopes: the compile cache's executable of the step "
+                "was compiled from an older version of the program "
+                "(same ops, other scope names); compiling the step "
+                "afresh for its names")
+            text = trainer.compile_step_afresh(state, carry).as_text()
+        path = kernels_lib.write_op_scopes(trace_path, text)
+    except Exception:
+        log.exception("op scopes: reading the compiled step failed")
+        return
+    log.info("op scopes written to %s in %.1fs", path,
+             time.monotonic() - t0)
+
+
 def _configure_live_mfu(ledger, lower_fn, num_devices: int,
                         updates_per_execution: int = 1):
     """Arm the ledger's live ``ledger/mfu`` gauge (obs/ledger.py).
@@ -723,6 +765,80 @@ def _configure_live_mfu(ledger, lower_fn, num_devices: int,
              flops, peak, num_devices)
 
 
+class _SetupStages:
+    """The run's set-up as contiguous ``setup/*`` stages, from
+    ``driver.main``'s first line to the first dispatch returning.
+
+    ``enter(name)`` ends the open stage and starts the next, so the
+    stages leave no gap: whatever runs before the next ``enter`` is the
+    open stage's.  Each stage's seconds are kept whether or not the run
+    is traced (``_write_mttr_breakdown`` reads ``setup/restore`` and
+    ``setup/first_dispatch`` from here); with ``--trace`` each is also
+    a span, ``cat="setup"``, and the compile spans of the programs it
+    traced, lowered and compiled nest inside it (obs/registry.py)."""
+
+    def __init__(self, t_entry_ns: int):
+        # setup/config (Config.from_argv) ran before --trace was known:
+        # it is recorded when it ends, which is now.
+        now = time.perf_counter_ns()
+        self.seconds: Dict[str, float] = {
+            "setup/config": (now - t_entry_ns) * 1e-9}
+        get_tracer().add_span("setup/config", "setup",
+                              t_entry_ns // 1000, now // 1000)
+        self._name: Optional[str] = None
+        self._t0_ns = now
+        self._span = None
+
+    @property
+    def open(self) -> Optional[str]:
+        return self._name
+
+    def enter(self, name: Optional[str]):
+        """End the open stage; start ``name`` (None: start nothing)."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        now = time.perf_counter_ns()
+        if self._name is not None:
+            self.seconds[self._name] = (
+                self.seconds.get(self._name, 0.0)
+                + (now - self._t0_ns) * 1e-9)
+        self._name, self._t0_ns = name, now
+        if name is not None:
+            self._span = get_tracer().span(name, cat="setup")
+            self._span.__enter__()
+
+    def done(self):
+        self.enter(None)
+
+
+def _open_timeline(config: Config, t_entry_ns: Optional[int]
+                   ) -> _SetupStages:
+    """Start the run's one timeline.  With ``--trace`` the span tracer
+    records from here (in memory: ``_attach_trace_file`` opens
+    ``trace.p<proc>.<pid>.json`` once the process index may be asked
+    for) and ``_teardown_observability`` closes it; the compile
+    listener is hooked before the first program compiles."""
+    if t_entry_ns is None:
+        t_entry_ns = time.perf_counter_ns()
+    if config.trace:
+        configure_tracer(None, deferred=True)
+    get_registry().install_jax_hooks()
+    return _SetupStages(t_entry_ns)
+
+
+def _attach_trace_file(config: Config):
+    if config.trace:
+        # Per-(process, pid) file names: N processes of one run share
+        # the logdir, and two runs pointed at the same logdir must not
+        # clobber each other's trace.  obs/aggregate.py merges them.
+        proc = jax.process_index()
+        get_tracer().attach(
+            os.path.join(config.logdir,
+                         f"trace.p{proc}.{os.getpid()}.json"),
+            process_index=proc)
+
+
 @dataclasses.dataclass
 class _ObsHandles:
     """Everything _setup_observability wires and _teardown unwinds."""
@@ -734,21 +850,14 @@ class _ObsHandles:
 
 
 def _setup_observability(config: Config, coordinator: bool) -> _ObsHandles:
-    """Wire the obs subsystem for one training run: the span tracer
-    (--trace -> <logdir>/trace.p<proc>.<pid>.json), JAX recompile/memory
+    """Wire the obs subsystem for one training run: JAX compile/memory
     hooks on the global registry, a per-process Prometheus snapshot file
     (the coordinator keeps the plain metrics.prom name), the flight
     recorder + crash handlers (SIGTERM/SIGINT, unhandled exceptions),
     the watchdog (--watchdog_timeout_s), and the optional live scrape
-    endpoint (--metrics_http_port)."""
+    endpoint (--metrics_http_port).  The span tracer is NOT made here:
+    it has been recording since ``_open_timeline``."""
     proc = jax.process_index()
-    if config.trace:
-        # Per-(process, pid) file names: N processes of one run share
-        # the logdir, and two runs pointed at the same logdir must not
-        # clobber each other's trace.  obs/aggregate.py merges them.
-        name = f"trace.p{proc}.{os.getpid()}.json"
-        configure_tracer(os.path.join(config.logdir, name),
-                         process_index=proc)
     registry = get_registry().install_jax_hooks()
     prom_name = "metrics.prom" if coordinator else f"metrics.p{proc}.prom"
     prom = PrometheusExporter(
@@ -895,6 +1004,8 @@ class _HealthPlane:
         self.window_stop_at = (updates
                                + self._config.health_window_updates)
         self.monitor.note_window_open(anomaly_id, trace_dir)
+        get_tracer().instant("health/window", cat="health",
+                             args={"id": anomaly_id, "state": "open"})
         log.info("health: auto-profile window %s open through update "
                  "%d (%s)", anomaly_id, self.window_stop_at, trace_dir)
         return True
@@ -912,6 +1023,8 @@ class _HealthPlane:
         except Exception:
             log.exception("health profile window failed to stop")
         get_tracer().set_annotate(False)
+        get_tracer().instant("health/window", cat="health",
+                             args={"id": anomaly_id, "state": "closed"})
         out_name = f"kernels.{anomaly_id}.json"
         table = _harvest_kernel_ledger(
             self._config, lower_fn,
@@ -935,6 +1048,9 @@ class _HealthPlane:
         if self.monitor is None:
             return
         if self.window_open:
+            get_tracer().instant(
+                "health/window", cat="health",
+                args={"id": self.window_id, "state": "closed"})
             self.window_id = self.window_dir = None
             self.window_stop_at = None
             try:
@@ -1037,19 +1153,23 @@ def _arm_faults(config: Config):
         process_id=max(0, config.distributed_process_id))
 
 
-def _write_mttr_breakdown(config: Config, restore_s: float,
-                          compile_s: float):
+def _write_mttr_breakdown(config: Config, stages: _SetupStages):
     """Publish this process's startup-cost segments for the elastic
     supervisor's MTTR decomposition (runtime/elastic.py reads the file
     at the recovery beacon and folds the segments into the epochs-log
-    ``mttr`` record).  Coordinator only; atomic replace."""
+    ``mttr`` record): the ``setup/restore`` stage, and the
+    ``setup/first_dispatch`` stage — the first dispatch blocks through
+    the step's compile, so its wall time is the compile segment.
+    Coordinator only; atomic replace."""
     if jax.process_index() != 0:
         return
     from scalable_agent_tpu.runtime.elastic import MTTR_BREAKDOWN_NAME
 
     payload = {"epoch": int(config.fleet_epoch),
-               "restore_s": round(restore_s, 3),
-               "compile_s": round(compile_s, 3),
+               "restore_s": round(
+                   stages.seconds.get("setup/restore", 0.0), 3),
+               "compile_s": round(
+                   stages.seconds.get("setup/first_dispatch", 0.0), 3),
                "t_unix": time.time()}
     path = os.path.join(config.logdir, MTTR_BREAKDOWN_NAME)
     tmp = f"{path}.tmp.{os.getpid()}"
@@ -1061,8 +1181,13 @@ def _write_mttr_breakdown(config: Config, restore_s: float,
         log.exception("mttr breakdown write failed (non-fatal)")
 
 
-def train(config: Config) -> Dict[str, float]:
+def train(config: Config,
+          t_entry_ns: Optional[int] = None) -> Dict[str, float]:
     """Train until total_environment_frames.  Returns final metrics.
+
+    ``t_entry_ns``: when the caller's work for this run began on the
+    ``time.perf_counter_ns`` clock (``main``'s first line) — where the
+    run's timeline starts; now when not given.
 
     Multi-host: run the SAME command on every host with
     --distributed_coordinator/--distributed_num_processes/
@@ -1071,25 +1196,41 @@ def train(config: Config) -> Dict[str, float]:
     learner update is one SPMD program over the global device mesh
     (parallel/distributed.py; role of the reference's learner+actor
     jobs, experiment.py:497-512)."""
+    stages = _open_timeline(config, t_entry_ns)
+    try:
+        if config.train_backend == "ingraph":
+            return train_ingraph(config, stages)
+        if config.train_backend != "host":
+            raise ValueError(
+                f"unknown train_backend {config.train_backend!r} "
+                f"(host | ingraph)")
+        return _train_host(config, stages)
+    finally:
+        # However it ended: close the stage a failed set-up left open,
+        # and the trace where a raise before the ``try`` that owns
+        # ``_teardown_observability`` left it open.
+        stages.done()
+        if config.trace:
+            configure_tracer(None)
+
+
+def _train_host(config: Config, stages: _SetupStages) -> Dict[str, float]:
+    """``train`` on the host backend (env workers -> actors -> learner)."""
     from scalable_agent_tpu.parallel.distributed import (
         initialize_distributed,
         is_coordinator,
     )
 
-    if config.train_backend == "ingraph":
-        return train_ingraph(config)
-    if config.train_backend != "host":
-        raise ValueError(
-            f"unknown train_backend {config.train_backend!r} "
-            f"(host | ingraph)")
-
+    stages.enter("setup/distributed_init")
     initialize_distributed(
         config.distributed_coordinator or None,
         config.distributed_num_processes or None,
         config.distributed_process_id
         if config.distributed_process_id >= 0 else None,
         init_timeout_s=config.coordinator_init_timeout_s)
+    _attach_trace_file(config)
 
+    stages.enter("setup/compile_cache")
     config = apply_env_overrides(config)
     if is_coordinator():
         config.save()
@@ -1104,6 +1245,7 @@ def train(config: Config) -> Dict[str, float]:
     # very first unroll); the try below owns teardown from this point
     # on, so a failure anywhere in construction still flushes/closes
     # the trace file and dumps the flight recorder.
+    stages.enter("setup/observability")
     obs_handles = _setup_observability(config, is_coordinator())
     registry, prom = obs_handles.registry, obs_handles.prom
     # Fleet fault domains (runtime/fleet.py): peer heartbeats over the
@@ -1142,6 +1284,7 @@ def train(config: Config) -> Dict[str, float]:
     health = _HealthPlane(config, backend="host")
     injector = get_fault_injector()
     try:
+        stages.enter("setup/probe_env")
         level_names = training_level_names(config)
         multi_task = len(level_names) > 1
         probe_config = (
@@ -1149,9 +1292,11 @@ def train(config: Config) -> Dict[str, float]:
             if multi_task else config)
         observation_spec, action_space, num_agents = probe_env(
             probe_config)
+        stages.enter("setup/build_agent")
         agent = build_agent(config, action_space,
                             observation_spec.frame.shape)
 
+        stages.enter("setup/build_learner")
         learner = build_training_learner(config, agent)
         # Device-resident replay (runtime/replay.py): every fresh
         # batch's packed upload also lands in the slab, and
@@ -1180,6 +1325,7 @@ def train(config: Config) -> Dict[str, float]:
         ckpt = CheckpointManager(config.logdir,
                                  config.checkpoint_interval_s,
                                  config.checkpoint_keep)
+        stages.enter("setup/trainer_init")
         example = zero_trajectory(config, observation_spec, agent)
         state = learner.init(jax.random.key(config.seed), example)
         if cpu_lockstep:
@@ -1187,7 +1333,7 @@ def train(config: Config) -> Dict[str, float]:
             # otherwise still be draining when restore()'s has_any
             # broadcast posts its own ops.
             jax.block_until_ready(state)
-        restore_t0 = time.monotonic()
+        stages.enter("setup/restore")
         restored = ckpt.restore(target=state)
         if restored is not None:
             start_updates, host_state = restored
@@ -1207,8 +1353,8 @@ def train(config: Config) -> Dict[str, float]:
                      start_updates, _host_scalar(state.env_frames))
         else:
             start_updates = 0
-        restore_s = time.monotonic() - restore_t0
 
+        stages.enter("setup/live_mfu")
         # Live MFU numerator: lower (don't compile) the update once at
         # the run's REAL [T+1, local_B] shape for its cost-analysis
         # FLOPs.  The denominator is this PROCESS'S share of the mesh
@@ -1225,6 +1371,7 @@ def train(config: Config) -> Dict[str, float]:
             max(1, learner.mesh.devices.size // jax.process_count()))
         del mfu_example
 
+        stages.enter("setup/env_groups")
         env_groups = make_env_groups(config, observation_spec.frame,
                                      num_agents=num_agents,
                                      level_names=level_names)
@@ -1259,6 +1406,7 @@ def train(config: Config) -> Dict[str, float]:
         # Device prefetch stage: stages the next batch while the current
         # update runs (the reference's StagingArea +1-step policy lag,
         # experiment.py:587-597).
+        stages.enter("setup/prefetch_start")
         staged: queue_lib.Queue = queue_lib.Queue(maxsize=1)
         prefetch_thread = start_prefetch(pool, learner, staged,
                                          prefetch_stop)
@@ -1334,7 +1482,6 @@ def train(config: Config) -> Dict[str, float]:
         # restore gets — so a tight --watchdog_timeout_s doesn't read
         # them as hangs.  The post-update touch re-arms.
         rejit_pending = True
-        first_dispatch_t0 = None
         while frames < config.total_environment_frames:
             if (config.profile_dir and not profiling
                     and not health.window_open
@@ -1374,7 +1521,7 @@ def train(config: Config) -> Dict[str, float]:
                 watchdog.suspend("learner")
                 rejit_pending = False
                 if updates == start_updates:
-                    first_dispatch_t0 = time.monotonic()
+                    stages.enter("setup/first_dispatch")
             with timing.time_avg("update"), interval.add_time("update"):
                 state, dispatched = learner.update(state, traj)
                 # Chaos: a deterministic mid-run slowdown (thermal
@@ -1396,15 +1543,11 @@ def train(config: Config) -> Dict[str, float]:
                 # and gloo mispairs anything that arrives alongside it.
                 jax.block_until_ready(state)
             watchdog.touch("learner")
-            if first_dispatch_t0 is not None:
-                # Startup-cost beacon for the supervisor's MTTR
-                # decomposition: the first dispatch blocks through the
-                # update's compile, so its wall time is the compile
-                # segment.
-                _write_mttr_breakdown(config, restore_s,
-                                      time.monotonic()
-                                      - first_dispatch_t0)
-                first_dispatch_t0 = None
+            if stages.open == "setup/first_dispatch":
+                # Set-up ends here; the startup-cost beacon for the
+                # supervisor's MTTR decomposition goes out with it.
+                stages.done()
+                _write_mttr_breakdown(config, stages)
             if audit_snap is not None:
                 # Shadow audit: recompute this batch's grads + param
                 # delta through the reference arm on device and compare
@@ -1531,133 +1674,146 @@ def train(config: Config) -> Dict[str, float]:
 
             now = time.monotonic()
             if now - last_log >= config.log_interval_s:
-                if not metrics:
-                    # Nothing has fallen out of the in-flight window
-                    # yet (the first W-1 updates): log the newest
-                    # dispatched update rather than an empty dict —
-                    # the log-time fetch below is the sync the seed
-                    # loop always paid here.
-                    metrics = dispatched
-                # The log-time fetches (host scalars here, the devtel/
-                # sentinel publishes below) drain the device queue —
-                # which, right after an audit or ladder demotion,
-                # carries the recovery path's compiles.  That wait is
-                # device backlog, not a wedged learner: disarm across
-                # the fetch section; the touch after ledger.publish
-                # re-arms.
-                watchdog.suspend("learner")
-                host_metrics = {k: _host_scalar(v)
-                                for k, v in metrics.items()}
-                # Only RECORD the verdict here: the log gate runs on
-                # local wall clocks, and acting inside it would let
-                # multi-host processes enter the collective restore on
-                # different iterations.  The rollback itself happens at
-                # the fixed per-iteration point below.
-                if nonfinite.observe(host_metrics):
-                    rollback_wanted = True
-                fps = (frames - frames_at_last_log) / (now - last_log)
-                host_metrics["fps"] = fps
-                stats = pool.episode_stats()
-                if stats:
-                    host_metrics["episode_return"] = float(
-                        np.mean([r for r, _ in stats]))
-                    host_metrics["episode_frames"] = float(
-                        np.mean([l for _, l in stats])
-                        * config.num_action_repeats)
-                # Per-level attribution (reference logs
-                # <level>/episode_return and /episode_frames per episode,
-                # experiment.py:634-650; interval means here).
-                for level, entries in pool.drain_level_stats().items():
-                    host_metrics[f"{level}/episode_return"] = float(
-                        np.mean([r for r, _ in entries]))
-                    host_metrics[f"{level}/episode_frames"] = float(
-                        np.mean([l for _, l in entries])
-                        * config.num_action_repeats)
-                    if multi_task:
-                        bare = (level[len("dmlab_"):]
-                                if level.startswith("dmlab_") else level)
-                        if bare in suite_returns:
-                            suite_returns[bare].extend(
-                                r for r, _ in entries)
-                if multi_task and suite_returns and min(
-                        len(v) for v in suite_returns.values()) >= 1:
-                    # Every level reported since the last score: emit the
-                    # capped/uncapped human-normalized TRAINING score and
-                    # clear (reference: experiment.py:652-667).
-                    host_metrics["dmlab30/training_no_cap"] = (
-                        dmlab30.compute_human_normalized_score(
-                            suite_returns, per_level_cap=None))
-                    host_metrics["dmlab30/training_cap_100"] = (
-                        dmlab30.compute_human_normalized_score(
-                            suite_returns, per_level_cap=100.0))
+                # The log-time fetches drain the device queue and the
+                # publishes run with nothing dispatched: the whole block
+                # and each part of it is a span (the fused loop's
+                # block, same names).
+                tracer = get_tracer()
+                with tracer.span("driver/log_publish", cat="log"):
+                    if not metrics:
+                        # Nothing has fallen out of the in-flight window
+                        # yet (the first W-1 updates): log the newest
+                        # dispatched update rather than an empty dict —
+                        # the log-time fetch below is the sync the seed
+                        # loop always paid here.
+                        metrics = dispatched
+                    # The log-time fetches (host scalars here, the devtel/
+                    # sentinel publishes below) drain the device queue —
+                    # which, right after an audit or ladder demotion,
+                    # carries the recovery path's compiles.  That wait is
+                    # device backlog, not a wedged learner: disarm across
+                    # the fetch section; the touch after ledger.publish
+                    # re-arms.
+                    watchdog.suspend("learner")
+                    with tracer.span("log/fetch_metrics", cat="log"):
+                        host_metrics = {k: _host_scalar(v)
+                                        for k, v in metrics.items()}
+                    # Only RECORD the verdict here: the log gate runs on
+                    # local wall clocks, and acting inside it would let
+                    # multi-host processes enter the collective restore on
+                    # different iterations.  The rollback itself happens at
+                    # the fixed per-iteration point below.
+                    if nonfinite.observe(host_metrics):
+                        rollback_wanted = True
+                    fps = (frames - frames_at_last_log) / (now - last_log)
+                    host_metrics["fps"] = fps
+                    stats = pool.episode_stats()
+                    if stats:
+                        host_metrics["episode_return"] = float(
+                            np.mean([r for r, _ in stats]))
+                        host_metrics["episode_frames"] = float(
+                            np.mean([l for _, l in stats])
+                            * config.num_action_repeats)
+                    # Per-level attribution (reference logs
+                    # <level>/episode_return and /episode_frames per episode,
+                    # experiment.py:634-650; interval means here).
+                    for level, entries in pool.drain_level_stats().items():
+                        host_metrics[f"{level}/episode_return"] = float(
+                            np.mean([r for r, _ in entries]))
+                        host_metrics[f"{level}/episode_frames"] = float(
+                            np.mean([l for _, l in entries])
+                            * config.num_action_repeats)
+                        if multi_task:
+                            bare = (level[len("dmlab_"):]
+                                    if level.startswith("dmlab_") else level)
+                            if bare in suite_returns:
+                                suite_returns[bare].extend(
+                                    r for r, _ in entries)
+                    if multi_task and suite_returns and min(
+                            len(v) for v in suite_returns.values()) >= 1:
+                        # Every level reported since the last score: emit the
+                        # capped/uncapped human-normalized TRAINING score and
+                        # clear (reference: experiment.py:652-667).
+                        host_metrics["dmlab30/training_no_cap"] = (
+                            dmlab30.compute_human_normalized_score(
+                                suite_returns, per_level_cap=None))
+                        host_metrics["dmlab30/training_cap_100"] = (
+                            dmlab30.compute_human_normalized_score(
+                                suite_returns, per_level_cap=100.0))
+                        log.info(
+                            "dmlab30 training score — no cap: %.2f cap 100: "
+                            "%.2f", host_metrics["dmlab30/training_no_cap"],
+                            host_metrics["dmlab30/training_cap_100"])
+                        suite_returns = {
+                            name: [] for name in dmlab30.TRAIN_LEVELS}
+                    # Separate actor-FPS vs learner-FPS: the learner's
+                    # consumption rate (`fps`) can hide an actor surplus or
+                    # deficit that the queue currently masks.
+                    actor_steps = actor_steps_counter.value
+                    actor_fps = ((actor_steps - actor_steps_at_last_log)
+                                 * config.num_action_repeats
+                                 / (now - last_log))
+                    actor_steps_at_last_log = actor_steps
+                    actor_fps_gauge.set(actor_fps)
+                    learner_fps_gauge.set(fps)
+                    host_metrics["actor_fps"] = actor_fps
+                    # Machine-readable timing snapshot (Timing.summary): the
+                    # same numbers as the log line, str-parse-free.
+                    timing_summary = timing.summary()
+                    host_metrics.update(
+                        {f"timing/{k}": v for k, v in timing_summary.items()})
+                    # Device telemetry: the ONE fetch the on-device
+                    # instruments ever cost (a few hundred bytes at log
+                    # cadence), folded into the registry as devtel/* so it
+                    # rides the writer/prom dumps below.
+                    with tracer.span("log/telemetry", cat="log"):
+                        learner.publish_device_telemetry()
+                        if sentinel is not None:
+                            sentinel.publish()
+                    # Ledger derivation BEFORE stall attribution, so the
+                    # verdict line carries this interval's dominant-stage
+                    # share (rates/ρ/staleness/MFU land in the registry and
+                    # ride the writer/prom dumps below).
+                    with tracer.span("log/ledger", cat="log"):
+                        ledger.publish()
+                    watchdog.touch("learner")
+                    # Stall attribution over THIS interval's stage sums.
+                    interval_summary = interval.summary()
+                    interval.clear()
+                    category, evidence = stall.attribute(
+                        interval_summary.get("wait_batch", 0.0),
+                        interval_summary.get("update", 0.0),
+                        retire_s=interval_summary.get("retire", 0.0))
+                    # Health detectors over the registry stream plus this
+                    # interval's host metrics, with the verdict and ledger
+                    # attribution captured at trip time; a fresh trip may
+                    # arm a profiling window, opened here (next update
+                    # onward profiles) unless the scheduled window is live.
+                    with tracer.span("log/health", cat="log"):
+                        if health.active:
+                            health.step(
+                                {**registry.snapshot(), **host_metrics},
+                                update=updates, verdict=category,
+                                evidence=evidence)
+                            if not profiling:
+                                health.maybe_open_window(updates)
+                    with tracer.span("log/write", cat="log"):
+                        if writer is not None:
+                            writer.write(updates, host_metrics)
+                            writer.write_registry(updates)
+                    with tracer.span("log/prom", cat="log"):
+                        if prom is not None:
+                            prom.dump()
                     log.info(
-                        "dmlab30 training score — no cap: %.2f cap 100: "
-                        "%.2f", host_metrics["dmlab30/training_no_cap"],
-                        host_metrics["dmlab30/training_cap_100"])
-                    suite_returns = {
-                        name: [] for name in dmlab30.TRAIN_LEVELS}
-                # Separate actor-FPS vs learner-FPS: the learner's
-                # consumption rate (`fps`) can hide an actor surplus or
-                # deficit that the queue currently masks.
-                actor_steps = actor_steps_counter.value
-                actor_fps = ((actor_steps - actor_steps_at_last_log)
-                             * config.num_action_repeats / (now - last_log))
-                actor_steps_at_last_log = actor_steps
-                actor_fps_gauge.set(actor_fps)
-                learner_fps_gauge.set(fps)
-                host_metrics["actor_fps"] = actor_fps
-                # Machine-readable timing snapshot (Timing.summary): the
-                # same numbers as the log line, str-parse-free.
-                timing_summary = timing.summary()
-                host_metrics.update(
-                    {f"timing/{k}": v for k, v in timing_summary.items()})
-                # Device telemetry: the ONE fetch the on-device
-                # instruments ever cost (a few hundred bytes at log
-                # cadence), folded into the registry as devtel/* so it
-                # rides the writer/prom dumps below.
-                learner.publish_device_telemetry()
-                if sentinel is not None:
-                    sentinel.publish()
-                # Ledger derivation BEFORE stall attribution, so the
-                # verdict line carries this interval's dominant-stage
-                # share (rates/ρ/staleness/MFU land in the registry and
-                # ride the writer/prom dumps below).
-                ledger.publish()
-                watchdog.touch("learner")
-                # Stall attribution over THIS interval's stage sums.
-                interval_summary = interval.summary()
-                interval.clear()
-                category, evidence = stall.attribute(
-                    interval_summary.get("wait_batch", 0.0),
-                    interval_summary.get("update", 0.0),
-                    retire_s=interval_summary.get("retire", 0.0))
-                # Health detectors over the registry stream plus this
-                # interval's host metrics, with the verdict and ledger
-                # attribution captured at trip time; a fresh trip may
-                # arm a profiling window, opened here (next update
-                # onward profiles) unless the scheduled window is live.
-                if health.active:
-                    health.step(
-                        {**registry.snapshot(), **host_metrics},
-                        update=updates, verdict=category,
-                        evidence=evidence)
-                    if not profiling:
-                        health.maybe_open_window(updates)
-                if writer is not None:
-                    writer.write(updates, host_metrics)
-                    writer.write_registry(updates)
-                if prom is not None:
-                    prom.dump()
-                log.info(
-                    "update %d frames %.3g fps %.0f (actors %.0f) "
-                    "loss %.3f return %s | %s | %s",
-                    updates, frames, fps, actor_fps,
-                    host_metrics.get("total_loss", float("nan")),
-                    f"{host_metrics.get('episode_return', float('nan')):.2f}",
-                    " ".join(f"{k} {v:.4f}s"
-                             for k, v in timing_summary.items()),
-                    StallAttributor.describe(category, evidence))
-                last_log, frames_at_last_log = now, frames
+                        "update %d frames %.3g fps %.0f (actors %.0f) "
+                        "loss %.3f return %s | %s | %s",
+                        updates, frames, fps, actor_fps,
+                        host_metrics.get("total_loss", float("nan")),
+                        f"{host_metrics.get('episode_return', float('nan')):.2f}",
+                        " ".join(f"{k} {v:.4f}s"
+                                 for k, v in timing_summary.items()),
+                        StallAttributor.describe(category, evidence))
+                    last_log, frames_at_last_log = now, frames
             # Rollback AND preemption decisions at a point EVERY
             # process reaches on the SAME iteration, with the
             # coordinator's verdict broadcast — the divergent-local-
@@ -2012,7 +2168,8 @@ def build_sentinel(config: Config, agent, learner, action_space,
 _INGRAPH_PENDING_CAP = 2048
 
 
-def train_ingraph(config: Config) -> Dict[str, float]:
+def train_ingraph(config: Config,
+                  stages: _SetupStages) -> Dict[str, float]:
     """Fused in-graph training: rollout + update as ONE jitted device
     program per dispatch (runtime/ingraph.py — K = updates_per_dispatch
     fused updates per launch), for levels whose simulator is
@@ -2023,11 +2180,15 @@ def train_ingraph(config: Config) -> Dict[str, float]:
     CheckpointManager — so `--train_backend=ingraph` is a drop-in flag.
     (Replaces the whole host actor pipeline the reference is built
     around, experiment.py:479-672, with zero per-step host↔device
-    traffic.)
+    traffic.)  Reached through ``train``, which owns the timeline
+    ``stages`` belongs to.
     """
     from scalable_agent_tpu.envs.device import make_device_env
     from scalable_agent_tpu.runtime import InGraphTrainer
 
+    # The first stage after setup/config also pays the backend's
+    # start-up where this process has not touched jax yet.
+    stages.enter("setup/compile_cache")
     # This dispatch runs BEFORE jax.distributed would initialize, so
     # check the config flags too — process_count() alone is still 1
     # here even when the user asked for a distributed run, and silently
@@ -2052,6 +2213,7 @@ def train_ingraph(config: Config) -> Dict[str, float]:
             "sentinel_interval > 0 requires --updates_per_dispatch=1: "
             "the shadow audit snapshots state at update granularity "
             "(runtime/sentinel.py)")
+    _attach_trace_file(config)  # single-process: the index is 0
     config = apply_env_overrides(config)
     config.save()
     setup_compile_cache()
@@ -2062,9 +2224,12 @@ def train_ingraph(config: Config) -> Dict[str, float]:
     # is the mirrored envs/fake.py implementation; for device-native
     # levels (device_*) it is the HostDeviceEnv adapter driving the
     # same transition function, so agreement is by construction.
+    stages.enter("setup/probe_env")
     observation_spec, action_space, _ = probe_env(config)
+    stages.enter("setup/build_agent")
     agent = build_agent(config, action_space,
                         observation_spec.frame.shape)
+    stages.enter("setup/device_env")
     env = make_device_env(
         config.level_name, height=config.height, width=config.width,
         # Composite spaces have no .n; make_device_env rejects their
@@ -2080,6 +2245,7 @@ def train_ingraph(config: Config) -> Dict[str, float]:
             f"!= device mirror {device_frame} (envs/fake.py and "
             f"envs/device/ must stay in lock-step)")
 
+    stages.enter("setup/build_learner")
     learner = build_training_learner(config, agent)
     # The sentinel's shadow audit consumes the dispatch's emitted
     # trajectory, so arming it turns emission on like replay does.
@@ -2101,11 +2267,12 @@ def train_ingraph(config: Config) -> Dict[str, float]:
 
         replay = DeviceReplayBuffer(config.replay_capacity,
                                     seed=config.seed)
+    stages.enter("setup/trainer_init")
     state, carry = trainer.init(jax.random.key(config.seed))
 
+    stages.enter("setup/restore")
     ckpt = CheckpointManager(config.logdir, config.checkpoint_interval_s,
                              config.checkpoint_keep)
-    restore_t0 = time.monotonic()
     restored = ckpt.restore(target=state)
     if restored is not None:
         start_updates, host_state = restored
@@ -2120,8 +2287,8 @@ def train_ingraph(config: Config) -> Dict[str, float]:
                  start_updates, _host_scalar(state.env_frames))
     else:
         start_updates = 0
-    restore_s = time.monotonic() - restore_t0
 
+    stages.enter("setup/observability")
     timing = Timing()
     updates = start_updates
     # One dispatch = K fused updates (the megaloop): the host loop's
@@ -2135,7 +2302,8 @@ def train_ingraph(config: Config) -> Dict[str, float]:
     frames_at_last_log = frames
     metrics = {}
     # Setup immediately before the try that owns teardown: nothing can
-    # raise in between, so the trace file can't leak.
+    # raise in between, so its hooks can't leak (the trace file is
+    # train()'s to close either way).
     obs_handles = _setup_observability(config, coordinator=True)
     registry, prom = obs_handles.registry, obs_handles.prom
     # Single-process fleet: only the preemption-grace protocol arms
@@ -2165,11 +2333,16 @@ def train_ingraph(config: Config) -> Dict[str, float]:
         frames_per_trajectory=frames_per_dispatch,
         logdir=config.logdir,
         process_index=0)
+    stages.enter("setup/live_mfu")
     _configure_live_mfu(
         ledger,
         lambda: trainer.train_step.lower(state, carry, np.int32(0)),
         learner.mesh.devices.size,
         updates_per_execution=updates_per_dispatch)
+    # What is left before the loop (the health plane, the metrics
+    # writer and its TensorBoard import) is a stage of its own, not the
+    # gauge's.
+    stages.enter("setup/loop_entry")
     profiling = False
     profile_stop_at = None
     if restored is not None:
@@ -2195,7 +2368,6 @@ def train_ingraph(config: Config) -> Dict[str, float]:
             # first dispatch and the post-demotion trainer re-jit run
             # with the learner heartbeat suspended.
             rejit_pending = True
-            first_dispatch_t0 = None
             while frames < config.total_environment_frames:
                 if (config.profile_dir and not profiling
                         and not health.window_open
@@ -2218,10 +2390,12 @@ def train_ingraph(config: Config) -> Dict[str, float]:
                     watchdog.suspend("learner")
                     rejit_pending = False
                     if updates == start_updates:
-                        first_dispatch_t0 = time.monotonic()
+                        stages.enter("setup/first_dispatch")
+                tracer = get_tracer()
                 with timing.time_avg("update"), \
-                        get_tracer().span("learner/train_step",
-                                          cat="learner"):
+                        tracer.span("learner/train_step", cat="learner",
+                                    args=({"update": updates}
+                                          if tracer.enabled else None)):
                     # The update counter keys the rollout rng
                     # (jax.random.fold_in), so resume continues the exact
                     # action-sampling stream the interrupted run would
@@ -2243,14 +2417,12 @@ def train_ingraph(config: Config) -> Dict[str, float]:
                                                np.int32(updates)))
                 ledger.stamp(ledger_tid, "dispatch")
                 pending_tids.append(ledger_tid)
-                if first_dispatch_t0 is not None:
-                    # Startup-cost beacon for the supervisor's MTTR
-                    # decomposition (the first dispatch blocks through
-                    # the megaloop's compile).
-                    _write_mttr_breakdown(config, restore_s,
-                                          time.monotonic()
-                                          - first_dispatch_t0)
-                    first_dispatch_t0 = None
+                if stages.open == "setup/first_dispatch":
+                    # Set-up ends here (the first dispatch blocks
+                    # through the megaloop's compile); the supervisor's
+                    # startup-cost beacon goes out with it.
+                    stages.done()
+                    _write_mttr_breakdown(config, stages)
                 # Chaos: the same deterministic mid-run slowdown as the
                 # host backend (occurrences count dispatches), timed as
                 # update work so the interval's fps sag is attributable.
@@ -2402,74 +2574,92 @@ def train_ingraph(config: Config) -> Dict[str, float]:
                                     * updates_per_dispatch))
                 now = time.monotonic()
                 if now - last_log >= config.log_interval_s:
-                    host_metrics = _finalize_ingraph_metrics(
-                        metrics, config)
-                    # The fetch above materialized the newest update;
-                    # the device stream is in-order, so every pending
-                    # dispatch has retired by now.
-                    for tid in pending_tids:
-                        ledger.close(tid, retired=True)
-                    pending_tids.clear()
-                    # Device telemetry (env episodes + learner update
-                    # instruments riding the donated carry): the one
-                    # obs fetch, folded into the registry for the prom
-                    # dump below.
-                    trainer.publish_telemetry(carry)
-                    if sentinel is not None:
-                        sentinel.publish()
-                    ledger.publish()
-                    if nonfinite.observe(host_metrics):
-                        state, updates, frames = _rollback_or_exit(
-                            config, ckpt, learner, state, nonfinite)
-                        # The rollout carry is env-side state, not
-                        # params — it rides through the rollback like
-                        # the host backend's env processes do.  The
-                        # in-graph streak peak and the replay slab are
-                        # the abandoned timeline's: reset both so
-                        # neither a stale peak nor stale-lineage
-                        # samples leak past the restore.
-                        if replay is not None:
-                            replay.flush()
-                        if carry.streak_peak is not None:
-                            carry = carry._replace(
-                                streak_peak=jnp.zeros((), jnp.float32))
-                        last_log = time.monotonic()
-                        frames_at_last_log = frames
-                        continue
-                    fps = (frames - frames_at_last_log) / (now - last_log)
-                    host_metrics["fps"] = fps
-                    registry.gauge(
-                        "learner/fps",
-                        "env frames consumed per second").set(fps)
-                    timing_summary = timing.summary()
-                    host_metrics.update({f"timing/{k}": v
-                                         for k, v in timing_summary.items()})
-                    # Run-health step rides the same cadence; no stall
-                    # attributor in the fused loop, so records carry
-                    # ledger attribution only (verdict=None).
-                    if health.active:
-                        health.step(
-                            {**registry.snapshot(), **host_metrics},
-                            update=updates)
-                        if not profiling:
-                            health.maybe_open_window(updates)
-                    writer.write(updates, host_metrics)
-                    # Registry snapshot rows (obs/ prefix): the per-
-                    # interval devtel/learn/* series obs.report's
-                    # staleness↔clipping join and obs.diagnose read —
-                    # the host backend has always written these.
-                    writer.write_registry(updates)
-                    if prom is not None:
-                        prom.dump()
-                    log.info(
-                        "update %d frames %.3g fps %.0f loss %.3f return "
-                        "%s | %s",
-                        updates, frames, fps,
-                        host_metrics.get("total_loss", float("nan")),
-                        f"{host_metrics.get('episode_return', float('nan')):.2f}",
-                        " ".join(f"{k} {v:.4f}s"
-                                 for k, v in timing_summary.items()))
-                    last_log, frames_at_last_log = now, frames
+                    with tracer.span("driver/log_publish", cat="log",
+                                     args=({"update": updates}
+                                           if tracer.enabled else None)):
+                        # The fetch drains the device queue: until the
+                        # next dispatch the chip idles, which is why the
+                        # whole block and each part of it is a span.
+                        with tracer.span("log/fetch_metrics", cat="log"):
+                            host_metrics = _finalize_ingraph_metrics(
+                                metrics, config)
+                            # The fetch above materialized the newest
+                            # update; the device stream is in-order, so
+                            # every pending dispatch has retired by now.
+                            for tid in pending_tids:
+                                ledger.close(tid, retired=True)
+                            pending_tids.clear()
+                        # Device telemetry (env episodes + learner
+                        # update instruments riding the donated carry):
+                        # the one obs fetch, folded into the registry
+                        # for the prom dump below.
+                        with tracer.span("log/telemetry", cat="log"):
+                            trainer.publish_telemetry(carry)
+                            if sentinel is not None:
+                                sentinel.publish()
+                        with tracer.span("log/ledger", cat="log"):
+                            ledger.publish()
+                        if nonfinite.observe(host_metrics):
+                            state, updates, frames = _rollback_or_exit(
+                                config, ckpt, learner, state, nonfinite)
+                            # The rollout carry is env-side state,
+                            # not params — it rides through the
+                            # rollback like the host backend's env
+                            # processes do.  The in-graph streak peak
+                            # and the replay slab are the abandoned
+                            # timeline's: reset both so neither a stale
+                            # peak nor stale-lineage samples leak past
+                            # the restore.
+                            if replay is not None:
+                                replay.flush()
+                            if carry.streak_peak is not None:
+                                carry = carry._replace(
+                                    streak_peak=jnp.zeros(
+                                        (), jnp.float32))
+                            last_log = time.monotonic()
+                            frames_at_last_log = frames
+                            continue
+                        fps = ((frames - frames_at_last_log)
+                               / (now - last_log))
+                        host_metrics["fps"] = fps
+                        registry.gauge(
+                            "learner/fps",
+                            "env frames consumed per second").set(fps)
+                        timing_summary = timing.summary()
+                        host_metrics.update(
+                            {f"timing/{k}": v
+                             for k, v in timing_summary.items()})
+                        # Run-health step rides the same cadence; no
+                        # stall attributor in the fused loop, so records
+                        # carry ledger attribution only (verdict=None).
+                        with tracer.span("log/health", cat="log"):
+                            if health.active:
+                                health.step(
+                                    {**registry.snapshot(),
+                                     **host_metrics},
+                                    update=updates)
+                                if not profiling:
+                                    health.maybe_open_window(updates)
+                        with tracer.span("log/write", cat="log"):
+                            writer.write(updates, host_metrics)
+                            # Registry snapshot rows (obs/ prefix): the
+                            # per-interval devtel/learn/* series
+                            # obs.report's staleness↔clipping join and
+                            # obs.diagnose read — the host backend has
+                            # always written these.
+                            writer.write_registry(updates)
+                        with tracer.span("log/prom", cat="log"):
+                            if prom is not None:
+                                prom.dump()
+                        log.info(
+                            "update %d frames %.3g fps %.0f loss %.3f "
+                            "return %s | %s",
+                            updates, frames, fps,
+                            host_metrics.get("total_loss", float("nan")),
+                            f"{host_metrics.get('episode_return', float('nan')):.2f}",
+                            " ".join(f"{k} {v:.4f}s"
+                                     for k, v in timing_summary.items()))
+                        last_log, frames_at_last_log = now, frames
                 if sentinel is not None and updates % 8 == 0:
                     # Param fingerprint at the host backend's broadcast
                     # cadence.  Single-process, so there is no peer to
@@ -2504,6 +2694,7 @@ def train_ingraph(config: Config) -> Dict[str, float]:
                 pending_tids.clear()
             if ckpt.maybe_save(updates, state, force=True):
                 fleet.note_checkpoint(updates)
+        trace_path = get_tracer().path  # a clean end: see the finally
     finally:
         # Same verdict-first contract as train(): the membership
         # verdict must beat any teardown abort (no-op single-process).
@@ -2541,6 +2732,7 @@ def train_ingraph(config: Config) -> Dict[str, float]:
         ckpt.close()
         _teardown_observability(config, obs_handles)
         configure_fleet(None)  # after obs: covers the whole tail
+    _write_op_scopes(trace_path, trainer, state, carry)
     return _finalize_ingraph_metrics(metrics, config)
 
 
@@ -2801,6 +2993,7 @@ def main(argv: Optional[Sequence[str]] = None):
     final metrics, test's per-level returns — so a caller driving the
     CLI in-process (chip_smoke.py) reads the run's result, not only
     its exit."""
+    t_entry_ns = time.perf_counter_ns()  # where a run's timeline starts
     config = Config.from_argv(argv, description=__doc__)
     if config.mode == "train":
         if config.elastic:
@@ -2817,7 +3010,7 @@ def main(argv: Optional[Sequence[str]] = None):
             if code:
                 raise SystemExit(code)
             return None
-        return train(config)
+        return train(config, t_entry_ns)
     if config.mode == "test":
         return test(config)
     raise ValueError(f"unknown mode {config.mode!r}")

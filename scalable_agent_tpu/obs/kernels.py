@@ -54,15 +54,18 @@ __all__ = [
     "build_kernel_table",
     "find_profiler_traces",
     "harvest",
+    "holds_scope",
     "hlo_module_name",
     "last_dominant",
     "last_worst",
     "load_trace_kernel_events",
+    "op_scopes_path",
     "parse_hlo_kernel_costs",
     "primary_kernel_names",
     "publish_kernel_metrics",
     "scan_kernel_series",
     "write_kernels_json",
+    "write_op_scopes",
 ]
 
 _SCHEMA_VERSION = 2  # 2: + per-row "scope" and table "scope_time_shares"
@@ -375,6 +378,44 @@ def _scope_of(attrs: str) -> Optional[str]:
         if marker in op_name:
             return scope
     return None
+
+
+_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+
+
+def op_scopes_path(trace_path: str) -> str:
+    """``<logdir>/op_scopes.p<proc>.<pid>.json`` for a run's
+    ``<logdir>/trace.p<proc>.<pid>.json``: same directory, same
+    suffix."""
+    folder, name = os.path.split(trace_path)
+    return os.path.join(folder, "op_scopes." + name.split(".", 1)[-1])
+
+
+def holds_scope(hlo_text: str, scope: str) -> bool:
+    """Does some instruction's ``op_name`` hold ``scope`` as a whole
+    component of its path (wrapped by autodiff or not)?"""
+    return re.search(r'op_name="[^"]*[/(]%s[/)"]' % re.escape(scope),
+                     hlo_text) is not None
+
+
+def write_op_scopes(trace_path: str, hlo_text: str) -> str:
+    """Leave, beside a run's span trace, the table a device trace needs
+    to tell which layer an op belongs to: instruction name ->
+    ``op_name`` (the ``jax.named_scope`` / flax-module path) for every
+    instruction of the compiled step that carries one.  A v5e profiler
+    trace names each event by its instruction (``%fusion.238 = ...``)
+    and carries no ``op_name``; the join is on the instruction name.
+    Returns the path written."""
+    ops = {}
+    for line in hlo_text.splitlines():
+        scope = _OP_NAME_RE.search(line)
+        name = _INSTR_NAME_RE.match(line) if scope else None
+        if name:
+            ops[name.group(1)] = scope.group(1)
+    folder, name = os.path.split(op_scopes_path(trace_path))
+    return write_kernels_json(
+        folder, {"module": hlo_module_name(hlo_text), "ops": ops},
+        name=name)
 
 
 # -- trace ingestion ---------------------------------------------------------

@@ -20,10 +20,13 @@ Instruments are cheap and thread-safe:
 
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional, Union
 
 import numpy as np
+
+from scalable_agent_tpu.obs.trace import get_tracer
 
 __all__ = [
     "Counter",
@@ -228,29 +231,28 @@ class MetricsRegistry:
     # -- runtime hooks -----------------------------------------------------
 
     def install_jax_hooks(self) -> "MetricsRegistry":
-        """Register JAX recompilation counters/timers and device-memory
-        gauges on this registry.  Idempotent per registry; safe when the
+        """Register JAX compile counters and device-memory gauges on
+        this registry.  Idempotent per registry; safe when the
         monitoring API or memory_stats are unavailable (CPU backends
-        return None there — the gauges then read 0)."""
+        return None there — the gauges then read 0).
+
+        ``jax/compile_count`` / ``jax/compile_time_s`` count BACKEND
+        compiles (one per program XLA compiled or loaded from the
+        persistent cache; tracing and lowering are spans, not
+        compiles), ``jax/compile_cache_hits_total`` /
+        ``jax/compile_cache_misses_total`` tell the two apart."""
         if getattr(self, "_jax_hooks_installed", False):
             return self
         self._jax_hooks_installed = True
-        compiles = self.counter(
-            "jax/compile_count", "XLA compilations observed")
-        compile_time = self.counter(
-            "jax/compile_time_s", "cumulative XLA compile seconds")
-        try:
-            import jax.monitoring
-
-            def _on_duration(event: str, duration: float, **kwargs):
-                if "compile" in event:
-                    compiles.inc()
-                    compile_time.inc(max(0.0, duration))
-
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_duration)
-        except Exception:
-            pass
+        self.counter("jax/compile_count",
+                     "XLA backend compiles (or cache loads) observed")
+        self.counter("jax/compile_time_s",
+                     "cumulative XLA backend compile seconds")
+        self.counter("jax/compile_cache_hits_total",
+                     "programs loaded from the persistent compile cache")
+        self.counter("jax/compile_cache_misses_total",
+                     "programs the persistent compile cache did not hold")
+        _hook_jax_monitoring(self)
 
         def _memory_bytes() -> float:
             try:
@@ -264,6 +266,70 @@ class MetricsRegistry:
         self.gauge("device/memory_bytes_in_use",
                    "live HBM bytes on local device 0", fn=_memory_bytes)
         return self
+
+
+# -- JAX's monitoring events --------------------------------------------------
+# ONE pair of listeners per process, however many registries hook in:
+# JAX offers no way to take a listener back, and each of the three
+# compile events must become one span, not one per registry.
+
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+}
+_CACHE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "jax/compile_cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "jax/compile_cache_misses_total",
+}
+_jax_hooked: "weakref.WeakSet[MetricsRegistry]" = weakref.WeakSet()
+_jax_hook_lock = threading.Lock()
+_jax_listening = False
+
+
+def _on_compile_span(event: str, start_time: float, end_time: float,
+                     **kwargs):
+    """JAX's time-span listener: each compile event is a span on the
+    process tracer's clock (``args.fun_name`` names the program), under
+    whatever span the compiling thread has open; backend compiles also
+    count."""
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    if name == "compile/backend":
+        for registry in list(_jax_hooked):
+            registry.counter("jax/compile_count").inc()
+            registry.counter("jax/compile_time_s").inc(
+                max(0.0, end_time - start_time))
+    tracer = get_tracer()
+    if tracer.enabled:
+        tracer.add_wall_span(
+            name, "compile", start_time, end_time,
+            {"fun_name": str(kwargs.get("fun_name", ""))})
+
+
+def _on_cache_event(event: str, **kwargs):
+    name = _CACHE_COUNTERS.get(event)
+    if name is not None:
+        for registry in list(_jax_hooked):
+            registry.counter(name).inc()
+
+
+def _hook_jax_monitoring(registry: MetricsRegistry):
+    global _jax_listening
+    with _jax_hook_lock:
+        _jax_hooked.add(registry)
+        if _jax_listening:
+            return
+        try:
+            import jax.monitoring
+
+            jax.monitoring.register_event_time_span_listener(
+                _on_compile_span)
+            jax.monitoring.register_event_listener(_on_cache_event)
+        except Exception:  # no monitoring API: the counters read 0
+            return
+        _jax_listening = True
 
 
 # -- module-global registry --------------------------------------------------

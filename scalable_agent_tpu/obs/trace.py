@@ -24,18 +24,32 @@ File format: the first line is ``[`` and every event line ends with a
 comma — the Trace Event spec explicitly allows the unclosed array, which
 is what makes the file appendable/crash-safe AND loadable by Perfetto.
 ``load_trace_events`` parses it back for tests/tools.
+
+One timeline per run: the driver starts the tracer at ``driver.main``'s
+first line, before the process index is known (``deferred=True``: events
+are held in memory), and ``attach`` opens the file once it is.  Every
+span ("ph": "X") carries three fields beside the Chrome ones: ``sid``
+(its id), ``parent`` (the ``sid`` of the span open on the same thread
+when it started; absent at the top) and ``self`` (microseconds: its
+duration less the part its children cover).  ``add_wall_span`` places a
+span that something else timed on the wall clock (JAX's compile events)
+on the span clock through the tracer's ``trace_epoch`` pair.
+``last_trace_path()`` is how the same process finds a finished run's
+spans afterwards.
 """
 
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
     "Tracer",
     "configure_tracer",
     "get_tracer",
+    "last_trace_path",
     "load_trace_events",
     "span",
 ]
@@ -62,9 +76,45 @@ from scalable_agent_tpu.obs.flightrec import (  # noqa: E402
 )
 
 
+def _cover(covered: List[Tuple[int, int]], start: int, end: int) -> int:
+    """Add the child interval [start, end] to ``covered``, a span's
+    disjoint child intervals, and return how much of it earlier children
+    already covered.  Children are reported when they END, so ends only
+    grow and only the list's tail can touch the new interval: a span
+    reported after the spans nested in it (a compile event arrives after
+    the inner jits' events) swallows them here, and the return value is
+    what its own self time leaves out."""
+    inside, lo = 0, start
+    while covered and covered[-1][1] > start:
+        s, e = covered.pop()
+        inside += max(0, min(e, end) - max(s, start))
+        lo, end = min(lo, s), max(end, e)
+    covered.append((lo, end))
+    return inside
+
+
+def _cover_child(parent, start: int, end: int) -> int:
+    inside = _cover(parent._covered, start, end)
+    # A thread's root never closes: keep only the tail a late span can
+    # still touch.
+    if parent._sid is None and len(parent._covered) > 256:
+        del parent._covered[:128]
+    return inside
+
+
+class _Root:
+    """The bottom of a thread's span stack: no span is open."""
+
+    __slots__ = ("_sid", "_covered")
+
+    def __init__(self):
+        self._sid = None
+        self._covered: List[Tuple[int, int]] = []
+
+
 class _Span:
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_start_us",
-                 "_annotation")
+                 "_annotation", "_sid", "_parent", "_covered", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args):
         self._tracer = tracer
@@ -83,6 +133,11 @@ class _Span:
                 self._annotation.__enter__()
             except Exception:  # profiler unavailable: spans still record
                 tracer._annotate = False
+        self._stack = stack = tracer._stack()
+        self._sid = next(tracer._ids)
+        self._parent = stack[-1]
+        self._covered = []
+        stack.append(self)
         self._start_us = time.perf_counter_ns() // 1000
         return self
 
@@ -90,9 +145,17 @@ class _Span:
         end_us = time.perf_counter_ns() // 1000
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
+        stack = self._stack
+        if stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # closed out of order: keep the rest intact
+            stack.remove(self)
+        dur = end_us - self._start_us
+        _cover_child(self._parent, self._start_us, end_us)
         self._tracer._complete(
-            self._name, self._cat, self._start_us,
-            end_us - self._start_us, self._args)
+            self._name, self._cat, self._start_us, dur, self._args,
+            self._sid, self._parent._sid,
+            dur - sum(e - s for s, e in self._covered))
         return False
 
 
@@ -101,7 +164,12 @@ class Tracer:
 
     ``span(name)`` spans nest naturally: events on the same (pid, tid)
     track whose [ts, ts+dur] intervals contain each other render as a
-    stack in Perfetto — no explicit parent ids needed.
+    stack in Perfetto; readers that want the tree without sorting use
+    the ``sid``/``parent`` fields, kept from a per-thread stack.
+
+    ``deferred=True`` with no ``path`` records into memory until
+    ``attach(path, process_index)`` opens the file (a run's first spans
+    exist before ``jax.process_index()`` may be called).
     """
 
     def __init__(self, path: Optional[str] = None,
@@ -109,10 +177,13 @@ class Tracer:
                  annotate: bool = False,
                  flush_every_events: int = 8192,
                  max_events: int = 2_000_000,
-                 process_index: int = 0):
-        self.path = path
-        self.enabled = path is not None
+                 process_index: int = 0,
+                 deferred: bool = False):
+        self.path = None
+        self.enabled = path is not None or deferred
         self.process_index = process_index
+        self._process_name = process_name
+        self._deferred = deferred and path is None
         self._annotate = annotate and self.enabled
         self._flush_every = flush_every_events
         # Hard event budget (~100 bytes/event -> ~200 MB at the
@@ -127,27 +198,40 @@ class Tracer:
         self._file = None
         self._named_tids: Dict[int, str] = {}
         self._pid = os.getpid()
-        if self.enabled:
-            parent = os.path.dirname(os.path.abspath(path))
-            os.makedirs(parent, exist_ok=True)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        # Per-process clock epoch: a back-to-back (unix wall time,
+        # monotonic span clock) pair.  Event timestamps are
+        # process-local perf_counter microseconds; the aggregator
+        # (obs/aggregate.py) uses this record to shift every process's
+        # events onto one shared wall-clock timeline, and add_wall_span
+        # to place wall-clock-stamped spans on the span clock.
+        self._epoch_perf_us = time.perf_counter_ns() // 1000
+        self._epoch_unix_us = int(time.time() * 1e6)
+        if path is not None:
+            self.attach(path, process_index)
+
+    def attach(self, path: str, process_index: int = 0):
+        """Open the trace file and write what was held in memory."""
+        global _last_trace_path
+        parent = os.path.dirname(os.path.abspath(path))
+        os.makedirs(parent, exist_ok=True)
+        with self._lock:
+            self.path = path
+            self.process_index = process_index
             self._file = open(path, "w")
             self._file.write("[\n")
-            self._meta("process_name", {"name": process_name})
-            self._meta("process_sort_index",
-                       {"sort_index": process_index})
-            # Per-process clock epoch: a back-to-back (unix wall time,
-            # monotonic span clock) pair.  Event timestamps are
-            # process-local perf_counter microseconds; the aggregator
-            # (obs/aggregate.py) uses this record to shift every
-            # process's events onto one shared wall-clock timeline.
-            perf_us = time.perf_counter_ns() // 1000
-            unix_us = int(time.time() * 1e6)
-            self._push(json.dumps({
-                "name": "trace_epoch", "ph": "i", "s": "g", "cat": "meta",
-                "ts": perf_us, "pid": self._pid, "tid": 0,
-                "args": {"unix_time_us": unix_us,
-                         "perf_time_us": perf_us,
-                         "process_index": process_index}}))
+            self._deferred = False
+        _last_trace_path = path
+        self._meta("process_name", {"name": self._process_name})
+        self._meta("process_sort_index", {"sort_index": process_index})
+        self._push(json.dumps({
+            "name": "trace_epoch", "ph": "i", "s": "g", "cat": "meta",
+            "ts": self._epoch_perf_us, "pid": self._pid, "tid": 0,
+            "args": {"unix_time_us": self._epoch_unix_us,
+                     "perf_time_us": self._epoch_perf_us,
+                     "process_index": process_index}}))
+        self.flush()
 
     def set_annotate(self, flag: bool):
         """Toggle ``jax.profiler.TraceAnnotation`` wrapping.  An
@@ -165,6 +249,39 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
+
+    def add_span(self, name: str, cat: str, start_us: int, end_us: int,
+                 args: Optional[dict] = None):
+        """Record a span timed elsewhere, [start_us, end_us] on the span
+        clock, as a child of the span open on the calling thread.  Call
+        it when the span ends (``_cover`` relies on that order)."""
+        if not self.enabled:
+            return
+        parent = self._stack()[-1]
+        inside = _cover_child(parent, start_us, end_us)
+        dur = end_us - start_us
+        self._complete(name, cat, start_us, dur, args,
+                       next(self._ids), parent._sid, dur - inside)
+
+    def add_wall_span(self, name: str, cat: str, start_unix_s: float,
+                      end_unix_s: float, args: Optional[dict] = None):
+        """``add_span`` for a span stamped with ``time.time()`` (JAX's
+        compile events): moved onto the span clock through this
+        tracer's ``trace_epoch`` pair, and never past now."""
+        if not self.enabled:
+            return
+        shift = self._epoch_perf_us - self._epoch_unix_us
+        end_us = min(int(end_unix_s * 1e6) + shift,
+                     time.perf_counter_ns() // 1000)
+        dur = max(0, int((end_unix_s - start_unix_s) * 1e6))
+        self.add_span(name, cat, end_us - dur, end_us, args)
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = stack = [_Root()]
+            return stack
 
     def instant(self, name: str, cat: str = "pipeline",
                 args: Optional[dict] = None):
@@ -186,7 +303,7 @@ class Tracer:
             "pid": self._pid, "tid": 0,
             "args": {k: float(v) for k, v in values.items()}}))
 
-    def _complete(self, name, cat, ts, dur, args):
+    def _complete(self, name, cat, ts, dur, args, sid, parent, self_us):
         # Completed spans also enter the flight recorder's ring
         # (obs/flightrec.py) — on a crash the unflushed trace tail is
         # lost, but the ring's copy survives into flightrec.<pid>.json.
@@ -196,16 +313,22 @@ class Tracer:
         # rare quote/backslash falls back to the robust path).
         if '"' in name or "\\" in name or '"' in cat or "\\" in cat:
             event = {"name": name, "ph": "X", "cat": cat, "ts": ts,
-                     "dur": dur, "pid": self._pid, "tid": self._tid()}
+                     "dur": dur, "pid": self._pid, "tid": self._tid(),
+                     "sid": sid, "self": self_us}
+            if parent is not None:
+                event["parent"] = parent
             if args:
                 event["args"] = args
             self._push(json.dumps(event))
             return
         suffix = (", \"args\": %s}" % json.dumps(args)) if args else "}"
+        if parent is not None:
+            suffix = ', "parent": %d%s' % (parent, suffix)
         self._push(
             '{"name": "%s", "ph": "X", "cat": "%s", "ts": %d, '
-            '"dur": %d, "pid": %d, "tid": %d%s'
-            % (name, cat, ts, dur, self._pid, self._tid(), suffix))
+            '"dur": %d, "pid": %d, "tid": %d, "sid": %d, "self": %d%s'
+            % (name, cat, ts, dur, self._pid, self._tid(), sid, self_us,
+               suffix))
 
     def _tid(self) -> int:
         tid = threading.get_ident()
@@ -242,6 +365,8 @@ class Tracer:
     # -- lifecycle ---------------------------------------------------------
 
     def _flush_locked(self):
+        if self._deferred:
+            return  # held until attach() opens the file
         if self._file is None or not self._events:
             self._events.clear()
             return
@@ -255,6 +380,7 @@ class Tracer:
 
     def close(self):
         with self._lock:
+            self._deferred = False  # never attached: nothing to keep
             self._flush_locked()
             if self._file is not None:
                 self._file.close()
@@ -275,6 +401,17 @@ class Tracer:
 
 _tracer = Tracer(path=None)
 _tracer_lock = threading.Lock()
+_last_trace_path: Optional[str] = None
+
+
+def last_trace_path() -> Optional[str]:
+    """The file of the newest file-backed trace this process opened —
+    after ``driver.main`` returns, the finished run's timeline
+    (``load_trace_events`` reads it).  THE way for code in the same
+    process (the benchmark's readers) to find a run's spans; the
+    run's ``op_scopes.p<proc>.<pid>.json`` (obs/kernels.py) lies beside
+    it under the same suffix.  None before any traced run."""
+    return _last_trace_path
 
 
 def get_tracer() -> Tracer:
@@ -283,8 +420,9 @@ def get_tracer() -> Tracer:
 
 def configure_tracer(path: Optional[str], **kwargs) -> Tracer:
     """Install (and return) the process-global tracer.  ``path=None``
-    restores the disabled tracer; a previous file-backed tracer is
-    closed first so its tail is flushed."""
+    restores the disabled tracer (or, with ``deferred=True``, starts
+    one that records into memory until ``attach``); a previous
+    file-backed tracer is closed first so its tail is flushed."""
     global _tracer
     with _tracer_lock:
         old, _tracer = _tracer, Tracer(path=path, **kwargs)

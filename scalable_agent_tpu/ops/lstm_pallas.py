@@ -47,6 +47,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# The three Mosaic calls, by the name each carries twice: as its
+# ``pallas_call`` name and as the ``jax.named_scope`` it is bound
+# under.  The scope is what survives into the compiled instruction's
+# name, so a device trace shows ``pallas_lstm_fwd.<n>`` where all three
+# used to show the flax scope's ``core.<n>`` (the stem kernel does the
+# same, ops/conv_pallas.py GRADW_KERNEL_NAME).  Read by the profiler's
+# viewers and the benchmark's trace readers; obs/kernels.py matches
+# Pallas calls by their custom-call target, not by these names.
+FWD_KERNEL_NAME = "pallas_lstm_fwd"    # the update's residual-producing unroll
+STEP_KERNEL_NAME = "pallas_lstm_step"  # T=1 inference, the lean forward
+BWD_KERNEL_NAME = "pallas_lstm_bwd"
+
+
 def _mm(a, b, matmul_dtype):
     """MXU matmul at the configured operand precision, f32 accumulate."""
     return jnp.dot(a.astype(matmul_dtype), b.astype(matmul_dtype),
@@ -195,7 +208,7 @@ def _fwd_call(x, done, c0, h0, wi, wh, b, *, interpret, with_residuals,
     carry_spec, carry_shape = const(batch, hidden), jax.ShapeDtypeStruct(
         (batch, hidden), f32)
     if with_residuals:
-        kernel = _fwd_kernel
+        kernel, name = _fwd_kernel, FWD_KERNEL_NAME
         out_specs = (
             t_spec(batch, hidden),           # ys
             t_spec(batch, 4 * hidden),       # ifgo
@@ -209,10 +222,10 @@ def _fwd_call(x, done, c0, h0, wi, wh, b, *, interpret, with_residuals,
             tb(batch, hidden), tb(batch, 4 * hidden), tb(batch, hidden),
             tb(batch, hidden), tb(batch, hidden), carry_shape, carry_shape)
     else:
-        kernel = _fwd_kernel_lean
+        kernel, name = _fwd_kernel_lean, STEP_KERNEL_NAME
         out_specs = (t_spec(batch, hidden), carry_spec, carry_spec)
         out_shape = (tb(batch, hidden), carry_shape, carry_shape)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(kernel, matmul_dtype=matmul_dtype),
         grid=(unroll_len,),
         in_specs=[
@@ -231,7 +244,10 @@ def _fwd_call(x, done, c0, h0, wi, wh, b, *, interpret, with_residuals,
             pltpu.VMEM((batch, hidden), f32),
         ],
         interpret=interpret,
-    )(x, done[..., None], c0, h0, wi, wh, b.reshape(1, -1))
+        name=name,
+    )
+    with jax.named_scope(name):
+        return call(x, done[..., None], c0, h0, wi, wh, b.reshape(1, -1))
 
 
 def _bwd_call(residuals, cotangents, *, interpret,
@@ -244,7 +260,7 @@ def _bwd_call(residuals, cotangents, *, interpret,
     rev = lambda *shape: pl.BlockSpec(
         (1,) + shape, lambda k: (unroll_len - 1 - k,) + (0,) * len(shape))
     const = lambda *shape: pl.BlockSpec(shape, lambda k: (0,) * len(shape))
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_kernel, matmul_dtype=matmul_dtype),
         grid=(unroll_len,),
         in_specs=[
@@ -284,7 +300,11 @@ def _bwd_call(residuals, cotangents, *, interpret,
             pltpu.VMEM((1, 4 * hidden), f32),       # db accum
         ],
         interpret=interpret,
-    )(dys, x, done[..., None], ifgo, cpost, hpost, cnew, wi, wh, dct, dht)
+        name=BWD_KERNEL_NAME,
+    )
+    with jax.named_scope(BWD_KERNEL_NAME):
+        return call(dys, x, done[..., None], ifgo, cpost, hpost, cnew,
+                    wi, wh, dct, dht)
 
 
 def _resolve_matmul_dtype(matmul_dtype):

@@ -230,8 +230,9 @@ def from_importance_weights(
         # The diagnostics are elementwise reductions with no time
         # recurrence, so they need none of the sequence sharding —
         # compute them here and attach them to the delegated result.
-        return sharded._replace(diagnostics=importance_diagnostics(
-            log_rhos, clip_rho_threshold, clip_pg_rho_threshold))
+        with jax.named_scope("telemetry"):  # as below
+            return sharded._replace(diagnostics=importance_diagnostics(
+                log_rhos, clip_rho_threshold, clip_pg_rho_threshold))
     log_rhos = jnp.asarray(log_rhos, jnp.float32)
     discounts = jnp.asarray(discounts, jnp.float32)
     rewards = jnp.asarray(rewards, jnp.float32)
@@ -248,8 +249,11 @@ def from_importance_weights(
     if discounts.ndim != log_rhos.ndim or rewards.ndim != log_rhos.ndim:
         raise ValueError("discounts/rewards rank must match log_rhos rank")
 
-    diagnostics = importance_diagnostics(
-        log_rhos, clip_rho_threshold, clip_pg_rho_threshold)
+    # Only the obs plane reads these: their ops go under the scope the
+    # benchmark's scope reader files as telemetry (runtime/learner.py).
+    with jax.named_scope("telemetry"):
+        diagnostics = importance_diagnostics(
+            log_rhos, clip_rho_threshold, clip_pg_rho_threshold)
 
     if scan_impl == "pallas":
         # Fused single-kernel path (ops/vtrace_pallas.py).  The kernel is

@@ -39,6 +39,7 @@ from scalable_agent_tpu.obs.device_telemetry import (
     fetch_merged,
     merge_init,
 )
+from scalable_agent_tpu.obs.trace import get_tracer
 from scalable_agent_tpu.parallel.mesh import (
     batch_sharding,
     replicated_sharding,
@@ -163,6 +164,29 @@ class InGraphTrainer:
         self.replay_step = jax.jit(self._replay_step,
                                    donate_argnums=(0, 1))
 
+    def compile_step_afresh(self, state, carry):
+        """The fused step compiled from THIS program's text, metadata
+        and all.  The persistent compile cache's key leaves op metadata
+        out, so ``train_step`` may be running an executable that an
+        older version of the program compiled — same ops, other scope
+        names — and that executable's text then names the old scopes.
+        Here the key takes the metadata in and the function is traced
+        anew (a fresh wrapper: the jit's own caches would hand the old
+        executable back).  Costs a trace, a lowering and, the first time
+        for a version of the program, a compile; the result is not used
+        to run anything."""
+        def _fused(state, carry, counter):
+            return self._fused(state, carry, counter)
+
+        option = "jax_compilation_cache_include_metadata_in_key"
+        before = getattr(jax.config, option)
+        jax.config.update(option, True)
+        try:
+            return jax.jit(_fused, donate_argnums=(0, 1)).lower(
+                state, carry, np.int32(0)).compile()
+        finally:
+            jax.config.update(option, before)
+
     # -- initialization ----------------------------------------------------
 
     def init(self, rng: jax.Array) -> Tuple[object, TrainCarry]:
@@ -210,7 +234,8 @@ class InGraphTrainer:
                     lambda x: None if x is None else x[None],
                     agent_output, is_leaf=lambda x: x is None)),
         )
-        state = self._learner.init(rng, example)
+        with get_tracer().span("setup/learner_init", cat="setup"):
+            state = self._learner.init(rng, example)
         return state, carry
 
     # -- the fused program -------------------------------------------------
@@ -218,10 +243,15 @@ class InGraphTrainer:
     def _rollout(self, params, carry: RolloutCarry, rng):
         agent, env = self._agent, self._env
 
-        # The named scopes land in the compiled HLO's op_name metadata,
-        # which the kernel ledger (obs/kernels.py) reads to attribute
-        # device time env-vs-inference-vs-learner inside a
-        # device_bound verdict.
+        # The named scopes (here, ``telemetry`` and ``learner_update``
+        # below, and the learner's own) land in the compiled HLO's
+        # op_name metadata.  Two readers: the kernel ledger
+        # (obs/kernels.py) attributes device time env-vs-inference-vs-
+        # learner inside a device_bound verdict, and the benchmark's
+        # scope reader (benchmark/lib/scopes.py, through the table
+        # obs/kernels.write_op_scopes leaves beside a --trace run's
+        # trace) splits a step's device time by layer.  They are
+        # metadata: no op, fusion or number depends on them.
         def scan_fn(c, t):
             with jax.named_scope("actor_inference"):
                 out, core = actor_step(
@@ -232,8 +262,9 @@ class InGraphTrainer:
             return RolloutCarry(env_state, env_output, out, core), (
                 env_output, out)
 
-        new_carry, (env_seq, agent_seq) = jax.lax.scan(
-            scan_fn, carry, jnp.arange(self._unroll_length))
+        with jax.named_scope("rollout"):
+            new_carry, (env_seq, agent_seq) = jax.lax.scan(
+                scan_fn, carry, jnp.arange(self._unroll_length))
         trajectory = Trajectory(
             agent_state=carry.core_state,
             env_outputs=_stack_first(carry.env_output, env_seq),
@@ -280,8 +311,9 @@ class InGraphTrainer:
         emitted = jax.tree_util.tree_map(
             lambda t: None if t is None else t[1:],
             trajectory.env_outputs, is_leaf=lambda x: x is None)
-        telemetry = record_episode_telemetry(
-            self._env_tel_spec, telemetry, emitted)
+        with jax.named_scope("telemetry"):
+            telemetry = record_episode_telemetry(
+                self._env_tel_spec, telemetry, emitted)
         with jax.named_scope("learner_update"):
             new_state, telemetry, metrics = self._learner._update_impl(
                 state, trajectory, telemetry)

@@ -669,7 +669,8 @@ class Learner:
                 capture_intermediates=_torso_filter,
                 mutable=["intermediates"],
             )
-            return out, _dead_unit_fraction(captured)
+            with jax.named_scope("telemetry"):
+                return out, _dead_unit_fraction(captured)
         out, _ = self._agent.apply(
             params,
             trajectory.agent_outputs.action,
@@ -714,54 +715,60 @@ class Learner:
         else:
             comparison_logits, comparison_baselines = (
                 self._comparison_forward(params, trajectory))
-        # The last baseline is the bootstrap; then drop the last target
-        # output and the first behaviour/env entry (reference:
-        # experiment.py:368-375 — "use last baseline value for
-        # bootstrapping").
-        bootstrap_value = comparison_baselines[-1]
-        behaviour = jax.tree_util.tree_map(
-            lambda t: t[1:], trajectory.agent_outputs)
-        env_outputs = jax.tree_util.tree_map(
-            lambda t: t[1:], trajectory.env_outputs)
-        target_logits = target_logits[:-1]
-        baselines = baselines[:-1]
-        comparison_logits = comparison_logits[:-1]
-        comparison_baselines = comparison_baselines[:-1]
+        # ``vtrace_loss``: what the loss computes outside the flax
+        # modules (a scope name the benchmark's scope reader files
+        # under update.loss_heads; metadata only).
+        with jax.named_scope("vtrace_loss"):
+            # The last baseline is the bootstrap; then drop the last
+            # target output and the first behaviour/env entry
+            # (reference: experiment.py:368-375 — "use last baseline
+            # value for bootstrapping").
+            bootstrap_value = comparison_baselines[-1]
+            behaviour = jax.tree_util.tree_map(
+                lambda t: t[1:], trajectory.agent_outputs)
+            env_outputs = jax.tree_util.tree_map(
+                lambda t: t[1:], trajectory.env_outputs)
+            target_logits = target_logits[:-1]
+            baselines = baselines[:-1]
+            comparison_logits = comparison_logits[:-1]
+            comparison_baselines = comparison_baselines[:-1]
 
-        rewards = losses_lib.clip_rewards(
-            env_outputs.reward, hp.reward_clipping)
-        discounts = jnp.where(
-            env_outputs.done, 0.0, hp.discounting).astype(jnp.float32)
+            rewards = losses_lib.clip_rewards(
+                env_outputs.reward, hp.reward_clipping)
+            discounts = jnp.where(
+                env_outputs.done, 0.0, hp.discounting).astype(jnp.float32)
 
-        dist_spec = self._agent.dist_spec
-        # V-trace reads the COMPARISON quantities (identical tensors in
-        # the fused path; V-trace stop-gradients internally, so the
-        # unfused reference matches it bit-for-bit)...
-        vt = vtrace.from_logits(
-            behaviour_policy_logits=behaviour.policy_logits,
-            target_policy_logits=comparison_logits,
-            actions=behaviour.action,
-            discounts=discounts,
-            rewards=rewards,
-            values=comparison_baselines,
-            bootstrap_value=bootstrap_value,
-            clip_rho_threshold=hp.clip_rho_threshold,
-            clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
-            scan_impl=self._scan_impl,
-            dist_spec=dist_spec,
-            mesh=self._mesh if self._scan_impl == "time_sharded" else None,
-        )
+            dist_spec = self._agent.dist_spec
+            # V-trace reads the COMPARISON quantities (identical
+            # tensors in the fused path; V-trace stop-gradients
+            # internally, so the unfused reference matches it
+            # bit-for-bit)...
+            vt = vtrace.from_logits(
+                behaviour_policy_logits=behaviour.policy_logits,
+                target_policy_logits=comparison_logits,
+                actions=behaviour.action,
+                discounts=discounts,
+                rewards=rewards,
+                values=comparison_baselines,
+                bootstrap_value=bootstrap_value,
+                clip_rho_threshold=hp.clip_rho_threshold,
+                clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
+                scan_impl=self._scan_impl,
+                dist_spec=dist_spec,
+                mesh=(self._mesh if self._scan_impl == "time_sharded"
+                      else None),
+            )
 
-        # ...while the DIFFERENTIATED outputs feed the loss terms.
-        pg_loss = losses_lib.compute_policy_gradient_loss(
-            target_logits, behaviour.action, vt.pg_advantages,
-            dist_spec=dist_spec)
-        baseline_loss = losses_lib.compute_baseline_loss(
-            vt.vs - baselines)
-        entropy_loss = losses_lib.compute_entropy_loss(
-            target_logits, dist_spec=dist_spec)
-        total = (pg_loss + hp.baseline_cost * baseline_loss
-                 + hp.entropy_cost * entropy_loss)
+            # ...while the DIFFERENTIATED outputs feed the loss terms.
+            pg_loss = losses_lib.compute_policy_gradient_loss(
+                target_logits, behaviour.action, vt.pg_advantages,
+                dist_spec=dist_spec)
+            baseline_loss = losses_lib.compute_baseline_loss(
+                vt.vs - baselines)
+            entropy_loss = losses_lib.compute_entropy_loss(
+                target_logits, dist_spec=dist_spec)
+            total = (pg_loss + hp.baseline_cost * baseline_loss
+                     + hp.entropy_cost * entropy_loss)
         metrics = {
             "total_loss": total,
             "policy_gradient_loss": pg_loss,
@@ -796,48 +803,50 @@ class Learner:
         # tolerating arbitrarily stale behaviour data; the fused-
         # forward contract is about the ONLINE net only.
         (anchor_logits, _), _ = self._forward(target_params, trajectory)
-        bootstrap_value = comparison_baselines[-1]
-        behaviour = jax.tree_util.tree_map(
-            lambda t: t[1:], trajectory.agent_outputs)
-        env_outputs = jax.tree_util.tree_map(
-            lambda t: t[1:], trajectory.env_outputs)
-        online_logits = online_logits[:-1]
-        anchor_logits = anchor_logits[:-1]
-        baselines = baselines[:-1]
-        comparison_baselines = comparison_baselines[:-1]
+        with jax.named_scope("vtrace_loss"):  # as in _loss_vtrace
+            bootstrap_value = comparison_baselines[-1]
+            behaviour = jax.tree_util.tree_map(
+                lambda t: t[1:], trajectory.agent_outputs)
+            env_outputs = jax.tree_util.tree_map(
+                lambda t: t[1:], trajectory.env_outputs)
+            online_logits = online_logits[:-1]
+            anchor_logits = anchor_logits[:-1]
+            baselines = baselines[:-1]
+            comparison_baselines = comparison_baselines[:-1]
 
-        rewards = losses_lib.clip_rewards(
-            env_outputs.reward, hp.reward_clipping)
-        discounts = jnp.where(
-            env_outputs.done, 0.0, hp.discounting).astype(jnp.float32)
+            rewards = losses_lib.clip_rewards(
+                env_outputs.reward, hp.reward_clipping)
+            discounts = jnp.where(
+                env_outputs.done, 0.0, hp.discounting).astype(jnp.float32)
 
-        dist_spec = self._agent.dist_spec
-        vt = vtrace.from_logits(
-            behaviour_policy_logits=behaviour.policy_logits,
-            target_policy_logits=anchor_logits,
-            actions=behaviour.action,
-            discounts=discounts,
-            rewards=rewards,
-            values=comparison_baselines,
-            bootstrap_value=bootstrap_value,
-            clip_rho_threshold=hp.clip_rho_threshold,
-            clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
-            scan_impl=self._scan_impl,
-            dist_spec=dist_spec,
-            mesh=self._mesh if self._scan_impl == "time_sharded" else None,
-        )
+            dist_spec = self._agent.dist_spec
+            vt = vtrace.from_logits(
+                behaviour_policy_logits=behaviour.policy_logits,
+                target_policy_logits=anchor_logits,
+                actions=behaviour.action,
+                discounts=discounts,
+                rewards=rewards,
+                values=comparison_baselines,
+                bootstrap_value=bootstrap_value,
+                clip_rho_threshold=hp.clip_rho_threshold,
+                clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
+                scan_impl=self._scan_impl,
+                dist_spec=dist_spec,
+                mesh=(self._mesh if self._scan_impl == "time_sharded"
+                      else None),
+            )
 
-        surrogate = impact_lib.surrogate_from_logits(
-            online_logits, anchor_logits, behaviour.action,
-            vt.pg_advantages,
-            clip_epsilon=self._impact_clip_epsilon,
-            dist_spec=dist_spec)
-        baseline_loss = losses_lib.compute_baseline_loss(
-            vt.vs - baselines)
-        entropy_loss = losses_lib.compute_entropy_loss(
-            online_logits, dist_spec=dist_spec)
-        total = (surrogate.loss + hp.baseline_cost * baseline_loss
-                 + hp.entropy_cost * entropy_loss)
+            surrogate = impact_lib.surrogate_from_logits(
+                online_logits, anchor_logits, behaviour.action,
+                vt.pg_advantages,
+                clip_epsilon=self._impact_clip_epsilon,
+                dist_spec=dist_spec)
+            baseline_loss = losses_lib.compute_baseline_loss(
+                vt.vs - baselines)
+            entropy_loss = losses_lib.compute_entropy_loss(
+                online_logits, dist_spec=dist_spec)
+            total = (surrogate.loss + hp.baseline_cost * baseline_loss
+                     + hp.entropy_cost * entropy_loss)
         metrics = {
             "total_loss": total,
             "policy_gradient_loss": surrogate.loss,
@@ -865,17 +874,19 @@ class Learner:
         and its gradient are bit-identical with the plane on or off."""
         sg = jax.lax.stop_gradient
         diag = vt.diagnostics
-        online = sg(online_logits)
-        entropy = jnp.mean(distributions.entropy(online, dist_spec))
-        kl = jnp.mean(distributions.kl_divergence(
-            sg(behaviour_logits), online, dist_spec))
-        vs = sg(vt.vs)
-        explained_variance = 1.0 - (
-            jnp.var(vs - sg(baselines))
-            / jnp.maximum(jnp.var(vs), jnp.float32(1e-8)))
+        with jax.named_scope("telemetry"):
+            online = sg(online_logits)
+            entropy = jnp.mean(distributions.entropy(online, dist_spec))
+            kl = jnp.mean(distributions.kl_divergence(
+                sg(behaviour_logits), online, dist_spec))
+            vs = sg(vt.vs)
+            explained_variance = 1.0 - (
+                jnp.var(vs - sg(baselines))
+                / jnp.maximum(jnp.var(vs), jnp.float32(1e-8)))
+            entropy_frac = entropy / jnp.float32(self._max_entropy)
         return {
             "policy_entropy": entropy,
-            "entropy_frac": entropy / jnp.float32(self._max_entropy),
+            "entropy_frac": entropy_frac,
             "behaviour_kl": kl,
             "explained_variance": explained_variance,
             "rho_clip_fraction": diag.rho_clip_fraction,
@@ -907,14 +918,20 @@ class Learner:
         lr = self._hp.learning_rate * jnp.maximum(
             0.0, 1.0 - frames / self._hp.total_environment_frames)
 
-        updates, opt_state = self._tx.update(
-            grads, state.opt_state, state.params)
-        updates = jax.tree_util.tree_map(lambda u: u * lr, updates)
-        params = optax.apply_updates(state.params, updates)
+        # Scope names for the benchmark's scope reader (metadata only):
+        # ``optimizer`` is the step the update takes, the finite guard's
+        # select included; ``telemetry`` is whatever the step computes
+        # that only the obs plane reads.
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self._tx.update(
+                grads, state.opt_state, state.params)
+            updates = jax.tree_util.tree_map(lambda u: u * lr, updates)
+            params = optax.apply_updates(state.params, updates)
 
         metrics = dict(metrics)
         metrics["learning_rate"] = lr
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("telemetry"):
+            metrics["grad_norm"] = optax.global_norm(grads)
 
         skips, streak = state.nonfinite_skips, state.nonfinite_streak
         if self._finite_guard:
@@ -924,17 +941,18 @@ class Learner:
             # env_frames still advances — the batch WAS consumed, and
             # the driver's host-side frame accounting increments
             # unconditionally, so the two counts stay exact).
-            finite = jnp.isfinite(metrics["total_loss"])
-            for leaf in jax.tree_util.tree_leaves(grads):
-                finite = jnp.logical_and(
-                    finite, jnp.all(jnp.isfinite(leaf)))
+            with jax.named_scope("optimizer"):
+                finite = jnp.isfinite(metrics["total_loss"])
+                for leaf in jax.tree_util.tree_leaves(grads):
+                    finite = jnp.logical_and(
+                        finite, jnp.all(jnp.isfinite(leaf)))
 
-            def keep(new, old):
-                return jnp.where(finite, new, old)
+                def keep(new, old):
+                    return jnp.where(finite, new, old)
 
-            params = jax.tree_util.tree_map(keep, params, state.params)
-            opt_state = jax.tree_util.tree_map(
-                keep, opt_state, state.opt_state)
+                params = jax.tree_util.tree_map(keep, params, state.params)
+                opt_state = jax.tree_util.tree_map(
+                    keep, opt_state, state.opt_state)
             skipped = 1.0 - finite.astype(jnp.float32)
             skips = skips + skipped
             streak = jnp.where(finite, 0.0, streak + 1.0)
@@ -975,6 +993,15 @@ class Learner:
             target_params=target_params,
         )
         metrics["env_frames"] = new_state.env_frames
+        with jax.named_scope("telemetry"):
+            devtel = self._record_update_telemetry(
+                devtel, metrics, grads, updates, params)
+        return new_state, devtel, metrics
+
+    def _record_update_telemetry(self, devtel, metrics, grads, updates,
+                                 params):
+        """What the update leaves in the donated devtel pytree: the
+        update counters and the learning-dynamics plane."""
         if self._devtel_enabled:
             # Device telemetry: the same zero-host-sync contract as the
             # non-finite counters — a few scalar adds and one bucketed
@@ -995,7 +1022,7 @@ class Learner:
         if self._learn_enabled:
             devtel = self._accumulate_learning_telemetry(
                 devtel, metrics, grads, updates, params)
-        return new_state, devtel, metrics
+        return devtel
 
     def _accumulate_learning_telemetry(self, devtel, metrics, grads,
                                        updates, params):
