@@ -81,7 +81,7 @@ class Config:
     # f32 params' precision.  Explicit values decouple the two.
     core_matmul_dtype: str = "auto"
     # Stem-conv grad-W lowering: auto | xla | pallas.  "pallas" swaps
-    # ONLY the stem's weight gradient for the im2col MXU kernel
+    # ONLY the stem's weight gradient for the Pallas MXU kernel
     # (ops/conv_pallas.py) — the named worst kernel in the roofline
     # ledger (conv0_gradw, 0.107 MFU).  "auto" follows core_impl's
     # rule — pallas on a single-device TPU mesh, xla elsewhere — and

@@ -226,16 +226,44 @@ def resolve_core_impl(config: Config) -> str:
         num_devices=_intended_mesh_size(config)) else "xla")
 
 
+def stem_gradw_tile(config: Config, frame_shape=None):
+    """What the Pallas grad-W kernel would do with this run's stem:
+    ``(images per grid step or 0, images its last step masks, the
+    geometry in words)`` from ops/conv_pallas.gradw_batch_tile at the
+    merged batch, frame size and dtype.  ``frame_shape`` is the probed
+    (H, W, C); the config's height/width x 3 when omitted."""
+    from scalable_agent_tpu.models.networks import STEM_GEOMETRY
+    from scalable_agent_tpu.ops.conv_pallas import (
+        gradw_batch_tile,
+        gradw_padded_images,
+    )
+
+    if config.torso_type not in STEM_GEOMETRY:
+        raise ValueError(
+            f"torso_type must be one of {sorted(STEM_GEOMETRY)}, got "
+            f"{config.torso_type!r}")
+    features, kernel_size, stride = STEM_GEOMETRY[config.torso_type]
+    height, width, channels = (
+        frame_shape or (config.height, config.width, 3))
+    dtype = jnp.dtype(config.compute_dtype)
+    images = (config.unroll_length + 1) * config.batch_size
+    tile = gradw_batch_tile((images, height, width, channels), features,
+                            kernel_size, stride, dtype)
+    geometry = (f"the {config.torso_type} stem ({kernel_size}x"
+                f"{kernel_size}/stride-{stride}) over {height}x{width}x"
+                f"{channels} {dtype.name} frames")
+    return tile, gradw_padded_images(images, tile), geometry
+
+
 def resolve_conv_backend(config: Config, frame_shape=None) -> str:
     """"auto" follows the SAME mesh-size rule as ``core_impl``
     (fused_kernels_profitable: Pallas only on a single-device TPU mesh
     — a multi-device mesh cannot lower a ``pallas_call`` at all), and
     then only for a stem the grad-W kernel takes at this run's frame
-    size and dtype (ops/conv_pallas.gradw_batch_tile — the ResNet
-    3x3/stride-1 stem at 72x96 does not fit VMEM).  An explicit
-    ``pallas`` the kernel cannot take is refused here, at config time,
-    instead of dying inside Mosaic.  ``frame_shape`` is the probed
-    (H, W, C); the config's height/width x 3 when omitted."""
+    size and dtype (``stem_gradw_tile`` — the ResNet 3x3/stride-1 stem
+    at 72x96 does not fit VMEM).  An explicit ``pallas`` the kernel
+    cannot take is refused here, at config time, instead of dying
+    inside Mosaic."""
     if config.conv_backend not in ("auto",) + CONV_BACKENDS:
         raise ValueError(
             f"conv_backend must be auto or one of {CONV_BACKENDS}, "
@@ -247,24 +275,9 @@ def resolve_conv_backend(config: Config, frame_shape=None) -> str:
     if (config.conv_backend == "auto" and not fused_kernels_profitable(
             num_devices=_intended_mesh_size(config))):
         return "xla"
-    from scalable_agent_tpu.models.networks import STEM_GEOMETRY
-    from scalable_agent_tpu.ops.conv_pallas import gradw_batch_tile
-
-    if config.torso_type not in STEM_GEOMETRY:
-        raise ValueError(
-            f"torso_type must be one of {sorted(STEM_GEOMETRY)}, got "
-            f"{config.torso_type!r}")
-    features, kernel_size, stride = STEM_GEOMETRY[config.torso_type]
-    height, width, channels = (
-        frame_shape or (config.height, config.width, 3))
-    dtype = jnp.dtype(config.compute_dtype)
-    images = (config.unroll_length + 1) * config.batch_size
-    if gradw_batch_tile((images, height, width, channels), features,
-                        kernel_size, stride, dtype):
+    tile, _, geometry = stem_gradw_tile(config, frame_shape)
+    if tile:
         return "pallas"
-    geometry = (f"the {config.torso_type} stem ({kernel_size}x"
-                f"{kernel_size}/stride-{stride}) over {height}x{width}x"
-                f"{channels} {dtype.name} frames")
     if config.conv_backend == "pallas":
         raise ValueError(
             f"conv_backend=pallas: the grad-W kernel does not take "
@@ -325,12 +338,26 @@ def build_agent(config: Config, action_space,
     remat_torso = resolve_remat_torso(config)
     from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
+    # The grad-W kernel's tile and what its last step masks are
+    # decided at trace time: gauges, set once, beside the policy.
+    tile, padded = (stem_gradw_tile(config, frame_shape)[:2]
+                    if conv_backend == "pallas" else (0, 0))
+    registry = get_registry()
+    registry.gauge(
+        "conv0_gradw/batch_tile",
+        "images per grid step of the Pallas stem grad-W kernel "
+        "(0: the stem is XLA's)").set(tile)
+    registry.gauge(
+        "conv0_gradw/padded_images",
+        "images past N its last grid step masks (none is ever padded "
+        "in HBM)").set(padded)
     log.info(
         "kernel policy: backend=%s mesh_devices=%d core_impl=%s "
-        "(matmul %s) conv_backend=%s remat_torso=%s compute_dtype=%s "
+        "(matmul %s) conv_backend=%s (conv0_gradw batch_tile=%d "
+        "padded_images=%d) remat_torso=%s compute_dtype=%s "
         "pallas_interpret=%s",
         jax.default_backend(), _intended_mesh_size(config), core_impl,
-        core_matmul_dtype, conv_backend, remat_torso,
+        core_matmul_dtype, conv_backend, tile, padded, remat_torso,
         config.compute_dtype, pallas_interpret())
     return ImpalaAgent(
         action_space=action_space,

@@ -172,7 +172,7 @@ class ImpalaAgent(nn.Module):
     # rate, f32 accumulation).  Ignored by the xla core.
     core_matmul_dtype: str = "float32"
     # Stem-conv grad-W lowering: "xla" (plain nn.Conv) or "pallas"
-    # (ops/conv_pallas.py im2col MXU kernel).
+    # (ops/conv_pallas.py MXU kernel).
     # Identical parameter trees — checkpoints are interchangeable.
     conv_backend: str = "xla"
     # Rematerialize the torso in the backward pass (jax.checkpoint via

@@ -14,7 +14,8 @@ every conv/matmul hits the MXU with the largest possible batch; compute can
 run in bfloat16 (``dtype``) with float32 params.
 """
 
-from typing import Any, Optional
+import functools
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from flax import linen as nn
 
 # Stem-conv backends every torso accepts (``--conv_backend``).  "xla"
 # is the plain nn.Conv lowering; "pallas" swaps ONLY the weight
-# gradient for the im2col MXU kernel (ops/conv_pallas.py) — forward
+# gradient for the Pallas MXU kernel (ops/conv_pallas.py) — forward
 # math is identical, parameter trees are identical, checkpoints are
 # interchangeable.  The (negative-result) space-to-depth formulation
 # is deliberately NOT in this registry: it stays reachable via
@@ -115,10 +116,15 @@ class _SpaceToDepthFirstConv(nn.Module):
 
 class PallasStemConv(nn.Module):
     """A SAME-padded strided conv whose weight gradient is the Pallas
-    im2col kernel (ops/conv_pallas.py stem_conv).  Forward and input
+    kernel of ops/conv_pallas.py (``stem_conv``).  Forward and input
     gradient are XLA's own — numerically this IS the ``nn.Conv`` it
-    replaces; only d/dW's lowering changes.  Parameter tree, shapes,
-    and initializers are IDENTICAL to
+    replaces; only d/dW's lowering changes: the kernel reads the
+    normalised frame and the cotangent batch-minor, as XLA keeps them,
+    so nothing is re-laid-out or batch-padded on the way in (on a v5e
+    in the fused cell's update, 25,856 bf16 images: a 3.09 ms call
+    behind one 2.82 ms fused pad, against 8.38 ms for XLA's own grad-W
+    fusion; my chip runs, PR 25 — PERF.md section 5).  Parameter tree,
+    shapes, and initializers are IDENTICAL to
     ``nn.Conv(features, (k, k), strides=s, padding="SAME")`` — kernel
     [k, k, C, F] + bias under the same module name — so checkpoints
     are interchangeable both ways (the _SpaceToDepthFirstConv
@@ -128,13 +134,18 @@ class PallasStemConv(nn.Module):
     call (the one home of that decision), so CPU tier-1 exercises the
     same kernel body.  MXU operand precision follows ``dtype``: a bfloat16
     module runs bf16 operands with f32 accumulation; override with
-    ``matmul_dtype`` to decouple them."""
+    ``matmul_dtype`` to decouple them.  ``normalize`` is the torso's
+    raw-frame entry: the module is then called on the frame as the
+    torso got it (uint8) and the op applies ``normalize`` itself, where
+    the forward conv and the kernel's one pad can each fuse it in;
+    without it the input is the conv's input as it stands."""
 
     features: int
     kernel_size: int = 8
     stride: int = 4
     dtype: Any = jnp.float32
     matmul_dtype: Optional[str] = None
+    normalize: Optional[Callable] = None
 
     @nn.compact
     def __call__(self, x):
@@ -149,12 +160,15 @@ class PallasStemConv(nn.Module):
             (self.kernel_size, self.kernel_size, c, self.features))
         bias = self.param("bias", nn.initializers.zeros_init(),
                           (self.features,))
-        x, k, b = (jnp.asarray(t, self.dtype) for t in (x, kernel, bias))
+        k, b = (jnp.asarray(t, self.dtype) for t in (kernel, bias))
+        if self.normalize is None:
+            x = jnp.asarray(x, self.dtype)
         matmul_dtype = self.matmul_dtype or (
             "bfloat16" if jnp.dtype(self.dtype) == jnp.dtype(jnp.bfloat16)
             else "float32")
         out = conv_pallas.stem_conv(
-            x, k, self.stride, pallas_interpret(), matmul_dtype)
+            x, k, self.stride, pallas_interpret(), matmul_dtype,
+            self.normalize)
         return out + b
 
 
@@ -194,7 +208,9 @@ class ShallowConvTorso(nn.Module):
             if i == 0 and pallas_stem:
                 x = PallasStemConv(
                     num_ch, filter_size, stride, dtype=self.dtype,
-                    name="conv_0")(x)
+                    normalize=functools.partial(
+                        _normalize_frame, dtype=self.dtype),
+                    name="conv_0")(frame)
             elif i == 0 and self.space_to_depth:
                 x = _SpaceToDepthFirstConv(
                     num_ch, dtype=self.dtype, name="conv_0")(x)
@@ -259,7 +275,9 @@ class ResNetTorso(nn.Module):
             if i == 0 and pallas_stem:
                 x = PallasStemConv(*STEM_GEOMETRY["resnet"],
                                    dtype=self.dtype,
-                                   name="downscale_0")(x)
+                                   normalize=functools.partial(
+                                       _normalize_frame, dtype=self.dtype),
+                                   name="downscale_0")(frame)
             else:
                 x = nn.Conv(num_ch, (3, 3), padding="SAME",
                             dtype=self.dtype, name=f"downscale_{i}")(x)

@@ -209,24 +209,26 @@ _PALLAS_GRADW_MARKER = "pallas_conv0_gradw"
 
 
 def _pallas_gradw_flops(result: List, operands: List) -> Optional[float]:
-    """ops/conv_pallas.py grad-W: an im2col matmul contracting every
-    output position of the upstream gradient ``g=[N,OH,OW,F]`` against
-    the patch matrix into dW rows ``[K*K*Cin, F]``:
-    ``2 * N*OH*OW * rows * F``.  The g operand is recognized among the
-    custom-call's inputs as the 4-d tensor whose trailing dim matches
-    the result's feature dim (the patch operand's trailing dim is the
-    im2col depth ``S*S*Cin`` instead)."""
+    """ops/conv_pallas.py grad-W: per output row and group of JG output
+    columns, one matmul contracting the images of ``g=[OH,OW,F,N]``
+    (batch in the lanes) against the stacked tap windows into the
+    band ``[K*Cin*WIN, JG*F]``: ``2 * rows * JG*F * N*OH*OW/JG`` =
+    ``2 * rows * prod(g)`` — what the MXU executes, of which K/WIN
+    lands in dW (the rest is the band's off-diagonal).  The g operand
+    is recognized among the custom-call's inputs as the (last) 4-d
+    tensor whose third dim, F, divides the result's columns (the padded
+    input ``[HP,Cin,WP,N]`` has WP there)."""
     if not result or not operands:
         return None
     out_dims = result[0][1]
     if len(out_dims) != 2:
         return None
-    rows, features = out_dims
-    g_dims = next((dims for _, dims in operands
-                   if len(dims) == 4 and dims[-1] == features), None)
+    rows, columns = out_dims
+    g_dims = next((dims for _, dims in reversed(operands)
+                   if len(dims) == 4 and columns % dims[2] == 0), None)
     if g_dims is None:
         return None
-    return 2.0 * math.prod(g_dims[:3]) * rows * features
+    return 2.0 * rows * math.prod(g_dims)
 
 
 _PALLAS_KERNEL_COSTS = (

@@ -1,41 +1,47 @@
 """Pallas weight-gradient kernel for the torso's strided stem conv.
 
-The per-kernel roofline ledger names ``conv0_gradw`` as the learner's
-worst kernel: XLA lowers the 8x8/stride-4 stem's weight gradient to a
-kernel last measured at 0.107 MFU for ~13 ms at the B=256 merged batch
-(ROADMAP, "What the record says"), and the space-to-depth reformulation
-made it WORSE (0.047) because it only helps the input gradient — which
-the stem, fed by the gradient-free uint8 frame, never computes.  This
-module attacks the weight gradient directly.
-
-``stem_conv`` is the SAME 8x8/stride-4 convolution wrapped in a
+``stem_conv`` is the 8x8/stride-4 convolution wrapped in a
 ``jax.custom_vjp``:
 
-- **forward** and **grad-input** stay XLA's (both already run near the
-  layer's output-lane ceiling; grad-input is DCE'd entirely in the
-  torso, whose stem input needs no gradient),
-- **grad-W** is a Pallas im2col-tiled MXU matmul.  The padded input is
-  re-laid-out once (space-to-depth by the stride S, so every kernel tap
-  becomes a CONTIGUOUS slice), then a sequential grid over the batch
-  gathers per-tile patch matrices ``P [BN*OH*OW, K*K*Cin]`` from D*D
-  static slices (D = K/S), contracts them against the output cotangent
-  ``G [BN*OH*OW, Cout]`` on the MXU, and accumulates ``[K*K*Cin, Cout]``
+- **forward** and **grad-input** stay XLA's (grad-input is DCE'd
+  entirely in the torso, whose stem input needs no gradient),
+- **grad-W** is a Pallas MXU kernel that takes both operands THE WAY
+  XLA KEEPS THEM.  For a conv over few channels the TPU compiler lays
+  activations out batch-minor (the images in the 128 lanes, W or the
+  features in the sublanes).  The kernel's operands are declared in
+  that order — x as [HP, C, WP, N], the cotangent as [OH, OW, F, N] —
+  so the transposes that get them there compile to bitcasts, and the
+  only XLA op in front of the call is one fused pad of x (SAME's zero
+  borders, W filled out to whole vregs).  A sequential grid walks the
+  batch a lane tile at a time; per output row one ``q @ k^T`` matmul
+  contracts the images (and the row's column groups, side by side in
+  the lanes) of the stacked tap windows [K*C*WIN, ...] against the
+  stacked cotangents [JG*F, ...], accumulating a [K*C*WIN, JG*F] band
   in float32 VMEM scratch across grid steps — one revisited
-  constant-index output block, exactly the lstm_pallas.py accumulation
-  idiom.
+  constant-index output block, the lstm_pallas.py accumulation idiom.
+  dW is the band's diagonal, picked out by four slices afterwards.
 
-Why this beats XLA's lowering: XLA derives grad-W as a conv with the
-8x8 kernel dims mapped to the *spatial output* of a big dilated
-convolution — a shape (8x8 "image", 32 lanes) that strands most of the
-MXU.  Here the contraction is a single [K*K*Cin, N*OH*OW] x
-[N*OH*OW, Cout] matmul with the huge merged batch as the contracting
-dimension, which is the shape the MXU was built for.
+What it replaced, and why (TPU v5e, the fused cell's 25,856 images in
+bf16; PERF.md section 5 has the tables).  Until PR 25 the kernel took
+row-major operands: a space-to-depth'd x [N,19,25,48] and g
+[N,18,24,32], 48 and 32 channels in 128 lanes.  The call read 7.8 GB
+for 1.9 GB of data (9.1 ms), XLA spent 37 ms a step re-laying-out and
+batch-padding its operands (``pad.44``, ``pad.45``, ``copy.141``,
+``reshape.184``; ledger, PR 24), and the row-major constraint on g
+reached back through the ReLU into the forward conv's output and
+conv_1's input gradient, each copied into a 3.8 GB lane-padded array:
+a 158 ms step where XLA's own lowering gives 52.  XLA's grad-W conv
+is one fusion of 8.38 ms in that update; this kernel is a 3.09 ms call
+behind a 2.82 ms pad, and the step 48.5 ms (my chip runs, PR 25).  No
+operand is padded along the batch: a tile is chosen that divides N
+where one does, and a ragged last grid step masks its lanes past N in
+the kernel.
 
 Which geometries the kernel takes is decided in ONE place,
-``gradw_batch_tile``: it needs ``K % S == 0`` (D = K/S; true for the
-8/4 stem) and one image's working set inside the VMEM budget (true for
-the shallow stem in either dtype; false for the ResNet 3x3/stride-1
-stem at 72x96, whose 3-channel taps pad 3 -> 128 lanes).  The driver's
+``gradw_batch_tile``: it takes ``K % S == 0`` (true for the 8/4 stem)
+and a lane tile of images inside the VMEM budget (true for the shallow
+stem in either dtype; false for the ResNet 3x3/stride-1 stem at 72x96,
+whose cotangent alone is 27 MB per 128 images).  The driver's
 ``conv_backend=auto`` policy asks it before routing a stem here;
 ``conv_gradw`` itself REFUSES an unsupported geometry rather than
 quietly handing XLA the derivative — a run that says Pallas runs
@@ -48,9 +54,11 @@ either way via ``preferred_element_type``).
 """
 
 import functools
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -60,15 +68,15 @@ from jax.experimental.pallas import tpu as pltpu
 # instruction's op_name metadata — change them together.
 GRADW_KERNEL_NAME = "pallas_conv0_gradw"
 
-# Scoped-VMEM budget for one grid step, out of the 16 MiB Mosaic grants
-# a kernel on a v5e by default.  What the step holds is modelled by
-# _image_vmem_bytes below — deliberately the worst case (every tap
-# gather live at once): AOT compiles for v5e showed the shallow stem
-# compiling up to BN=17 (bf16) / BN=8 (f32) where this model stops at
-# 11 / 5, and the ResNet stem failing even at BN=1 where it says so.
-_VMEM_BUDGET_BYTES = 14 << 20
-_MAX_BATCH_TILE = 32
-_LANES, _SUBLANES = 128, 8
+# VMEM one grid step may hold, and the scoped limit asked of Mosaic for
+# it (the 16 MiB default is a fraction of a v5e core's 128 MiB).  What
+# a step holds is modelled by _image_vmem_bytes: AOT compiles for v5e
+# take the shallow stem at 256 images a step in bf16 and 128 in f32;
+# the ResNet stem does not fit at 128.
+_VMEM_BUDGET_BYTES = 48 << 20
+_VMEM_LIMIT_BYTES = 64 << 20
+_MAX_BATCH_TILE = 512
+_LANES = 128
 
 
 def _resolve_matmul_dtype(matmul_dtype):
@@ -79,9 +87,9 @@ def _resolve_matmul_dtype(matmul_dtype):
     return dtype
 
 
-def _forward(x, w, stride):
+def _forward(x, w, stride, normalize=None):
     return lax.conv_general_dilated(
-        x, w, (stride, stride), "SAME",
+        normalize(x) if normalize else x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
@@ -92,176 +100,262 @@ def _same_pads(size, k, s):
     return out, (total // 2, total - total // 2)
 
 
-def _gradw_kernel(xs_ref, g_ref, dw_ref, acc_s, *, depth, out_h, out_w,
-                  matmul_dtype):
-    """One batch tile of the grad-W contraction.
-
-    xs_ref [BN, OH+D-1, OW+D-1, S*S*C] — space-to-depth input; each
-    kernel tap (dh, dw) of the ORIGINAL conv is the contiguous slice
-    ``xs[:, dh:dh+OH, dw:dw+OW, :]``.  g_ref [BN, OH, OW, F] is the
-    output cotangent.  Accumulates [D*D*S*S*C, F] in f32 scratch; the
-    constant-index dw_ref block is written every step (last survives).
-    """
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    bn = xs_ref.shape[0]
-    s2c = xs_ref.shape[-1]
-    f = g_ref.shape[-1]
-    rows = bn * out_h * out_w
-    patches = [
-        xs_ref[:, dh:dh + out_h, dw:dw + out_w, :].reshape(rows, s2c)
-        for dh in range(depth) for dw in range(depth)
-    ]
-    p = jnp.concatenate(patches, axis=-1).astype(matmul_dtype)
-    g = g_ref[...].reshape(rows, f).astype(matmul_dtype)
-    # [D*D*S*S*C, BN*OH*OW] x [BN*OH*OW, F]: the merged batch is the
-    # contracting dim — the MXU-shaped form of grad-W.
-    acc_s[...] += lax.dot_general(
-        p, g, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dw_ref[...] = acc_s[...]
-
-
 def _round_up(x, m):
     return -(-x // m) * m
 
 
-def _image_vmem_bytes(tile_h, tile_w, s2c, out_h, out_w, f, depth,
-                      itemsize, mm_itemsize):
-    """Scoped VMEM one image costs a grid step, in the (8, 128)-tiled
-    layout Mosaic allocates (minor dim padded to 128 lanes, second-minor
-    to 8 sublanes — what a float count misses by 2.7x on a 48-lane
-    block and 42x on a 3-lane one): both input blocks double-buffered
-    by the pipeline, the D*D tap gathers, the concatenated patch matrix
-    and the flattened cotangent."""
-    inputs = 2 * itemsize * (
-        tile_h * _round_up(tile_w, _SUBLANES) * _round_up(s2c, _LANES)
-        + out_h * _round_up(out_w, _SUBLANES) * _round_up(f, _LANES))
-    rows = out_h * out_w
-    taps = depth * depth
-    gathers = taps * rows * _round_up(s2c, _LANES) * mm_itemsize
-    patches = rows * _round_up(taps * s2c, _LANES) * mm_itemsize
-    cotangent = rows * _round_up(f, _LANES) * mm_itemsize
-    return inputs + gathers + patches + cotangent
+def _sublanes(dtype):
+    """Rows of one vreg at this width: 8 x 32 bits, narrower types
+    packed along the sublanes."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
 
 
-def gradw_batch_tile(x_shape, features, kernel_size, stride, dtype,
-                     matmul_dtype=None) -> int:
+class _Geometry(NamedTuple):
+    """How one conv's grad-W is laid on the MXU (see _gradw_kernel)."""
+    out_h: int
+    out_w: int
+    pads_h: Tuple[int, int]
+    pad_w_lo: int
+    group: int      # JG: output columns contracted by one matmul
+    groups: int     # OW / JG
+    window: int     # WIN: input columns one group's taps span, in vregs
+    width: int      # W as handed to the kernel: every window in range
+
+
+def _geometry(h, w_in, f, k, s, dtype) -> _Geometry:
+    out_h, pads_h = _same_pads(h, k, s)
+    out_w, (pad_w_lo, _) = _same_pads(w_in, k, s)
+    # As many output columns as fill the MXU's 128 result lanes with
+    # (column, feature) pairs — and divide OW, so no group is ragged.
+    # For the 8/4 stem: 4 columns, windows 16 input columns apart and
+    # 32 wide — every one starts and ends on a vreg boundary.
+    group = max(j for j in range(1, out_w + 1)
+                if out_w % j == 0 and (j * f <= _LANES or j == 1))
+    groups = out_w // group
+    window = _round_up(s * (group - 1) + k, _sublanes(dtype))
+    return _Geometry(out_h, out_w, pads_h, pad_w_lo, group, groups,
+                     window, s * group * (groups - 1) + window)
+
+
+def _gradw_kernel(x_ref, g_ref, acc_ref, acc_s, *, kernel_size, stride,
+                  geometry, images, matmul_dtype):
+    """One batch tile of the grad-W contraction, the batch in the lanes.
+
+    x_ref [HP, C, WP, BN] is the zero-padded input and g_ref
+    [OH, OW, F, BN] the output cotangent, both as XLA itself keeps a
+    few-channel conv's activations (batch minor, W or F in the
+    sublanes): no operand is re-laid-out to get here.  Output columns
+    are taken JG at a time.  Group ``q`` of output row ``i`` needs, for
+    each tap row kh and channel c, input columns ``S*JG*q .. +WIN`` of
+    padded row ``S*i + kh`` — a [WIN, BN] slab of whole vregs.  The
+    K*C slabs stack into [K*C*WIN, BN]; the group's cotangents stack
+    into [JG*F, BN]; the groups of one output row sit side by side in
+    the lanes, and one q @ k^T matmul contracts images and groups at
+    once into [K*C*WIN, JG*F], accumulated in f32 scratch.  Entry
+    ``[(kh, c, S*jj + kw), (jj, f)]`` of it is the weight gradient's
+    ``[kh, kw, c, f]`` share from every JG-th output column, the
+    jj-th on; the rest of the band is the price of feeding the MXU whole vregs (K/WIN
+    of its work is kept).  The constant-index acc_ref block is written
+    every step (last survives).  ``images`` is N: a ragged last step
+    masks the lanes past it in BOTH operands (what a block reads out of
+    bounds is not zeros, and 0 * NaN is NaN)."""
+    step = pl.program_id(0)
+    k, s, geo = kernel_size, stride, geometry
+    channels, bn = x_ref.shape[1], x_ref.shape[3]
+
+    @pl.when(step == 0)
+    def _():
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def contract(valid):
+        def load(ref, *index):
+            tile = ref[index]
+            if valid is not None:
+                lane = lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+                tile = jnp.where(lane < valid, tile, jnp.zeros_like(tile))
+            return tile.astype(matmul_dtype)
+
+        def row(i, carry):
+            taps, cots = [], []
+            for q in range(geo.groups):
+                columns = pl.ds(s * geo.group * q, geo.window)
+                taps.append(jnp.concatenate(
+                    [load(x_ref, s * i + kh, c, columns)
+                     for kh in range(k) for c in range(channels)],
+                    axis=0))
+                cots.append(jnp.concatenate(
+                    [load(g_ref, i, geo.group * q + jj)
+                     for jj in range(geo.group)], axis=0))
+            acc_s[...] += lax.dot_general(
+                jnp.concatenate(taps, axis=1),
+                jnp.concatenate(cots, axis=1),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        lax.fori_loop(0, geo.out_h, row, 0)
+
+    ragged = images % bn
+    if ragged:
+        last = pl.num_programs(0) - 1
+        pl.when(step == last)(lambda: contract(ragged))
+        pl.when(step != last)(lambda: contract(None))
+    else:
+        contract(None)
+    acc_ref[...] = acc_s[...]
+
+
+def _image_vmem_bytes(geo, hp, c, f, dtype):
+    """VMEM one image (one lane) costs a grid step: its column of both
+    operand blocks, double-buffered by the pipeline.  WP is whole vregs
+    by construction; F is rounded up to them."""
+    return 2 * jnp.dtype(dtype).itemsize * (
+        hp * c * geo.width
+        + geo.out_h * geo.out_w * _round_up(f, _sublanes(dtype)))
+
+
+def gradw_batch_tile(x_shape, features, kernel_size, stride,
+                     dtype) -> int:
     """Images per grid step for the grad-W kernel at this geometry, or
     0 when the kernel does not take it: ``kernel_size % stride != 0``
-    (the D-slice gather needs every tap on the s2d lattice), or a
-    single image's working set already exceeds the VMEM budget.  The
-    ONE support predicate — the driver's ``conv_backend`` policy and
-    ``conv_gradw`` both ask here.  ``dtype`` is the activations' (x and
-    its cotangent); ``matmul_dtype`` the MXU operands', ``dtype``'s
-    own when omitted (PallasStemConv's default)."""
+    (no limit of this formulation — the cut the kernel has had since
+    its space-to-depth days, kept because no stem needs it lifted and
+    no chip has run one), or not even one lane tile of images fits the
+    VMEM budget (the ResNet 3x3/1 stem: its
+    72x96x16 cotangent alone is 27 MB per 128 images).  The images are
+    the lane dim of both operands, so a tile short of N is a multiple
+    of 128 — the one whose last grid step runs past N by the fewest
+    images, the largest of equals: one that divides N when one does
+    (25,856 = 101 x 256: no step is ragged), and for the rest (6,464 =
+    50.5 x 128) the kernel masks the lanes past N in its last step.
+    Nothing is ever padded in HBM to round the batch.  The ONE support predicate — the
+    driver's ``conv_backend`` policy and ``conv_gradw`` both ask here.
+    ``dtype`` is the activations' (x and its cotangent); the MXU
+    operands' sizes nothing (they are cast a vreg at a time)."""
     n, h, w_in, c = x_shape
     k, s = int(kernel_size), int(stride)
     if k % s != 0:
         return 0
-    depth = k // s
-    out_h, _ = _same_pads(h, k, s)
-    out_w, _ = _same_pads(w_in, k, s)
+    geo = _geometry(h, w_in, features, k, s, dtype)
     per_image = _image_vmem_bytes(
-        out_h + depth - 1, out_w + depth - 1, s * s * c, out_h, out_w,
-        features, depth, jnp.dtype(dtype).itemsize,
-        jnp.dtype(matmul_dtype or dtype).itemsize)
-    # The [K*K*C, F] f32 accumulator: output block (double-buffered)
-    # plus the scratch copy.
-    fixed = 3 * _round_up(k * k * c, _SUBLANES) * _round_up(
-        features, _LANES) * 4
-    return max(0, min(n, _MAX_BATCH_TILE,
-                      (_VMEM_BUDGET_BYTES - fixed) // per_image))
+        geo, h + sum(geo.pads_h), c, features, dtype)
+    fit = _VMEM_BUDGET_BYTES // per_image
+    if fit < min(n, _LANES):
+        return 0
+    cap = min(n, _MAX_BATCH_TILE, fit)
+    if cap == n or cap < _LANES:    # under a lane tile: the tests' cap
+        return cap
+    return min(range(cap // _LANES * _LANES, 0, -_LANES),
+               key=lambda tile: gradw_padded_images(n, tile))
+
+
+def gradw_padded_images(n, tile) -> int:
+    """Lanes of the last grid step past image N — masked in the kernel,
+    never copied or padded in HBM.  0 when the tile divides N."""
+    return -n % tile if tile else 0
 
 
 def conv_gradw(x, g, kernel_size, stride, interpret=False,
-               matmul_dtype="float32"):
+               matmul_dtype="float32", normalize=None):
     """Weight gradient of the SAME-padded ``kernel_size``/``stride``
-    conv: x [N,H,W,C], g [N,OH,OW,F] -> dW [K,K,C,F] float32.  Raises
-    ValueError for a geometry ``gradw_batch_tile`` does not take."""
+    conv: x [N,H,W,C], g [N,OH,OW,F] -> dW [K,K,C,F] float32.  With
+    ``normalize`` the conv's input is ``normalize(x)`` (``stem_conv``'s
+    raw-frame entry).  Raises ValueError for a geometry
+    ``gradw_batch_tile`` does not take."""
     matmul_dtype = _resolve_matmul_dtype(matmul_dtype)
     n, h, w_in, c = x.shape
-    _, out_h, out_w, f = g.shape
+    f = g.shape[-1]
     k, s = int(kernel_size), int(stride)
-    bn = gradw_batch_tile(x.shape, f, k, s, x.dtype, matmul_dtype)
+    dtype = (jax.eval_shape(normalize, x).dtype if normalize
+             else x.dtype)
+    bn = gradw_batch_tile(x.shape, f, k, s, dtype)
     if bn == 0:
         raise ValueError(
             f"the Pallas grad-W kernel does not take a {k}x{k}/stride-"
-            f"{s} conv over {h}x{w_in}x{c} {x.dtype} frames "
-            f"(kernel_size % stride must be 0 and one image's tiles "
-            f"must fit VMEM); use conv_backend=xla or auto")
+            f"{s} conv over {h}x{w_in}x{c} {dtype} frames "
+            f"(kernel_size % stride must be 0 and a lane tile of "
+            f"images must fit VMEM); use conv_backend=xla or auto")
 
-    depth = k // s
-    _, (ph_lo, ph_hi) = _same_pads(h, k, s)
-    _, (pw_lo, pw_hi) = _same_pads(w_in, k, s)
-    xp = jnp.pad(x, ((0, 0), (ph_lo, ph_hi), (pw_lo, pw_hi), (0, 0)))
-    hp, wp = xp.shape[1], xp.shape[2]
-    # Space-to-depth by the stride: [N, HP/S, WP/S, S*S*C], depth rows
-    # ordered (sh, sw, c).  HP = (OH-1)*S + K = (OH+D-1)*S exactly, so
-    # the lattice always divides.
-    xs = xp.reshape(n, hp // s, s, wp // s, s, c)
-    xs = xs.transpose(0, 1, 3, 2, 4, 5).reshape(
-        n, hp // s, wp // s, s * s * c)
-    tile_h, tile_w = out_h + depth - 1, out_w + depth - 1
-    s2c = s * s * c
-    n_pad = -(-n // bn) * bn
-    if n_pad != n:
-        # Zero-padded images contribute zero cotangent rows — exact.
-        xs = jnp.pad(xs, ((0, n_pad - n), (0, 0), (0, 0), (0, 0)))
-        g = jnp.pad(g, ((0, n_pad - n), (0, 0), (0, 0), (0, 0)))
-    rows_out = depth * depth * s2c
+    geo = _geometry(h, w_in, f, k, s, dtype)
+    # The one pass over x: SAME's zero borders, W filled out so that
+    # every group's window is in range.  A raw frame is normalised
+    # AFTER the pad (the borders stay zero: normalize(0) is 0), so this
+    # normalisation is an expression of its own that XLA fuses with
+    # the pad, and the forward conv keeps its own fused: sharing one
+    # normalised copy between them costs two extra passes over the
+    # frames (5.0 ms a step; my chip run, PR 25).  The transposes put
+    # the batch minor — where XLA already keeps it — and move nothing.
+    xt = jnp.pad(x, ((0, 0), geo.pads_h,
+                     (geo.pad_w_lo, geo.width - w_in - geo.pad_w_lo),
+                     (0, 0)))
+    if normalize:
+        xt = normalize(xt)
+    xt = xt.transpose(1, 3, 2, 0)
+    gt = g.transpose(1, 2, 3, 0)
+    band = (k * c * geo.window, geo.group * f)
     with jax.named_scope(GRADW_KERNEL_NAME):
-        dw = pl.pallas_call(
+        acc = pl.pallas_call(
             functools.partial(
-                _gradw_kernel, depth=depth, out_h=out_h, out_w=out_w,
-                matmul_dtype=matmul_dtype),
-            grid=(n_pad // bn,),
+                _gradw_kernel, kernel_size=k, stride=s, geometry=geo,
+                images=n, matmul_dtype=matmul_dtype),
+            grid=(-(-n // bn),),
             in_specs=[
-                pl.BlockSpec((bn, tile_h, tile_w, s2c),
-                             lambda i: (i, 0, 0, 0)),
-                pl.BlockSpec((bn, out_h, out_w, f),
-                             lambda i: (i, 0, 0, 0)),
+                pl.BlockSpec(xt.shape[:3] + (bn,), lambda i: (0, 0, 0, i)),
+                pl.BlockSpec(gt.shape[:3] + (bn,), lambda i: (0, 0, 0, i)),
             ],
-            out_specs=pl.BlockSpec((rows_out, f), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows_out, f), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((rows_out, f), jnp.float32)],
+            out_specs=pl.BlockSpec(band, lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct(band, jnp.float32),
+            scratch_shapes=[pltpu.VMEM(band, jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret,
             name=GRADW_KERNEL_NAME,
-        )(xs, g)
-    # Rows are ordered (dh, dw, sh, sw, c); kh = dh*S + sh.
-    dw = dw.reshape(depth, depth, s, s, c, f).transpose(0, 2, 1, 3, 4, 5)
-    return dw.reshape(k, k, c, f)
+        )(xt, gt)
+    # The band's diagonal: output column jj of a group saw tap kw at
+    # window column S*jj + kw.
+    acc = acc.reshape(k, c, geo.window, geo.group, f)
+    dw = sum(acc[:, :, s * jj:s * jj + k, jj, :]
+             for jj in range(geo.group))
+    return dw.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def stem_conv(x, w, stride=4, interpret=False, matmul_dtype="float32"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def stem_conv(x, w, stride=4, interpret=False, matmul_dtype="float32",
+              normalize=None):
     """SAME-padded NHWC conv (x [N,H,W,C], w [K,K,C,F], square stride)
-    whose weight gradient is the Pallas im2col kernel above.  Forward
-    and input gradient are XLA's — numerically this op IS
+    whose weight gradient is the Pallas kernel above.  Forward and
+    input gradient are XLA's — numerically this op IS
     ``lax.conv_general_dilated(..., "SAME")``; only d/dW's lowering
     differs.  ``interpret`` and ``matmul_dtype`` follow
     ops/lstm_pallas.py's contract.  Only for geometries
-    ``gradw_batch_tile`` takes: the backward pass raises otherwise."""
-    return _forward(x, w, stride)
+    ``gradw_batch_tile`` takes: the backward pass raises otherwise.
+
+    ``normalize`` is the raw-frame entry: x is the frame as the torso
+    was given it (uint8, no gradient), the conv's input is
+    ``normalize(x)`` — an elementwise map with ``normalize(0) == 0``,
+    the torso's own, so the forward and the kernel see the same value
+    of every pixel — and the residual is the frame itself."""
+    return _forward(x, w, stride, normalize)
 
 
-def _vjp_fwd(x, w, stride, interpret, matmul_dtype):
-    return _forward(x, w, stride), (x, w)
+def _vjp_fwd(x, w, stride, interpret, matmul_dtype, normalize):
+    return _forward(x, w, stride, normalize), (x, w)
 
 
-def _vjp_bwd(stride, interpret, matmul_dtype, residuals, g):
+def _vjp_bwd(stride, interpret, matmul_dtype, normalize, residuals, g):
     x, w = residuals
-    # Input gradient: XLA's transposed conv.  In the torso the stem's
-    # input is the gradient-free normalized frame, so this whole branch
-    # is dead code XLA eliminates; it exists for standalone parity.
-    _, vjp_x = jax.vjp(lambda xx: _forward(xx, w, stride), x)
-    dx = vjp_x(g)[0]
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        # Input gradient: XLA's transposed conv.  In the torso the
+        # stem's input is the gradient-free frame, so this whole
+        # branch is dead code XLA eliminates; it exists for standalone
+        # parity.
+        _, vjp_x = jax.vjp(
+            lambda xx: _forward(xx, w, stride, normalize), x)
+        dx = vjp_x(g)[0]
+    else:
+        dx = np.zeros(x.shape, jax.dtypes.float0)
     dw = conv_gradw(x, g, w.shape[0], stride, interpret=interpret,
-                    matmul_dtype=matmul_dtype)
+                    matmul_dtype=matmul_dtype, normalize=normalize)
     return dx, dw.astype(w.dtype)
 
 

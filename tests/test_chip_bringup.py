@@ -26,6 +26,7 @@ import sys
 import tempfile
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from scalable_agent_tpu import driver
@@ -136,19 +137,50 @@ class TestKernelPolicy:
         from scalable_agent_tpu.ops.conv_pallas import gradw_batch_tile
 
         shape = (101 * 32, 72, 96, 3)
-        bf16 = gradw_batch_tile(shape, 32, 8, 4, "bfloat16", "bfloat16")
-        f32 = gradw_batch_tile(shape, 32, 8, 4, "float32", "float32")
-        # AOT compiles for v5e: bf16 fits up to 17 images, f32 up to 8.
-        assert 1 <= bf16 <= 17
-        assert 1 <= f32 <= 8
-        assert f32 < bf16
+        bf16 = gradw_batch_tile(shape, 32, 8, 4, "bfloat16")
+        f32 = gradw_batch_tile(shape, 32, 8, 4, "float32")
+        # The images are the operands' lane dim since PR 25, so a tile
+        # is whole lane tiles of them.  AOT compiles for v5e take 256
+        # in bf16 and 128 in f32 (TestAotCompileForV5e below compiles
+        # both, ragged last step included: 3,232 = 12.6 x 256).
+        assert (bf16, f32) == (256, 128)
+
+    @pytest.mark.parametrize("overrides,tile,masked", [
+        (dict(batch_size=256), 256, 0),       # the fused cell: 25,856
+        (dict(batch_size=64), 128, 64),       # the host loop: 6,464
+        (dict(batch_size=256, conv_backend="xla"), 0, 0),
+    ])
+    def test_the_stem_kernels_tile_is_a_gauge_and_in_the_policy_line(
+            self, monkeypatch, overrides, tile, masked):
+        """The tile and what the last grid step masks are decided at
+        trace time: ``build_agent`` sets them as gauges, once, and says
+        them in the kernel-policy line.  Nothing is padded in HBM in
+        any case; the fused cell's batch masks nothing either."""
+        from scalable_agent_tpu.obs import get_registry
+
+        _as_tpu(monkeypatch)
+        said = []
+        monkeypatch.setattr(
+            driver.log, "info",
+            lambda message, *args: said.append(message % args))
+        config = Config(mesh_data=1, compute_dtype="bfloat16",
+                        logdir="/tmp/unused", **overrides)
+        observation_spec, action_space, _ = driver.probe_env(config)
+        driver.build_agent(config, action_space,
+                           observation_spec.frame.shape)
+        gauges = get_registry().snapshot()
+        assert gauges["conv0_gradw/batch_tile"] == tile
+        assert gauges["conv0_gradw/padded_images"] == masked
+        (line,) = [m for m in said if m.startswith("kernel policy")]
+        assert (f"conv0_gradw batch_tile={tile} "
+                f"padded_images={masked}") in line
 
     def test_resnet_stem_is_routed_to_xla_loudly(self, monkeypatch):
         from scalable_agent_tpu.ops.conv_pallas import gradw_batch_tile
 
         _as_tpu(monkeypatch)
         assert gradw_batch_tile((101 * 32, 72, 96, 3), 16, 3, 1,
-                                "bfloat16", "bfloat16") == 0
+                                "bfloat16") == 0
         warned = []
         monkeypatch.setattr(
             driver.log, "warning",
@@ -356,3 +388,37 @@ class TestAotCompileForV5e:
             monkeypatch, v5e_topology, 1, torso_type="resnet")
         assert (agent.core_impl, agent.conv_backend) == ("pallas", "xla")
         assert text.count("tpu_custom_call") == 2
+
+    def test_stem_gradw_operands_arrive_without_a_relayout(
+            self, v5e_topology):
+        """The grad-W kernel at the fused cell's size (25,856 images,
+        bf16), compiled alone for a v5e: its operands keep the batch in
+        the lanes as XLA itself does, so the cotangent reaches the call
+        through a bitcast, the input through ONE fused pad, and nothing
+        is copied, reshaped or padded along the batch (the parent of
+        PR 25 paid 37 ms a step for pad.44, pad.45, copy.141 and
+        reshape.184 in front of a 9.5 ms call).  A compiler verdict on
+        layouts, not a run."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import conv_pallas
+
+        n = 256 * 101
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+        x = jax.ShapeDtypeStruct((n, 72, 96, 3), jnp.bfloat16,
+                                 sharding=one_chip)
+        g = jax.ShapeDtypeStruct((n, 18, 24, 32), jnp.bfloat16,
+                                 sharding=one_chip)
+        text = jax.jit(lambda x, g: conv_pallas.conv_gradw(
+            x, g, 8, 4, interpret=False, matmul_dtype="bfloat16")
+        ).lower(x, g).compile().as_text()
+        entry = text[text.index("ENTRY "):]
+        # Opcode of every entry instruction whose result has the batch
+        # as a dim: the two parameters, g's bitcast, x's pad fusion.
+        batch_ops = sorted(
+            match.group(2) for match in re.finditer(
+                r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", entry)
+            if str(n) in match.group(1).split(","))
+        assert batch_ops == ["bitcast", "fusion", "parameter",
+                             "parameter"], entry
+        assert "pad(" in text and str(n + 1) not in text

@@ -113,22 +113,23 @@ ENTRY %main (a: f32[128,64], b: f32[64,32]) -> f32[128,32] {
     def test_pallas_gradw_custom_call_flops(self):
         """ISSUE 18: a pallas_call lowers to a custom-call XLA cannot
         see inside, so the named grad-W kernel gets an explicit cost —
-        2 * N*OH*OW * rows * F off the operand/result shapes — instead
-        of the one-flop-per-element floor (which would misprice the MXU
-        matmul by ~3 orders of magnitude and hide it from the
-        worst-kernel verdict)."""
+        2 * rows * prod(g), what its MXU executes, off the operand and
+        result shapes — instead of the one-flop-per-element floor
+        (which would misprice the MXU matmul by ~3 orders of magnitude
+        and hide it from the worst-kernel verdict)."""
         hlo = """
-ENTRY %main (xs: bf16[256,19,25,48], g: bf16[256,18,24,32]) -> f32[768,32] {
-  %xs = bf16[256,19,25,48]{3,2,1,0} parameter(0)
-  %g = bf16[256,18,24,32]{3,2,1,0} parameter(1)
-  ROOT %cc.1 = f32[768,32]{1,0} custom-call(bf16[256,19,25,48]{3,2,1,0} %xs, bf16[256,18,24,32]{3,2,1,0} %g), custom_call_target="tpu_custom_call", metadata={op_name="jit(update)/pallas_conv0_gradw/pallas_call"}
+ENTRY %main (xt: bf16[76,3,112,256], gt: bf16[18,24,32,256]) -> f32[768,128] {
+  %xt = bf16[76,3,112,256]{3,2,1,0} parameter(0)
+  %gt = bf16[18,24,32,256]{3,2,1,0} parameter(1)
+  ROOT %cc.1 = f32[768,128]{1,0} custom-call(bf16[76,3,112,256]{3,2,1,0} %xt, bf16[18,24,32,256]{3,2,1,0} %gt), custom_call_target="tpu_custom_call", metadata={op_name="jit(update)/pallas_conv0_gradw/pallas_call"}
 }
 """
         costs = kernels_lib.parse_hlo_kernel_costs(hlo)
-        # The g operand is the 4-d input whose trailing dim matches the
-        # result's feature dim; contraction length is its N*OH*OW.
+        # The g operand is the 4-d input whose third dim, F, divides
+        # the band's columns (JG*F); each of its N*OH*OW/JG groups is
+        # one [768, N-tile] x [JG*F, N-tile]^T matmul.
         assert costs["cc.1"]["flops_est"] == pytest.approx(
-            2 * (256 * 18 * 24) * 768 * 32)
+            2 * 768 * 128 * (256 * 18 * 24 // 4))
         assert costs["cc.1"]["op"] == "custom-call"
 
     def test_unrecognized_custom_call_keeps_elementwise_floor(self):
