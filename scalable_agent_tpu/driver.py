@@ -718,10 +718,13 @@ def _write_op_scopes(trace_path: Optional[str], trainer, state, carry):
     """After a ``--trace`` run of the fused loop: the table that gives
     each instruction of the compiled step its scope path, beside the
     run's trace (obs/kernels.write_op_scopes; the benchmark's scope
-    reader joins a device trace to it).  The running step's own
-    executable is at hand (nothing is traced or compiled again) —
-    unless the persistent cache handed back one compiled from an older
-    version of the program, whose text names that version's scopes:
+    reader joins a device trace to it), and with it what the
+    partitioner made the step move between devices (the table's notes
+    and the ``spmd/collective_bytes/<kind>`` gauges).  The running
+    step's own executable is at hand (nothing is traced or compiled
+    again) — unless the persistent cache handed back one compiled from
+    an older version of the program, whose text names that version's
+    scopes:
     then the step is compiled afresh for its names
     (``InGraphTrainer.compile_step_afresh``), which is why this runs
     last, after every deadline of the run is disarmed.  Never raises: it

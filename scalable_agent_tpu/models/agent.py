@@ -12,6 +12,9 @@ experiment.py:109-237), re-designed for TPU/XLA:
 
 - The torso runs on the whole [T*B] flattened batch at once (one big conv
   batch for the MXU) instead of the reference's per-timestep BatchApply.
+  Where a mesh shards B, the merge puts the shard index outermost
+  (``batch_shards``), so the merged axis is sharded too and each device
+  runs the torso over its own envs only.
 
 - Sampling is separated from the forward pass: the model returns logits and
   baseline; ``actor_step`` samples with an explicit PRNG key (the reference
@@ -182,6 +185,20 @@ class ImpalaAgent(nn.Module):
     # the default-path jaxpr (and the golden-loss anchor) is
     # untouched; the learner turns it on with the fused forward.
     remat_torso: bool = False
+    # How many pieces the mesh cuts the batch axis in (parallel/mesh.py
+    # batch_shards: data x seq; the Learner sets it on its copy of the
+    # agent from the mesh it is given).  It orders the [T, B] -> [T*B]
+    # merge: B is the sharded axis, and a time-major merge of it is not
+    # a tiling of the merged axis, so the SPMD partitioner gathered the
+    # frames and every device computed the whole global batch's torso,
+    # heads and backward (ISSUE 26: four chips at 1.09x one).  Merged
+    # as [S, T, B/S] — shard index outermost — the merged axis is
+    # tiled S ways, nothing is gathered, and a device's own rows stay
+    # time-major exactly as on one chip, where S = 1 and the merge is
+    # the plain reshape.  Only the order of rows inside the merge
+    # changes, never a value: any S that divides B gives the same
+    # outputs on any mesh.
+    batch_shards: int = 1
     # Composite policies: a TupleSpace mixing Discrete/Discretized
     # components (reference: TupleActionDistribution,
     # algorithms/utils/action_distributions.py:111-201).  When unset, the
@@ -223,7 +240,26 @@ class ImpalaAgent(nn.Module):
 
         # ---- Torso over the merged [T*B] batch (reference: _torso,
         # experiment.py:148-198, but batched over all timesteps at once).
-        flat = lambda x: x.reshape((unroll_len * batch,) + x.shape[2:])
+        # T = 1 (acting) has nothing to order, and a batch the shard
+        # count does not divide (a shape-only init) is not sharded.
+        shards = (self.batch_shards
+                  if unroll_len > 1 and batch % self.batch_shards == 0
+                  else 1)
+        per_shard = batch // shards
+
+        def flat(x):  # [T, B, ...] -> [T*B, ...], shard index outermost
+            trailing = x.shape[2:]
+            if shards > 1:
+                x = jnp.swapaxes(x.reshape(
+                    (unroll_len, shards, per_shard) + trailing), 0, 1)
+            return x.reshape((unroll_len * batch,) + trailing)
+
+        def unflat(x, *trailing):  # [T*B, ...] -> [T, B, *trailing]
+            if shards > 1:
+                x = jnp.swapaxes(x.reshape(
+                    (shards, unroll_len, per_shard) + trailing), 0, 1)
+            return x.reshape((unroll_len, batch) + trailing)
+
         torso_cls = TORSOS[self.torso_type]
         if self.remat_torso:
             # jax.checkpoint on the torso: activations are recomputed
@@ -250,7 +286,7 @@ class ImpalaAgent(nn.Module):
         # values).
         torso_out = jnp.asarray(
             jnp.concatenate(parts, axis=-1), self.compute_dtype)
-        torso_out = torso_out.reshape((unroll_len, batch, -1))
+        torso_out = unflat(torso_out, torso_out.shape[-1])
 
         # ---- LSTM core: one fused scan over time with done-reset
         # (reference: experiment.py:228-237).
@@ -276,19 +312,19 @@ class ImpalaAgent(nn.Module):
 
         # ---- Heads (reference: _head, experiment.py:200-210), again on the
         # merged batch.
-        core_flat = core_outputs.reshape((unroll_len * batch, -1))
+        core_flat = flat(core_outputs)
         num_logits = self.num_logits
         # Heads run at compute_dtype; the OUTPUTS are upcast to f32 —
         # the loss/V-trace/optimizer side of the dtype policy never
         # sees bf16 (under the f32 default both casts are identities).
-        policy_logits = jnp.asarray(
+        policy_logits = unflat(jnp.asarray(
             nn.Dense(num_logits, dtype=self.compute_dtype,
                      name="policy_logits")(core_flat),
-            jnp.float32).reshape((unroll_len, batch, num_logits))
-        baseline = jnp.asarray(
+            jnp.float32), num_logits)
+        baseline = unflat(jnp.asarray(
             nn.Dense(1, dtype=self.compute_dtype, name="baseline")(
                 core_flat),
-            jnp.float32).reshape((unroll_len, batch))
+            jnp.float32))
         return (policy_logits, baseline), new_state
 
 

@@ -208,19 +208,22 @@ class DeviceTelemetry:
             raise KeyError(f"unknown telemetry histogram {name!r}")
         edges, _ = self._hists[name]
         raw = jnp.asarray(values, jnp.float32)
+        # Nothing here flattens ``values``: a [T, B] input whose B is
+        # sharded over a mesh stays sharded through the bucketing, and
+        # the reductions below become partial sums plus an all-reduce
+        # of the buckets (a ravel was a time-major merge of the
+        # sharded axis: an all-gather of the input on every device).
         if where is None:
-            weights = jnp.ones(raw.size, jnp.float32)
+            weights = jnp.ones(raw.shape, jnp.float32)
         else:
             weights = jnp.broadcast_to(
-                jnp.asarray(where), raw.shape).astype(
-                    jnp.float32).ravel()
-        values = raw.ravel()
+                jnp.asarray(where), raw.shape).astype(jnp.float32)
         # Masked-out entries must be SELECTED out, not multiplied by
         # zero: NaN * 0 = NaN, so a masked non-finite value would
         # still poison the cumulative ":sum" buffer (and relying on
         # XLA to rewrite the multiply into a select is an optimizer
         # behavior, not a contract).
-        values = jnp.where(weights > 0, values, 0.0)
+        values = jnp.where(weights > 0, raw, 0.0)
         edges_arr = jnp.asarray(edges, jnp.float32)
         # side="left": a value exactly equal to an edge lands in that
         # edge's bucket, matching the published ``le_<edge>`` (<=)
@@ -230,7 +233,8 @@ class DeviceTelemetry:
         base = self._key(_HIST, name)
         tel = dict(tel)
         tel[base + ":buckets"] = (tel[base + ":buckets"]
-                                  + (onehot * weights[:, None]).sum(0))
+                                  + (onehot * weights[..., None]).sum(
+                                      tuple(range(raw.ndim))))
         tel[base + ":sum"] = tel[base + ":sum"] + (values * weights).sum()
         tel[base + ":count"] = tel[base + ":count"] + weights.sum()
         return tel

@@ -52,6 +52,8 @@ __all__ = [
     "BENCH_KERNEL_SERIES_RE",
     "KERNELS_JSON_NAME",
     "build_kernel_table",
+    "collective_bytes",
+    "collectives",
     "find_profiler_traces",
     "harvest",
     "holds_scope",
@@ -400,23 +402,115 @@ def holds_scope(hlo_text: str, scope: str) -> bool:
                      hlo_text) is not None
 
 
-def write_op_scopes(trace_path: str, hlo_text: str) -> str:
+# Cross-device ops of a partitioned program, by the kind the gauges
+# are published under.  The ``-start`` half of an async pair carries
+# the shapes; the ``-done`` half is the same transfer and is skipped.
+_COLLECTIVE_KINDS = {
+    "all-reduce": "all_reduce",
+    "all-gather": "all_gather",
+    "all-to-all": "other",
+    "collective-permute": "other",
+    "reduce-scatter": "other",
+    "collective-broadcast": "other",
+    "ragged-all-to-all": "other",
+}
+_COLLECTIVE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
+    r"(?P<shape>\(.*?\)|\S+)\s+"
+    r"(?P<op>" + "|".join(map(re.escape, _COLLECTIVE_KINDS))
+    + r")(?P<start>-start)?\(")
+_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+
+
+def collectives(hlo_text: str) -> List[dict]:
+    """Every collective instruction of a compiled (partitioned)
+    module: ``{"name", "op", "kind", "dims", "bytes", "op_name"}`` in
+    text order.  ``dims`` are the dims of each array of the result —
+    PER DEVICE, as every shape of a partitioned module is — and
+    ``bytes`` their size: what one device holds of the collective's
+    output (an all-gather's gathered array, an all-reduce's sum).  An
+    async ``-start`` result also lists its operands first; those are
+    dropped.  One transfer the TPU compiler chains over several fused
+    computations prints once per link under one ``channel_id``: the
+    first stands for it."""
+    out, channels = [], set()
+    for line in hlo_text.splitlines():
+        m = _COLLECTIVE_RE.match(line)
+        if not m:
+            continue
+        channel = _CHANNEL_RE.search(line)
+        if channel:
+            if channel.group(1) in channels:
+                continue
+            channels.add(channel.group(1))
+        shapes = _parse_shapes(m.group("shape"))
+        if m.group("start") and m.group("op") != "all-reduce":
+            # (operands..., results..., [context scalars]): keep the
+            # results — the larger half for a gather, either for a
+            # permute.
+            arrays = [s for s in shapes if s[1] != [1]] or shapes
+            shapes = arrays[len(arrays) // 2:]
+        scope = _OP_NAME_RE.search(line)
+        out.append({
+            "name": m.group("name"),
+            "op": m.group("op"),
+            "kind": _COLLECTIVE_KINDS[m.group("op")],
+            "dims": [dims for _, dims in shapes],
+            "bytes": _bytes(shapes),
+            "op_name": scope.group(1) if scope else None,
+        })
+    return out
+
+
+def collective_bytes(rows: Sequence[dict]) -> Dict[str, int]:
+    """``collectives()`` rows -> ``{"all_reduce", "all_gather",
+    "other"}``: bytes one device holds of that kind's outputs in one
+    run of the module (``other``: all-to-all, collective-permute,
+    reduce-scatter, broadcast).  A data-parallel step's all-reduce is
+    its parameters' size and its all-gather a few scalars' worth; an
+    all-gather the size of the batch says some op was not partitioned
+    and every device computes it whole (ISSUE 26: the ``[T, B] ->
+    [T*B]`` merge)."""
+    totals = {"all_reduce": 0, "all_gather": 0, "other": 0}
+    for row in rows:
+        totals[row["kind"]] += row["bytes"]
+    return totals
+
+
+def write_op_scopes(trace_path: str, hlo_text: str,
+                    registry=None) -> str:
     """Leave, beside a run's span trace, the table a device trace needs
     to tell which layer an op belongs to: instruction name ->
     ``op_name`` (the ``jax.named_scope`` / flax-module path) for every
     instruction of the compiled step that carries one.  A v5e profiler
     trace names each event by its instruction (``%fusion.238 = ...``)
     and carries no ``op_name``; the join is on the instruction name.
-    Returns the path written."""
+    The same parse says what the partitioner made the step move between
+    devices: ``collective_bytes`` by kind goes to the table's ``notes``
+    (beside the largest collectives and their per-device shapes) and to
+    the ``spmd/collective_bytes/<kind>`` gauges.  Returns the path
+    written."""
+    from scalable_agent_tpu.obs.registry import get_registry
+
     ops = {}
     for line in hlo_text.splitlines():
         scope = _OP_NAME_RE.search(line)
         name = _INSTR_NAME_RE.match(line) if scope else None
         if name:
             ops[name.group(1)] = scope.group(1)
+    rows = sorted(collectives(hlo_text), key=lambda row: -row["bytes"])
+    totals = collective_bytes(rows)
+    registry = registry or get_registry()
+    for kind, value in totals.items():
+        registry.gauge(
+            f"spmd/collective_bytes/{kind}",
+            "bytes one device holds of this kind of collective's "
+            "outputs in one run of the compiled fused step").set(value)
     folder, name = os.path.split(op_scopes_path(trace_path))
     return write_kernels_json(
-        folder, {"module": hlo_module_name(hlo_text), "ops": ops},
+        folder, {"module": hlo_module_name(hlo_text), "ops": ops,
+                 "notes": {"collective_bytes": totals,
+                           "largest_collectives": rows[:8]}},
         name=name)
 
 

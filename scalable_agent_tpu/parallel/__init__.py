@@ -1,5 +1,6 @@
 from scalable_agent_tpu.parallel.mesh import (
     MeshSpec,
+    batch_shards,
     batch_sharding,
     fused_kernels_profitable,
     make_mesh,

@@ -74,6 +74,10 @@ def make_mesh(spec: Optional[MeshSpec] = None,
     return Mesh(array, axis_names=("data", "seq", "model"))
 
 
+# The mesh axes the batch dimension is cut over, outermost first.
+BATCH_AXES = ("data", "seq")
+
+
 def batch_sharding(mesh: Mesh, batch_axis_index: int = 1) -> NamedSharding:
     """Shard the batch dimension over the (data, seq) axes.
 
@@ -81,11 +85,31 @@ def batch_sharding(mesh: Mesh, batch_axis_index: int = 1) -> NamedSharding:
     The seq axis joins the batch sharding so its devices carry real
     model compute too — time-resharding happens only around the V-trace
     recurrence (parallel/sequence.py).
+
+    The rule that comes with a sharded B (ISSUE 26): **a merge of the
+    batch axis with another keeps the shard index outermost.**
+    ``[T, B] -> [T*B]`` time-major is not a tiling of the merged axis,
+    and the SPMD partitioner answers by gathering the operand and
+    computing everything downstream on every device, silently.  Merge
+    batch-major (``[B, T] -> [B*T]``) or, where a device's own rows
+    should keep their time-major layout, shard-major (``[S, T, B/S]``
+    with S = ``batch_shards`` — models/agent.py); and reduce a
+    ``[T, B]`` array over its axes as they are, never through a
+    ``ravel``.
     """
     pspec = [None] * (batch_axis_index + 1)
-    pspec[batch_axis_index] = (("data", "seq")
+    pspec[batch_axis_index] = (BATCH_AXES
                                if "seq" in mesh.shape else "data")
     return NamedSharding(mesh, PartitionSpec(*pspec))
+
+
+def batch_shards(mesh_shape) -> int:
+    """How many pieces ``batch_sharding`` cuts the batch axis in on a
+    mesh of this ``Mesh.shape`` (any mapping of axis sizes): the
+    product over ``BATCH_AXES``.  What the Learner sets
+    ``ImpalaAgent.batch_shards`` to, so that the unroll's
+    ``[T, B] -> [T*B]`` merge stays sharded."""
+    return math.prod(int(mesh_shape.get(axis, 1)) for axis in BATCH_AXES)
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:

@@ -4,9 +4,16 @@ Functional parity with the reference's ``build_learner`` (reference:
 experiment.py:346-427), re-designed for TPU:
 
 - The whole update — target-policy unroll, V-trace, losses, RMSProp — is
-  ONE jitted function over a ``('data', 'model')`` mesh.  Trajectory
-  batches are sharded over ``data``; parameters are replicated; XLA's
-  partitioner inserts the gradient all-reduce (psum over ICI).  The
+  ONE jitted function over a ``('data', 'seq', 'model')`` mesh.
+  Trajectory batches are sharded over ``data``; parameters are
+  replicated; XLA's partitioner inserts the gradient all-reduce (psum
+  over ICI) — and that all-reduce, with a few scalar metrics, is ALL
+  that crosses devices, as long as every merge of the sharded batch
+  axis keeps the shard index outermost (batch-major or shard-major,
+  never ``[T, B] -> [T*B]`` time-major: parallel/mesh.py
+  batch_sharding; the partitioner answers a time-major merge by
+  gathering the batch and computing the torso on every device, and
+  says nothing).  tests/test_data_parallel_unroll.py holds it.  The
   reference instead runs a single-GPU learner fed by a gRPC queue and
   places V-trace on the *CPU* because its sequential scan was slow on
   device (experiment.py:387-397) — here V-trace is an associative scan and
@@ -50,6 +57,7 @@ from scalable_agent_tpu.ops import impact as impact_lib
 from scalable_agent_tpu.ops import losses as losses_lib
 from scalable_agent_tpu.ops import vtrace
 from scalable_agent_tpu.parallel.mesh import (
+    batch_shards,
     batch_sharding,
     model_parallel_shardings,
     replicated_sharding,
@@ -301,6 +309,15 @@ class Learner:
         impact_clip_epsilon: float = 0.3,
         fused_forward: bool = True,
     ):
+        # The unroll's [T, B] -> [T*B] merge must keep the mesh's shard
+        # index outermost, or the partitioner replicates the torso on
+        # every device (parallel/mesh.py batch_sharding has the rule).
+        # The agent is built before any mesh exists; the mesh in hand
+        # says how the batch is cut, so the learner's copy of the agent
+        # follows it.  Parameters do not depend on it.
+        shards = batch_shards(mesh.shape)
+        if agent.batch_shards != shards:
+            agent = agent.clone(batch_shards=shards)
         self._agent = agent
         # Fused single-forward loss (default): ONE whole-trajectory
         # unroll (Learner._forward) produces both the

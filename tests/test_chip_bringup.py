@@ -19,6 +19,7 @@ Nothing here touches a TPU.  What it pins instead:
 """
 
 import dataclasses
+import math
 import os
 import re
 import subprocess
@@ -304,12 +305,14 @@ def v5e_topology():
         pytest.skip(f"cannot build a v5e topology here: {exc}")
 
 
-def _compile_default_update(monkeypatch, topology, devices, **overrides):
+def _compile_default_update(monkeypatch, topology, devices,
+                            compiled=False, **overrides):
     """Lower + compile the learner update the driver would build for
     ``devices`` v5e chips at the production shapes (T=100, B=32, 72x96
     uint8), against abstract arguments placed on the topology's
-    devices.  Returns ``(agent, lowered_text)``; compile errors (VMEM,
-    partitioning) propagate."""
+    devices.  Returns ``(agent, lowered_text)`` — the compiled
+    (partitioned, per-device) text instead with ``compiled=True``;
+    compile errors (VMEM, partitioning) propagate."""
     from scalable_agent_tpu.parallel import (
         MeshSpec,
         batch_sharding,
@@ -350,8 +353,8 @@ def _compile_default_update(monkeypatch, topology, devices, **overrides):
     lowered = learner.lower_update(
         abstract(state, replicated), trajectory,
         abstract(learner.device_telemetry, replicated))
-    lowered.compile()
-    return agent, lowered.as_text()
+    executable = lowered.compile()
+    return agent, (executable if compiled else lowered).as_text()
 
 
 class TestAotCompileForV5e:
@@ -372,6 +375,43 @@ class TestAotCompileForV5e:
         agent, text = _compile_default_update(monkeypatch, v5e_topology, 4)
         assert (agent.core_impl, agent.conv_backend) == ("xla", "xla")
         assert text.count("tpu_custom_call") == 0
+
+    def test_four_chip_update_moves_gradients_and_nothing_else(
+            self, monkeypatch, v5e_topology):
+        """ISSUE 26, on the v5e's own partitioner: the update compiled
+        for data=4 holds each device to its own 8 of the 32 envs.  No
+        instruction's per-device shape has the global merge (101 x 32 =
+        3,232 rows) as a dim, the torso runs on 808, the only gather
+        left is a [T, B] of scalars (V-trace's exact p95 sorts all of
+        it), and the all-reduce is the whole parameter set — before
+        PR 26 it was the LSTM and the heads alone, because every chip
+        had computed every torso gradient itself from an all-gathered
+        ``u8[101,32,72,96,3]``."""
+        from scalable_agent_tpu.obs import kernels as kernels_lib
+
+        _, text = _compile_default_update(
+            monkeypatch, v5e_topology, 4, compiled=True)
+        rows = kernels_lib.collectives(text)
+        gathered = [dims for row in rows if row["kind"] == "all_gather"
+                    for dims in row["dims"]]
+        assert all(math.prod(dims) <= 101 * 32 for dims in gathered), (
+            gathered)
+        totals = kernels_lib.collective_bytes(rows)
+        assert totals["other"] == 0
+        assert totals["all_reduce"] > 4e6, totals     # ~1.6M parameters
+        has_dim = lambda n: re.search(  # noqa: E731
+            r"\[(?:\d+,)*%d(?:,\d+)*\]" % n, text)
+        assert not has_dim(101 * 32)
+        assert has_dim(101 * 8)
+
+    def test_one_chip_update_is_merged_as_ever(
+            self, monkeypatch, v5e_topology):
+        """One shard: the merge is the plain time-major reshape of
+        every PR before 26 (a bitcast on the chip), so the frames are
+        never transposed and the one-chip cells' step is the parent's."""
+        _, text = _compile_default_update(monkeypatch, v5e_topology, 1)
+        assert not re.search(
+            r"stablehlo\.transpose.*x72x96x3xui8>", text)
 
     def test_float32_compiles_with_the_kernels(
             self, monkeypatch, v5e_topology):

@@ -167,6 +167,80 @@ ENTRY %main (a: f32[64,32]) -> f32[64,32] {
             2 * (8 * 16 * 16 * 16) * (5 * 5 * 3))
 
 
+_PARTITIONED_MODULE = """
+HloModule jit__fused
+
+%fused_gather.1 (param_0: f32[100,256]) -> f32[100,1024] {
+  %param_0 = f32[100,256]{1,0} parameter(0)
+  ROOT %all-gather.7 = f32[100,1024]{1,0} all-gather(%param_0), channel_id=3, replica_groups=[1,4]<=[4], dimensions={1}, metadata={op_name="jit(_fused)/telemetry/jit(quantile)/sort"}
+}
+
+%fused_gather.2 (param_0: f32[100,256]) -> f32[100,1024] {
+  %param_0 = f32[100,256]{1,0} parameter(0)
+  ROOT %all-gather.9 = f32[100,1024]{1,0} all-gather(%param_0), channel_id=3, replica_groups=[1,4]<=[4], dimensions={1}
+}
+
+ENTRY %main (p0: u8[101,256,72,96,3], p1: f32[256,9]) -> f32[256,9] {
+  %p0 = u8[101,256,72,96,3]{4,3,2,1,0} parameter(0)
+  %p1 = f32[256,9]{1,0} parameter(1)
+  %all-gather-start.1 = (u8[101,256,72,96,3]{4,3,2,1,0}, u8[101,1024,72,96,3]{4,3,2,1,0}) all-gather-start(%p0), channel_id=1, dimensions={1}, metadata={op_name="jit(_fused)/learner_update/jvp(ImpalaAgent)/reshape"}
+  %all-gather-done.1 = u8[101,1024,72,96,3]{4,3,2,1,0} all-gather-done(%all-gather-start.1)
+  %all-reduce.5 = (f32[256,9]{1,0}, f32[]) all-reduce(%p1, %c), channel_id=2, to_apply=%add
+  %collective-permute-start.2 = (f32[8,128]{1,0}, f32[8,128]{1,0}, u32[], u32[]) collective-permute-start(%x), channel_id=4, source_target_pairs={{0,1}}
+  ROOT %r = f32[256,9]{1,0} get-tuple-element(%all-reduce.5), index=0
+}
+"""
+
+
+class TestCollectives:
+    """What the partitioner made a step move between devices (ISSUE
+    26), read off compiled text: per-device result dims and bytes."""
+
+    def test_rows_and_bytes_by_kind(self):
+        rows = {row["name"]: row
+                for row in kernels_lib.collectives(_PARTITIONED_MODULE)}
+        # one transfer chained over two fused computations counts once
+        assert sorted(rows) == ["all-gather-start.1", "all-gather.7",
+                                "all-reduce.5",
+                                "collective-permute-start.2"]
+        frames = rows["all-gather-start.1"]
+        # the async pair's result lists the operand first: dropped
+        assert frames["dims"] == [[101, 1024, 72, 96, 3]]
+        assert frames["bytes"] == 101 * 1024 * 72 * 96 * 3
+        assert frames["kind"] == "all_gather"
+        assert frames["op_name"].endswith("jvp(ImpalaAgent)/reshape")
+        assert rows["all-reduce.5"]["bytes"] == 4 * (256 * 9 + 1)
+        assert rows["collective-permute-start.2"]["dims"] == [[8, 128]]
+        assert kernels_lib.collective_bytes(rows.values()) == {
+            "all_gather": frames["bytes"] + 4 * 100 * 1024,
+            "all_reduce": 4 * (256 * 9 + 1),
+            "other": 4 * 8 * 128,
+        }
+
+    def test_scope_table_carries_them_and_sets_the_gauges(self, tmp_path):
+        import json
+
+        from scalable_agent_tpu.obs import MetricsRegistry
+
+        registry = MetricsRegistry()
+        path = kernels_lib.write_op_scopes(
+            str(tmp_path / "trace.p0.7.json"), _PARTITIONED_MODULE,
+            registry=registry)
+        table = json.load(open(path))
+        assert table["ops"]["all-gather.7"].endswith("jit(quantile)/sort")
+        notes = table["notes"]
+        assert notes["largest_collectives"][0]["name"] == (
+            "all-gather-start.1")
+        gauges = registry.snapshot()
+        for kind, value in notes["collective_bytes"].items():
+            assert gauges[f"spmd/collective_bytes/{kind}"] == value > 0
+
+    def test_a_one_device_module_moves_nothing(self):
+        text = "ENTRY %main () -> f32[] {\n  ROOT %c = f32[] constant(0)\n}"
+        assert kernels_lib.collectives(text) == []
+        assert set(kernels_lib.collective_bytes([]).values()) == {0}
+
+
 class TestTraceJoin:
     def test_harvest_roundtrip(self, tmp_path, monkeypatch):
         """Profile a compiled program, harvest, and verify the
