@@ -303,8 +303,15 @@ def resolve_core_matmul_dtype(config: Config, core_impl: str) -> str:
 
 
 def resolve_remat_torso(config: Config) -> bool:
-    """"auto" = remat on TPU (where the fused single-forward update's
-    peak activation memory at B=256 is the concern), off elsewhere."""
+    """Whether the torso rematerializes in its backward pass: "auto"
+    = on a TPU (where HBM caps the batch), off elsewhere.  "On" is
+    each torso's OWN placement of the ``jax.checkpoint`` boundary
+    (models/networks.py REMAT_PLACEMENTS; the kernel-policy line names
+    it): the ResNet's stem segment, nothing behind the shallow torso's
+    Pallas stem, the whole shallow torso behind XLA's stem.  Never one
+    boundary around whichever torso: that recomputes the whole forward
+    and frees nothing at the peak, where every residual is live again
+    (ISSUE 27)."""
     if config.remat_torso not in ("auto", "on", "off"):
         raise ValueError(
             f"remat_torso must be auto, on, or off, got "
@@ -351,15 +358,7 @@ def build_agent(config: Config, action_space,
         "conv0_gradw/padded_images",
         "images past N its last grid step masks (none is ever padded "
         "in HBM)").set(padded)
-    log.info(
-        "kernel policy: backend=%s mesh_devices=%d core_impl=%s "
-        "(matmul %s) conv_backend=%s (conv0_gradw batch_tile=%d "
-        "padded_images=%d) remat_torso=%s compute_dtype=%s "
-        "pallas_interpret=%s",
-        jax.default_backend(), _intended_mesh_size(config), core_impl,
-        core_matmul_dtype, conv_backend, tile, padded, remat_torso,
-        config.compute_dtype, pallas_interpret())
-    return ImpalaAgent(
+    agent = ImpalaAgent(
         action_space=action_space,
         torso_type=config.torso_type,
         use_instruction=config.use_instruction,
@@ -369,6 +368,15 @@ def build_agent(config: Config, action_space,
         conv_backend=conv_backend,
         remat_torso=remat_torso,
     )
+    log.info(
+        "kernel policy: backend=%s mesh_devices=%d core_impl=%s "
+        "(matmul %s) conv_backend=%s (conv0_gradw batch_tile=%d "
+        "padded_images=%d) remat_torso=%s (remat=%s) compute_dtype=%s "
+        "pallas_interpret=%s",
+        jax.default_backend(), _intended_mesh_size(config), core_impl,
+        core_matmul_dtype, conv_backend, tile, padded, remat_torso,
+        agent.remat_placement, config.compute_dtype, pallas_interpret())
+    return agent
 
 
 def training_level_names(config: Config) -> List[str]:

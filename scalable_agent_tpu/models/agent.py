@@ -178,12 +178,15 @@ class ImpalaAgent(nn.Module):
     # (ops/conv_pallas.py MXU kernel).
     # Identical parameter trees — checkpoints are interchangeable.
     conv_backend: str = "xla"
-    # Rematerialize the torso in the backward pass (jax.checkpoint via
-    # nn.remat).  The fused single-forward update keeps the behaviour
-    # logits and the loss's outputs from ONE unroll; remat keeps that
-    # from costing peak activation memory at B=256.  Default OFF so
-    # the default-path jaxpr (and the golden-loss anchor) is
-    # untouched; the learner turns it on with the fused forward.
+    # Rematerialize in the torso's backward pass (jax.checkpoint),
+    # where the torso itself says it pays (models/networks.py
+    # REMAT_PLACEMENTS): the ResNet's stem segment, nothing behind
+    # the shallow torso's Pallas stem, the whole shallow torso behind
+    # XLA's stem.  Until ISSUE 27 this wrapped whichever torso whole,
+    # which recomputes the entire forward to free nothing at the peak
+    # (every residual is live again before the first backward op).
+    # Default OFF so the default-path jaxpr (and the golden-loss
+    # anchor) is untouched; the driver turns it on on a TPU.
     remat_torso: bool = False
     # How many pieces the mesh cuts the batch axis in (parallel/mesh.py
     # batch_shards: data x seq; the Learner sets it on its copy of the
@@ -218,6 +221,14 @@ class ImpalaAgent(nn.Module):
     @property
     def num_action_components(self) -> int:
         return self.dist_spec.num_components
+
+    @property
+    def remat_placement(self) -> str:
+        """Where ``remat_torso`` puts the checkpoint in THIS agent's
+        torso: one of models/networks.py REMAT_PLACEMENTS."""
+        return TORSOS[self.torso_type](
+            conv_backend=self.conv_backend, remat=self.remat_torso,
+            parent=None).remat_placement
 
     def zero_actions(self, batch: int) -> jnp.ndarray:
         """All-zeros last-action input at the agent's action layout
@@ -260,15 +271,11 @@ class ImpalaAgent(nn.Module):
                     (shards, unroll_len, per_shard) + trailing), 0, 1)
             return x.reshape((unroll_len, batch) + trailing)
 
-        torso_cls = TORSOS[self.torso_type]
-        if self.remat_torso:
-            # jax.checkpoint on the torso: activations are recomputed
-            # in the backward pass instead of living across the whole
-            # unroll+loss — what keeps the fused single-forward update
-            # flat on peak memory at B=256.
-            torso_cls = nn.remat(torso_cls)
-        torso = torso_cls(dtype=self.compute_dtype,
-                          conv_backend=self.conv_backend, name="convnet")
+        # Where (and whether) the backward recomputes is the torso's
+        # own placement, from its structure and its stem path.
+        torso = TORSOS[self.torso_type](
+            dtype=self.compute_dtype, conv_backend=self.conv_backend,
+            remat=self.remat_torso, name="convnet")
         conv_out = torso(flat(frame))  # [T*B, 256] compute_dtype
 
         clipped_reward = jnp.clip(

@@ -42,6 +42,20 @@ STEM_GEOMETRY = {
 }
 
 
+# Where a torso built with ``remat=True`` puts its ``jax.checkpoint``
+# boundary (each torso's ``remat_placement``; the driver's kernel-policy
+# line names it).  One checkpoint around a WHOLE torso recomputes the
+# entire forward before the first backward op can run, and at that
+# moment every residual of the torso is live at once, as if nothing had
+# been rematerialised: it frees only what would otherwise sit across
+# the core, the heads and the loss, for the price of a whole forward
+# (ISSUE 27: 0.50 of 10.2 GiB on the ResNet at the fused cell's batch,
+# nothing behind the Pallas stem).  So the boundary goes around the
+# segment whose residuals are large and cheap to rebuild, and each
+# torso knows its own.
+REMAT_PLACEMENTS = ("none", "stem", "torso")
+
+
 def _normalize_frame(frame, dtype):
     """uint8 HWC frame -> [0, 1] float.  (reference: experiment.py:153-155)"""
     return jnp.asarray(frame, dtype) / 255.0
@@ -193,14 +207,41 @@ class ShallowConvTorso(nn.Module):
     input needs no gradient (see _SpaceToDepthFirstConv for the
     measurement story).  Output dtype is ``dtype`` — the caller owns
     any upcast (the agent's heads return f32 logits/baseline).
+
+    ``remat`` (the agent's ``remat_torso``): behind the Pallas stem
+    there is nothing worth rebuilding — its VJP keeps the uint8 frame,
+    not a float copy, and the step's peak is the grad-W kernel's
+    operand late in the backward, when the saved activations are
+    already gone — so no checkpoint is placed.  XLA's stem VJP keeps
+    the normalised float frames (the largest residual by far), and the
+    only boundary found that frees them within the peak-memory bound
+    is the one around the whole torso (ISSUE 27: +16% live bytes
+    around ``conv_0`` alone, +67% with none), so that path keeps it.
     """
 
     dtype: Any = jnp.float32
     space_to_depth: bool = False
     conv_backend: str = "xla"
+    remat: bool = False
+
+    @property
+    def remat_placement(self) -> str:
+        """One of REMAT_PLACEMENTS: where ``remat`` puts the boundary."""
+        if not self.remat or _stem_backend(self.conv_backend):
+            return "none"
+        return "torso"
 
     @nn.compact
     def __call__(self, frame):
+        forward = ShallowConvTorso._forward
+        if self.remat_placement == "torso":
+            # Lifted over a function of THIS module, not a child: the
+            # parameter paths (convnet/conv_0/...) do not move.
+            forward = nn.remat(forward)
+        return forward(self, frame)
+
+    @nn.nowrap  # no scope or capture of its own when called directly
+    def _forward(self, frame):
         pallas_stem = _stem_backend(self.conv_backend)
         x = _normalize_frame(frame, self.dtype)
         for i, (num_ch, filter_size, stride) in enumerate(
@@ -262,26 +303,53 @@ class ResNetTorso(nn.Module):
     3-channel 72x96 frames pads 3 lanes to 128 and does not fit VMEM
     (ops/conv_pallas.gradw_batch_tile), so the driver's ``auto`` keeps
     this stem on XLA and an explicit ``pallas`` is refused there.
+
+    ``remat`` (the agent's ``remat_torso``) checkpoints the stem
+    segment alone: frame -> ``_normalize_frame`` -> ``downscale_0`` ->
+    ``max_pool``.  The stem conv's full-resolution output is the one
+    residual worth rebuilding (a quarter of the torso's saved bytes
+    for one 3-channel conv; the pool's backward reads it, the pool's
+    own output is not needed again and is dropped), so the backward
+    recomputes one convolution of fifteen and ``residual_*``,
+    ``downscale_1/2`` and ``fc`` keep their activations.
     """
 
     dtype: Any = jnp.float32
     conv_backend: str = "xla"
+    remat: bool = False
+
+    @property
+    def remat_placement(self) -> str:
+        """One of REMAT_PLACEMENTS: where ``remat`` puts the boundary."""
+        return "stem" if self.remat else "none"
+
+    @nn.nowrap  # no scope or capture of its own when called directly
+    def _stem(self, frame):
+        if _stem_backend(self.conv_backend):
+            x = PallasStemConv(*STEM_GEOMETRY["resnet"], dtype=self.dtype,
+                               normalize=functools.partial(
+                                   _normalize_frame, dtype=self.dtype),
+                               name="downscale_0")(frame)
+        else:
+            x = nn.Conv(STEM_GEOMETRY["resnet"][0], (3, 3), padding="SAME",
+                        dtype=self.dtype, name="downscale_0")(
+                            _normalize_frame(frame, self.dtype))
+        return nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
 
     @nn.compact
     def __call__(self, frame):
-        pallas_stem = _stem_backend(self.conv_backend)
-        x = _normalize_frame(frame, self.dtype)
+        stem = ResNetTorso._stem
+        if self.remat_placement == "stem":
+            # Lifted over a function of THIS module, not a child: the
+            # parameter paths (convnet/downscale_0/...) do not move.
+            stem = nn.remat(stem)
         for i, (num_ch, num_blocks) in enumerate([(16, 2), (32, 2), (32, 2)]):
-            if i == 0 and pallas_stem:
-                x = PallasStemConv(*STEM_GEOMETRY["resnet"],
-                                   dtype=self.dtype,
-                                   normalize=functools.partial(
-                                       _normalize_frame, dtype=self.dtype),
-                                   name="downscale_0")(frame)
+            if i == 0:
+                x = stem(self, frame)
             else:
                 x = nn.Conv(num_ch, (3, 3), padding="SAME",
                             dtype=self.dtype, name=f"downscale_{i}")(x)
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+                x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
             for j in range(num_blocks):
                 x = _ResidualBlock(num_ch, dtype=self.dtype,
                                    name=f"residual_{i}_{j}")(x)

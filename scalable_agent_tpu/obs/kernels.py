@@ -65,6 +65,7 @@ __all__ = [
     "parse_hlo_kernel_costs",
     "primary_kernel_names",
     "publish_kernel_metrics",
+    "rematerialized",
     "scan_kernel_series",
     "write_kernels_json",
     "write_op_scopes",
@@ -477,6 +478,55 @@ def collective_bytes(rows: Sequence[dict]) -> Dict[str, int]:
     return totals
 
 
+_OPCODE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?:\(.*?\)|\S+)\s+"
+    r"(?P<op>[\w\-]+)\(")
+_FUSED_BODY_RE = re.compile(r"\sfusion\(.*\scalls=%?([\w.\-]+)")
+# How many recomputed instructions the notes name (the count is whole).
+REMAT_NAMES_MAX = 24
+
+
+def rematerialized(hlo_text: str) -> dict:
+    """What a compiled step computes a second time in its backward
+    pass, read off its text: ``jax.checkpoint`` marks the ops inside
+    its boundary with a ``checkpoint`` component in their ``op_name``
+    path, and the forward ops it runs AGAIN for the backward with
+    ``checkpoint/rematted_computation``.  Returns ``{"instructions":
+    recomputed instructions a device trace names (those not inside a
+    fusion's body), "convolutions": recomputed ``convolution`` ops
+    wherever they sit, "names": {instruction: op_name} of the first
+    REMAT_NAMES_MAX of the former, those that hold a convolution
+    first, "checkpointed_instructions": every instruction inside a
+    boundary, recomputed or not}`` — so a reader of a traced run sees
+    what is recomputed without matching twin ops by hand (ISSUE 27:
+    one boundary around a whole torso recomputed 16 convolutions a
+    step of the ResNet's update; around its stem segment, one)."""
+    fused_bodies = set(_FUSED_BODY_RE.findall(hlo_text))
+    named, convolutions, checkpointed, in_fusion = [], 0, 0, False
+    for line in hlo_text.splitlines():
+        computation = _COMPUTATION_RE.match(line)
+        if computation:
+            in_fusion = computation.group("name") in fused_bodies
+            continue
+        scope = _OP_NAME_RE.search(line)
+        instr = _OPCODE_RE.match(line) if scope else None
+        if not instr:
+            continue
+        path = scope.group(1).split("/")
+        if "checkpoint" not in path:
+            continue
+        checkpointed += 1
+        if "rematted_computation" not in path:
+            continue
+        convolutions += instr.group("op") == "convolution"
+        if not in_fusion:
+            named.append((instr.group("name"), scope.group(1)))
+    named.sort(key=lambda row: "conv_general_dilated" not in row[1])
+    return {"instructions": len(named), "convolutions": convolutions,
+            "names": dict(named[:REMAT_NAMES_MAX]),
+            "checkpointed_instructions": checkpointed}
+
+
 def write_op_scopes(trace_path: str, hlo_text: str,
                     registry=None) -> str:
     """Leave, beside a run's span trace, the table a device trace needs
@@ -488,8 +538,9 @@ def write_op_scopes(trace_path: str, hlo_text: str,
     The same parse says what the partitioner made the step move between
     devices: ``collective_bytes`` by kind goes to the table's ``notes``
     (beside the largest collectives and their per-device shapes) and to
-    the ``spmd/collective_bytes/<kind>`` gauges.  Returns the path
-    written."""
+    the ``spmd/collective_bytes/<kind>`` gauges; and what the step
+    recomputes under ``jax.checkpoint`` (``rematerialized``), to the
+    notes too.  Returns the path written."""
     from scalable_agent_tpu.obs.registry import get_registry
 
     ops = {}
@@ -510,7 +561,8 @@ def write_op_scopes(trace_path: str, hlo_text: str,
     return write_kernels_json(
         folder, {"module": hlo_module_name(hlo_text), "ops": ops,
                  "notes": {"collective_bytes": totals,
-                           "largest_collectives": rows[:8]}},
+                           "largest_collectives": rows[:8],
+                           "rematerialized": rematerialized(hlo_text)}},
         name=name)
 
 

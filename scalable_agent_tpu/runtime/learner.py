@@ -238,9 +238,11 @@ def learning_telemetry_spec(loss: str = "vtrace") -> DeviceTelemetry:
     return spec
 
 
-def _torso_filter(mdl, _method_name) -> bool:
-    """flax capture_intermediates filter: only the conv torso output."""
-    return mdl.name == "convnet"
+def _torso_filter(mdl, method_name) -> bool:
+    """flax capture_intermediates filter: only the conv torso output
+    (not the segment a torso rematerializes, which flax calls as a
+    method of the same module)."""
+    return mdl.name == "convnet" and method_name == "__call__"
 
 
 def _dead_unit_fraction(captured) -> jax.Array:

@@ -353,3 +353,64 @@ class TestPallasCore:
                 jnp.zeros((4, 16), jnp.float32),
                 jnp.zeros((16,), jnp.float32),
                 True, "int8")
+
+
+# (torso_type, conv_backend) -> where remat_torso puts the checkpoint
+REMAT_PLACEMENTS = {
+    ("resnet", "xla"): "stem",
+    ("shallow", "xla"): "torso",
+    ("shallow", "pallas"): "none",
+}
+
+
+@pytest.mark.parametrize("case", sorted(REMAT_PLACEMENTS), ids="-".join)
+class TestRematTorso:
+    """``remat_torso`` is handed to the torso, which places the
+    checkpoint (ISSUE 27): same outputs, same gradients, same
+    parameter tree — a checkpoint written with either value restores
+    into the other."""
+
+    def _agents(self, case):
+        torso_type, conv_backend = case
+        return [ImpalaAgent(num_actions=NUM_ACTIONS, torso_type=torso_type,
+                            conv_backend=conv_backend, remat_torso=remat)
+                for remat in (False, True)]
+
+    def _inputs(self):
+        rng = np.random.default_rng(3)
+        done = rng.random((4, 2)) < 0.3
+        return (rng.integers(0, NUM_ACTIONS, (4, 2)).astype(np.int32),
+                make_env_outputs(rng, 4, 2, done=done), initial_state(2))
+
+    def test_placement_and_parameter_tree(self, case):
+        plain, remat = self._agents(case)
+        assert plain.remat_placement == "none"
+        assert remat.remat_placement == REMAT_PLACEMENTS[case]
+        assert remat.remat_torso is True     # the probes read a bool
+        trees = [
+            {jax.tree_util.keystr(path): (leaf.shape, leaf.dtype)
+             for path, leaf in jax.tree_util.tree_leaves_with_path(
+                 agent.init(jax.random.key(0), *self._inputs()))}
+            for agent in (plain, remat)]
+        assert trees[0] == trees[1]
+        stem = "downscale_0" if case[0] == "resnet" else "conv_0"
+        assert f"['params']['convnet']['{stem}']['kernel']" in trees[1]
+
+    def test_outputs_and_gradients_equal_to_the_bit(self, case):
+        plain, remat = self._agents(case)
+        inputs = self._inputs()
+        params = plain.init(jax.random.key(0), *inputs)
+
+        def loss(agent):
+            def fn(p):
+                (logits, baseline), state = agent.apply(p, *inputs)
+                return (jnp.sum(logits ** 2) + jnp.sum(baseline ** 2)
+                        + jnp.sum(state.h))
+            return fn
+
+        jax.tree_util.tree_map(
+            np.testing.assert_array_equal,
+            plain.apply(params, *inputs), remat.apply(params, *inputs))
+        jax.tree_util.tree_map(
+            np.testing.assert_array_equal,
+            jax.grad(loss(plain))(params), jax.grad(loss(remat))(params))

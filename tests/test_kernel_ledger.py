@@ -241,6 +241,81 @@ class TestCollectives:
         assert set(kernels_lib.collective_bytes([]).values()) == {0}
 
 
+# Condensed from the compiled step of ``deep.ingraph`` (benchmark/aot.py
+# for a v5e, ISSUE 27): the ResNet's stem conv as the forward computes
+# it, and again in the backward under the stem segment's checkpoint;
+# the pool's backward sits inside the boundary and is not recomputed.
+_REMAT_UPDATE = ("jit(_fused)/while/body/closed_call/learner_update/"
+                 "transpose(jvp(ImpalaAgent))/convnet/learner_update/"
+                 "jvp(ImpalaAgent)/convnet/checkpoint")
+_REMAT_MODULE = """
+HloModule jit__fused
+
+%fused_computation.232 (param_0: bf16[12928,72,96,3], param_1: bf16[3,3,3,16]) -> bf16[12928,72,96,16] {
+  %param_0 = bf16[12928,72,96,3]{0,3,2,1} parameter(0)
+  %param_1 = bf16[3,3,3,16]{3,2,1,0} parameter(1)
+  ROOT %conv_general_dilated.289 = bf16[12928,72,96,16]{0,3,2,1} convolution(%param_0, %param_1), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f, metadata={op_name="jit(_fused)/while/body/closed_call/learner_update/jvp(ImpalaAgent)/convnet/convnet._stem/downscale_0/conv_general_dilated" stack_frame_id=4}
+}
+
+%fused_computation.214 (param_0: u8[12928,72,96,3], param_1: bf16[3,3,3,16]) -> bf16[12928,72,96,16] {
+  %param_0 = u8[12928,72,96,3]{0,2,3,1} parameter(0)
+  %convert_multiply_fusion.2 = bf16[12928,72,96,3]{0,3,2,1} fusion(%param_0), kind=kLoop, calls=%fused_computation.234, metadata={op_name="REMAT/rematted_computation/convnet._stem/div" stack_frame_id=486}
+  %param_1 = bf16[3,3,3,16]{3,2,1,0} parameter(1)
+  ROOT %conv_general_dilated.287 = bf16[12928,72,96,16]{0,3,2,1} convolution(%convert_multiply_fusion.2, %param_1), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f, metadata={op_name="REMAT/rematted_computation/convnet._stem/downscale_0/conv_general_dilated" stack_frame_id=486}
+}
+
+ENTRY %main (p0: u8[12928,72,96,3], p1: bf16[3,3,3,16], p2: bf16[12928,36,48,16]) -> bf16[12928,72,96,16] {
+  %p0 = u8[12928,72,96,3]{0,2,3,1} parameter(0)
+  %p1 = bf16[3,3,3,16]{3,2,1,0} parameter(1)
+  %p2 = bf16[12928,36,48,16]{0,3,2,1} parameter(2)
+  %fusion.173 = bf16[12928,72,96,3]{0,3,2,1} fusion(%p0), kind=kLoop, calls=%fused_computation.235, metadata={op_name="jit(_fused)/while/body/closed_call/learner_update/jvp(ImpalaAgent)/convnet/convnet._stem/div"}
+  %fusion.172 = bf16[12928,72,96,16]{0,3,2,1} fusion(%fusion.173, %p1), kind=kOutput, calls=%fused_computation.232, metadata={op_name="jit(_fused)/while/body/closed_call/learner_update/jvp(ImpalaAgent)/convnet/convnet._stem/downscale_0/conv_general_dilated" stack_frame_id=4}
+  %convert_element_type.119 = bf16[3,3,3,16]{3,2,1,0} convert(%p1), metadata={op_name="REMAT/rematted_computation/convnet._stem/downscale_0/convert_element_type" stack_frame_id=486}
+  %convolution_add_fusion.1 = bf16[12928,72,96,16]{0,3,2,1} fusion(%p0, %convert_element_type.119), kind=kOutput, calls=%fused_computation.214, metadata={op_name="REMAT/rematted_computation/convnet._stem/downscale_0/conv_general_dilated" stack_frame_id=486}
+  ROOT %select-and-scatter.5 = bf16[12928,72,96,16]{0,3,2,1} select-and-scatter(%convolution_add_fusion.1, %p2, %c), window={size=3x3 stride=2x2 pad=0_1x0_1}, select=%ge, scatter=%add, metadata={op_name="REMAT/convnet._stem/select_and_scatter_add" stack_frame_id=486}
+}
+""".replace("REMAT", _REMAT_UPDATE)
+
+
+class TestRematerialized:
+    """What a step recomputes under ``jax.checkpoint`` (ISSUE 27), read
+    off compiled text by the ``checkpoint`` / ``rematted_computation``
+    components of an instruction's ``op_name`` path."""
+
+    def test_counts_and_names_the_recomputed_instructions(self):
+        found = kernels_lib.rematerialized(_REMAT_MODULE)
+        # the forward's own conv (fusion.172) is computed once: not named
+        assert list(found["names"]) == ["convolution_add_fusion.1",
+                                        "convert_element_type.119"]
+        assert found["names"]["convolution_add_fusion.1"].endswith(
+            "rematted_computation/convnet._stem/downscale_0/"
+            "conv_general_dilated")
+        # instructions a trace names; a fusion's body is not among them
+        assert found["instructions"] == 2
+        assert found["convolutions"] == 1
+        # + the two inside the recomputed fusion's body and the pool's
+        # backward, which is inside the boundary and computed once
+        assert found["checkpointed_instructions"] == 5
+
+    def test_a_step_with_no_checkpoint_recomputes_nothing(self):
+        assert kernels_lib.rematerialized(_PARTITIONED_MODULE) == {
+            "instructions": 0, "convolutions": 0, "names": {},
+            "checkpointed_instructions": 0}
+
+    def test_scope_table_notes_carry_it(self, tmp_path):
+        import json
+
+        from scalable_agent_tpu.obs import MetricsRegistry
+
+        path = kernels_lib.write_op_scopes(
+            str(tmp_path / "trace.p0.7.json"), _REMAT_MODULE,
+            registry=MetricsRegistry())
+        notes = json.load(open(path))["notes"]
+        assert notes["rematerialized"] == kernels_lib.rematerialized(
+            _REMAT_MODULE)
+        assert notes["rematerialized"]["convolutions"] == 1
+
+
 class TestTraceJoin:
     def test_harvest_roundtrip(self, tmp_path, monkeypatch):
         """Profile a compiled program, harvest, and verify the

@@ -45,6 +45,41 @@ def _conv_count(learner, state, traj):
     return str(jaxpr).count("conv_general_dilated")
 
 
+class TestRematPlacement:
+    """Where the update's gradient recomputes (ISSUE 27): each torso
+    places its own checkpoint.  One boundary around the whole ResNet
+    recomputed all 15 forward convolutions to free nothing at the
+    peak; around the stem segment it is one."""
+
+    @pytest.mark.parametrize("agent_kwargs,convs,recomputed", [
+        # 15 forward + 14 input-gradient + 15 weight-gradient
+        (dict(torso_type="resnet"), 44, 1),
+        # 3 + 2 + 3; XLA's stem keeps the old whole-torso boundary
+        (dict(torso_type="shallow", conv_backend="xla"), 8, 3),
+        # the stem's weight gradient is the Pallas kernel; nothing
+        # behind it is worth rebuilding
+        (dict(torso_type="shallow", conv_backend="pallas"), 7, 0),
+    ], ids=("resnet", "shallow-xla", "shallow-pallas"))
+    def test_the_gradient_recomputes_what_the_torso_placed(
+            self, agent_kwargs, convs, recomputed):
+        counts = {
+            remat: _conv_count(*_make(True, remat_torso=remat,
+                                      **agent_kwargs))
+            for remat in (False, True)}
+        assert counts == {False: convs, True: convs + recomputed}
+
+    @pytest.mark.parametrize("torso_type", ("shallow", "resnet"))
+    def test_the_default_program_holds_no_checkpoint(self, torso_type):
+        """``remat_torso`` is off by default: the golden-loss anchor's
+        program is untouched."""
+        learner, state, traj = _make(True, torso_type=torso_type)
+        assert learner._agent.remat_torso is False
+        text = str(jax.make_jaxpr(
+            lambda p: jax.grad(lambda q: learner._loss(q, traj)[0])(p)
+        )(state.params))
+        assert "remat2[" not in text  # jax.checkpoint's primitive
+
+
 class TestSingleForward:
     def test_fused_lowers_fewer_convs(self):
         """The unfused program runs one extra stop-gradiented unroll

@@ -74,3 +74,75 @@ class TestSpaceToDepthEquivalence:
                         jax.tree_util.tree_leaves(g_direct)):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
+
+
+# -- where a torso rematerializes (ISSUE 27) ---------------------------------
+
+# (torso, stem path) -> where ``remat=True`` puts the checkpoint
+REMAT_CASES = {
+    ("resnet", "xla"): "stem",
+    ("shallow", "xla"): "torso",
+    ("shallow", "pallas"): "none",
+}
+
+
+def _torso(case, remat):
+    from scalable_agent_tpu.models.networks import TORSOS
+
+    torso_type, conv_backend = case
+    return TORSOS[torso_type](conv_backend=conv_backend, remat=remat)
+
+
+def _torso_loss(torso, frames):
+    return lambda params: jnp.sum(torso.apply(params, frames) ** 2)
+
+
+@pytest.mark.parametrize("case", sorted(REMAT_CASES), ids="-".join)
+class TestRematPlacement:
+    """``remat`` changes WHEN a value is computed and where the
+    boundary sits — each torso's own choice — never a value, a
+    parameter path or a shape."""
+
+    def test_outputs_and_gradients_equal_to_the_bit(self, case):
+        frames = _frames((5, 24, 32, 3))
+        plain, remat = _torso(case, False), _torso(case, True)
+        params = plain.init(jax.random.key(0), frames)
+        np.testing.assert_array_equal(plain.apply(params, frames),
+                                      remat.apply(params, frames))
+        g_plain = jax.grad(_torso_loss(plain, frames))(params)
+        g_remat = jax.grad(_torso_loss(remat, frames))(params)
+        assert (jax.tree_util.tree_structure(g_plain)
+                == jax.tree_util.tree_structure(g_remat))
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               g_plain, g_remat)
+
+    def test_parameter_tree_is_identical(self, case):
+        frames = _frames((2, 24, 32, 3))
+        shapes = [
+            {jax.tree_util.keystr(path): (leaf.shape, leaf.dtype)
+             for path, leaf in jax.tree_util.tree_leaves_with_path(
+                 _torso(case, remat).init(jax.random.key(0), frames))}
+            for remat in (False, True)]
+        assert shapes[0] == shapes[1]
+        stem = "downscale_0" if case[0] == "resnet" else "conv_0"
+        assert f"['params']['{stem}']['kernel']" in shapes[1]
+
+    def test_the_boundary_is_where_the_torso_says(self, case):
+        """The conv counts of the whole update's gradient are pinned in
+        tests/test_learner_fused.py; here, that a checkpoint exists
+        exactly where the torso says it placed one."""
+        from scalable_agent_tpu.models.networks import REMAT_PLACEMENTS
+
+        placement = REMAT_CASES[case]
+        assert placement in REMAT_PLACEMENTS
+        frames = _frames((2, 24, 32, 3))
+        assert _torso(case, False).remat_placement == "none"
+        assert _torso(case, True).remat_placement == placement
+        for remat in (False, True):
+            torso = _torso(case, remat)
+            params = torso.init(jax.random.key(0), frames)
+            text = str(jax.make_jaxpr(
+                jax.grad(_torso_loss(torso, frames)))(params))
+            # jax.checkpoint's primitive prints as ``remat2``
+            assert ("remat2[" in text) == (
+                remat and placement != "none")
