@@ -283,10 +283,6 @@ class Config:
     health_max_windows: int = 2
     # Updates one anomaly-triggered profiling window spans.
     health_window_updates: int = 5
-    # Prime detectors from the newest committed BENCH_r*.json so a run
-    # that STARTS slower than the last proving round trips immediately:
-    # '' = off, 'auto' = the repo's committed rounds, else a directory.
-    health_baseline_dir: str = ""
     # -- self-healing (docs/robustness.md) --------------------------------
     # Non-finite guard: a NaN/Inf loss or gradient makes the update a
     # no-op (params/opt_state held, frames still retired) and counts in
@@ -436,8 +432,8 @@ class Config:
         field) into a Config — the ONE parser shared by the driver and
         the elastic supervisor entry points, so their flag surfaces can
         never drift.  ``description`` is what ``--help`` prints above
-        the option list (the driver passes its module docstring — the
-        curated flag reference)."""
+        the option list (the driver passes its module docstring); what
+        each flag is for stands in the comment above its field."""
         import argparse
 
         parser = argparse.ArgumentParser(

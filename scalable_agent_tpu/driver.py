@@ -2,89 +2,19 @@
 
 The role of the reference's ``experiment.py`` driver (reference:
 experiment.py:479-733) without its TF1 machinery: no sessions, no in-graph
-queues — a host loop wiring ActorPool → device prefetch → Learner, with
-checkpointing, metrics, and DMLab-30 scoring.
+queues.  ``train`` is one loop (``_run``) over a backend — host-stepped
+simulators behind ActorPool -> device prefetch -> Learner, or a device
+world fused with the update into one program — with checkpointing,
+metrics, and DMLab-30 scoring; ``test`` evaluates a checkpoint.
 
 Run:
     python -m scalable_agent_tpu.driver --mode=train \
         --level_name=fake_benchmark --total_environment_frames=100000
     python -m scalable_agent_tpu.driver --mode=test --logdir=...
 
-Actor runtime flags (docs/performance.md, "Continuous-batching actor
-service"):
-    --actor=grouped|service
-        ``grouped`` (default) is the lockstep ActorPool: one thread per
-        env group, the slowest env worker gates its whole group each
-        step.  ``service`` is the continuous-batching actor service
-        (runtime/service.py): env workers stream observations out the
-        moment they finish, ONE inference thread batches whatever
-        arrived (bucketed shapes, device-resident LSTM state slab), and
-        per-env trajectory packers feed the same queue/transport — no
-        per-step group barrier.
-    --service_max_batch=N
-        Largest service device batch (envs per inference call); 0 =
-        auto (all of this process's envs).
-
-Transport flags (docs/performance.md, "The trajectory transport"):
-    --transport=packed|per_leaf
-        How host trajectory batches reach the mesh.  ``packed`` (the
-        default) flattens every Trajectory leaf into one contiguous,
-        dtype-segmented, 128-byte-aligned staging buffer — a single H2D
-        copy per batch — and restores the pytree with a jitted on-device
-        unpack; ``per_leaf`` is the seed path (one device_put per leaf),
-        preserved bit-for-bit for golden comparisons.
-    --inflight_updates=W
-        Bounded in-flight dispatch window: the update loop keeps up to W
-        updates dispatched-but-unmaterialized and blocks only when the
-        window is full, so batch k+1's pack/upload overlaps update k on
-        the device.  2 (the default) pipelines one update deep with
-        exact FIFO metrics accounting; 1 forces strict per-update
-        lock-step (debugging, not throughput).
-
-Self-healing flags (docs/robustness.md):
-    --nonfinite_tolerance=N   consecutive non-finite (skipped) updates
-        before rolling back to the last verified checkpoint; with
-        --no_rollback the run exits 71 instead.
-    --actor_max_restarts=K    bounded actor-thread respawn budget with
-        capped exponential backoff.
-    --chaos_spec='point@i[:j...];...'   deterministic fault injection
-        (runtime/faults.py) for chaos testing the recovery paths; also
-        accepts 'point@t=30s' (time trigger) and 'point@p=0.01'
-        (seeded per-evaluation probability) entries.
-    --chaos_channel           tail <logdir>/chaos_inject.jsonl for
-        runtime-injected one-shot faults — the chaos soak engine's
-        (runtime/soak.py) injection path into an already-running run.
-
-Compile cache (utils/compile_cache.py): JAX's persistent compilation
-cache is always armed — at $JAX_COMPILATION_CACHE_DIR when set
-(children inherit it), else at <repo>/.jax_cache — so a relaunch or
-restart of the same program compiles from disk, which is what keeps
-elastic-reshard MTTR flat (docs/robustness.md).
-
-Fleet fault-domain flags (runtime/fleet.py, docs/robustness.md):
-    --peer_timeout_s=T        multi-process peer heartbeat deadline: a
-        peer silent for T seconds triggers forensics + exit 72 in every
-        survivor instead of an unbounded collective hang.
-    --preemption_grace_s=G    SIGTERM raises a fleet-wide preemption
-        flag; all processes drain and take ONE coordinated final
-        checkpoint within G seconds, then exit 0 (frame-exact resume).
-        0 restores the legacy dump-and-exit(143).
-    --collective_timeout_s=C  deadline on each blocking cross-process
-        point (0 = auto); --coordinator_init_timeout_s bounds the
-        initialize retry loop.
-
-Elastic membership flags (runtime/elastic.py, docs/robustness.md):
-    --elastic                 supervisor mode: own N worker processes,
-        convert a fleet-fatal (exit 72) or preemption into a RESHARD —
-        relaunch the survivors as an (N-1)-process fleet resuming from
-        the newest verified checkpoint — and scale back to N when the
-        lost slot rejoins (graceful drain at a checkpoint boundary).
-        Equivalent: python -m scalable_agent_tpu.runtime.elastic.
-    --elastic_restart_budget / --elastic_stable_s   consecutive-restart
-        cap with capped backoff; the budget resets once an epoch stays
-        up elastic_stable_s.
-    --elastic_rejoin_delay_s  how long a lost slot stays out before it
-        may rejoin (touch <logdir>/rejoin.<slot> to force it early).
+Every flag is a field of ``Config`` (config.py), documented where it is
+declared; docs/performance.md, docs/robustness.md and
+docs/observability.md say what each group is for.
 """
 
 import dataclasses
@@ -131,8 +61,13 @@ from scalable_agent_tpu.obs import (
     install_crash_handlers,
 )
 from scalable_agent_tpu.parallel import MeshSpec, make_mesh
+from scalable_agent_tpu.parallel.distributed import (
+    initialize_distributed,
+    is_coordinator,
+)
 from scalable_agent_tpu.runtime import (
     ActorPool,
+    InGraphTrainer,
     InflightWindow,
     Learner,
     LearnerHyperparams,
@@ -141,6 +76,7 @@ from scalable_agent_tpu.runtime import (
     Trajectory,
     configure_faults,
     configure_fleet,
+    get_fleet,
 )
 from scalable_agent_tpu.runtime.checkpoint import CheckpointManager
 from scalable_agent_tpu.runtime.exit_codes import (
@@ -958,8 +894,7 @@ def _teardown_observability(config: Config, handles: _ObsHandles):
 class _HealthPlane:
     """Driver-side state of the run-health plane (obs/health.py): the
     ``HealthMonitor`` plus the single in-flight anomaly-triggered
-    profiling window, shared by BOTH backends so their wiring cannot
-    drift.  The monitor arbitrates (budget, cooldown, one window at a
+    profiling window.  The monitor arbitrates (budget, cooldown, one window at a
     time); this class owns the jax.profiler start/stop and the
     ``_harvest_kernel_ledger`` call against the window's own trace dir
     and ``kernels.<anomaly_id>.json`` name.  Inert (every method a
@@ -989,17 +924,6 @@ class _HealthPlane:
             registry=get_registry(),
             cooldown_s=config.health_cooldown_s,
             max_windows=config.health_max_windows)
-        if config.health_baseline_dir:
-            bench_dir = (None if config.health_baseline_dir == "auto"
-                         else config.health_baseline_dir)
-            try:
-                source = self.monitor.prime_from_bench(bench_dir)
-            except Exception:
-                log.exception("health baseline priming failed")
-                source = None
-            if source:
-                log.info("health detectors primed from committed "
-                         "round %s", source)
 
     @property
     def active(self) -> bool:
@@ -1048,8 +972,9 @@ class _HealthPlane:
                  "%d (%s)", anomaly_id, self.window_stop_at, trace_dir)
         return True
 
-    def close_window(self, lower_fn, executions: Optional[int] = None):
-        """Stop the window's trace and harvest its kernel ledger into
+    def close_window(self, lower_fn, executions: int):
+        """Stop the window's trace and harvest its kernel ledger
+        (``executions`` updates ran in it) into
         ``kernels.<anomaly_id>.json``, finalizing the anomaly record
         with the worst-kernel delta vs the run's baseline window."""
         if self.monitor is None or not self.window_open:
@@ -1065,9 +990,7 @@ class _HealthPlane:
                              args={"id": anomaly_id, "state": "closed"})
         out_name = f"kernels.{anomaly_id}.json"
         table = _harvest_kernel_ledger(
-            self._config, lower_fn,
-            executions=(executions if executions is not None
-                        else self._config.health_window_updates),
+            self._config, lower_fn, executions=executions,
             profile_dir=trace_dir, out_name=out_name)
         self.monitor.note_window_result(
             anomaly_id, table,
@@ -1179,18 +1102,6 @@ def _rollback_or_exit(config: Config, ckpt: CheckpointManager,
     return state, step, frames
 
 
-def _arm_faults(config: Config):
-    """Arm the chaos injector for this run: the --chaos_spec triggers
-    plus, under --chaos_channel, the <logdir>/chaos_inject.jsonl
-    runtime channel (the soak engine's injection path)."""
-    configure_faults(
-        config.chaos_spec,
-        channel_path=(os.path.join(config.logdir, CHANNEL_NAME)
-                      if config.chaos_channel else None),
-        seed=config.seed,
-        process_id=max(0, config.distributed_process_id))
-
-
 def _write_mttr_breakdown(config: Config, stages: _SetupStages):
     """Publish this process's startup-cost segments for the elastic
     supervisor's MTTR decomposition (runtime/elastic.py reads the file
@@ -1217,839 +1128,6 @@ def _write_mttr_breakdown(config: Config, stages: _SetupStages):
         os.replace(tmp, path)
     except OSError:
         log.exception("mttr breakdown write failed (non-fatal)")
-
-
-def train(config: Config,
-          t_entry_ns: Optional[int] = None) -> Dict[str, float]:
-    """Train until total_environment_frames.  Returns final metrics.
-
-    ``t_entry_ns``: when the caller's work for this run began on the
-    ``time.perf_counter_ns`` clock (``main``'s first line) — where the
-    run's timeline starts; now when not given.
-
-    Multi-host: run the SAME command on every host with
-    --distributed_coordinator/--distributed_num_processes/
-    --distributed_process_id set (or JAX_* env vars).  Every process
-    runs its own actor pool contributing 1/P of each global batch; the
-    learner update is one SPMD program over the global device mesh
-    (parallel/distributed.py; role of the reference's learner+actor
-    jobs, experiment.py:497-512)."""
-    stages = _open_timeline(config, t_entry_ns)
-    try:
-        if config.train_backend == "ingraph":
-            return train_ingraph(config, stages)
-        if config.train_backend != "host":
-            raise ValueError(
-                f"unknown train_backend {config.train_backend!r} "
-                f"(host | ingraph)")
-        return _train_host(config, stages)
-    finally:
-        # However it ended: close the stage a failed set-up left open,
-        # and the trace where a raise before the ``try`` that owns
-        # ``_teardown_observability`` left it open.
-        stages.done()
-        if config.trace:
-            configure_tracer(None)
-
-
-def _train_host(config: Config, stages: _SetupStages) -> Dict[str, float]:
-    """``train`` on the host backend (env workers -> actors -> learner)."""
-    from scalable_agent_tpu.parallel.distributed import (
-        initialize_distributed,
-        is_coordinator,
-    )
-
-    stages.enter("setup/distributed_init")
-    initialize_distributed(
-        config.distributed_coordinator or None,
-        config.distributed_num_processes or None,
-        config.distributed_process_id
-        if config.distributed_process_id >= 0 else None,
-        init_timeout_s=config.coordinator_init_timeout_s)
-    _attach_trace_file(config)
-
-    stages.enter("setup/compile_cache")
-    config = apply_env_overrides(config)
-    if is_coordinator():
-        config.save()
-    setup_compile_cache()
-    # Chaos harness: arm the deterministic fault-injection points and
-    # (under --chaos_channel) the runtime injection channel (no-op with
-    # neither configured); disarmed again in the finally so one run's
-    # spec can't leak into the next in-process run.
-    _arm_faults(config)
-    # Observability comes up BEFORE the actor pool so its threads are
-    # born with the live tracer and watchdog (spans/heartbeats from the
-    # very first unroll); the try below owns teardown from this point
-    # on, so a failure anywhere in construction still flushes/closes
-    # the trace file and dumps the flight recorder.
-    stages.enter("setup/observability")
-    obs_handles = _setup_observability(config, is_coordinator())
-    registry, prom = obs_handles.registry, obs_handles.prom
-    # Fleet fault domains (runtime/fleet.py): peer heartbeats over the
-    # jax.distributed KV store, collective deadlines, and the SIGTERM
-    # preemption-grace protocol.  Up BEFORE the learner/restore so a
-    # peer lost during the (collective) restore or first compile is
-    # already bounded; its SIGTERM handler layers over the crash
-    # handlers _setup_observability just installed.
-    fleet = configure_fleet(
-        config.peer_timeout_s,
-        preemption_grace_s=config.preemption_grace_s,
-        collective_timeout_s=config.collective_timeout_s,
-        registry=registry,
-        recorder=get_flight_recorder(),
-        epoch=config.fleet_epoch,
-        logdir=config.logdir)
-    # Pipeline ledger (obs/ledger.py): per-trajectory provenance
-    # records stamped at every stage boundary below, derived into
-    # per-stage rates/ρ, the staleness histogram, and the live MFU
-    # gauge at each log interval.  Configured fresh per run so one
-    # run's open records can never leak into the next.
-    ledger = configure_ledger(
-        registry=registry,
-        frames_per_trajectory=config.frames_per_update(),
-        logdir=config.logdir,
-        process_index=jax.process_index())
-    pool = prefetch_thread = writer = ckpt = learner = None
-    sentinel = None
-    prefetch_stop = threading.Event()
-    profiling = False
-    completed = False
-    metrics = {}
-    # Run-health plane (obs/health.py): detectors at log cadence plus
-    # the anomaly-triggered profiling window.  Constructed before the
-    # try so the finally's flush always sees it.
-    health = _HealthPlane(config, backend="host")
-    injector = get_fault_injector()
-    try:
-        stages.enter("setup/probe_env")
-        level_names = training_level_names(config)
-        multi_task = len(level_names) > 1
-        probe_config = (
-            dataclasses.replace(config, level_name=level_names[0])
-            if multi_task else config)
-        observation_spec, action_space, num_agents = probe_env(
-            probe_config)
-        stages.enter("setup/build_agent")
-        agent = build_agent(config, action_space,
-                            observation_spec.frame.shape)
-
-        stages.enter("setup/build_learner")
-        learner = build_training_learner(config, agent)
-        # Device-resident replay (runtime/replay.py): every fresh
-        # batch's packed upload also lands in the slab, and
-        # --replay_ratio replayed updates ride behind each fresh one —
-        # None (and nothing allocated) when the dial is at 0.
-        replay = build_replay(config, learner)
-        # Numerics sentinel (runtime/sentinel.py): shadow audits of the
-        # optimized hot path against the reference arm every
-        # --sentinel_interval updates, param fingerprints at the
-        # decision-broadcast cadence, and the degradation ladder on
-        # breach.  None (and no jitted program changes anywhere) when
-        # the dial is at 0 — the default path stays bit-exact.
-        sentinel = build_sentinel(config, agent, learner, action_space,
-                                  observation_spec.frame.shape)
-
-        # gloo (the multi-process CPU collectives transport) pairs ops
-        # by ARRIVAL order per process-pair: no two programs with
-        # collectives may ever be in flight at once, or their ops
-        # mispair across processes and abort the whole fleet with a
-        # size mismatch.  TPU/GPU streams serialize collectives in
-        # issue order, so only the CPU rig pays these explicit
-        # materialization barriers (here and in the update loop).
-        cpu_lockstep = (jax.process_count() > 1
-                        and jax.devices()[0].platform == "cpu")
-
-        ckpt = CheckpointManager(config.logdir,
-                                 config.checkpoint_interval_s,
-                                 config.checkpoint_keep)
-        stages.enter("setup/trainer_init")
-        example = zero_trajectory(config, observation_spec, agent)
-        state = learner.init(jax.random.key(config.seed), example)
-        if cpu_lockstep:
-            # init is a global-mesh program whose collectives would
-            # otherwise still be draining when restore()'s has_any
-            # broadcast posts its own ops.
-            jax.block_until_ready(state)
-        stages.enter("setup/restore")
-        restored = ckpt.restore(target=state)
-        if restored is not None:
-            start_updates, host_state = restored
-            state = learner.place_state(host_state)
-            if cpu_lockstep:
-                jax.block_until_ready(state)
-            # Topology-agnostic resume (runtime/elastic.py): when this
-            # fleet's process/device layout differs from the one that
-            # wrote the checkpoint (an elastic reshard), the placed
-            # state is gathered back and re-verified against the
-            # per-leaf CRC manifest — collective, so every process
-            # reaches it together (restore() guarantees `restored` is
-            # non-None on all of them together).
-            ckpt.verify_after_reshard(start_updates, state)
-            fleet.note_checkpoint(start_updates)
-            log.info("restored checkpoint at update %d (%.0f frames)",
-                     start_updates, _host_scalar(state.env_frames))
-        else:
-            start_updates = 0
-
-        stages.enter("setup/live_mfu")
-        # Live MFU numerator: lower (don't compile) the update once at
-        # the run's REAL [T+1, local_B] shape for its cost-analysis
-        # FLOPs.  The denominator is this PROCESS'S share of the mesh
-        # (local devices), matching the local-batch numerator — each
-        # process then gauges its own chips' utilization, and the
-        # aggregator's MAX fold shows the busiest process.  No-op on
-        # chips without a roofline entry (CPU).
-        mfu_example = zero_trajectory(
-            config, observation_spec, agent,
-            batch=max(1, config.batch_size // jax.process_count()),
-            t_plus_1=config.unroll_length + 1)
-        _configure_live_mfu(
-            ledger, lambda: learner.lower_update(state, mfu_example),
-            max(1, learner.mesh.devices.size // jax.process_count()))
-        del mfu_example
-
-        stages.enter("setup/env_groups")
-        env_groups = make_env_groups(config, observation_spec.frame,
-                                     num_agents=num_agents,
-                                     level_names=level_names)
-        if config.actor == "service":
-            # Continuous-batching actor service (runtime/service.py):
-            # same queue/get_trajectory surface as the pool, so the
-            # prefetch stage and everything downstream are unchanged.
-            from scalable_agent_tpu.runtime.service import ActorService
-
-            if config.inference_mode != "structural":
-                raise ValueError(
-                    f"--actor=service owns its inference (one "
-                    f"continuous-batching thread); inference_mode="
-                    f"{config.inference_mode!r} applies to "
-                    f"--actor=grouped only")
-            pool = ActorService(
-                agent, env_groups, config.unroll_length,
-                level_name=config.level_name, seed=config.seed,
-                max_batch=config.service_max_batch,
-                max_restarts=config.actor_max_restarts)
-        else:
-            pool = ActorPool(
-                agent, env_groups, config.unroll_length,
-                level_name=config.level_name, seed=config.seed,
-                inference_mode=config.inference_mode,
-                observation_spec=observation_spec,
-                fused_shards=config.accum_fused_shards,
-                max_restarts=config.actor_max_restarts)
-        pool.set_params(state.params)
-        pool.start()
-
-        # Device prefetch stage: stages the next batch while the current
-        # update runs (the reference's StagingArea +1-step policy lag,
-        # experiment.py:587-597).
-        stages.enter("setup/prefetch_start")
-        staged: queue_lib.Queue = queue_lib.Queue(maxsize=1)
-        prefetch_thread = start_prefetch(pool, learner, staged,
-                                         prefetch_stop)
-
-        stall = StallAttributor(registry)
-        # Non-finite guard policy: the jitted update carries the skip
-        # counters in its metrics (runtime/learner.py); this tracker
-        # reads them at log time — the fetch the loop already pays —
-        # and arbitrates rollback vs exit 71.  Baseline at the restored
-        # state's cumulative count: a resumed run must not re-count the
-        # previous run's lifetime skips into this process's counter.
-        nonfinite = NonFiniteTracker(config.nonfinite_tolerance,
-                                     registry=registry)
-        nonfinite.rebase(_host_scalar(state.nonfinite_skips))
-        actor_steps_counter = registry.counter("actor/agent_steps_total")
-        actor_fps_gauge = registry.gauge(
-            "actor/fps", "env frames/s generated by this host's actors")
-        learner_fps_gauge = registry.gauge(
-            "learner/fps", "env frames/s consumed by the learner")
-        writer = (MetricsWriter(config.logdir, registry=registry)
-                  if is_coordinator() else None)
-        timing = Timing()
-        # Per-interval stage sums for the stall attributor (the display
-        # `timing` keeps moving averages; attribution needs THIS
-        # interval).
-        interval = Timing()
-        actor_steps_at_last_log = actor_steps_counter.value
-        updates = start_updates
-        frames_per_update = config.frames_per_update()
-        # The restored TrainState's env_frames (which drives the LR
-        # schedule) is authoritative — recomputing
-        # updates*frames_per_update from the CURRENT config would
-        # silently disagree if batch_size/unroll_length/
-        # num_action_repeats changed between runs.
-        frames = _host_scalar(state.env_frames)
-        last_log = time.monotonic()
-        frames_at_last_log = frames
-        # Multi-task: per-level returns accumulated toward the TRAINING
-        # suite score, cleared after each score like the reference
-        # (experiment.py:652-667).
-        suite_returns: Dict[str, List[float]] = (
-            {name: [] for name in dmlab30.TRAIN_LEVELS}
-            if multi_task else {})
-        # Device-level tracing (SURVEY §5.1): --profile_dir captures a
-        # jax.profiler trace of updates [profile_start_update,
-        # +profile_num_updates) viewable in TensorBoard/XProf — the tool
-        # for locating host↔device stalls the Timing counters can't
-        # attribute.
-        watchdog = get_watchdog()
-        # Bounded in-flight dispatch (runtime/transport.py): up to
-        # --inflight_updates updates stay dispatched-but-unmaterialized;
-        # the loop blocks ("retire") only when the window fills, so the
-        # next batch's staging overlaps the running update while
-        # backpressure and per-update metrics ordering stay exact.
-        # Same gloo arrival-order hazard as above: neither two
-        # overlapping update executions (inflight window) nor an async
-        # update racing the loop's next blocking broadcast may coexist
-        # on the CPU rig.
-        inflight_updates = config.inflight_updates
-        if inflight_updates > 1 and cpu_lockstep:
-            log.warning(
-                "inflight_updates=%d downgraded to 1: multi-process "
-                "CPU (gloo) runs mispair collectives from overlapping "
-                "update executions", inflight_updates)
-            inflight_updates = 1
-        inflight = InflightWindow(inflight_updates,
-                                  registry=registry)
-        rollback_wanted = False
-        # Compile windows are recovery/startup cost, not wedges: the
-        # first dispatch (cold or relaunch compile) and the re-jit
-        # after a sentinel ladder demotion (~13s measured) run with the
-        # learner heartbeat suspended — the same treatment rollback
-        # restore gets — so a tight --watchdog_timeout_s doesn't read
-        # them as hangs.  The post-update touch re-arms.
-        rejit_pending = True
-        while frames < config.total_environment_frames:
-            if (config.profile_dir and not profiling
-                    and not health.window_open
-                    and updates - start_updates
-                    == config.profile_start_update):
-                jax.profiler.start_trace(config.profile_dir)
-                # Host spans annotate into the device capture only while
-                # it records (TraceAnnotation is ~100x a span; see
-                # Tracer.set_annotate).
-                get_tracer().set_annotate(True)
-                profiling = True
-                profile_stop_at = updates + config.profile_num_updates
-            # Disarm the learner heartbeat while blocked on the staged
-            # queue: starvation is the stall attributor's domain, and a
-            # wedged UPSTREAM thread's own stale heartbeat names the
-            # culprit — the learner waiting on it is a symptom.
-            watchdog.suspend("learner")
-            with timing.time_avg("wait_batch"), \
-                    interval.add_time("wait_batch"), \
-                    get_tracer().span("learner/wait_batch", cat="learner"):
-                traj = staged.get()
-            watchdog.touch("learner")
-            if isinstance(traj, Exception):
-                raise traj
-            # Recover the batch's provenance record; the in-flight
-            # window owns its end (retire stamps + close, or the
-            # rollback discard's retired=False close).
-            ledger_tid = ledger.lookup(id(traj))
-            audit_snap = None
-            if sentinel is not None and sentinel.audit_due(updates):
-                # Pre-update snapshot for the shadow audit below: the
-                # hot update donates its input state, so the audit
-                # needs its own buffers (the trajectory is not
-                # donated and rides through as-is).
-                audit_snap = sentinel.snapshot(state)
-            if rejit_pending:
-                watchdog.suspend("learner")
-                rejit_pending = False
-                if updates == start_updates:
-                    stages.enter("setup/first_dispatch")
-            with timing.time_avg("update"), interval.add_time("update"):
-                state, dispatched = learner.update(state, traj)
-                # Chaos: a deterministic mid-run slowdown (thermal
-                # throttle / noisy neighbor stand-in) the health plane
-                # must catch — occurrences count fresh update
-                # dispatches.  Inside the update timing block so the
-                # stall attributor reads it as a slow device.
-                if injector.active and injector.should_fire(
-                        "throughput_sag"):
-                    time.sleep(throughput_sag_s())
-            if ledger_tid is not None:
-                ledger.stamp(ledger_tid, "dispatch")
-            inflight.push(dispatched, ledger_id=ledger_tid)
-            if cpu_lockstep:
-                # Materialize the WHOLE update before the loop can
-                # reach another cross-process point (decision
-                # broadcast, save collective): metrics resolving does
-                # not mean the program's last all-reduce has drained,
-                # and gloo mispairs anything that arrives alongside it.
-                jax.block_until_ready(state)
-            watchdog.touch("learner")
-            if stages.open == "setup/first_dispatch":
-                # Set-up ends here; the startup-cost beacon for the
-                # supervisor's MTTR decomposition goes out with it.
-                stages.done()
-                _write_mttr_breakdown(config, stages)
-            if audit_snap is not None:
-                # Shadow audit: recompute this batch's grads + param
-                # delta through the reference arm on device and compare
-                # (one D2H bool at audit cadence).  Runs BEFORE the
-                # replay updates below so the delta compare sees the
-                # fresh update's params, and may demote the ladder —
-                # in which case the next update re-jits on the demoted
-                # learner (the prefetch thread keeps the old learner's
-                # transport; its placed trajectories feed the new
-                # learner unchanged — computation follows data).
-                # The reference arm's own compile (first audit) and the
-                # compare are recovery machinery, not progress the
-                # heartbeat should time — suspend like rollback
-                # restore; the touch below re-arms.
-                watchdog.suspend("learner")
-                with timing.time_avg("audit"), \
-                        interval.add_time("audit"):
-                    state = sentinel.audit(audit_snap, traj, state,
-                                           updates)
-                audit_snap = None
-                if sentinel.consume_swap():
-                    # Flush the old hot path's devtel before dropping
-                    # it, then adopt the demoted learner.  The replay
-                    # slab's lineage is suspect (filled by the breached
-                    # path) — drop it and re-warm.
-                    learner.publish_device_telemetry()
-                    learner = sentinel.learner
-                    agent = sentinel.agent
-                    if replay is not None:
-                        replay.flush()
-                    # The demoted rung re-jits inside the next dispatch
-                    # (~13s measured): suspend across it too.
-                    rejit_pending = True
-                watchdog.touch("learner")
-            # The size gate covers the re-warm-up window after a
-            # rollback/demotion flush: the slab refills from the
-            # prefetch thread's uploads, and until the first lands the
-            # replayed updates are simply skipped (fresh training
-            # continues at ratio 0) rather than sampling an empty ring.
-            if replay is not None and replay.size >= 1:
-                # The off-policy dial: R replayed updates behind every
-                # fresh batch — on-device sample + unpack + update,
-                # env_frames held (fresh frames count exactly once),
-                # metrics through the same in-flight window with no
-                # provenance record (the batch's frames were accounted
-                # at fresh consumption; its AGE lands in
-                # ledger/staleness_replayed_s at sample time).
-                for _ in range(config.replay_ratio):
-                    with timing.time_avg("update"), \
-                            interval.add_time("update"), \
-                            get_tracer().span("learner/replay_update",
-                                              cat="learner"):
-                        rtraj = replay.sample()
-                        state, dispatched = learner.update(
-                            state, rtraj, fresh=False)
-                    inflight.push(dispatched, ledger_id=None)
-                    updates += 1
-                    if inflight.full:
-                        with timing.time_avg("retire"), \
-                                interval.add_time("retire"), \
-                                fleet.collective("retire_update"):
-                            metrics = inflight.retire()
-                    watchdog.touch("learner")
-            pool.set_params(state.params, version=updates)
-            updates += 1
-            frames += frames_per_update
-            if inflight.full:
-                # Materialize the OLDEST in-flight update's metrics
-                # (FIFO, so the logged metrics always belong to a known
-                # update and env_frames accounting is exact); this is
-                # the loop's only device wait — in a multi-process run
-                # it materializes the cross-host all-reduce, so a peer
-                # lost mid-update surfaces (and is attributed) here.
-                with timing.time_avg("retire"), \
-                        interval.add_time("retire"), \
-                        fleet.collective("retire_update"):
-                    metrics = inflight.retire()
-            watchdog.touch("learner")
-            if profiling and updates >= profile_stop_at:
-                jax.block_until_ready(dispatched["total_loss"])
-                jax.profiler.stop_trace()
-                get_tracer().set_annotate(False)
-                profiling = False
-                log.info("profiler trace written to %s",
-                         config.profile_dir)
-                # Per-kernel roofline ledger over the window just
-                # captured (obs/kernels.py): rebuild the zero example
-                # at the update's real shapes for the lowering — the
-                # state/trajectory in flight carry the same avals.
-                kernel_example = zero_trajectory(
-                    config, observation_spec, agent,
-                    batch=max(1,
-                              config.batch_size // jax.process_count()),
-                    t_plus_1=config.unroll_length + 1)
-                # The harvest re-pays the production-shape AOT compile
-                # (multi-minute on TPU) on this thread: disarm the
-                # learner heartbeat across it like every other healthy
-                # long pause — the next loop touch re-arms.
-                watchdog.suspend("learner")
-                table = _harvest_kernel_ledger(
-                    config,
-                    lambda: learner.lower_update(state, kernel_example),
-                    executions=config.profile_num_updates)
-                # The scheduled window doubles as the health plane's
-                # baseline: anomaly windows report their worst-kernel
-                # delta against it.
-                health.note_baseline(table)
-                del kernel_example
-            if health.window_open and updates >= health.window_stop_at:
-                # An anomaly-triggered profiling window just completed:
-                # same stop/harvest discipline as the scheduled window,
-                # but into kernels.<anomaly_id>.json and back into the
-                # anomaly record.
-                jax.block_until_ready(dispatched["total_loss"])
-                kernel_example = zero_trajectory(
-                    config, observation_spec, agent,
-                    batch=max(1,
-                              config.batch_size // jax.process_count()),
-                    t_plus_1=config.unroll_length + 1)
-                watchdog.suspend("learner")
-                health.close_window(
-                    lambda: learner.lower_update(state, kernel_example))
-                del kernel_example
-
-            now = time.monotonic()
-            if now - last_log >= config.log_interval_s:
-                # The log-time fetches drain the device queue and the
-                # publishes run with nothing dispatched: the whole block
-                # and each part of it is a span (the fused loop's
-                # block, same names).
-                tracer = get_tracer()
-                with tracer.span("driver/log_publish", cat="log"):
-                    if not metrics:
-                        # Nothing has fallen out of the in-flight window
-                        # yet (the first W-1 updates): log the newest
-                        # dispatched update rather than an empty dict —
-                        # the log-time fetch below is the sync the seed
-                        # loop always paid here.
-                        metrics = dispatched
-                    # The log-time fetches (host scalars here, the devtel/
-                    # sentinel publishes below) drain the device queue —
-                    # which, right after an audit or ladder demotion,
-                    # carries the recovery path's compiles.  That wait is
-                    # device backlog, not a wedged learner: disarm across
-                    # the fetch section; the touch after ledger.publish
-                    # re-arms.
-                    watchdog.suspend("learner")
-                    with tracer.span("log/fetch_metrics", cat="log"):
-                        host_metrics = {k: _host_scalar(v)
-                                        for k, v in metrics.items()}
-                    # Only RECORD the verdict here: the log gate runs on
-                    # local wall clocks, and acting inside it would let
-                    # multi-host processes enter the collective restore on
-                    # different iterations.  The rollback itself happens at
-                    # the fixed per-iteration point below.
-                    if nonfinite.observe(host_metrics):
-                        rollback_wanted = True
-                    fps = (frames - frames_at_last_log) / (now - last_log)
-                    host_metrics["fps"] = fps
-                    stats = pool.episode_stats()
-                    if stats:
-                        host_metrics["episode_return"] = float(
-                            np.mean([r for r, _ in stats]))
-                        host_metrics["episode_frames"] = float(
-                            np.mean([l for _, l in stats])
-                            * config.num_action_repeats)
-                    # Per-level attribution (reference logs
-                    # <level>/episode_return and /episode_frames per episode,
-                    # experiment.py:634-650; interval means here).
-                    for level, entries in pool.drain_level_stats().items():
-                        host_metrics[f"{level}/episode_return"] = float(
-                            np.mean([r for r, _ in entries]))
-                        host_metrics[f"{level}/episode_frames"] = float(
-                            np.mean([l for _, l in entries])
-                            * config.num_action_repeats)
-                        if multi_task:
-                            bare = (level[len("dmlab_"):]
-                                    if level.startswith("dmlab_") else level)
-                            if bare in suite_returns:
-                                suite_returns[bare].extend(
-                                    r for r, _ in entries)
-                    if multi_task and suite_returns and min(
-                            len(v) for v in suite_returns.values()) >= 1:
-                        # Every level reported since the last score: emit the
-                        # capped/uncapped human-normalized TRAINING score and
-                        # clear (reference: experiment.py:652-667).
-                        host_metrics["dmlab30/training_no_cap"] = (
-                            dmlab30.compute_human_normalized_score(
-                                suite_returns, per_level_cap=None))
-                        host_metrics["dmlab30/training_cap_100"] = (
-                            dmlab30.compute_human_normalized_score(
-                                suite_returns, per_level_cap=100.0))
-                        log.info(
-                            "dmlab30 training score — no cap: %.2f cap 100: "
-                            "%.2f", host_metrics["dmlab30/training_no_cap"],
-                            host_metrics["dmlab30/training_cap_100"])
-                        suite_returns = {
-                            name: [] for name in dmlab30.TRAIN_LEVELS}
-                    # Separate actor-FPS vs learner-FPS: the learner's
-                    # consumption rate (`fps`) can hide an actor surplus or
-                    # deficit that the queue currently masks.
-                    actor_steps = actor_steps_counter.value
-                    actor_fps = ((actor_steps - actor_steps_at_last_log)
-                                 * config.num_action_repeats
-                                 / (now - last_log))
-                    actor_steps_at_last_log = actor_steps
-                    actor_fps_gauge.set(actor_fps)
-                    learner_fps_gauge.set(fps)
-                    host_metrics["actor_fps"] = actor_fps
-                    # Machine-readable timing snapshot (Timing.summary): the
-                    # same numbers as the log line, str-parse-free.
-                    timing_summary = timing.summary()
-                    host_metrics.update(
-                        {f"timing/{k}": v for k, v in timing_summary.items()})
-                    # Device telemetry: the ONE fetch the on-device
-                    # instruments ever cost (a few hundred bytes at log
-                    # cadence), folded into the registry as devtel/* so it
-                    # rides the writer/prom dumps below.
-                    with tracer.span("log/telemetry", cat="log"):
-                        learner.publish_device_telemetry()
-                        if sentinel is not None:
-                            sentinel.publish()
-                    # Ledger derivation BEFORE stall attribution, so the
-                    # verdict line carries this interval's dominant-stage
-                    # share (rates/ρ/staleness/MFU land in the registry and
-                    # ride the writer/prom dumps below).
-                    with tracer.span("log/ledger", cat="log"):
-                        ledger.publish()
-                    watchdog.touch("learner")
-                    # Stall attribution over THIS interval's stage sums.
-                    interval_summary = interval.summary()
-                    interval.clear()
-                    category, evidence = stall.attribute(
-                        interval_summary.get("wait_batch", 0.0),
-                        interval_summary.get("update", 0.0),
-                        retire_s=interval_summary.get("retire", 0.0))
-                    # Health detectors over the registry stream plus this
-                    # interval's host metrics, with the verdict and ledger
-                    # attribution captured at trip time; a fresh trip may
-                    # arm a profiling window, opened here (next update
-                    # onward profiles) unless the scheduled window is live.
-                    with tracer.span("log/health", cat="log"):
-                        if health.active:
-                            health.step(
-                                {**registry.snapshot(), **host_metrics},
-                                update=updates, verdict=category,
-                                evidence=evidence)
-                            if not profiling:
-                                health.maybe_open_window(updates)
-                    with tracer.span("log/write", cat="log"):
-                        if writer is not None:
-                            writer.write(updates, host_metrics)
-                            writer.write_registry(updates)
-                    with tracer.span("log/prom", cat="log"):
-                        if prom is not None:
-                            prom.dump()
-                    log.info(
-                        "update %d frames %.3g fps %.0f (actors %.0f) "
-                        "loss %.3f return %s | %s | %s",
-                        updates, frames, fps, actor_fps,
-                        host_metrics.get("total_loss", float("nan")),
-                        f"{host_metrics.get('episode_return', float('nan')):.2f}",
-                        " ".join(f"{k} {v:.4f}s"
-                                 for k, v in timing_summary.items()),
-                        StallAttributor.describe(category, evidence))
-                    last_log, frames_at_last_log = now, frames
-            # Rollback AND preemption decisions at a point EVERY
-            # process reaches on the SAME iteration, with the
-            # coordinator's verdict broadcast — the divergent-local-
-            # clocks discipline maybe_save applies to its save decision
-            # — so the collective restore inside _rollback_or_exit (or
-            # the coordinated preemption drain) is entered by all
-            # processes together.  The multi-host broadcast is gated on
-            # the update counter (identical on every process, unlike
-            # wall clocks) every 8 updates, so the hot loop doesn't pay
-            # a second per-update collective; the added detection
-            # latency is dwarfed by the log-interval gate above for
-            # rollback and by the grace window for preemption.  A
-            # SIGTERM'd process must NOT act on its local flag alone:
-            # entering the final-save collective while peers keep
-            # training is exactly the unpaired-collective hang this
-            # layer exists to prevent — the KV flag carries the signal
-            # to the coordinator, whose broadcast verdict commits
-            # everyone at once.
-            do_rollback = rollback_wanted
-            rollback_reason = "nonfinite"
-            do_preempt = fleet.preemption_requested()
-            # Param fingerprint at the decision-broadcast cadence: an
-            # update-counter gate (identical on every process, unlike
-            # wall clocks) so the multi-process allgather below is
-            # issued on the same iteration everywhere — the gloo
-            # arrival-order discipline of the broadcast it rides with.
-            fingerprint = None
-            if sentinel is not None and updates % 8 == 0:
-                fingerprint = sentinel.local_fingerprint(state.params)
-            if jax.process_count() > 1:
-                do_rollback = do_preempt = False
-                if updates % 8 == 0:
-                    from jax.experimental import multihost_utils
-
-                    with fleet.collective("decision_broadcast"):
-                        verdict = multihost_utils.broadcast_one_to_all(
-                            np.asarray([rollback_wanted,
-                                        fleet.preemption_requested()]))
-                        if fingerprint is not None:
-                            gathered = multihost_utils.process_allgather(
-                                np.asarray([fingerprint], np.float64))
-                    do_rollback = bool(verdict[0])
-                    do_preempt = bool(verdict[1])
-                    if (fingerprint is not None
-                            and sentinel.check_fingerprints(gathered)):
-                        # Replicas disagree bit-exact: SDC or a
-                        # divergent replica.  Every process sees the
-                        # same gathered set, so every process reaches
-                        # this verdict together — no extra broadcast.
-                        do_rollback = True
-                        rollback_reason = "sentinel"
-            if sentinel is not None and sentinel.rollback_pending:
-                # An audit breach survived the full degradation ladder:
-                # the sentinel wants the newest verified checkpoint.
-                # The audit cadence is update-counter gated, so every
-                # process set this flag on the same iteration —
-                # SPMD-consistent without a broadcast.
-                do_rollback = True
-                rollback_reason = "sentinel"
-            if do_preempt:
-                # Coordinated preemption drain: fall through to the
-                # normal shutdown tail below — in-flight window
-                # drained, ONE forced verified checkpoint (whose
-                # internal broadcast/allgather every process now
-                # reaches together), clean exit 0.  The fleet monitor's
-                # grace deadline bounds this whole tail with exit 72.
-                fleet.note_preempt_decision(updates)
-                log.warning(
-                    "preemption drain: stopping at update %d "
-                    "(%.3g frames) for the coordinated final "
-                    "checkpoint", updates, frames)
-                break
-            if do_rollback:
-                rollback_wanted = False
-                state, updates, frames = _rollback_or_exit(
-                    config, ckpt, learner, state, nonfinite,
-                    reason=rollback_reason,
-                    exit_code=(SENTINEL_EXIT_CODE
-                               if rollback_reason == "sentinel"
-                               else NONFINITE_EXIT_CODE))
-                # Nothing from the abandoned timeline may leak forward:
-                # drop in-flight metrics (without blocking on them),
-                # flush the replay slab (its trajectories are the
-                # abandoned lineage's — stale-lineage samples must not
-                # feed post-restore updates; the off-policy dial
-                # re-warms from fresh batches), and republish the
-                # restored weights.
-                inflight.discard()
-                metrics = {}
-                if replay is not None:
-                    replay.flush()
-                if sentinel is not None and rollback_reason == "sentinel":
-                    sentinel.note_rollback()
-                pool.set_params(state.params, version=updates)
-                last_log = time.monotonic()
-                frames_at_last_log = frames
-                interval.clear()
-                continue
-            if ckpt.maybe_save(updates, state):
-                # The membership verdict (fleet_epoch.json) names the
-                # newest resumable step — the elastic supervisor's
-                # answer to "where will the resharded fleet resume".
-                fleet.note_checkpoint(updates)
-        # Disarm before the shutdown tail (final forced checkpoint,
-        # pool joins, writer close): a slow-but-healthy shutdown must
-        # not read as a stalled_thread wedge — and must never be
-        # os._exit'ed mid-checkpoint under --watchdog_abort.
-        watchdog.suspend("learner")
-        # Drain the in-flight window so the returned metrics are the
-        # NEWEST update's (the lock-step loop's contract).
-        drained = inflight.drain()
-        if drained is not None:
-            metrics = drained
-        if ckpt.maybe_save(updates, state, force=True):
-            fleet.note_checkpoint(updates)
-        completed = True
-    finally:
-        # Membership verdict FIRST: an exception unwinding a
-        # multi-process run is usually a peer's death arriving as an
-        # aborted collective, and jax's own client fatal (SIGABRT) can
-        # end this process anywhere in the teardown below — the
-        # elastic supervisor's epoch-stamped verdict must already be
-        # on disk by then (fleet.note_fatal_error no-ops on clean
-        # exits, single-process runs, and when the monitor's richer
-        # verdict already landed).
-        import sys as _sys
-
-        _exc = _sys.exc_info()[1]
-        if _exc is not None and not isinstance(
-                _exc, (SystemExit, KeyboardInterrupt)):
-            fleet.note_fatal_error(_exc)
-        # Disarm the watchdog for the WHOLE teardown tail — the
-        # exception path skips the loop-exit suspend above, and pool
-        # joins/writer/ckpt closes must never be os._exit(70)'d by a
-        # heartbeat that simply stopped because the run is ending.
-        # (The exception dump in _teardown_observability still runs.)
-        configure_watchdog(None)
-        configure_faults("")  # chaos spec must not outlive its run
-        if profiling:
-            jax.profiler.stop_trace()
-        # Health teardown: stop a still-open anomaly window's trace and
-        # append the final state of open anomaly records, BEFORE the
-        # obs teardown's final prom dump so health/* counters land in
-        # the last snapshot.
-        health.finalize()
-        prefetch_stop.set()
-        # Construction may have failed partway — clean up whatever
-        # exists (None-guards), and always flush/close the obs state.
-        if pool is not None:
-            pool.stop()
-        if prefetch_thread is not None:
-            prefetch_thread.join(timeout=5)
-        # Ledger finalize AFTER the pipeline threads stopped (no new
-        # stamps) and BEFORE the obs teardown's final prom dump, so the
-        # snapshot shows the swept state: in-pipeline records closed as
-        # abandoned, zero open records on a clean exit, last derivation
-        # published, ledger.p<proc>.json on disk.
-        try:
-            get_ledger().finalize()
-        except Exception:
-            log.exception("ledger finalize failed")
-        # Final device-telemetry publish BEFORE the teardown's prom
-        # dump: a run (or run tail) shorter than log_interval_s never
-        # hit the interval gate, and the final metrics.prom would show
-        # devtel/* absent or frozen at the last fetch.  Guarded — on
-        # the exception path the device buffers may be donated husks.
-        if learner is not None:
-            try:
-                learner.publish_device_telemetry()
-            except Exception:
-                log.exception("final device-telemetry publish failed")
-        if sentinel is not None:
-            try:
-                sentinel.publish()
-            except Exception:
-                log.exception("final sentinel-telemetry publish failed")
-        if writer is not None:
-            writer.close()
-        if ckpt is not None:
-            ckpt.close()
-        _teardown_observability(config, obs_handles)
-        if completed and jax.process_count() > 1:
-            # No process may exit (tearing down the coordination
-            # service) until every process finished its checkpoint IO.
-            # Skipped on the EXCEPTION path: a failed process must not
-            # block in a barrier its healthy peers (stuck inside their
-            # own collectives) can never reach — dying fast surfaces
-            # the error and unblocks everyone.
-            from jax.experimental import multihost_utils
-
-            with fleet.collective("train_exit_barrier"):
-                multihost_utils.sync_global_devices("train_exit")
-        # Fleet teardown LAST: peer-loss detection and the preemption
-        # grace deadline must cover the whole teardown tail — a peer
-        # dying during the final save or exit barrier is still a
-        # bounded exit 72, not a hang.
-        configure_fleet(None)
-    return {k: _host_scalar(v) for k, v in metrics.items()}
 
 
 def build_training_learner(config: Config, agent: ImpalaAgent):
@@ -2154,11 +1232,16 @@ def build_replay(config: Config, learner: Learner):
     the slab stores the packed transport's uploaded buffers and samples
     unpack through the transport's existing jitted unpack; the insert
     tap carries the current ledger record's birth stamp so
-    ``ledger/staleness_replayed_s`` measures true frame age."""
+    ``ledger/staleness_replayed_s`` measures true frame age.  Fused
+    backend: no transport, so the slab holds the unroll's Trajectory
+    pytree as the step emits it, and the loop inserts it."""
     if config.replay_ratio <= 0:
         return None
     from scalable_agent_tpu.runtime.replay import DeviceReplayBuffer
 
+    if config.train_backend == "ingraph":
+        return DeviceReplayBuffer(config.replay_capacity,
+                                  seed=config.seed)
     transport = learner._transport
     from scalable_agent_tpu.runtime.transport import PackedTransport
 
@@ -2199,594 +1282,1241 @@ def build_sentinel(config: Config, agent, learner, action_space,
     return NumericsSentinel(config, agent, learner, rebuild)
 
 
-# How many fused updates may be dispatched-but-unretired before the
-# in-graph loop forces one materialization to retire them: safely under
-# the ledger's 8192 open-record capacity, and high enough that the
-# log-interval fetch almost always fires first.
+def train(config: Config,
+          t_entry_ns: Optional[int] = None) -> Dict[str, float]:
+    """Train until total_environment_frames.  Returns final metrics.
+
+    ``t_entry_ns``: when the caller's work for this run began on the
+    ``time.perf_counter_ns`` clock (``main``'s first line) — where the
+    run's timeline starts; now when not given.
+
+    ``--train_backend`` picks where trajectories come from (``host``:
+    env workers -> actors -> learner; ``ingraph``: rollout and update
+    fused into one device program); the loop is ``_run``, written once.
+
+    Multi-host (host backend): run the SAME command on every host with
+    --distributed_coordinator/--distributed_num_processes/
+    --distributed_process_id set (or JAX_* env vars).  Every process
+    runs its own actor pool contributing 1/P of each global batch; the
+    learner update is one SPMD program over the global device mesh
+    (parallel/distributed.py; role of the reference's learner+actor
+    jobs, experiment.py:497-512)."""
+    stages = _open_timeline(config, t_entry_ns)
+    try:
+        backends = {"host": _HostBackend, "ingraph": _FusedBackend}
+        if config.train_backend not in backends:
+            raise ValueError(
+                f"unknown train_backend {config.train_backend!r} "
+                f"(host | ingraph)")
+        return _run(backends[config.train_backend](config, stages))
+    finally:
+        # However it ended: close the stage a failed set-up left open,
+        # and the trace where a raise before ``_teardown_observability``
+        # left it open.
+        stages.done()
+        if config.trace:
+            configure_tracer(None)
+
+
+class _Backend:
+    """One training run's services, and the operations in which the two
+    backends differ.  ``_run`` is the loop over them.
+
+    ``setup() -> state`` is each backend's own — the order of the
+    set-up stages differs (the host backend brings observability up
+    before its actor threads are born; the fused one has nothing to
+    observe until its trainer exists) — built from the shared pieces
+    below.  Each backend also defines:
+
+    * ``next_batch() -> (trajectory or None, ledger record id)`` of the
+      next fresh step, waiting for it where there is something to wait
+      for;
+    * ``dispatch(state, trajectory, ledger_id, updates) -> (state,
+      dispatched metrics, the trajectory trained on — None where the
+      step keeps it on the device and nothing asked for it)``: one
+      fresh step, its provenance record stamped and kept until it
+      retires;
+    * ``replay_update(state, trajectory, dispatched) -> (state, newest
+      dispatched metrics)``: one replayed update (env_frames held, no
+      provenance record: the batch's frames were accounted at fresh
+      consumption, its age lands in ``ledger/staleness_replayed_s`` at
+      sample time);
+    * ``retire(dispatched)``: the metrics the next publish should
+      read, when newer ones are known than it has, else None;
+    * ``publish_telemetry()``: the one fetch the on-device instruments
+      cost (a few hundred bytes), folded into the registry as
+      ``devtel/*``;
+    * ``adopt(learner, agent)``: the sentinel demoted the hot path —
+      train on these from the next dispatch (which re-jits);
+    * ``lower_step(state)``: the step lowered at the run's real shapes
+      (the live MFU gauge's cost analysis, the kernel ledger's HLO);
+    * ``drain(dispatched)``: the loop ended — retire what is in
+      flight; the newest update's metrics where they are not the
+      caller's already.
+
+    The rest have defaults that do nothing: the loop calls them for
+    both."""
+
+    name = ""
+    updates_per_step = 1    # updates one fresh dispatch advances
+    # gloo (the multi-process CPU collectives transport) pairs ops by
+    # ARRIVAL order per process-pair: no two programs with collectives
+    # may ever be in flight at once, or their ops mispair across
+    # processes and abort the whole fleet with a size mismatch.  TPU/GPU
+    # streams serialize collectives in issue order, so only the CPU rig
+    # (a multi-process host backend there sets this) pays explicit
+    # materialization barriers between such programs.
+    cpu_lockstep = False
+
+    def __init__(self, config: Config, stages: _SetupStages):
+        self.config = config
+        self.stages = stages
+        self.timing = Timing()
+        # Per-interval stage sums (``timing`` keeps moving averages;
+        # stall attribution needs THIS interval).
+        self.interval = Timing()
+        self.obs = self.fleet = self.ledger = self.health = None
+        self.agent = self.learner = self.sentinel = self.replay = None
+        self.ckpt = self.writer = self.nonfinite = None
+        self.start_updates = 0
+
+    # -- shared set-up pieces ----------------------------------------------
+
+    def _arm(self):
+        """Env overrides, the persisted config, the compile cache and
+        the chaos harness: the --chaos_spec triggers plus, under
+        --chaos_channel, the <logdir>/chaos_inject.jsonl runtime
+        channel (the soak engine's injection path).  ``_run``'s finally
+        disarms it, so one run's spec can't leak into the next
+        in-process run."""
+        self.config = config = apply_env_overrides(self.config)
+        if is_coordinator():
+            config.save()
+        setup_compile_cache()
+        configure_faults(
+            config.chaos_spec,
+            channel_path=(os.path.join(config.logdir, CHANNEL_NAME)
+                          if config.chaos_channel else None),
+            seed=config.seed,
+            process_id=max(0, config.distributed_process_id))
+
+    def _bring_up_services(self):
+        """Observability, then the fleet's fault domains (peer
+        heartbeats over the jax.distributed KV store, collective
+        deadlines, the SIGTERM preemption-grace protocol — its handler
+        layers over the crash handlers observability just installed;
+        single-process only the grace protocol arms), then the pipeline
+        ledger (obs/ledger.py: one provenance record per dispatch,
+        derived into per-stage rates, staleness and the live MFU gauge
+        at each publish; fresh per run, so one run's open records can
+        never leak into the next)."""
+        config = self.config
+        # The log interval's clock starts here, not at the first
+        # dispatch: a run whose set-up outlasts the interval publishes
+        # right after its first update, so progress shows at once and
+        # the first publish's own small compiles are behind the run
+        # before its steady state.
+        self.log_clock_start = time.monotonic()
+        self.obs = _setup_observability(config, is_coordinator())
+        self.fleet = configure_fleet(
+            config.peer_timeout_s,
+            preemption_grace_s=config.preemption_grace_s,
+            collective_timeout_s=config.collective_timeout_s,
+            registry=self.obs.registry,
+            recorder=get_flight_recorder(),
+            epoch=config.fleet_epoch,
+            logdir=config.logdir)
+        # A restore that ran before the fleet was up is noted now.
+        self.fleet.note_checkpoint(self.start_updates)
+        self.ledger = configure_ledger(
+            registry=self.obs.registry,
+            frames_per_trajectory=(config.frames_per_update()
+                                   * self.updates_per_step),
+            logdir=config.logdir,
+            process_index=jax.process_index())
+
+    def _build_learner(self, action_space, frame_shape):
+        """The learner, the device replay slab behind it (None at
+        ``--replay_ratio=0``) and the numerics sentinel (None at
+        ``--sentinel_interval=0``: nothing jitted, the default path
+        stays bit-exact)."""
+        self.learner = build_training_learner(self.config, self.agent)
+        self.replay = build_replay(self.config, self.learner)
+        self.sentinel = build_sentinel(
+            self.config, self.agent, self.learner, action_space,
+            frame_shape)
+
+    def _restore(self, state):
+        """``state``, or the newest verified checkpoint placed on this
+        run's mesh.  Topology-agnostic resume (runtime/elastic.py):
+        when this run's process/device layout differs from the one
+        that wrote the checkpoint, the placed state is gathered back
+        and re-verified against the per-leaf CRC manifest — collective,
+        so every process reaches it together (``restore`` returns
+        non-None on all of them together)."""
+        config = self.config
+        self.ckpt = CheckpointManager(config.logdir,
+                                      config.checkpoint_interval_s,
+                                      config.checkpoint_keep)
+        restored = self.ckpt.restore(target=state)
+        if restored is None:
+            return state
+        self.start_updates, host_state = restored
+        state = self.learner.place_state(host_state)
+        if self.cpu_lockstep:
+            jax.block_until_ready(state)
+        self.ckpt.verify_after_reshard(self.start_updates, state)
+        get_fleet().note_checkpoint(self.start_updates)
+        log.info("restored checkpoint at update %d (%.0f frames)",
+                 self.start_updates, _host_scalar(state.env_frames))
+        return state
+
+    def _enter_loop(self, state):
+        """What the loop reads besides the services: the run-health
+        plane, the non-finite guard's policy and the metrics writer."""
+        config, registry = self.config, self.obs.registry
+        self.health = _HealthPlane(config, backend=self.name)
+        # The jitted update carries the skip counters in its metrics
+        # (runtime/learner.py); the tracker reads them at the publish
+        # and arbitrates rollback vs exit 71.  Baseline at the restored
+        # state's cumulative count: a resumed run must not re-count the
+        # previous run's lifetime skips.
+        self.nonfinite = NonFiniteTracker(config.nonfinite_tolerance,
+                                          registry=registry)
+        self.nonfinite.rebase(_host_scalar(state.nonfinite_skips))
+        if is_coordinator():
+            self.writer = MetricsWriter(config.logdir, registry=registry)
+
+    # -- what differs (defaults: nothing to do) ------------------------------
+
+    def replay_insert(self, trajectory):
+        """Put the fresh step's trajectory into the replay slab, where
+        its upload has not already."""
+
+    def after_update(self, state, updates: int):
+        """The fresh update and its replayed ones are dispatched."""
+
+    def synced(self):
+        """The caller has waited for the newest dispatch: the device
+        stream is in order, so everything dispatched has run."""
+
+    def fetch(self, metrics) -> Dict[str, float]:
+        """Device metrics -> the host dict that is logged and
+        returned."""
+        return {k: _host_scalar(v) for k, v in metrics.items()}
+
+    def publish_extras(self, host_metrics, elapsed_s: float):
+        """What this backend adds to a publish, after the ledger's:
+        rows into ``host_metrics``; returns ``(stall verdict, its
+        evidence, what the log line says of both)``."""
+        return None, None, ""
+
+    def after_rollback(self, state, updates: int):
+        """``state`` is the restored one: nothing of the abandoned
+        timeline may leak forward."""
+
+    def stop(self):
+        """Teardown of the backend's own threads and processes; must
+        stand a set-up that failed partway."""
+
+    def after_teardown(self, trace_path: Optional[str], state):
+        """A clean run's last act, after every deadline is disarmed."""
+
+
+class _HostBackend(_Backend):
+    """Host-stepped simulators: env workers -> actor pool or service ->
+    device prefetch -> ``Learner.update``, up to ``--inflight_updates``
+    updates dispatched but not materialized."""
+
+    name = "host"
+
+    def __init__(self, config, stages):
+        super().__init__(config, stages)
+        self.pool = self.prefetch_thread = self.inflight = None
+        self.prefetch_stop = threading.Event()
+
+    def setup(self):
+        stages = self.stages
+        stages.enter("setup/distributed_init")
+        config = self.config
+        initialize_distributed(
+            config.distributed_coordinator or None,
+            config.distributed_num_processes or None,
+            config.distributed_process_id
+            if config.distributed_process_id >= 0 else None,
+            init_timeout_s=config.coordinator_init_timeout_s)
+        self.cpu_lockstep = (jax.process_count() > 1
+                             and jax.devices()[0].platform == "cpu")
+        _attach_trace_file(config)
+
+        stages.enter("setup/compile_cache")
+        self._arm()
+        config = self.config
+        # Observability comes up BEFORE the actor pool so its threads
+        # are born with the live tracer and watchdog (spans/heartbeats
+        # from the very first unroll), and the fleet before the
+        # learner/restore so a peer lost during the (collective)
+        # restore or first compile is already bounded.
+        stages.enter("setup/observability")
+        self._bring_up_services()
+        registry = self.obs.registry
+
+        stages.enter("setup/probe_env")
+        level_names = training_level_names(config)
+        multi_task = len(level_names) > 1
+        probe_config = (
+            dataclasses.replace(config, level_name=level_names[0])
+            if multi_task else config)
+        observation_spec, action_space, num_agents = probe_env(
+            probe_config)
+        self.observation_spec = observation_spec
+        stages.enter("setup/build_agent")
+        self.agent = build_agent(config, action_space,
+                                 observation_spec.frame.shape)
+        stages.enter("setup/build_learner")
+        self._build_learner(action_space, observation_spec.frame.shape)
+
+        stages.enter("setup/trainer_init")
+        state = self.learner.init(
+            jax.random.key(config.seed),
+            zero_trajectory(config, observation_spec, self.agent))
+        if self.cpu_lockstep:
+            # init is a global-mesh program whose collectives would
+            # otherwise still be draining when restore()'s has_any
+            # broadcast posts its own ops.
+            jax.block_until_ready(state)
+        stages.enter("setup/restore")
+        state = self._restore(state)
+
+        stages.enter("setup/live_mfu")
+        # The denominator is this PROCESS'S share of the mesh (local
+        # devices), matching the local-batch numerator — each process
+        # gauges its own chips' utilization, and the aggregator's MAX
+        # fold shows the busiest process.
+        _configure_live_mfu(
+            self.ledger, lambda: self.lower_step(state),
+            max(1, self.learner.mesh.devices.size // jax.process_count()))
+
+        stages.enter("setup/env_groups")
+        env_groups = make_env_groups(config, observation_spec.frame,
+                                     num_agents=num_agents,
+                                     level_names=level_names)
+        if config.actor == "service":
+            # Continuous-batching actor service (runtime/service.py):
+            # same queue/get_trajectory surface as the pool, so the
+            # prefetch stage and everything downstream are unchanged.
+            from scalable_agent_tpu.runtime.service import ActorService
+
+            if config.inference_mode != "structural":
+                raise ValueError(
+                    f"--actor=service owns its inference (one "
+                    f"continuous-batching thread); inference_mode="
+                    f"{config.inference_mode!r} applies to "
+                    f"--actor=grouped only")
+            self.pool = ActorService(
+                self.agent, env_groups, config.unroll_length,
+                level_name=config.level_name, seed=config.seed,
+                max_batch=config.service_max_batch,
+                max_restarts=config.actor_max_restarts)
+        else:
+            self.pool = ActorPool(
+                self.agent, env_groups, config.unroll_length,
+                level_name=config.level_name, seed=config.seed,
+                inference_mode=config.inference_mode,
+                observation_spec=observation_spec,
+                fused_shards=config.accum_fused_shards,
+                max_restarts=config.actor_max_restarts)
+        self.pool.set_params(state.params)
+        self.pool.start()
+
+        # Device prefetch stage: stages the next batch while the current
+        # update runs (the reference's StagingArea +1-step policy lag,
+        # experiment.py:587-597).
+        stages.enter("setup/prefetch_start")
+        self.staged: queue_lib.Queue = queue_lib.Queue(maxsize=1)
+        self.prefetch_thread = start_prefetch(
+            self.pool, self.learner, self.staged, self.prefetch_stop)
+
+        self.stall = StallAttributor(registry)
+        self.actor_steps = registry.counter("actor/agent_steps_total")
+        self.actor_steps_at_last_log = self.actor_steps.value
+        self.actor_fps_gauge = registry.gauge(
+            "actor/fps", "env frames/s generated by this host's actors")
+        # Multi-task: per-level returns accumulated toward the TRAINING
+        # suite score, cleared after each score like the reference
+        # (experiment.py:652-667).
+        self.suite_returns: Dict[str, List[float]] = (
+            {name: [] for name in dmlab30.TRAIN_LEVELS}
+            if multi_task else {})
+        # Bounded in-flight dispatch (runtime/transport.py): the loop
+        # blocks ("retire") only when the window fills, so the next
+        # batch's staging overlaps the running update while
+        # backpressure and per-update metrics ordering stay exact.
+        inflight_updates = config.inflight_updates
+        if inflight_updates > 1 and self.cpu_lockstep:
+            log.warning(
+                "inflight_updates=%d downgraded to 1: multi-process "
+                "CPU (gloo) runs mispair collectives from overlapping "
+                "update executions", inflight_updates)
+            inflight_updates = 1
+        self.inflight = InflightWindow(inflight_updates,
+                                       registry=registry)
+        self._enter_loop(state)
+        return state
+
+    def next_batch(self):
+        watchdog = get_watchdog()
+        # Disarm the learner heartbeat while blocked on the staged
+        # queue: starvation is the stall attributor's domain, and a
+        # wedged UPSTREAM thread's own stale heartbeat names the
+        # culprit — the learner waiting on it is a symptom.
+        watchdog.suspend("learner")
+        with self.timing.time_avg("wait_batch"), \
+                self.interval.add_time("wait_batch"), \
+                get_tracer().span("learner/wait_batch", cat="learner"):
+            traj = self.staged.get()
+        watchdog.touch("learner")
+        if isinstance(traj, Exception):
+            raise traj
+        # The batch's provenance record; the in-flight window owns its
+        # end (retire stamps + close, or the rollback discard's
+        # retired=False close).
+        return traj, self.ledger.lookup(id(traj))
+
+    def dispatch(self, state, trajectory, ledger_id, updates):
+        state, dispatched = self.learner.update(state, trajectory)
+        if ledger_id is not None:
+            self.ledger.stamp(ledger_id, "dispatch")
+        self.inflight.push(dispatched, ledger_id=ledger_id)
+        if self.cpu_lockstep:
+            # Materialize the WHOLE update before the loop can reach
+            # another cross-process point (decision broadcast, save
+            # collective): metrics resolving does not mean the
+            # program's last all-reduce has drained.
+            jax.block_until_ready(state)
+        return state, dispatched, trajectory
+
+    def replay_update(self, state, trajectory, dispatched):
+        state, dispatched = self.learner.update(state, trajectory,
+                                                fresh=False)
+        self.inflight.push(dispatched, ledger_id=None)
+        return state, dispatched
+
+    def retire(self, dispatched):
+        if not self.inflight.full:
+            return None
+        # Materialize the OLDEST in-flight update's metrics (FIFO, so
+        # the logged metrics always belong to a known update and
+        # env_frames accounting is exact); this is the loop's only
+        # device wait — in a multi-process run it materializes the
+        # cross-host all-reduce, so a peer lost mid-update surfaces
+        # (and is attributed) here.
+        with self.timing.time_avg("retire"), \
+                self.interval.add_time("retire"), \
+                self.fleet.collective("retire_update"):
+            return self.inflight.retire()
+
+    def after_update(self, state, updates):
+        self.pool.set_params(state.params, version=updates)
+
+    def publish_extras(self, host_metrics, elapsed_s):
+        config = self.config
+        stats = self.pool.episode_stats()
+        if stats:
+            host_metrics["episode_return"] = float(
+                np.mean([r for r, _ in stats]))
+            host_metrics["episode_frames"] = float(
+                np.mean([l for _, l in stats])
+                * config.num_action_repeats)
+        # Per-level attribution (reference logs <level>/episode_return
+        # and /episode_frames per episode, experiment.py:634-650;
+        # interval means here).
+        suite_returns = self.suite_returns
+        for level, entries in self.pool.drain_level_stats().items():
+            host_metrics[f"{level}/episode_return"] = float(
+                np.mean([r for r, _ in entries]))
+            host_metrics[f"{level}/episode_frames"] = float(
+                np.mean([l for _, l in entries])
+                * config.num_action_repeats)
+            bare = (level[len("dmlab_"):]
+                    if level.startswith("dmlab_") else level)
+            if bare in suite_returns:
+                suite_returns[bare].extend(r for r, _ in entries)
+        if suite_returns and min(
+                len(v) for v in suite_returns.values()) >= 1:
+            # Every level reported since the last score: emit the
+            # capped/uncapped human-normalized TRAINING score and clear
+            # (reference: experiment.py:652-667).
+            host_metrics["dmlab30/training_no_cap"] = (
+                dmlab30.compute_human_normalized_score(
+                    suite_returns, per_level_cap=None))
+            host_metrics["dmlab30/training_cap_100"] = (
+                dmlab30.compute_human_normalized_score(
+                    suite_returns, per_level_cap=100.0))
+            log.info(
+                "dmlab30 training score — no cap: %.2f cap 100: %.2f",
+                host_metrics["dmlab30/training_no_cap"],
+                host_metrics["dmlab30/training_cap_100"])
+            self.suite_returns = {
+                name: [] for name in dmlab30.TRAIN_LEVELS}
+        # Actor fps beside the learner's: the learner's consumption
+        # rate can hide an actor surplus or deficit that the queue
+        # currently masks.
+        actor_steps = self.actor_steps.value
+        actor_fps = ((actor_steps - self.actor_steps_at_last_log)
+                     * config.num_action_repeats / elapsed_s)
+        self.actor_steps_at_last_log = actor_steps
+        self.actor_fps_gauge.set(actor_fps)
+        host_metrics["actor_fps"] = actor_fps
+        # Stall attribution over THIS interval's stage sums — after
+        # the ledger's derivation, so the verdict line carries this
+        # interval's dominant-stage share.
+        interval = self.interval.summary()
+        category, evidence = self.stall.attribute(
+            interval.get("wait_batch", 0.0),
+            interval.get("update", 0.0),
+            retire_s=interval.get("retire", 0.0))
+        return category, evidence, (
+            f" | actors {actor_fps:.0f} fps | "
+            f"{StallAttributor.describe(category, evidence)}")
+
+    def publish_telemetry(self):
+        if self.learner is not None:
+            self.learner.publish_device_telemetry()
+
+    def adopt(self, learner, agent):
+        # Flush the old hot path's devtel before dropping it.  The
+        # prefetch thread keeps the old learner's transport; its placed
+        # trajectories feed the new learner unchanged (computation
+        # follows data).
+        self.learner.publish_device_telemetry()
+        self.learner, self.agent = learner, agent
+
+    def after_rollback(self, state, updates):
+        # Drop in-flight metrics (without blocking on them) and
+        # republish the restored weights.
+        self.inflight.discard()
+        self.pool.set_params(state.params, version=updates)
+
+    def lower_step(self, state):
+        config = self.config
+        example = zero_trajectory(
+            config, self.observation_spec, self.agent,
+            batch=max(1, config.batch_size // jax.process_count()),
+            t_plus_1=config.unroll_length + 1)
+        return self.learner.lower_update(state, example)
+
+    def drain(self, dispatched):
+        return self.inflight.drain()
+
+    def stop(self):
+        self.prefetch_stop.set()
+        if self.pool is not None:
+            self.pool.stop()
+        if self.prefetch_thread is not None:
+            self.prefetch_thread.join(timeout=5)
+
+
+# How many fused dispatches may be unretired before the fused backend
+# forces one materialization to retire them: safely under the ledger's
+# 8192 open-record capacity, and high enough that the log-interval
+# fetch almost always fires first.
 _INGRAPH_PENDING_CAP = 2048
 
 
-def train_ingraph(config: Config,
-                  stages: _SetupStages) -> Dict[str, float]:
-    """Fused in-graph training: rollout + update as ONE jitted device
-    program per dispatch (runtime/ingraph.py — K = updates_per_dispatch
-    fused updates per launch), for levels whose simulator is
-    expressible in XLA (envs/device/, the DEVICE_LEVELS registry).
+class _FusedBackend(_Backend):
+    """Device worlds: rollout + update as ONE jitted program per
+    dispatch (runtime/ingraph.py; K = ``--updates_per_dispatch`` fused
+    updates per launch), for levels whose simulator is expressible in
+    XLA (envs/device/, the DEVICE_LEVELS registry).  Replaces the whole
+    host actor pipeline the reference is built around
+    (experiment.py:479-672) with zero per-step host<->device traffic;
+    the Learner, the checkpoints, the metric names, the LR schedule and
+    resume are the host backend's.  Single-process.  There is no
+    in-flight window: a dispatch's ledger record stays open until the
+    loop next waits for the device (publish, profiling-window close,
+    the cap above, the end), so birth->retire is the true
+    dispatch-to-materialization latency of the fused stream."""
 
-    Checkpoint cadence, metrics names, LR schedule, and resume semantics
-    match the host loop exactly — the two backends share the Learner and
-    CheckpointManager — so `--train_backend=ingraph` is a drop-in flag.
-    (Replaces the whole host actor pipeline the reference is built
-    around, experiment.py:479-672, with zero per-step host↔device
-    traffic.)  Reached through ``train``, which owns the timeline
-    ``stages`` belongs to.
-    """
-    from scalable_agent_tpu.envs.device import make_device_env
-    from scalable_agent_tpu.runtime import InGraphTrainer
+    name = "ingraph"
 
-    # The first stage after setup/config also pays the backend's
-    # start-up where this process has not touched jax yet.
-    stages.enter("setup/compile_cache")
-    # This dispatch runs BEFORE jax.distributed would initialize, so
-    # check the config flags too — process_count() alone is still 1
-    # here even when the user asked for a distributed run, and silently
-    # training P independent duplicate runs into one logdir would be
-    # far worse than this error.
-    if (jax.process_count() > 1 or config.distributed_coordinator
-            or config.distributed_num_processes > 0):
-        raise ValueError(
-            "train_backend=ingraph is single-process (the host backend "
-            "covers multi-host training)")
-    if config.actor == "service":
-        raise ValueError(
-            "train_backend=ingraph has no host actor pipeline; "
-            "--actor=service applies to the host backend")
-    if config.replay_ratio > 0 and config.updates_per_dispatch > 1:
-        raise ValueError(
-            "replay_ratio > 0 requires --updates_per_dispatch=1: "
-            "replayed updates interleave with fresh ones between "
-            "dispatches (runtime/ingraph.py)")
-    if config.sentinel_interval > 0 and config.updates_per_dispatch > 1:
-        raise ValueError(
-            "sentinel_interval > 0 requires --updates_per_dispatch=1: "
-            "the shadow audit snapshots state at update granularity "
-            "(runtime/sentinel.py)")
-    _attach_trace_file(config)  # single-process: the index is 0
-    config = apply_env_overrides(config)
-    config.save()
-    setup_compile_cache()
-    _arm_faults(config)  # disarmed again in the finally
+    def __init__(self, config, stages):
+        super().__init__(config, stages)
+        self.updates_per_step = config.updates_per_dispatch
+        self.trainer = self.carry = None
+        # Dispatched, not yet known to have run.
+        self.pending_tids: List[int] = []
 
-    # Probe the HOST twin of the level so action/observation specs stay
-    # in lock-step with the device env.  For the fake family the twin
-    # is the mirrored envs/fake.py implementation; for device-native
-    # levels (device_*) it is the HostDeviceEnv adapter driving the
-    # same transition function, so agreement is by construction.
-    stages.enter("setup/probe_env")
-    observation_spec, action_space, _ = probe_env(config)
-    stages.enter("setup/build_agent")
-    agent = build_agent(config, action_space,
-                        observation_spec.frame.shape)
-    stages.enter("setup/device_env")
-    env = make_device_env(
-        config.level_name, height=config.height, width=config.width,
-        # Composite spaces have no .n; make_device_env rejects their
-        # levels with a clear error before num_actions matters.
-        num_actions=getattr(action_space, "n", 0),
-        num_action_repeats=config.num_action_repeats,
-        with_instruction=config.use_instruction)
-    host_frame = tuple(observation_spec.frame.shape)
-    device_frame = tuple(env.observation_spec.frame.shape)
-    if host_frame != device_frame:
-        raise ValueError(
-            f"host/device observation drift: host frame {host_frame} "
-            f"!= device mirror {device_frame} (envs/fake.py and "
-            f"envs/device/ must stay in lock-step)")
+    def _make_trainer(self):
+        config = self.config
+        return InGraphTrainer(
+            self.agent, self.learner, self.env, config.unroll_length,
+            config.batch_size, seed=config.seed,
+            # Replay and the sentinel's shadow audit both consume the
+            # dispatch's trajectory: either turns emission on.
+            emit_trajectory=self.emitting,
+            updates_per_dispatch=config.updates_per_dispatch)
 
-    stages.enter("setup/build_learner")
-    learner = build_training_learner(config, agent)
-    # The sentinel's shadow audit consumes the dispatch's emitted
-    # trajectory, so arming it turns emission on like replay does.
-    emitting = config.replay_ratio > 0 or config.sentinel_interval > 0
-    trainer = InGraphTrainer(
-        agent, learner, env, config.unroll_length,
-        config.batch_size, seed=config.seed,
-        emit_trajectory=emitting,
-        updates_per_dispatch=config.updates_per_dispatch)
-    sentinel = build_sentinel(config, agent, learner, action_space,
-                              observation_spec.frame.shape)
-    # Device replay for the fused backend: the unroll's device-born
-    # Trajectory pytree goes straight into the slab (no transport in
-    # this backend, so no packed buffer to store — the per-leaf slabs
-    # carry the same batch sharding the rollout constrains).
-    replay = None
-    if config.replay_ratio > 0:
-        from scalable_agent_tpu.runtime.replay import DeviceReplayBuffer
+    def setup(self):
+        from scalable_agent_tpu.envs.device import make_device_env
 
-        replay = DeviceReplayBuffer(config.replay_capacity,
-                                    seed=config.seed)
-    stages.enter("setup/trainer_init")
-    state, carry = trainer.init(jax.random.key(config.seed))
+        stages = self.stages
+        # The first stage after setup/config also pays the backend's
+        # start-up where this process has not touched jax yet.
+        stages.enter("setup/compile_cache")
+        config = self.config
+        # jax.distributed is never initialized here, so check the
+        # config flags too — process_count() alone is still 1 even
+        # when the user asked for a distributed run, and silently
+        # training P independent duplicate runs into one logdir would
+        # be far worse than this error.
+        if (jax.process_count() > 1 or config.distributed_coordinator
+                or config.distributed_num_processes > 0):
+            raise ValueError(
+                "train_backend=ingraph is single-process (the host "
+                "backend covers multi-host training)")
+        if config.actor == "service":
+            raise ValueError(
+                "train_backend=ingraph has no host actor pipeline; "
+                "--actor=service applies to the host backend")
+        if config.replay_ratio > 0 and config.updates_per_dispatch > 1:
+            raise ValueError(
+                "replay_ratio > 0 requires --updates_per_dispatch=1: "
+                "replayed updates interleave with fresh ones between "
+                "dispatches (runtime/ingraph.py)")
+        if config.sentinel_interval > 0 and config.updates_per_dispatch > 1:
+            raise ValueError(
+                "sentinel_interval > 0 requires --updates_per_dispatch=1: "
+                "the shadow audit snapshots state at update granularity "
+                "(runtime/sentinel.py)")
+        _attach_trace_file(config)
+        self._arm()
+        config = self.config
 
-    stages.enter("setup/restore")
-    ckpt = CheckpointManager(config.logdir, config.checkpoint_interval_s,
-                             config.checkpoint_keep)
-    restored = ckpt.restore(target=state)
-    if restored is not None:
-        start_updates, host_state = restored
-        state = learner.place_state(host_state)
-        # Same topology-agnostic resume contract as the host backend
-        # (single-process here, so a reshard means a device-count
-        # change — e.g. a debug resume of an 8-device run on 1).
-        ckpt.verify_after_reshard(start_updates, state)
-        log.info("restored checkpoint at update %d (%.0f frames); the "
-                 "device env rollout restarts from fresh episodes (like "
-                 "the host pipeline's env processes)",
-                 start_updates, _host_scalar(state.env_frames))
-    else:
-        start_updates = 0
+        # Probe the HOST twin of the level so action/observation specs
+        # stay in lock-step with the device env.  For the fake family
+        # the twin is the mirrored envs/fake.py implementation; for
+        # device-native levels (device_*) it is the HostDeviceEnv
+        # adapter driving the same transition function.
+        stages.enter("setup/probe_env")
+        observation_spec, action_space, _ = probe_env(config)
+        stages.enter("setup/build_agent")
+        self.agent = build_agent(config, action_space,
+                                 observation_spec.frame.shape)
+        stages.enter("setup/device_env")
+        self.env = make_device_env(
+            config.level_name, height=config.height, width=config.width,
+            # Composite spaces have no .n; make_device_env rejects their
+            # levels with a clear error before num_actions matters.
+            num_actions=getattr(action_space, "n", 0),
+            num_action_repeats=config.num_action_repeats,
+            with_instruction=config.use_instruction)
+        host_frame = tuple(observation_spec.frame.shape)
+        device_frame = tuple(self.env.observation_spec.frame.shape)
+        if host_frame != device_frame:
+            raise ValueError(
+                f"host/device observation drift: host frame {host_frame} "
+                f"!= device mirror {device_frame} (envs/fake.py and "
+                f"envs/device/ must stay in lock-step)")
 
-    stages.enter("setup/observability")
-    timing = Timing()
-    updates = start_updates
-    # One dispatch = K fused updates (the megaloop): the host loop's
-    # counters, ledger records, and checkpoint/preemption decisions all
-    # advance at dispatch granularity.
-    updates_per_dispatch = config.updates_per_dispatch
-    frames_per_dispatch = (config.frames_per_update()
-                           * updates_per_dispatch)
-    frames = _host_scalar(state.env_frames)
-    last_log = time.monotonic()
-    frames_at_last_log = frames
-    metrics = {}
-    # Setup immediately before the try that owns teardown: nothing can
-    # raise in between, so its hooks can't leak (the trace file is
-    # train()'s to close either way).
-    obs_handles = _setup_observability(config, coordinator=True)
-    registry, prom = obs_handles.registry, obs_handles.prom
-    # Single-process fleet: only the preemption-grace protocol arms
-    # (no peers to heartbeat) — SIGTERM drains to one final verified
-    # checkpoint inside --preemption_grace_s instead of dump-and-die.
-    fleet = configure_fleet(
-        config.peer_timeout_s,
-        preemption_grace_s=config.preemption_grace_s,
-        collective_timeout_s=config.collective_timeout_s,
-        registry=registry,
-        recorder=get_flight_recorder(),
-        epoch=config.fleet_epoch,
-        logdir=config.logdir)
-    # Ledger in the fused backend: there is no host pipeline to stamp,
-    # but the records are no longer degenerate — each update opens a
-    # record at dispatch, and the whole in-flight stream retires at the
-    # NEXT log-interval metrics fetch (the loop's only real device
-    # sync), so birth→retire measures the true dispatch-to-
-    # materialization latency of the fused stream (the device segment
-    # = the in-flight window, matching the host backend's semantics)
-    # and the retire rate drives the live MFU gauge honestly.
-    ledger = configure_ledger(
-        registry=registry,
-        # One ledger record per DISPATCH: its frame volume is the K
-        # fused updates' worth, so retire-rate-derived MFU and fps stay
-        # honest under the megaloop.
-        frames_per_trajectory=frames_per_dispatch,
-        logdir=config.logdir,
-        process_index=0)
-    stages.enter("setup/live_mfu")
-    _configure_live_mfu(
-        ledger,
-        lambda: trainer.train_step.lower(state, carry, np.int32(0)),
-        learner.mesh.devices.size,
-        updates_per_execution=updates_per_dispatch)
-    # What is left before the loop (the health plane, the metrics
-    # writer and its TensorBoard import) is a stage of its own, not the
-    # gauge's.
-    stages.enter("setup/loop_entry")
+        stages.enter("setup/build_learner")
+        self._build_learner(action_space, observation_spec.frame.shape)
+        self.emitting = (config.replay_ratio > 0
+                         or config.sentinel_interval > 0)
+        self.trainer = self._make_trainer()
+        stages.enter("setup/trainer_init")
+        state, self.carry = self.trainer.init(jax.random.key(config.seed))
+        # The device env's rollout restarts from fresh episodes on a
+        # restore, like the host pipeline's env processes.
+        stages.enter("setup/restore")
+        state = self._restore(state)
+
+        stages.enter("setup/observability")
+        self._bring_up_services()
+        stages.enter("setup/live_mfu")
+        # XLA's cost analysis counts a lax.scan body ONCE whatever its
+        # trip count, so the lowered flops cover one update while a
+        # retired ledger record covers K.
+        _configure_live_mfu(
+            self.ledger, lambda: self.lower_step(state),
+            self.learner.mesh.devices.size,
+            updates_per_execution=self.updates_per_step)
+        # What is left before the loop (the health plane, the metrics
+        # writer and its TensorBoard import) is a stage of its own, not
+        # the gauge's.
+        stages.enter("setup/loop_entry")
+        self._enter_loop(state)
+        return state
+
+    def next_batch(self):
+        return None, self.ledger.open("ingraph", self.config.level_name)
+
+    def dispatch(self, state, trajectory, ledger_id, updates):
+        tracer = get_tracer()
+        with tracer.span("learner/train_step", cat="learner",
+                         args=({"update": updates}
+                               if tracer.enabled else None)):
+            # The update counter keys the rollout rng
+            # (jax.random.fold_in), so resume continues the exact
+            # action-sampling stream the interrupted run would have
+            # used.  Called through the instance attribute each time.
+            out = self.trainer.train_step(state, self.carry,
+                                          np.int32(updates))
+        self.ledger.stamp(ledger_id, "dispatch")
+        self.pending_tids.append(ledger_id)
+        state, self.carry, dispatched = out[:3]
+        return state, dispatched, (out[3] if self.emitting else None)
+
+    def replay_insert(self, trajectory):
+        # No transport in this backend, so no packed buffer to store:
+        # the unroll's device-born Trajectory goes straight into the
+        # slab (the per-leaf slabs carry the batch sharding the rollout
+        # constrains).
+        self.replay.insert(trajectory)
+
+    def replay_update(self, state, trajectory, dispatched):
+        state, telemetry, replayed = self.trainer.replay_step(
+            state, self.carry.telemetry, trajectory)
+        self.carry = self.carry._replace(telemetry=telemetry)
+        # The replayed dict carries loss keys only: the FRESH step's
+        # metrics keep the log line's episode stats, with the loss
+        # readings of the last replayed update (the freshest params).
+        return state, dict(dispatched, **replayed)
+
+    def retire(self, dispatched):
+        # Bound the open-record stream: a run fast enough to dispatch
+        # thousands of steps inside one log interval would overflow the
+        # ledger's open-record table and trip its eviction path.
+        if len(self.pending_tids) >= _INGRAPH_PENDING_CAP:
+            jax.block_until_ready(dispatched["total_loss"])
+            self.synced()
+        return dispatched
+
+    def synced(self):
+        for tid in self.pending_tids:
+            self.ledger.close(tid, retired=True)
+        self.pending_tids.clear()
+
+    def fetch(self, metrics):
+        """Per-unroll episode means appear only when episodes actually
+        finished, and frames are simulator frames (agent steps x
+        num_action_repeats): the host backend's contract, for the
+        logged rows and the returned dict alike."""
+        host_metrics = super().fetch(metrics)
+        if host_metrics.pop("episodes_completed", 0) < 1:
+            host_metrics.pop("episode_return", None)
+            host_metrics.pop("episode_frames", None)
+        elif "episode_frames" in host_metrics:
+            host_metrics["episode_frames"] *= (
+                self.config.num_action_repeats)
+        return host_metrics
+
+    def publish_telemetry(self):
+        # Env episodes + learner update instruments ride the donated
+        # carry.
+        if self.trainer is not None:
+            self.trainer.publish_telemetry(self.carry)
+
+    def adopt(self, learner, agent):
+        # Rebuild the fused trainer around the demoted learner.  The
+        # rollout carry is env-side state and rides through unchanged —
+        # the rollout rng is keyed by the update counter, so the action
+        # stream stays continuous — and device telemetry rides the
+        # carry, so it survives the swap as it is.
+        self.learner, self.agent = learner, agent
+        self.trainer = self._make_trainer()
+
+    def after_rollback(self, state, updates):
+        # The rollout carry is env-side state, not params: it rides
+        # through the rollback like the host backend's env processes
+        # do.  The in-graph streak peak is the abandoned timeline's.
+        if self.carry.streak_peak is not None:
+            self.carry = self.carry._replace(
+                streak_peak=jnp.zeros((), jnp.float32))
+
+    def lower_step(self, state):
+        return self.trainer.train_step.lower(state, self.carry,
+                                             np.int32(0))
+
+    def drain(self, dispatched):
+        if self.pending_tids and dispatched:
+            # One final materialization retires every still-pending
+            # record (otherwise finalize() would sweep real retires as
+            # "abandoned").
+            jax.block_until_ready(dispatched["total_loss"])
+            self.synced()
+        return None
+
+    def after_teardown(self, trace_path, state):
+        _write_op_scopes(trace_path, self.trainer, state, self.carry)
+
+
+def _run(backend: _Backend) -> Dict[str, float]:
+    """The training loop: set the backend up, step it until
+    ``total_environment_frames``, tear everything down.  One iteration
+    is one fresh dispatch, the replayed updates that chase it, the
+    profiling windows' ends, the publish when the log interval has
+    elapsed, and the decision point (rollback, preemption, checkpoint)
+    every process reaches on the same iteration."""
+    b = backend
+    stages = b.stages
     profiling = False
-    profile_stop_at = None
-    if restored is not None:
-        fleet.note_checkpoint(start_updates)
-    watchdog = get_watchdog()
-    nonfinite = NonFiniteTracker(config.nonfinite_tolerance,
-                                 registry=registry)
-    # A resumed run must not re-count the checkpoint's lifetime skips.
-    nonfinite.rebase(_host_scalar(state.nonfinite_skips))
-    # Run-health plane, same wiring as the host backend (no stall
-    # attributor here — the fused loop has no host pipeline to time,
-    # so anomaly records carry the ledger attribution only).
-    health = _HealthPlane(config, backend="ingraph")
-    injector = get_fault_injector()
+    completed = False
+    trace_path = None
+    state = None
+    metrics = {}
     try:
-        # Context-managed writer: the JSONL handle can't leak when the
-        # loop (or checkpointing) raises.
-        with MetricsWriter(config.logdir, registry=registry) as writer:
-            # Updates dispatched but not yet known-materialized: their
-            # ledger records retire together at the next metrics fetch.
-            pending_tids: List[int] = []
-            # Same compile-window discipline as the host backend: the
-            # first dispatch and the post-demotion trainer re-jit run
-            # with the learner heartbeat suspended.
-            rejit_pending = True
-            while frames < config.total_environment_frames:
-                if (config.profile_dir and not profiling
-                        and not health.window_open
-                        and profile_stop_at is None
-                        and updates - start_updates
-                        >= config.profile_start_update):
-                    # Same --profile_dir window as the host backend —
-                    # the capture the kernel ledger joins below.  >=,
-                    # not ==: the megaloop advances ``updates`` in
-                    # strides of K, which need not land exactly on
-                    # profile_start_update (the one-shot gate is the
-                    # still-None profile_stop_at).
-                    jax.profiler.start_trace(config.profile_dir)
-                    get_tracer().set_annotate(True)
-                    profiling = True
-                    profile_stop_at = updates + config.profile_num_updates
-                ledger_tid = ledger.open("ingraph",
-                                         config.level_name)
-                if rejit_pending:
-                    watchdog.suspend("learner")
-                    rejit_pending = False
-                    if updates == start_updates:
-                        stages.enter("setup/first_dispatch")
-                tracer = get_tracer()
-                with timing.time_avg("update"), \
-                        tracer.span("learner/train_step", cat="learner",
-                                    args=({"update": updates}
-                                          if tracer.enabled else None)):
-                    # The update counter keys the rollout rng
-                    # (jax.random.fold_in), so resume continues the exact
-                    # action-sampling stream the interrupted run would
-                    # have used.
-                    if sentinel is not None and sentinel.audit_due(
-                            updates):
-                        # Pre-update snapshot for the shadow audit
-                        # below — train_step donates (state, carry),
-                        # so the audit needs its own buffers.
-                        audit_snap = sentinel.snapshot(state)
-                    else:
-                        audit_snap = None
-                    if not emitting:
-                        state, carry, metrics = trainer.train_step(
-                            state, carry, np.int32(updates))
-                    else:
-                        state, carry, metrics, fresh_traj = (
-                            trainer.train_step(state, carry,
-                                               np.int32(updates)))
-                ledger.stamp(ledger_tid, "dispatch")
-                pending_tids.append(ledger_tid)
-                if stages.open == "setup/first_dispatch":
-                    # Set-up ends here (the first dispatch blocks
-                    # through the megaloop's compile); the supervisor's
-                    # startup-cost beacon goes out with it.
-                    stages.done()
-                    _write_mttr_breakdown(config, stages)
-                # Chaos: the same deterministic mid-run slowdown as the
-                # host backend (occurrences count dispatches), timed as
-                # update work so the interval's fps sag is attributable.
+        state = b.setup()
+        config, fleet, ledger, health = b.config, b.fleet, b.ledger, b.health
+        sentinel, replay, ckpt = b.sentinel, b.replay, b.ckpt
+        registry, prom = b.obs.registry, b.obs.prom
+        nonfinite, writer = b.nonfinite, b.writer
+        timing, interval = b.timing, b.interval
+        watchdog = get_watchdog()
+        injector = get_fault_injector()  # the one setup armed
+        learner_fps_gauge = registry.gauge(
+            "learner/fps", "env frames/s consumed by the learner")
+        start_updates = updates = b.start_updates
+        frames_per_step = config.frames_per_update() * b.updates_per_step
+        # The restored TrainState's env_frames (which drives the LR
+        # schedule) is authoritative — recomputing from the CURRENT
+        # config would silently disagree if batch_size/unroll_length/
+        # num_action_repeats changed between runs.
+        frames = _host_scalar(state.env_frames)
+        last_log = b.log_clock_start
+        frames_at_last_log = frames
+        profile_stop_at = None
+        rollback_wanted = False
+        dispatched = {}
+        # Compile windows are recovery/startup cost, not wedges: the
+        # first dispatch (cold or relaunch compile) and the re-jit
+        # after a sentinel ladder demotion (~13s measured) run with the
+        # learner heartbeat suspended — the same treatment rollback
+        # restore gets — so a tight --watchdog_timeout_s doesn't read
+        # them as hangs.  The post-dispatch touch re-arms.
+        rejit_pending = True
+        while frames < config.total_environment_frames:
+            if (config.profile_dir and profile_stop_at is None
+                    and not health.window_open
+                    and updates - start_updates
+                    >= config.profile_start_update):
+                # Device-level tracing (SURVEY §5.1): --profile_dir
+                # captures a jax.profiler trace of updates
+                # [profile_start_update, +profile_num_updates) — the
+                # capture the kernel ledger joins below.  >=, not ==:
+                # ``updates`` advances in strides (K per dispatch, the
+                # replayed updates) that need not land on the start;
+                # the one-shot gate is the still-None profile_stop_at.
+                jax.profiler.start_trace(config.profile_dir)
+                # Host spans annotate into the device capture only
+                # while it records (TraceAnnotation is ~100x a span;
+                # see Tracer.set_annotate).
+                get_tracer().set_annotate(True)
+                profiling = True
+                profile_stop_at = updates + config.profile_num_updates
+            traj, ledger_tid = b.next_batch()
+            audit_snap = None
+            if sentinel is not None and sentinel.audit_due(updates):
+                # Pre-update snapshot for the shadow audit below: the
+                # step donates its input state, so the audit needs its
+                # own buffers.
+                audit_snap = sentinel.snapshot(state)
+            if rejit_pending:
+                watchdog.suspend("learner")
+                rejit_pending = False
+                if updates == start_updates:
+                    stages.enter("setup/first_dispatch")
+            with timing.time_avg("update"), interval.add_time("update"):
+                state, dispatched, traj = b.dispatch(
+                    state, traj, ledger_tid, updates)
+                # Chaos: a deterministic mid-run slowdown (thermal
+                # throttle / noisy neighbor stand-in) the health plane
+                # must catch — occurrences count fresh dispatches.
+                # Inside the update timing block so the stall
+                # attributor reads it as a slow device.
                 if injector.active and injector.should_fire(
                         "throughput_sag"):
-                    with timing.time_avg("update"):
-                        time.sleep(throughput_sag_s())
-                if audit_snap is not None:
-                    # Shadow audit on the dispatch's emitted trajectory
-                    # (same batch the fused update trained on), before
-                    # any replay updates move the params.  The
-                    # reference arm's own compile (first audit) is
-                    # recovery machinery — heartbeat suspended, same as
-                    # rollback restore; the touch below re-arms.
-                    watchdog.suspend("learner")
-                    with timing.time_avg("audit"):
-                        state = sentinel.audit(audit_snap, fresh_traj,
-                                               state, updates)
-                    audit_snap = None
-                    if sentinel.consume_swap():
-                        # Adopt the demoted learner: rebuild the fused
-                        # trainer around it (one re-jit at the next
-                        # dispatch).  The rollout carry is env-side
-                        # state and rides through unchanged — the
-                        # rollout rng is keyed by the update counter,
-                        # so the action stream stays continuous.  The
-                        # replay slab's lineage is suspect; drop it.
-                        # (Device telemetry rides the trainer CARRY in
-                        # this backend and survives the swap as-is.)
-                        learner = sentinel.learner
-                        agent = sentinel.agent
-                        trainer = InGraphTrainer(
-                            agent, learner, env, config.unroll_length,
-                            config.batch_size, seed=config.seed,
-                            emit_trajectory=emitting,
-                            updates_per_dispatch=updates_per_dispatch)
-                        if replay is not None:
-                            replay.flush()
-                        # The rebuilt trainer re-jits at the next
-                        # dispatch (~13s measured) — suspend across it.
-                        rejit_pending = True
-                    watchdog.touch("learner")
-                if sentinel is not None and sentinel.rollback_pending:
-                    # A breach survived the full degradation ladder:
-                    # roll back to the newest verified checkpoint (or
-                    # exit 73).  Single-process backend — no broadcast
-                    # needed before acting.
-                    state, updates, frames = _rollback_or_exit(
-                        config, ckpt, learner, state, nonfinite,
-                        reason="sentinel",
-                        exit_code=SENTINEL_EXIT_CODE)
-                    sentinel.note_rollback()
+                    time.sleep(throughput_sag_s())
+            watchdog.touch("learner")
+            if stages.open == "setup/first_dispatch":
+                # Set-up ends here (the first dispatch blocks through
+                # the step's compile); the startup-cost beacon for the
+                # supervisor's MTTR decomposition goes out with it.
+                stages.done()
+                _write_mttr_breakdown(config, stages)
+            if audit_snap is not None:
+                # Shadow audit: recompute this batch's grads + param
+                # delta through the reference arm on device and compare
+                # (one D2H bool at audit cadence).  Runs BEFORE the
+                # replayed updates below so the delta compare sees the
+                # fresh update's params, and may demote the ladder.
+                # The reference arm's own compile (first audit) and the
+                # compare are recovery machinery, not progress the
+                # heartbeat should time — suspend like rollback
+                # restore; the touch below re-arms.
+                watchdog.suspend("learner")
+                with timing.time_avg("audit"), interval.add_time("audit"):
+                    state = sentinel.audit(audit_snap, traj, state,
+                                           updates)
+                if sentinel.consume_swap():
+                    # Adopt the demoted learner.  The replay slab's
+                    # lineage is suspect (filled by the breached path)
+                    # — drop it and re-warm.  The demoted rung re-jits
+                    # inside the next dispatch: suspend across it too.
+                    b.adopt(sentinel.learner, sentinel.agent)
                     if replay is not None:
                         replay.flush()
-                    if carry.streak_peak is not None:
-                        carry = carry._replace(
-                            streak_peak=jnp.zeros((), jnp.float32))
-                    last_log = time.monotonic()
-                    frames_at_last_log = frames
-                    continue
-                if replay is not None:
-                    # Same off-policy dial as the host backend: the
-                    # fresh unroll lands in the slab, then R replayed
-                    # updates (env_frames held, no provenance record —
-                    # only their age is observed) chase it.  The
-                    # replayed dict carries loss keys only — the FRESH
-                    # step's metrics keep the log line's episode stats,
-                    # with the loss readings taken from the last
-                    # replayed update (the freshest param state).
-                    replay.insert(fresh_traj)
-                    for _ in range(config.replay_ratio):
-                        with timing.time_avg("update"), \
-                                get_tracer().span(
-                                    "learner/replay_update",
-                                    cat="learner"):
-                            rtraj = replay.sample()
-                            state, tel, replay_metrics = (
-                                trainer.replay_step(
-                                    state, carry.telemetry, rtraj))
-                            carry = carry._replace(telemetry=tel)
-                            metrics = dict(metrics, **replay_metrics)
-                        updates += 1
-                # Bound the open-record stream: a fused run fast enough
-                # to dispatch thousands of updates inside one log
-                # interval would overflow the ledger's open-record
-                # table (8192) and trip its eviction/truncation path.
-                # One explicit materialization per _INGRAPH_PENDING_CAP
-                # updates retires the whole window honestly (the device
-                # stream is in-order) — in the common case the
-                # log-interval fetch below fires first and this never
-                # runs.
-                if len(pending_tids) >= _INGRAPH_PENDING_CAP:
-                    jax.block_until_ready(metrics["total_loss"])
-                    for tid in pending_tids:
-                        ledger.close(tid, retired=True)
-                    pending_tids.clear()
+                    rejit_pending = True
                 watchdog.touch("learner")
-                updates += updates_per_dispatch
-                frames += frames_per_dispatch
-                if profiling and updates >= profile_stop_at:
-                    jax.block_until_ready(metrics["total_loss"])
-                    # The sync above materialized every pending
-                    # dispatch; retire them NOW, before the harvest's
-                    # multi-minute AOT compile below would inflate
-                    # their birth→retire stamps (and the staleness
-                    # histogram) by compile time the updates never saw.
-                    for tid in pending_tids:
-                        ledger.close(tid, retired=True)
-                    pending_tids.clear()
+            if replay is not None:
+                b.replay_insert(traj)
+            # The size gate covers the re-warm-up window after a
+            # rollback/demotion flush: until the slab holds a batch
+            # again the replayed updates are skipped (fresh training
+            # continues at ratio 0) rather than sampling an empty ring.
+            if replay is not None and replay.size >= 1:
+                # The off-policy dial: R replayed updates behind every
+                # fresh batch — on-device sample + update.
+                for _ in range(config.replay_ratio):
+                    with timing.time_avg("update"), \
+                            interval.add_time("update"), \
+                            get_tracer().span("learner/replay_update",
+                                              cat="learner"):
+                        state, dispatched = b.replay_update(
+                            state, replay.sample(), dispatched)
+                    updates += 1
+                    metrics = b.retire(dispatched) or metrics
+                    watchdog.touch("learner")
+            b.after_update(state, updates)
+            updates += b.updates_per_step
+            frames += frames_per_step
+            metrics = b.retire(dispatched) or metrics
+            watchdog.touch("learner")
+            scheduled_ends = profiling and updates >= profile_stop_at
+            if scheduled_ends or (health.window_open
+                                  and updates >= health.window_stop_at):
+                # A profiling window (the scheduled one, or an
+                # anomaly's) ends.  Retire what the wait materializes
+                # NOW, before the harvest's AOT compile of the step at
+                # its production shape (multi-minute on TPU) would
+                # inflate its birth->retire stamps by compile time the
+                # updates never saw; and disarm the learner heartbeat
+                # across that compile like every other healthy long
+                # pause — the next loop touch re-arms.
+                jax.block_until_ready(dispatched["total_loss"])
+                b.synced()
+                watchdog.suspend("learner")
+                lower_fn = functools.partial(b.lower_step, state)
+                if scheduled_ends:
                     jax.profiler.stop_trace()
                     get_tracer().set_annotate(False)
                     profiling = False
                     log.info("profiler trace written to %s",
                              config.profile_dir)
-                    # Disarm the heartbeat across the harvest's AOT
-                    # compile (multi-minute on TPU) — the loop's touch
-                    # below re-arms.
-                    watchdog.suspend("learner")
-                    # ``executions`` is the UPDATE count in the trace
-                    # window: XLA's cost analysis counts a lax.scan
-                    # body once regardless of trip count (verified:
-                    # K=8 lowers to ~the K=1 flops), so flops_total ≈
-                    # one update's flops — and the window runs whole
-                    # dispatches, ceil(profile_num_updates / K) of
-                    # them, each K updates' device time.
-                    profiled_dispatches = -(-config.profile_num_updates
-                                            // updates_per_dispatch)
+                    # The scheduled window doubles as the health
+                    # plane's baseline: anomaly windows report their
+                    # worst-kernel delta against it.
                     health.note_baseline(_harvest_kernel_ledger(
-                        config,
-                        lambda: trainer.train_step.lower(
-                            state, carry, np.int32(0)),
-                        executions=(profiled_dispatches
-                                    * updates_per_dispatch)))
-                if (health.window_open
-                        and updates >= health.window_stop_at):
-                    # Anomaly-triggered window: same sync + retire +
-                    # heartbeat discipline as the scheduled stop above.
-                    jax.block_until_ready(metrics["total_loss"])
-                    for tid in pending_tids:
-                        ledger.close(tid, retired=True)
-                    pending_tids.clear()
-                    watchdog.suspend("learner")
-                    window_dispatches = -(-config.health_window_updates
-                                          // updates_per_dispatch)
+                        config, lower_fn, executions=_whole_dispatches(
+                            config.profile_num_updates,
+                            b.updates_per_step)))
+                else:
+                    # Into kernels.<anomaly_id>.json and back into the
+                    # anomaly record.
                     health.close_window(
-                        lambda: trainer.train_step.lower(
-                            state, carry, np.int32(0)),
-                        executions=(window_dispatches
-                                    * updates_per_dispatch))
-                now = time.monotonic()
-                if now - last_log >= config.log_interval_s:
-                    with tracer.span("driver/log_publish", cat="log",
-                                     args=({"update": updates}
-                                           if tracer.enabled else None)):
-                        # The fetch drains the device queue: until the
-                        # next dispatch the chip idles, which is why the
-                        # whole block and each part of it is a span.
-                        with tracer.span("log/fetch_metrics", cat="log"):
-                            host_metrics = _finalize_ingraph_metrics(
-                                metrics, config)
-                            # The fetch above materialized the newest
-                            # update; the device stream is in-order, so
-                            # every pending dispatch has retired by now.
-                            for tid in pending_tids:
-                                ledger.close(tid, retired=True)
-                            pending_tids.clear()
-                        # Device telemetry (env episodes + learner
-                        # update instruments riding the donated carry):
-                        # the one obs fetch, folded into the registry
-                        # for the prom dump below.
-                        with tracer.span("log/telemetry", cat="log"):
-                            trainer.publish_telemetry(carry)
-                            if sentinel is not None:
-                                sentinel.publish()
-                        with tracer.span("log/ledger", cat="log"):
-                            ledger.publish()
-                        if nonfinite.observe(host_metrics):
-                            state, updates, frames = _rollback_or_exit(
-                                config, ckpt, learner, state, nonfinite)
-                            # The rollout carry is env-side state,
-                            # not params — it rides through the
-                            # rollback like the host backend's env
-                            # processes do.  The in-graph streak peak
-                            # and the replay slab are the abandoned
-                            # timeline's: reset both so neither a stale
-                            # peak nor stale-lineage samples leak past
-                            # the restore.
-                            if replay is not None:
-                                replay.flush()
-                            if carry.streak_peak is not None:
-                                carry = carry._replace(
-                                    streak_peak=jnp.zeros(
-                                        (), jnp.float32))
-                            last_log = time.monotonic()
-                            frames_at_last_log = frames
-                            continue
-                        fps = ((frames - frames_at_last_log)
-                               / (now - last_log))
-                        host_metrics["fps"] = fps
-                        registry.gauge(
-                            "learner/fps",
-                            "env frames consumed per second").set(fps)
-                        timing_summary = timing.summary()
-                        host_metrics.update(
-                            {f"timing/{k}": v
-                             for k, v in timing_summary.items()})
-                        # Run-health step rides the same cadence; no
-                        # stall attributor in the fused loop, so records
-                        # carry ledger attribution only (verdict=None).
-                        with tracer.span("log/health", cat="log"):
-                            if health.active:
-                                health.step(
-                                    {**registry.snapshot(),
-                                     **host_metrics},
-                                    update=updates)
-                                if not profiling:
-                                    health.maybe_open_window(updates)
-                        with tracer.span("log/write", cat="log"):
+                        lower_fn, executions=_whole_dispatches(
+                            config.health_window_updates,
+                            b.updates_per_step))
+
+            now = time.monotonic()
+            if now - last_log >= config.log_interval_s:
+                # The log-time fetches drain the device queue and the
+                # publishes run with nothing dispatched — until the
+                # next dispatch the chip idles, which is why the whole
+                # block and each part of it is a span.
+                tracer = get_tracer()
+                with tracer.span("driver/log_publish", cat="log",
+                                 args=({"update": updates}
+                                       if tracer.enabled else None)):
+                    if not metrics:
+                        # Nothing has been retired yet (the first W-1
+                        # updates of an in-flight window): log the
+                        # newest dispatched update rather than an empty
+                        # dict.
+                        metrics = dispatched
+                    # Right after an audit or ladder demotion the
+                    # device queue carries the recovery path's
+                    # compiles.  That wait is device backlog, not a
+                    # wedged learner: disarm across the fetch section;
+                    # the touch after ledger.publish re-arms.
+                    watchdog.suspend("learner")
+                    with tracer.span("log/fetch_metrics", cat="log"):
+                        host_metrics = b.fetch(metrics)
+                        if metrics is dispatched:
+                            b.synced()
+                    # Only RECORD the verdict here: the log gate runs
+                    # on local wall clocks, and acting inside it would
+                    # let multi-host processes enter the collective
+                    # restore on different iterations.  The rollback
+                    # itself happens at the fixed per-iteration point
+                    # below.
+                    if nonfinite.observe(host_metrics):
+                        rollback_wanted = True
+                    elapsed = now - last_log
+                    fps = (frames - frames_at_last_log) / elapsed
+                    host_metrics["fps"] = fps
+                    learner_fps_gauge.set(fps)
+                    # Machine-readable timing snapshot (Timing.summary):
+                    # the same numbers as the log line.
+                    timing_summary = timing.summary()
+                    host_metrics.update(
+                        {f"timing/{k}": v
+                         for k, v in timing_summary.items()})
+                    with tracer.span("log/telemetry", cat="log"):
+                        b.publish_telemetry()
+                        if sentinel is not None:
+                            sentinel.publish()
+                    # Rates/rho/staleness/MFU land in the registry and
+                    # ride the writer/prom dumps below.
+                    with tracer.span("log/ledger", cat="log"):
+                        ledger.publish()
+                    watchdog.touch("learner")
+                    category, evidence, note = b.publish_extras(
+                        host_metrics, elapsed)
+                    interval.clear()
+                    # Health detectors over the registry stream plus
+                    # this interval's host metrics, with the verdict
+                    # and ledger attribution captured at trip time; a
+                    # fresh trip may arm a profiling window, opened
+                    # here (next update onward profiles) unless the
+                    # scheduled window is live.
+                    with tracer.span("log/health", cat="log"):
+                        if health.active:
+                            health.step(
+                                {**registry.snapshot(), **host_metrics},
+                                update=updates, verdict=category,
+                                evidence=evidence)
+                            if not profiling:
+                                health.maybe_open_window(updates)
+                    with tracer.span("log/write", cat="log"):
+                        if writer is not None:
                             writer.write(updates, host_metrics)
                             # Registry snapshot rows (obs/ prefix): the
                             # per-interval devtel/learn/* series
-                            # obs.report's staleness↔clipping join and
-                            # obs.diagnose read — the host backend has
-                            # always written these.
+                            # obs.report and obs.diagnose read.
                             writer.write_registry(updates)
-                        with tracer.span("log/prom", cat="log"):
-                            if prom is not None:
-                                prom.dump()
-                        log.info(
-                            "update %d frames %.3g fps %.0f loss %.3f "
-                            "return %s | %s",
-                            updates, frames, fps,
-                            host_metrics.get("total_loss", float("nan")),
-                            f"{host_metrics.get('episode_return', float('nan')):.2f}",
-                            " ".join(f"{k} {v:.4f}s"
-                                     for k, v in timing_summary.items()))
-                        last_log, frames_at_last_log = now, frames
-                if sentinel is not None and updates % 8 == 0:
-                    # Param fingerprint at the host backend's broadcast
-                    # cadence.  Single-process, so there is no peer to
-                    # compare against — the gauge (and the
-                    # replica_diverge chaos point's occurrence
-                    # counting) still ride it, and a postmortem can
-                    # diff two runs' series.
-                    sentinel.local_fingerprint(state.params)
-                if fleet.preemption_requested():
-                    # Same per-iteration decision point as the host
-                    # backend (single-process, so no broadcast): fall
-                    # through to the forced final save below and exit
-                    # cleanly inside the grace window.
-                    fleet.note_preempt_decision(updates)
-                    log.warning(
-                        "preemption drain: stopping at update %d "
-                        "(%.3g frames) for the final checkpoint",
-                        updates, frames)
-                    break
-                if ckpt.maybe_save(updates, state):
-                    fleet.note_checkpoint(updates)
-            # Same shutdown-tail disarm as the host backend: the final
-            # forced save must not trip (or be aborted by) the watchdog.
-            watchdog.suspend("learner")
-            if pending_tids and metrics:
-                # Clean-exit drain: one final materialization retires
-                # every still-pending record (otherwise finalize()
-                # would sweep real retires as "abandoned").
-                _finalize_ingraph_metrics(metrics, config)
-                for tid in pending_tids:
-                    ledger.close(tid, retired=True)
-                pending_tids.clear()
-            if ckpt.maybe_save(updates, state, force=True):
+                    with tracer.span("log/prom", cat="log"):
+                        if prom is not None:
+                            prom.dump()
+                    log.info(
+                        "update %d frames %.3g fps %.0f loss %.3f "
+                        "return %s | %s%s",
+                        updates, frames, fps,
+                        host_metrics.get("total_loss", float("nan")),
+                        f"{host_metrics.get('episode_return', float('nan')):.2f}",
+                        " ".join(f"{k} {v:.4f}s"
+                                 for k, v in timing_summary.items()),
+                        note)
+                    last_log, frames_at_last_log = now, frames
+            # Rollback AND preemption decisions at a point EVERY
+            # process reaches on the SAME iteration, with the
+            # coordinator's verdict broadcast — the divergent-local-
+            # clocks discipline maybe_save applies to its save decision
+            # — so the collective restore inside _rollback_or_exit (or
+            # the coordinated preemption drain) is entered by all
+            # processes together.  The multi-host broadcast is gated on
+            # the update counter (identical on every process, unlike
+            # wall clocks) every 8 updates, so the hot loop doesn't pay
+            # a second per-update collective; the added detection
+            # latency is dwarfed by the log-interval gate above for
+            # rollback and by the grace window for preemption.  A
+            # SIGTERM'd process must NOT act on its local flag alone:
+            # entering the final-save collective while peers keep
+            # training is exactly the unpaired-collective hang this
+            # layer exists to prevent — the KV flag carries the signal
+            # to the coordinator, whose broadcast verdict commits
+            # everyone at once.
+            do_rollback = rollback_wanted
+            rollback_reason = "nonfinite"
+            do_preempt = fleet.preemption_requested()
+            # Param fingerprint at the decision-broadcast cadence: an
+            # update-counter gate, so the multi-process allgather below
+            # is issued on the same iteration everywhere.  A single
+            # process has no peer to compare against — the gauge (and
+            # the replica_diverge chaos point's occurrence counting)
+            # still ride it.
+            fingerprint = None
+            if sentinel is not None and updates % 8 == 0:
+                fingerprint = sentinel.local_fingerprint(state.params)
+            if jax.process_count() > 1:
+                do_rollback = do_preempt = False
+                if updates % 8 == 0:
+                    from jax.experimental import multihost_utils
+
+                    with fleet.collective("decision_broadcast"):
+                        verdict = multihost_utils.broadcast_one_to_all(
+                            np.asarray([rollback_wanted,
+                                        fleet.preemption_requested()]))
+                        if fingerprint is not None:
+                            gathered = multihost_utils.process_allgather(
+                                np.asarray([fingerprint], np.float64))
+                    do_rollback = bool(verdict[0])
+                    do_preempt = bool(verdict[1])
+                    if (fingerprint is not None
+                            and sentinel.check_fingerprints(gathered)):
+                        # Replicas disagree bit-exact: SDC or a
+                        # divergent replica.  Every process sees the
+                        # same gathered set, so every process reaches
+                        # this verdict together — no extra broadcast.
+                        do_rollback = True
+                        rollback_reason = "sentinel"
+            if sentinel is not None and sentinel.rollback_pending:
+                # An audit breach survived the full degradation ladder:
+                # the sentinel wants the newest verified checkpoint.
+                # The audit cadence is update-counter gated, so every
+                # process set this flag on the same iteration —
+                # SPMD-consistent without a broadcast.
+                do_rollback = True
+                rollback_reason = "sentinel"
+            if do_preempt:
+                # Coordinated preemption drain: fall through to the
+                # normal shutdown tail below — in-flight updates
+                # drained, ONE forced verified checkpoint (whose
+                # internal broadcast/allgather every process now
+                # reaches together), clean exit 0.  The fleet monitor's
+                # grace deadline bounds this whole tail with exit 72.
+                fleet.note_preempt_decision(updates)
+                log.warning(
+                    "preemption drain: stopping at update %d "
+                    "(%.3g frames) for the coordinated final "
+                    "checkpoint", updates, frames)
+                break
+            if do_rollback:
+                rollback_wanted = False
+                state, updates, frames = _rollback_or_exit(
+                    config, ckpt, b.learner, state, nonfinite,
+                    reason=rollback_reason,
+                    exit_code=(SENTINEL_EXIT_CODE
+                               if rollback_reason == "sentinel"
+                               else NONFINITE_EXIT_CODE))
+                # Nothing from the abandoned timeline may leak forward:
+                # not its metrics, and not the replay slab (stale-
+                # lineage samples must not feed post-restore updates;
+                # the off-policy dial re-warms from fresh batches).
+                metrics = {}
+                if replay is not None:
+                    replay.flush()
+                if sentinel is not None and rollback_reason == "sentinel":
+                    sentinel.note_rollback()
+                b.after_rollback(state, updates)
+                last_log = time.monotonic()
+                frames_at_last_log = frames
+                interval.clear()
+                continue
+            if ckpt.maybe_save(updates, state):
+                # The membership verdict (fleet_epoch.json) names the
+                # newest resumable step — the elastic supervisor's
+                # answer to "where will the resharded fleet resume".
                 fleet.note_checkpoint(updates)
-        trace_path = get_tracer().path  # a clean end: see the finally
+        # Disarm before the shutdown tail (final forced checkpoint,
+        # pool joins, writer close): a slow-but-healthy shutdown must
+        # not read as a stalled_thread wedge — and must never be
+        # os._exit'ed mid-checkpoint under --watchdog_abort.
+        watchdog.suspend("learner")
+        # The returned metrics are the NEWEST update's.
+        metrics = b.drain(dispatched) or metrics
+        if ckpt.maybe_save(updates, state, force=True):
+            fleet.note_checkpoint(updates)
+        completed = True
+        trace_path = get_tracer().path  # the teardown closes the tracer
     finally:
-        # Same verdict-first contract as train(): the membership
-        # verdict must beat any teardown abort (no-op single-process).
+        # Membership verdict FIRST: an exception unwinding a
+        # multi-process run is usually a peer's death arriving as an
+        # aborted collective, and jax's own client fatal (SIGABRT) can
+        # end this process anywhere in the teardown below — the
+        # elastic supervisor's epoch-stamped verdict must already be
+        # on disk by then (fleet.note_fatal_error no-ops on clean
+        # exits, single-process runs, and when the monitor's richer
+        # verdict already landed).
         import sys as _sys
 
         _exc = _sys.exc_info()[1]
         if _exc is not None and not isinstance(
                 _exc, (SystemExit, KeyboardInterrupt)):
-            fleet.note_fatal_error(_exc)
-        configure_watchdog(None)  # same teardown-tail disarm as train()
-        configure_faults("")
+            get_fleet().note_fatal_error(_exc)
+        # Disarm the watchdog for the WHOLE teardown tail — the
+        # exception path skips the loop-exit suspend above, and pool
+        # joins/writer/ckpt closes must never be os._exit(70)'d by a
+        # heartbeat that simply stopped because the run is ending.
+        # (The exception dump in _teardown_observability still runs.)
+        configure_watchdog(None)
+        configure_faults("")  # chaos spec must not outlive its run
         if profiling:
             jax.profiler.stop_trace()
-        health.finalize()
+        # Construction may have failed partway: clean up whatever
+        # exists (None-guards), and always flush/close the obs state.
+        # Health teardown BEFORE the obs teardown's final prom dump so
+        # health/* counters land in the last snapshot.
+        if b.health is not None:
+            b.health.finalize()
+        b.stop()
+        # Ledger finalize AFTER the pipeline threads stopped (no new
+        # stamps) and BEFORE the final prom dump, so the snapshot shows
+        # the swept state: in-pipeline records closed as abandoned,
+        # zero open records on a clean exit, ledger.p<proc>.json on
+        # disk.
+        if b.ledger is not None:
+            try:
+                b.ledger.finalize()
+            except Exception:
+                log.exception("ledger finalize failed")
+        # Final telemetry publish BEFORE the final prom dump, on both
+        # exit paths: a run (or run tail) shorter than log_interval_s
+        # never hit the interval gate, and the final metrics.prom would
+        # show devtel/* absent or frozen at the last fetch.  Guarded —
+        # on the exception path the device buffers may be donated
+        # husks, or the set-up never got as far as having any.
         try:
-            get_ledger().finalize()
-        except Exception:
-            log.exception("ledger finalize failed")
-        # Final telemetry publish BEFORE the teardown's prom dump — on
-        # BOTH exit paths: a run (or run tail) shorter than
-        # log_interval_s never hit the interval gate, and a crash's
-        # final metrics.prom would show devtel/* absent or frozen at
-        # the last fetch while host counters show the true totals.
-        # Guarded — an exception mid-train_step leaves ``carry``
-        # holding donated husks.
-        try:
-            trainer.publish_telemetry(carry)
+            b.publish_telemetry()
         except Exception:
             log.exception("final device-telemetry publish failed")
-        if sentinel is not None:
+        if b.sentinel is not None:
             try:
-                sentinel.publish()
+                b.sentinel.publish()
             except Exception:
                 log.exception("final sentinel-telemetry publish failed")
-        ckpt.close()
-        _teardown_observability(config, obs_handles)
-        configure_fleet(None)  # after obs: covers the whole tail
-    _write_op_scopes(trace_path, trainer, state, carry)
-    return _finalize_ingraph_metrics(metrics, config)
+        if b.writer is not None:
+            b.writer.close()
+        if b.ckpt is not None:
+            b.ckpt.close()
+        if b.obs is not None:
+            _teardown_observability(b.config, b.obs)
+        if completed and jax.process_count() > 1:
+            # No process may exit (tearing down the coordination
+            # service) until every process finished its checkpoint IO.
+            # Skipped on the EXCEPTION path: a failed process must not
+            # block in a barrier its healthy peers (stuck inside their
+            # own collectives) can never reach — dying fast surfaces
+            # the error and unblocks everyone.
+            from jax.experimental import multihost_utils
+
+            with get_fleet().collective("train_exit_barrier"):
+                multihost_utils.sync_global_devices("train_exit")
+        # Fleet teardown LAST: peer-loss detection and the preemption
+        # grace deadline must cover the whole teardown tail — a peer
+        # dying during the final save or exit barrier is still a
+        # bounded exit 72, not a hang.
+        configure_fleet(None)
+    b.after_teardown(trace_path, state)
+    return b.fetch(metrics)
 
 
-def _finalize_ingraph_metrics(metrics, config: Config) -> Dict[str, float]:
-    """Device metrics -> host dict with the episode-stat contract the
-    host backend keeps: per-unroll episode means appear only when
-    episodes actually finished, and frames are simulator frames
-    (agent steps x num_action_repeats).  Applied to BOTH the logged
-    rows and train_ingraph's return value so they can never disagree."""
-    host_metrics = {k: _host_scalar(v) for k, v in metrics.items()}
-    if host_metrics.pop("episodes_completed", 0) < 1:
-        host_metrics.pop("episode_return", None)
-        host_metrics.pop("episode_frames", None)
-    elif "episode_frames" in host_metrics:
-        host_metrics["episode_frames"] *= config.num_action_repeats
-    return host_metrics
+def _whole_dispatches(updates: int, updates_per_step: int) -> int:
+    """The updates a profiling window of ``updates`` really holds: it
+    runs whole dispatches, ceil(updates / K) of them.  (The lowered
+    step's flops are ONE update's whatever K — see
+    ``_configure_live_mfu`` — so the harvest wants the update count.)"""
+    return -(-updates // updates_per_step) * updates_per_step
 
 
 def _eval_loop(envs, config: Config, agent: ImpalaAgent, params, step_fn,

@@ -149,6 +149,7 @@ _COMPUTATION_RE = re.compile(
 # ``to_apply=``.  Conditional's ``branch_computations={...}`` is a
 # list and is left to the elementwise fallback.
 _CALLS_RE = re.compile(r"(?:calls|body|to_apply)=%?([\w.\-]+)")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 # The jax.named_scope breadcrumbs inside the instruction metadata's
 # op_name — how device time attributes to pipeline stages inside one
 # fused program (runtime/ingraph.py wraps its three phases in these
@@ -328,8 +329,21 @@ def parse_hlo_kernel_costs(hlo_text: str) -> Dict[str, Dict[str, float]]:
             "op": m.group("op"),
             "result": _parse_shapes(m.group("shape")),
             "operands": _parse_shapes(m.group("args")),
+            "args": m.group("args"),
             "attrs": m.group("attrs"),
         })
+    # Newer XLA prints an operand by name alone (``dot(%a, %b)``): its
+    # shape is the result shape of the instruction (or parameter) of
+    # that name.
+    results = {instr["name"]: instr["result"]
+               for instrs in computations.values() for instr in instrs}
+    for instrs in computations.values():
+        for instr in instrs:
+            if not instr["operands"]:
+                instr["operands"] = [
+                    shape for name in _OPERAND_NAME_RE.findall(
+                        instr["args"])
+                    for shape in results.get(name, ())]
 
     # Pass 2: per-computation flops sums (for fusion/call resolution),
     # resolved iteratively so nesting order in the text doesn't matter.

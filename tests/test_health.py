@@ -18,6 +18,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import urllib.error
 import urllib.request
 
@@ -666,12 +667,15 @@ def _health_config(tmp_path, **overrides):
         log_interval_s=0.0,
         seed=5,
         # 12 update-cadence intervals: the first 6 (compile-dominated,
-        # noisy-loss warm-in) only build baselines; the z floor rides
-        # above the batch-2 run's genuine loss swings (4 <-> 21), while
-        # the sag's ~97% relative fps drop trips the rel path on its
-        # own.
+        # noisy-loss warm-in) only build baselines.  The z paths are
+        # off: a batch-2 run's loss and grad-norm swing by multiples
+        # (4 <-> 21; 1.3 <-> 9.5), differently on every host-backend
+        # run (which params an actor thread sees is thread timing), and
+        # a z trip on one of them takes the cooldown the sag's record
+        # needs.  The sag's ~97% relative fps drop trips the rel path
+        # on its own; the z arithmetic has its unit tests above.
         health_warmup_intervals=6,
-        health_z_threshold=6.0,
+        health_z_threshold=1e9,
         health_max_windows=1,
         health_window_updates=2,
     )
@@ -733,7 +737,12 @@ def test_throughput_sag_drives_the_full_anomaly_protocol(
     assert os.path.exists(kernels_json)
     table = json.load(open(kernels_json))
     assert table["kernels"] and table["dominant_kernel"]
-    assert record["window"]["worst_kernel"]
+    # The table's verdicts are written back.  Its worst kernel is the
+    # lowest-MFU one above a share of the window's time that no kernel
+    # with flops need reach in a CPU trace of this tiny update: it may
+    # be None, which the kernel ledger's own tests cover.
+    assert record["window"]["dominant_kernel"] == table["dominant_kernel"]
+    assert record["window"]["worst_kernel"] == table["worst_kernel"]
 
     prom = open(os.path.join(config.logdir, "metrics.prom")).read()
     assert "impala_health_profile_windows_total" in prom
@@ -765,14 +774,36 @@ def test_throughput_sag_drives_the_full_anomaly_protocol(
     assert any(a["id"] == record["id"] for a in machine["anomalies"])
 
 
+class _SteadyClock:
+    """The ``time`` module with a ``monotonic`` that advances one
+    second a call: the loop's fps then depends on no load."""
+
+    def __init__(self):
+        self._now = 0.0
+
+    def monotonic(self):
+        self._now += 1.0
+        return self._now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
 @pytest.mark.chaos
-def test_clean_run_stays_anomaly_free(tmp_path):
-    """The same config without chaos: zero anomalies — the detectors'
-    warm-up + thresholds must absorb normal CPU-run jitter."""
+def test_clean_run_stays_anomaly_free(tmp_path, monkeypatch):
+    """A run without chaos: zero anomalies — the detectors' warm-up +
+    thresholds must absorb a small run's learning noise.  Every
+    detector input is made a function of the seed: the fused backend's
+    losses are (tests/test_one_loop.py holds them to the bit; the host
+    backend's depend on thread timing), and the loop's clock is pinned,
+    so the suite's load (six workers) cannot trip the fps detector."""
+    from scalable_agent_tpu import driver
     from scalable_agent_tpu.driver import train as run_train
     from scalable_agent_tpu.obs import get_registry
 
-    config = _health_config(tmp_path)
+    monkeypatch.setattr(driver, "time", _SteadyClock())
+    config = _health_config(tmp_path, train_backend="ingraph",
+                            health_z_threshold=6.0)
     before = get_registry().snapshot().get("health/anomalies_total", 0.0)
     metrics = run_train(config)
     assert metrics["env_frames"] == 96

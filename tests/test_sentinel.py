@@ -33,7 +33,11 @@ from scalable_agent_tpu.driver import build_sentinel, zero_trajectory
 from scalable_agent_tpu.driver import train as run_train
 from scalable_agent_tpu.envs.spec import TensorSpec
 from scalable_agent_tpu.models import ImpalaAgent
-from scalable_agent_tpu.obs import get_flight_recorder, get_registry
+from scalable_agent_tpu.obs import (
+    configure_flight_recorder,
+    get_flight_recorder,
+    get_registry,
+)
 from scalable_agent_tpu.parallel import MeshSpec, make_mesh
 from scalable_agent_tpu.runtime import (
     Learner,
@@ -197,6 +201,9 @@ class TestDegradationLadder:
     def test_exhaustion_rolls_back_once_then_exits_73(
             self, learner_setup):
         agent, learner, _ = learner_setup
+        # A fresh recorder: the pin is sticky, and an earlier driver
+        # run in this worker may have left its own (a health trip's).
+        configure_flight_recorder(None)
         sentinel = _make_sentinel(agent, learner)
         trips_before = _counter_value("sentinel/trips_total")
         for updates in range(len(LADDER)):
