@@ -61,6 +61,15 @@ def _normalize_frame(frame, dtype):
     return jnp.asarray(frame, dtype) / 255.0
 
 
+def _stem_input(frame, dtype):
+    """The normalised frames as an XLA stem conv's input: the layout
+    hint first, on the bytes (the Pallas stem's forward gives the same
+    one inside ``stem_conv``)."""
+    from scalable_agent_tpu.parallel.mesh import frames_batch_minor
+
+    return _normalize_frame(frames_batch_minor(frame), dtype)
+
+
 def space_to_depth_rearrange(x, kernel):
     """The stem's space-to-depth re-indexing, as one pure function:
     ``(x [N,H,W,C], kernel [8,8,C,F]) -> (x' [N,bh,bw,16C],
@@ -243,7 +252,7 @@ class ShallowConvTorso(nn.Module):
     @nn.nowrap  # no scope or capture of its own when called directly
     def _forward(self, frame):
         pallas_stem = _stem_backend(self.conv_backend)
-        x = _normalize_frame(frame, self.dtype)
+        x = _stem_input(frame, self.dtype)
         for i, (num_ch, filter_size, stride) in enumerate(
                 [STEM_GEOMETRY["shallow"], (64, 4, 2), (128, 3, 2)]):
             if i == 0 and pallas_stem:
@@ -333,7 +342,7 @@ class ResNetTorso(nn.Module):
         else:
             x = nn.Conv(STEM_GEOMETRY["resnet"][0], (3, 3), padding="SAME",
                         dtype=self.dtype, name="downscale_0")(
-                            _normalize_frame(frame, self.dtype))
+                            _stem_input(frame, self.dtype))
         return nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
 
     @nn.compact

@@ -541,6 +541,72 @@ def rematerialized(hlo_text: str) -> dict:
             "checkpointed_instructions": checkpointed}
 
 
+# A computation's header whatever its parameters (_COMPUTATION_RE stops
+# at a tuple parameter's first ``)``, so it misses every loop body).
+_HEADER_RE = re.compile(
+    r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
+_U8_RESULT_RE = re.compile(
+    r"^\s+(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*u8\[(?P<dims>[\d,]+)\]"
+    r"\S*\s+(?P<op>[\w\-]+)\(")
+# Opcodes whose result is their operand's bytes again, in another order
+# or another place; a fusion counts unless it writes in place.
+_RELAYOUT_OPS = frozenset((
+    "copy", "concatenate", "pad", "reshape", "transpose", "fusion"))
+
+
+def frame_relayouts(hlo_text: str) -> List[dict]:
+    """The instructions of a compiled step that write the trajectory's
+    WHOLE uint8 frame tensor out again without computing anything:
+    ``{"name", "op", "dims", "bytes", "op_name"}`` in text order, PER
+    DEVICE as every shape of a partitioned module is.  The frame tensor
+    is the largest ``u8`` array any instruction a device trace names
+    (one outside a fusion's body) results in; a row is a ``copy``,
+    ``concatenate``, ``pad``, ``reshape``, ``transpose`` or a fusion
+    with that many elements as its result.  Not rows: the loop that
+    fills the tensor and a fusion around a ``dynamic-update-slice``
+    (they write a slot in place), bitcasts, tuple plumbing, and what
+    sits inside a fusion's body — the stem weight gradient's pad, which
+    reads the frames once and results in their padded float copy.
+    ISSUE 29: stacked by the rollout's scan, the frames paid a
+    concatenate (XLA lowers it as pad + add) for the overlap entry and
+    a transposing copy for the ``[T+1, B] -> [(T+1)*B]`` merge, 2 x
+    536 MB of results a step on one chip and 3 x on four; written once
+    into the buffer the update reads (runtime/ingraph.py
+    ``_FrameSlots``) there is no row."""
+    fused_bodies = set(_FUSED_BODY_RE.findall(hlo_text))
+    in_place, named, current = set(), [], None
+    for line in hlo_text.splitlines():
+        header = _HEADER_RE.match(line)
+        if header:
+            current = header.group("name")
+        elif current in fused_bodies:
+            if " dynamic-update-slice(" in line:
+                in_place.add(current)
+        else:
+            result = _U8_RESULT_RE.match(line)
+            if result:
+                dims = [int(d) for d in result.group("dims").split(",")]
+                named.append((result, dims, line))
+    whole = max((math.prod(dims) for _, dims, _ in named), default=0)
+    rows = []
+    for result, dims, line in named:
+        if (math.prod(dims) != whole
+                or result.group("op") not in _RELAYOUT_OPS):
+            continue
+        body = _FUSED_BODY_RE.search(line)
+        if body and body.group(1) in in_place:
+            continue
+        scope = _OP_NAME_RE.search(line)
+        rows.append({
+            "name": result.group("name"),
+            "op": result.group("op"),
+            "dims": dims,
+            "bytes": whole,
+            "op_name": scope.group(1) if scope else None,
+        })
+    return rows
+
+
 def write_op_scopes(trace_path: str, hlo_text: str,
                     registry=None) -> str:
     """Leave, beside a run's span trace, the table a device trace needs
@@ -554,7 +620,10 @@ def write_op_scopes(trace_path: str, hlo_text: str,
     (beside the largest collectives and their per-device shapes) and to
     the ``spmd/collective_bytes/<kind>`` gauges; and what the step
     recomputes under ``jax.checkpoint`` (``rematerialized``), to the
-    notes too.  Returns the path written."""
+    notes too; and what it spends writing the trajectory's frames out
+    again (``frame_relayouts``): ``frame_relayout_bytes`` in the notes,
+    with the rows, and the gauge ``fused/frame_relayout_bytes``.
+    Returns the path written."""
     from scalable_agent_tpu.obs.registry import get_registry
 
     ops = {}
@@ -571,12 +640,23 @@ def write_op_scopes(trace_path: str, hlo_text: str,
             f"spmd/collective_bytes/{kind}",
             "bytes one device holds of this kind of collective's "
             "outputs in one run of the compiled fused step").set(value)
+    relayouts = frame_relayouts(hlo_text)
+    relayout_bytes = sum(row["bytes"] for row in relayouts)
+    registry.gauge(
+        "fused/frame_relayout_bytes",
+        "bytes one device writes in one run of the compiled fused step "
+        "to hold the trajectory's whole uint8 frame tensor again "
+        "(copies, concatenates, pads, reshapes): 0 when the rollout "
+        "writes the frames where the update reads them").set(
+            relayout_bytes)
     folder, name = os.path.split(op_scopes_path(trace_path))
     return write_kernels_json(
         folder, {"module": hlo_module_name(hlo_text), "ops": ops,
                  "notes": {"collective_bytes": totals,
                            "largest_collectives": rows[:8],
-                           "rematerialized": rematerialized(hlo_text)}},
+                           "rematerialized": rematerialized(hlo_text),
+                           "frame_relayout_bytes": relayout_bytes,
+                           "frame_relayouts": relayouts[:8]}},
         name=name)
 
 

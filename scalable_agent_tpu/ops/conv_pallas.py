@@ -88,6 +88,11 @@ def _resolve_matmul_dtype(matmul_dtype):
 
 
 def _forward(x, w, stride, normalize=None):
+    if normalize:
+        # The raw-frame entry: see parallel/mesh.py frames_batch_minor.
+        from scalable_agent_tpu.parallel.mesh import frames_batch_minor
+
+        x = frames_batch_minor(x)
     return lax.conv_general_dilated(
         normalize(x) if normalize else x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))

@@ -17,11 +17,13 @@ data-parallel gradient traffic then rides ICI within a slice and DCN
 across slices, chosen by XLA from the device topology.
 """
 
+import functools
 import math
 from typing import NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
@@ -152,6 +154,50 @@ def pallas_interpret() -> bool:
     arithmetic; it never sees a VMEM limit, a tiling rule, or the SPMD
     partitioner (tests/test_chip_bringup.py AOT-compiles for that)."""
     return jax.default_backend() != "tpu"
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _with_layout(x, major_to_minor):
+    return with_layout_constraint(x, Layout(major_to_minor=major_to_minor))
+
+
+def layout_hint(x, major_to_minor):
+    """``x`` with a hint to the TPU compiler: keep it in HBM with its
+    axes in this order, outermost first.  No value changes.  Off a TPU
+    the hint is not given — the same one decision as
+    ``pallas_interpret`` — because the CPU's SPMD partitioner cannot
+    shard through the hint's custom call and gathers its operand.  The
+    hint sits in a ``jit`` of its own, which a compiled program
+    inlines: evaluated op by op (a model's ``init``, an eager
+    ``jax.checkpoint``) it then ends at that small program's result,
+    where a bare ``with_layout_constraint`` would hand the next op an
+    array in an order its executable was not compiled for."""
+    if jax.default_backend() != "tpu":
+        return x
+    return _with_layout(x, tuple(major_to_minor))
+
+
+def frames_batch_minor(frames):
+    """Raw uint8 frames ``[N, H, W, C]`` on their way into a stem's
+    FORWARD conv, asked for (``layout_hint``) in the order the TPU
+    compiler gives that conv's input — batch in the lanes, channels in
+    the sublanes — WHILE THEY ARE STILL ONE BYTE A PIXEL.  Without the
+    hint the compiler is free to turn the frames to the conv's order
+    AFTER ``frame / 255`` instead, on the float copy, and then the
+    normalisation is a pass of its own that writes every frame out in
+    bf16 for the conv to read back (1 GiB a step at the fused cell's
+    size).  It did exactly that as soon as the frames reached the
+    update in a buffer whose order is fixed (ISSUE 29,
+    runtime/ingraph.py ``_FrameSlots``), and had always done it to the
+    T=1 acting step, whose stem conv is 1.1 ms a step faster for the
+    hint (my chip run, PR 29).  With it the conv's fusion reads the
+    uint8 frames and converts as it goes.
+
+    Given per use (models/networks.py's XLA stems, ops/conv_pallas.py's
+    forward), not once for all: the hinted frames are a value of their
+    own, and a second reader (a rematerialised forward, the weight
+    gradient's own pad) would make the compiler write them out."""
+    return layout_hint(frames, (1, 2, 3, 0))
 
 
 def fused_kernels_profitable(mesh: Optional[Mesh] = None,

@@ -12,6 +12,17 @@ Per-update semantics match the host pipeline:
 - Trajectory layout is the reference's T+1 overlap layout (first entry of
   unroll k+1 == last entry of unroll k, reference: experiment.py:311-321)
   via the rollout carry.
+- The trajectory's frames — all but a thousandth of its bytes — are
+  written ONCE, by the rollout, where the update reads them
+  (``_FrameSlots``): one buffer of T+1 slots rides the donated carry,
+  slot 0 takes the carry's frame (the overlap entry), scan step t
+  writes slot t+1 in place, and the buffer's order is the order of the
+  update's merged ``[(T+1)*B]`` frames, so the merge is a bitcast.
+  Stacked as the scan's ``ys`` they were written three times — the
+  stack, a concatenate for the overlap entry, a transposing copy for
+  the merge — 8% of a step on one chip and 10% on four that computed
+  nothing (ISSUE 29).  Every other leaf (under 1 MB together) is still
+  stacked and concatenated.
 - The rollout runs under the params of the CURRENT state, i.e. zero
   policy lag.  The host pipeline has >= 1 update of lag (the reference's
   queue + staging design, experiment.py:531,587-597); V-trace corrects
@@ -42,6 +53,7 @@ from scalable_agent_tpu.obs.device_telemetry import (
 from scalable_agent_tpu.obs.trace import get_tracer
 from scalable_agent_tpu.parallel.mesh import (
     batch_sharding,
+    layout_hint,
     replicated_sharding,
 )
 from scalable_agent_tpu.runtime.faults import get_fault_injector
@@ -81,6 +93,15 @@ class TrainCarry(NamedTuple):
     # takes max(streak, peak), and the driver resets the peak to 0 on
     # rollback (the only action that forgives a tolerance breach).
     streak_peak: Any = None
+    # The trajectory's frame buffer (``_FrameSlots``): T+1 slots the
+    # rollout fills in place and the update reads where they lie.  It
+    # is SCRATCH — every slot is overwritten before anything reads it,
+    # so no step depends on what it held on entry — and rides the
+    # donated carry only so that it is born once: made inside the step
+    # it cost a 536 MB fill every step and, living from the rollout's
+    # first write to the stem's weight gradient, 0.55 GiB of the
+    # compiler's heap (AOT, ISSUE 29).  Nothing saves the carry.
+    frames: Any = None
 
 
 def _stack_first(first, seq):
@@ -89,6 +110,87 @@ def _stack_first(first, seq):
         lambda f, r: None if f is None else jnp.concatenate(
             [f[None], r], axis=0),
         first, seq, is_leaf=lambda x: x is None)
+
+
+# Rows of a TPU tile.  The compiled update keeps the merged frames
+# ``u8[(T+1)*B, H, W, C]`` with the batch in the lanes and W in the
+# sublanes (minor to major: N, W, C, H, tiles of 8 x 128 over (W, N)),
+# as XLA keeps any few-channel conv's activations.
+_SUBLANES = 8
+
+
+class _FrameSlots:
+    """The trajectory's frame leaf as ONE buffer of T+1 slots that the
+    rollout fills in place, in the physical order the update reads.
+
+    The frames are nearly all of a trajectory's bytes (536 MB of 537 at
+    256 envs of 72x96x3), and stacked as the scan's ``ys`` they were
+    written three times: by the scan, time-major; by ``_stack_first``'s
+    concatenate, to make room for the overlap entry; and by a
+    transposing copy, because the update's ``[T+1, B] -> [(T+1)*B]``
+    merge wants T INSIDE ``[H][C][W/8]``, directly above the (8 x B)
+    tiles, and a time-major stack has it outermost (ISSUE 29: 3.26 ms
+    of a 40.75 ms step that compute nothing).  Here slot 0 takes the
+    carry's frame, scan step t writes slot t+1 with a
+    ``dynamic_update_slice`` (XLA updates a while-carried buffer in
+    place, which is how ``ys`` are stacked anyway), and the buffer's
+    logical shape is ``[H, C, W/8, T+1, 8, B]``: its row-major order IS
+    the order of the merged frames, so ``frames()``'s transpose back to
+    ``[T+1, B, H, W, C]`` and the agent's merge compile to bitcasts.
+    As a donated argument of the step the buffer arrives row-major;
+    ``write`` asks for it to stay so, or the compiler may turn it to
+    the order of the scan's own frame for the length of the loop and
+    pay two whole copies for that (the ResNet's step did, AOT).
+
+    The order comes from the frame's shape alone.  A frame that is not
+    ``[B, H, W, C]`` with W a whole number of sublanes (the 10x10 and
+    15x15 worlds) has no such split: its slots are stacked time-major,
+    without the concatenate, and the compiler inserts whatever copy it
+    inserted before.  Values never depend on the order."""
+
+    def __init__(self, frame_shape, slots: int, mesh):
+        self._shape = tuple(frame_shape)
+        self._slots = slots
+        self._tiled = (len(self._shape) == 4
+                       and self._shape[2] % _SUBLANES == 0)
+        if self._tiled:
+            batch, height, width, channels = self._shape
+            self._slot_axis, batch_axis = 3, 5
+            self._buffer_shape = (height, channels, width // _SUBLANES,
+                                  slots, _SUBLANES, batch)
+        else:
+            self._slot_axis, batch_axis = 0, 1
+            self._buffer_shape = (slots,) + self._shape
+        # The buffer is sharded as the rollout is: over its batch axis.
+        self.sharding = batch_sharding(mesh, batch_axis)
+
+    def empty(self, dtype):
+        """The buffer, all slots blank, born on its own devices: made
+        whole on one chip first, a four-chip buffer is that chip's
+        high-water mark for the life of the process (5.43 GiB where
+        the step holds 2.18; my chip run, PR 29)."""
+        return jnp.zeros(self._buffer_shape, dtype, device=self.sharding)
+
+    def write(self, buffer, frame, index):
+        """``buffer`` with ``frame`` ``[B, ...]`` in slot ``index``."""
+        if self._tiled:
+            batch, height, width, channels = self._shape
+            frame = frame.reshape(
+                batch, height, width // _SUBLANES, _SUBLANES, channels
+            ).transpose(1, 4, 2, 3, 0)
+        buffer = jax.lax.dynamic_update_slice_in_dim(
+            buffer, jnp.expand_dims(frame, self._slot_axis), index,
+            self._slot_axis)
+        if self._tiled:
+            buffer = layout_hint(buffer, range(buffer.ndim))
+        return jax.lax.with_sharding_constraint(buffer, self.sharding)
+
+    def frames(self, buffer):
+        """The buffer as the trajectory's ``[T+1, B, ...]`` leaf."""
+        if not self._tiled:
+            return buffer
+        return buffer.transpose(3, 5, 0, 2, 4, 1).reshape(
+            (self._slots,) + self._shape)
 
 
 class InGraphTrainer:
@@ -208,7 +310,9 @@ class InGraphTrainer:
             # finite guard is off — the carry structure then matches
             # pre-peak checkpointed runs byte-for-byte.
             streak_peak=(jnp.float32(0.0)
-                         if self._learner._finite_guard else None))
+                         if self._learner._finite_guard else None),
+            frames=self._frame_slots(env_output).empty(
+                env_output.observation.frame.dtype))
         # Commit the carry to the placement the fused step hands back
         # (batch-sharded rollout state, replicated scalars): an
         # uncommitted first carry has different input types from every
@@ -220,7 +324,8 @@ class InGraphTrainer:
                            else replicated), carry.rollout),
             telemetry=replicated,
             streak_peak=(None if carry.streak_peak is None
-                         else replicated)))
+                         else replicated),
+            frames=self._frame_slots(env_output).sharding))
         example = Trajectory(
             agent_state=core_state,
             env_outputs=_stack_first(
@@ -240,7 +345,15 @@ class InGraphTrainer:
 
     # -- the fused program -------------------------------------------------
 
-    def _rollout(self, params, carry: RolloutCarry, rng):
+    def _frame_slots(self, env_output) -> _FrameSlots:
+        return _FrameSlots(env_output.observation.frame.shape,
+                           self._unroll_length + 1, self._learner.mesh)
+
+    def _rollout(self, params, carry: RolloutCarry, rng, frames):
+        """One unroll: ``(trajectory, new carry, frames)``.  ``frames``
+        is the frame buffer (``TrainCarry.frames``), handed back filled
+        with this unroll's T+1 frames; the trajectory's frame leaf is a
+        view of it."""
         agent, env = self._agent, self._env
 
         # The named scopes (here, ``telemetry`` and ``learner_update``
@@ -252,25 +365,42 @@ class InGraphTrainer:
         # obs/kernels.write_op_scopes leaves beside a --trace run's
         # trace) splits a step's device time by layer.  They are
         # metadata: no op, fusion or number depends on them.
+        slots = self._frame_slots(carry.env_output)
+
+        def without_frame(env_output, frame=None):
+            return env_output._replace(
+                observation=env_output.observation._replace(frame=frame))
+
         def scan_fn(c, t):
+            c, frames = c
             with jax.named_scope("actor_inference"):
                 out, core = actor_step(
                     agent, params, jax.random.fold_in(rng, t),
                     c.agent_output.action, c.env_output, c.core_state)
             with jax.named_scope("env_step"):
                 env_state, env_output = env.step(c.env_state, out.action)
-            return RolloutCarry(env_state, env_output, out, core), (
-                env_output, out)
+            # The frame goes to its slot of the one buffer; every other
+            # leaf (under 1 MB together) is stacked as the scan's ys.
+            frames = slots.write(
+                frames, env_output.observation.frame, t + 1)
+            return (RolloutCarry(env_state, env_output, out, core),
+                    frames), (without_frame(env_output), out)
 
         with jax.named_scope("rollout"):
-            new_carry, (env_seq, agent_seq) = jax.lax.scan(
-                scan_fn, carry, jnp.arange(self._unroll_length))
+            # Slot 0: the overlap entry, the previous unroll's last.
+            frames = slots.write(
+                frames, carry.env_output.observation.frame, 0)
+            (new_carry, frames), (env_seq, agent_seq) = jax.lax.scan(
+                scan_fn, (carry, frames),
+                jnp.arange(self._unroll_length))
+        env_outputs = _stack_first(
+            without_frame(carry.env_output), env_seq)
         trajectory = Trajectory(
             agent_state=carry.core_state,
-            env_outputs=_stack_first(carry.env_output, env_seq),
+            env_outputs=without_frame(env_outputs, slots.frames(frames)),
             agent_outputs=_stack_first(carry.agent_output, agent_seq),
         )
-        return trajectory, new_carry
+        return trajectory, new_carry, frames
 
     def _constrain_batch(self, tree):
         return jax.tree_util.tree_map(
@@ -278,15 +408,16 @@ class InGraphTrainer:
             else jax.lax.with_sharding_constraint(x, self._batch_sharding),
             tree, is_leaf=lambda x: x is None)
 
-    def _one_update(self, state, rollout_carry, telemetry, update_index):
+    def _one_update(self, state, rollout_carry, telemetry, update_index,
+                    frames):
         """One fused (rollout + update) iteration — the megaloop's scan
         body.  ``update_index`` is the GLOBAL update counter (it keys
         the rollout rng), so K scanned iterations are the same stream
         as K separate dispatches."""
         rng = jax.random.fold_in(
             jax.random.key(self._seed), update_index)
-        trajectory, new_rollout = self._rollout(
-            state.params, rollout_carry, rng)
+        trajectory, new_rollout, frames = self._rollout(
+            state.params, rollout_carry, rng, frames)
         # Chaos (trace-time): the host backend's ``nan_grad`` hook
         # lives in Learner.update, which this fused path never calls —
         # bake the armed occurrence set into the compiled program and
@@ -332,7 +463,7 @@ class InGraphTrainer:
                 finished, steps, 0)).astype(jnp.float32),
         }
         return new_state, new_rollout, telemetry, metrics, \
-            episode_sums, trajectory
+            episode_sums, trajectory, frames
 
     def _fused(self, state, carry: TrainCarry, counter):
         # Only the rollout state takes the batch-sharding constraint:
@@ -342,10 +473,10 @@ class InGraphTrainer:
         k = self._updates_per_dispatch
 
         def body(loop_carry, update_index):
-            state, rollout_carry, telemetry, peak = loop_carry
+            state, rollout_carry, telemetry, peak, frames = loop_carry
             (state, rollout_carry, telemetry, metrics, episode_sums,
-             trajectory) = self._one_update(
-                state, rollout_carry, telemetry, update_index)
+             trajectory, frames) = self._one_update(
+                state, rollout_carry, telemetry, update_index, frames)
             if peak is not None and "nonfinite_streak" in metrics:
                 # The megaloop's tolerance contract: fold the
                 # post-update streak into the monotone peak each
@@ -355,16 +486,18 @@ class InGraphTrainer:
             ys = (metrics, episode_sums)
             if self._emit_trajectory:
                 ys = ys + (trajectory,)
-            return (state, rollout_carry, telemetry, peak), ys
+            return (state, rollout_carry, telemetry, peak, frames), ys
 
         # K == 1 runs through the SAME scan body: lax.scan compiles the
         # body as its own while-loop computation at any length, so a
         # K-update dispatch is bit-exact with K single-update dispatches
         # (the golden property driver resume / the K knob rely on).
-        (new_state, new_rollout, telemetry, peak), ys = jax.lax.scan(
-            body,
-            (state, rollout_carry, carry.telemetry, carry.streak_peak),
-            counter + jnp.arange(k, dtype=jnp.int32))
+        (new_state, new_rollout, telemetry, peak, frames), ys = (
+            jax.lax.scan(
+                body,
+                (state, rollout_carry, carry.telemetry, carry.streak_peak,
+                 carry.frames),
+                counter + jnp.arange(k, dtype=jnp.int32)))
         metrics_seq, episode_seq = ys[0], ys[1]
         # Scalar gauges (loss, lr, grad_norm, env_frames, ...) read the
         # LAST update's value — the state the dispatch hands back;
@@ -377,7 +510,7 @@ class InGraphTrainer:
         metrics["episode_frames"] = episode_seq["frames_sum"].sum() / denom
         if peak is not None:
             metrics["nonfinite_streak_peak"] = peak
-        out_carry = TrainCarry(new_rollout, telemetry, peak)
+        out_carry = TrainCarry(new_rollout, telemetry, peak, frames)
         if self._emit_trajectory:
             # K == 1 (enforced in __init__): drop the length-1 scan
             # axis so the replay tap sees the plain [T+1, B] pytree.

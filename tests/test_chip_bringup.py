@@ -357,6 +357,68 @@ def _compile_default_update(monkeypatch, topology, devices,
     return agent, (executable if compiled else lowered).as_text()
 
 
+def _compile_default_fused_step(monkeypatch, topology, devices,
+                                **overrides):
+    """Compile the fused (rollout + update) step the driver would build
+    for ``devices`` v5e chips at a benchmark cell's sizes (T=100, 72x96
+    uint8, bf16, 256 envs a chip unless overridden), carry and state as
+    shapes placed on the topology's devices.  The trainer builds its
+    mesh from ``jax.devices()``, so for the length of the test those
+    calls answer with the described devices (``benchmark/aot.py``'s
+    way).  Returns the compiled (partitioned, per-device) text."""
+    from scalable_agent_tpu.envs.device import make_device_env
+    from scalable_agent_tpu.parallel import (
+        batch_sharding,
+        replicated_sharding,
+    )
+    from scalable_agent_tpu.runtime import InGraphTrainer
+
+    chips = list(topology.devices[:devices])
+    _as_tpu(monkeypatch)
+    for name in ("devices", "local_devices"):
+        monkeypatch.setattr(jax, name, lambda *a, **k: chips)
+    for name in ("device_count", "local_device_count"):
+        monkeypatch.setattr(jax, name, lambda *a, **k: len(chips))
+    # No array can live on a described device: placements are no-ops.
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    flags = dict(mesh_data=devices, batch_size=256 * devices,
+                 train_backend="ingraph", level_name="fake_benchmark",
+                 compute_dtype="bfloat16", num_action_repeats=4,
+                 logdir="/tmp/unused")
+    flags.update(overrides)
+    config = Config(**flags)
+    observation_spec, action_space, _ = driver.probe_env(config)
+    agent = driver.build_agent(config, action_space,
+                               observation_spec.frame.shape)
+    learner = driver.build_training_learner(config, agent)
+    env = make_device_env(
+        config.level_name, height=config.height, width=config.width,
+        num_actions=action_space.n,
+        num_action_repeats=config.num_action_repeats)
+    trainer = InGraphTrainer(agent, learner, env, config.unroll_length,
+                             config.batch_size, seed=1)
+    state, carry = jax.eval_shape(trainer.init, jax.random.key(0))
+    replicated = replicated_sharding(learner.mesh)
+
+    def abstract(tree, sharding_of):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding_of(x)), tree)
+
+    rows = batch_sharding(learner.mesh, 0)
+    carry = carry._replace(
+        rollout=abstract(carry.rollout,
+                         lambda x: rows if x.ndim else replicated),
+        telemetry=abstract(carry.telemetry, lambda x: replicated),
+        streak_peak=abstract(carry.streak_peak, lambda x: replicated),
+        frames=abstract(carry.frames, lambda x: trainer._frame_slots(
+            carry.rollout.env_output).sharding))
+    return trainer.train_step.lower(
+        abstract(state, lambda x: replicated), carry,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
+    ).compile().as_text()
+
+
 class TestAotCompileForV5e:
     def test_one_chip_default_keeps_all_three_kernels(
             self, monkeypatch, v5e_topology):
@@ -462,3 +524,48 @@ class TestAotCompileForV5e:
         assert batch_ops == ["bitcast", "fusion", "parameter",
                              "parameter"], entry
         assert "pad(" in text and str(n + 1) not in text
+
+    @pytest.mark.parametrize("devices,overrides,merged", [
+        (1, {}, 101 * 256),
+        (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128),
+        (4, {}, 101 * 256),
+    ], ids=["shallow", "resnet", "shallow-data4"])
+    def test_fused_step_writes_the_frames_once(
+            self, monkeypatch, v5e_topology, devices, overrides, merged):
+        """ISSUE 29, the compiler's verdict on the three fused cells'
+        steps: no instruction results in the whole uint8 frame tensor
+        but the loop that fills the carry's buffer and its in-place
+        slot writes, bitcasts, and (inside its fusion) the stem weight
+        gradient's pad — no concatenate for the overlap entry, no
+        transposing copy for the ``[T+1, B] -> [(T+1)*B]`` merge, on a
+        mesh no per-device reshape (the parent: 1.07 GB of such results
+        a step on one chip, 1.6 GB a chip on four).  And the update's
+        stem forward conv still reads the uint8 frames and converts as
+        it goes (``frames_batch_minor``): no float copy of all the
+        frames is written first."""
+        from scalable_agent_tpu.obs import kernels as kernels_lib
+
+        text = _compile_default_fused_step(
+            monkeypatch, v5e_topology, devices, **overrides)
+        rows = kernels_lib.frame_relayouts(text)
+        assert not rows, (
+            f"{sum(row['bytes'] for row in rows):,} bytes a device a "
+            f"step still go on holding the frame tensor again: {rows}")
+        # The merge of the buffer is there, as a bitcast...
+        assert re.search(
+            r"= u8\[%d,72,96,3\]\S* bitcast\(" % merged, text), merged
+        # ...and no instruction a trace names (one outside a fusion's
+        # body) results in all the frames as floats.
+        bodies = set(re.findall(r"\sfusion\(.*\scalls=%?([\w.\-]+)", text))
+        floats, computation = [], None
+        for line in text.splitlines():
+            header = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$",
+                              line)
+            if header:
+                computation = header.group(1)
+            elif computation not in bodies and re.match(
+                    r"\s+(?:ROOT )?%%?[\w.\-]+ = (?:bf16|f32)"
+                    r"\[%d,72,96,3\]" % merged, line):
+                floats.append(line.split(" = ")[0].strip())
+        assert not floats, floats
+

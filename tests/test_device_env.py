@@ -161,18 +161,108 @@ class TestInGraphTrainer:
         trainer = self.make()
         state, carry = trainer.init(jax.random.key(0))
         rng = jax.random.key(1)
-        # _rollout takes the bare RolloutCarry; the telemetry half of
-        # the TrainCarry rides only the fused step.
-        traj1, carry2 = jax.jit(trainer._rollout)(
-            state.params, carry.rollout, rng)
-        traj2, _ = jax.jit(trainer._rollout)(
-            state.params, carry2, jax.random.key(2))
+        # _rollout takes the bare RolloutCarry and the frame buffer it
+        # fills; the telemetry half of the TrainCarry rides only the
+        # fused step.
+        traj1, carry2, frames = jax.jit(trainer._rollout)(
+            state.params, carry.rollout, rng, carry.frames)
+        traj2, _, _ = jax.jit(trainer._rollout)(
+            state.params, carry2, jax.random.key(2), frames)
         np.testing.assert_array_equal(
             np.asarray(traj1.env_outputs.observation.frame[self.T]),
             np.asarray(traj2.env_outputs.observation.frame[0]))
         np.testing.assert_array_equal(
             np.asarray(traj1.agent_outputs.action[self.T]),
             np.asarray(traj2.agent_outputs.action[0]))
+
+
+class TestFrameSlots:
+    """ISSUE 29: the rollout writes the trajectory's frames once, into
+    the carry's buffer — slot 0 the overlap entry, slot t+1 in scan
+    step t, T inside [H][C][W/8] where W is whole sublanes — and the
+    trajectory's frame leaf is a view of it.  Where the bytes lie is
+    all that changed: every value is what the scan's stacked ``ys``
+    under ``_stack_first``'s concatenate gave."""
+
+    T, B = 5, 4
+    # fake_benchmark at 16x24: W is three sublanes, the tiled order;
+    # the real worlds' 15x15 and 10x10 frames take the time-major one.
+    LEVELS = {"fake_benchmark": dict(height=16, width=24),
+              "device_grid_small": {}, "device_minatar_breakout": {}}
+
+    def make(self, level, k=1, emit_trajectory=False):
+        from scalable_agent_tpu.envs.device import make_device_env
+
+        env = make_device_env(level, **self.LEVELS[level])
+        agent = ImpalaAgent(num_actions=env.num_actions)
+        mesh = make_mesh(MeshSpec(data=1, model=1),
+                         devices=jax.devices()[:1])
+        learner = Learner(agent, LearnerHyperparams(
+            total_environment_frames=1e6), mesh,
+            frames_per_update=self.T * self.B)
+        return InGraphTrainer(agent, learner, env, self.T, self.B,
+                              seed=5, updates_per_dispatch=k,
+                              emit_trajectory=emit_trajectory)
+
+    @staticmethod
+    def stacked_frames(trainer, params, carry, update_index):
+        """The frame leaf as every PR before 29 assembled it: the
+        scan's stacked ys behind the carry's entry."""
+        from scalable_agent_tpu.models.agent import actor_step
+        from scalable_agent_tpu.runtime.ingraph import _stack_first
+
+        rng = jax.random.fold_in(jax.random.key(trainer._seed),
+                                 update_index)
+
+        def scan_fn(c, t):
+            out, core = actor_step(
+                trainer._agent, params, jax.random.fold_in(rng, t),
+                c.agent_output.action, c.env_output, c.core_state)
+            env_state, env_output = trainer._env.step(
+                c.env_state, out.action)
+            return type(c)(env_state, env_output, out, core), (
+                env_output.observation.frame)
+
+        _, frames = jax.lax.scan(scan_fn, carry,
+                                 jnp.arange(trainer._unroll_length))
+        return _stack_first(carry.env_output.observation.frame, frames)
+
+    @pytest.mark.parametrize("level", sorted(LEVELS))
+    def test_frame_leaf_is_the_stacked_assembly(self, level):
+        trainer = self.make(level, emit_trajectory=True)
+        state, carry = trainer.init(jax.random.key(0))
+        tiled = level == "fake_benchmark"
+        assert carry.frames.ndim == (6 if tiled else 5)
+        last = None
+        for update in range(2):
+            want = np.asarray(jax.jit(
+                self.stacked_frames, static_argnums=(0, 3))(
+                    trainer, state.params, carry.rollout, update))
+            state, carry, _, trajectory = trainer.train_step(
+                state, carry, np.int32(update))
+            got = np.asarray(trajectory.env_outputs.observation.frame)
+            assert got.shape == (self.T + 1, self.B) + tuple(
+                trainer._env.observation_spec.frame.shape)
+            assert got.dtype == np.uint8
+            np.testing.assert_array_equal(got, want)
+            if last is not None:        # the T+1 overlap entry
+                np.testing.assert_array_equal(got[0], last)
+            last = got[self.T]
+            assert got[1:].any(), "the world drew nothing"
+
+    @pytest.mark.parametrize("level", sorted(LEVELS))
+    def test_k2_in_one_dispatch_is_two_dispatches(self, level):
+        """The buffer is scratch — every slot is written before it is
+        read — so riding the megaloop's scan carry changes nothing."""
+        one = self.make(level, k=1)
+        state1, carry1 = one.init(jax.random.key(0))
+        state1, carry1, _ = one.run(state1, carry1, 2)
+        two = self.make(level, k=2)
+        state2, carry2 = two.init(jax.random.key(0))
+        state2, carry2, _ = two.run(state2, carry2, 2)
+        for a, b in zip(jax.tree_util.tree_leaves((state1, carry1)),
+                        jax.tree_util.tree_leaves((state2, carry2))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestMegaloop:

@@ -316,6 +316,152 @@ class TestRematerialized:
         assert notes["rematerialized"]["convolutions"] == 1
 
 
+# Condensed from the compiled step of ``shallow.ingraph`` (benchmark/
+# aot.py's way, for a v5e, ISSUE 29), layouts and tilings as printed.
+# The parent: the scan stacks 100 frames time-major into an allocated
+# buffer, ``_stack_first``'s concatenate (pad + add) makes the 101, a
+# copy moves T inside [H][C][W/8] for the merge; the stem weight
+# gradient's pad reads the merged frames inside its own fusion.
+_FRAMES_SCOPE = "jit(_fused)/while/body/closed_call"
+_FRAMES_PARENT = """
+HloModule jit__fused
+
+%fused_computation.152 (param_0.1589: u8[25856,72,96,3]) -> bf16[76,3,112,25856] {
+  %param_0.1589 = u8[25856,72,96,3]{0,2,3,1:T(8,128)(4,1)} parameter(0)
+  %constant.2319 = u8[]{:T(256)} constant(0)
+  %pad.90 = u8[25856,76,112,3]{0,2,3,1:T(8,128)(4,1)} pad(%param_0.1589, %constant.2319), padding=0_0x2_2x2_14x0_0, metadata={op_name="SCOPE/learner_update/transpose(learner_update)/jvp(ImpalaAgent)/convnet/conv_0/jit(_pad)/pad"}
+  %convert.1 = bf16[25856,76,112,3]{0,2,3,1:T(8,128)(2,1)} convert(%pad.90)
+  ROOT %bitcast.3 = bf16[76,3,112,25856]{3,2,1,0:T(8,128)(2,1)} bitcast(%convert.1)
+}
+
+%fused_computation.163 (param_0.566: u8[100,256,72,96,3], param_1.1978: u8[1,256,72,96,3]) -> u8[101,72,3,12,8,256] {
+  %param_1.1978 = u8[1,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)S(1)} parameter(1)
+  %constant.2320 = u8[]{:T(256)} constant(0)
+  %pad.92 = u8[101,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} pad(%param_1.1978, %constant.2320), padding=0_100x0_0x0_0x0_0x0_0, metadata={op_name="SCOPE/concatenate"}
+  %param_0.566 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} parameter(0)
+  %pad.91 = u8[101,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} pad(%param_0.566, %constant.2320), padding=1_0x0_0x0_0x0_0x0_0, metadata={op_name="SCOPE/concatenate"}
+  %add.2566 = u8[101,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} add(%pad.92, %pad.91), metadata={op_name="SCOPE/concatenate"}
+  ROOT %bitcast.101 = u8[101,72,3,12,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} bitcast(%add.2566)
+}
+
+%fused_computation.36 (param_0.1981: u8[100,256,72,96,3], param_1.2341: s32[], param_2.2164: u8[256,72,96,3]) -> u8[100,256,72,96,3] {
+  %param_0.1981 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} parameter(0)
+  %param_2.2164 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)} parameter(2)
+  %bitcast.232 = u8[1,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} bitcast(%param_2.2164)
+  %param_1.2341 = s32[]{:T(128)} parameter(1)
+  %constant.3017 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic_update_slice.149 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} dynamic-update-slice(%param_0.1981, %bitcast.232, %param_1.2341, %constant.3017, %constant.3017, /*index=5*/%constant.3017, %constant.3017), metadata={op_name="SCOPE/rollout/while/body/dynamic_update_slice"}
+}
+
+%region_1.26 (arg_tuple.4: (s32[], u8[256,72,96,3], u8[100,256,72,96,3])) -> (s32[], u8[256,72,96,3], u8[100,256,72,96,3]) {
+  %arg_tuple.4 = (s32[]{:T(128)}, u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)}, u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)}) parameter(0)
+  %get-tuple-element.2959 = s32[]{:T(128)} get-tuple-element(%arg_tuple.4), index=0
+  %get-tuple-element.2968 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)} get-tuple-element(%arg_tuple.4), index=1
+  %get-tuple-element.2978 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} get-tuple-element(%arg_tuple.4), index=2
+  %bitcast_dynamic-update-slice_fusion.7 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} fusion(%get-tuple-element.2978, %get-tuple-element.2959, %get-tuple-element.2968), kind=kLoop, calls=%fused_computation.36, metadata={op_name="SCOPE/rollout/while/body/dynamic_update_slice"}
+  ROOT %tuple.392 = (s32[]{:T(128)}, u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)}, u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)}) tuple(%get-tuple-element.2959, %get-tuple-element.2968, %bitcast_dynamic-update-slice_fusion.7)
+}
+
+ENTRY %main.260 (frame.1: u8[256,72,96,3]) -> bf16[76,3,112,25856] {
+  %frame.1 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)} parameter(0), metadata={op_name="carry.rollout.env_output.observation.frame"}
+  %custom-call.14 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} custom-call(), custom_call_target="AllocateBuffer", metadata={op_name="SCOPE/rollout/broadcast_in_dim"}
+  %while.10 = u8[100,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)} get-tuple-element(%while.71), index=2
+  %bitcast.255 = u8[1,256,72,96,3]{1,3,4,2,0:T(8,128)(4,1)S(1)} bitcast(%frame.1)
+  %add_bitcast_fusion = u8[101,72,3,12,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} fusion(%while.10, %bitcast.255), kind=kLoop, calls=%fused_computation.163
+  %copy.78 = u8[101,72,3,12,8,256]{5,4,0,3,2,1:T(8,128)(4,1)} copy(%add_bitcast_fusion), metadata={op_name="SCOPE/learner_update/jvp(ImpalaAgent)/reshape"}
+  %bitcast.62 = u8[25856,72,96,3]{0,2,3,1:T(8,128)(4,1)} bitcast(%copy.78), metadata={op_name="SCOPE/learner_update/jvp(ImpalaAgent)/reshape"}
+  ROOT %multiply_bitcast_fusion = bf16[76,3,112,25856]{3,2,1,0:T(8,128)(2,1)} fusion(%bitcast.62), kind=kLoop, calls=%fused_computation.152
+}
+""".replace("SCOPE", _FRAMES_SCOPE)
+# The change: the carry's buffer [H, C, W/8, T+1, 8, B] takes slot 0
+# and, in the scan, slot t+1, both in place; the merge is a bitcast.
+_FRAMES_CHANGE = """
+HloModule jit__fused
+
+%fused_computation.151 (param_0.1581: u8[25856,72,96,3]) -> bf16[76,3,112,25856] {
+  %param_0.1581 = u8[25856,72,96,3]{0,2,3,1:T(8,128)(4,1)} parameter(0)
+  %constant.2318 = u8[]{:T(256)} constant(0)
+  %pad.88 = u8[25856,76,112,3]{0,2,3,1:T(8,128)(4,1)} pad(%param_0.1581, %constant.2318), padding=0_0x2_2x2_14x0_0
+  %convert.1 = bf16[25856,76,112,3]{0,2,3,1:T(8,128)(2,1)} convert(%pad.88)
+  ROOT %bitcast.3 = bf16[76,3,112,25856]{3,2,1,0:T(8,128)(2,1)} bitcast(%convert.1)
+}
+
+%fused_computation.36 (param_0.1975: u8[72,3,12,101,8,256], param_1.2336: s32[], param_2.2159: u8[256,72,96,3]) -> u8[72,3,12,101,8,256] {
+  %param_0.1975 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} parameter(0)
+  %param_2.2159 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)} parameter(2)
+  %bitcast.248 = u8[72,3,12,1,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} bitcast(%param_2.2159)
+  %constant.3024 = s32[]{:T(128)} constant(0)
+  %param_1.2336 = s32[]{:T(128)} parameter(1)
+  ROOT %dynamic_update_slice.152 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} dynamic-update-slice(%param_0.1975, %bitcast.248, %constant.3024, %constant.3024, %constant.3024, /*index=5*/%param_1.2336, %constant.3024, %constant.3024), metadata={op_name="SCOPE/rollout/while/body/closed_call/dynamic_update_slice"}
+}
+
+%region_1.26 (arg_tuple.4: (s32[], u8[256,72,96,3], u8[72,3,12,101,8,256])) -> (s32[], u8[256,72,96,3], u8[72,3,12,101,8,256]) {
+  %arg_tuple.4 = (s32[]{:T(128)}, u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)}, u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)}) parameter(0)
+  %get-tuple-element.2960 = s32[]{:T(128)} get-tuple-element(%arg_tuple.4), index=0
+  %get-tuple-element.2970 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)} get-tuple-element(%arg_tuple.4), index=1
+  %get-tuple-element.2992 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} get-tuple-element(%arg_tuple.4), index=2
+  %bitcast_dynamic-update-slice_fusion.8 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} fusion(%get-tuple-element.2992, %get-tuple-element.2960, %get-tuple-element.2970), kind=kLoop, calls=%fused_computation.36, metadata={op_name="SCOPE/rollout/while/body/closed_call/dynamic_update_slice"}
+  ROOT %tuple.392 = (s32[]{:T(128)}, u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)S(1)}, u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)}) tuple(%get-tuple-element.2960, %get-tuple-element.2970, %bitcast_dynamic-update-slice_fusion.8)
+}
+
+ENTRY %main.260 (frame.1: u8[256,72,96,3], carry_frames.1: u8[72,3,12,101,8,256]) -> bf16[76,3,112,25856] {
+  %frame.1 = u8[256,72,96,3]{0,2,3,1:T(8,128)(4,1)} parameter(0), metadata={op_name="carry.rollout.env_output.observation.frame"}
+  %carry_frames.1 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} parameter(1), metadata={op_name="carry.frames"}
+  %constant.1 = s32[]{:T(128)} constant(0)
+  %bitcast_dynamic-update-slice_fusion.2 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} fusion(%carry_frames.1, %constant.1, %frame.1), kind=kLoop, calls=%fused_computation.36, metadata={op_name="SCOPE/rollout/dynamic_update_slice"}
+  %get-tuple-element.3494 = u8[72,3,12,101,8,256]{5,4,3,2,1,0:T(8,128)(4,1)} get-tuple-element(%while.71), index=2
+  %bitcast.10 = u8[25856,72,96,3]{0,2,3,1:T(8,128)(4,1)} bitcast(%get-tuple-element.3494), metadata={op_name="SCOPE/learner_update/jvp(ImpalaAgent)/reshape;reshape"}
+  ROOT %multiply_bitcast_fusion = bf16[76,3,112,25856]{3,2,1,0:T(8,128)(2,1)} fusion(%bitcast.10), kind=kLoop, calls=%fused_computation.151
+}
+""".replace("SCOPE", _FRAMES_SCOPE)
+_FRAME_TENSOR_BYTES = 101 * 256 * 72 * 96 * 3        # 536 MB
+
+
+class TestFrameRelayouts:
+    """What a compiled step spends writing the trajectory's whole uint8
+    frame tensor out again (ISSUE 29), read off its text."""
+
+    def test_the_parents_step_holds_the_frames_twice_more(self):
+        rows = kernels_lib.frame_relayouts(_FRAMES_PARENT)
+        # the concatenate (a pad-as-add fusion) and the transposing
+        # copy; not the scan's in-place write of 100 slots, its
+        # allocation, or the stem weight gradient's pad (inside its
+        # fusion, and larger than the tensor)
+        assert [(row["name"], row["op"]) for row in rows] == [
+            ("add_bitcast_fusion", "fusion"), ("copy.78", "copy")]
+        assert [row["bytes"] for row in rows] == [_FRAME_TENSOR_BYTES] * 2
+        assert sum(row["bytes"] for row in rows) == 1_072_300_032
+        assert rows[1]["dims"] == [101, 72, 3, 12, 8, 256]
+        assert rows[1]["op_name"].endswith("jvp(ImpalaAgent)/reshape")
+
+    def test_written_once_there_is_nothing_to_count(self):
+        # both in-place slot writes result in the whole tensor: fusions
+        # around a dynamic-update-slice, so neither is a row
+        assert kernels_lib.frame_relayouts(_FRAMES_CHANGE) == []
+
+    def test_a_module_with_no_uint8_array_has_no_frames(self):
+        assert kernels_lib.frame_relayouts(_PARTITIONED_MODULE) == []
+
+    @pytest.mark.parametrize("text,expected", [
+        (_FRAMES_PARENT, 2 * _FRAME_TENSOR_BYTES), (_FRAMES_CHANGE, 0)],
+        ids=["parent", "change"])
+    def test_scope_table_notes_and_gauge_carry_it(self, tmp_path, text,
+                                                  expected):
+        import json
+
+        from scalable_agent_tpu.obs import MetricsRegistry
+
+        registry = MetricsRegistry()
+        path = kernels_lib.write_op_scopes(
+            str(tmp_path / "trace.p0.7.json"), text, registry=registry)
+        notes = json.load(open(path))["notes"]
+        assert notes["frame_relayout_bytes"] == expected
+        assert notes["frame_relayouts"] == kernels_lib.frame_relayouts(
+            text)
+        assert registry.snapshot()["fused/frame_relayout_bytes"] == (
+            expected)
+
+
 class TestTraceJoin:
     def test_harvest_roundtrip(self, tmp_path, monkeypatch):
         """Profile a compiled program, harvest, and verify the
