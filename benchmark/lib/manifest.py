@@ -4,13 +4,16 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own under ``benchmark/``, found by
 the name ``BENCHMARK.json`` uses.  A later PR adds a cell by adding
 files and entries; nothing here knows a cell, a configuration or a
-metric by name.
+metric by name.  A configuration file may name its plain reference
+(``"reference": "<name>"`` -> ``benchmark/references/<name>.py``); one
+that names none gets ``benchmark/lib/reference.py``.
 """
 
+import importlib
 import importlib.util
 import json
 import os
-from typing import Any, Dict, List, NamedTuple
+from typing import Any, Dict, List, NamedTuple, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -19,6 +22,9 @@ TRAFFIC_DIR = "traffic"
 METRICS_DIR = "metrics"
 ROOFLINES_DIR = "rooflines"
 LIMITS_DIR = "limits"
+REFERENCES_DIR = "references"
+DEFAULT_REFERENCE = "benchmark.lib.reference"
+_REFERENCES: Dict[str, Any] = {}     # path -> module, loaded once a process
 
 # End-to-end metrics the harness takes in every cell.  The one other is
 # the cell's rate, which its traffic file names (``rate_metric``).
@@ -36,6 +42,7 @@ class Cell(NamedTuple):
     chips: int
     config_name: str
     config: Dict[str, Any]    # the configuration file, whole
+    reference: Optional[str]  # the file's ``reference``: the module's path
     traffic_name: str
     traffic: Dict[str, Any]   # the traffic file, whole
     limits: Dict[str, float]  # benchmark/limits/<cell>.json: ``correct``'s
@@ -57,6 +64,19 @@ def load_module(path: str, name: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def reference_module(cell: "Cell"):
+    """The plain reference of the cell's configuration.  It imports
+    jax, so it is loaded where it is first needed and not with the
+    cell: ``run.py`` settles the platform before anything imports jax."""
+    if cell.reference is None:
+        return importlib.import_module(DEFAULT_REFERENCE)
+    if cell.reference not in _REFERENCES:      # its jitted functions: once
+        name = os.path.splitext(os.path.basename(cell.reference))[0]
+        _REFERENCES[cell.reference] = load_module(
+            cell.reference, "reference_" + name)
+    return _REFERENCES[cell.reference]
 
 
 def _reports(entry: Dict[str, Any], cell: str,
@@ -97,6 +117,14 @@ def load_cell(workload: str, root: str = ROOT,
     config = load_json(os.path.join(root, config_entry["file"]))
     traffic = load_json(find_file(bench_dir, TRAFFIC_DIR, entry["traffic"]))
     limits = load_json(find_file(bench_dir, LIMITS_DIR, workload))["limits"]
+    reference = config.get("reference")
+    if reference is not None:
+        reference = os.path.join(bench_dir, REFERENCES_DIR,
+                                 reference + ".py")
+        if not os.path.exists(reference):
+            raise FileNotFoundError(
+                f"configuration {entry['config']!r} names a reference "
+                f"that is not there: {reference}")
 
     # The cell's rate is the one its traffic file names: a later cell
     # brings its rate with its files, and no end-to-end entry is edited.
@@ -117,7 +145,7 @@ def load_cell(workload: str, root: str = ROOT,
         per_layer.append(Metric(m["name"], m, module))
     return Cell(
         name=workload, chips=int(entry["chips"]),
-        config_name=entry["config"], config=config,
+        config_name=entry["config"], config=config, reference=reference,
         traffic_name=entry["traffic"], traffic=traffic, limits=limits,
         end_to_end=end_to_end, per_layer=per_layer,
         run_seconds=int(bench["run_seconds"]))

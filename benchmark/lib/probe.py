@@ -15,7 +15,7 @@ import collections
 import time
 from typing import Any, Dict, List, Optional
 
-from benchmark.lib import reference, window
+from benchmark.lib import reference as default_reference, window
 
 CHECK_STEPS = 3        # the reference follows this many first steps
 FUSED_WARMUP = 6       # fused updates discarded before the window
@@ -58,8 +58,10 @@ class CompileCounter:
 class Probe:
     def __init__(self, *, config: Dict[str, Any], backend: str, seed: int,
                  seconds: float, trace: bool, trace_seconds: float,
-                 trace_dir: str, t_launch: float):
+                 trace_dir: str, t_launch: float, reference=None):
         self.config = config
+        # the cell's plain reference: the seeded weights are its
+        self.reference = reference or default_reference
         self.backend = backend
         self.seed = seed
         self.trace = trace
@@ -93,7 +95,7 @@ class Probe:
         self.weights_replaced = False
         self.marks: Dict[str, float] = {}      # seconds since launch
         # -- the first steps, for the reference ----------------------------
-        self.check_batches: List[reference.Batch] = []   # host loop
+        self.check_batches: List[Any] = []     # host loop: Batch each
         self.check_losses: List[Any] = []
         self.check_nu1: Optional[List[Any]] = None
         self.check_params: Optional[Dict] = None
@@ -174,7 +176,7 @@ class Probe:
         weights (same tree, same shapes, same placement)."""
         import jax
 
-        flat = reference.make_weights(self.config, self.seed)
+        flat = self.reference.make_weights(self.config, self.seed)
         seen = []
 
         def swap(path, leaf):
@@ -199,15 +201,20 @@ class Probe:
         return state._replace(params=params)
 
     def _note_learner(self, learner) -> None:
+        # a policy without a conv stem has no ``conv_backend``: None
         agent = learner._agent
-        self.policy = {"core_impl": agent.core_impl,
-                       "conv_backend": agent.conv_backend,
-                       "core_matmul_dtype": agent.core_matmul_dtype,
-                       "remat_torso": agent.remat_torso,
-                       "torso_type": agent.torso_type,
-                       "mesh_devices": int(learner.mesh.devices.size)}
+        self.policy = {name: getattr(agent, name, None) for name in (
+            "core_impl", "conv_backend", "core_matmul_dtype",
+            "remat_torso", "torso_type")}
+        self.policy["mesh_devices"] = int(learner.mesh.devices.size)
 
     # -- the first steps -----------------------------------------------------
+
+    def before_step(self, k: int, state, carry, counter):
+        """What fused dispatch ``k`` (from 1) is given.  A run gives it
+        what the program hands over; ``benchmark/seeds.py`` starts
+        every third from the next seed's weights."""
+        return state, carry, counter
 
     def _capture_post(self, k: int, new_state, metrics) -> None:
         import jax
@@ -287,7 +294,7 @@ class Probe:
             probe.mark("first_dispatch")
             if k <= CHECK_STEPS:
                 host = jax.device_get(trajectory)
-                probe.check_batches.append(reference.Batch(
+                probe.check_batches.append(probe.reference.Batch(
                     action=host.agent_outputs.action,
                     logits=host.agent_outputs.policy_logits,
                     reward=host.env_outputs.reward,
@@ -341,7 +348,7 @@ class Probe:
                 probe.dispatched += 1
                 k = probe.dispatched
                 probe.mark("first_dispatch")
-                out = step(state, carry, counter)
+                out = step(*probe.before_step(k, state, carry, counter))
                 probe._capture_post(k, out[0], out[2])
                 pending.append(out[2])
                 if len(pending) >= FUSED_INFLIGHT:

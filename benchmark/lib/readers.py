@@ -192,11 +192,15 @@ def train_flops_per_env_frame(cfg) -> float:
 def mfu(ctx) -> Optional[float]:
     """Model FLOPs of one step (from shapes; a rematerialized forward is
     not model work and is not counted) over the device time of one whole
-    run of the step program in the trace, over the chip's peak."""
+    run of the step program in the trace, over the chip's peak.  The
+    count is the cell's reference module's ``train_flops_per_env_frame``
+    where it brings one, and the one above where it does not."""
     step_ms = step_device_ms(ctx)
     if step_ms is None or ctx.peak is None:
         return None
-    flops = train_flops_per_env_frame(ctx.config) * ctx.frames_per_update
+    count = getattr(getattr(ctx, "reference", None),
+                    "train_flops_per_env_frame", train_flops_per_env_frame)
+    flops = count(ctx.config) * ctx.frames_per_update
     return (100.0 * flops
             / (ctx.chips * step_ms * 1e-3 * ctx.peak["flops_bf16"]))
 

@@ -28,6 +28,8 @@ class, first match in this order:
 
 A share is of the device time of those whole step runs, so the classes
 and the gaps between ops add up to 100; the mean over the cell's chips.
+A reader of a scope these classes do not hold apart asks
+``share_where(ctx, pattern)``.
 """
 
 import json
@@ -88,12 +90,11 @@ def table(ctx) -> Optional[Dict[str, str]]:
     return ctx.op_scopes
 
 
-def _plane_shares(ctx, plane: str, ops: Dict[str, str]):
-    """({class: seconds}, {unscoped op: seconds}, seconds of whole step
-    runs) on one chip."""
+def _step_ops(ctx, plane: str):
+    """([(instruction name, self seconds)] of every op event inside a
+    whole run of the step program, seconds of those runs) on one chip."""
     runs = sorted(readers.step_runs(ctx, plane), key=lambda r: r.start)
-    seconds = dict.fromkeys(CLASSES, 0.0)
-    unscoped: Dict[str, float] = {}
+    found = []
     events = trace_reduce.line_events(ctx.events, plane,
                                       trace_reduce.OPS_LINE)
     cursor = 0
@@ -107,12 +108,22 @@ def _plane_shares(ctx, plane: str, ops: Dict[str, str]):
         if event.start < run.start - 1e-9 or (
                 event.start + event.dur > run.start + run.dur + 1e-9):
             continue
-        name = trace_reduce.short_name(event.name)
+        found.append((trace_reduce.short_name(event.name), self_s))
+    return found, sum(r.dur for r in runs)
+
+
+def _plane_shares(ctx, plane: str, ops: Dict[str, str]):
+    """({class: seconds}, {unscoped op: seconds}, seconds of whole step
+    runs) on one chip."""
+    seconds = dict.fromkeys(CLASSES, 0.0)
+    unscoped: Dict[str, float] = {}
+    found, total = _step_ops(ctx, plane)
+    for name, self_s in found:
         kind = classify(ops.get(name))
         seconds[kind] += self_s
         if kind == "unscoped":
             unscoped[name] = unscoped.get(name, 0.0) + self_s
-    return seconds, unscoped, sum(r.dur for r in runs)
+    return seconds, unscoped, total
 
 
 def shares(ctx) -> Optional[Dict[str, float]]:
@@ -154,3 +165,27 @@ def shares(ctx) -> Optional[Dict[str, float]]:
 def share(ctx, kind: str) -> Optional[float]:
     found = shares(ctx)
     return None if found is None else found[kind]
+
+
+def share_where(ctx, pattern) -> Optional[float]:
+    """% of the step's device time in ops whose ``op_name`` the pattern
+    finds (``re.search``; a string or a compiled pattern; a scope is a
+    whole word of the path: ``r"\\bexperts\\b"``): the same self times
+    over the same whole step runs as ``share``, mean over chips, for a
+    scope the seven classes do not hold apart.  0.0 where nothing
+    matches; None with no table or no whole step run."""
+    ops = table(ctx)
+    if ops is None:
+        return None
+    wanted = re.compile(pattern)
+    per_plane = []
+    for plane in readers.planes(ctx):
+        found, total = _step_ops(ctx, plane)
+        if total <= 0:
+            continue
+        seconds = 0.0
+        for name, self_s in found:
+            if wanted.search(ops.get(name) or ""):
+                seconds += self_s
+        per_plane.append(100.0 * seconds / total)
+    return statistics.mean(per_plane) if per_plane else None

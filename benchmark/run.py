@@ -23,6 +23,7 @@ T_LAUNCH = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -34,6 +35,16 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 TRACE_SECONDS = 4.0    # the profiler records the window's last seconds
+
+# The TPU runtime pins a host buffer for transfers when it starts.  On a
+# machine without transparent hugepages (the check's: JAX warns of it in
+# every run) the default buffer took 9.4-12.3 s of ``jax.devices()`` in
+# one call's runs and 21.4 s in its first, 1.6-2.4 s at this size (my
+# chip runs, PR 31): a quarter of set-up's seconds and most of its spread,
+# none of it the program's.  The cells move a few MB between host and
+# device (metrics, the three checked steps' parameters).  A value the
+# environment brings is kept.
+TPU_PREMAPPED_BUFFER_BYTES = 256 * 2 ** 20
 
 
 def say(*parts):
@@ -82,6 +93,8 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell.chips}")
+    os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE",
+                          str(TPU_PREMAPPED_BUFFER_BYTES))
 
     import jax
 
@@ -118,6 +131,7 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
     from scalable_agent_tpu import driver
 
     backend = cell.traffic["backend"]
+    reference = manifest.reference_module(cell)
     trace_dir = os.path.join(logdir, "profile")
     # The program's own seed (its worlds' seeds and its sampling keys) is
     # the same in every run: the fused step bakes it into the compiled
@@ -133,7 +147,7 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
         config=cell.config, backend=backend, seed=args.seed,
         seconds=args.seconds, trace=bool(args.trace),
         trace_seconds=TRACE_SECONDS, trace_dir=trace_dir,
-        t_launch=T_LAUNCH)
+        t_launch=T_LAUNCH, reference=reference)
     argv_driver = manifest.flags_to_argv(flags) + [
         "--mode=train", f"--logdir={logdir}", f"--seed={program_seed}",
         f"--trace={'true' if args.trace else 'false'}"]
@@ -203,7 +217,7 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
         program = correct.program_numbers(
             cell.config, args.seed, probe.param_paths,
             jax.device_get(probe.check_losses), probe.check_nu1,
-            probe.check_params)
+            probe.check_params, reference=reference)
         fused = None if host else {
             "world": cell.traffic["world"],
             "batch": int(flags["batch_size"]),
@@ -211,7 +225,7 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
             "program_seed": program_seed}
         follow = dict(frames_per_update=frames_per_update,
                       batches=probe.check_batches if host else None,
-                      fused=fused)
+                      fused=fused, reference=reference)
         ref = correct.follow(cell.config, args.seed, **follow)
         numbers = correct.compare(program, ref)
         ref_seconds = time.perf_counter() - t0
@@ -239,8 +253,9 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
             say(f"trace: {len(events)} events read in "
                 f"{time.perf_counter() - t0:.1f}s")
         ctx = types.SimpleNamespace(
-            config=cell.config, flags=flags, chips=cell.chips,
-            traffic=cell.traffic, retires=retires, rate=rate,
+            config=cell.config, reference=reference, flags=flags,
+            chips=cell.chips, traffic=cell.traffic, retires=retires,
+            rate=rate,
             frames_per_update=frames_per_update,
             t_launch=T_LAUNCH, t_open=probe.t_open, t_close=probe.t_close,
             t_first_update=probe.t_first_update,
@@ -320,8 +335,37 @@ def run_cell(args, cell, flags, logdir, peak, t_imported) -> int:
                     "sizes; at rehearsal sizes a gap may pass them"}
     if args.trace and trace_summary.get("breakdown") and not args.rehearse:
         line["breakdown"] = trace_summary["breakdown"]
+    # What failed, the comparisons with the reference first (a refusal's
+    # record keeps the first numbers of this line), then every number
+    # compared beside its limit: last in the line and last on stderr.
+    line["checks_failed"] = checks_failed(checks, numbers)
+    line["compared"] = {
+        name: {"value": _plain(value), "limit": limit}
+        for name, value, limit, _ in checks if name in numbers}
     say(json.dumps(line))
+    for name, row in line["compared"].items():
+        print(f"compared {name}: value={row['value']} "
+              f"limit={row['limit']}", file=sys.stderr, flush=True)
     return 0
+
+
+def checks_failed(checks, compared=()):
+    """{name: {value, limit}} of the rows of ``checks`` that did not
+    pass; those named in ``compared`` (the numbers held against the
+    reference) first, in ``compared``'s order."""
+    failed = {name: {"value": _plain(value), "limit": limit}
+              for name, value, limit, passed in checks if not passed}
+    first = [name for name in compared if name in failed]
+    return {name: failed[name]
+            for name in first + [n for n in failed if n not in first]}
+
+
+def _plain(value):
+    """A number as it is; one that JSON has no word for (nan, inf) as
+    its name, so that the line stays one any reader can parse."""
+    if isinstance(value, float) and not math.isfinite(value):
+        return repr(value)
+    return value
 
 
 def report_per_layer(per_layer, ctx, metrics) -> int:

@@ -160,9 +160,8 @@ def test_the_table_is_found_beside_the_programs_trace(tmp_path,
     assert scopes.table(ctx) == {"fusion.1": "a/rollout/b"}
 
 
-@pytest.mark.skipif(not os.path.exists(RECORDED),
-                    reason="no recorded step beside the test")
-def test_one_recorded_step_of_a_real_v5e_trace():
+def recorded_step():
+    """(the recorded file, a ctx holding its one whole step run)."""
     with open(RECORDED) as f:
         recorded = json.load(f)
     plane = recorded["plane"]
@@ -175,9 +174,15 @@ def test_one_recorded_step_of_a_real_v5e_trace():
     # to lie whole inside the traced window
     events += [Event(plane, OPS_LINE, "%neighbour = ...", at, 1e-9)
                for at in (-1e-6, (start + dur) * 1e-9 + 1e-6)]
-    ctx = types.SimpleNamespace(
+    return recorded, types.SimpleNamespace(
         events=events, op_scopes=recorded["op_scopes"], notes=[],
         traffic={"step_module": "_fused"})
+
+
+@pytest.mark.skipif(not os.path.exists(RECORDED),
+                    reason="no recorded step beside the test")
+def test_one_recorded_step_of_a_real_v5e_trace():
+    recorded, ctx = recorded_step()
     found = scopes.shares(ctx)
     want = recorded["expect"]
     for kind in scopes.CLASSES:
