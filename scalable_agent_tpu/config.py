@@ -64,6 +64,15 @@ class Config:
 
     # -- TPU-native knobs (no reference equivalent)
     torso_type: str = "shallow"  # shallow | resnet
+    # Which policy acts: the conv torso + LSTM agent (models/agent.py)
+    # when this is empty; else the token policy (models/token_policy.py)
+    # that the named JSON file describes under the source's own key
+    # names (model_type, hidden_size, num_experts, layer_types, ...; the
+    # benchmark's configuration files are such files; ``model_type``
+    # says which decoder family, and only ``afmoe`` is built).  The
+    # token policy runs on the fused loop (``--train_backend=ingraph``)
+    # in a token world (``--level_name=token_recall``), one chip, V-trace.
+    model_config: str = ""
     # Activation/matmul dtype END-TO-END (torso, LSTM core, heads):
     # params, loss, V-trace, and optimizer reductions stay f32
     # regardless (models/agent.py documents the full policy).

@@ -257,12 +257,112 @@ def resolve_remat_torso(config: Config) -> bool:
     return jax.default_backend() == "tpu"
 
 
+# What the token policy does not run on yet, each with the flag that
+# asks for it and why (ROADMAP R6-R10 have what is left of each).
+def _token_policy_refusals(config: Config):
+    mesh = _intended_mesh_size(config)
+    return (
+        (config.train_backend != "ingraph",
+         f"--train_backend={config.train_backend}: the token policy runs "
+         f"on the fused loop only (--train_backend=ingraph); the host "
+         f"loop's actors, batcher and transport carry an LSTM state and a "
+         f"vector of logits a step, not an attention cache"),
+        (config.actor == "service",
+         "--actor=service: the inference service batches requests that "
+         "carry an LSTM state, not an attention cache"),
+        (config.inference_mode.startswith("accum"),
+         f"--inference_mode={config.inference_mode}: the accumulating "
+         f"actors keep an LSTM state on the device, not an attention "
+         f"cache"),
+        (config.loss == "impact",
+         "--loss=impact: IMPACT unrolls a target network through the "
+         "same trajectory, and the token policy's update reads the "
+         "rollout's one cache; only --loss=vtrace is built"),
+        (config.replay_ratio > 0,
+         f"--replay_ratio={config.replay_ratio}: a replayed trajectory "
+         f"would need its own copy of the attention cache (the slab "
+         f"holds none); only --replay_ratio=0 is built"),
+        (config.sentinel_interval > 0,
+         f"--sentinel_interval={config.sentinel_interval}: the shadow "
+         f"audit takes the dispatch's trajectory, which would carry the "
+         f"attention cache out of the step"),
+        (mesh > 1,
+         f"a mesh of {mesh} devices: the token policy runs on one chip "
+         f"(one expert-parallel chip's share, without the experts' "
+         f"exchange); pass --mesh_data=1 on a host with more"),
+    )
+
+
+def build_token_policy(config: Config, action_space, frame_shape=None):
+    """The token policy (models/token_policy.py) from the file
+    ``--model_config`` names, its rings sized for this run's unroll and
+    its world's episodes.  Every combination it is not built for is
+    refused here, at configuration time, by name."""
+    from scalable_agent_tpu.envs.device import DEVICE_LEVELS
+    from scalable_agent_tpu.models.token_policy import (
+        TokenModelConfig,
+        TokenPolicy,
+    )
+
+    for refused, why in _token_policy_refusals(config):
+        if refused:
+            raise ValueError(
+                f"the token policy (--model_config) does not run with "
+                f"{why}")
+    model = TokenModelConfig.from_file(config.model_config)
+    level = DEVICE_LEVELS.get(config.level_name)
+    tokens = getattr(action_space, "n", None)
+    if (level is None or "episode_length" not in level.defaults
+            or tuple(frame_shape or ()) != ()):
+        raise ValueError(
+            f"the token policy (--model_config) acts in a token world (a "
+            f"device level whose "
+            f"observation is a token id, e.g. token_recall); "
+            f"--level_name={config.level_name} is none")
+    if tokens != model.vocab_size:
+        raise ValueError(
+            f"--level_name={config.level_name} shows {tokens} tokens, "
+            f"{config.model_config} has vocab_size {model.vocab_size}: "
+            f"the world's tokens are the policy's actions")
+    agent = TokenPolicy(
+        model=model, unroll_length=config.unroll_length,
+        episode_length=int(level.defaults["episode_length"]),
+        compute_dtype=jnp.dtype(config.compute_dtype))
+    registry = get_registry()
+    for name, value, text in (
+            ("cache/bytes", agent.cache_bytes(config.batch_size),
+             "bytes of the attention cache the rollout carries"),
+            ("cache/window_slots", agent.window_slots,
+             "slots of a window layer's ring (window + unroll)"),
+            ("cache/full_slots", agent.full_slots,
+             "slots of a full layer's ring (episode + unroll)"),
+            ("policy/vocab_slice", model.vocab_size,
+             "tokens of the vocabulary this chip's head and embedding "
+             "hold")):
+        registry.gauge(name, text).set(value)
+    log.info(
+        "kernel policy: backend=%s mesh_devices=%d policy=token "
+        "model_config=%s layers=%d experts_held=%d/%d (first %d) "
+        "window_slots=%d full_slots=%d core_impl=%s conv_backend=%s "
+        "remat=%s compute_dtype=%s",
+        jax.default_backend(), _intended_mesh_size(config),
+        config.model_config, model.num_hidden_layers, model.experts_held,
+        model.num_experts, model.first_expert, agent.window_slots,
+        agent.full_slots, agent.core_impl, agent.conv_backend,
+        agent.remat_placement, config.compute_dtype)
+    return agent
+
+
 def build_agent(config: Config, action_space,
                 frame_shape=None) -> ImpalaAgent:
     """Policy heads derive from the probed action space — one Discrete
     head or a composite tuple-categorical (ops/distributions.py).  The
     kernel policy (every "auto" resolved, interpreted or compiled) is
-    logged here, once per agent built."""
+    logged here, once per agent built.  ``--model_config=<file>``
+    builds the token policy that file describes instead
+    (``build_token_policy``)."""
+    if config.model_config:
+        return build_token_policy(config, action_space, frame_shape)
     core_impl = resolve_core_impl(config)
     core_matmul_dtype = resolve_core_matmul_dtype(config, core_impl)
     if core_matmul_dtype not in ("float32", "bfloat16"):
@@ -1223,7 +1323,10 @@ def build_training_learner(config: Config, agent: ImpalaAgent):
                    loss=config.loss,
                    target_update_interval=config.target_update_interval,
                    impact_clip_epsilon=config.impact_clip_epsilon,
-                   fused_forward=config.fused_forward)
+                   fused_forward=config.fused_forward,
+                   # the fused step's update follows its own rollout
+                   on_policy=(config.train_backend == "ingraph"
+                              and config.replay_ratio == 0))
 
 
 def build_replay(config: Config, learner: Learner):
@@ -2003,6 +2106,11 @@ class _FusedBackend(_Backend):
         num_action_repeats): the host backend's contract, for the
         logged rows and the returned dict alike."""
         host_metrics = super().fetch(metrics)
+        # What the agent's forward pass reports of itself (the expert
+        # layers' load) is a gauge under its own name as well.
+        for name in self.agent.STATS:
+            if name in host_metrics:
+                get_registry().gauge(name).set(host_metrics[name])
         if host_metrics.pop("episodes_completed", 0) < 1:
             host_metrics.pop("episode_return", None)
             host_metrics.pop("episode_frames", None)
@@ -2535,7 +2643,7 @@ def _eval_loop(envs, config: Config, agent: ImpalaAgent, params, step_fn,
     returns: List[float] = []
     try:
         output = envs.initial()
-        core_state = initial_state(batch, agent.core_size)
+        core_state = agent.initial_state(batch)
         action = np.asarray(agent.zero_actions(batch))
         rng = jax.random.key(config.seed)
         step_index = 0

@@ -24,6 +24,9 @@ Package layout (docs/environments.md is the narrative version):
 - ``gridworld`` / ``minatar``: the real XLA worlds —
   ``device_grid_*`` (procedural key-door) and ``device_minatar_*``
   (Atari-lite object-channel games).
+- ``token_recall``: ``DeviceTokenRecall``, the world that emits tokens
+  (``token_recall*``: seeded Zipf streams that repeat past an attention
+  window; the observation is an int32 token id).
 - ``host``: ``HostDeviceEnv``, the gym-like adapter that makes any
   device level a host ``Environment`` (probe_env/eval/registry).
 - ``conformance``: the protocol checks every registered level must
@@ -52,6 +55,8 @@ _EXPORTS = {
     "DeviceGridWorld": "gridworld",
     "DeviceAsterix": "minatar",
     "DeviceBreakout": "minatar",
+    "DeviceTokenRecall": "token_recall",
+    "TokenRecallState": "token_recall",
     "DeviceWorld": "world",
     "HostDeviceEnv": "host",
     "make_host_device_env": "host",

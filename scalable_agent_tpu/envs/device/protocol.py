@@ -215,3 +215,20 @@ register_device_level(
     dict(episode_length=128, sticky_prob=0.0),
     description="MinAtar-style asterix: streaming enemies/gold, "
                 "hash-spawned")
+
+# Token worlds (envs/device/token_recall.py): the observation is a token
+# id and the action a token of the same vocabulary slice, so the slice
+# is ``num_actions`` and nothing else of the geometry knobs applies.
+register_device_level(
+    "token_recall",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=25024, episode_length=4096, period=2560),
+    description="seeded Zipf token streams that repeat after 2,560 "
+                "positions: the token to predict lies past a 2,048 "
+                "window, inside a 4,096-token episode")
+register_device_level(
+    "token_recall_small",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=64, episode_length=16, period=10),
+    description="the same world at test size: 64 tokens, episodes of "
+                "16, period 10 (past a window of 8)")

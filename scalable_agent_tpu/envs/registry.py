@@ -121,6 +121,8 @@ _make_gym = _lazy_family(
 
 register_family("fake_", _make_fake, consumes_action_repeats=True)
 register_family("device_", _make_device, consumes_action_repeats=True)
+# token worlds (envs/device/token_recall.py) are device-native too
+register_family("token_", _make_device, consumes_action_repeats=True)
 register_family("doom_", _make_doom, consumes_action_repeats=True)
 register_family("atari_", _make_atari, consumes_action_repeats=True)
 register_family("dmlab_", _make_dmlab, consumes_action_repeats=True)

@@ -237,6 +237,43 @@ class ImpalaAgent(nn.Module):
         shape = (batch,) if k == 1 else (batch, k)
         return jnp.zeros(shape, jnp.int32)
 
+    # -- what every agent declares (models/token_policy.py declares its
+    # own): its state, and what the learner's telemetry reads of it ------
+
+    def initial_state(self, batch_size: int) -> AgentState:
+        return initial_state(batch_size, self.core_size)
+
+    def unroll_state(self, start: AgentState, end: AgentState) -> AgentState:
+        """The state the update unrolls from, given the rollout's first
+        and last: the first."""
+        del end
+        return start
+
+    def acting_params(self, params):
+        """The parameters as the rollout's steps read them."""
+        return params
+
+    # Parameter groups of the learning-dynamics gauges
+    # (runtime/learner.py), the module whose output the dead-unit
+    # reading takes, and no collection of the forward pass's own numbers
+    # (``STATS`` names them).  ``Learner.init`` initializes op by op.
+    layer_groups = ("torso", "core", "heads")
+    dead_unit_module = "convnet"
+    stats_collection = None
+    STATS = ()
+    init_in_one_program = False
+
+    @staticmethod
+    def layer_group(path) -> str:
+        """A param-tree path's group: the conv torso ("convnet" and the
+        optional instruction encoder), the recurrent core, the heads."""
+        keys = {str(getattr(entry, "key", entry)) for entry in path}
+        if "core" in keys:
+            return "core"
+        if "policy_logits" in keys or "baseline" in keys:
+            return "heads"
+        return "torso"
+
     @nn.compact
     def __call__(
         self,
@@ -336,7 +373,7 @@ class ImpalaAgent(nn.Module):
 
 
 def actor_step(
-    agent: ImpalaAgent,
+    agent,
     params,
     rng: jax.Array,
     last_action,
@@ -361,6 +398,11 @@ def actor_step(
     # Composite spaces sample every component ([B, K]); plain Discrete
     # keeps the [B] layout.
     action = distributions.sample(rng, policy_logits, agent.dist_spec)
+    if distributions.stores_log_prob(agent.dist_spec):
+        # a vocabulary: the trajectory keeps what V-trace reads of the
+        # behaviour policy, the taken action's log-probability
+        policy_logits = distributions.log_prob(
+            policy_logits, action, agent.dist_spec)[..., None]
     return (
         AgentOutput(
             action=jnp.asarray(action, jnp.int32),

@@ -15,7 +15,9 @@ Two consumers, one registry (obs/registry.py):
 """
 
 import json
+import logging
 import os
+import queue
 import re
 import threading
 import time
@@ -27,6 +29,8 @@ from scalable_agent_tpu.obs.registry import (
     Histogram,
     MetricsRegistry,
 )
+
+log = logging.getLogger(__name__)
 
 __all__ = ["MetricsHTTPServer", "MetricsWriter", "PrometheusExporter",
            "render_prometheus"]
@@ -216,6 +220,16 @@ class MetricsWriter:
 
     A context manager (``with MetricsWriter(logdir) as writer:``) so the
     JSONL handle can't leak when the training loop raises.
+
+    ``write`` hands the row to a writer thread and returns: the files
+    are the thread's.  The caller is the training loop at its log
+    publish, which has just waited for the device's queue to empty (the
+    fetch of the newest update's metrics), so whatever time a write
+    takes there the device idles through; and a write is not always
+    120 ms: 2.5 s once a run on a TPU host, behind the page cache's
+    write-back of the compile cache an in-process compile had left
+    (``log/write``, PERF.md section 6, PR 32).  ``flush`` and ``close``
+    wait for the rows handed over so far.
     """
 
     def __init__(self, logdir: str, flush_every_s: float = 5.0,
@@ -231,6 +245,10 @@ class MetricsWriter:
             self._tb = SummaryWriter(os.path.join(logdir, "summaries"))
         except ImportError:
             self._tb = None
+        self._rows: "queue.Queue" = queue.Queue()
+        self._thread = threading.Thread(
+            target=self._drain, name="metrics-writer", daemon=True)
+        self._thread.start()
 
     def write(self, step: int, scalars: Dict[str, float],
               wall_time: Optional[float] = None):
@@ -238,17 +256,36 @@ class MetricsWriter:
         # zero in replayed/simulated-clock runs) must be preserved.
         if wall_time is None:
             wall_time = time.time()
+        # The values become floats HERE: a device scalar is waited for
+        # by the caller, as it always was, not by the thread.
         record = {"step": int(step), "time": wall_time}
         for key, value in scalars.items():
-            value = float(value)
-            record[key] = value
-            if self._tb is not None:
-                self._tb.add_scalar(key, value, global_step=step,
-                                    walltime=wall_time)
+            record[key] = float(value)
+        self._rows.put(record)
+
+    def _drain(self):
+        while True:
+            record = self._rows.get()
+            try:
+                if record is None:
+                    return
+                self._write_row(record)
+            except Exception:  # noqa: BLE001  (a full disk ends no run)
+                log.exception("metrics writer: a row was not written")
+            finally:
+                self._rows.task_done()
+
+    def _write_row(self, record: Dict[str, float]):
+        if self._tb is not None:
+            for key, value in record.items():
+                if key not in ("step", "time"):
+                    self._tb.add_scalar(key, value,
+                                        global_step=record["step"],
+                                        walltime=record["time"])
         self._jsonl.write(json.dumps(record) + "\n")
         now = time.monotonic()
         if now - self._last_flush > self._flush_every_s:
-            self.flush()
+            self._flush_files()
             self._last_flush = now
 
     def write_registry(self, step: int,
@@ -264,13 +301,20 @@ class MetricsWriter:
                     for k, v in self._registry.snapshot().items()},
                    wall_time=wall_time)
 
-    def flush(self):
+    def _flush_files(self):
         self._jsonl.flush()
         if self._tb is not None:
             self._tb.flush()
 
+    def flush(self):
+        self._rows.join()
+        self._flush_files()
+
     def close(self):
-        self.flush()
+        if self._thread.is_alive():
+            self._rows.put(None)
+            self._thread.join()
+        self._flush_files()
         self._jsonl.close()
         if self._tb is not None:
             self._tb.close()

@@ -400,6 +400,9 @@ def _scope_of(attrs: str) -> Optional[str]:
 
 
 _INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+# an op_name with no path and no ``jit(...)``: a compiler pass's own
+_PASS_NAMED_RE = re.compile(r"^[\w.\-]+$")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 
 
 def op_scopes_path(trace_path: str) -> str:
@@ -627,11 +630,25 @@ def write_op_scopes(trace_path: str, hlo_text: str,
     from scalable_agent_tpu.obs.registry import get_registry
 
     ops = {}
+    renamed = {}     # instruction -> its operands, where a pass named it
     for line in hlo_text.splitlines():
         scope = _OP_NAME_RE.search(line)
         name = _INSTR_NAME_RE.match(line) if scope else None
         if name:
             ops[name.group(1)] = scope.group(1)
+            if _PASS_NAMED_RE.match(scope.group(1)):
+                renamed[name.group(1)] = _OPERAND_RE.findall(
+                    line[name.end():].split("metadata=", 1)[0])
+    # An op an XLA pass made (the grouped matrix product's Mosaic calls,
+    # ``op_name="ragged-dot-none"``) lost the scope path of the op it
+    # stands for: it takes that of its last operand that has one (the
+    # weights' cast, the sorted rows' gather), and so its layer.
+    for instruction, operands in renamed.items():
+        for operand in reversed(operands):
+            path = ops.get(operand, "")
+            if "/" in path:
+                ops[instruction] = f"{path}/{ops[instruction]}"
+                break
     rows = sorted(collectives(hlo_text), key=lambda row: -row["bytes"])
     totals = collective_bytes(rows)
     registry = registry or get_registry()

@@ -35,6 +35,9 @@ class DistributionSpec(NamedTuple):
     independent component."""
 
     sizes: Tuple[int, ...]
+    # One categorical over a vocabulary of tokens (the token policy
+    # declares it): see ``stores_log_prob``.
+    vocabulary: bool = False
 
     @property
     def num_logits(self) -> int:
@@ -43,6 +46,20 @@ class DistributionSpec(NamedTuple):
     @property
     def num_components(self) -> int:
         return len(self.sizes)
+
+
+def stores_log_prob(spec: DistributionSpec) -> bool:
+    """Does a trajectory of this policy hold the taken action's
+    log-probability in place of the logits?  A vocabulary's does:
+    ``[T+1, B, 25024]`` float32 logits are 823 MB at T = 256, B = 32,
+    and the behaviour policy's log-probability of the action taken is
+    all V-trace reads of them."""
+    return spec.vocabulary and spec.num_components == 1
+
+
+def behaviour_size(spec: DistributionSpec) -> int:
+    """Width of ``AgentOutput.policy_logits`` in a trajectory."""
+    return 1 if stores_log_prob(spec) else spec.num_logits
 
 
 def spec_for_space(space: Space) -> DistributionSpec:

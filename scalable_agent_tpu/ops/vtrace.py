@@ -366,3 +366,69 @@ def from_logits(
         behaviour_action_log_probs=behaviour_action_log_probs,
         target_action_log_probs=target_action_log_probs,
         diagnostics=vtrace_returns.diagnostics)
+
+
+def from_behaviour_log_probs(
+    behaviour_action_log_probs,
+    target_policy_logits,
+    actions,
+    discounts,
+    rewards,
+    values,
+    bootstrap_value,
+    clip_rho_threshold: Optional[float] = 1.0,
+    clip_pg_rho_threshold: Optional[float] = 1.0,
+    scan_impl: str = "associative",
+    on_policy: bool = False,
+) -> VTraceFromLogitsReturns:
+    """``from_logits`` for a trajectory that kept the behaviour
+    policy's log-probability of each action taken and not its logits
+    (one large categorical, ops/distributions.py ``stores_log_prob``):
+    the importance ratios read nothing else of the behaviour policy.
+
+    ``on_policy``: the caller knows the actions were sampled under the
+    very parameters it evaluates (the fused step's update follows its
+    own rollout).  The ratio is then 1 by construction, and what is
+    measured — the same policy evaluated by two compiled programs, one
+    token at a time and over the unroll, whose bfloat16 roundings fall
+    independently after a few layers — is rounding, not a second
+    policy.  Fed to the recursion it is worse than noise: ``min(1,
+    rho)`` keeps its negative half, and the product of the ``c``'s over
+    the ~100 steps a discount of 0.99 looks ahead reads a log-ratio
+    scatter of 4.5e-3 as traces an eighth short (PERF.md section 6, PR
+    32).  So the targets are computed at ratios of exactly 1, which is
+    what a learner that evaluates the behaviour log-probabilities with
+    its own program would store, and the measured ratios go to the
+    diagnostics alone (``log_rho_p95`` is then the acting / learning
+    mismatch).
+
+    behaviour_action_log_probs: [T, B]; target logits: [T, B,
+    NUM_LOGITS]; actions: [T, B] int.
+    """
+    target_action_log_probs = log_probs_from_logits_and_actions(
+        target_policy_logits, actions)
+    behaviour_action_log_probs = jnp.asarray(
+        behaviour_action_log_probs, jnp.float32)
+    log_rhos = target_action_log_probs - behaviour_action_log_probs
+    returns = from_importance_weights(
+        # times zero, not ``zeros_like``: the targets then still wait
+        # for the update's logits, and the compiler keeps the schedule
+        # it has without ``on_policy`` (with ``zeros_like`` the recursion
+        # runs early and one more [T+1, B, vocabulary] float32 tensor is
+        # live at the step's peak: 12.95 against 12.17 GiB, AOT, PR 32)
+        log_rhos=log_rhos * 0.0 if on_policy else log_rhos,
+        discounts=discounts, rewards=rewards,
+        values=values, bootstrap_value=bootstrap_value,
+        clip_rho_threshold=clip_rho_threshold,
+        clip_pg_rho_threshold=clip_pg_rho_threshold, scan_impl=scan_impl)
+    diagnostics = returns.diagnostics
+    if on_policy:
+        with jax.named_scope("telemetry"):
+            diagnostics = importance_diagnostics(
+                log_rhos, clip_rho_threshold, clip_pg_rho_threshold)
+    return VTraceFromLogitsReturns(
+        vs=returns.vs, pg_advantages=returns.pg_advantages,
+        log_rhos=log_rhos,
+        behaviour_action_log_probs=behaviour_action_log_probs,
+        target_action_log_probs=target_action_log_probs,
+        diagnostics=diagnostics)

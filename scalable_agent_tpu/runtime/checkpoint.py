@@ -152,7 +152,10 @@ class CheckpointManager:
             self._manager = ocp.CheckpointManager(self._dir,
                                                   options=options)
         self._interval_s = interval_s
-        self._last_save = 0.0
+        # The cadence counts from here: the monotonic clock's zero is
+        # the host's boot, and a cadence counted from it saves when the
+        # HOST turns an interval old, wherever in the run that falls.
+        self._last_save = time.monotonic()
         registry = get_registry()
         self._save_failures = registry.counter(
             "checkpoint/save_failures_total",

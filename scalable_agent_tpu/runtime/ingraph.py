@@ -40,17 +40,14 @@ from scalable_agent_tpu.envs.device import (
     env_telemetry_spec,
     record_episode_telemetry,
 )
-from scalable_agent_tpu.models.agent import (
-    ImpalaAgent,
-    actor_step,
-    initial_state,
-)
+from scalable_agent_tpu.models.agent import ImpalaAgent, actor_step
 from scalable_agent_tpu.obs.device_telemetry import (
     TelemetryPublisher,
     fetch_merged,
     merge_init,
 )
 from scalable_agent_tpu.obs.trace import get_tracer
+from scalable_agent_tpu.ops import distributions
 from scalable_agent_tpu.parallel.mesh import (
     batch_sharding,
     layout_hint,
@@ -58,7 +55,7 @@ from scalable_agent_tpu.parallel.mesh import (
 )
 from scalable_agent_tpu.runtime.faults import get_fault_injector
 from scalable_agent_tpu.runtime.learner import Learner, Trajectory
-from scalable_agent_tpu.types import AgentOutput, AgentState
+from scalable_agent_tpu.types import AgentOutput
 
 
 class RolloutCarry(NamedTuple):
@@ -67,7 +64,10 @@ class RolloutCarry(NamedTuple):
     env_state: object
     env_output: object  # StepOutput
     agent_output: AgentOutput
-    core_state: AgentState
+    # the agent's state, whatever pytree it declares
+    # (``agent.initial_state``): the IMPALA agents' LSTM carry, the
+    # token policy's attention cache
+    core_state: object
 
 
 class TrainCarry(NamedTuple):
@@ -298,10 +298,12 @@ class InGraphTrainer:
         agent_output = AgentOutput(
             action=jnp.asarray(self._agent.zero_actions(self._batch)),
             policy_logits=jnp.zeros(
-                (self._batch, self._agent.num_logits), jnp.float32),
+                (self._batch,
+                 distributions.behaviour_size(self._agent.dist_spec)),
+                jnp.float32),
             baseline=jnp.zeros((self._batch,), jnp.float32),
         )
-        core_state = initial_state(self._batch, self._agent.core_size)
+        core_state = self._agent.initial_state(self._batch)
         carry = TrainCarry(
             rollout=RolloutCarry(env_state, env_output, agent_output,
                                  core_state),
@@ -366,6 +368,11 @@ class InGraphTrainer:
         # trace) splits a step's device time by layer.  They are
         # metadata: no op, fusion or number depends on them.
         slots = self._frame_slots(carry.env_output)
+        with jax.named_scope("rollout"):
+            # what the steps read, made once before the scan (a token
+            # policy's matrices in its compute dtype; the IMPALA
+            # agents' parameters as they are)
+            params = agent.acting_params(params)
 
         def without_frame(env_output, frame=None):
             return env_output._replace(
@@ -396,7 +403,12 @@ class InGraphTrainer:
         env_outputs = _stack_first(
             without_frame(carry.env_output), env_seq)
         trajectory = Trajectory(
-            agent_state=carry.core_state,
+            # the state at the unroll's START is what the update
+            # unrolls from; an agent whose state is a cache hands back
+            # the rollout's own buffers under the start's counters
+            # instead of a copy (models/token_policy.py unroll_state)
+            agent_state=agent.unroll_state(carry.core_state,
+                                           new_carry.core_state),
             env_outputs=without_frame(env_outputs, slots.frames(frames)),
             agent_outputs=_stack_first(carry.agent_output, agent_seq),
         )

@@ -155,18 +155,35 @@ def learner_telemetry_spec() -> DeviceTelemetry:
 
 
 # Per-layer-group telemetry buckets: the agent's param tree divides
-# into the conv torso ("convnet" + the optional instruction encoder),
-# the recurrent core ("core"/lstm), and the linear heads
-# ("policy_logits"/"baseline").  Keyed on flax module names so a new
-# head lands in "heads" and anything else defaults to the torso.
-LAYER_GROUPS = ("torso", "core", "heads")
+# into the groups the agent declares (``agent.layer_groups`` /
+# ``agent.layer_group(path)``).  The IMPALA agents': the conv torso
+# ("convnet" + the optional instruction encoder), the recurrent core
+# ("core"/lstm), and the linear heads ("policy_logits"/"baseline"),
+# keyed on flax module names so a new head lands in "heads" and anything
+# else defaults to the torso.
+LAYER_GROUPS = ImpalaAgent.layer_groups
+
+# Bucket edges for what a forward pass reports of itself
+# (``agent.STATS``: shares, loads, ratios); the exact sum and count give
+# the mean over every update between fetches at any resolution.
+_STAT_EDGES = (0.01, 0.05, 0.125, 0.25, 0.5, 1.0, 2.0, 4.0, 16.0, 64.0,
+               256.0, 1024.0)
+
+
+def stat_histogram_name(stat: str) -> str:
+    """``moe/expert_load_max_over_mean`` -> its ``devtel/learn``
+    histogram's name."""
+    return stat.replace("/", "_")
 
 # Shared bucket edges for fraction-valued histograms ([0, 1] series:
 # clip fractions, ESS, normalized entropy).
 _FRACTION_EDGES = (0.01, 0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 0.99)
 
 
-def learning_telemetry_spec(loss: str = "vtrace") -> DeviceTelemetry:
+def learning_telemetry_spec(loss: str = "vtrace",
+                            groups=LAYER_GROUPS,
+                            stats=(),
+                            dead_units: bool = True) -> DeviceTelemetry:
     """The learning-dynamics instrument set (ISSUE 17): off-policy clip
     diagnostics, policy entropy/KL, value explained-variance, and
     per-layer-group optimizer health — all accumulated INSIDE the
@@ -203,12 +220,17 @@ def learning_telemetry_spec(loss: str = "vtrace") -> DeviceTelemetry:
          "mean log importance ratio log(pi/mu) (0 = on-policy)"),
         ("log_rho_p95",
          "p95 log importance ratio — the off-policy tail"),
+    ) + ((
+        # only for an agent that names the module to read them from
         ("dead_torso_frac",
          "fraction of conv-torso output units at <=0 across the whole "
          "batch (dead ReLUs)"),
-    ):
+    ) if dead_units else ()):
         spec.gauge(name, help_text)
-    for group in LAYER_GROUPS:
+    for stat in stats:
+        spec.histogram(stat_histogram_name(stat), _STAT_EDGES,
+                       f"{stat} of each update's forward pass")
+    for group in groups:
         spec.gauge(f"grad_norm_{group}",
                    f"gradient norm over the {group} param group")
         spec.gauge(f"param_norm_{group}",
@@ -238,29 +260,24 @@ def learning_telemetry_spec(loss: str = "vtrace") -> DeviceTelemetry:
     return spec
 
 
-def _torso_filter(mdl, method_name) -> bool:
-    """flax capture_intermediates filter: only the conv torso output
-    (not the segment a torso rematerializes, which flax calls as a
-    method of the same module)."""
-    return mdl.name == "convnet" and method_name == "__call__"
+def _module_filter(module: str):
+    """flax capture_intermediates filter: only the output of the module
+    the agent names for the dead-unit reading (the conv torso; not the
+    segment a torso rematerializes, which flax calls as a method of the
+    same module)."""
+    def keep(mdl, method_name) -> bool:
+        return mdl.name == module and method_name == "__call__"
+
+    return keep
 
 
-def _dead_unit_fraction(captured) -> jax.Array:
-    """Fraction of torso output units that are <= 0 for EVERY element
-    of the [T*B] batch — dead ReLUs the optimizer can no longer reach."""
-    conv_out = captured["intermediates"]["convnet"]["__call__"][0]
-    conv_out = jax.lax.stop_gradient(jnp.asarray(conv_out, jnp.float32))
-    return jnp.mean(jnp.all(conv_out <= 0.0, axis=0).astype(jnp.float32))
-
-
-def _layer_group(path) -> str:
-    """Map a param-tree path to its LAYER_GROUPS bucket."""
-    keys = {str(getattr(entry, "key", entry)) for entry in path}
-    if "core" in keys:
-        return "core"
-    if "policy_logits" in keys or "baseline" in keys:
-        return "heads"
-    return "torso"
+def _dead_unit_fraction(captured, module: str) -> jax.Array:
+    """Fraction of the module's output units that are <= 0 for EVERY
+    element of the [T*B] batch — dead ReLUs the optimizer can no longer
+    reach."""
+    out = captured["intermediates"][module]["__call__"][0]
+    out = jax.lax.stop_gradient(jnp.asarray(out, jnp.float32))
+    return jnp.mean(jnp.all(out <= 0.0, axis=0).astype(jnp.float32))
 
 
 def _make_optimizer(hp: LearnerHyperparams) -> optax.GradientTransformation:
@@ -310,6 +327,7 @@ class Learner:
         target_update_interval: int = 100,
         impact_clip_epsilon: float = 0.3,
         fused_forward: bool = True,
+        on_policy: bool = False,
     ):
         # The unroll's [T, B] -> [T*B] merge must keep the mesh's shard
         # index outermost, or the partitioner replicates the torso on
@@ -321,6 +339,16 @@ class Learner:
         if agent.batch_shards != shards:
             agent = agent.clone(batch_shards=shards)
         self._agent = agent
+        # Are fresh trajectories sampled under the very parameters the
+        # update evaluates (the fused step, nothing replayed)?  Read
+        # where the trajectory keeps the taken action's log-probability
+        # (ops/vtrace.py from_behaviour_log_probs has why).
+        self._on_policy = bool(on_policy)
+        # What the agent's forward pass reports of itself (the expert
+        # layers' load): sown into ``agent.stats_collection``, read out
+        # by ``_forward`` and carried in the update's metrics.
+        self._forward_stats = (tuple(agent.STATS)
+                               if agent.stats_collection else ())
         # Fused single-forward loss (default): ONE whole-trajectory
         # unroll (Learner._forward) produces both the
         # behaviour-comparison quantities V-trace consumes (target
@@ -420,7 +448,10 @@ class Learner:
         # "learn" namespace, merged into the SAME donated pytree —
         # same buffers, same single log-interval fetch, zero new syncs.
         self._learn_enabled = bool(learn_telemetry) and self._devtel_enabled
-        self._learn_spec = (learning_telemetry_spec(loss)
+        self._learn_spec = (learning_telemetry_spec(
+                                loss, agent.layer_groups,
+                                self._forward_stats,
+                                agent.dead_unit_module is not None)
                             if self._learn_enabled
                             else DeviceTelemetry("learn"))
         # Normalizer for entropy_frac: the distribution's max entropy
@@ -563,12 +594,23 @@ class Learner:
         example = jax.tree_util.tree_map(
             lambda x: x if x is None else jnp.asarray(x),
             example_trajectory, is_leaf=lambda x: x is None)
-        params = self._agent.init(
+        agent = self._agent
+        # The IMPALA agents initialize op by op (a hundred tiny
+        # programs, ~6 s on a TPU); an agent of hundreds of ops and
+        # parameters asks for ONE program, of which the compiler keeps
+        # the initializers and drops the forward pass.
+        init = (jax.jit(agent.init) if agent.init_in_one_program
+                else agent.init)
+        params = init(
             rng,
             example.agent_outputs.action,
             example.env_outputs,
             example.agent_state,
         )
+        if agent.stats_collection:
+            # what the forward pass sows of itself is no parameter
+            params = {name: tree for name, tree in params.items()
+                      if name != agent.stats_collection}
         opt_state = self._tx.init(params)
         state = TrainState(
             params=params,
@@ -678,25 +720,30 @@ class Learner:
         pin it.  ``capture=True`` additionally captures the torso
         output (flax capture_intermediates) for the dead-unit gauge —
         still no second forward.  Returns ``((logits [T+1,B,L] f32,
-        baselines [T+1,B] f32), dead_torso_frac | None)``."""
-        if capture:
-            (out, _), captured = self._agent.apply(
-                params,
-                trajectory.agent_outputs.action,
-                trajectory.env_outputs,
-                trajectory.agent_state,
-                capture_intermediates=_torso_filter,
-                mutable=["intermediates"],
-            )
+        baselines [T+1,B] f32), observed)``: what the pass showed
+        besides its outputs, as metrics — ``dead_torso_frac``, or, for an
+        agent that names no such module, its forward pass's own numbers
+        (``agent.STATS``); empty without ``capture``."""
+        agent = self._agent
+        module = agent.dead_unit_module
+        args = (params, trajectory.agent_outputs.action,
+                trajectory.env_outputs, trajectory.agent_state)
+        if capture and module is not None:
+            (out, _), captured = agent.apply(
+                *args, capture_intermediates=_module_filter(module),
+                mutable=["intermediates"])
             with jax.named_scope("telemetry"):
-                return out, _dead_unit_fraction(captured)
-        out, _ = self._agent.apply(
-            params,
-            trajectory.agent_outputs.action,
-            trajectory.env_outputs,
-            trajectory.agent_state,
-        )
-        return out, None
+                return out, {"dead_torso_frac": _dead_unit_fraction(
+                    captured, module)}
+        if capture and self._forward_stats:
+            # no module's dead units to read, but the pass's own numbers
+            (out, _), sown = agent.apply(
+                *args, mutable=[agent.stats_collection])
+            stats = sown.get(agent.stats_collection, {})
+            return out, {name: jax.lax.stop_gradient(stats[name])
+                         for name in self._forward_stats}
+        out, _ = agent.apply(*args)
+        return out, {}
 
     def _comparison_forward(self, params, trajectory: Trajectory):
         """The UNFUSED (``fused_forward=False``) reference: a separate
@@ -726,7 +773,7 @@ class Learner:
 
     def _loss_vtrace(self, params, trajectory: Trajectory):
         hp = self._hp
-        (target_logits, baselines), dead_torso = self._forward(
+        (target_logits, baselines), observed = self._forward(
             params, trajectory, capture=self._learn_enabled)
         if self._fused_forward:
             comparison_logits, comparison_baselines = (
@@ -762,21 +809,33 @@ class Learner:
             # tensors in the fused path; V-trace stop-gradients
             # internally, so the unfused reference matches it
             # bit-for-bit)...
-            vt = vtrace.from_logits(
-                behaviour_policy_logits=behaviour.policy_logits,
-                target_policy_logits=comparison_logits,
-                actions=behaviour.action,
-                discounts=discounts,
-                rewards=rewards,
-                values=comparison_baselines,
-                bootstrap_value=bootstrap_value,
-                clip_rho_threshold=hp.clip_rho_threshold,
-                clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
-                scan_impl=self._scan_impl,
-                dist_spec=dist_spec,
-                mesh=(self._mesh if self._scan_impl == "time_sharded"
-                      else None),
-            )
+            if distributions.stores_log_prob(dist_spec):
+                # One large categorical: the trajectory kept the taken
+                # action's behaviour log-probability, not the logits.
+                vt = vtrace.from_behaviour_log_probs(
+                    behaviour.policy_logits[..., 0], comparison_logits,
+                    behaviour.action, discounts, rewards,
+                    comparison_baselines, bootstrap_value,
+                    clip_rho_threshold=hp.clip_rho_threshold,
+                    clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
+                    scan_impl=self._scan_impl,
+                    on_policy=self._on_policy)
+            else:
+                vt = vtrace.from_logits(
+                    behaviour_policy_logits=behaviour.policy_logits,
+                    target_policy_logits=comparison_logits,
+                    actions=behaviour.action,
+                    discounts=discounts,
+                    rewards=rewards,
+                    values=comparison_baselines,
+                    bootstrap_value=bootstrap_value,
+                    clip_rho_threshold=hp.clip_rho_threshold,
+                    clip_pg_rho_threshold=hp.clip_pg_rho_threshold,
+                    scan_impl=self._scan_impl,
+                    dist_spec=dist_spec,
+                    mesh=(self._mesh if self._scan_impl == "time_sharded"
+                          else None),
+                )
 
             # ...while the DIFFERENTIATED outputs feed the loss terms.
             pg_loss = losses_lib.compute_policy_gradient_loss(
@@ -797,7 +856,7 @@ class Learner:
         if self._learn_enabled:
             metrics.update(self._learning_metrics(
                 vt, behaviour.policy_logits, target_logits, baselines,
-                dist_spec, dead_torso))
+                dist_spec, observed))
         return total, metrics
 
     def _loss_impact(self, params, trajectory: Trajectory, target_params):
@@ -810,7 +869,7 @@ class Learner:
         hp = self._hp
         # ONE online unroll (capture feeds the dead-unit gauge — the
         # params being optimized).
-        (online_logits, baselines), dead_torso = self._forward(
+        (online_logits, baselines), observed = self._forward(
             params, trajectory, capture=self._learn_enabled)
         if self._fused_forward:
             comparison_baselines = baselines
@@ -877,14 +936,14 @@ class Learner:
         if self._learn_enabled:
             metrics.update(self._learning_metrics(
                 vt, behaviour.policy_logits, online_logits, baselines,
-                dist_spec, dead_torso))
+                dist_spec, observed))
             metrics["impact_log_ratio_mean"] = surrogate.log_ratio_mean
             metrics["impact_log_ratio_p95"] = surrogate.log_ratio_p95
             metrics["impact_ess_frac"] = surrogate.ess_frac
         return total, metrics
 
     def _learning_metrics(self, vt, behaviour_logits, online_logits,
-                          baselines, dist_spec, dead_torso
+                          baselines, dist_spec, observed
                           ) -> Dict[str, jax.Array]:
         """The learning-dynamics scalars (ISSUE 17): V-trace clip/ESS
         diagnostics, policy entropy (absolute + normalized),
@@ -896,8 +955,13 @@ class Learner:
         with jax.named_scope("telemetry"):
             online = sg(online_logits)
             entropy = jnp.mean(distributions.entropy(online, dist_spec))
-            kl = jnp.mean(distributions.kl_divergence(
-                sg(behaviour_logits), online, dist_spec))
+            if distributions.stores_log_prob(dist_spec):
+                # No behaviour logits were kept: the estimate of
+                # KL(behaviour || learner) over the actions taken.
+                kl = -jnp.mean(sg(vt.log_rhos))
+            else:
+                kl = jnp.mean(distributions.kl_divergence(
+                    sg(behaviour_logits), online, dist_spec))
             vs = sg(vt.vs)
             explained_variance = 1.0 - (
                 jnp.var(vs - sg(baselines))
@@ -914,7 +978,7 @@ class Learner:
             "log_rho_mean": diag.log_rho_mean,
             "log_rho_p95": diag.log_rho_p95,
             "ess_frac": diag.ess_frac,
-            "dead_torso_frac": dead_torso,
+            **observed,
         }
 
     def _update_impl(self, state: TrainState, trajectory: Trajectory,
@@ -1054,7 +1118,10 @@ class Learner:
         for name in ("entropy_frac", "ess_frac", "explained_variance",
                      "rho_clip_fraction", "cs_clip_fraction",
                      "pg_rho_clip_fraction", "log_rho_mean",
-                     "log_rho_p95", "dead_torso_frac"):
+                     "log_rho_p95") + (
+                         ("dead_torso_frac",)
+                         if self._agent.dead_unit_module is not None
+                         else ()):
             devtel = lspec.set(devtel, name, metrics[name])
         devtel = lspec.set(devtel, "kl", metrics["behaviour_kl"])
         if self._loss_name == "impact":
@@ -1073,15 +1140,19 @@ class Learner:
                                metrics["impact_log_ratio_p95"])
             devtel = lspec.set(devtel, "impact_ess_frac",
                                metrics["impact_ess_frac"])
+        for stat in self._forward_stats:
+            devtel = lspec.observe(devtel, stat_histogram_name(stat),
+                                   metrics[stat])
         # Per-layer-group optimizer health: grads/updates/params share
         # one treedef, so a single flatten-with-path keys all three.
         zero = jnp.zeros((), jnp.float32)
-        acc = {group: [zero, zero, zero] for group in LAYER_GROUPS}
+        acc = {group: [zero, zero, zero]
+               for group in self._agent.layer_groups}
         flat_grads, _ = jax.tree_util.tree_flatten_with_path(grads)
         flat_updates = jax.tree_util.tree_leaves(updates)
         flat_params = jax.tree_util.tree_leaves(params)
         for (path, g), u, p in zip(flat_grads, flat_updates, flat_params):
-            group = acc[_layer_group(path)]
+            group = acc[self._agent.layer_group(path)]
             group[0] = group[0] + jnp.sum(
                 jnp.square(jnp.asarray(g, jnp.float32)))
             group[1] = group[1] + jnp.sum(

@@ -48,7 +48,12 @@ class StepOutput(NamedTuple):
 
 
 class AgentState(NamedTuple):
-    """LSTM core carry.  (reference: experiment.py:118-121)"""
+    """LSTM core carry.  (reference: experiment.py:118-121)
+
+    The IMPALA agents' state.  An agent's state is whatever pytree it
+    declares (``agent.initial_state(batch)``, reset at ``done`` inside
+    its own step): the token policy's is its attention cache
+    (models/token_policy.py ``TokenCache``)."""
 
     c: Any
     h: Any
@@ -58,7 +63,10 @@ class AgentOutput(NamedTuple):
     """Per-step model output.  (reference: experiment.py:101-102)"""
 
     action: Any  # i32 []
-    policy_logits: Any  # f32 [num_actions]
+    # f32 [num_logits]; [1], the taken action's log-probability, where
+    # the policy is one large categorical (ops/distributions.py
+    # ``behaviour_size``): a vocabulary of logits a step is not kept
+    policy_logits: Any
     baseline: Any  # f32 []
 
 

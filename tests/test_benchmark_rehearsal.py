@@ -26,10 +26,17 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 POLICY_ATTRIBUTES = ("core_impl", "conv_backend", "core_matmul_dtype",
                      "remat_torso", "torso_type")
-# Checks that failed in the rehearsal at the parent commit (289cec2),
-# --seed 7: every other check passed.
+# Checks that fail in the rehearsal, --seed 7: none, since PR 31 re-set
+# the limits (``grad_norm_gap``, which ``shallow.ingraph`` failed at this
+# size, is no longer compared).  ``trinity.ingraph`` is not here: the
+# harness hands a cell's reference the configuration file whole, and at
+# the published widths the float32 reference is 2.8 GB of weights and
+# minutes of CPU a step; a rehearsal at the tiny preset would need the
+# harness to hand the reference the rehearsal's sizes
+# (tests/test_token_policy.py drives the same policy, world and loop
+# through ``driver.main`` and against the same reference at that size).
 FAILED_AT_REHEARSAL_SIZES = {
-    "shallow.ingraph": {"grad_norm_gap"},
+    "shallow.ingraph": set(),
     "shallow.ingraph.x4": set(),
 }
 

@@ -32,6 +32,8 @@ CONFORMANCE_LEVELS = (
     "fake_benchmark",
     "fake_memory",
     "fake_small",
+    "token_recall",
+    "token_recall_small",
 )
 
 
