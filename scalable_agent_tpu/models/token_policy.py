@@ -289,7 +289,7 @@ class _Attention(nn.Module):
         query = attention_lib.round_to(query, dtype)
         key = attention_lib.round_to(key, dtype)
         with jax.named_scope("window" if self.sliding else "full"):
-            out = attention_lib.cached_attention(
+            out, stats = attention_lib.cached_attention(
                 query, key, value, ring_keys, ring_values, ring_index,
                 index, episode_start,
                 window=model.sliding_window if self.sliding else None)
@@ -298,7 +298,7 @@ class _Attention(nn.Module):
                                                    written)
         out = out * jax.nn.sigmoid(gate)
         return (_Linear(model.hidden_size, dtype, name="o_proj")(out),
-                ring_keys, ring_values)
+                ring_keys, ring_values, stats)
 
 
 class _Layer(nn.Module):
@@ -315,18 +315,19 @@ class _Layer(nn.Module):
         def norm(name):
             return _RMSNorm(model.rms_norm_eps, name=name)
 
-        attn, ring_keys, ring_values = _Attention(
+        attn, ring_keys, ring_values, seen = _Attention(
             model, self.sliding, dtype, name="attention")(
                 norm("input_norm")(h), position, index, episode_start,
                 ring_keys, ring_values, ring_index, written)
+        stats = {f"attention/{name}": x for name, x in seen.items()}
         h = h + norm("post_attn_norm")(attn)
         m = norm("pre_mlp_norm")(h)
         flat = m.reshape(-1, m.shape[-1])
-        stats = {}
         if self.expert:
             # one token an env: a decode step
-            f, stats = _MoE(model, dtype, name="moe")(
+            f, routed = _MoE(model, dtype, name="moe")(
                 flat, decode=m.shape[1] == 1)
+            stats.update({f"moe/{name}": x for name, x in routed.items()})
         else:
             f = _GatedMLP(model.intermediate_size, dtype, name="mlp")(flat)
         h = h + norm("post_mlp_norm")(f.reshape(m.shape))
@@ -379,6 +380,10 @@ class TokenPolicy(nn.Module):
     conv_backend = None
     remat_torso = None
     torso_type = None
+    # what the boundary still buys: the expert layer's sorted pairs'
+    # buffers and every float32 activation between matmuls, one layer
+    # at a time; attention keeps no score in HBM either way
+    # (ops/attention.py: its backward kernel recomputes them in VMEM)
     remat_placement = "each layer"
     # learning-dynamics telemetry (runtime/learner.py): the parameter
     # groups, no module whose dead units are read, and the collection
@@ -390,7 +395,8 @@ class TokenPolicy(nn.Module):
     # ``Learner.init``: one jitted program, not ~400 eager ones
     init_in_one_program = True
     STATS = ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
-             "moe/expert_load_max_over_mean")
+             "moe/expert_load_max_over_mean",
+             "attention/key_blocks_visited_share")
 
     @staticmethod
     def layer_group(path) -> str:
@@ -524,8 +530,12 @@ class TokenPolicy(nn.Module):
 
         ring_index = {SLIDING: before(state.window_index),
                       FULL: before(state.full_index)}
-        # Learning keeps one layer's residuals at a time (the sorted
-        # pairs' buffers of an expert layer are 1 GB at 8,224 tokens).
+        # Learning keeps one layer's residuals at a time: the sorted
+        # pairs' buffers of an expert layer are 1 GB at 8,224 tokens,
+        # and the float32 activations between matmuls 67 MB apiece.
+        # Attention's scores are not among them (its kernel writes none
+        # and its backward recomputes a block's in VMEM), so the
+        # boundary costs attention one more forward kernel, no more.
         layer_cls = nn.remat(_Layer) if count > 1 else _Layer
         keys, values, stats = [], [], []
         for layer, kind in enumerate(model.layer_types):
@@ -536,13 +546,15 @@ class TokenPolicy(nn.Module):
                     state.values[layer], ring_index[kind], written)
             keys.append(ring_keys)
             values.append(ring_values)
-            if layer_stats:
-                stats.append(layer_stats)
-        for name in self.STATS if stats else ():
-            short = name.split("/", 1)[1]
-            self.sow(self.stats_collection, name,
-                     jnp.mean(jnp.stack([s[short] for s in stats])),
-                     init_fn=lambda: 0.0, reduce_fn=lambda _, new: new)
+            stats.append(layer_stats)
+        # each number's mean over the layers that say it (acting says
+        # none of the attention's: one query an env visits every slot)
+        for name in self.STATS:
+            said = [s[name] for s in stats if name in s]
+            if said:
+                self.sow(self.stats_collection, name,
+                         jnp.mean(jnp.stack(said)),
+                         init_fn=lambda: 0.0, reduce_fn=lambda _, new: new)
 
         z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
         z = jnp.swapaxes(z, 0, 1)                         # [T, B, hidden]

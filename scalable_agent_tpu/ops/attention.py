@@ -12,19 +12,34 @@ rides beside it (``ring_index``, ``NO_KEY`` where a slot holds nothing
 a query may see).  Every shape is fixed: how full the cache is changes
 a mask and no trip count.
 
-The score tensor ``[B, heads, T, S + T]`` in float32 is 2.4 GB a layer
-at 32 envs x 257 queries x 2,561 keys, so the batch is taken ``block``
-envs at a time under ``jax.checkpoint``: the backward pass recomputes a
-block's scores instead of keeping every block's.  Grouped queries: the
-``heads // kv_heads`` query heads that share a key/value head are one
-matmul's rows.
+Two paths, chosen by the one shape that tells them apart.  One query an
+env (acting) scores every slot of the ring in XLA (``_attend``): a
+matrix-vector product at its bytes.  More than one (learning) would
+write ``[B, heads, T, S + T]`` scores in float32 (2.7 GB a window layer
+at 32 envs x 257 queries x 2,561 keys, 4.9 GB on the full layer) and
+read them back several times, forward, rematerialized forward and
+backward; there ``_blockwise`` walks the ring and then the call's own
+keys a block at a time in one Pallas kernel with a running maximum and
+sum, so a score lives in VMEM only.  Its backward kernel recomputes a
+block's scores from the saved log-sum-exp and gives the query's
+gradient and the call's OWN keys' and values'; the ring is the agent's
+state and gets no cotangent.  A key block none of an env's queries can
+see is neither fetched nor scored (``attention/key_blocks_visited_share``
+counts the rest).  Grouped queries: the ``heads // kv_heads`` query
+heads that share a key/value head are one matmul's columns.  Inside the
+kernels a block's scores lie keys down, queries across (``[K, R]``): the
+maximum and the sum over keys are then elementwise over vregs, and what
+a query carries (its bounds, maximum, sum) is a lane vector.
 """
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def round_to(x, dtype):
@@ -45,17 +60,6 @@ def round_to(x, dtype):
 # A slot no query may see: below every episode's start (those are >= 0).
 NO_KEY = -(2 ** 30)
 _MASKED = -1e30
-
-
-def score_block(batch: int, heads: int, queries: int, keys: int,
-                budget_bytes: int = 256 * 2 ** 20) -> int:
-    """Envs per block: the largest divisor of ``batch`` whose float32
-    scores fit ``budget_bytes`` (one env where none does)."""
-    per_env = heads * queries * keys * 4
-    for block in range(batch, 0, -1):
-        if batch % block == 0 and block * per_env <= budget_bytes:
-            return block
-    return 1
 
 
 def _attend(query, key, value, ring_keys, ring_values, ring_index, index,
@@ -90,42 +94,347 @@ def _attend(query, key, value, ring_keys, ring_values, ring_index, index,
                          preferred_element_type=jnp.float32))
 
 
+# -- learning: blockwise, scores in VMEM only ---------------------------------
+
+_KEY_BLOCKS = (512, 256, 128)   # ring slots a grid step; the first that
+                                # divides the ring (a ring none divides,
+                                # a test's, is one block)
+_LANES = 128                    # queries lie along lanes, padded to these,
+                                # and so are the call's own keys
+_FAR = 2 ** 30                  # an index no query reaches: a padded key
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _key_block(slots: int) -> int:
+    for block in _KEY_BLOCKS:
+        if slots % block == 0:
+            return block
+    return slots
+
+
+def _bounds(index, episode_start, window):
+    """A query sees the keys of stream index ``low <= k <= high``:
+    ([B, T], [T])."""
+    low = episode_start
+    if window is not None:
+        low = jnp.maximum(low, index[None, :] - (window - 1))
+    return low, index
+
+
+def visited_blocks(ring_index, index, episode_start, window, block: int):
+    """bool [B, S // block]: does any query of the env see any key of
+    the ring's block?  The mask's own rule, reduced."""
+    low, high = _bounds(index, episode_start, window)
+    seen = ((ring_index[None, None, :] >= low[:, :, None])
+            & (ring_index[None, None, :] <= high[None, :, None]))
+    return seen.reshape(seen.shape[0], seen.shape[1], -1, block).any(
+        axis=(1, 3))
+
+
+_NN = (((1,), (0,)), ((), ()))          # [K, D] x [D, R]
+_NT = (((1,), (1,)), ((), ()))          # [K, R] x [D, R] -> [K, D]
+_TN = (((0,), (0,)), ((), ()))          # [K, D] x [K, R] -> [D, R]
+
+
+def _scores(q, k, key_index, low, high, scale):
+    """Masked float32 scores of one key block, keys down the sublanes
+    and queries along the lanes (a reduction over keys is then a
+    vreg-wise one, and a per-query number is a lane vector): q [D, R],
+    k [K, D], key_index [K, 1], low / high [1, R] -> [K, R]."""
+    s = jax.lax.dot_general(k, q, _NN,
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where((key_index >= low) & (key_index <= high), s, _MASKED)
+
+
+def _flat(env, step, blocks):
+    """Where (env, ring step) lies in the flat ``visit`` / ``fetch``; the
+    own keys' step reads the last ring block's entry and ignores it."""
+    return env * blocks + jnp.minimum(step, blocks - 1)
+
+
+def _forward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
+                    rv_ref, ri_ref, ok_ref, ov_ref, oi_ref, out_ref, lse_ref,
+                    m_ref, l_ref, acc_ref, *, scale, blocks):
+    del fetch_ref                       # the index maps read it
+    env, step = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(k_ref, v_ref, index_ref):
+        # A query that has seen no key yet keeps m = _MASKED and gathers
+        # weights of exp(0); the first real score's alpha = exp(-1e30)
+        # wipes them, and every query sees itself among the own keys,
+        # which come last.
+        s = _scores(q_ref[...], k_ref[...], index_ref[...], low_ref[...],
+                    high_ref[...], scale)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        v = v_ref[...]
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
+    def _():
+        block(rk_ref, rv_ref, ri_ref)
+
+    @pl.when(step == blocks)
+    def _():
+        block(ok_ref, ov_ref, oi_ref)
+        out_ref[...] = acc_ref[...] / l_ref[...]
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+
+
+def _backward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
+                     rv_ref, ri_ref, ok_ref, ov_ref, oi_ref, out_ref,
+                     lse_ref, do_ref, dq_ref, dk_ref, dv_ref, delta_ref,
+                     dob_ref, acc_ref, *, scale, blocks):
+    del fetch_ref
+    env, step = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _():
+        do = do_ref[...]
+        delta_ref[...] = jnp.sum(out_ref[...] * do, axis=0, keepdims=True)
+        dob_ref[...] = do.astype(dob_ref.dtype)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(k_ref, v_ref, index_ref):
+        """(the weights, d scores) of one key block, both in the compute
+        dtype, after adding its part of d query."""
+        k = k_ref[...]
+        s = _scores(q_ref[...], k, index_ref[...], low_ref[...],
+                    high_ref[...], scale)
+        p = jnp.exp(s - lse_ref[...])
+        dp = jax.lax.dot_general(v_ref[...], dob_ref[...], _NN,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[...]) * scale).astype(k.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            k, ds, _TN, preferred_element_type=jnp.float32)
+        return p.astype(k.dtype), ds
+
+    @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
+    def _():
+        block(rk_ref, rv_ref, ri_ref)
+
+    @pl.when(step == blocks)
+    def _():
+        p, ds = block(ok_ref, ov_ref, oi_ref)
+        dk_ref[...] = jax.lax.dot_general(
+            ds, q_ref[...], _NT, preferred_element_type=jnp.float32)
+        dv_ref[...] = jax.lax.dot_general(
+            p, dob_ref[...], _NT, preferred_element_type=jnp.float32)
+        dq_ref[...] = acc_ref[...]
+
+
+_VMEM_LIMIT = 96 * 2 ** 20
+
+
+@functools.partial(jax.jit, static_argnames=("backward", "interpret"))
+def _kernel(operands, residuals=(), *, backward=False, interpret):
+    """One of the two kernels over ``_operands`` (the backward one also
+    over ``residuals``: out, log-sum-exp, d out, queries along lanes),
+    grid (env, kv head, key step): the ring's blocks, then the own keys.
+    Jitted for the eager caller's sake, who would otherwise compile the
+    interpreter's program at every call."""
+    q, ring, own_keys = operands[2], operands[5], operands[8]
+    batch, kv, dim, rows = q.shape
+    own = own_keys.shape[2]
+    blocks = operands[0].shape[0] // batch
+    block = ring.shape[2] // blocks
+
+    def ring_block(env, step, fetch):
+        # a step that skips names the block the last visited step
+        # fetched, so nothing moves
+        return fetch[_flat(env, step, blocks)]
+
+    def fixed(*shape):                   # one block an (env, kv head)
+        return pl.BlockSpec((None, None) + shape,
+                            lambda e, h, s, *_: (e, h, 0, 0))
+
+    per_query = pl.BlockSpec((None, 1, rows), lambda e, h, s, *_: (e, 0, 0))
+    ring_kv = pl.BlockSpec(
+        (None, None, block, dim),
+        lambda e, h, s, visit, fetch: (e, h, ring_block(e, s, fetch), 0))
+    ring_index = pl.BlockSpec(
+        (block, 1), lambda e, h, s, visit, fetch: (ring_block(e, s, fetch), 0))
+    own_index = pl.BlockSpec((own, 1), lambda e, h, s, *_: (0, 0))
+    in_specs = [fixed(dim, rows), per_query, per_query, ring_kv, ring_kv,
+                ring_index, fixed(own, dim), fixed(own, dim), own_index]
+
+    def result(*shape):
+        return jax.ShapeDtypeStruct((batch, kv) + shape, jnp.float32)
+
+    if backward:
+        kernel = _backward_kernel
+        in_specs += [fixed(dim, rows), fixed(1, rows), fixed(dim, rows)]
+        out_specs = [fixed(dim, rows), fixed(own, dim), fixed(own, dim)]
+        out_shape = [result(dim, rows), result(own, dim), result(own, dim)]
+        scratch = [pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((dim, rows), q.dtype),
+                   pltpu.VMEM((dim, rows), jnp.float32)]
+    else:
+        kernel = _forward_kernel
+        out_specs = [fixed(dim, rows), fixed(1, rows)]
+        out_shape = [result(dim, rows), result(1, rows)]
+        scratch = [pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((dim, rows), jnp.float32)]
+    return pl.pallas_call(
+        functools.partial(kernel, scale=1.0 / math.sqrt(dim), blocks=blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(batch, kv, blocks + 1),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(*operands, *residuals)
+
+
+def _operands(query, key, value, ring_keys, ring_values, ring_index, index,
+              episode_start, visit, window):
+    """What both kernels read, in the order their blocks want: (visit,
+    fetch, q [B, kv, D, R], low / high [B, 1, R], the ring and the own
+    keys and values head-major, [B, kv, S, D] and [B, kv, K, D], each
+    with its index [S, 1] / [K, 1]).  A query is a lane (lane = g * T +
+    t); padded queries repeat the last one's bounds.  Head-major is the
+    order the compiled step keeps the rings in (its decode's products
+    want it), so their transpose is a bitcast there, not a copy."""
+    batch, queries, kv, group, dim = query.shape
+    rows = _round_up(group * queries, _LANES)
+    own = _round_up(queries, _LANES)
+    # a skipped step names the block of the last visited step before it
+    # (the first visited one, before any)
+    blocks = visit.shape[1]
+    at = jnp.where(visit, jnp.arange(blocks, dtype=jnp.int32), -1)
+    last = jax.lax.cummax(at, axis=1)
+    first = jnp.argmax(visit, axis=1).astype(jnp.int32)
+    fetch = jnp.where(last >= 0, last, first[:, None])
+
+    low, high = _bounds(index, episode_start, window)
+    high = jnp.broadcast_to(high[None, :], low.shape)
+
+    def per_query(x):                    # [B, T] -> [B, 1, R]
+        x = jnp.tile(x, (1, group))
+        return jnp.pad(x, ((0, 0), (0, rows - group * queries)),
+                       mode="edge")[:, None, :]
+
+    def head_major(x):                   # [B, n, kv, D] -> [B, kv, n, D]
+        return jnp.transpose(x, (0, 2, 1, 3))
+
+    def keys(x):                         # [B, T, kv, D] -> [B, kv, K, D]
+        return jnp.pad(head_major(x),
+                       ((0, 0), (0, 0), (0, own - queries), (0, 0)))
+
+    own_index = jnp.pad(index, (0, own - queries),
+                        constant_values=_FAR)[:, None]
+    return (visit.astype(jnp.int32).reshape(-1), fetch.reshape(-1),
+            _to_lanes(query), per_query(low), per_query(high),
+            head_major(ring_keys), head_major(ring_values),
+            ring_index[:, None], keys(key), keys(value), own_index)
+
+
+def _to_lanes(x):
+    """[B, T, kv, g, D] -> [B, kv, D, R], zeros in the padding."""
+    batch, queries, kv, group, dim = x.shape
+    x = jnp.transpose(x, (0, 2, 4, 3, 1)).reshape(
+        batch, kv, dim, group * queries)
+    return jnp.pad(x, ((0, 0),) * 3 + (
+        (0, _round_up(group * queries, _LANES) - group * queries),))
+
+
+def _from_lanes(x, queries, group):
+    """[B, kv, D, R] -> [B, T, kv, g, D]."""
+    batch, kv, dim, _ = x.shape
+    x = x[..., :group * queries].reshape(batch, kv, dim, group, queries)
+    return jnp.transpose(x, (0, 4, 1, 3, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _blockwise(query, key, value, ring_keys, ring_values, ring_index, index,
+               episode_start, visit, window, interpret):
+    """``_attend``'s result for any number of queries, with no score
+    outside VMEM.  Its arguments (query [B, T, kv, g, D]) and ``visit``,
+    ``visited_blocks`` of them."""
+    return _blockwise_fwd(query, key, value, ring_keys, ring_values,
+                          ring_index, index, episode_start, visit, window,
+                          interpret)[0]
+
+
+def _blockwise_fwd(query, key, value, ring_keys, ring_values, ring_index,
+                   index, episode_start, visit, window, interpret):
+    operands = _operands(query, key, value, ring_keys, ring_values,
+                         ring_index, index, episode_start, visit, window)
+    out, lse = _kernel(operands, interpret=interpret)
+    return (_from_lanes(out, query.shape[1], query.shape[3]),
+            (operands, out, lse))
+
+
+def _blockwise_bwd(window, interpret, saved, d_out):
+    del window
+    operands, out, lse = saved
+    queries, group = d_out.shape[1], d_out.shape[3]
+    dtype = operands[2].dtype
+    dq, dk, dv = _kernel(
+        operands, (out, lse, _to_lanes(d_out.astype(jnp.float32))),
+        backward=True, interpret=interpret)
+
+    def own_keys_back(x):                # [B, kv, K, D] -> [B, T, kv, D]
+        return jnp.transpose(x[:, :, :queries], (0, 2, 1, 3)).astype(dtype)
+
+    # the ring is the agent's state: nothing differentiates it
+    return (_from_lanes(dq, queries, group).astype(dtype),
+            own_keys_back(dk), own_keys_back(dv)) + (None,) * 6
+
+
+_blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
 def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
-                     index, episode_start, window: Optional[int] = None,
-                     block: Optional[int] = None):
+                     index, episode_start, window: Optional[int] = None):
     """``query`` [B, T, heads, D] and this call's own ``key`` / ``value``
     [B, T, kv, D] against themselves and the cache ``ring_keys`` /
-    ``ring_values`` [B, S, kv, D] -> [B, T, heads * D] in float32
-    (operands in their own dtype, scores, softmax and the weighted sum
-    in float32).
+    ``ring_values`` [B, S, kv, D] -> ([B, T, heads * D] in float32,
+    what the pass says of itself) (operands in their own dtype, scores,
+    softmax and the weighted sum in float32).
 
     ``ring_index`` [S]: the stream index of the token in each slot
     (``NO_KEY`` where the slot is empty or is one of this call's own
     tokens); ``index`` [T]: this call's tokens' stream indices;
     ``episode_start`` [B, T]: where each query's episode began;
-    ``window``: None on a full layer."""
+    ``window``: None on a full layer.
+
+    One query an env is ``_attend``; more go blockwise through the
+    kernel, and say which share of (env, key block) pairs it visited
+    (the ring's blocks some query of the env sees, and the own keys)."""
     batch, queries, heads, dim = query.shape
     kv = key.shape[2]
     query = query.reshape(batch, queries, kv, heads // kv, dim)
-    if block is None:
-        block = score_block(batch, heads, queries,
-                            ring_keys.shape[1] + queries)
-    if block >= batch:
+    if queries == 1:
         out = _attend(query, key, value, ring_keys, ring_values, ring_index,
                       index, episode_start, window)
-        return out.reshape(batch, queries, heads * dim)
+        return out.reshape(batch, queries, heads * dim), {}
+    from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
-    def blocks(x):
-        return x.reshape((batch // block, block) + x.shape[1:])
-
-    @jax.checkpoint
-    def one(xs):
-        q, k, v, rk, rv, start = xs
-        return _attend(q, k, v, rk, rv, ring_index, index, start, window)
-
-    out = jax.lax.map(one, tuple(blocks(x) for x in (
-        query, key, value, ring_keys, ring_values, episode_start)))
-    return out.reshape(batch, queries, heads * dim)
+    visit = visited_blocks(ring_index, index, episode_start, window,
+                           _key_block(ring_keys.shape[1]))
+    out = _blockwise(query, key, value, jax.lax.stop_gradient(ring_keys),
+                     jax.lax.stop_gradient(ring_values), ring_index, index,
+                     episode_start, visit, window, pallas_interpret())
+    share = (jnp.sum(visit) + batch) / (batch * (visit.shape[1] + 1))
+    return (out.reshape(batch, queries, heads * dim),
+            {"key_blocks_visited_share": share.astype(jnp.float32)})
 
 
 def ring_write(ring, new, written):

@@ -525,6 +525,47 @@ class TestAotCompileForV5e:
                              "parameter"], entry
         assert "pad(" in text and str(n + 1) not in text
 
+    @pytest.mark.parametrize("slots,window", [(2304, 2048), (4352, None)],
+                             ids=["window", "full"])
+    def test_update_attention_compiles_with_no_score_in_hbm(
+            self, monkeypatch, v5e_topology, slots, window):
+        """ISSUE 33: the blockwise attention kernels (ops/attention.py,
+        T > 1) at ``trinity.ingraph``'s widths — 257 queries of 32
+        heads over 4 key/value heads of 128, a ring of 2,304 / 4,352
+        slots — compiled alone for a v5e, forward and backward (the
+        interpreter never sees a VMEM limit or a tiling rule): two
+        Mosaic calls, and no float32 result as large as one env's
+        scores (257 x 32 heads x the ring) anywhere in the program."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import attention
+
+        _as_tpu(monkeypatch)
+        envs, queries, heads, kv, dim = 2, 257, 32, 4, 128
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(query, key, value, *cache):
+            out, _ = attention.cached_attention(query, key, value, *cache,
+                                                window=window)
+            return jnp.sum(out)
+
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            operand((envs, queries, heads, dim)),
+            operand((envs, queries, kv, dim)),
+            operand((envs, queries, kv, dim)),
+            operand((envs, slots, kv, dim)), operand((envs, slots, kv, dim)),
+            operand((slots,), jnp.int32), operand((queries,), jnp.int32),
+            operand((envs, queries), jnp.int32)).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        scores = queries * heads * slots
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest < scores, (largest, scores)
+
     @pytest.mark.parametrize("devices,overrides,merged", [
         (1, {}, 101 * 256),
         (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128),

@@ -786,6 +786,12 @@ def test_three_updates_through_the_driver(tmp_path):
     for name in TokenPolicy.STATS:
         assert np.isfinite(final[name]), name
     assert 0.0 < final["moe/pairs_here_share"] < 1.0
+    # the update's attention says how many key blocks it visited, and
+    # the number is a gauge like the expert layers' (ISSUE 33)
+    assert 0.0 < final["attention/key_blocks_visited_share"] <= 1.0
+    assert driver.get_registry().gauge(
+        "attention/key_blocks_visited_share").value == pytest.approx(
+            final["attention/key_blocks_visited_share"])
     for group in TokenPolicy.layer_groups:
         assert f"devtel/learn/grad_norm_{group}" in (
             driver.get_registry().snapshot())
