@@ -295,28 +295,32 @@ def _token_policy_refusals(config: Config):
 
 def build_token_policy(config: Config, action_space, frame_shape=None):
     """The token policy (models/token_policy.py) from the file
-    ``--model_config`` names, its rings sized for this run's unroll and
-    its world's episodes.  Every combination it is not built for is
-    refused here, at configuration time, by name."""
+    ``--model_config`` names (its ``model_type`` says which of the
+    policy's families), its rings sized for this run's unroll and its
+    world's episodes.  Every combination it is not built for is refused
+    here, at configuration time, by name."""
     from scalable_agent_tpu.envs.device import DEVICE_LEVELS
     from scalable_agent_tpu.models.token_policy import (
         TokenModelConfig,
         TokenPolicy,
     )
 
+    # a family the policy does not build is refused there, with the
+    # families it does
+    model = TokenModelConfig.from_file(config.model_config)
+    family = model.model_type
     for refused, why in _token_policy_refusals(config):
         if refused:
             raise ValueError(
-                f"the token policy (--model_config) does not run with "
-                f"{why}")
-    model = TokenModelConfig.from_file(config.model_config)
+                f"the token policy (--model_config, family {family}) does "
+                f"not run with {why}")
     level = DEVICE_LEVELS.get(config.level_name)
     tokens = getattr(action_space, "n", None)
     if (level is None or "episode_length" not in level.defaults
             or tuple(frame_shape or ()) != ()):
         raise ValueError(
-            f"the token policy (--model_config) acts in a token world (a "
-            f"device level whose "
+            f"the token policy (--model_config, family {family}) acts in "
+            f"a token world (a device level whose "
             f"observation is a token id, e.g. token_recall); "
             f"--level_name={config.level_name} is none")
     if tokens != model.vocab_size:
@@ -336,20 +340,29 @@ def build_token_policy(config: Config, action_space, frame_shape=None):
              "slots of a window layer's ring (window + unroll)"),
             ("cache/full_slots", agent.full_slots,
              "slots of a full layer's ring (episode + unroll)"),
+            ("cache/ring_readers", agent.ring_readers,
+             "the most layers that read one ring: its own layer and the "
+             "cross layers into it"),
+            ("ssm/state_bytes", agent.ssm_state_bytes(config.batch_size),
+             "bytes of the state-space layers' recurrent states and "
+             "convolution tails the rollout carries (float32)"),
             ("policy/vocab_slice", model.vocab_size,
              "tokens of the vocabulary this chip's head and embedding "
              "hold")):
         registry.gauge(name, text).set(value)
     log.info(
-        "kernel policy: backend=%s mesh_devices=%d policy=token "
-        "model_config=%s layers=%d experts_held=%d/%d (first %d) "
-        "window_slots=%d full_slots=%d core_impl=%s conv_backend=%s "
-        "remat=%s compute_dtype=%s",
-        jax.default_backend(), _intended_mesh_size(config),
-        config.model_config, model.num_hidden_layers, model.experts_held,
-        model.num_experts, model.first_expert, agent.window_slots,
-        agent.full_slots, agent.core_impl, agent.conv_backend,
-        agent.remat_placement, config.compute_dtype)
+        "kernel policy: backend=%s mesh_devices=%d policy=token family=%s "
+        "model_config=%s layers=%d (%s) experts_held=%d/%d (first %d) "
+        "window_slots=%d full_slots=%d ring_readers=%d core_impl=%s "
+        "conv_backend=%s remat=%s compute_dtype=%s",
+        jax.default_backend(), _intended_mesh_size(config), family,
+        config.model_config, model.num_hidden_layers,
+        ", ".join(f"{model.layer_types.count(kind)} {kind}"
+                  for kind in dict.fromkeys(model.layer_types)),
+        model.experts_held, model.num_experts, model.first_expert,
+        agent.window_slots, agent.full_slots, agent.ring_readers,
+        agent.core_impl, agent.conv_backend, agent.remat_placement,
+        config.compute_dtype)
     return agent
 
 

@@ -227,6 +227,14 @@ register_device_level(
                 "positions: the token to predict lies past a 2,048 "
                 "window, inside a 4,096-token episode")
 register_device_level(
+    "token_recall_long",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=25008, episode_length=6144, period=3584),
+    description="the same world for a policy with windows of 512 and "
+                "one full cache: episodes of 6,144 tokens that repeat "
+                "after 3,584 positions, seven windows back, from an "
+                "eighth of a 200,064-token vocabulary")
+register_device_level(
     "token_recall_small",
     "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
     dict(num_actions=64, episode_length=16, period=10),

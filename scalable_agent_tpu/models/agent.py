@@ -262,6 +262,7 @@ class ImpalaAgent(nn.Module):
     stats_collection = None
     STATS = ()
     init_in_one_program = False
+    init_steps = None           # the whole example trajectory
 
     @staticmethod
     def layer_group(path) -> str:

@@ -1,19 +1,29 @@
-"""A token policy: a decoder of the ``afmoe`` family (window and full
-attention mixed, a mixture of experts with a shared expert) behind the
-agent's calling contract.
+"""A token policy: a decoder behind the agent's calling contract, its
+layers a mixer (attention through a ring of its own, attention into
+another layer's ring, a state-space scan, a gated memory unit) and an
+MLP (dense or experts), picked by the configuration file.  Two families
+(``FAMILIES``): ``afmoe`` (window and full attention mixed, a mixture of
+experts with a shared expert) and ``phi4flash`` (the decoder-hybrid-
+decoder: state-space and window layers, one full-attention layer whose
+cache every later cross layer reads, memory units gated by the last
+state-space layer's output).
 
 ``__call__(actions, env_outputs, state) -> ((policy_logits, baseline),
 state)`` over time-major ``[T, B]`` inputs, as ``ImpalaAgent`` has it:
 T = 1 is acting and T = unroll is learning, from one definition.  The
 observation is a token id (``observation.frame``, int32), the action a
 token of the same vocabulary, and the agent's state is its attention
-cache (``TokenCache``): per layer a ring of keys and values, each
-slot's index in the env's token stream beside it, and where each env's
-episode began.  An episode's end clears nothing: a query sees a key of
-its own episode only (ops/attention.py), so ``done`` moves
-``episode_start`` and the stale slots fall out of every mask.
+cache (``TokenCache``): per layer that makes keys a ring of keys and
+values, each slot's index in the env's token stream beside it, where
+each env's episode began, and per state-space layer its recurrent state
+and the last inputs of its short convolution.  An episode's end clears
+no ring: a query sees a key of its own episode only (ops/attention.py),
+so ``done`` moves ``episode_start`` and the stale slots fall out of
+every mask; a recurrence cannot be masked after the fact, so the scan
+zeroes its state at an episode's first token (ops/ssm.py) and the
+convolution drops the taps that reach before it.
 
-The layer, for token ids ``x`` (sizes under the source's key names,
+The ``afmoe`` layer, for token ids ``x`` (sizes under the source's key names,
 ``TokenModelConfig``; benchmark/references/afmoe_token.py is the plain
 float32 statement of the same equations)::
 
@@ -30,37 +40,83 @@ float32 statement of the same equations)::
 One chip holds ``experts_held`` of ``num_experts`` experts and routes
 over all of them.
 
+The ``phi4flash`` layers (benchmark/references/sambay_token.py states
+them in float32; no position encoding anywhere, LayerNorm with bias, a
+tied head)::
+
+    h = E[x];  h = h + Mixer(LN_in(h));  h = h + MLP(LN_post(h))
+    state space: [x, z] = a W_in;  x = silu(conv_4(x) + b)
+                 [d, B, C] = x W_x;  delta = softplus(d W_dt + b_dt)
+                 y = scan(x, delta, -exp(A_log), B, C) + D x   (ops/ssm.py)
+                 memory = y;  out = (y * silu(z)) W_out
+    memory unit: out = (silu(a W_in) * memory) W_out      the same token's
+    differential attention (heads in adjacent pairs, v of a pair 2 x wide):
+                 o = (softmax(q1 k1) - lambda softmax(q2 k2)) v
+                 lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init(l)
+                 out = concat(RMSNorm(o) * (1 - lambda_init)) W_o
+    a cross layer projects q only: k, v and the ring are the full layer's
+
 The rings are sized so that ONE buffer serves the rollout and the
 update: ``window + unroll`` slots (``episode_length + unroll`` on a full
 layer) still hold, when an unroll ends, everything its first query may
 see, so the update attends into the cache as the rollout left it and
 masks the unroll's own slots by their index (``unroll_state``); no copy
-of the cache is kept from the unroll's start.
+of the rings is kept from the unroll's start.  The recurrent state and
+the convolution's tail are kept from the start (0.8 MB an env at the
+published widths): the update scans again from them.
 """
 
 import dataclasses
 import json
 import math
 import os
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from scalable_agent_tpu.ops import attention as attention_lib
-from scalable_agent_tpu.ops import distributions, moe
+from scalable_agent_tpu.ops import distributions, moe, ssm
 from scalable_agent_tpu.types import StepOutput
 
 SLIDING = "sliding_attention"
 FULL = "full_attention"
+CROSS = "cross_attention"       # queries only, into the last full layer's ring
+STATE_SPACE = "state_space"
+MEMORY_UNIT = "memory_unit"     # gated by the last state-space layer's output
+FAMILIES = ("afmoe", "phi4flash")
+_OWN_RING = (SLIDING, FULL)
+# the keys a family's file must have (the rest of the fields default)
+_ALWAYS = ("vocab_size", "hidden_size", "num_attention_heads",
+           "num_key_value_heads", "intermediate_size", "num_hidden_layers",
+           "sliding_window")
+_REQUIRED = {
+    "afmoe": _ALWAYS + (
+        "head_dim", "layer_types", "moe_intermediate_size", "num_experts",
+        "num_experts_per_tok", "num_shared_experts", "num_dense_layers",
+        "route_scale", "route_norm", "rope_theta", "rms_norm_eps",
+        "mup_enabled", "experts_held"),
+    "phi4flash": _ALWAYS + (
+        "layer_kinds", "layer_norm_eps", "mamba_d_state", "mamba_d_conv",
+        "mamba_expand", "mamba_dt_rank"),
+}
+# what a family's file may not say otherwise
+_ONLY = {
+    "afmoe": (("hidden_act", "silu"), ("score_func", "sigmoid"),
+              ("rope_scaling", None)),
+    "phi4flash": (("hidden_act", "silu"), ("tie_word_embeddings", True),
+                  ("mlp_bias", False), ("lm_head_bias", False)),
+}
 
 
 @dataclasses.dataclass(frozen=True)
 class TokenModelConfig:
     """The sizes of the model as it is run, under the source's own key
     names, from one JSON file (``from_file``); keys it does not name are
-    the file's own business (loss, optimizer, flags)."""
+    the file's own business (loss, optimizer, flags).  ``model_type``
+    says which family's keys the file has; the other family's stay at
+    their defaults and nothing reads them."""
 
     vocab_size: int
     hidden_size: int
@@ -68,49 +124,119 @@ class TokenModelConfig:
     num_attention_heads: int
     num_key_value_heads: int
     intermediate_size: int
-    moe_intermediate_size: int
-    num_experts: int
-    num_experts_per_tok: int
-    num_shared_experts: int
     num_hidden_layers: int
-    num_dense_layers: int
-    layer_types: Tuple[str, ...]
+    layer_types: Tuple[str, ...]        # each layer's mixer
     sliding_window: int
-    route_scale: float
-    route_norm: bool
-    rope_theta: float
-    rms_norm_eps: float
-    mup_enabled: bool
-    experts_held: int
+    model_type: str = "afmoe"
+    # afmoe
+    moe_intermediate_size: int = 0
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    num_shared_experts: int = 0
+    num_dense_layers: int = 0
+    route_scale: float = 1.0
+    route_norm: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = False
+    experts_held: int = 0
     first_expert: int = 0
+    # phi4flash
+    layer_index: Tuple[int, ...] = ()   # each layer's published index
+    layer_norm_eps: float = 1e-5
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    def is_expert_layer(self, layer: int) -> bool:
+        return self.model_type == "afmoe" and layer >= self.num_dense_layers
+
+    def ring_of(self, layer: int) -> Optional[int]:
+        """The layer whose ring ``layer`` attends into: its own, the last
+        full layer before it (a cross layer), none."""
+        kind = self.layer_types[layer]
+        if kind in _OWN_RING:
+            return layer
+        if kind == CROSS:
+            return max(at for at in range(layer)
+                       if self.layer_types[at] == FULL)
+        return None
+
+    @property
+    def memory_from(self) -> Optional[int]:
+        """The state-space layer whose output gates the memory units:
+        the last one before the first of them."""
+        if MEMORY_UNIT not in self.layer_types:
+            return None
+        first = self.layer_types.index(MEMORY_UNIT)
+        return max(at for at in range(first)
+                   if self.layer_types[at] == STATE_SPACE)
 
     @classmethod
     def from_dict(cls, raw: Dict[str, Any]) -> "TokenModelConfig":
-        for key, want in (("model_type", "afmoe"), ("hidden_act", "silu"),
-                          ("score_func", "sigmoid"), ("rope_scaling", None)):
+        family = raw.get("model_type", "afmoe")
+        if family not in FAMILIES:
+            raise ValueError(
+                f"token policy: model_type={family!r} is not built "
+                f"(only {', '.join(map(repr, FAMILIES))})")
+        for key, want in _ONLY[family]:
             if raw.get(key, want) != want:
                 raise ValueError(
                     f"token policy: {key}={raw[key]!r} is not built "
                     f"(only {want!r})")
-        names = [f.name for f in dataclasses.fields(cls)]
-        missing = [n for n in names if n not in raw and n != "first_expert"]
+        missing = [n for n in _REQUIRED[family] if n not in raw]
         if missing:
             raise ValueError(
                 f"token policy: the model configuration lacks {missing}")
+        names = [f.name for f in dataclasses.fields(cls)]
         values = {n: raw[n] for n in names if n in raw}
+        values["model_type"] = family
+        if family == "phi4flash":
+            values["layer_types"] = [k["kind"] for k in raw["layer_kinds"]]
+            values["layer_index"] = tuple(
+                int(k["published_index"]) for k in raw["layer_kinds"])
+            values.setdefault("head_dim", raw["hidden_size"]
+                              // raw["num_attention_heads"])
         values["layer_types"] = tuple(values["layer_types"])
         model = cls(**values)
+        kinds = ((SLIDING, FULL) if family == "afmoe" else
+                 (SLIDING, FULL, CROSS, STATE_SPACE, MEMORY_UNIT))
         if len(model.layer_types) != model.num_hidden_layers or any(
-                kind not in (SLIDING, FULL) for kind in model.layer_types):
+                kind not in kinds for kind in model.layer_types):
+            if family == "afmoe":
+                raise ValueError(
+                    "token policy: layer_types must name sliding_attention "
+                    "or full_attention for each of num_hidden_layers")
             raise ValueError(
-                "token policy: layer_types must name sliding_attention or "
-                "full_attention for each of num_hidden_layers")
-        if not (0 <= model.first_expert and model.first_expert
+                f"token policy: layer_kinds must name one of {kinds} for "
+                f"each of num_hidden_layers")
+        if family == "afmoe" and not (
+                0 <= model.first_expert and model.first_expert
                 + model.experts_held <= model.num_experts):
             raise ValueError(
                 f"token policy: experts [{model.first_expert}, "
                 f"{model.first_expert + model.experts_held}) are not "
                 f"among {model.num_experts}")
+        for layer, kind in enumerate(model.layer_types):
+            before = model.layer_types[:layer]
+            if ((kind == CROSS and FULL not in before)
+                    or (kind == MEMORY_UNIT and STATE_SPACE not in before)):
+                raise ValueError(
+                    f"token policy: layer {layer} ({kind}) has no "
+                    f"{FULL if kind == CROSS else STATE_SPACE} layer "
+                    f"before it to read")
+        if family == "phi4flash" and (
+                model.num_attention_heads % 2 or model.num_key_value_heads % 2
+                or (model.num_attention_heads // 2)
+                % (model.num_key_value_heads // 2)):
+            raise ValueError(
+                "token policy: differential attention takes heads in "
+                "pairs, the query pairs a multiple of the key pairs")
         return model
 
     @classmethod
@@ -125,14 +251,19 @@ class TokenModelConfig:
 
 
 class TokenCache(NamedTuple):
-    """The token policy's state: what the next query attends back into."""
+    """The token policy's state: what the next query attends back into
+    and what the next token's recurrences continue from."""
 
-    keys: Tuple[Any, ...]       # per layer [B, slots, kv_heads, head_dim]
-    values: Tuple[Any, ...]
+    keys: Tuple[Any, ...]       # per layer that makes keys, in order:
+    values: Tuple[Any, ...]     #   [B, slots, kv_heads, head_dim]
     window_index: Any           # i32 [window slots]: stream index by slot
     full_index: Any             # i32 [full slots]
     written: Any                # i32 []: tokens in every env's stream
     episode_start: Any          # i32 [B]: index at which the episode began
+    # per state-space layer, in order (ops/ssm.py has why states lie
+    # down the sublanes): f32 [B, d_state, d_inner], [B, d_conv - 1, d_inner]
+    ssm_state: Tuple[Any, ...] = ()
+    conv_tail: Tuple[Any, ...] = ()
 
 
 class _Linear(nn.Module):
@@ -176,6 +307,22 @@ class _RMSNorm(nn.Module):
         y = x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
         return y * scale
+
+
+class _LayerNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+        y = x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
+        return y * scale + bias
 
 
 class _GatedMLP(nn.Module):
@@ -301,37 +448,207 @@ class _Attention(nn.Module):
                 ring_keys, ring_values, stats)
 
 
-class _Layer(nn.Module):
+class _Handed(NamedTuple):
+    """What a layer leaves for the later layers of the same call: the
+    gating state-space layer's output, and the full layer's own keys and
+    values of this call beside its ring as this call found it."""
+
+    memory: Any = None          # f32 [B, T, d_inner]
+    key: Any = None             # [B, T, kv pairs, 2 * head_dim]
+    value: Any = None
+    ring_keys: Any = None
+    ring_values: Any = None
+
+
+def lambda_init(published_index: int) -> float:
+    return 0.8 - 0.6 * math.exp(-0.3 * published_index)
+
+
+class _DifferentialAttention(nn.Module):
+    """Heads in adjacent pairs: a pair's two queries score its two keys
+    in two softmaxes over the one value of twice the width
+    (``ops/attention.py`` ``streams``).  A cross layer projects queries
+    only and reads the full layer's keys, values and ring."""
+
     model: TokenModelConfig
-    sliding: bool
-    expert: bool
+    kind: str
+    published_index: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, index, episode_start, ring_keys, ring_values,
+                 ring_index, written, handed: _Handed):
+        model, dtype = self.model, self.dtype
+        batch, count, _ = a.shape
+        pairs, kv = (model.num_attention_heads // 2,
+                     model.num_key_value_heads // 2)
+        dim = 2 * model.head_dim
+
+        def project(name, heads):
+            return attention_lib.round_to(
+                _Linear(heads * dim, dtype, name=name)(a).reshape(
+                    batch, count, heads, dim), dtype)
+
+        query = project("q_proj", pairs)
+        if self.kind == CROSS:
+            key, value = handed.key, handed.value
+            ring_keys, ring_values = handed.ring_keys, handed.ring_values
+        else:
+            key, value = project("k_proj", kv), project("v_proj", kv)
+        scope = {SLIDING: "window", FULL: "full", CROSS: "cross"}[self.kind]
+        with jax.named_scope(scope):
+            out, stats = attention_lib.cached_attention(
+                query, key, value, ring_keys, ring_values, ring_index,
+                index, episode_start,
+                window=model.sliding_window if self.kind == SLIDING else None,
+                streams=2)
+            if self.kind == FULL:
+                handed = handed._replace(
+                    key=key, value=value, ring_keys=ring_keys,
+                    ring_values=ring_values)
+            if self.kind != CROSS:
+                ring_keys = attention_lib.ring_write(ring_keys, key, written)
+                ring_values = attention_lib.ring_write(ring_values, value,
+                                                       written)
+        out = out.reshape(batch, count, pairs, 2, dim)
+        small = nn.initializers.normal(0.1)
+        lq1, lk1, lq2, lk2 = (
+            self.param(name, small, (model.head_dim,))
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+        start = lambda_init(self.published_index)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + start
+        out = _RMSNorm(model.layer_norm_eps, name="pair_norm")(
+            out[..., 0, :] - lam * out[..., 1, :]) * (1.0 - start)
+        return (_Linear(model.hidden_size, dtype, name="o_proj")(
+            out.reshape(batch, count, pairs * dim)),
+            ring_keys, ring_values, handed, stats)
+
+
+def _family_dt_bias(key, shape, dtype=jnp.float32):
+    """The family's start of the step's bias: softplus(bias) log-uniform
+    in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class _StateSpace(nn.Module):
+    """The selective state-space mixer: a short causal convolution and
+    the scan of ``ops/ssm.py``, both of which start afresh at an
+    episode's first token."""
+
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, position, state, tail):
+        model, dtype = self.model, self.dtype
+        count = a.shape[1]
+        width, states = model.d_inner, model.mamba_d_state
+        taps, rank = model.mamba_d_conv, model.mamba_dt_rank
+        x, z = jnp.split(_Linear(2 * width, dtype, name="in_proj")(a), 2,
+                         axis=-1)
+        with jax.named_scope("conv"):
+            kernel = self.param(
+                "conv_kernel", nn.initializers.normal(1.0 / math.sqrt(taps)),
+                (taps, width))
+            bias = self.param("conv_bias", nn.initializers.zeros_init(),
+                              (width,))
+            # tap k reaches k tokens back, and not before the episode
+            seen = jnp.concatenate([tail, x], axis=1)
+            x = bias + sum(
+                kernel[taps - 1 - back]
+                * seen[:, taps - 1 - back:taps - 1 - back + count]
+                * (position >= back)[..., None] for back in range(taps))
+            x = jax.nn.silu(x)
+            tail = seen[:, count:]
+        chosen = _Linear(rank + 2 * states, dtype, name="x_proj")(x)
+        delta = jax.nn.softplus(
+            _Linear(width, dtype, name="dt_proj")(chosen[..., :rank])
+            + self.param("dt_bias", _family_dt_bias, (width,)))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[1] + 1, dtype=jnp.float32)), shape), (width, states))
+        skip = self.param("D", nn.initializers.ones_init(), (width,))
+        with jax.named_scope("scan"):
+            y, state = ssm.selective_scan(
+                x, delta, -jnp.exp(a_log).T, skip,
+                chosen[..., rank:rank + states], chosen[..., rank + states:],
+                position == 0, state)
+        with jax.named_scope("gate"):
+            gated = y * jax.nn.silu(z)
+        return (_Linear(model.hidden_size, dtype, name="out_proj")(gated), y,
+                state, tail)
+
+
+class _MemoryUnit(nn.Module):
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, memory):
+        gate = _Linear(self.model.d_inner, self.dtype, name="in_proj")(a)
+        return _Linear(self.model.hidden_size, self.dtype, name="out_proj")(
+            jax.nn.silu(gate) * memory)
+
+
+class _Layer(nn.Module):
+    """A mixer and an MLP between residual adds, as the family places
+    its norms.  ``ring_*``: the ring the mixer attends into (None where
+    it attends into none); ``state`` / ``tail``: a state-space mixer's;
+    ``handed``: what earlier layers of this call left."""
+
+    model: TokenModelConfig
+    layer: int
     dtype: Any
 
     @nn.compact
     def __call__(self, h, position, index, episode_start, ring_keys,
-                 ring_values, ring_index, written):
+                 ring_values, ring_index, written, handed, state, tail):
         model, dtype = self.model, self.dtype
+        kind = model.layer_types[self.layer]
+        afmoe = model.model_type == "afmoe"
 
         def norm(name):
-            return _RMSNorm(model.rms_norm_eps, name=name)
+            if afmoe:
+                return _RMSNorm(model.rms_norm_eps, name=name)
+            return _LayerNorm(model.layer_norm_eps, name=name)
 
-        attn, ring_keys, ring_values, seen = _Attention(
-            model, self.sliding, dtype, name="attention")(
-                norm("input_norm")(h), position, index, episode_start,
-                ring_keys, ring_values, ring_index, written)
+        seen = {}                   # what an attention pass says of itself
+        a = norm("input_norm")(h)
+        if afmoe:
+            mixed, ring_keys, ring_values, seen = _Attention(
+                model, kind == SLIDING, dtype, name="attention")(
+                    a, position, index, episode_start, ring_keys,
+                    ring_values, ring_index, written)
+        elif kind == STATE_SPACE:
+            mixed, memory, state, tail = _StateSpace(
+                model, dtype, name="ssm")(a, position, state, tail)
+            if self.layer == model.memory_from:
+                handed = handed._replace(memory=memory)
+        elif kind == MEMORY_UNIT:
+            mixed = _MemoryUnit(model, dtype, name="gmu")(a, handed.memory)
+        else:
+            mixed, ring_keys, ring_values, handed, seen = (
+                _DifferentialAttention(
+                    model, kind, model.layer_index[self.layer], dtype,
+                    name="attention")(
+                        a, index, episode_start, ring_keys, ring_values,
+                        ring_index, written, handed))
         stats = {f"attention/{name}": x for name, x in seen.items()}
-        h = h + norm("post_attn_norm")(attn)
+        h = h + (norm("post_attn_norm")(mixed) if afmoe else mixed)
         m = norm("pre_mlp_norm")(h)
         flat = m.reshape(-1, m.shape[-1])
-        if self.expert:
+        if model.is_expert_layer(self.layer):
             # one token an env: a decode step
             f, routed = _MoE(model, dtype, name="moe")(
                 flat, decode=m.shape[1] == 1)
             stats.update({f"moe/{name}": x for name, x in routed.items()})
         else:
             f = _GatedMLP(model.intermediate_size, dtype, name="mlp")(flat)
-        h = h + norm("post_mlp_norm")(f.reshape(m.shape))
-        return h, ring_keys, ring_values, stats
+        f = f.reshape(m.shape)
+        h = h + (norm("post_mlp_norm")(f) if afmoe else f)
+        return h, ring_keys, ring_values, handed, state, tail, stats
 
 
 class _Baseline(nn.Module):
@@ -350,15 +667,40 @@ class _Baseline(nn.Module):
 
 
 class _Embed(nn.Module):
+    """The table: the caller looks tokens up in it and, where the head
+    is tied, multiplies by it."""
+
     vocab: int
     hidden: int
 
     @nn.compact
-    def __call__(self, tokens):
-        table = self.param(
+    def __call__(self):
+        return self.param(
             "embedding", nn.initializers.normal(1.0 / math.sqrt(self.hidden)),
             (self.vocab, self.hidden))
-        return table[tokens]
+
+
+def tied_logits(z, table, dtype):
+    """``z`` [T, B, hidden] against the rows of the embedding ``table``
+    [vocab, hidden] this chip holds: its slice of the logits."""
+    return jax.lax.dot_general(
+        attention_lib.round_to(z, dtype), table.astype(dtype),
+        (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+# family -> (learning-dynamics telemetry's parameter groups, the numbers
+# the forward pass leaves in the ``stats`` collection)
+_TELEMETRY = {
+    "afmoe": (
+        ("embedding", "attention", "experts", "mlp", "norms", "heads"),
+        ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
+         "moe/expert_load_max_over_mean",
+         "attention/key_blocks_visited_share")),
+    # the tied table is the head too, and is counted as the embedding
+    "phi4flash": (
+        ("embedding", "attention", "ssm", "gmu", "mlp", "norms", "heads"),
+        ("attention/key_blocks_visited_share",)),
+}
 
 
 class TokenPolicy(nn.Module):
@@ -388,15 +730,26 @@ class TokenPolicy(nn.Module):
     # learning-dynamics telemetry (runtime/learner.py): the parameter
     # groups, no module whose dead units are read, and the collection
     # the forward pass leaves its own numbers in
-    layer_groups = ("embedding", "attention", "experts", "mlp", "norms",
-                    "heads")
     dead_unit_module = None
     stats_collection = "stats"
-    # ``Learner.init``: one jitted program, not ~400 eager ones
+    # ``Learner.init``: one jitted program, not ~400 eager ones, of one
+    # token an env (no parameter's shape follows the unroll's length, and
+    # the whole unroll's forward pass, kernels and all, took 23 s to
+    # compile for the compiler to drop it; my chip run, PR 34)
     init_in_one_program = True
-    STATS = ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
-             "moe/expert_load_max_over_mean",
-             "attention/key_blocks_visited_share")
+    init_steps = 1
+
+    # the parameter groups and what the forward pass says of itself
+    # (``_TELEMETRY``): the first family's here, the model's own once
+    # the policy is made
+    layer_groups: Tuple[str, ...] = _TELEMETRY["afmoe"][0]
+    STATS: Tuple[str, ...] = _TELEMETRY["afmoe"][1]
+
+    def __post_init__(self):
+        groups, stats = _TELEMETRY[self.model.model_type]
+        object.__setattr__(self, "layer_groups", groups)
+        object.__setattr__(self, "STATS", stats)
+        super().__post_init__()
 
     @staticmethod
     def layer_group(path) -> str:
@@ -405,10 +758,11 @@ class TokenPolicy(nn.Module):
             return "heads"
         if "embed" in keys:
             return "embedding"
-        if keys[-1] == "scale":
+        if keys[-1] == "scale" or keys[-2].endswith("norm"):
             return "norms"
-        if "attention" in keys:
-            return "attention"
+        for group in ("attention", "ssm", "gmu"):
+            if group in keys:
+                return group
         if "experts" in keys or "router" in keys:
             return "experts"
         return "mlp"
@@ -446,24 +800,55 @@ class TokenPolicy(nn.Module):
                 if self.model.layer_types[layer] == SLIDING
                 else self.full_slots)
 
+    @property
+    def _ring_layers(self) -> Tuple[int, ...]:
+        """The layers that make keys, in order: ``TokenCache.keys[i]`` is
+        the ring of the i-th of them."""
+        return tuple(layer for layer, kind
+                     in enumerate(self.model.layer_types)
+                     if kind in _OWN_RING)
+
+    @property
+    def _scan_layers(self) -> Tuple[int, ...]:
+        return tuple(layer for layer, kind
+                     in enumerate(self.model.layer_types)
+                     if kind == STATE_SPACE)
+
+    @property
+    def ring_readers(self) -> int:
+        """The most layers that read one ring (its own layer and the
+        cross layers into it)."""
+        model = self.model
+        read = [model.ring_of(layer)
+                for layer in range(model.num_hidden_layers)]
+        return max(read.count(layer) for layer in self._ring_layers)
+
     def initial_state(self, batch: int) -> TokenCache:
         model = self.model
+        if model.model_type == "afmoe":
+            heads, dim = model.num_key_value_heads, model.head_dim
+        else:       # a pair of heads is one of twice the width
+            heads, dim = model.num_key_value_heads // 2, 2 * model.head_dim
 
         def ring(layer):
-            return jnp.zeros((batch, self._slots(layer),
-                              model.num_key_value_heads, model.head_dim),
+            return jnp.zeros((batch, self._slots(layer), heads, dim),
                              self.compute_dtype)
 
-        layers = range(model.num_hidden_layers)
+        def per_scan(rows):
+            return tuple(jnp.zeros((batch, rows, model.d_inner), jnp.float32)
+                         for _ in self._scan_layers)
+
         return TokenCache(
-            keys=tuple(ring(layer) for layer in layers),
-            values=tuple(ring(layer) for layer in layers),
+            keys=tuple(ring(layer) for layer in self._ring_layers),
+            values=tuple(ring(layer) for layer in self._ring_layers),
             window_index=jnp.full((self.window_slots,),
                                   attention_lib.NO_KEY, jnp.int32),
             full_index=jnp.full((self.full_slots,),
                                 attention_lib.NO_KEY, jnp.int32),
             written=jnp.zeros((), jnp.int32),
-            episode_start=jnp.zeros((batch,), jnp.int32))
+            episode_start=jnp.zeros((batch,), jnp.int32),
+            ssm_state=per_scan(model.mamba_d_state),
+            conv_tail=per_scan(model.mamba_d_conv - 1))
 
     def unroll_state(self, start: TokenCache, end: TokenCache) -> TokenCache:
         """The state the update unrolls from, without a copy of the
@@ -471,16 +856,26 @@ class TokenPolicy(nn.Module):
         them, under the start's counters.  ``__call__`` masks every slot
         not written before ``written``, which hides the unroll's own,
         and the rings are long enough that the unroll overwrote nothing
-        its queries may see."""
+        its queries may see.  A recurrence cannot be masked after the
+        fact: the scans' states and the convolutions' tails are the
+        start's."""
         return end._replace(written=start.written,
-                            episode_start=start.episode_start)
+                            episode_start=start.episode_start,
+                            ssm_state=start.ssm_state,
+                            conv_tail=start.conv_tail)
 
     def cache_bytes(self, batch: int) -> int:
         model = self.model
         per_slot = (2 * model.num_key_value_heads * model.head_dim
                     * jnp.dtype(self.compute_dtype).itemsize)
         return batch * per_slot * sum(
-            self._slots(layer) for layer in range(model.num_hidden_layers))
+            self._slots(layer) for layer in self._ring_layers)
+
+    def ssm_state_bytes(self, batch: int) -> int:
+        """The scans' states and the convolutions' tails, float32."""
+        model = self.model
+        return (batch * 4 * model.d_inner * len(self._scan_layers)
+                * (model.mamba_d_state + model.mamba_d_conv - 1))
 
     def acting_params(self, params):
         """The parameters as acting reads them: cast once to the compute
@@ -488,9 +883,11 @@ class TokenPolicy(nn.Module):
         router stays float32: its product is float32)."""
         def cast(path, leaf):
             keys = [str(getattr(entry, "key", entry)) for entry in path]
-            # matrices only: a norm's scale and the one bias are read
-            # in float32 by both passes
-            if "router" in keys or leaf.ndim < 2:
+            # matrix products' operands only: a norm's scale, the one
+            # bias and what a scan or its convolution reads are read in
+            # float32 by both passes
+            if ("router" in keys or leaf.ndim < 2
+                    or keys[-1] in ("A_log", "conv_kernel")):
                 return leaf
             return leaf.astype(self.compute_dtype)
 
@@ -518,9 +915,8 @@ class TokenPolicy(nn.Module):
 
         # rounded to the compute dtype first: acting reads a table cast
         # beforehand (``acting_params``), learning the float32 one
-        h = attention_lib.round_to(
-            _Embed(model.vocab_size, model.hidden_size, name="embed")(
-                tokens.T), dtype).astype(jnp.float32)
+        table = _Embed(model.vocab_size, model.hidden_size, name="embed")()
+        h = attention_lib.round_to(table[tokens.T], dtype).astype(jnp.float32)
         if model.mup_enabled:
             h = h * math.sqrt(model.hidden_size)
 
@@ -534,18 +930,33 @@ class TokenPolicy(nn.Module):
         # pairs' buffers of an expert layer are 1 GB at 8,224 tokens,
         # and the float32 activations between matmuls 67 MB apiece.
         # Attention's scores are not among them (its kernel writes none
-        # and its backward recomputes a block's in VMEM), so the
-        # boundary costs attention one more forward kernel, no more.
+        # and its backward recomputes a block's in VMEM), nor a scan's
+        # states, so the boundary costs each one more forward kernel.
         layer_cls = nn.remat(_Layer) if count > 1 else _Layer
-        keys, values, stats = [], [], []
+        keys, values, stats = list(state.keys), list(state.values), []
+        ssm_state, conv_tail = list(state.ssm_state), list(state.conv_tail)
+        handed = _Handed()
         for layer, kind in enumerate(model.layer_types):
-            h, ring_keys, ring_values, layer_stats = layer_cls(
-                model, kind == SLIDING, layer >= model.num_dense_layers,
-                dtype, name=f"layer_{layer}")(
-                    h, position, index, start, state.keys[layer],
-                    state.values[layer], ring_index[kind], written)
-            keys.append(ring_keys)
-            values.append(ring_values)
+            # the ring the layer attends into, and the slot of its own
+            reads = model.ring_of(layer)
+            ring = (self._ring_layers.index(reads)
+                    if reads is not None else None)
+            scan = (self._scan_layers.index(layer)
+                    if kind == STATE_SPACE else None)
+            h, ring_keys, ring_values, handed, scanned, tail, layer_stats = (
+                layer_cls(model, layer, dtype, name=f"layer_{layer}")(
+                    h, position, index, start,
+                    None if ring is None else state.keys[ring],
+                    None if ring is None else state.values[ring],
+                    None if reads is None
+                    else ring_index[model.layer_types[reads]],
+                    written, handed,
+                    None if scan is None else ssm_state[scan],
+                    None if scan is None else conv_tail[scan]))
+            if kind in _OWN_RING:
+                keys[ring], values[ring] = ring_keys, ring_values
+            if scan is not None:
+                ssm_state[scan], conv_tail[scan] = scanned, tail
             stats.append(layer_stats)
         # each number's mean over the layers that say it (acting says
         # none of the attention's: one query an env visits every slot)
@@ -556,10 +967,17 @@ class TokenPolicy(nn.Module):
                          jnp.mean(jnp.stack(said)),
                          init_fn=lambda: 0.0, reduce_fn=lambda _, new: new)
 
-        z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
+        if model.model_type == "afmoe":
+            z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
+        else:
+            z = _LayerNorm(model.layer_norm_eps, name="final_norm")(h)
         z = jnp.swapaxes(z, 0, 1)                         # [T, B, hidden]
-        policy_logits = _Linear(model.vocab_size, dtype,
-                                name="policy_logits")(z)
+        if model.model_type == "afmoe":
+            policy_logits = _Linear(model.vocab_size, dtype,
+                                    name="policy_logits")(z)
+        else:
+            with jax.named_scope("policy_logits"):
+                policy_logits = tied_logits(z, table, dtype)
         baseline = _Baseline(dtype, name="baseline")(z)
         new_state = TokenCache(
             keys=tuple(keys), values=tuple(values),
@@ -568,5 +986,6 @@ class TokenPolicy(nn.Module):
             full_index=attention_lib.index_write(
                 state.full_index, written, count),
             written=written + count,
-            episode_start=start[:, -1])
+            episode_start=start[:, -1],
+            ssm_state=tuple(ssm_state), conv_tail=tuple(conv_tail))
         return (policy_logits, baseline), new_state

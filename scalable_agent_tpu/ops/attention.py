@@ -63,15 +63,23 @@ _MASKED = -1e30
 
 
 def _attend(query, key, value, ring_keys, ring_values, ring_index, index,
-            episode_start, window):
+            episode_start, window, streams=1):
     """One block of envs.  query [b, T, kv, g, D]; key/value [b, T, kv,
     D]; ring_* [b, S, kv, D]; ring_index [S]; index [T];
-    episode_start [b, T]."""
+    episode_start [b, T].  ``streams`` > 1 (differential attention): a
+    head's D holds that many queries (keys) side by side, each scored
+    and normalized alone, ``z`` below; all weigh the one value, and the
+    result's D holds their sums side by side."""
     dtype = query.dtype
-    scale = 1.0 / math.sqrt(query.shape[-1])
+    scale = 1.0 / math.sqrt(query.shape[-1] // streams)
+    z = "z" if streams > 1 else ""
+    if z:
+        query = query.reshape(query.shape[:-1] + (streams, -1))
 
     def scores(keys):
-        return jnp.einsum("btkgd,bskd->bkgts", query, keys,
+        if z:
+            keys = keys.reshape(keys.shape[:-1] + (streams, -1))
+        return jnp.einsum(f"btkg{z}d,bsk{z}d->bkg{z}ts", query, keys,
                           preferred_element_type=jnp.float32) * scale
 
     def seen(key_index):                    # [S'] -> bool [b, T, S']
@@ -85,13 +93,16 @@ def _attend(query, key, value, ring_keys, ring_values, ring_index, index,
     slots = ring_keys.shape[1]
     logits = jnp.concatenate([scores(ring_keys), scores(key)], axis=-1)
     mask = jnp.concatenate([seen(ring_index), seen(index)], axis=-1)
-    logits = jnp.where(mask[:, None, None], logits, _MASKED)
+    logits = jnp.where(mask[(slice(None),) + (None,) * (logits.ndim - 3)],
+                       logits, _MASKED)
     # every query sees itself, so no row is all masked
     weights = round_to(jax.nn.softmax(logits, axis=-1), dtype)
-    return (jnp.einsum("bkgts,bskd->btkgd", weights[..., :slots],
-                       ring_values, preferred_element_type=jnp.float32)
-            + jnp.einsum("bkgts,bskd->btkgd", weights[..., slots:], value,
-                         preferred_element_type=jnp.float32))
+    weighted = f"bkg{z}ts,bskd->btkg{z}d"
+    out = (jnp.einsum(weighted, weights[..., :slots], ring_values,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum(weighted, weights[..., slots:], value,
+                        preferred_element_type=jnp.float32))
+    return out.reshape(out.shape[:4] + (-1,))
 
 
 # -- learning: blockwise, scores in VMEM only ---------------------------------
@@ -155,9 +166,30 @@ def _flat(env, step, blocks):
     return env * blocks + jnp.minimum(step, blocks - 1)
 
 
+def _stream(x, z, streams):
+    """[streams * d, R] with every stream's rows but ``z``'s zeroed: a
+    product over all the rows is then stream ``z``'s alone (and as wide
+    as the MXU either way)."""
+    if streams == 1:
+        return x
+    rows = x.shape[0] // streams
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where((row >= z * rows) & (row < (z + 1) * rows), x,
+                     jnp.zeros_like(x))
+
+
+def _rows(ref, z, streams):
+    """Stream ``z``'s rows of a ref that holds the streams' one below the
+    other (all of it where there is one)."""
+    if streams == 1:
+        return (Ellipsis,)
+    rows = ref.shape[0] // streams
+    return (slice(z * rows, (z + 1) * rows),)
+
+
 def _forward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
                     rv_ref, ri_ref, ok_ref, ov_ref, oi_ref, out_ref, lse_ref,
-                    m_ref, l_ref, acc_ref, *, scale, blocks):
+                    m_ref, l_ref, acc_ref, *, scale, blocks, streams):
     del fetch_ref                       # the index maps read it
     env, step = pl.program_id(0), pl.program_id(2)
 
@@ -171,18 +203,24 @@ def _forward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
         # A query that has seen no key yet keeps m = _MASKED and gathers
         # weights of exp(0); the first real score's alpha = exp(-1e30)
         # wipes them, and every query sees itself among the own keys,
-        # which come last.
-        s = _scores(q_ref[...], k_ref[...], index_ref[...], low_ref[...],
-                    high_ref[...], scale)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
-        v = v_ref[...]
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # which come last.  Each stream scores the block that one fetch
+        # brought and keeps a maximum, a sum and a weighted value of its
+        # own.
+        for z in range(streams):
+            one, wide = _rows(m_ref, z, streams), _rows(acc_ref, z, streams)
+            s = _scores(_stream(q_ref[...], z, streams), k_ref[...],
+                        index_ref[...], low_ref[...], high_ref[...], scale)
+            m_old = m_ref[one]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[one] = alpha * l_ref[one] + jnp.sum(p, axis=0,
+                                                      keepdims=True)
+            v = v_ref[...]
+            acc_ref[wide] = alpha * acc_ref[wide] + jax.lax.dot_general(
+                v, p.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)
+            m_ref[one] = m_new
 
     @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
     def _():
@@ -191,37 +229,46 @@ def _forward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
     @pl.when(step == blocks)
     def _():
         block(ok_ref, ov_ref, oi_ref)
-        out_ref[...] = acc_ref[...] / l_ref[...]
-        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+        for z in range(streams):
+            one, wide = _rows(m_ref, z, streams), _rows(acc_ref, z, streams)
+            out_ref[wide] = acc_ref[wide] / l_ref[one]
+            lse_ref[one] = m_ref[one] + jnp.log(l_ref[one])
 
 
 def _backward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
                      rv_ref, ri_ref, ok_ref, ov_ref, oi_ref, out_ref,
                      lse_ref, do_ref, dq_ref, dk_ref, dv_ref, delta_ref,
-                     dob_ref, acc_ref, *, scale, blocks):
+                     dob_ref, acc_ref, *, scale, blocks, streams):
     del fetch_ref
     env, step = pl.program_id(0), pl.program_id(2)
 
     @pl.when(step == 0)
     def _():
         do = do_ref[...]
-        delta_ref[...] = jnp.sum(out_ref[...] * do, axis=0, keepdims=True)
+        weighed = out_ref[...] * do
+        for z in range(streams):
+            delta_ref[_rows(delta_ref, z, streams)] = jnp.sum(
+                weighed[_rows(do_ref, z, streams)], axis=0, keepdims=True)
         dob_ref[...] = do.astype(dob_ref.dtype)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def block(k_ref, v_ref, index_ref):
-        """(the weights, d scores) of one key block, both in the compute
-        dtype, after adding its part of d query."""
+        """A stream apiece (the weights, d scores) of one key block,
+        both in the compute dtype, after adding its part of d query."""
         k = k_ref[...]
-        s = _scores(q_ref[...], k, index_ref[...], low_ref[...],
-                    high_ref[...], scale)
-        p = jnp.exp(s - lse_ref[...])
-        dp = jax.lax.dot_general(v_ref[...], dob_ref[...], _NN,
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[...]) * scale).astype(k.dtype)
-        acc_ref[...] += jax.lax.dot_general(
-            k, ds, _TN, preferred_element_type=jnp.float32)
-        return p.astype(k.dtype), ds
+        streamed = []
+        for z in range(streams):
+            one, wide = _rows(lse_ref, z, streams), _rows(dob_ref, z, streams)
+            s = _scores(_stream(q_ref[...], z, streams), k, index_ref[...],
+                        low_ref[...], high_ref[...], scale)
+            p = jnp.exp(s - lse_ref[one])
+            dp = jax.lax.dot_general(v_ref[...], dob_ref[wide], _NN,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[one]) * scale).astype(k.dtype)
+            acc_ref[...] += _stream(jax.lax.dot_general(
+                k, ds, _TN, preferred_element_type=jnp.float32), z, streams)
+            streamed.append((p.astype(k.dtype), ds))
+        return streamed
 
     @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
     def _():
@@ -229,24 +276,36 @@ def _backward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref, rk_ref,
 
     @pl.when(step == blocks)
     def _():
-        p, ds = block(ok_ref, ov_ref, oi_ref)
-        dk_ref[...] = jax.lax.dot_general(
-            ds, q_ref[...], _NT, preferred_element_type=jnp.float32)
-        dv_ref[...] = jax.lax.dot_general(
-            p, dob_ref[...], _NT, preferred_element_type=jnp.float32)
+        streamed = block(ok_ref, ov_ref, oi_ref)
+
+        def over_streams(term):
+            total = term(0, *streamed[0])
+            for z in range(1, streams):
+                total = total + term(z, *streamed[z])
+            return total
+
+        dk_ref[...] = over_streams(lambda z, p, ds: jax.lax.dot_general(
+            ds, _stream(q_ref[...], z, streams), _NT,
+            preferred_element_type=jnp.float32))
+        dv_ref[...] = over_streams(lambda z, p, ds: jax.lax.dot_general(
+            p, dob_ref[_rows(dob_ref, z, streams)], _NT,
+            preferred_element_type=jnp.float32))
         dq_ref[...] = acc_ref[...]
 
 
 _VMEM_LIMIT = 96 * 2 ** 20
 
 
-@functools.partial(jax.jit, static_argnames=("backward", "interpret"))
-def _kernel(operands, residuals=(), *, backward=False, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("backward", "interpret", "streams"))
+def _kernel(operands, residuals=(), *, backward=False, interpret, streams=1):
     """One of the two kernels over ``_operands`` (the backward one also
     over ``residuals``: out, log-sum-exp, d out, queries along lanes),
     grid (env, kv head, key step): the ring's blocks, then the own keys.
     Jitted for the eager caller's sake, who would otherwise compile the
-    interpreter's program at every call."""
+    interpreter's program at every call.  ``streams``: the queries
+    (keys) side by side in a head's ``dim``; out, log-sum-exp and d out
+    then hold a stream's rows below the other's."""
     q, ring, own_keys = operands[2], operands[5], operands[8]
     batch, kv, dim, rows = q.shape
     own = own_keys.shape[2]
@@ -277,21 +336,23 @@ def _kernel(operands, residuals=(), *, backward=False, interpret):
 
     if backward:
         kernel = _backward_kernel
-        in_specs += [fixed(dim, rows), fixed(1, rows), fixed(dim, rows)]
+        in_specs += [fixed(streams * dim, rows), fixed(streams, rows),
+                     fixed(streams * dim, rows)]
         out_specs = [fixed(dim, rows), fixed(own, dim), fixed(own, dim)]
         out_shape = [result(dim, rows), result(own, dim), result(own, dim)]
-        scratch = [pltpu.VMEM((1, rows), jnp.float32),
-                   pltpu.VMEM((dim, rows), q.dtype),
+        scratch = [pltpu.VMEM((streams, rows), jnp.float32),
+                   pltpu.VMEM((streams * dim, rows), q.dtype),
                    pltpu.VMEM((dim, rows), jnp.float32)]
     else:
         kernel = _forward_kernel
-        out_specs = [fixed(dim, rows), fixed(1, rows)]
-        out_shape = [result(dim, rows), result(1, rows)]
-        scratch = [pltpu.VMEM((1, rows), jnp.float32),
-                   pltpu.VMEM((1, rows), jnp.float32),
-                   pltpu.VMEM((dim, rows), jnp.float32)]
+        out_specs = [fixed(streams * dim, rows), fixed(streams, rows)]
+        out_shape = [result(streams * dim, rows), result(streams, rows)]
+        scratch = [pltpu.VMEM((streams, rows), jnp.float32),
+                   pltpu.VMEM((streams, rows), jnp.float32),
+                   pltpu.VMEM((streams * dim, rows), jnp.float32)]
     return pl.pallas_call(
-        functools.partial(kernel, scale=1.0 / math.sqrt(dim), blocks=blocks),
+        functools.partial(kernel, scale=1.0 / math.sqrt(dim // streams),
+                          blocks=blocks, streams=streams),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(batch, kv, blocks + 1),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
@@ -361,34 +422,34 @@ def _from_lanes(x, queries, group):
     return jnp.transpose(x, (0, 4, 1, 3, 2))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
 def _blockwise(query, key, value, ring_keys, ring_values, ring_index, index,
-               episode_start, visit, window, interpret):
+               episode_start, visit, window, interpret, streams):
     """``_attend``'s result for any number of queries, with no score
     outside VMEM.  Its arguments (query [B, T, kv, g, D]) and ``visit``,
     ``visited_blocks`` of them."""
     return _blockwise_fwd(query, key, value, ring_keys, ring_values,
                           ring_index, index, episode_start, visit, window,
-                          interpret)[0]
+                          interpret, streams)[0]
 
 
 def _blockwise_fwd(query, key, value, ring_keys, ring_values, ring_index,
-                   index, episode_start, visit, window, interpret):
+                   index, episode_start, visit, window, interpret, streams):
     operands = _operands(query, key, value, ring_keys, ring_values,
                          ring_index, index, episode_start, visit, window)
-    out, lse = _kernel(operands, interpret=interpret)
+    out, lse = _kernel(operands, interpret=interpret, streams=streams)
     return (_from_lanes(out, query.shape[1], query.shape[3]),
             (operands, out, lse))
 
 
-def _blockwise_bwd(window, interpret, saved, d_out):
+def _blockwise_bwd(window, interpret, streams, saved, d_out):
     del window
     operands, out, lse = saved
     queries, group = d_out.shape[1], d_out.shape[3]
     dtype = operands[2].dtype
     dq, dk, dv = _kernel(
         operands, (out, lse, _to_lanes(d_out.astype(jnp.float32))),
-        backward=True, interpret=interpret)
+        backward=True, interpret=interpret, streams=streams)
 
     def own_keys_back(x):                # [B, kv, K, D] -> [B, T, kv, D]
         return jnp.transpose(x[:, :, :queries], (0, 2, 1, 3)).astype(dtype)
@@ -402,7 +463,8 @@ _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
 def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
-                     index, episode_start, window: Optional[int] = None):
+                     index, episode_start, window: Optional[int] = None,
+                     streams: int = 1):
     """``query`` [B, T, heads, D] and this call's own ``key`` / ``value``
     [B, T, kv, D] against themselves and the cache ``ring_keys`` /
     ``ring_values`` [B, S, kv, D] -> ([B, T, heads * D] in float32,
@@ -415,6 +477,15 @@ def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
     ``episode_start`` [B, T]: where each query's episode began;
     ``window``: None on a full layer.
 
+    ``key`` / ``value`` and the ring may be another layer's (a layer
+    that projects queries only and reads a cache it does not write): a
+    ring has as many readers as calls name it, and its owner's own keys
+    get a cotangent from each.  ``streams`` > 1 is differential
+    attention's two softmaxes over one value: a head's ``D`` holds the
+    streams' queries (keys) side by side, and the result
+    ``heads * streams * D`` wide each stream's weighted value, for the
+    caller to combine.
+
     One query an env is ``_attend``; more go blockwise through the
     kernel, and say which share of (env, key block) pairs it visited
     (the ring's blocks some query of the env sees, and the own keys)."""
@@ -423,17 +494,18 @@ def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
     query = query.reshape(batch, queries, kv, heads // kv, dim)
     if queries == 1:
         out = _attend(query, key, value, ring_keys, ring_values, ring_index,
-                      index, episode_start, window)
-        return out.reshape(batch, queries, heads * dim), {}
+                      index, episode_start, window, streams)
+        return out.reshape(batch, queries, heads * streams * dim), {}
     from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
     visit = visited_blocks(ring_index, index, episode_start, window,
                            _key_block(ring_keys.shape[1]))
     out = _blockwise(query, key, value, jax.lax.stop_gradient(ring_keys),
                      jax.lax.stop_gradient(ring_values), ring_index, index,
-                     episode_start, visit, window, pallas_interpret())
+                     episode_start, visit, window, pallas_interpret(),
+                     streams)
     share = (jnp.sum(visit) + batch) / (batch * (visit.shape[1] + 1))
-    return (out.reshape(batch, queries, heads * dim),
+    return (out.reshape(batch, queries, heads * streams * dim),
             {"key_blocks_visited_share": share.astype(jnp.float32)})
 
 

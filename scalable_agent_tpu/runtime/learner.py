@@ -601,12 +601,14 @@ class Learner:
         # the initializers and drops the forward pass.
         init = (jax.jit(agent.init) if agent.init_in_one_program
                 else agent.init)
-        params = init(
-            rng,
-            example.agent_outputs.action,
-            example.env_outputs,
-            example.agent_state,
-        )
+        actions, env_outputs = (example.agent_outputs.action,
+                                example.env_outputs)
+        if agent.init_steps:
+            # an agent whose parameters do not depend on the unroll's
+            # length is initialized through its first steps alone
+            actions, env_outputs = jax.tree_util.tree_map(
+                lambda x: x[:agent.init_steps], (actions, env_outputs))
+        params = init(rng, actions, env_outputs, example.agent_state)
         if agent.stats_collection:
             # what the forward pass sows of itself is no parameter
             params = {name: tree for name, tree in params.items()

@@ -566,6 +566,81 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= f32\[([\d,]+)\]", text))
         assert largest < scores, (largest, scores)
 
+    @pytest.mark.parametrize("slots,window", [(768, 512), (6400, None)],
+                             ids=["window", "full_or_cross"])
+    def test_differential_update_attention_compiles(
+            self, monkeypatch, v5e_topology, slots, window):
+        """ISSUE 34: the same kernels with two score streams a pair of
+        heads (``streams=2``) at ``phi4flash.ingraph``'s widths — 257
+        queries of 20 query pairs over 10 key pairs of 128 (two heads of
+        64 side by side), a ring of 768 / 6,400 slots — compiled alone
+        for a v5e, forward and backward: two Mosaic calls, and no
+        float32 result as large as one env's scores."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import attention
+
+        _as_tpu(monkeypatch)
+        envs, queries, pairs, kv, dim = 2, 257, 20, 10, 128
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(query, key, value, *cache):
+            out, _ = attention.cached_attention(
+                query, key, value, *cache, window=window, streams=2)
+            return jnp.sum(out)
+
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            operand((envs, queries, pairs, dim)),
+            operand((envs, queries, kv, dim)),
+            operand((envs, queries, kv, dim)),
+            operand((envs, slots, kv, dim)), operand((envs, slots, kv, dim)),
+            operand((slots,), jnp.int32), operand((queries,), jnp.int32),
+            operand((envs, queries), jnp.int32)).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        scores = queries * 2 * pairs * slots
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest < scores, (largest, scores)
+
+    def test_selective_scan_compiles_with_no_state_a_token_in_hbm(
+            self, monkeypatch, v5e_topology):
+        """ISSUE 34: the selective-scan kernels (ops/ssm.py, T > 1) at
+        ``phi4flash.ingraph``'s widths — 257 tokens, 5,120 channels, 16
+        states — compiled alone for a v5e, forward and backward: two
+        Mosaic calls, and no float32 result as large as a state a token
+        (the forward keeps a state a chunk of 64 tokens)."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import ssm
+
+        _as_tpu(monkeypatch)
+        envs, tokens, width, states = 2, 257, 5120, 16
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(x, delta, a, dp, b, c, state, reset):
+            y, last = ssm.selective_scan(x, delta, a, dp, b, c, reset, state)
+            return jnp.sum(y) + jnp.sum(last)
+
+        text = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+            operand((envs, tokens, width)), operand((envs, tokens, width)),
+            operand((states, width)), operand((width,)),
+            operand((envs, tokens, states)), operand((envs, tokens, states)),
+            operand((envs, states, width)),
+            operand((envs, tokens), jnp.bool_)).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        a_state_a_token = envs * tokens * states * width
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
+
     @pytest.mark.parametrize("devices,overrides,merged", [
         (1, {}, 101 * 256),
         (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128),
