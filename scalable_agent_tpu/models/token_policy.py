@@ -695,11 +695,13 @@ _TELEMETRY = {
         ("embedding", "attention", "experts", "mlp", "norms", "heads"),
         ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
          "moe/expert_load_max_over_mean",
-         "attention/key_blocks_visited_share")),
+         "attention/key_blocks_visited_share",
+         "attention/decode_key_blocks_visited_share")),
     # the tied table is the head too, and is counted as the embedding
     "phi4flash": (
         ("embedding", "attention", "ssm", "gmu", "mlp", "norms", "heads"),
-        ("attention/key_blocks_visited_share",)),
+        ("attention/key_blocks_visited_share",
+         "attention/decode_key_blocks_visited_share")),
 }
 
 
@@ -959,7 +961,8 @@ class TokenPolicy(nn.Module):
                 ssm_state[scan], conv_tail[scan] = scanned, tail
             stats.append(layer_stats)
         # each number's mean over the layers that say it (acting says
-        # none of the attention's: one query an env visits every slot)
+        # none of the attention's: the update's pass counts the blocks
+        # the unroll's decode steps visited, by the decode's own rule)
         for name in self.STATS:
             said = [s[name] for s in stats if name in s]
             if said:

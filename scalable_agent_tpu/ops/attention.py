@@ -9,27 +9,36 @@ query sees a key when the key is not later than it, belongs to its
 episode, and (window layers) is fewer than ``window`` tokens back.  The
 cache is a ring, so its slots are in no order; each slot's stream index
 rides beside it (``ring_index``, ``NO_KEY`` where a slot holds nothing
-a query may see).  Every shape is fixed: how full the cache is changes
-a mask and no trip count.
+a query may see).  Every array's shape is fixed: how full the cache is
+changes a mask and, in the decode, how many blocks a kernel walks.
 
-Two paths, chosen by the one shape that tells them apart.  One query an
-env (acting) scores every slot of the ring in XLA (``_attend``): a
-matrix-vector product at its bytes.  More than one (learning) would
-write ``[B, heads, T, S + T]`` scores in float32 (2.7 GB a window layer
-at 32 envs x 257 queries x 2,561 keys, 4.9 GB on the full layer) and
-read them back several times, forward, rematerialized forward and
+Two kernels, chosen by the one shape that tells them apart; no score of
+either leaves VMEM.  One query an env (acting) goes through ``_decode``:
+a step needs the keys and values of the ring's LIVE slots once and
+nothing else, so the kernel walks, for each env, the blocks that hold a
+key its query may see (``decode_visits``: an episode half over sees
+half of a full ring) and brings every key/value head of a block in one
+fetch; the scores lie queries down the sublanes (``group * streams``
+rows a key/value head), keys along the lanes.  More than one (learning)
+would write ``[B, heads, T, S + T]`` scores in float32 (2.7 GB a window
+layer at 32 envs x 257 queries x 2,561 keys, 4.9 GB on the full layer)
+and read them back several times, forward, rematerialized forward and
 backward; there ``_blockwise`` walks the ring and then the call's own
 keys a block at a time in one Pallas kernel with a running maximum and
-sum, so a score lives in VMEM only.  Its backward kernel recomputes a
-block's scores from the saved log-sum-exp and gives the query's
-gradient and the call's OWN keys' and values'; the ring is the agent's
-state and gets no cotangent.  A key block none of an env's queries can
-see is neither fetched nor scored (``attention/key_blocks_visited_share``
-counts the rest).  Grouped queries: the ``heads // kv_heads`` query
-heads that share a key/value head are one matmul's columns.  Inside the
-kernels a block's scores lie keys down, queries across (``[K, R]``): the
-maximum and the sum over keys are then elementwise over vregs, and what
-a query carries (its bounds, maximum, sum) is a lane vector.
+sum.  Its backward kernel recomputes a block's scores from the saved
+log-sum-exp and gives the query's gradient and the call's OWN keys' and
+values'; the ring is the agent's state and gets no cotangent.  A key
+block none of an env's queries can see is neither fetched nor scored
+(``attention/key_blocks_visited_share`` counts the rest, and
+``attention/decode_key_blocks_visited_share`` what the unroll's decode
+steps visited).  Grouped queries: the ``heads // kv_heads`` query heads
+that share a key/value head are one matmul's columns.  Inside the
+update's kernels a block's scores lie keys down, queries across
+(``[K, R]``): the maximum and the sum over keys are then elementwise
+over vregs, and what a query carries (its bounds, maximum, sum) is a
+lane vector.  ``_attend`` scores every slot in XLA: the plain form both
+kernels are held to (tests/test_attention_kernel.py), which nothing
+else calls.
 """
 
 import functools
@@ -135,14 +144,37 @@ def _bounds(index, episode_start, window):
     return low, index
 
 
+def _seen_blocks(ring_index, low, high, block: int):
+    """bool [..., S // block]: does the query of bounds ``low`` / ``high``
+    [...] see any key of the ring's block, the slots holding the stream
+    indices ``ring_index`` [..., S]?  The mask's own rule, reduced."""
+    seen = ((ring_index >= low[..., None]) & (ring_index <= high[..., None]))
+    return seen.reshape(seen.shape[:-1] + (-1, block)).any(axis=-1)
+
+
 def visited_blocks(ring_index, index, episode_start, window, block: int):
     """bool [B, S // block]: does any query of the env see any key of
-    the ring's block?  The mask's own rule, reduced."""
+    the ring's block?"""
     low, high = _bounds(index, episode_start, window)
-    seen = ((ring_index[None, None, :] >= low[:, :, None])
-            & (ring_index[None, None, :] <= high[None, :, None]))
-    return seen.reshape(seen.shape[0], seen.shape[1], -1, block).any(
-        axis=(1, 3))
+    return _seen_blocks(ring_index[None, None, :], low, high[None, :],
+                        block).any(axis=1)
+
+
+def decode_visits(ring_index, index, episode_start, window, block: int):
+    """bool [B, T, S // block]: the ring blocks the decode kernel visits
+    for the query of stream index ``index[t]``, had the ring stood as
+    acting finds it at that token: ``ring_index`` and this call's own
+    tokens before ``t`` in their slots.  One query (acting) has none
+    before it and this is the kernel's block list; an unroll's (learning)
+    is what its decode steps visited, by the same rule."""
+    slots = ring_index.shape[0]
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    # the one token from index[0] on that lands in each slot
+    own = index[0] + (slot - index[0]) % slots
+    at = jnp.where(own[None, :] < index[:, None], own[None, :],
+                   ring_index[None, :])                     # [T, S]
+    low, high = _bounds(index, episode_start, window)
+    return _seen_blocks(at[None], low, high[None, :], block)
 
 
 _NN = (((1,), (0,)), ((), ()))          # [K, D] x [D, R]
@@ -391,19 +423,21 @@ def _operands(query, key, value, ring_keys, ring_values, ring_index, index,
         return jnp.pad(x, ((0, 0), (0, rows - group * queries)),
                        mode="edge")[:, None, :]
 
-    def head_major(x):                   # [B, n, kv, D] -> [B, kv, n, D]
-        return jnp.transpose(x, (0, 2, 1, 3))
-
     def keys(x):                         # [B, T, kv, D] -> [B, kv, K, D]
-        return jnp.pad(head_major(x),
+        return jnp.pad(_head_major(x),
                        ((0, 0), (0, 0), (0, own - queries), (0, 0)))
 
     own_index = jnp.pad(index, (0, own - queries),
                         constant_values=_FAR)[:, None]
     return (visit.astype(jnp.int32).reshape(-1), fetch.reshape(-1),
             _to_lanes(query), per_query(low), per_query(high),
-            head_major(ring_keys), head_major(ring_values),
+            _head_major(ring_keys), _head_major(ring_values),
             ring_index[:, None], keys(key), keys(value), own_index)
+
+
+def _head_major(x):
+    """[B, n, kv, D] -> [B, kv, n, D]."""
+    return jnp.transpose(x, (0, 2, 1, 3))
 
 
 def _to_lanes(x):
@@ -462,6 +496,158 @@ def _blockwise_bwd(window, interpret, streams, saved, d_out):
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
+# -- acting: one query an env, the ring's live blocks only --------------------
+
+_DECODE_BLOCK_BYTES = 2 ** 20   # the keys a decode grid step brings, every
+                                # key/value head of a block of slots: a step
+                                # costs ~0.35 us whatever it moves
+
+
+def _decode_block(slots: int, slot_bytes: int) -> int:
+    """Ring slots a decode grid step: the most whole lane tiles that
+    divide the ring and hold ``_DECODE_BLOCK_BYTES`` of keys or fewer (a
+    ring none divides, a test's, is one block)."""
+    fit = [block for block in range(_LANES, slots + 1, _LANES)
+           if slots % block == 0
+           and block * slot_bytes <= _DECODE_BLOCK_BYTES]
+    return max(fit, default=slots)
+
+
+# a key/value head at a time, h: [h, R, D] x [h, K, D] -> [h, R, K], and
+# [h, R, K] x [h, K, D] -> [h, R, D]
+_ROWS_KEYS = (((2,), (2,)), ((0,), (0,)))
+_ROWS_VALUES = (((2,), (1,)), ((0,), (0,)))
+
+
+def _decode_kernel(order_ref, visit_ref, low_ref, high_ref, steps_ref, q_ref,
+                   ok_ref, ov_ref, rk_ref, rv_ref, ri_ref, out_ref, m_ref,
+                   l_ref, acc_ref, *, scale, blocks):
+    """Grid step ``i`` is the (env, ring block) pair ``order[i]``, an
+    env's pairs side by side.  Queries down the sublanes, keys along the
+    lanes: q [kv, R, D], a block's keys and values [kv, K, D], its scores
+    [kv, R, K].  Every query sees itself, so the own key is where an
+    env's running maximum, sum and weighted value start."""
+    i = pl.program_id(0)
+    pair = order_ref[i]
+    env = pair // blocks
+    first = (i == 0) | (order_ref[jnp.maximum(i - 1, 0)] // blocks != env)
+    last = ((i == steps_ref[0] - 1)
+            | (order_ref[jnp.minimum(i + 1, steps_ref[0] - 1)] // blocks
+               != env))
+
+    @pl.when(first)
+    def _():
+        q = q_ref[...].astype(jnp.float32)
+        m_ref[...] = jnp.sum(q * ok_ref[...][:, None, :], axis=-1,
+                             keepdims=True) * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(ov_ref[...][:, None, :],
+                                        acc_ref.shape)
+
+    @pl.when(visit_ref[pair] == 1)
+    def _():
+        v = rv_ref[...]
+        s = jax.lax.dot_general(q_ref[...], rk_ref[...], _ROWS_KEYS,
+                                preferred_element_type=jnp.float32) * scale
+        key_index = ri_ref[...]                              # [1, K]
+        seen = (key_index >= low_ref[env]) & (key_index <= high_ref[0])
+        s = jnp.where(seen[None], s, _MASKED)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)          # 0 where masked: m is a real score
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, _ROWS_VALUES,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(last)
+    def _():
+        out_ref[...] = acc_ref[...] / l_ref[...]
+
+
+def _decode_order(visit):
+    """(order, steps) of ``visit`` [B, blocks]: the flat (env, block)
+    pairs the kernel's grid walks, the visited ones in their order and
+    block 0 (to skip) of an env that sees none, so that every env has a
+    step to start and end in; ``steps`` [1] of them, the rest of
+    ``order`` [B * blocks] unused.  A step costs its ~0.35 us whether it
+    fetches or skips, so the pairs to skip are not in the grid at all."""
+    blocks = visit.shape[1]
+    none = ~jnp.any(visit, axis=1, keepdims=True)
+    step = visit | (none & (jnp.arange(blocks) == 0)[None, :])
+    ends = jnp.cumsum(step.reshape(-1).astype(jnp.int32))
+    # step i is the first pair with i + 1 steps up to and with it
+    at = jnp.arange(ends.shape[0], dtype=jnp.int32)
+    order = jnp.sum((ends[None, :] <= at[:, None]).astype(jnp.int32), axis=1)
+    # the unused entries name a pair that exists: an index map may run
+    # one step ahead of the grid
+    return jnp.minimum(order, ends.shape[0] - 1), ends[-1:]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("window", "interpret", "streams"))
+def _decode(query, key, value, ring_keys, ring_values, ring_index, index,
+            episode_start, *, window, interpret, streams=1):
+    """``_attend``'s result for one query an env (query [B, 1, kv, g, D]),
+    reading only the ring blocks ``decode_visits`` names.  A head's rows
+    are its ``g * streams`` queries: stream ``z``'s row keeps its own part
+    of ``D`` and zeros elsewhere, so one product over a block gives every
+    stream's scores and each row its own softmax.  The rings go to the
+    kernel head-major, the order the compiled step keeps them in (the
+    update's kernels read them so too): a bitcast there, and the token's
+    slot is written after, in place."""
+    batch, _, kv, group, dim = query.shape
+    slots = ring_keys.shape[1]
+    dtype = ring_keys.dtype
+    block = _decode_block(slots, kv * dim * dtype.itemsize)
+    blocks = slots // block
+    visit = decode_visits(ring_index, index, episode_start, window,
+                          block)[:, 0]
+    order, steps = _decode_order(visit)
+    low, high = _bounds(index, episode_start, window)
+
+    real = group * streams
+    rows = _round_up(real, 32 // dtype.itemsize)    # whole sublane tiles
+    lane = jnp.arange(dim, dtype=jnp.int32) // (dim // streams)
+    q = jnp.where(lane[None, :] == jnp.arange(streams)[:, None],
+                  query[:, 0, :, :, None, :], jnp.zeros((), query.dtype))
+    q = jnp.pad(q.reshape(batch, kv, real, dim),
+                ((0, 0), (0, 0), (0, rows - real), (0, 0)))
+
+    def per_env(*shape):
+        return pl.BlockSpec(
+            (None,) + shape,
+            lambda i, order, *_: (order[i] // blocks,) + (0,) * len(shape))
+
+    ring_kv = pl.BlockSpec(
+        (None, kv, block, dim),
+        lambda i, order, *_: (order[i] // blocks, 0, order[i] % blocks, 0))
+    block_index = pl.BlockSpec(
+        (1, block), lambda i, order, *_: (0, order[i] % blocks))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, blocks=blocks,
+                          scale=1.0 / math.sqrt(dim // streams)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(steps[0],),
+            in_specs=[per_env(kv, rows, dim), per_env(kv, dim),
+                      per_env(kv, dim), ring_kv, ring_kv, block_index],
+            out_specs=per_env(kv, rows, dim),
+            scratch_shapes=[pltpu.VMEM((kv, rows, 1), jnp.float32),
+                            pltpu.VMEM((kv, rows, 1), jnp.float32),
+                            pltpu.VMEM((kv, rows, dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((batch, kv, rows, dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret)(
+            order, visit.astype(jnp.int32).reshape(-1), low[:, 0], high,
+            steps, q, key[:, 0].astype(jnp.float32),
+            value[:, 0].astype(jnp.float32), _head_major(ring_keys),
+            _head_major(ring_values), ring_index[None, :])
+    return out[:, :, :real].reshape(batch, 1, kv, group, streams * dim)
+
+
 def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
                      index, episode_start, window: Optional[int] = None,
                      streams: int = 1):
@@ -486,27 +672,40 @@ def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
     ``heads * streams * D`` wide each stream's weighted value, for the
     caller to combine.
 
-    One query an env is ``_attend``; more go blockwise through the
-    kernel, and say which share of (env, key block) pairs it visited
-    (the ring's blocks some query of the env sees, and the own keys)."""
-    batch, queries, heads, dim = query.shape
-    kv = key.shape[2]
-    query = query.reshape(batch, queries, kv, heads // kv, dim)
-    if queries == 1:
-        out = _attend(query, key, value, ring_keys, ring_values, ring_index,
-                      index, episode_start, window, streams)
-        return out.reshape(batch, queries, heads * streams * dim), {}
+    One query an env goes through the decode kernel, more go blockwise
+    through the update's, and say which share of (env, key block) pairs
+    it visited (the ring's blocks some query of the env sees, and the
+    own keys) and which share of (env, query, key block) triples the
+    decode kernel visits, a decode step a query (the own key a block
+    more, and the blocks the decode's own)."""
     from scalable_agent_tpu.parallel.mesh import pallas_interpret
 
+    batch, queries, heads, dim = query.shape
+    kv = key.shape[2]
+    slots = ring_keys.shape[1]
+    query = query.reshape(batch, queries, kv, heads // kv, dim)
+    if queries == 1:
+        out = _decode(query, key, value, ring_keys, ring_values, ring_index,
+                      index, episode_start, window=window,
+                      interpret=pallas_interpret(), streams=streams)
+        return out.reshape(batch, queries, heads * streams * dim), {}
     visit = visited_blocks(ring_index, index, episode_start, window,
-                           _key_block(ring_keys.shape[1]))
+                           _key_block(slots))
     out = _blockwise(query, key, value, jax.lax.stop_gradient(ring_keys),
                      jax.lax.stop_gradient(ring_values), ring_index, index,
                      episode_start, visit, window, pallas_interpret(),
                      streams)
-    share = (jnp.sum(visit) + batch) / (batch * (visit.shape[1] + 1))
+    decode = decode_visits(
+        ring_index, index, episode_start, window,
+        _decode_block(slots, kv * dim * ring_keys.dtype.itemsize))
+
+    def share(visit):       # of the ring's blocks and one more, always seen
+        seen = jnp.mean(visit.astype(jnp.float32))
+        return (seen * visit.shape[-1] + 1.0) / (visit.shape[-1] + 1)
+
     return (out.reshape(batch, queries, heads * streams * dim),
-            {"key_blocks_visited_share": share.astype(jnp.float32)})
+            {"key_blocks_visited_share": share(visit),
+             "decode_key_blocks_visited_share": share(decode)})
 
 
 def ring_write(ring, new, written):

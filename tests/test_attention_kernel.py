@@ -1,7 +1,8 @@
-"""The blockwise attention kernel (ops/attention.py, T > 1) against the
-score-everything form it replaced (``_attend``, still the T = 1 path):
-tiny shapes, the Pallas interpreter, float32 operands, so the two agree
-to float32's rounding."""
+"""The attention kernels (ops/attention.py: the blockwise one of T > 1
+and the decode's, one query an env) against the score-everything form
+they replaced (``_attend``, which the program no longer calls): tiny
+shapes, the Pallas interpreter, float32 operands, so the two agree to
+float32's rounding."""
 
 import functools
 
@@ -44,11 +45,11 @@ def case(queries=5, slots=12, window=4, written=20, done_at=None,
             jnp.asarray(start)), window
 
 
-def reference(query, key, value, *rest, window):
+def reference(query, key, value, *rest, window, streams=1):
     batch, queries, heads, dim = query.shape
     grouped = query.reshape(batch, queries, KV, heads // KV, dim)
-    return A._attend(grouped, key, value, *rest, window).reshape(
-        batch, queries, heads * dim)
+    return A._attend(grouped, key, value, *rest, window, streams).reshape(
+        batch, queries, heads * streams * dim)
 
 
 def mask(ring_index, index, episode_start, window):
@@ -162,21 +163,144 @@ def test_the_skipped_blocks_are_those_no_query_sees(written, want):
         (visit.sum() + batch) / (batch * 4))
 
 
-def test_one_query_an_env_is_attend_and_no_kernel():
+def test_one_query_an_env_is_the_decode_kernel_and_equals_attend():
     args, window = case(queries=1)
     out, stats = _forward(args, window)
     assert stats == {}
-    np.testing.assert_array_equal(
-        out, jax.jit(functools.partial(reference, window=window))(*args))
+    np.testing.assert_allclose(
+        out, jax.jit(functools.partial(reference, window=window))(*args),
+        rtol=0, atol=2e-6)
 
-    def primitives(queries):
+    def kernels(queries):
         args, window = case(queries=queries)
         text = str(jax.make_jaxpr(
             lambda *a: A.cached_attention(*a, window=window)[0])(*args))
-        return "pallas_call" in text
+        assert "pallas_call" in text
+        return [name for name in ("_decode", "_blockwise")
+                if f"name={name}" in text]
 
-    assert not primitives(1)
-    assert primitives(2)
+    assert kernels(1) == ["_decode"]
+    assert kernels(2) == ["_blockwise"]
+
+
+# -- the decode kernel: one query an env --------------------------------------
+
+DECODE_CASES = dict(
+    CASES, **{
+        "an env that sees the whole ring": dict(FULL, written=40),
+        "a window that wraps the ring's end": dict(WINDOW, written=26),
+        "a window that wraps the ring's end, by blocks": dict(
+            BLOCKS, written=400),
+    })
+DECODE_BLOCK = 128              # slots a grid step, on the BLOCKS ring
+
+
+@pytest.fixture(scope="module")
+def small_blocks():
+    """Decode blocks of 128 slots, three to the BLOCKS ring: a MiB of
+    keys would hold any of the tests' rings whole.  For the module's
+    length: the jitted kernel reads the size when it is traced."""
+    real = A._DECODE_BLOCK_BYTES
+    A._DECODE_BLOCK_BYTES = DECODE_BLOCK * KV * DIM * 4
+    A._decode.clear_cache()
+    yield
+    A._DECODE_BLOCK_BYTES = real
+    A._decode.clear_cache()
+
+
+def decode_case(name):
+    """The case with one query an env; env 2's episode, where the case
+    has one begin inside the unroll, begins at this very token."""
+    kwargs = dict(DECODE_CASES[name], queries=1)
+    if "done_at" in kwargs:
+        kwargs["done_at"] = 0
+    return case(**kwargs)
+
+
+# every ring with one stream; two streams on a ring of each shape
+TWO_STREAMS = ("a ring that has wrapped",
+               "an episode that began inside it, full",
+               "a window that wraps the ring's end, by blocks")
+
+
+@pytest.mark.parametrize(
+    "name,streams", [(name, 1) for name in DECODE_CASES]
+    + [(name, 2) for name in TWO_STREAMS])
+def test_the_decode_kernel_is_attend(small_blocks, name, streams):
+    args, window = decode_case(name)
+    out, stats = A.cached_attention(*args, window=window, streams=streams)
+    assert stats == {} and out.dtype == jnp.float32
+    np.testing.assert_allclose(
+        out, reference(*args, window=window, streams=streams), rtol=0,
+        atol=1e-5)
+
+
+@pytest.mark.parametrize("written,want", [
+    (500, [True, False, True]),      # tokens 116..499 held, 301.. seen
+    (400, [True, True, True]),       # the window wraps the ring's end
+    (384, [False, True, True]),
+    (100, [True, False, False]),     # the ring a quarter full
+    (0, [False, False, False]),      # and empty
+])
+def test_the_decode_skips_the_blocks_its_query_does_not_see(
+        small_blocks, written, want):
+    args, window = case(**dict(BLOCKS, queries=1, written=written,
+                               empty=written == 0))
+    ring_index, index, start = args[5:]
+    batch = start.shape[0]
+    visit = np.asarray(A.decode_visits(ring_index, index, start, window,
+                                       DECODE_BLOCK))[:, 0]
+    seen = mask(ring_index, index, start, window).reshape(
+        batch, 3, DECODE_BLOCK)
+    np.testing.assert_array_equal(visit, seen.any(axis=2))
+    assert visit[0].tolist() == want
+    # the grid walks the visited pairs in their order, and one step (to
+    # skip) of an env that sees no block
+    order, steps = A._decode_order(jnp.asarray(visit))
+    walked = np.asarray(order)[:int(steps[0])]
+    lone = ~visit.any(axis=1, keepdims=True) & (np.arange(3) == 0)
+    np.testing.assert_array_equal(
+        walked, np.flatnonzero((visit | lone).reshape(-1)))
+    # what is skipped is not read: poison there changes nothing
+    poison = jnp.where(jnp.asarray(np.repeat(visit, DECODE_BLOCK, axis=1))
+                       [:, :, None, None], 0.0, jnp.nan)
+    out, _ = A.cached_attention(
+        *args[:3], args[3] + poison, args[4] + poison, *args[5:],
+        window=window)
+    np.testing.assert_allclose(out, reference(*args, window=window),
+                               rtol=0, atol=1e-5)
+
+
+def test_the_updates_pass_counts_what_its_decode_steps_visited(
+        small_blocks):
+    """``decode_key_blocks_visited_share``: query ``t`` of an unroll
+    against the ring with the unroll's tokens before ``t`` in their
+    slots is the decode step that made token ``t``."""
+    queries = 9
+    args, window = case(**dict(BLOCKS, written=500, done_at=4))
+    ring_index, index, start = args[5:]
+    batch = start.shape[0]
+    visited = 0
+    for t in range(queries):
+        visited += int(jnp.sum(A.decode_visits(
+            ring_index, index[t:t + 1], start[:, t:t + 1], window,
+            DECODE_BLOCK)))
+        ring_index = A.index_write(ring_index, index[t], 1)
+    _, stats = A.cached_attention(*args, window=window)
+    assert float(stats["decode_key_blocks_visited_share"]) == pytest.approx(
+        (visited + batch * queries) / (batch * queries * 4))
+
+
+def test_the_decode_block_is_the_most_lane_tiles_within_its_bytes(
+        monkeypatch):
+    monkeypatch.setattr(A, "_DECODE_BLOCK_BYTES", 2 ** 20)
+    # the cells' rings at their widths (slots, bytes a slot of keys)
+    assert A._decode_block(2304, 4 * 128 * 2) == 768
+    assert A._decode_block(4352, 4 * 128 * 2) == 256
+    assert A._decode_block(768, 10 * 128 * 2) == 384
+    assert A._decode_block(6400, 10 * 128 * 2) == 256
+    # a ring no lane tile divides is one block
+    assert A._decode_block(12, 64) == 12
 
 
 def test_bfloat16_operands_stay_within_their_rounding():
@@ -188,6 +312,21 @@ def test_bfloat16_operands_stay_within_their_rounding():
     low = tuple(A.round_to(x, jnp.bfloat16) for x in args[:5]) + args[5:]
     out, _ = _forward(low, window)
     want = reference(*low, window=window)
+    assert out.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(out - want))) < 2 ** -7 * float(
+        jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("streams", [1, 2])
+def test_the_decode_kernels_bfloat16_stays_within_its_rounding(
+        small_blocks, streams):
+    """As above for one query an env over three blocks: a block's
+    unnormalised weights are rounded once, as ``_attend`` rounds the
+    normalised ones."""
+    args, window = case(**dict(BLOCKS, queries=1, written=500))
+    low = tuple(A.round_to(x, jnp.bfloat16) for x in args[:5]) + args[5:]
+    out, _ = A.cached_attention(*low, window=window, streams=streams)
+    want = reference(*low, window=window, streams=streams)
     assert out.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(out - want))) < 2 ** -7 * float(
         jnp.max(jnp.abs(want)))

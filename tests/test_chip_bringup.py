@@ -606,6 +606,50 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= f32\[([\d,]+)\]", text))
         assert largest < scores, (largest, scores)
 
+    @pytest.mark.parametrize("heads,kv,streams,slots,window", [
+        (32, 4, 1, 2304, 2048), (32, 4, 1, 4352, None),
+        (20, 10, 2, 768, 512), (20, 10, 2, 6400, None)],
+        ids=["trinity_window", "trinity_full", "phi4flash_window",
+             "phi4flash_full_or_cross"])
+    def test_decode_attention_compiles_with_no_score_in_hbm(
+            self, monkeypatch, v5e_topology, heads, kv, streams, slots,
+            window):
+        """ISSUE 35: the decode kernel (ops/attention.py, one query an
+        env) at both token cells' widths — 32 envs, a ring of 2,304 /
+        4,352 slots of 4 key/value heads read by groups of 8, a ring of
+        768 / 6,400 slots of 10 key pairs read by groups of 2 in two
+        streams — compiled alone for a v5e: one Mosaic call, and no
+        float32 result as large as the envs' scores or as a ring."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import attention
+
+        _as_tpu(monkeypatch)
+        envs, dim = 32, 128
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def act(query, key, value, *cache):
+            return attention.cached_attention(
+                query, key, value, *cache, window=window,
+                streams=streams)[0]
+
+        compiled = jax.jit(act).lower(
+            operand((envs, 1, heads, dim)), operand((envs, 1, kv, dim)),
+            operand((envs, 1, kv, dim)),
+            operand((envs, slots, kv, dim)), operand((envs, slots, kv, dim)),
+            operand((slots,), jnp.int32), operand((1,), jnp.int32),
+            operand((envs, 1), jnp.int32)).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest < envs * slots * min(heads * streams, kv * dim), (
+            largest, slots)
+
     def test_selective_scan_compiles_with_no_state_a_token_in_hbm(
             self, monkeypatch, v5e_topology):
         """ISSUE 34: the selective-scan kernels (ops/ssm.py, T > 1) at

@@ -404,7 +404,8 @@ def through_the_cache(case, window, lam, **replaced):
 @pytest.mark.parametrize("window", [None, 5])
 @pytest.mark.parametrize("queries", [1, 7])
 def test_differential_attention_is_the_two_softmaxes(queries, window):
-    """One query an env is ``_attend``, seven go through ``_blockwise``."""
+    """One query an env goes through the decode kernel, seven through
+    ``_blockwise``."""
     case = attention_case(queries)
     got = through_the_cache(case, window, 0.37)
     assert rel(got, two_softmaxes(case, window, 0.37)) < 1e-5

@@ -281,6 +281,58 @@ def test_the_update_unrolls_from_the_rollouts_own_rings(forty_steps):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_the_decode_gauge_is_the_kernels_block_lists_over_a_rollout(
+        forty_steps, monkeypatch):
+    """``attention/decode_key_blocks_visited_share`` as the update's pass
+    says it against the block lists the decode kernel walked, summed
+    over the rollout that made the unroll: both from ``decode_visits``,
+    the first at once from the unroll's indices, the second a step at a
+    time from the state acting found.  Blocks of two slots, so that the
+    tiny rings (14 and 22 slots) are several."""
+    from scalable_agent_tpu.ops import attention as attention_lib
+
+    block = 2
+    monkeypatch.setattr(attention_lib, "_decode_block",
+                        lambda slots, slot_bytes: block)
+    attention_lib._decode.clear_cache()
+    agent, params, tokens, done, stepwise, _ = forty_steps
+    stats = agent.stats_collection
+    unroll = jax.jit(lambda p, e, s: agent.apply(
+        p, jnp.zeros(e.done.shape, jnp.int32), e, s, mutable=[stats]))
+    state = agent.initial_state(BATCH)
+    first = 24          # both rings have wrapped, every env is mid-episode
+    for t in range(0, first + UNROLL, UNROLL):
+        start = state
+        ((_, state), said) = unroll(
+            params, env_outputs(tokens[t:t + UNROLL], done[t:t + UNROLL]),
+            state)
+    step = jax.jit(lambda p, e, s: agent.apply(
+        p, jnp.zeros(e.done.shape, jnp.int32), e, s))
+    state, rows = start, []
+    shares = {kind: 0.0 for kind in MODEL.layer_types}
+    for t in range(first, first + UNROLL):
+        began = jnp.where(done[t], state.written, state.episode_start)
+        for kind, ring_index, window in (
+                ("sliding_attention", state.window_index,
+                 MODEL.sliding_window),
+                ("full_attention", state.full_index, None)):
+            visit = attention_lib.decode_visits(
+                ring_index, state.written[None], began[:, None], window,
+                block)[:, 0]
+            shares[kind] += (float(jnp.sum(visit)) + BATCH) / (
+                BATCH * UNROLL * (visit.shape[1] + 1))
+        (logits, _), state = step(
+            params, env_outputs(tokens[t:t + 1], done[t:t + 1]), state)
+        rows.append(logits[0])
+    # the decode at these blocks is still the decode
+    assert rel(jnp.stack(rows), stepwise[first:first + UNROLL]) < 1e-5
+    want = np.mean([shares[kind] for kind in MODEL.layer_types])
+    got = said[stats]["attention/decode_key_blocks_visited_share"]
+    assert 0.0 < want < 1.0
+    assert float(got) == pytest.approx(want, rel=1e-6)
+    attention_lib._decode.clear_cache()
+
+
 # -- (c) the share adds up ----------------------------------------------------
 
 @pytest.fixture(scope="module")
