@@ -59,6 +59,8 @@ from scalable_agent_tpu.obs import (
     get_tracer,
     get_watchdog,
     install_crash_handlers,
+    start_host_watch,
+    stop_host_watch,
 )
 from scalable_agent_tpu.parallel import MeshSpec, make_mesh
 from scalable_agent_tpu.parallel.distributed import (
@@ -881,18 +883,21 @@ class _SetupStages:
         return self._name
 
     def enter(self, name: Optional[str]):
-        """End the open stage; start ``name`` (None: start nothing)."""
-        if self._span is not None:
-            self._span.__exit__(None, None, None)
-            self._span = None
+        """End the open stage; start ``name`` (None: start nothing).
+        Both take ONE clock reading, so a stage begins exactly where
+        the one before it ends, however long the hand-over takes."""
         now = time.perf_counter_ns()
+        if self._span is not None:
+            self._span.close(now)
+            self._span = None
         if self._name is not None:
             self.seconds[self._name] = (
                 self.seconds.get(self._name, 0.0)
                 + (now - self._t0_ns) * 1e-9)
         self._name, self._t0_ns = name, now
         if name is not None:
-            self._span = get_tracer().span(name, cat="setup")
+            self._span = get_tracer().span(name, cat="setup",
+                                           start_ns=now)
             self._span.__enter__()
 
     def done(self):
@@ -910,8 +915,19 @@ def _open_timeline(config: Config, t_entry_ns: Optional[int]
         t_entry_ns = time.perf_counter_ns()
     if config.trace:
         configure_tracer(None, deferred=True)
+        # Full collections and a frozen host are spans of the same
+        # timeline for as long as it records (obs/trace.py HostWatch).
+        start_host_watch(get_registry())
     get_registry().install_jax_hooks()
     return _SetupStages(t_entry_ns)
+
+
+def _close_timeline(config: Config):
+    """End what ``_open_timeline`` started: the host watch's hook and
+    thread go, the file tracer is closed (and flushed)."""
+    if config.trace:
+        stop_host_watch()
+        configure_tracer(None)
 
 
 def _attach_trace_file(config: Config):
@@ -996,8 +1012,7 @@ def _teardown_observability(config: Config, handles: _ObsHandles):
     configure_watchdog(None)
     if handles.http is not None:
         handles.http.close()
-    if config.trace:
-        configure_tracer(None)  # closes (and flushes) the file tracer
+    _close_timeline(config)
     if handles.prom is not None:
         handles.prom.dump()
     if handles.uninstall_handlers is not None:
@@ -1430,8 +1445,7 @@ def train(config: Config,
         # and the trace where a raise before ``_teardown_observability``
         # left it open.
         stages.done()
-        if config.trace:
-            configure_tracer(None)
+        _close_timeline(config)
 
 
 class _Backend:

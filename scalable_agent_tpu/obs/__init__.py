@@ -59,6 +59,8 @@ from scalable_agent_tpu.obs.trace import (
     get_tracer,
     load_trace_events,
     span,
+    start_host_watch,
+    stop_host_watch,
 )
 from scalable_agent_tpu.obs.watchdog import (
     Watchdog,
@@ -99,4 +101,6 @@ __all__ = [
     "read_anomalies",
     "render_prometheus",
     "span",
+    "start_host_watch",
+    "stop_host_watch",
 ]

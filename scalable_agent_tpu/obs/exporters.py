@@ -29,6 +29,11 @@ from scalable_agent_tpu.obs.registry import (
     Histogram,
     MetricsRegistry,
 )
+from scalable_agent_tpu.obs.trace import (
+    get_tracer,
+    thread_usage,
+    usage_since,
+)
 
 log = logging.getLogger(__name__)
 
@@ -264,16 +269,40 @@ class MetricsWriter:
         self._rows.put(record)
 
     def _drain(self):
+        """The writer thread: every batch of rows that has come in is
+        written under one span, ``writer/rows`` — this is the one thread
+        that does file I/O and long pure-Python work beside the loop, so
+        its spans say when it had the GIL."""
         while True:
-            record = self._rows.get()
+            batch = [self._rows.get()]
             try:
-                if record is None:
-                    return
-                self._write_row(record)
-            except Exception:  # noqa: BLE001  (a full disk ends no run)
-                log.exception("metrics writer: a row was not written")
-            finally:
+                while True:
+                    batch.append(self._rows.get_nowait())
+            except queue.Empty:
+                pass
+            closing = None in batch
+            rows = batch[:batch.index(None)] if closing else batch
+            if rows:
+                self._write_batch(rows)
+            for _ in batch:
                 self._rows.task_done()
+            if closing:
+                return
+
+    def _write_batch(self, rows):
+        tracer = get_tracer()
+        args = before = None
+        if tracer.enabled:
+            args, before = {"rows": len(rows)}, thread_usage()
+        with tracer.span("writer/rows", cat="log", args=args):
+            for record in rows:
+                try:
+                    self._write_row(record)
+                except Exception:  # noqa: BLE001  (a full disk ends no run)
+                    log.exception("metrics writer: a row was not written")
+            if before is not None:
+                # what the batch cost THIS thread (obs/trace.py)
+                args.update(usage_since(before))
 
     def _write_row(self, record: Dict[str, float]):
         if self._tb is not None:
@@ -285,7 +314,8 @@ class MetricsWriter:
         self._jsonl.write(json.dumps(record) + "\n")
         now = time.monotonic()
         if now - self._last_flush > self._flush_every_s:
-            self._flush_files()
+            with get_tracer().span("writer/flush", cat="log"):
+                self._flush_files()
             self._last_flush = now
 
     def write_registry(self, step: int,

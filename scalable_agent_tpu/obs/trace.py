@@ -36,22 +36,35 @@ span that something else timed on the wall clock (JAX's compile events)
 on the span clock through the tracer's ``trace_epoch`` pair.
 ``last_trace_path()`` is how the same process finds a finished run's
 spans afterwards.
+
+What can freeze the host is on the same timeline (``HostWatch``, started
+and stopped with a run's tracer): every full garbage collection is a
+span ``gc/collect``, and a thread that sleeps 10 ms at a time records
+``host/late_wakeup`` whenever it wakes 50 ms or more late, which is when
+nothing in this process got the GIL, or the process did not run.
 """
 
+import gc
 import itertools
 import json
 import os
+import resource
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
+    "HostWatch",
     "Tracer",
     "configure_tracer",
     "get_tracer",
     "last_trace_path",
     "load_trace_events",
     "span",
+    "start_host_watch",
+    "stop_host_watch",
+    "thread_usage",
+    "usage_since",
 ]
 
 
@@ -65,6 +78,9 @@ class _NullSpan:
 
     def __exit__(self, *exc_info):
         return False
+
+    def close(self, end_ns=None):
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -116,12 +132,14 @@ class _Span:
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_start_us",
                  "_annotation", "_sid", "_parent", "_covered", "_stack")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args,
+                 start_ns: Optional[int] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
         self._annotation = None
+        self._start_us = None if start_ns is None else start_ns // 1000
 
     def __enter__(self):
         tracer = self._tracer
@@ -138,13 +156,22 @@ class _Span:
         self._parent = stack[-1]
         self._covered = []
         stack.append(self)
-        self._start_us = time.perf_counter_ns() // 1000
+        if self._start_us is None:
+            self._start_us = time.perf_counter_ns() // 1000
         return self
 
     def __exit__(self, *exc_info):
-        end_us = time.perf_counter_ns() // 1000
+        self.close()
+        return False
+
+    def close(self, end_ns: Optional[int] = None):
+        """End the span, at ``end_ns`` (a ``perf_counter_ns`` reading
+        the caller took) where one is given: the span that starts on
+        the same reading then begins exactly where this one ends."""
+        end_us = (time.perf_counter_ns() if end_ns is None
+                  else end_ns) // 1000
         if self._annotation is not None:
-            self._annotation.__exit__(*exc_info)
+            self._annotation.__exit__(None, None, None)
         stack = self._stack
         if stack[-1] is self:
             stack.pop()
@@ -156,7 +183,6 @@ class _Span:
             self._name, self._cat, self._start_us, dur, self._args,
             self._sid, self._parent._sid,
             dur - sum(e - s for s, e in self._covered))
-        return False
 
 
 class Tracer:
@@ -193,7 +219,9 @@ class Tracer:
         # marker and disables itself — the head of the run stays
         # loadable.
         self._remaining_events = max_events
-        self._lock = threading.Lock()
+        # Re-entrant: a full collection that starts while this thread
+        # holds the lock ends in ``gc/collect``'s own ``_push``.
+        self._lock = threading.RLock()
         self._events: List[str] = []  # preformatted JSON event lines
         self._file = None
         self._named_tids: Dict[int, str] = {}
@@ -244,11 +272,15 @@ class Tracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, cat: str = "pipeline",
-             args: Optional[dict] = None):
-        """Context manager timing one nested span."""
+             args: Optional[dict] = None, start_ns: Optional[int] = None):
+        """Context manager timing one nested span.  ``args`` is written
+        when the span ends, so what is put into the dict while it is
+        open is kept; ``start_ns`` is a ``perf_counter_ns`` reading to
+        start it on in place of its own (``_Span.close`` takes the
+        other end)."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, cat, args, start_ns)
 
     def add_span(self, name: str, cat: str, start_us: int, end_us: int,
                  args: Optional[dict] = None):
@@ -437,6 +469,154 @@ def configure_tracer(path: Optional[str], **kwargs) -> Tracer:
 def span(name: str, cat: str = "pipeline", args: Optional[dict] = None):
     """``with obs.span('learner/update'):`` against the global tracer."""
     return _tracer.span(name, cat=cat, args=args)
+
+
+# -- what a span cost its thread ----------------------------------------------
+# The args a span takes when the question is what its thread WAITED for:
+# major page faults (paging), involuntary context switches
+# (descheduled), voluntary ones (blocked), blocks written (throttled on
+# dirty pages).
+
+USAGE_ARGS = ("majflt", "nivcsw", "nvcsw", "oublock")
+
+
+def thread_usage() -> Tuple[int, int, int, int]:
+    """The calling thread's ``USAGE_ARGS`` counts so far."""
+    usage = resource.getrusage(resource.RUSAGE_THREAD)
+    return (usage.ru_majflt, usage.ru_nivcsw, usage.ru_nvcsw,
+            usage.ru_oublock)
+
+
+def usage_since(before: Tuple[int, int, int, int]) -> Dict[str, int]:
+    """``USAGE_ARGS`` -> what the calling thread has added to each
+    since ``before`` (a ``thread_usage()`` reading of its own)."""
+    return dict(zip(USAGE_ARGS, (
+        now - then for then, now in zip(before, thread_usage()))))
+
+
+# -- what can freeze the host ------------------------------------------------
+
+class HostWatch:
+    """A garbage collection and a frozen host, as spans on ``tracer``.
+
+    ``gc/collect`` (cat ``host``): a ``gc.callbacks`` hook opens a real
+    span when a generation-2 collection starts and closes it when the
+    collection stops (args ``collected``, ``uncollectable``), on the
+    thread that collects, under whatever span that thread has open; the
+    younger generations only count (``gc/collections_total``,
+    ``gc/pause_s_total``: every generation's).
+
+    ``host/late_wakeup`` (cat ``host``): the daemon thread
+    ``host-pulse`` sleeps ``PULSE_NS`` at a time and records the
+    interval it overslept when that is ``LATE_NS`` or more (args
+    ``late_ms``): no thread of this process got the GIL for that long,
+    or the process did not run.  The span is PLACED when the thread
+    wakes (``add_span``), never held open: it belongs to no thread that
+    works, and a reader that asks which span was open in an idle gap
+    must not find it there.
+
+    A stall reads three ways: ``gc/collect`` covers it (the collector);
+    ``host/late_wakeup`` without ``gc/collect`` (another thread or
+    native code kept the GIL, or the process was stopped: the thread
+    with a span open across it is the suspect); neither, and the span
+    that is long ran with the GIL free (its own args say what it waited
+    for).  ``start`` installs both, ``stop`` takes both out again."""
+
+    PULSE_NS = 10_000_000
+    LATE_NS = 50_000_000
+
+    def __init__(self, tracer: Tracer, registry):
+        self._tracer = tracer
+        # Looked up here and not in the hook: making an instrument
+        # takes the registry's lock and allocates, and a collection
+        # may begin under that lock.
+        self._collections = registry.counter(
+            "gc/collections_total",
+            "garbage collections while the tracer was on")
+        self._pause_s = registry.counter(
+            "gc/pause_s_total",
+            "seconds inside garbage collections while the tracer was on")
+        self._gc_t0_ns = 0
+        self._gc_span = None
+        self._gc_args: Optional[dict] = None
+        self._stop = threading.Event()
+        self._thread: Optional[threading.Thread] = None
+
+    def start(self) -> "HostWatch":
+        gc.callbacks.append(self._on_gc)
+        self._thread = threading.Thread(
+            target=self._pulse, name="host-pulse", daemon=True)
+        self._thread.start()
+        return self
+
+    def stop(self):
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join()
+            self._thread = None
+
+    def _on_gc(self, phase: str, info: dict):
+        if phase == "start":
+            self._gc_t0_ns = time.perf_counter_ns()
+            if info.get("generation") == 2:
+                self._gc_args = {}
+                self._gc_span = self._tracer.span(
+                    "gc/collect", cat="host", args=self._gc_args,
+                    start_ns=self._gc_t0_ns)
+                self._gc_span.__enter__()
+            return
+        now = time.perf_counter_ns()
+        self._collections.inc()
+        self._pause_s.inc(max(0, now - self._gc_t0_ns) * 1e-9)
+        if self._gc_span is not None:
+            self._gc_args["collected"] = info.get("collected", 0)
+            self._gc_args["uncollectable"] = info.get("uncollectable", 0)
+            span, self._gc_span = self._gc_span, None
+            span.close(now)
+
+    @classmethod
+    def late_interval(cls, due_ns: int, woke_ns: int
+                      ) -> Optional[Tuple[int, int, dict]]:
+        """The pulse's rule: due at ``due_ns``, awake at ``woke_ns`` —
+        ``(start_us, end_us, args)`` of the span to record, or None
+        when the wake-up was on time."""
+        late_ns = woke_ns - due_ns
+        if late_ns < cls.LATE_NS:
+            return None
+        return (due_ns // 1000, woke_ns // 1000,
+                {"late_ms": round(late_ns * 1e-6, 3)})
+
+    def _pulse(self):
+        period_s = self.PULSE_NS * 1e-9
+        due_ns = time.perf_counter_ns() + self.PULSE_NS
+        while not self._stop.wait(period_s):
+            woke_ns = time.perf_counter_ns()
+            late = self.late_interval(due_ns, woke_ns)
+            if late is not None:
+                self._tracer.add_span("host/late_wakeup", "host", *late)
+            due_ns = woke_ns + self.PULSE_NS
+
+
+_host_watch: Optional[HostWatch] = None
+
+
+def start_host_watch(registry) -> HostWatch:
+    """Watch the host on the process tracer, which the caller has just
+    made (``driver._open_timeline``); a watch left from an earlier run
+    is stopped first."""
+    global _host_watch
+    stop_host_watch()
+    _host_watch = HostWatch(_tracer, registry).start()
+    return _host_watch
+
+
+def stop_host_watch():
+    global _host_watch
+    watch, _host_watch = _host_watch, None
+    if watch is not None:
+        watch.stop()
 
 
 def load_trace_events(path: str) -> Iterator[dict]:

@@ -259,10 +259,10 @@ class InGraphTrainer:
         # all K megaloop iterations of a dispatch.
         self._tel_specs.extend(learner.devtel_specs)
         self._tel_publisher = TelemetryPublisher(self._tel_specs)
-        self.train_step = jax.jit(self._fused, donate_argnums=(0, 1))
-        # Replayed-batch update: the learner's fresh=False
-        # specialization driven with THIS trainer's merged telemetry
-        # pytree (donated, like the fused step's carry).
+        self.train_step = self._handover = self._instrumented(
+            jax.jit(self._fused, donate_argnums=(0, 1)))
+        # Replayed-batch update: the learner's fresh=False specialization
+        # with THIS trainer's merged telemetry pytree (donated likewise).
         self.replay_step = jax.jit(self._replay_step,
                                    donate_argnums=(0, 1))
 
@@ -585,4 +585,22 @@ class InGraphTrainer:
         ``devtel/learner/*`` ride the normal prom/report path)."""
         fetched = self.fetch_telemetry(carry)
         self._tel_publisher.publish(fetched)
+        self._handover.publish()
         return fetched
+
+    # -- the hand-over -----------------------------------------------------
+
+    @staticmethod
+    def _instrumented(step):
+        """``train_step``: the jitted step inside ``StepHandover``,
+        which says what each call cost the host and what the device
+        had left to run.  Callers (the driver, ``run``, a harness that
+        wraps the attribute) dispatch through it; ``.lower`` is the
+        jitted step's.  Down here, import and all, because everything
+        above is traced into the step, and the compiled kernels' text
+        holds the line numbers of what traced them: lines added above
+        make every Mosaic kernel's serialized body, and with it the
+        step's lowered text and its compile-cache key, another's."""
+        from scalable_agent_tpu.runtime.handover import StepHandover
+
+        return StepHandover(step)

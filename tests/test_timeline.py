@@ -7,11 +7,14 @@ Two tiny traced runs (fused and host), each made once per module, and
 unit checks of the tracer and the compile listener beside them.
 """
 
+import gc
 import glob
 import json
 import os
 import re
+import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +41,8 @@ PUBLISH_CHILDREN = ["log/fetch_metrics", "log/telemetry", "log/ledger",
                     "log/health", "log/write", "log/prom"]
 UPDATES = 3
 SLACK_US = 5000     # clock conversion and stage hand-over: within 5 ms
+ENQUEUE_ARGS = {"update", "in_flight", "in_flight_after",
+                *trace_lib.USAGE_ARGS}
 
 
 def _run(tmp_path_factory, backend):
@@ -456,3 +461,362 @@ def test_a_stale_executable_from_the_cache_is_compiled_afresh_for_its_names(
     # an executable that is this program's own is read as it is
     driver._write_op_scopes(trace_path, trainer, state, carry)
     driver._write_op_scopes(None, None, None, None)      # tracing off
+
+
+# -- inside the fused dispatch (ISSUE 36) ------------------------------------
+
+def test_fused_enqueue_is_the_train_steps_child_and_says_what_it_cost(
+        fused):
+    steps = {e["sid"]: e for e in fused["spans"]
+             if e["name"] == "learner/train_step"}
+    enqueues = sorted((e for e in fused["spans"]
+                       if e["name"] == "fused/enqueue"),
+                      key=lambda e: e["ts"])
+    assert len(enqueues) == len(steps) == UPDATES
+    for e in enqueues:
+        step = steps[e["parent"]]
+        assert e["cat"] == "learner"
+        assert set(e["args"]) == ENQUEUE_ARGS
+        assert all(isinstance(v, int) for v in e["args"].values())
+        assert e["args"]["update"] == step["args"]["update"]
+        # what the step's span holds beside the hand-over is its self
+        # time: under a harness its wait, here next to nothing
+        assert step["self"] == step["dur"] - e["dur"]
+    # --log_interval_s=0: every dispatch follows a publish, whose fetch
+    # emptied the queue
+    assert [e["args"]["in_flight"] for e in enqueues] == [0] * UPDATES
+    # the step is still traced, lowered and compiled by the first
+    # dispatch, now inside its hand-over
+    step_compiles = [
+        e for e in fused["spans"] if e["cat"] == "compile"
+        and e["args"]["fun_name"].endswith(("_fused", "_fused)"))
+        and _inside(fused, e, "setup/first_dispatch")]
+    assert {e["name"] for e in step_compiles} == {
+        "compile/trace", "compile/lower", "compile/backend"}
+    for e in step_compiles:
+        assert _inside(fused, e, "fused/enqueue")
+        assert _inside(fused, e, "learner/train_step")
+
+
+def test_writer_rows_are_spans_of_the_writers_thread(fused):
+    threads = {e["tid"]: e["args"]["name"]
+               for e in trace_lib.load_trace_events(fused["path"])
+               if e.get("name") == "thread_name"}
+    rows = [e for e in fused["spans"] if e["name"] == "writer/rows"]
+    assert rows and all(e["cat"] == "log" for e in rows)
+    # what each batch cost the writer's thread, as fused/enqueue says it
+    # of the loop's
+    assert all(set(e["args"]) == {"rows"} | set(trace_lib.USAGE_ARGS)
+               for e in rows)
+    # two rows a publish (the metrics, the registry's snapshot)
+    assert sum(e["args"]["rows"] for e in rows) == 2 * UPDATES
+    loop = {e["tid"] for e in fused["spans"]
+            if e["name"] == "learner/train_step"}
+    assert {threads[e["tid"]] for e in rows} == {"metrics-writer"}
+    assert not loop & {e["tid"] for e in rows}
+    # log/write hands the rows over and returns: none is its child
+    assert all("parent" not in e for e in rows)
+    flushes = [e for e in fused["spans"] if e["name"] == "writer/flush"]
+    assert len(flushes) == 1      # the timed one, inside the first batch
+    assert fused["by_sid"][flushes[0]["parent"]]["name"] == "writer/rows"
+
+
+class _Loss:
+    """Stands where a dispatch's ``total_loss`` does in the queue."""
+
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+@pytest.fixture()
+def traced(tmp_path):
+    """The process tracer on, into a file; its spans once it is off."""
+    path = str(tmp_path / "trace.p0.1.json")
+    trace_lib.configure_tracer(path)
+
+    def spans():
+        trace_lib.configure_tracer(None)
+        return [e for e in trace_lib.load_trace_events(path)
+                if e.get("ph") == "X"]
+
+    yield spans
+    trace_lib.configure_tracer(None)
+
+
+def test_in_flight_is_the_dispatches_not_yet_ready_and_zero_is_starved(
+        tiny_trainer, traced, monkeypatch):
+    trainer, _, _ = tiny_trainer
+    handover = trainer._handover
+    assert trainer.train_step is handover
+    assert handover.lower == handover.step.lower     # still lowers
+    starved = get_registry().counter("fused/dispatch_starved_total")
+    state, carry = trainer.init(jax.random.key(1))   # the step donates
+    handover._unready.clear()
+    handover._dispatches = 0
+    before = starved.value
+    out = trainer.train_step(state, carry, np.int32(0))
+    assert starved.value == before           # the first dispatch apart
+    jax.block_until_ready(out)               # waited for: the queue is empty
+    out = trainer.train_step(out[0], out[1], np.int32(1))
+    assert starved.value == before + 1
+    # dispatched straight after another: that one is still running (what
+    # a loop reads while the device keeps up, and stops reading when a
+    # throughput_sag holds the host)
+    jax.block_until_ready(out)
+    handover._unready.append(_Loss(ready=False))
+    out = trainer.train_step(out[0], out[1], np.int32(2))
+    assert starved.value == before + 1
+    # the ready ones leave at the old end, in the device's order
+    handover._unready.clear()
+    handover._unready.extend([_Loss(True), _Loss(False), _Loss(True)])
+    assert handover.in_flight() == 2
+    jax.block_until_ready(out)
+    handover._unready.clear()
+    enqueues = [e for e in traced() if e["name"] == "fused/enqueue"]
+    assert [e["args"]["update"] for e in enqueues] == [0, 1, 2]
+    assert [e["args"]["in_flight"] for e in enqueues] == [0, 0, 1]
+    assert enqueues[2]["args"]["in_flight_after"] == 1
+    assert all(set(e["args"]) == ENQUEUE_ARGS for e in enqueues)
+    # the interval's longest hand-over goes out with the publish, and
+    # the next interval starts afresh
+    assert handover._enqueue_ms_max >= max(
+        e["dur"] for e in enqueues[1:]) * 1e-3
+    handover._enqueue_gauge.set(0.0)
+    trainer.publish_telemetry(out[1])
+    assert get_registry().gauge("fused/enqueue_ms_max").value > 0.0
+    assert handover._enqueue_ms_max == 0.0
+
+    # tracing off: the count and the gauge go on, and nothing asks the
+    # kernel for the thread's usage
+    trace_lib.configure_tracer(None)
+
+    def no_usage(*args):
+        raise AssertionError("getrusage on the dispatch path")
+
+    monkeypatch.setattr(trace_lib.resource, "getrusage", no_usage)
+    jax.block_until_ready(out)
+    out = trainer.train_step(out[0], out[1], np.int32(3))
+    assert starved.value == before + 2
+    assert handover._enqueue_ms_max > 0.0
+    jax.block_until_ready(out)
+    handover._unready.clear()
+
+
+def test_a_full_collection_is_one_gc_collect_span_with_its_args(tmp_path):
+    callbacks = list(gc.callbacks)
+    threads = {t.name for t in threading.enumerate()}
+    registry = MetricsRegistry()
+    tracer = trace_lib.Tracer(str(tmp_path / "t.json"))
+    watch = trace_lib.HostWatch(tracer, registry).start()
+    try:
+        assert len(gc.callbacks) == len(callbacks) + 1
+        assert "host-pulse" in {t.name for t in threading.enumerate()}
+        enabled = gc.isenabled()
+        gc.disable()                 # none but the collections asked for
+        try:
+            with tracer.span("region") as region:
+                gc.collect(0)                # a young one: counted only
+                junk = [[] for _ in range(1000)]
+                for item in junk:
+                    item.append(item)        # cycles: the collector's
+                del junk, item
+                gc.collect()
+        finally:
+            if enabled:
+                gc.enable()
+    finally:
+        watch.stop()
+    tracer.close()
+    assert list(gc.callbacks) == callbacks
+    assert {t.name for t in threading.enumerate()} == threads
+    spans = [e for e in trace_lib.load_trace_events(str(tmp_path / "t.json"))
+             if e.get("ph") == "X" and e["name"] != "host/late_wakeup"]
+    (collect,) = [e for e in spans if e["name"] == "gc/collect"]
+    assert collect["cat"] == "host"
+    assert collect["parent"] == region._sid
+    assert set(collect["args"]) == {"collected", "uncollectable"}
+    assert collect["args"]["collected"] >= 1000
+    assert registry.counter("gc/collections_total").value == 2
+    assert registry.counter("gc/pause_s_total").value \
+        >= collect["dur"] * 1e-6
+
+
+def test_the_host_watch_lives_only_while_a_traced_run_does(tmp_path):
+    callbacks = list(gc.callbacks)
+    threads = {t.name for t in threading.enumerate()}
+
+    def watched():
+        return (len(gc.callbacks) - len(callbacks),
+                "host-pulse" in {t.name for t in threading.enumerate()})
+
+    off = driver.Config(logdir=str(tmp_path), trace=False)
+    driver._open_timeline(off, None)
+    assert watched() == (0, False)
+    driver._close_timeline(off)
+    on = driver.Config(logdir=str(tmp_path), trace=True)
+    driver._open_timeline(on, None)
+    try:
+        assert watched() == (1, True)
+        assert trace_lib.get_tracer().enabled
+    finally:
+        driver._close_timeline(on)
+    assert not trace_lib.get_tracer().enabled
+    assert list(gc.callbacks) == callbacks
+    assert {t.name for t in threading.enumerate()} == threads
+
+
+def test_the_pulse_records_a_wake_up_50_ms_late_and_no_earlier(tmp_path):
+    late = trace_lib.HostWatch.late_interval
+    due = 7_000_000_000
+    assert late(due, due) is None
+    assert late(due, due + 49_999_999) is None      # scheduling jitter
+    assert late(due, due + 50_000_000) == (
+        7_000_000, 7_050_000, {"late_ms": 50.0})
+    assert late(due, due + 2_400_000_000) == (
+        7_000_000, 9_400_000, {"late_ms": 2400.0})
+    # the span is PLACED when the thread wakes, over the time it
+    # overslept: at no moment is it open on any thread
+    tracer = trace_lib.Tracer(str(tmp_path / "t.json"))
+    with tracer.span("loop"):
+        pulse = threading.Thread(
+            target=lambda: tracer.add_span(
+                "host/late_wakeup", "host", *late(due, due + 60_000_000)),
+            name="host-pulse")
+        pulse.start()
+        pulse.join()
+    tracer.close()
+    events = {e["name"]: e for e in trace_lib.load_trace_events(
+        str(tmp_path / "t.json")) if e.get("ph") == "X"}
+    wake = events["host/late_wakeup"]
+    assert (wake["ts"], wake["dur"]) == (7_000_000, 60_000)
+    assert wake["args"] == {"late_ms": 60.0} and "parent" not in wake
+    assert wake["tid"] != events["loop"]["tid"]
+
+
+def test_a_span_takes_the_stamps_it_is_handed(tmp_path):
+    tracer = trace_lib.Tracer(str(tmp_path / "t.json"))
+    first = tracer.span("setup/a", cat="setup", start_ns=1_000_000)
+    first.__enter__()
+    time.sleep(0.002)                 # however long the hand-over takes
+    first.close(5_000_000)
+    second = tracer.span("setup/b", cat="setup", start_ns=5_000_000)
+    second.__enter__()
+    second.close(9_000_999)
+    tracer.close()
+    a, b = [e for e in trace_lib.load_trace_events(str(tmp_path / "t.json"))
+            if e.get("ph") == "X"]
+    assert (a["ts"], a["dur"]) == (1000, 4000)
+    assert (b["ts"], b["dur"]) == (5000, 4000)
+    assert b["ts"] == _end(a)
+    trace_lib._NULL_SPAN.close(1)      # tracing off: the same calls
+
+
+# -- the readers of those spans (benchmark/metrics/*.fused.py) ---------------
+
+DISPATCH_READERS = (
+    "dispatch_longest_over_median.fused", "enqueue_ms.fused",
+    "starved_dispatch_share.fused", "host_frozen_ms.fused",
+    "gc_pause_ms.fused")
+
+
+def _dispatch_reader(name):
+    from benchmark.lib import manifest
+
+    return manifest.load_module(os.path.join(
+        manifest.BENCH_DIR, manifest.METRICS_DIR, name + ".py"), name)
+
+
+def _recorded(steps, extra=(), enqueue=True):
+    """A window [100 s, 145 s] of a run's spans: before it the first
+    dispatch (30 s, the compile) and a full collection, then ``steps``
+    as ``(start_s, train_step_ms, enqueue_ms, in_flight)``, and
+    ``extra`` as ``(name, start_s, ms)``."""
+    spans, sid = [], iter(range(1, 10_000))
+
+    def span(name, cat, start_s, ms, parent=None, args=None, tid=1):
+        event = {"name": name, "cat": cat, "ts": int(start_s * 1e6),
+                 "dur": int(ms * 1e3), "tid": tid, "sid": next(sid),
+                 "self": int(ms * 1e3)}
+        if parent is not None:
+            event["parent"] = parent
+        if args is not None:
+            event["args"] = args
+        spans.append(event)
+        return event["sid"]
+
+    span("gc/collect", "host", 50.0, 900.0,
+         args={"collected": 5, "uncollectable": 0})
+    for update, (start_s, step_ms, enqueue_ms, in_flight) in enumerate(
+            [(60.0, 30_000.0, 29_990.0, 0)] + list(steps)):
+        parent = span("learner/train_step", "learner", start_s, step_ms,
+                      args={"update": update})
+        if enqueue:
+            span("fused/enqueue", "learner", start_s, enqueue_ms,
+                 parent=parent, args={
+                     "update": update, "in_flight": in_flight,
+                     "in_flight_after": in_flight, "majflt": 0,
+                     "nivcsw": 0, "nvcsw": 1, "oublock": 0})
+    for name, start_s, ms in extra:
+        span(name, "host", start_s, ms,
+             tid=2 if name == "host/late_wakeup" else 1)
+    return types.SimpleNamespace(
+        program_spans=spans, t_open=100.0, t_close=145.0, notes=[])
+
+
+_STEADY = [(100.0 + 1.2 * k, 1160.0 + k, 2.0 + 0.1 * (k % 3), 1)
+           for k in range(37)]
+_AFTER_A_PUBLISH = [(s, d, e, 0 if k in (8, 17, 26, 35) else f)
+                    for k, (s, d, e, f) in enumerate(_STEADY)]
+_HELD = list(_AFTER_A_PUBLISH)
+_HELD[30] = (136.0, 3580.0, 2420.0, 1)       # the cold run's stall
+_HELD[31] = (139.6, 1160.0, 2.0, 0)          # ...and the queue it drained
+# The dispatch whose wait closes the window holds the harness's stopping
+# of the profiler too: an enqueue of the window, no dispatch time of it.
+_CLOSING = (144.5, 12_000.0, 2.1, 1)
+_AFTER_A_PUBLISH.append(_CLOSING)
+_HELD.append(_CLOSING)
+
+
+@pytest.mark.parametrize("name, ctx, expected", [
+    ("steady", _recorded(_AFTER_A_PUBLISH), {
+        "dispatch_longest_over_median.fused": 1196.0 / 1178.0,
+        "enqueue_ms.fused": 2.1,
+        "starved_dispatch_share.fused": 100.0 * 4 / 38,
+        "host_frozen_ms.fused": 0.0, "gc_pause_ms.fused": 0.0}),
+    ("held_by_the_collector", _recorded(_HELD, extra=[
+        ("gc/collect", 136.1, 2400.0),
+        ("host/late_wakeup", 136.11, 2385.0),
+        ("host/late_wakeup", 146.0, 70.0)]), {     # after the window
+        "dispatch_longest_over_median.fused": 3580.0 / 1177.0,
+        "enqueue_ms.fused": 2.1,
+        "starved_dispatch_share.fused": 100.0 * 5 / 38,
+        "host_frozen_ms.fused": 2385.0, "gc_pause_ms.fused": 2400.0}),
+    ("held_by_another_thread", _recorded(_HELD, extra=[
+        ("host/late_wakeup", 136.11, 1200.0),
+        ("host/late_wakeup", 137.4, 1100.0)]), {
+        "dispatch_longest_over_median.fused": 3580.0 / 1177.0,
+        "enqueue_ms.fused": 2.1,
+        "starved_dispatch_share.fused": 100.0 * 5 / 38,
+        "host_frozen_ms.fused": 2300.0, "gc_pause_ms.fused": 0.0}),
+    ("the_parents_spans", _recorded(_HELD, enqueue=False),
+     dict.fromkeys(DISPATCH_READERS)),
+], ids=lambda v: v if isinstance(v, str) else "spans")
+def test_the_dispatch_readers_on_a_recorded_span_list(name, ctx, expected):
+    assert set(expected) == set(DISPATCH_READERS)
+    for reader in DISPATCH_READERS:
+        value = _dispatch_reader(reader).read(ctx)
+        if expected[reader] is None:
+            assert value is None, reader
+        else:
+            assert value == pytest.approx(expected[reader]), reader
+    if name != "the_parents_spans":
+        # the window's spans only: the compile's dispatch and the
+        # collection before the window are in none of them
+        assert any(n.startswith("starved dispatches: ") and
+                   n.endswith("of 38 in the window, updates "
+                              + str([9, 18, 27, 36] if name == "steady"
+                                    else [9, 18, 27, 32, 36]))
+                   for n in ctx.notes), ctx.notes
