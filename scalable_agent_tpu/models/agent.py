@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from scalable_agent_tpu.models.instruction import InstructionEncoder
-from scalable_agent_tpu.models.networks import TORSOS
+from scalable_agent_tpu.models.networks import HANDOVER, TORSOS
 from scalable_agent_tpu.ops import distributions
 from scalable_agent_tpu.types import (
     AgentOutput,
@@ -226,9 +226,14 @@ class ImpalaAgent(nn.Module):
     def remat_placement(self) -> str:
         """Where ``remat_torso`` puts the checkpoint in THIS agent's
         torso: one of models/networks.py REMAT_PLACEMENTS."""
+        return self._unbound_torso().remat_placement
+
+    def _unbound_torso(self):
+        """This agent's torso outside any ``apply``, for what it says
+        of its own structure."""
         return TORSOS[self.torso_type](
             conv_backend=self.conv_backend, remat=self.remat_torso,
-            parent=None).remat_placement
+            parent=None)
 
     def zero_actions(self, batch: int) -> jnp.ndarray:
         """All-zeros last-action input at the agent's action layout
@@ -252,6 +257,17 @@ class ImpalaAgent(nn.Module):
     def acting_params(self, params):
         """The parameters as the rollout's steps read them."""
         return params
+
+    @property
+    def handover_collection(self) -> Optional[str]:
+        """The variable collection into which an acting step
+        (``actor_step(..., handover=...)``) sows what an update over
+        the same frames UNDER THE SAME PARAMETERS takes back as
+        ``__call__``'s ``handed`` in place of computing it again; None
+        where there is nothing to hand.  Here the torso's to say: the
+        shallow torso's stem activation behind the Pallas stem
+        (models/networks.py ``hands_stem``)."""
+        return HANDOVER if self._unbound_torso().hands_stem else None
 
     # Parameter groups of the learning-dynamics gauges
     # (runtime/learner.py), the module whose output the dead-unit
@@ -281,7 +297,11 @@ class ImpalaAgent(nn.Module):
         actions,
         env_outputs: StepOutput,
         core_state: AgentState,
+        handed=None,
     ) -> Tuple[Tuple[jax.Array, jax.Array], AgentState]:
+        """``handed``: what acting steps sowed into
+        ``handover_collection`` on exactly these frames under exactly
+        these parameters, each leaf stacked ``[T, B, ...]``."""
         unroll_len, batch = actions.shape[:2]
         reward, _, done, observation = env_outputs
         frame = observation.frame
@@ -314,7 +334,9 @@ class ImpalaAgent(nn.Module):
         torso = TORSOS[self.torso_type](
             dtype=self.compute_dtype, conv_backend=self.conv_backend,
             remat=self.remat_torso, name="convnet")
-        conv_out = torso(flat(frame))  # [T*B, 256] compute_dtype
+        conv_out = (  # [T*B, 256] compute_dtype
+            torso(flat(frame)) if handed is None else
+            torso(flat(frame), stem=flat(handed["convnet"]["stem"])))
 
         clipped_reward = jnp.clip(
             jnp.asarray(flat(reward), jnp.float32), -1.0, 1.0)[:, None]
@@ -380,7 +402,8 @@ def actor_step(
     last_action,
     env_output: StepOutput,
     core_state: AgentState,
-) -> Tuple[AgentOutput, AgentState]:
+    handover: Optional[str] = None,
+):
     """One batched inference step: unroll T=1, sample an action.
 
     last_action [B] int32, env_output batched [B, ...].  Returns
@@ -388,12 +411,19 @@ def actor_step(
     the batching service calls it on gathered actor requests.
     (reference: Agent._build, experiment.py:212-217 + _head sampling
     :205-208)
+
+    With ``handover`` (the agent's ``handover_collection``) a third
+    result: what the step sowed there, leaves ``[B, ...]``.
     """
     expand = lambda x: x[None] if x is not None else None
     actions = expand(last_action)
     env_outputs = map_structure(expand, env_output)
-    (policy_logits, baseline), new_state = agent.apply(
-        params, actions, env_outputs, core_state)
+    if handover:
+        ((policy_logits, baseline), new_state), sown = agent.apply(
+            params, actions, env_outputs, core_state, mutable=[handover])
+    else:
+        (policy_logits, baseline), new_state = agent.apply(
+            params, actions, env_outputs, core_state)
     policy_logits = policy_logits[0]  # [B, num_logits]
     baseline = baseline[0]  # [B]
     # Composite spaces sample every component ([B, K]); plain Discrete
@@ -404,11 +434,11 @@ def actor_step(
         # behaviour policy, the taken action's log-probability
         policy_logits = distributions.log_prob(
             policy_logits, action, agent.dist_spec)[..., None]
-    return (
-        AgentOutput(
-            action=jnp.asarray(action, jnp.int32),
-            policy_logits=policy_logits,
-            baseline=baseline,
-        ),
-        new_state,
+    output = AgentOutput(
+        action=jnp.asarray(action, jnp.int32),
+        policy_logits=policy_logits,
+        baseline=baseline,
     )
+    if handover:
+        return output, new_state, sown[handover]
+    return output, new_state

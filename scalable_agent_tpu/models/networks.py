@@ -55,6 +55,12 @@ STEM_GEOMETRY = {
 # torso knows its own.
 REMAT_PLACEMENTS = ("none", "stem", "torso")
 
+# The variable collection a torso sows into what an update over the same
+# frames under the same parameters can take in place of computing it
+# again (``ShallowConvTorso.hands_stem``).  Sown only where a caller
+# makes the collection mutable; nothing of it is a parameter.
+HANDOVER = "handover"
+
 
 def _normalize_frame(frame, dtype):
     """uint8 HWC frame -> [0, 1] float.  (reference: experiment.py:153-155)"""
@@ -161,7 +167,13 @@ class PallasStemConv(nn.Module):
     raw-frame entry: the module is then called on the frame as the
     torso got it (uint8) and the op applies ``normalize`` itself, where
     the forward conv and the kernel's one pad can each fuse it in;
-    without it the input is the conv's input as it stands."""
+    without it the input is the conv's input as it stands.
+
+    ``handed`` is ``relu`` of this layer's output for this ``x`` as an
+    earlier call under THESE parameters computed it: the call then
+    returns it — past the ReLU, no conv run — with the gradients
+    ``relu(self(x))`` gives the kernel and the bias
+    (ops/conv_pallas.py ``stem_conv_handed``)."""
 
     features: int
     kernel_size: int = 8
@@ -171,7 +183,7 @@ class PallasStemConv(nn.Module):
     normalize: Optional[Callable] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, handed=None):
         # Lazy like _PallasCore: XLA-only consumers never pay (or
         # depend on) the Pallas TPU imports.
         from scalable_agent_tpu.ops import conv_pallas
@@ -189,6 +201,10 @@ class PallasStemConv(nn.Module):
         matmul_dtype = self.matmul_dtype or (
             "bfloat16" if jnp.dtype(self.dtype) == jnp.dtype(jnp.bfloat16)
             else "float32")
+        if handed is not None:
+            return conv_pallas.stem_conv_handed(
+                x, k, b, jnp.asarray(handed, self.dtype), self.stride,
+                pallas_interpret(), matmul_dtype, self.normalize)
         out = conv_pallas.stem_conv(
             x, k, self.stride, pallas_interpret(), matmul_dtype,
             self.normalize)
@@ -226,6 +242,20 @@ class ShallowConvTorso(nn.Module):
     only boundary found that frees them within the peak-memory bound
     is the one around the whole torso (ISSUE 27: +16% live bytes
     around ``conv_0`` alone, +67% with none), so that path keeps it.
+
+    What is handed over (``hands_stem``, the Pallas stem only): a call
+    sows the stem's activation — ``relu(conv_0(frame))``
+    ``[N, H/4, W/4, 32]`` in ``dtype``, what ``conv_1`` reads — into
+    the ``HANDOVER`` collection, which costs nothing unless the caller
+    made that collection mutable; and a call given ``stem``, that
+    activation for these frames under these parameters, starts at
+    ``conv_1``.  The fused loop acts on every frame before it learns
+    from it, on one set of parameters, so its update's forward never
+    runs the stem conv (runtime/ingraph.py; 5.4 of a 35.6 ms step,
+    ISSUE 37).  Handed exactly where this torso keeps that tensor
+    whole across the backward anyway, so no step's peak moves: behind
+    XLA's stem the handed tensor would be a saved input of the
+    whole-torso checkpoint on top of what the checkpoint rebuilds.
     """
 
     dtype: Any = jnp.float32
@@ -240,27 +270,42 @@ class ShallowConvTorso(nn.Module):
             return "none"
         return "torso"
 
+    @property
+    def hands_stem(self) -> bool:
+        """Whether a call sows the stem's activation and takes one
+        back: behind the Pallas stem, whose torso holds no checkpoint."""
+        return (_stem_backend(self.conv_backend)
+                and self.remat_placement == "none")
+
     @nn.compact
-    def __call__(self, frame):
+    def __call__(self, frame, stem=None):
+        if stem is not None and not self.hands_stem:
+            raise ValueError(
+                f"a {self.conv_backend!r} stem (remat="
+                f"{self.remat_placement}) takes no handed activation")
         forward = ShallowConvTorso._forward
         if self.remat_placement == "torso":
             # Lifted over a function of THIS module, not a child: the
             # parameter paths (convnet/conv_0/...) do not move.
             forward = nn.remat(forward)
-        return forward(self, frame)
+        return forward(self, frame, stem)
 
     @nn.nowrap  # no scope or capture of its own when called directly
-    def _forward(self, frame):
+    def _forward(self, frame, stem=None):
         pallas_stem = _stem_backend(self.conv_backend)
         x = _stem_input(frame, self.dtype)
         for i, (num_ch, filter_size, stride) in enumerate(
                 [STEM_GEOMETRY["shallow"], (64, 4, 2), (128, 3, 2)]):
             if i == 0 and pallas_stem:
-                x = PallasStemConv(
+                conv_0 = PallasStemConv(
                     num_ch, filter_size, stride, dtype=self.dtype,
                     normalize=functools.partial(
                         _normalize_frame, dtype=self.dtype),
-                    name="conv_0")(frame)
+                    name="conv_0")
+                if stem is not None:
+                    x = conv_0(frame, handed=stem)  # past its ReLU
+                    continue
+                x = conv_0(frame)
             elif i == 0 and self.space_to_depth:
                 x = _SpaceToDepthFirstConv(
                     num_ch, dtype=self.dtype, name="conv_0")(x)
@@ -270,6 +315,12 @@ class ShallowConvTorso(nn.Module):
                     strides=(stride, stride),
                     padding="SAME", dtype=self.dtype, name=f"conv_{i}")(x)
             x = nn.relu(x)
+            if i == 0 and self.hands_stem and not self.is_initializing():
+                # (``init`` makes every collection mutable, and this is
+                # no variable of the model.)  One value, not sow's
+                # default tuple of every call's.
+                self.sow(HANDOVER, "stem", x, init_fn=lambda: None,
+                         reduce_fn=lambda _, new: new)
         x = x.reshape((x.shape[0], -1))
         x = nn.Dense(256, dtype=self.dtype, name="fc")(x)
         x = nn.relu(x)
@@ -331,6 +382,12 @@ class ResNetTorso(nn.Module):
     def remat_placement(self) -> str:
         """One of REMAT_PLACEMENTS: where ``remat`` puts the boundary."""
         return "stem" if self.remat else "none"
+
+    @property
+    def hands_stem(self) -> bool:
+        """Never (see ShallowConvTorso): the stem's full-resolution
+        output is the residual this torso exists NOT to keep."""
+        return False
 
     @nn.nowrap  # no scope or capture of its own when called directly
     def _stem(self, frame):

@@ -729,6 +729,10 @@ class TokenPolicy(nn.Module):
     # at a time; attention keeps no score in HBM either way
     # (ops/attention.py: its backward kernel recomputes them in VMEM)
     remat_placement = "each layer"
+    # an acting step hands the update nothing but the cache itself
+    # (``unroll_state``): the update's passes are the rematerialized
+    # layers', over keys the decode never held together
+    handover_collection = None
     # learning-dynamics telemetry (runtime/learner.py): the parameter
     # groups, no module whose dead units are read, and the collection
     # the forward pass leaves its own numbers in

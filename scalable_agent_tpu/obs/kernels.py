@@ -575,7 +575,7 @@ def frame_relayouts(hlo_text: str) -> List[dict]:
     a transposing copy for the ``[T+1, B] -> [(T+1)*B]`` merge, 2 x
     536 MB of results a step on one chip and 3 x on four; written once
     into the buffer the update reads (runtime/ingraph.py
-    ``_FrameSlots``) there is no row."""
+    ``_Slots``) there is no row."""
     fused_bodies = set(_FUSED_BODY_RE.findall(hlo_text))
     in_place, named, current = set(), [], None
     for line in hlo_text.splitlines():
@@ -625,7 +625,12 @@ def write_op_scopes(trace_path: str, hlo_text: str,
     recomputes under ``jax.checkpoint`` (``rematerialized``), to the
     notes too; and what it spends writing the trajectory's frames out
     again (``frame_relayouts``): ``frame_relayout_bytes`` in the notes,
-    with the rows, and the gauge ``fused/frame_relayout_bytes``.
+    with the rows, and the gauge ``fused/frame_relayout_bytes``.  And,
+    beside it, ``stem_handed_share``: the reading of the gauge
+    ``fused/stem_handed_share``, which tracing the step set
+    (runtime/ingraph.py) — the share of an update's frames whose stem
+    activation the acting steps handed over, so that a reader of the
+    table's ops knows whether to look for the update's stem conv.
     Returns the path written."""
     from scalable_agent_tpu.obs.registry import get_registry
 
@@ -673,7 +678,9 @@ def write_op_scopes(trace_path: str, hlo_text: str,
                            "largest_collectives": rows[:8],
                            "rematerialized": rematerialized(hlo_text),
                            "frame_relayout_bytes": relayout_bytes,
-                           "frame_relayouts": relayouts[:8]}},
+                           "frame_relayouts": relayouts[:8],
+                           "stem_handed_share": registry.gauge(
+                               "fused/stem_handed_share").value}},
         name=name)
 
 

@@ -47,6 +47,13 @@ whose cotangent alone is 27 MB per 128 images).  The driver's
 quietly handing XLA the derivative — a run that says Pallas runs
 Pallas.
 
+``stem_conv_handed`` is the same layer for a caller that already holds
+its output: the forward returns the handed activation
+``relu(conv + b)`` and runs no conv, the backward is the one above
+(ISSUE 37: the fused loop's update, whose acting steps computed that
+activation 256 images at a time under the parameters being
+differentiated).
+
 ``interpret`` comes from parallel/mesh.py ``pallas_interpret`` (the one
 home of that decision); ``matmul_dtype`` picks the MXU operand
 precision ("float32" bit-parity / "bfloat16" 2x rate, f32 accumulation
@@ -365,3 +372,42 @@ def _vjp_bwd(stride, interpret, matmul_dtype, normalize, residuals, g):
 
 
 stem_conv.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def stem_conv_handed(x, w, b, activation, stride=4, interpret=False,
+                     matmul_dtype="float32", normalize=None):
+    """``relu(stem_conv(x, w, ...) + b)`` for a caller that already
+    holds it: ``activation`` [N,OH,OW,F] IS that value, computed from
+    this ``x`` under this ``w`` and ``b`` by an earlier call (the fused
+    loop's acting steps run the stem over every frame the update then
+    reads, under the parameters the update differentiates —
+    runtime/ingraph.py).  The forward returns it and runs no conv.  The
+    backward is the one ``relu(stem_conv(...) + b)`` has: the ReLU's
+    mask read off the activation itself (``relu(z) > 0`` where
+    ``z > 0``), the bias gradient the masked cotangent's sum, the
+    weight gradient ``conv_gradw`` of the frame and the masked
+    cotangent.  ``activation`` gets no cotangent: it is the same
+    function of ``w`` and ``b`` that is being differentiated here, and
+    whoever made it differentiates nothing through it.  Handing in
+    anything else is silently another function."""
+    del x, w, b
+    return activation
+
+
+def _handed_fwd(x, w, b, activation, stride, interpret, matmul_dtype,
+                normalize):
+    return activation, (x, w, b, activation)
+
+
+def _handed_bwd(stride, interpret, matmul_dtype, normalize, residuals, g):
+    x, w, b, activation = residuals
+    g = jnp.where(activation > 0, g, jnp.zeros_like(g))
+    dx, dw = _vjp_bwd(stride, interpret, matmul_dtype, normalize, (x, w), g)
+    # the bias's broadcast transposed, as ``conv + b`` transposes it
+    # (``jnp.sum`` would accumulate a bfloat16 sum in float32)
+    db, = jax.vjp(lambda bias: jnp.broadcast_to(bias, g.shape), b)[1](g)
+    return dx, dw, db, jnp.zeros_like(activation)
+
+
+stem_conv_handed.defvjp(_handed_fwd, _handed_bwd)

@@ -188,7 +188,7 @@ def frames_batch_minor(frames):
     bf16 for the conv to read back (1 GiB a step at the fused cell's
     size).  It did exactly that as soon as the frames reached the
     update in a buffer whose order is fixed (ISSUE 29,
-    runtime/ingraph.py ``_FrameSlots``), and had always done it to the
+    runtime/ingraph.py ``_Slots``), and had always done it to the
     T=1 acting step, whose stem conv is 1.1 ms a step faster for the
     hint (my chip run, PR 29).  With it the conv's fusion reads the
     uint8 frames and converts as it goes.

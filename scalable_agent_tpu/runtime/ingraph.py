@@ -14,7 +14,7 @@ Per-update semantics match the host pipeline:
   via the rollout carry.
 - The trajectory's frames — all but a thousandth of its bytes — are
   written ONCE, by the rollout, where the update reads them
-  (``_FrameSlots``): one buffer of T+1 slots rides the donated carry,
+  (``_Slots``): one buffer of T+1 slots rides the donated carry,
   slot 0 takes the carry's frame (the overlap entry), scan step t
   writes slot t+1 in place, and the buffer's order is the order of the
   update's merged ``[(T+1)*B]`` frames, so the merge is a bitcast.
@@ -28,6 +28,23 @@ Per-update semantics match the host pipeline:
   queue + staging design, experiment.py:531,587-597); V-trace corrects
   for the behaviour/target gap in both cases, so this only shifts where
   on the on/off-policy spectrum the data sits.
+- So the update's forward starts with work the rollout has just done,
+  on the same frames under the same parameters, and an agent may say
+  what of it the acting step hands over (``agent.handover_collection``;
+  the shallow conv agent behind the Pallas stem hands its stem
+  activation, a sixth of that cell's step recomputed otherwise —
+  ISSUE 37; every other agent hands nothing and compiles to the step
+  it had).  Scan step t acts on slot t's frame and writes what it
+  sowed to slot t of one more ``_Slots`` buffer per leaf; slot T, the
+  last env step's frame, which no acting step of THIS unroll sees,
+  takes one more acting step's after the scan, of which only the sown
+  part is live code.  The buffers are made inside the step and handed
+  to ``Learner._update_impl`` beside the trajectory, never in it and
+  never in ``TrainCarry``: a carried buffer is alive at the step's
+  peak (the stem weight gradient's operands, late in the backward)
+  where the update's own copy of that tensor is already dead, and a
+  trajectory leaf would go out through ``emit_trajectory`` to updates
+  that run under other parameters (``_replay_step``).
 """
 
 from typing import Any, Dict, NamedTuple, Tuple
@@ -46,6 +63,7 @@ from scalable_agent_tpu.obs.device_telemetry import (
     fetch_merged,
     merge_init,
 )
+from scalable_agent_tpu.obs.registry import get_registry
 from scalable_agent_tpu.obs.trace import get_tracer
 from scalable_agent_tpu.ops import distributions
 from scalable_agent_tpu.parallel.mesh import (
@@ -93,7 +111,7 @@ class TrainCarry(NamedTuple):
     # takes max(streak, peak), and the driver resets the peak to 0 on
     # rollback (the only action that forgives a tolerance breach).
     streak_peak: Any = None
-    # The trajectory's frame buffer (``_FrameSlots``): T+1 slots the
+    # The trajectory's frame buffer (``_Slots``): T+1 slots the
     # rollout fills in place and the update reads where they lie.  It
     # is SCRATCH — every slot is overwritten before anything reads it,
     # so no step depends on what it held on entry — and rides the
@@ -112,16 +130,26 @@ def _stack_first(first, seq):
         first, seq, is_leaf=lambda x: x is None)
 
 
-# Rows of a TPU tile.  The compiled update keeps the merged frames
-# ``u8[(T+1)*B, H, W, C]`` with the batch in the lanes and W in the
-# sublanes (minor to major: N, W, C, H, tiles of 8 x 128 over (W, N)),
-# as XLA keeps any few-channel conv's activations.
+# Rows of a TPU tile.  The compiled update keeps a few-channel conv's
+# merged ``[(T+1)*B, H, W, C]`` input and output with the batch in the
+# lanes and, sharing the tiles of 8 x 128 with it, the channels where
+# they fill whole tiles, else W: the frames ``u8[N,72,96,3]`` minor to
+# major N, W, C, H; the stem's activation ``bf16[N,18,24,32]`` N, C, W,
+# H (read off the compiled step, benchmark/aot.py's lowering).
 _SUBLANES = 8
+# By the axis of ``[B, H, W, C]`` that shares the tiles: how a leaf, that
+# axis split in (tiles, rows), goes into a slot, and how the buffer
+# ``[H, <the other axis>, tiles, T+1, 8, B]`` comes back out as
+# ``[T+1, B, H, W, C]`` with the axis still split.
+_SLOT_ORDERS = {2: ((1, 4, 2, 3, 0), (3, 5, 0, 2, 4, 1)),
+                3: ((1, 2, 3, 4, 0), (3, 5, 0, 1, 2, 4))}
 
 
-class _FrameSlots:
-    """The trajectory's frame leaf as ONE buffer of T+1 slots that the
-    rollout fills in place, in the physical order the update reads.
+class _Slots:
+    """One ``[B, ...]`` leaf of the rollout as ONE buffer of T+1 slots
+    that the rollout fills in place, in the physical order the update
+    reads: the trajectory's frames, and what an agent's acting step
+    hands the update.
 
     The frames are nearly all of a trajectory's bytes (536 MB of 537 at
     256 envs of 72x96x3), and stacked as the scan's ``ys`` they were
@@ -130,39 +158,48 @@ class _FrameSlots:
     transposing copy, because the update's ``[T+1, B] -> [(T+1)*B]``
     merge wants T INSIDE ``[H][C][W/8]``, directly above the (8 x B)
     tiles, and a time-major stack has it outermost (ISSUE 29: 3.26 ms
-    of a 40.75 ms step that compute nothing).  Here slot 0 takes the
-    carry's frame, scan step t writes slot t+1 with a
-    ``dynamic_update_slice`` (XLA updates a while-carried buffer in
-    place, which is how ``ys`` are stacked anyway), and the buffer's
-    logical shape is ``[H, C, W/8, T+1, 8, B]``: its row-major order IS
-    the order of the merged frames, so ``frames()``'s transpose back to
-    ``[T+1, B, H, W, C]`` and the agent's merge compile to bitcasts.
-    As a donated argument of the step the buffer arrives row-major;
-    ``write`` asks for it to stay so, or the compiler may turn it to
-    the order of the scan's own frame for the length of the loop and
-    pay two whole copies for that (the ResNet's step did, AOT).
+    of a 40.75 ms step that compute nothing).  Here a slot is written
+    with a ``dynamic_update_slice`` (XLA updates a while-carried buffer
+    in place, which is how ``ys`` are stacked anyway), and the buffer's
+    logical shape is ``[H, C, W/8, T+1, 8, B]`` for the frames and
+    ``[H, W, C/8, T+1, 8, B]`` for a leaf whose channels fill the
+    sublanes: its row-major order IS the order of the merged leaf, so
+    ``stacked()``'s transpose back to ``[T+1, B, H, W, C]`` and the
+    agent's merge compile to bitcasts.  ``write`` asks for the buffer
+    to stay row-major, or the compiler may turn it to the order of the
+    scan's own value for the length of the loop and pay two whole
+    copies for that (the ResNet's step did, AOT).
 
-    The order comes from the frame's shape alone.  A frame that is not
-    ``[B, H, W, C]`` with W a whole number of sublanes (the 10x10 and
-    15x15 worlds) has no such split: its slots are stacked time-major,
-    without the concatenate, and the compiler inserts whatever copy it
-    inserted before.  Values never depend on the order."""
+    The order comes from the leaf's shape alone.  A leaf that is not
+    ``[B, H, W, C]`` with C or W a whole number of sublanes (the 10x10
+    and 15x15 worlds' frames) has no such split: its slots are stacked
+    time-major, and the compiler inserts whatever copy it inserted
+    before.  Values never depend on the order."""
 
-    def __init__(self, frame_shape, slots: int, mesh):
-        self._shape = tuple(frame_shape)
+    def __init__(self, leaf_shape, slots: int, mesh):
+        self._shape = tuple(leaf_shape)
         self._slots = slots
-        self._tiled = (len(self._shape) == 4
-                       and self._shape[2] % _SUBLANES == 0)
-        if self._tiled:
-            batch, height, width, channels = self._shape
+        self._split = next(
+            (axis for axis in (3, 2) if len(self._shape) == 4
+             and self._shape[axis] % _SUBLANES == 0), None)
+        if self._split is not None:
+            height, other = self._shape[1], self._shape[5 - self._split]
             self._slot_axis, batch_axis = 3, 5
-            self._buffer_shape = (height, channels, width // _SUBLANES,
-                                  slots, _SUBLANES, batch)
+            self._buffer_shape = (
+                height, other, self._shape[self._split] // _SUBLANES,
+                slots, _SUBLANES, self._shape[0])
         else:
             self._slot_axis, batch_axis = 0, 1
             self._buffer_shape = (slots,) + self._shape
         # The buffer is sharded as the rollout is: over its batch axis.
         self.sharding = batch_sharding(mesh, batch_axis)
+
+    def _tiles(self):
+        """``[B, H, W, C]`` with the split axis in (tiles, rows)."""
+        split = self._split
+        return (self._shape[:split]
+                + (self._shape[split] // _SUBLANES, _SUBLANES)
+                + self._shape[split + 1:])
 
     def empty(self, dtype):
         """The buffer, all slots blank, born on its own devices: made
@@ -171,25 +208,30 @@ class _FrameSlots:
         the step holds 2.18; my chip run, PR 29)."""
         return jnp.zeros(self._buffer_shape, dtype, device=self.sharding)
 
-    def write(self, buffer, frame, index):
-        """``buffer`` with ``frame`` ``[B, ...]`` in slot ``index``."""
-        if self._tiled:
-            batch, height, width, channels = self._shape
-            frame = frame.reshape(
-                batch, height, width // _SUBLANES, _SUBLANES, channels
-            ).transpose(1, 4, 2, 3, 0)
+    def unwritten(self, dtype):
+        """The buffer for a step that makes it, writes every slot and
+        reads it, all inside one program: allocated, not filled (on a
+        TPU; zeros elsewhere).  Filled, the stem activations' 715 MB
+        were 0.99 ms of a 31.7 ms step (my chip run, PR 37)."""
+        return jax.lax.empty(self._buffer_shape, dtype)
+
+    def write(self, buffer, leaf, index):
+        """``buffer`` with ``leaf`` ``[B, ...]`` in slot ``index``."""
+        if self._split is not None:
+            leaf = leaf.reshape(self._tiles()).transpose(
+                _SLOT_ORDERS[self._split][0])
         buffer = jax.lax.dynamic_update_slice_in_dim(
-            buffer, jnp.expand_dims(frame, self._slot_axis), index,
+            buffer, jnp.expand_dims(leaf, self._slot_axis), index,
             self._slot_axis)
-        if self._tiled:
+        if self._split is not None:
             buffer = layout_hint(buffer, range(buffer.ndim))
         return jax.lax.with_sharding_constraint(buffer, self.sharding)
 
-    def frames(self, buffer):
-        """The buffer as the trajectory's ``[T+1, B, ...]`` leaf."""
-        if not self._tiled:
+    def stacked(self, buffer):
+        """The buffer as the ``[T+1, B, ...]`` stack of its slots."""
+        if self._split is None:
             return buffer
-        return buffer.transpose(3, 5, 0, 2, 4, 1).reshape(
+        return buffer.transpose(_SLOT_ORDERS[self._split][1]).reshape(
             (self._slots,) + self._shape)
 
 
@@ -347,16 +389,23 @@ class InGraphTrainer:
 
     # -- the fused program -------------------------------------------------
 
-    def _frame_slots(self, env_output) -> _FrameSlots:
-        return _FrameSlots(env_output.observation.frame.shape,
-                           self._unroll_length + 1, self._learner.mesh)
+    def _slots(self, leaf) -> _Slots:
+        return _Slots(leaf.shape, self._unroll_length + 1,
+                      self._learner.mesh)
+
+    def _frame_slots(self, env_output) -> _Slots:
+        return self._slots(env_output.observation.frame)
 
     def _rollout(self, params, carry: RolloutCarry, rng, frames):
-        """One unroll: ``(trajectory, new carry, frames)``.  ``frames``
-        is the frame buffer (``TrainCarry.frames``), handed back filled
-        with this unroll's T+1 frames; the trajectory's frame leaf is a
-        view of it."""
+        """One unroll: ``(trajectory, new carry, frames, handed)``.
+        ``frames`` is the frame buffer (``TrainCarry.frames``), handed
+        back filled with this unroll's T+1 frames; the trajectory's
+        frame leaf is a view of it.  ``handed`` is what the acting
+        steps sowed for an update UNDER ``params`` (the agent's
+        ``handover_collection``), leaves ``[T+1, B, ...]``; None from
+        an agent that hands nothing."""
         agent, env = self._agent, self._env
+        handover = agent.handover_collection
 
         # The named scopes (here, ``telemetry`` and ``learner_update``
         # below, and the learner's own) land in the compiled HLO's
@@ -378,28 +427,58 @@ class InGraphTrainer:
             return env_output._replace(
                 observation=env_output.observation._replace(frame=frame))
 
-        def scan_fn(c, t):
-            c, frames = c
+        def act(c, key):
+            """The acting step on carry ``c``: ``(agent output, core
+            state, what it hands the update)``."""
             with jax.named_scope("actor_inference"):
-                out, core = actor_step(
-                    agent, params, jax.random.fold_in(rng, t),
-                    c.agent_output.action, c.env_output, c.core_state)
+                result = actor_step(
+                    agent, params, key, c.agent_output.action,
+                    c.env_output, c.core_state, handover=handover)
+            return result if handover else result + ({},)
+
+        # A buffer per handed leaf, in the update's order as the frames
+        # are, made here: alive from its first write to the update's
+        # last read of it and no longer (see the module docstring).
+        sown_shapes = (jax.eval_shape(act, carry, rng)[2] if handover
+                       else {})
+        handed_slots = jax.tree_util.tree_map(self._slots, sown_shapes)
+        handed = jax.tree_util.tree_map(
+            lambda leaf_slots, leaf: leaf_slots.unwritten(leaf.dtype),
+            handed_slots, sown_shapes)
+
+        def hand_over(handed, sown, index):
+            return jax.tree_util.tree_map(
+                lambda leaf_slots, buffer, leaf: leaf_slots.write(
+                    buffer, leaf, index), handed_slots, handed, sown)
+
+        def scan_fn(c, t):
+            c, frames, handed = c
+            out, core, sown = act(c, jax.random.fold_in(rng, t))
             with jax.named_scope("env_step"):
                 env_state, env_output = env.step(c.env_state, out.action)
             # The frame goes to its slot of the one buffer; every other
             # leaf (under 1 MB together) is stacked as the scan's ys.
             frames = slots.write(
                 frames, env_output.observation.frame, t + 1)
+            # What the step sowed is of the frame it ACTED on: slot t.
+            handed = hand_over(handed, sown, t)
             return (RolloutCarry(env_state, env_output, out, core),
-                    frames), (without_frame(env_output), out)
+                    frames, handed), (without_frame(env_output), out)
 
         with jax.named_scope("rollout"):
             # Slot 0: the overlap entry, the previous unroll's last.
             frames = slots.write(
                 frames, carry.env_output.observation.frame, 0)
-            (new_carry, frames), (env_seq, agent_seq) = jax.lax.scan(
-                scan_fn, (carry, frames),
-                jnp.arange(self._unroll_length))
+            (new_carry, frames, handed), (env_seq, agent_seq) = (
+                jax.lax.scan(scan_fn, (carry, frames, handed),
+                             jnp.arange(self._unroll_length)))
+            if handover:
+                # Slot T: the last env step's frame, which the NEXT
+                # unroll's first step acts on, under the next
+                # parameters.  One more acting step under these; only
+                # what it sows is read, the rest is dead code.
+                handed = hand_over(handed, act(new_carry, rng)[2],
+                                   self._unroll_length)
         env_outputs = _stack_first(
             without_frame(carry.env_output), env_seq)
         trajectory = Trajectory(
@@ -409,10 +488,13 @@ class InGraphTrainer:
             # instead of a copy (models/token_policy.py unroll_state)
             agent_state=agent.unroll_state(carry.core_state,
                                            new_carry.core_state),
-            env_outputs=without_frame(env_outputs, slots.frames(frames)),
+            env_outputs=without_frame(env_outputs, slots.stacked(frames)),
             agent_outputs=_stack_first(carry.agent_output, agent_seq),
         )
-        return trajectory, new_carry, frames
+        handed = jax.tree_util.tree_map(
+            lambda leaf_slots, buffer: leaf_slots.stacked(buffer),
+            handed_slots, handed) if handover else None
+        return trajectory, new_carry, frames, handed
 
     def _constrain_batch(self, tree):
         return jax.tree_util.tree_map(
@@ -428,8 +510,17 @@ class InGraphTrainer:
         as K separate dispatches."""
         rng = jax.random.fold_in(
             jax.random.key(self._seed), update_index)
-        trajectory, new_rollout, frames = self._rollout(
+        trajectory, new_rollout, frames, handed = self._rollout(
             state.params, rollout_carry, rng, frames)
+        get_registry().gauge(
+            "fused/stem_handed_share",
+            "share of the frames an update reads whose stem activation "
+            "an acting step had computed under the same parameters and "
+            "handed over (T of the T+1 slots; the last one's is "
+            "computed after the scan, for the update alone): 0 where "
+            "the agent hands nothing").set(
+                self._unroll_length / (self._unroll_length + 1.0)
+                if handed is not None else 0.0)
         # Chaos (trace-time): the host backend's ``nan_grad`` hook
         # lives in Learner.update, which this fused path never calls —
         # bake the armed occurrence set into the compiled program and
@@ -458,8 +549,10 @@ class InGraphTrainer:
             telemetry = record_episode_telemetry(
                 self._env_tel_spec, telemetry, emitted)
         with jax.named_scope("learner_update"):
+            # ``handed`` holds only here: the rollout above and this
+            # update read the one ``state.params``.
             new_state, telemetry, metrics = self._learner._update_impl(
-                state, trajectory, telemetry)
+                state, trajectory, telemetry, handed=handed)
         # Episode accounting from the on-device env stream (the host
         # backend reads MultiEnv ring buffers; here the trajectory
         # itself carries the emitted per-done episode stats), as SUMS so

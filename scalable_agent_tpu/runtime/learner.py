@@ -713,7 +713,8 @@ class Learner:
 
     # -- update -----------------------------------------------------------
 
-    def _forward(self, params, trajectory: Trajectory, capture=False):
+    def _forward(self, params, trajectory: Trajectory, capture=False,
+                 handed=None):
         """The ONE whole-trajectory unroll of the update (reference:
         experiment.py:358-365).  Every loss quantity — the
         behaviour-comparison logits V-trace consumes AND the
@@ -725,26 +726,28 @@ class Learner:
         baselines [T+1,B] f32), observed)``: what the pass showed
         besides its outputs, as metrics — ``dead_torso_frac``, or, for an
         agent that names no such module, its forward pass's own numbers
-        (``agent.STATS``); empty without ``capture``."""
+        (``agent.STATS``); empty without ``capture``.  ``handed`` is
+        ``_update_impl``'s, for the agent (its ``handover_collection``)."""
         agent = self._agent
         module = agent.dead_unit_module
         args = (params, trajectory.agent_outputs.action,
                 trajectory.env_outputs, trajectory.agent_state)
+        kwargs = {} if handed is None else {"handed": handed}
         if capture and module is not None:
             (out, _), captured = agent.apply(
                 *args, capture_intermediates=_module_filter(module),
-                mutable=["intermediates"])
+                mutable=["intermediates"], **kwargs)
             with jax.named_scope("telemetry"):
                 return out, {"dead_torso_frac": _dead_unit_fraction(
                     captured, module)}
         if capture and self._forward_stats:
             # no module's dead units to read, but the pass's own numbers
             (out, _), sown = agent.apply(
-                *args, mutable=[agent.stats_collection])
+                *args, mutable=[agent.stats_collection], **kwargs)
             stats = sown.get(agent.stats_collection, {})
             return out, {name: jax.lax.stop_gradient(stats[name])
                          for name in self._forward_stats}
-        out, _ = agent.apply(*args)
+        out, _ = agent.apply(*args, **kwargs)
         return out, {}
 
     def _comparison_forward(self, params, trajectory: Trajectory):
@@ -766,17 +769,21 @@ class Learner:
         return (jax.lax.stop_gradient(logits),
                 jax.lax.stop_gradient(baselines))
 
-    def _loss(self, params, trajectory: Trajectory, target_params=None):
+    def _loss(self, params, trajectory: Trajectory, target_params=None,
+              handed=None):
         """Dispatch on the construction-time surrogate choice (a Python
-        branch: each jit specialization compiles exactly one)."""
+        branch: each jit specialization compiles exactly one).
+        ``handed`` (``_update_impl``'s) goes to the ONE forward whose
+        parameters are the ones that acted: the differentiated one."""
         if self._loss_name == "impact":
-            return self._loss_impact(params, trajectory, target_params)
-        return self._loss_vtrace(params, trajectory)
+            return self._loss_impact(params, trajectory, target_params,
+                                     handed)
+        return self._loss_vtrace(params, trajectory, handed)
 
-    def _loss_vtrace(self, params, trajectory: Trajectory):
+    def _loss_vtrace(self, params, trajectory: Trajectory, handed=None):
         hp = self._hp
         (target_logits, baselines), observed = self._forward(
-            params, trajectory, capture=self._learn_enabled)
+            params, trajectory, capture=self._learn_enabled, handed=handed)
         if self._fused_forward:
             comparison_logits, comparison_baselines = (
                 target_logits, baselines)
@@ -861,7 +868,8 @@ class Learner:
                 dist_spec, observed))
         return total, metrics
 
-    def _loss_impact(self, params, trajectory: Trajectory, target_params):
+    def _loss_impact(self, params, trajectory: Trajectory, target_params,
+                     handed=None):
         """IMPACT clipped-target surrogate (ops/impact.py): V-trace
         advantages computed with the TARGET network as the target
         policy (so the β = min(c̄, π_tgt/μ) behaviour→target correction
@@ -872,7 +880,7 @@ class Learner:
         # ONE online unroll (capture feeds the dead-unit gauge — the
         # params being optimized).
         (online_logits, baselines), observed = self._forward(
-            params, trajectory, capture=self._learn_enabled)
+            params, trajectory, capture=self._learn_enabled, handed=handed)
         if self._fused_forward:
             comparison_baselines = baselines
         else:
@@ -881,7 +889,8 @@ class Learner:
         # Second (TARGET-net) unroll: the staleness anchor.  This one
         # is irreducible — different params — and is the price of
         # tolerating arbitrarily stale behaviour data; the fused-
-        # forward contract is about the ONLINE net only.
+        # forward contract is about the ONLINE net only (and so is
+        # ``handed``: nothing acted under the target's parameters).
         (anchor_logits, _), _ = self._forward(target_params, trajectory)
         with jax.named_scope("vtrace_loss"):  # as in _loss_vtrace
             bootstrap_value = comparison_baselines[-1]
@@ -984,7 +993,7 @@ class Learner:
         }
 
     def _update_impl(self, state: TrainState, trajectory: Trajectory,
-                     devtel: Dict, fresh: bool = True
+                     devtel: Dict, fresh: bool = True, handed=None
                      ) -> Tuple[TrainState, Dict, Dict[str, jax.Array]]:
         """One update.  ``devtel`` is the device-telemetry pytree
         (donated; may carry other specs' leaves — e.g. the in-graph
@@ -992,10 +1001,16 @@ class Learner:
         ``fresh`` is a PYTHON (specialization-time) flag: a replayed
         batch's update holds env_frames (the frames were counted at
         fresh consumption) and skips the target-net sync schedule.
+        ``handed`` is what the acting steps that made ``trajectory``
+        sowed into the agent's ``handover_collection``, leaves
+        ``[T+1, B, ...]`` — only from a caller whose every acting step
+        ran under ``state.params`` themselves, which the fused loop's
+        did (runtime/ingraph.py) and a replayed batch's, a host actor's
+        or a restored run's did not.
         Returns ``(new_state, new_devtel, metrics)``."""
         (_, metrics), grads = jax.value_and_grad(
             self._loss, has_aux=True)(
-                state.params, trajectory, state.target_params)
+                state.params, trajectory, state.target_params, handed)
 
         # Linear decay to 0 over total frames (reference:
         # experiment.py:409-412 polynomial_decay power=1).

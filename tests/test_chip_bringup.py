@@ -685,13 +685,14 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= f32\[([\d,]+)\]", text))
         assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
 
-    @pytest.mark.parametrize("devices,overrides,merged", [
-        (1, {}, 101 * 256),
-        (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128),
-        (4, {}, 101 * 256),
+    @pytest.mark.parametrize("devices,overrides,merged,handed", [
+        (1, {}, 101 * 256, True),
+        (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128, False),
+        (4, {}, 101 * 256, False),
     ], ids=["shallow", "resnet", "shallow-data4"])
     def test_fused_step_writes_the_frames_once(
-            self, monkeypatch, v5e_topology, devices, overrides, merged):
+            self, monkeypatch, v5e_topology, devices, overrides, merged,
+            handed):
         """ISSUE 29, the compiler's verdict on the three fused cells'
         steps: no instruction results in the whole uint8 frame tensor
         but the loop that fills the carry's buffer and its in-place
@@ -702,7 +703,13 @@ class TestAotCompileForV5e:
         a step on one chip, 1.6 GB a chip on four).  And the update's
         stem forward conv still reads the uint8 frames and converts as
         it goes (``frames_batch_minor``): no float copy of all the
-        frames is written first."""
+        frames is written first.
+
+        ISSUE 37, on the same compiled text: behind the Pallas stem
+        (one chip, the shallow torso) the update runs no stem conv —
+        conv_1 reads a bitcast of the buffer the acting steps filled,
+        and nothing copies or transposes a tensor of that size; the
+        other two steps, handed nothing, keep the stem conv they had."""
         from scalable_agent_tpu.obs import kernels as kernels_lib
 
         text = _compile_default_fused_step(
@@ -728,4 +735,18 @@ class TestAotCompileForV5e:
                     r"\[%d,72,96,3\]" % merged, line):
                 floats.append(line.split(" = ")[0].strip())
         assert not floats, floats
+        # (the forward's own: a recomputed one's op_name starts with
+        # the transpose's path)
+        stem_forwards = len(re.findall(
+            r" convolution\(.*closed_call/learner_update/"
+            r"jvp\(ImpalaAgent\)/convnet/(?:convnet\._\w+/)?"
+            r"(?:conv_0|downscale_0)/conv_general_dilated", text))
+        assert stem_forwards == (0 if handed else 1)
+        if handed:
+            assert re.search(
+                r"= bf16\[%d,18,24,32\]\S* bitcast\(" % merged, text)
+            moved = re.findall(
+                r"= bf16\[(?:%d,18,24,32|18,24,4,101,8,256)\]\S* "
+                r"(?:copy|transpose)\(" % merged, text)
+            assert not moved, moved
 

@@ -316,3 +316,106 @@ class TestPallasStemConvModule:
         with pytest.raises(ValueError, match="conv_backend"):
             networks.ShallowConvTorso(conv_backend="tensorrt").init(
                 jax.random.key(0), self._frame())
+
+
+class TestStemHandOver:
+    """ISSUE 37: the stem's activation, computed once by a call under
+    the same parameters, stands in for the stem conv of a later call's
+    forward — and every gradient is the one the conv's own call has."""
+
+    @pytest.mark.parametrize("x_dtype", (jnp.uint8, jnp.float32))
+    def test_op_has_the_gradients_of_the_conv_it_stands_for(self, x_dtype):
+        kx, kw, kb, kc = jax.random.split(jax.random.key(41), 4)
+        x = jax.random.randint(kx, (3, 16, 24, 3), 0, 255).astype(x_dtype)
+        w = jax.random.normal(kw, (8, 8, 3, 32), jnp.float32) * 0.1
+        b = jax.random.normal(kb, (32,), jnp.float32) * 0.1
+        cot = jax.random.normal(kc, (3, 4, 6, 32), jnp.float32)
+        normalize = lambda frame: jnp.asarray(frame, jnp.float32) / 255.0
+        args = (4, _INTERPRET, "float32", normalize)
+
+        def computed(x, w, b):
+            return jax.nn.relu(conv_pallas.stem_conv(x, w, *args) + b)
+
+        activation = computed(x, w, b)
+        assert float((activation > 0).mean()) not in (0.0, 1.0)
+
+        def handed(x, w, b, activation):
+            return conv_pallas.stem_conv_handed(x, w, b, activation, *args)
+
+        np.testing.assert_array_equal(handed(x, w, b, activation),
+                                      activation)
+        wrt = (0, 1, 2) if x_dtype == jnp.float32 else (1, 2)
+        want = jax.grad(lambda *a: jnp.sum(computed(*a) * cot),
+                        argnums=wrt)(x, w, b)
+        got = jax.grad(lambda *a: jnp.sum(handed(*a) * cot),
+                       argnums=wrt + (3,))(x, w, b, activation)
+        for have, need in zip(got, want):
+            np.testing.assert_allclose(have, need, rtol=1e-6, atol=1e-6)
+        # the handed tensor gets no cotangent: whoever computed it
+        # differentiates nothing through it
+        np.testing.assert_array_equal(got[-1], jnp.zeros_like(activation))
+
+    @pytest.mark.parametrize("dtype,tolerance", [
+        (jnp.float32, 1e-6), (jnp.bfloat16, 1e-6)],
+        ids=("float32", "bfloat16"))
+    def test_torso_given_its_stem_activation_is_the_torso_that_computes_it(
+            self, dtype, tolerance):
+        from scalable_agent_tpu.models import networks
+
+        torso = networks.ShallowConvTorso(conv_backend="pallas",
+                                          dtype=dtype)
+        frame = jax.random.randint(
+            jax.random.key(43), (5, 24, 32, 3), 0, 255).astype(jnp.uint8)
+        params = torso.init(jax.random.key(2), frame)
+        # nothing of the hand-over is a variable of the model
+        assert set(params) == {"params"}
+        # a bias off zero, or its gradient's path would go untested
+        params = jax.tree_util.tree_map(
+            lambda p: p + 0.05 * jnp.cos(jnp.arange(p.size, dtype=p.dtype)
+                                          ).reshape(p.shape), params)
+        out, sown = torso.apply(params, frame,
+                                mutable=[networks.HANDOVER])
+        stem = sown[networks.HANDOVER]["stem"]
+        assert stem.shape == (5, 6, 8, 32) and stem.dtype == dtype
+        # an immutable collection: the sow is a no-op
+        np.testing.assert_array_equal(torso.apply(params, frame), out)
+        np.testing.assert_array_equal(torso.apply(params, frame, stem),
+                                      out)
+        weights = jax.random.normal(jax.random.key(3), out.shape)
+
+        def grads(*stem):
+            return jax.grad(lambda p: jnp.sum(
+                jnp.asarray(torso.apply(p, frame, *stem), jnp.float32)
+                * weights))(params)
+
+        want, got = grads(), grads(stem)
+        assert (jax.tree_util.tree_structure(want)
+                == jax.tree_util.tree_structure(got))
+        for path, need in jax.tree_util.tree_leaves_with_path(want):
+            have = got
+            for entry in path:
+                have = have[entry.key]
+            assert float(jnp.abs(need).max()) > 0, path
+            np.testing.assert_allclose(
+                have, need, rtol=tolerance,
+                atol=tolerance * float(jnp.abs(need).max()),
+                err_msg=jax.tree_util.keystr(path))
+
+    def test_only_the_checkpoint_free_pallas_torso_hands_anything(self):
+        from scalable_agent_tpu.models import networks
+
+        frame = jnp.zeros((2, 16, 16, 3), jnp.uint8)
+        hands = {
+            (name, backend, remat): networks.TORSOS[name](
+                conv_backend=backend, remat=remat).hands_stem
+            for name in networks.TORSOS
+            for backend in networks.CONV_BACKENDS
+            for remat in (False, True)}
+        assert {key for key, value in hands.items() if value} == {
+            ("shallow", "pallas", False), ("shallow", "pallas", True)}
+        xla = networks.ShallowConvTorso(conv_backend="xla")
+        params = xla.init(jax.random.key(0), frame)
+        _, sown = xla.apply(params, frame, mutable=[networks.HANDOVER])
+        assert not sown
+        with pytest.raises(ValueError, match="takes no handed"):
+            xla.apply(params, frame, jnp.zeros((2, 4, 4, 32)))

@@ -19,6 +19,7 @@ from scalable_agent_tpu.models import ImpalaAgent
 from scalable_agent_tpu.parallel import MeshSpec, make_mesh
 from scalable_agent_tpu.runtime import Learner, LearnerHyperparams
 from scalable_agent_tpu.runtime.ingraph import InGraphTrainer
+from scalable_agent_tpu.runtime.learner import Trajectory
 
 H = W = 12
 NUM_ACTIONS = 4
@@ -164,9 +165,9 @@ class TestInGraphTrainer:
         # _rollout takes the bare RolloutCarry and the frame buffer it
         # fills; the telemetry half of the TrainCarry rides only the
         # fused step.
-        traj1, carry2, frames = jax.jit(trainer._rollout)(
+        traj1, carry2, frames, _ = jax.jit(trainer._rollout)(
             state.params, carry.rollout, rng, carry.frames)
-        traj2, _, _ = jax.jit(trainer._rollout)(
+        traj2, _, _, _ = jax.jit(trainer._rollout)(
             state.params, carry2, jax.random.key(2), frames)
         np.testing.assert_array_equal(
             np.asarray(traj1.env_outputs.observation.frame[self.T]),
@@ -380,3 +381,124 @@ class TestInGraphDataParallel:
         loss4 = float(np.asarray(m4["total_loss"]))
         np.testing.assert_allclose(loss4, loss1, rtol=1e-4)
         assert float(np.asarray(m4["env_frames"])) == 3 * self.T * self.B
+
+
+class TestSlotOrders:
+    """The slot buffer of any ``[B, ...]`` leaf (ISSUE 37 generalised
+    ISSUE 29's frame buffer): which axis shares the tiles with the
+    batch comes from the leaf's shape alone, and no value depends on
+    where the bytes lie."""
+
+    SLOTS = 6
+
+    @pytest.mark.parametrize("shape,buffer_shape,dtype", [
+        # frames: three channels fill no tile, W does
+        ((4, 16, 24, 3), (16, 3, 3, 6, 8, 4), np.uint8),
+        # a stem's activation: the channels do
+        ((4, 4, 6, 32), (4, 6, 4, 6, 8, 4), np.float32),
+        # the 10x10 worlds' frames, and a leaf that is no image:
+        # stacked time-major
+        ((4, 10, 10, 3), (6, 4, 10, 10, 3), np.uint8),
+        ((4, 7), (6, 4, 7), np.float32),
+    ], ids=("width-tiled", "channel-tiled", "untiled-frame", "untiled"))
+    # the carry's buffer, born blank, and one a step makes for itself
+    @pytest.mark.parametrize("born", ("empty", "unwritten"))
+    def test_values_never_depend_on_the_order(self, shape, buffer_shape,
+                                              dtype, born):
+        from scalable_agent_tpu.runtime.ingraph import _Slots
+
+        mesh = make_mesh(MeshSpec(data=1, model=1),
+                         devices=jax.devices()[:1])
+        slots = _Slots(shape, self.SLOTS, mesh)
+        leaves = np.random.default_rng(0).integers(
+            0, 255, (self.SLOTS,) + shape).astype(dtype)
+        buffer = getattr(slots, born)(dtype)
+        assert buffer.shape == buffer_shape and buffer.dtype == dtype
+        write = jax.jit(slots.write)
+        for index in (3, 0, 5, 1, 4, 2):    # any order, each slot once
+            buffer = write(buffer, leaves[index], index)
+        np.testing.assert_array_equal(slots.stacked(buffer), leaves)
+
+
+class TestStemHandOver:
+    """ISSUE 37: behind the Pallas stem the acting steps hand the
+    update their stem activations — slot t from scan step t, slot T
+    from one more acting step after the scan — beside the trajectory,
+    to the one update whose parameters are the ones that acted."""
+
+    T, B = 5, 4
+
+    def make(self, loss="vtrace", emit_trajectory=False, **agent_kwargs):
+        from scalable_agent_tpu.envs.device import make_device_env
+
+        env = make_device_env("fake_benchmark", height=16, width=24)
+        agent = ImpalaAgent(num_actions=env.num_actions, **agent_kwargs)
+        mesh = make_mesh(MeshSpec(data=1, model=1),
+                         devices=jax.devices()[:1])
+        learner = Learner(agent, LearnerHyperparams(
+            total_environment_frames=1e6), mesh,
+            frames_per_update=self.T * self.B, loss=loss)
+        return InGraphTrainer(agent, learner, env, self.T, self.B,
+                              seed=5, emit_trajectory=emit_trajectory)
+
+    def test_handed_slots_are_the_stem_of_the_trajectorys_frames(self):
+        from scalable_agent_tpu.models.networks import HANDOVER
+
+        trainer = self.make(conv_backend="pallas")
+        state, carry = trainer.init(jax.random.key(0))
+        trajectory, _, _, handed = jax.jit(trainer._rollout)(
+            state.params, carry.rollout, jax.random.key(1), carry.frames)
+        stem = handed["convnet"]["stem"]
+        assert stem.shape == (self.T + 1, self.B, 4, 6, 32)
+        _, sown = trainer._agent.apply(
+            state.params, trajectory.agent_outputs.action,
+            trajectory.env_outputs, trajectory.agent_state,
+            mutable=[HANDOVER])
+        want = np.asarray(sown[HANDOVER]["convnet"]["stem"]).reshape(
+            stem.shape)
+        assert np.abs(np.diff(want, axis=0)).max() > 0, "frames all alike"
+        np.testing.assert_allclose(stem, want, rtol=1e-5, atol=1e-6)
+
+    def test_an_agent_that_declares_nothing_hands_nothing(self):
+        trainer = self.make()       # XLA's stem: the default
+        state, carry = trainer.init(jax.random.key(0))
+        assert jax.eval_shape(
+            trainer._rollout, state.params, carry.rollout,
+            jax.random.key(1), carry.frames)[3] is None
+
+    @pytest.mark.parametrize("loss", ("vtrace", "impact"))
+    def test_only_the_fused_updates_own_forward_is_handed_anything(
+            self, monkeypatch, loss):
+        """Not ``_replay_step`` (other parameters acted), not the
+        IMPACT target's forward (other parameters), not the emitted
+        trajectory (it goes to both)."""
+        trainer = self.make(loss=loss, emit_trajectory=True,
+                            conv_backend="pallas")
+        forwards, updates = [], []
+        forward, update = Learner._forward, Learner._update_impl
+
+        def spy_forward(self, params, trajectory, capture=False,
+                        handed=None):
+            forwards.append(handed is not None)
+            return forward(self, params, trajectory, capture, handed)
+
+        def spy_update(self, state, trajectory, devtel, fresh=True,
+                       handed=None):
+            updates.append((fresh, handed is not None))
+            return update(self, state, trajectory, devtel, fresh, handed)
+
+        monkeypatch.setattr(Learner, "_forward", spy_forward)
+        monkeypatch.setattr(Learner, "_update_impl", spy_update)
+        state, carry = trainer.init(jax.random.key(0))
+        forwards.clear()
+        state, carry, _, trajectory = trainer.train_step(
+            state, carry, np.int32(0))
+        # the online forward, then (IMPACT) the target network's
+        assert forwards == [True, False][:1 + (loss == "impact")]
+        assert updates == [(True, True)]
+        assert isinstance(trajectory, Trajectory)
+        assert all(leaf.shape[-1] != 32 for leaf in
+                   jax.tree_util.tree_leaves(trajectory))
+        trainer.replay_step(state, carry.telemetry, trajectory)
+        assert updates[1:] == [(False, False)]
+        assert not any(forwards[2:])

@@ -460,6 +460,46 @@ class TestFrameRelayouts:
             text)
         assert registry.snapshot()["fused/frame_relayout_bytes"] == (
             expected)
+        # beside it, the hand-over's share: nothing traced a step into
+        # this registry
+        assert notes["stem_handed_share"] == 0.0
+
+    @pytest.mark.parametrize("conv_backend,share", [
+        ("pallas", 5 / 6), ("xla", 0.0)])
+    def test_scope_table_carries_the_share_tracing_the_step_set(
+            self, tmp_path, conv_backend, share):
+        """ISSUE 37: ``fused/stem_handed_share`` is set where the fused
+        step is traced — T of an update's T+1 slots where the agent's
+        acting steps hand their stem activation over, 0 where the agent
+        declares nothing — and the scope table's notes hold the same
+        number."""
+        import json
+
+        import numpy as np
+
+        from scalable_agent_tpu.envs.device import make_device_env
+        from scalable_agent_tpu.models import ImpalaAgent
+        from scalable_agent_tpu.obs import get_registry
+        from scalable_agent_tpu.parallel import MeshSpec, make_mesh
+        from scalable_agent_tpu.runtime import Learner, LearnerHyperparams
+        from scalable_agent_tpu.runtime.ingraph import InGraphTrainer
+
+        env = make_device_env("fake_benchmark", height=16, width=24)
+        agent = ImpalaAgent(num_actions=env.num_actions,
+                            conv_backend=conv_backend)
+        learner = Learner(
+            agent, LearnerHyperparams(), make_mesh(
+                MeshSpec(data=1, model=1), devices=jax.devices()[:1]),
+            frames_per_update=5 * 4)
+        trainer = InGraphTrainer(agent, learner, env, 5, 4)
+        gauge = get_registry().gauge("fused/stem_handed_share")
+        gauge.set(-1.0)
+        state, carry = jax.eval_shape(trainer.init, jax.random.key(0))
+        trainer.train_step.lower(state, carry, np.int32(0))
+        assert gauge.value == share
+        path = kernels_lib.write_op_scopes(
+            str(tmp_path / "trace.p0.7.json"), _FRAMES_CHANGE)
+        assert json.load(open(path))["notes"]["stem_handed_share"] == share
 
 
 class TestTraceJoin:

@@ -68,6 +68,43 @@ class TestRematPlacement:
             for remat in (False, True)}
         assert counts == {False: convs, True: convs + recomputed}
 
+    @pytest.mark.parametrize("remat", (False, True))
+    @pytest.mark.parametrize("loss,convs", [
+        # 7 less the stem's forward: 2 + 2 + 2
+        ("vtrace", 6),
+        # and the target network's own forward, stem and all: + 3
+        ("impact", 9)])
+    def test_the_handed_update_runs_no_stem_conv(self, loss, convs, remat):
+        """ISSUE 37: given the acting steps' stem activations, the
+        update's gradient holds exactly one convolution fewer — the
+        stem's forward — and the IMPACT target's forward, under other
+        parameters, keeps the stem conv it has."""
+        learner, state, traj = _make(True, loss=loss, remat_torso=remat,
+                                     conv_backend="pallas")
+        agent = learner._agent
+        assert agent.handover_collection == "handover"
+        frames = traj.env_outputs.observation.frame
+        handed = {"convnet": {"stem": jnp.zeros(
+            frames.shape[:2] + (HW // 4, HW // 4, 32), jnp.float32)}}
+
+        def count(**handed):
+            return str(jax.make_jaxpr(jax.grad(
+                lambda p: learner._loss(p, traj, state.params,
+                                        **handed)[0]))(state.params)
+                       ).count("conv_general_dilated")
+
+        assert (count(), count(handed=handed)) == (convs + 1, convs)
+
+    @pytest.mark.parametrize("agent_kwargs", [
+        dict(torso_type="resnet"), dict(torso_type="resnet",
+                                        conv_backend="pallas"),
+        dict(torso_type="shallow", conv_backend="xla"),
+        dict(torso_type="shallow", conv_backend="xla", remat_torso=True)],
+        ids=("resnet", "resnet-pallas", "shallow-xla", "shallow-xla-remat"))
+    def test_every_other_agent_hands_nothing(self, agent_kwargs):
+        assert ImpalaAgent(num_actions=NUM_ACTIONS,
+                           **agent_kwargs).handover_collection is None
+
     @pytest.mark.parametrize("torso_type", ("shallow", "resnet"))
     def test_the_default_program_holds_no_checkpoint(self, torso_type):
         """``remat_torso`` is off by default: the golden-loss anchor's

@@ -90,6 +90,40 @@ def test_fused_run_logs_the_recorded_losses(tmp_path, k):
     assert _checkpoint_steps(config.logdir) == [6]
 
 
+@pytest.mark.parametrize("k", [1, 2])
+def test_fused_run_behind_the_pallas_stem_is_the_run_that_hands_nothing(
+        tmp_path, monkeypatch, k):
+    """ISSUE 37: behind the Pallas stem (the interpreter here) the
+    rollout hands the update its stem activations and the update's
+    forward starts at conv_1 — the same function of the same
+    parameters, 256 images at a time where the update's own conv took
+    them all at once, so update by update the losses are those of the
+    run whose agent declares nothing, to float32 round-off.  (The
+    recorded run above resolves to XLA's stem off a TPU: nothing is
+    handed there, and its bits stand.)"""
+    from scalable_agent_tpu.models import ImpalaAgent
+
+    def losses(name):
+        config = _config(tmp_path / name, "ingraph", conv_backend="pallas",
+                         updates_per_dispatch=k)
+        metrics = driver.train(config)
+        assert metrics["env_frames"] == 6 * FRAMES_PER_UPDATE
+        return [(row["step"], row["total_loss"])
+                for row in _rows(config.logdir)]
+
+    assert ImpalaAgent(num_actions=3, conv_backend="pallas"
+                       ).handover_collection == "handover"
+    handed = losses("handed")
+    assert get_registry().gauge("fused/stem_handed_share").value == 4 / 5
+    monkeypatch.setattr(ImpalaAgent, "handover_collection", None)
+    plain = losses("plain")
+    assert get_registry().gauge("fused/stem_handed_share").value == 0.0
+    assert [step for step, _ in handed] == [step for step, _ in plain] == (
+        list(range(k, 7, k)))
+    assert [loss for _, loss in handed] == pytest.approx(
+        [loss for _, loss in plain], rel=1e-5)
+
+
 # The union of the host run's training-row keys, recorded at the parent
 # commit, less the ``episode_*`` ones (in a row only when an episode
 # ended inside its interval).
