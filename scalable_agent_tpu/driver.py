@@ -345,6 +345,12 @@ def build_token_policy(config: Config, action_space, frame_shape=None):
             ("cache/ring_readers", agent.ring_readers,
              "the most layers that read one ring: its own layer and the "
              "cross layers into it"),
+            ("cache/ring_bytes", agent.ring_bytes(config.batch_size),
+             "bytes of the largest one layer's ring"),
+            ("cache/latent_bytes_per_token", agent.latent_bytes_per_token,
+             "bytes a token a layer the rings hold where attention is "
+             "latent (one compressed row, every head's key and value); "
+             "0 where they hold whole keys and values"),
             ("ssm/state_bytes", agent.ssm_state_bytes(config.batch_size),
              "bytes of the state-space layers' recurrent states and "
              "convolution tails the rollout carries (float32)"),
@@ -355,16 +361,17 @@ def build_token_policy(config: Config, action_space, frame_shape=None):
     log.info(
         "kernel policy: backend=%s mesh_devices=%d policy=token family=%s "
         "model_config=%s layers=%d (%s) experts_held=%d/%d (first %d) "
-        "window_slots=%d full_slots=%d ring_readers=%d core_impl=%s "
-        "conv_backend=%s remat=%s compute_dtype=%s",
+        "window_slots=%d full_slots=%d ring_readers=%d "
+        "latent_bytes_per_token=%d core_impl=%s conv_backend=%s remat=%s "
+        "compute_dtype=%s",
         jax.default_backend(), _intended_mesh_size(config), family,
         config.model_config, model.num_hidden_layers,
         ", ".join(f"{model.layer_types.count(kind)} {kind}"
                   for kind in dict.fromkeys(model.layer_types)),
         model.experts_held, model.num_experts, model.first_expert,
         agent.window_slots, agent.full_slots, agent.ring_readers,
-        agent.core_impl, agent.conv_backend, agent.remat_placement,
-        config.compute_dtype)
+        agent.latent_bytes_per_token, agent.core_impl, agent.conv_backend,
+        agent.remat_placement, config.compute_dtype)
     return agent
 
 

@@ -235,6 +235,14 @@ register_device_level(
                 "after 3,584 positions, seven windows back, from an "
                 "eighth of a 200,064-token vocabulary")
 register_device_level(
+    "token_recall_10k",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=16032, episode_length=10240, period=6144),
+    description="the same world for a policy whose every layer keeps "
+                "the whole episode (a latent cache): episodes of 10,240 "
+                "tokens that repeat after 6,144 positions, from an "
+                "eighth of a 128,256-token vocabulary")
+register_device_level(
     "token_recall_small",
     "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
     dict(num_actions=64, episode_length=16, period=10),

@@ -1,12 +1,15 @@
 """A token policy: a decoder behind the agent's calling contract, its
 layers a mixer (attention through a ring of its own, attention into
 another layer's ring, a state-space scan, a gated memory unit) and an
-MLP (dense or experts), picked by the configuration file.  Two families
+MLP (dense or experts), picked by the configuration file.  Three families
 (``FAMILIES``): ``afmoe`` (window and full attention mixed, a mixture of
-experts with a shared expert) and ``phi4flash`` (the decoder-hybrid-
+experts with a shared expert), ``phi4flash`` (the decoder-hybrid-
 decoder: state-space and window layers, one full-attention layer whose
 cache every later cross layer reads, memory units gated by the last
-state-space layer's output).
+state-space layer's output) and ``deepseek_v3`` (latent attention: the
+cache holds one compressed row a token, which every head's key and
+value are up-projections of; a mixture of experts with shared experts
+behind leading dense layers).
 
 ``__call__(actions, env_outputs, state) -> ((policy_logits, baseline),
 state)`` over time-major ``[T, B]`` inputs, as ``ImpalaAgent`` has it:
@@ -14,7 +17,8 @@ T = 1 is acting and T = unroll is learning, from one definition.  The
 observation is a token id (``observation.frame``, int32), the action a
 token of the same vocabulary, and the agent's state is its attention
 cache (``TokenCache``): per layer that makes keys a ring of keys and
-values, each slot's index in the env's token stream beside it, where
+values (of latent rows where the family's attention is latent), each
+slot's index in the env's token stream beside it, where
 each env's episode began, and per state-space layer its recurrent state
 and the last inputs of its short convolution.  An episode's end clears
 no ring: a query sees a key of its own episode only (ops/attention.py),
@@ -56,6 +60,23 @@ tied head)::
                  out = concat(RMSNorm(o) * (1 - lambda_init)) W_o
     a cross layer projects q only: k, v and the ring are the full layer's
 
+The ``deepseek_v3`` layer (benchmark/references/deepseek_v3_token.py;
+``q_lora_rank`` null; plain pre-norm residuals, no embedding scale)::
+
+    a = RMSNorm_in(h);  q = a Wq [heads, nope | rope]
+    [c | r] = a Wkva [kv_lora_rank | rope];  c = RMSNorm_kv(c)
+    q_rope, r = RoPE(q_rope, r; theta, position in episode)  one r for all heads
+    [k_nope | v] = c Wkvb [heads, nope | v_head_dim]
+    attn_h = softmax((q_nope_h . k_nope_h + q_rope_h . r) / sqrt(nope + rope)) v_h
+    h = h + concat(attn) Wo;  h = h + MLP(RMSNorm_post(h))   dense, then experts
+
+The ring of such a layer holds ``[c | r]`` and nothing else.  Neither
+pass makes a past token's ``k_nope`` or ``v``: ``q_nope_h . k_nope_hj =
+(q_nope_h Wkvb_k,h^T) . c_j`` and ``sum_j p_j v_hj = (sum_j p_j c_j)
+Wkvb_v,h``, so the query takes the up-projection in before the cache is
+read and the weighted sum of rows takes it after
+(``ops/attention.py latent_attention``).
+
 The rings are sized so that ONE buffer serves the rollout and the
 update: ``window + unroll`` slots (``episode_length + unroll`` on a full
 layer) still hold, when an unroll ends, everything its first query may
@@ -85,21 +106,37 @@ FULL = "full_attention"
 CROSS = "cross_attention"       # queries only, into the last full layer's ring
 STATE_SPACE = "state_space"
 MEMORY_UNIT = "memory_unit"     # gated by the last state-space layer's output
-FAMILIES = ("afmoe", "phi4flash")
-_OWN_RING = (SLIDING, FULL)
+LATENT = "latent_attention"     # full attention through a ring of latent rows
+FAMILIES = ("afmoe", "phi4flash", "deepseek_v3")
+_OWN_RING = (SLIDING, FULL, LATENT)
 # the keys a family's file must have (the rest of the fields default)
 _ALWAYS = ("vocab_size", "hidden_size", "num_attention_heads",
-           "num_key_value_heads", "intermediate_size", "num_hidden_layers",
-           "sliding_window")
+           "intermediate_size", "num_hidden_layers")
+_WINDOWED = _ALWAYS + ("num_key_value_heads", "sliding_window")
 _REQUIRED = {
-    "afmoe": _ALWAYS + (
+    "afmoe": _WINDOWED + (
         "head_dim", "layer_types", "moe_intermediate_size", "num_experts",
         "num_experts_per_tok", "num_shared_experts", "num_dense_layers",
         "route_scale", "route_norm", "rope_theta", "rms_norm_eps",
         "mup_enabled", "experts_held"),
-    "phi4flash": _ALWAYS + (
+    "phi4flash": _WINDOWED + (
         "layer_kinds", "layer_norm_eps", "mamba_d_state", "mamba_d_conv",
         "mamba_expand", "mamba_dt_rank"),
+    "deepseek_v3": _ALWAYS + (
+        "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+        "moe_intermediate_size", "n_routed_experts", "n_shared_experts",
+        "num_experts_per_tok", "first_k_dense_replace",
+        "routed_scaling_factor", "norm_topk_prob", "rope_interleave",
+        "rope_theta", "rms_norm_eps", "experts_held"),
+}
+# the source's key for what another family's file calls otherwise: the
+# expert layer is one (ops/moe.py), and reads one set of names
+_SAID_AS = {
+    "deepseek_v3": (("n_routed_experts", "num_experts"),
+                    ("n_shared_experts", "num_shared_experts"),
+                    ("first_k_dense_replace", "num_dense_layers"),
+                    ("routed_scaling_factor", "route_scale"),
+                    ("norm_topk_prob", "route_norm")),
 }
 # what a family's file may not say otherwise
 _ONLY = {
@@ -107,6 +144,13 @@ _ONLY = {
               ("rope_scaling", None)),
     "phi4flash": (("hidden_act", "silu"), ("tie_word_embeddings", True),
                   ("mlp_bias", False), ("lm_head_bias", False)),
+    # the group limit of the choice is a no-op at one group, which is all
+    # that is built; a compressed query (q_lora_rank) is not
+    "deepseek_v3": (("hidden_act", "silu"), ("scoring_func", "sigmoid"),
+                    ("rope_scaling", None), ("q_lora_rank", None),
+                    ("tie_word_embeddings", False),
+                    ("attention_bias", False), ("n_group", 1),
+                    ("topk_group", 1), ("moe_layer_freq", 1)),
 }
 
 
@@ -115,7 +159,7 @@ class TokenModelConfig:
     """The sizes of the model as it is run, under the source's own key
     names, from one JSON file (``from_file``); keys it does not name are
     the file's own business (loss, optimizer, flags).  ``model_type``
-    says which family's keys the file has; the other family's stay at
+    says which family's keys the file has; the other families' stay at
     their defaults and nothing reads them."""
 
     vocab_size: int
@@ -128,7 +172,7 @@ class TokenModelConfig:
     layer_types: Tuple[str, ...]        # each layer's mixer
     sliding_window: int
     model_type: str = "afmoe"
-    # afmoe
+    # afmoe, and deepseek_v3 under its own names (``_SAID_AS``)
     moe_intermediate_size: int = 0
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -148,13 +192,25 @@ class TokenModelConfig:
     mamba_d_conv: int = 0
     mamba_expand: int = 0
     mamba_dt_rank: int = 0
+    # deepseek_v3
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
 
     @property
     def d_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
 
+    @property
+    def latent_dim(self) -> int:
+        """Numbers a token a layer in a latent ring: the normalised
+        compression and the one rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
     def is_expert_layer(self, layer: int) -> bool:
-        return self.model_type == "afmoe" and layer >= self.num_dense_layers
+        return self.num_experts > 0 and layer >= self.num_dense_layers
 
     def ring_of(self, layer: int) -> Optional[int]:
         """The layer whose ring ``layer`` attends into: its own, the last
@@ -196,6 +252,15 @@ class TokenModelConfig:
         names = [f.name for f in dataclasses.fields(cls)]
         values = {n: raw[n] for n in names if n in raw}
         values["model_type"] = family
+        values.update((field, raw[said])
+                      for said, field in _SAID_AS.get(family, ()))
+        if family == "deepseek_v3":
+            # every layer attends through its own latent ring, over the
+            # whole episode; whole keys and values exist nowhere, so
+            # their head count and width size nothing
+            values["layer_types"] = [LATENT] * raw["num_hidden_layers"]
+            values.update(sliding_window=0, num_key_value_heads=0,
+                          head_dim=0)
         if family == "phi4flash":
             values["layer_types"] = [k["kind"] for k in raw["layer_kinds"]]
             values["layer_index"] = tuple(
@@ -204,8 +269,8 @@ class TokenModelConfig:
                               // raw["num_attention_heads"])
         values["layer_types"] = tuple(values["layer_types"])
         model = cls(**values)
-        kinds = ((SLIDING, FULL) if family == "afmoe" else
-                 (SLIDING, FULL, CROSS, STATE_SPACE, MEMORY_UNIT))
+        kinds = {"afmoe": (SLIDING, FULL), "deepseek_v3": (LATENT,)}.get(
+            family, (SLIDING, FULL, CROSS, STATE_SPACE, MEMORY_UNIT))
         if len(model.layer_types) != model.num_hidden_layers or any(
                 kind not in kinds for kind in model.layer_types):
             if family == "afmoe":
@@ -215,7 +280,7 @@ class TokenModelConfig:
             raise ValueError(
                 f"token policy: layer_kinds must name one of {kinds} for "
                 f"each of num_hidden_layers")
-        if family == "afmoe" and not (
+        if model.num_experts and not (
                 0 <= model.first_expert and model.first_expert
                 + model.experts_held <= model.num_experts):
             raise ValueError(
@@ -254,8 +319,13 @@ class TokenCache(NamedTuple):
     """The token policy's state: what the next query attends back into
     and what the next token's recurrences continue from."""
 
-    keys: Tuple[Any, ...]       # per layer that makes keys, in order:
-    values: Tuple[Any, ...]     #   [B, slots, kv_heads, head_dim]
+    # per layer that makes keys, in order: [B, slots, kv_heads, head_dim]
+    # each; under latent attention ``keys`` holds the one ring, [B,
+    # latent_dim, full slots], a column a token that is every head's key
+    # and value, compressed (ops/attention.py has why a token is a
+    # column), and ``values`` is empty
+    keys: Tuple[Any, ...]
+    values: Tuple[Any, ...]
     window_index: Any           # i32 [window slots]: stream index by slot
     full_index: Any             # i32 [full slots]
     written: Any                # i32 []: tokens in every env's stream
@@ -365,9 +435,10 @@ class _MoE(nn.Module):
         model = self.model
         hidden = x.shape[-1]
         with jax.named_scope("router"):
-            kernel = _RouterKernel(model.num_experts, name="router")(hidden)
-            # ``load_balance_coeff`` moves this bias by a rule the source
-            # does not publish: it is held at 0 (a buffer, not a weight).
+            kernel = _Kernel(model.num_experts, name="router")(hidden)
+            # ``load_balance_coeff`` (``topk_method: noaux_tc``) moves
+            # this bias by a rule the source does not publish: it is
+            # held at 0 (a buffer, not a weight).
             routing = moe.route(
                 x, kernel, jnp.zeros((model.num_experts,), jnp.float32),
                 model.num_experts_per_tok, model.route_scale,
@@ -386,13 +457,16 @@ class _MoE(nn.Module):
         return shared + routed, stats
 
 
-class _RouterKernel(nn.Module):
-    experts: int
+class _Kernel(nn.Module):
+    """A matrix its caller multiplies by in a way of its own (the router
+    in float32; latent attention, a head's columns at a time)."""
+
+    features: int
 
     @nn.compact
-    def __call__(self, hidden: int):
+    def __call__(self, inputs: int):
         return self.param("kernel", nn.initializers.lecun_normal(),
-                          (hidden, self.experts))
+                          (inputs, self.features))
 
 
 def rope(x, position, theta: float):
@@ -405,6 +479,22 @@ def rope(x, position, theta: float):
     x = x.astype(jnp.float32)
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def rope_interleaved(x, position, theta: float):
+    """``rope`` over adjacent pairs: numbers 2i and 2i + 1 turn by the
+    angle ``rope`` gives numbers i and i + D / 2 (``rope_interleave``;
+    the same rotation of a permuted vector, so a model's scores are the
+    half-split ones' under the matching permutation of its weights'
+    columns)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = position.astype(jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape)
 
 
 class _Attention(nn.Module):
@@ -446,6 +536,60 @@ class _Attention(nn.Module):
         out = out * jax.nn.sigmoid(gate)
         return (_Linear(model.hidden_size, dtype, name="o_proj")(out),
                 ring_keys, ring_values, stats)
+
+
+class _LatentAttention(nn.Module):
+    """Latent attention with the up-projection absorbed (the module's
+    docstring): what the ring holds, and what this call hands the
+    kernels as its own keys and values, is the row ``[c | r]``."""
+
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, position, index, episode_start, ring, ring_index,
+                 written):
+        model, dtype = self.model, self.dtype
+        batch, count, _ = a.shape
+        heads, rank = model.num_attention_heads, model.kv_lora_rank
+        nope, turned = model.qk_nope_head_dim, model.qk_rope_head_dim
+        rotate = rope_interleaved if model.rope_interleave else rope
+        with jax.named_scope("latent"):
+            with jax.named_scope("project"):
+                query = _Linear(heads * (nope + turned), dtype,
+                                name="q_proj")(a).reshape(
+                                    batch, count, heads, nope + turned)
+                row = _Linear(rank + turned, dtype, name="kv_a_proj")(a)
+                latent = attention_lib.round_to(jnp.concatenate([
+                    _RMSNorm(model.rms_norm_eps, name="kv_a_norm")(
+                        row[..., :rank]),
+                    rotate(row[..., None, rank:], position,
+                           model.rope_theta)[..., 0, :]], axis=-1), dtype)
+                up = _Kernel(heads * (nope + model.v_head_dim),
+                             name="kv_b_proj")(rank).astype(dtype).reshape(
+                                 rank, heads, nope + model.v_head_dim)
+                # a head's scores against c_j through its own columns of
+                # Wkvb, taken in before the cache is read
+                query = attention_lib.round_to(jnp.concatenate([
+                    jnp.einsum(
+                        "bthd,rhd->bthr",
+                        attention_lib.round_to(query[..., :nope], dtype),
+                        up[..., :nope], preferred_element_type=jnp.float32),
+                    rotate(query[..., nope:], position, model.rope_theta)],
+                    axis=-1), dtype)
+            with jax.named_scope("attend"):
+                out, stats = attention_lib.latent_attention(
+                    query, latent, ring, ring_index, index, episode_start,
+                    rank, 1.0 / math.sqrt(nope + turned))
+                ring = attention_lib.latent_ring_write(ring, latent, written)
+            with jax.named_scope("project"):
+                # the weighted sum of rows, up-projected a head at a time
+                out = jnp.einsum(
+                    "bthr,rhd->bthd", attention_lib.round_to(out, dtype),
+                    up[..., nope:], preferred_element_type=jnp.float32)
+            return (_Linear(model.hidden_size, dtype, name="o_proj")(
+                out.reshape(batch, count, heads * model.v_head_dim)),
+                ring, stats)
 
 
 class _Handed(NamedTuple):
@@ -607,12 +751,13 @@ class _Layer(nn.Module):
                  ring_values, ring_index, written, handed, state, tail):
         model, dtype = self.model, self.dtype
         kind = model.layer_types[self.layer]
+        # the first family norms each branch's result too
         afmoe = model.model_type == "afmoe"
 
         def norm(name):
-            if afmoe:
-                return _RMSNorm(model.rms_norm_eps, name=name)
-            return _LayerNorm(model.layer_norm_eps, name=name)
+            if model.model_type == "phi4flash":
+                return _LayerNorm(model.layer_norm_eps, name=name)
+            return _RMSNorm(model.rms_norm_eps, name=name)
 
         seen = {}                   # what an attention pass says of itself
         a = norm("input_norm")(h)
@@ -621,6 +766,11 @@ class _Layer(nn.Module):
                 model, kind == SLIDING, dtype, name="attention")(
                     a, position, index, episode_start, ring_keys,
                     ring_values, ring_index, written)
+        elif kind == LATENT:
+            mixed, ring_keys, seen = _LatentAttention(
+                model, dtype, name="attention")(
+                    a, position, index, episode_start, ring_keys, ring_index,
+                    written)
         elif kind == STATE_SPACE:
             mixed, memory, state, tail = _StateSpace(
                 model, dtype, name="ssm")(a, position, state, tail)
@@ -703,6 +853,7 @@ _TELEMETRY = {
         ("attention/key_blocks_visited_share",
          "attention/decode_key_blocks_visited_share")),
 }
+_TELEMETRY["deepseek_v3"] = _TELEMETRY["afmoe"]
 
 
 class TokenPolicy(nn.Module):
@@ -799,7 +950,32 @@ class TokenPolicy(nn.Module):
 
     @property
     def full_slots(self) -> int:
-        return self.episode_length + self.unroll_length
+        slots = self.episode_length + self.unroll_length
+        if self._latent_row_bytes:
+            # whole blocks of the decode's own size
+            return attention_lib.latent_ring_slots(
+                slots, self._latent_row_bytes)
+        return slots
+
+    @property
+    def _latent_row_bytes(self) -> int:
+        """Bytes of one compressed row; 0 where attention is not latent."""
+        return (self.model.latent_dim
+                * jnp.dtype(self.compute_dtype).itemsize)
+
+    @property
+    def latent_bytes_per_token(self) -> int:
+        """Bytes a token a layer the rings hold where attention is
+        latent, read off the state's own arrays (every ring, keys and
+        values alike, over envs x slots x layers): whatever a later
+        change keeps beside the compressed row counts.  0 where the
+        rings hold whole keys and values."""
+        if not self._latent_row_bytes:
+            return 0
+        state = jax.eval_shape(lambda: self.initial_state(1))
+        held = sum(ring.size * ring.dtype.itemsize
+                   for ring in state.keys + state.values)
+        return held // (self.full_slots * len(self._ring_layers))
 
     def _slots(self, layer: int) -> int:
         return (self.window_slots
@@ -831,22 +1007,29 @@ class TokenPolicy(nn.Module):
 
     def initial_state(self, batch: int) -> TokenCache:
         model = self.model
-        if model.model_type == "afmoe":
-            heads, dim = model.num_key_value_heads, model.head_dim
-        else:       # a pair of heads is one of twice the width
+        latent = bool(model.latent_dim)
+        if model.model_type == "phi4flash":
+            # a pair of heads is one of twice the width
             heads, dim = model.num_key_value_heads // 2, 2 * model.head_dim
+        else:
+            heads, dim = model.num_key_value_heads, model.head_dim
 
         def ring(layer):
-            return jnp.zeros((batch, self._slots(layer), heads, dim),
-                             self.compute_dtype)
+            slots = self._slots(layer)
+            return jnp.zeros(
+                (batch, model.latent_dim, slots) if latent
+                else (batch, slots, heads, dim), self.compute_dtype)
+
+        rings = tuple(ring(layer) for layer in self._ring_layers)
 
         def per_scan(rows):
             return tuple(jnp.zeros((batch, rows, model.d_inner), jnp.float32)
                          for _ in self._scan_layers)
 
         return TokenCache(
-            keys=tuple(ring(layer) for layer in self._ring_layers),
-            values=tuple(ring(layer) for layer in self._ring_layers),
+            keys=rings,
+            values=() if latent else tuple(
+                ring(layer) for layer in self._ring_layers),
             window_index=jnp.full((self.window_slots,),
                                   attention_lib.NO_KEY, jnp.int32),
             full_index=jnp.full((self.full_slots,),
@@ -871,11 +1054,19 @@ class TokenPolicy(nn.Module):
                             conv_tail=start.conv_tail)
 
     def cache_bytes(self, batch: int) -> int:
+        return sum(self.ring_bytes(batch, layer)
+                   for layer in self._ring_layers)
+
+    def ring_bytes(self, batch: int, layer: Optional[int] = None) -> int:
+        """Bytes of ``layer``'s ring (of the largest ring, for none)."""
+        if layer is None:
+            return max(self.ring_bytes(batch, layer)
+                       for layer in self._ring_layers)
         model = self.model
-        per_slot = (2 * model.num_key_value_heads * model.head_dim
-                    * jnp.dtype(self.compute_dtype).itemsize)
-        return batch * per_slot * sum(
-            self._slots(layer) for layer in self._ring_layers)
+        per_slot = self._latent_row_bytes or (
+            2 * model.num_key_value_heads * model.head_dim
+            * jnp.dtype(self.compute_dtype).itemsize)
+        return batch * per_slot * self._slots(layer)
 
     def ssm_state_bytes(self, batch: int) -> int:
         """The scans' states and the convolutions' tails, float32."""
@@ -932,6 +1123,7 @@ class TokenPolicy(nn.Module):
 
         ring_index = {SLIDING: before(state.window_index),
                       FULL: before(state.full_index)}
+        ring_index[LATENT] = ring_index[FULL]
         # Learning keeps one layer's residuals at a time: the sorted
         # pairs' buffers of an expert layer are 1 GB at 8,224 tokens,
         # and the float32 activations between matmuls 67 MB apiece.
@@ -953,14 +1145,17 @@ class TokenPolicy(nn.Module):
                 layer_cls(model, layer, dtype, name=f"layer_{layer}")(
                     h, position, index, start,
                     None if ring is None else state.keys[ring],
-                    None if ring is None else state.values[ring],
+                    None if ring is None or kind == LATENT
+                    else state.values[ring],
                     None if reads is None
                     else ring_index[model.layer_types[reads]],
                     written, handed,
                     None if scan is None else ssm_state[scan],
                     None if scan is None else conv_tail[scan]))
             if kind in _OWN_RING:
-                keys[ring], values[ring] = ring_keys, ring_values
+                keys[ring] = ring_keys
+                if kind != LATENT:
+                    values[ring] = ring_values
             if scan is not None:
                 ssm_state[scan], conv_tail[scan] = scanned, tail
             stats.append(layer_stats)
@@ -974,17 +1169,18 @@ class TokenPolicy(nn.Module):
                          jnp.mean(jnp.stack(said)),
                          init_fn=lambda: 0.0, reduce_fn=lambda _, new: new)
 
-        if model.model_type == "afmoe":
-            z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
-        else:
+        tied = model.model_type == "phi4flash"
+        if tied:
             z = _LayerNorm(model.layer_norm_eps, name="final_norm")(h)
-        z = jnp.swapaxes(z, 0, 1)                         # [T, B, hidden]
-        if model.model_type == "afmoe":
-            policy_logits = _Linear(model.vocab_size, dtype,
-                                    name="policy_logits")(z)
         else:
+            z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
+        z = jnp.swapaxes(z, 0, 1)                         # [T, B, hidden]
+        if tied:
             with jax.named_scope("policy_logits"):
                 policy_logits = tied_logits(z, table, dtype)
+        else:
+            policy_logits = _Linear(model.vocab_size, dtype,
+                                    name="policy_logits")(z)
         baseline = _Baseline(dtype, name="baseline")(z)
         new_state = TokenCache(
             keys=tuple(keys), values=tuple(values),

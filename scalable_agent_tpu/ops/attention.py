@@ -395,15 +395,12 @@ def _kernel(operands, residuals=(), *, backward=False, interpret, streams=1):
         interpret=interpret)(*operands, *residuals)
 
 
-def _operands(query, key, value, ring_keys, ring_values, ring_index, index,
-              episode_start, visit, window):
-    """What both kernels read, in the order their blocks want: (visit,
-    fetch, q [B, kv, D, R], low / high [B, 1, R], the ring and the own
-    keys and values head-major, [B, kv, S, D] and [B, kv, K, D], each
-    with its index [S, 1] / [K, 1]).  A query is a lane (lane = g * T +
-    t); padded queries repeat the last one's bounds.  Head-major is the
-    order the compiled step keeps the rings in (its decode's products
-    want it), so their transpose is a bitcast there, not a copy."""
+def _query_operands(query, index, episode_start, visit, window):
+    """What a blockwise kernel of any cache reads of the block list and
+    the queries: (visit, fetch, q [B, kv, D, R], low / high [B, 1, R]),
+    and the own keys' index [K, 1] with K, the own keys padded to whole
+    lanes.  A query is a lane (lane = g * T + t); padded queries repeat
+    the last one's bounds."""
     batch, queries, kv, group, dim = query.shape
     rows = _round_up(group * queries, _LANES)
     own = _round_up(queries, _LANES)
@@ -423,16 +420,31 @@ def _operands(query, key, value, ring_keys, ring_values, ring_index, index,
         return jnp.pad(x, ((0, 0), (0, rows - group * queries)),
                        mode="edge")[:, None, :]
 
+    own_index = jnp.pad(index, (0, own - queries),
+                        constant_values=_FAR)[:, None]
+    return ((visit.astype(jnp.int32).reshape(-1), fetch.reshape(-1),
+             _to_lanes(query), per_query(low), per_query(high)),
+            own_index, own)
+
+
+def _operands(query, key, value, ring_keys, ring_values, ring_index, index,
+              episode_start, visit, window):
+    """What both kernels read, in the order their blocks want:
+    ``_query_operands``, then the ring and the own keys and values
+    head-major, [B, kv, S, D] and [B, kv, K, D], each with its index
+    [S, 1] / [K, 1].  Head-major is the order the compiled step keeps
+    the rings in (its decode's products want it), so their transpose is
+    a bitcast there, not a copy."""
+    queries = query.shape[1]
+    shared, own_index, own = _query_operands(query, index, episode_start,
+                                             visit, window)
+
     def keys(x):                         # [B, T, kv, D] -> [B, kv, K, D]
         return jnp.pad(_head_major(x),
                        ((0, 0), (0, 0), (0, own - queries), (0, 0)))
 
-    own_index = jnp.pad(index, (0, own - queries),
-                        constant_values=_FAR)[:, None]
-    return (visit.astype(jnp.int32).reshape(-1), fetch.reshape(-1),
-            _to_lanes(query), per_query(low), per_query(high),
-            _head_major(ring_keys), _head_major(ring_values),
-            ring_index[:, None], keys(key), keys(value), own_index)
+    return shared + (_head_major(ring_keys), _head_major(ring_values),
+                     ring_index[:, None], keys(key), keys(value), own_index)
 
 
 def _head_major(x):
@@ -699,13 +711,15 @@ def cached_attention(query, key, value, ring_keys, ring_values, ring_index,
         ring_index, index, episode_start, window,
         _decode_block(slots, kv * dim * ring_keys.dtype.itemsize))
 
-    def share(visit):       # of the ring's blocks and one more, always seen
-        seen = jnp.mean(visit.astype(jnp.float32))
-        return (seen * visit.shape[-1] + 1.0) / (visit.shape[-1] + 1)
-
     return (out.reshape(batch, queries, heads * streams * dim),
-            {"key_blocks_visited_share": share(visit),
-             "decode_key_blocks_visited_share": share(decode)})
+            {"key_blocks_visited_share": _visited_share(visit),
+             "decode_key_blocks_visited_share": _visited_share(decode)})
+
+
+def _visited_share(visit):
+    """Of the ring's blocks and one more (the own keys), always seen."""
+    seen = jnp.mean(visit.astype(jnp.float32))
+    return (seen * visit.shape[-1] + 1.0) / (visit.shape[-1] + 1)
 
 
 def ring_write(ring, new, written):
@@ -726,3 +740,403 @@ def index_write(ring_index, written, count: int):
     ``written``."""
     new = written + jnp.arange(count, dtype=jnp.int32)
     return ring_index.at[new % ring_index.shape[0]].set(new)
+
+
+# -- a latent cache: one row a token is every head's key and value ------------
+#
+# Latent attention (MLA) keeps one row of ``D`` numbers a token a layer:
+# ``value_dim`` normalised ones, which every head's no-position key and
+# value are up-projections of, and the one rotated key all heads share.
+# With the up-projection absorbed into the query and taken out of the
+# weighted sum (the caller's two products), a head's key IS the row and
+# its value the row's first ``value_dim`` numbers: one key/value "head"
+# under a group of every query head, a key wider than the value, and a
+# softmax scale that is neither width's.  Both passes below take that
+# form, so no key or value of a past token is ever made, in HBM or in
+# VMEM; a block of the ring is fetched once and serves as both.  The
+# block lists and the masks are the ones above.
+#
+# The ring lies ``[B, D, slots]``, a token a column: a row of 576
+# numbers is no whole number of lanes, and the chip keeps a ``[B, slots,
+# 576]`` buffer slots-minor whatever the program says, so a kernel that
+# wants it rows-major pays a copy of every ring into the step and one
+# out of it (2 GB more at the peak; AOT for a v5e, PR 38).  Keys along
+# the lanes is also how the decode's scores lie.
+
+_LATENT_LANES = 2304            # query lanes (heads x queries) a grid step of
+                                # the update: its scores are [block, lanes]
+
+
+def _latent_scores(q, rows, key_index, low, high, scale):
+    """``_scores`` of a block of latent rows that lie a token a column:
+    q [D, R], rows [D, K], key_index [K, 1], low / high [1, R] -> [K,
+    R]."""
+    s = jax.lax.dot_general(rows, q, _TN,
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where((key_index >= low) & (key_index <= high), s, _MASKED)
+
+
+def _latent_forward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref,
+                           ring_ref, ri_ref, own_ref, oi_ref, out_ref,
+                           lse_ref, m_ref, l_ref, acc_ref, *, scale, blocks,
+                           value_dim):
+    del fetch_ref
+    env, step = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(rows_ref, index_ref):
+        rows = rows_ref[...]                                 # [D, K]
+        s = _latent_scores(q_ref[...], rows, index_ref[...], low_ref[...],
+                           high_ref[...], scale)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            rows[:value_dim], p.astype(rows.dtype), _NN,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
+    def _():
+        block(ring_ref, ri_ref)
+
+    @pl.when(step == blocks)
+    def _():
+        block(own_ref, oi_ref)
+        out_ref[...] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+
+
+def _latent_backward_kernel(visit_ref, fetch_ref, q_ref, low_ref, high_ref,
+                            ring_ref, ri_ref, own_ref, oi_ref, out_ref,
+                            lse_ref, do_ref, dq_ref, dl_ref, delta_ref,
+                            acc_ref, *, scale, blocks, value_dim):
+    del fetch_ref
+    env, step = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _():
+        delta_ref[...] = jnp.sum(
+            out_ref[...].astype(jnp.float32)
+            * do_ref[...].astype(jnp.float32), axis=0, keepdims=True)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(rows_ref, index_ref):
+        """(the weights, d scores) of one block of rows, both in the
+        compute dtype, after adding its part of d query."""
+        rows = rows_ref[...]                                 # [D, K]
+        s = _latent_scores(q_ref[...], rows, index_ref[...], low_ref[...],
+                           high_ref[...], scale)
+        p = jnp.exp(s - lse_ref[...])
+        dp = jax.lax.dot_general(rows[:value_dim], do_ref[...], _TN,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[...]) * scale).astype(rows.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            rows, ds, _NN, preferred_element_type=jnp.float32)
+        return p.astype(rows.dtype), ds
+
+    @pl.when((step < blocks) & (visit_ref[_flat(env, step, blocks)] == 1))
+    def _():
+        block(ring_ref, ri_ref)
+
+    @pl.when(step == blocks)
+    def _():
+        p, ds = block(own_ref, oi_ref)
+        # an own row is a key and, in its first numbers, a value: one
+        # array collects both cotangents
+        dl_ref[...] = jax.lax.dot_general(
+            q_ref[...], ds, _NT, preferred_element_type=jnp.float32)
+        dl_ref[:value_dim] += jax.lax.dot_general(
+            do_ref[...], p, _NT, preferred_element_type=jnp.float32)
+        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("backward", "interpret", "value_dim", "scale"))
+def _latent_kernel(operands, residuals=(), *, backward=False, interpret,
+                   value_dim, scale):
+    """One of the two latent kernels over ``_latent_operands`` (the
+    backward one also over out, log-sum-exp and d out, queries along
+    lanes), grid (env, tile of query heads, key step): the ring's
+    blocks, then the own rows.  Every tile of heads reads the same
+    rows.  What the caller rounds to the compute dtype at once (out, d
+    query) leaves the kernel in it: at 32 heads of 576 a float32 copy is
+    0.6 GB a layer."""
+    q, ring, own_rows = operands[2], operands[5], operands[7]
+    batch, tiles, dim, rows = q.shape
+    own = own_rows.shape[2]
+    blocks = operands[0].shape[0] // batch
+    block = ring.shape[2] // blocks
+
+    def ring_block(env, step, fetch):
+        return fetch[_flat(env, step, blocks)]
+
+    def fixed(*shape):                   # one block an (env, tile)
+        return pl.BlockSpec((None, None) + shape,
+                            lambda e, h, s, *_: (e, h, 0, 0))
+
+    per_query = pl.BlockSpec((None, 1, rows), lambda e, h, s, *_: (e, 0, 0))
+    in_specs = [
+        fixed(dim, rows), per_query, per_query,
+        pl.BlockSpec((None, dim, block), lambda e, h, s, visit, fetch:
+                     (e, 0, ring_block(e, s, fetch))),
+        pl.BlockSpec((block, 1), lambda e, h, s, visit, fetch:
+                     (ring_block(e, s, fetch), 0)),
+        pl.BlockSpec((None, dim, own), lambda e, h, s, *_: (e, 0, 0)),
+        pl.BlockSpec((own, 1), lambda e, h, s, *_: (0, 0))]
+
+    def result(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct((batch, tiles) + shape, dtype)
+
+    if backward:
+        kernel, name = _latent_backward_kernel, "latent_update_bwd"
+        in_specs += [fixed(value_dim, rows), fixed(1, rows),
+                     fixed(value_dim, rows)]
+        out_specs = [fixed(dim, rows), fixed(dim, own)]
+        out_shape = [result(dim, rows, dtype=q.dtype), result(dim, own)]
+        scratch = [pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((dim, rows), jnp.float32)]
+    else:
+        kernel, name = _latent_forward_kernel, "latent_update_fwd"
+        out_specs = [fixed(value_dim, rows), fixed(1, rows)]
+        out_shape = [result(value_dim, rows, dtype=q.dtype), result(1, rows)]
+        scratch = [pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((1, rows), jnp.float32),
+                   pltpu.VMEM((value_dim, rows), jnp.float32)]
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, blocks=blocks,
+                          value_dim=value_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(batch, tiles, blocks + 1),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=name)(*operands, *residuals)
+
+
+def _head_tile(heads: int, queries: int) -> int:
+    """Query heads a grid step of the update: the most that divide
+    ``heads`` and keep the step's lanes within ``_LATENT_LANES``."""
+    fit = [tile for tile in range(1, heads + 1)
+           if heads % tile == 0
+           and _round_up(tile * queries, _LANES) <= _LATENT_LANES]
+    return max(fit, default=1)
+
+
+def _latent_operands(query, latent, ring, ring_index, index, episode_start,
+                     visit):
+    """``_operands`` for the latent kernels: ``_query_operands`` (q [B,
+    tiles, D, R]), then the ring [B, D, S] as it lies and the own rows
+    [B, D, K], each with its index."""
+    queries = query.shape[1]
+    shared, own_index, own = _query_operands(query, index, episode_start,
+                                             visit, None)
+    return shared + (ring, ring_index[:, None],
+                     jnp.pad(jnp.swapaxes(latent, 1, 2),
+                             ((0, 0), (0, 0), (0, own - queries))),
+                     own_index)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _latent_blockwise(query, latent, ring, ring_index, index, episode_start,
+                      visit, interpret, value_dim, scale):
+    """Latent attention for any number of queries, with no score outside
+    VMEM: query [B, T, tiles, g, D], the call's own rows ``latent`` [B,
+    T, D], ``ring`` [B, D, S] -> [B, T, tiles, g, value_dim]."""
+    return _latent_blockwise_fwd(query, latent, ring, ring_index, index,
+                                 episode_start, visit, interpret, value_dim,
+                                 scale)[0]
+
+
+def _latent_blockwise_fwd(query, latent, ring, ring_index, index,
+                          episode_start, visit, interpret, value_dim, scale):
+    operands = _latent_operands(query, latent, ring, ring_index, index,
+                                episode_start, visit)
+    out, lse = _latent_kernel(operands, interpret=interpret,
+                              value_dim=value_dim, scale=scale)
+    return (_from_lanes(out, query.shape[1], query.shape[3]),
+            (operands, out, lse))
+
+
+def _latent_blockwise_bwd(interpret, value_dim, scale, saved, d_out):
+    operands, out, lse = saved
+    queries, group = d_out.shape[1], d_out.shape[3]
+    dtype = operands[2].dtype
+    dq, dl = _latent_kernel(
+        operands, (out, lse, _to_lanes(d_out.astype(dtype))),
+        backward=True, interpret=interpret, value_dim=value_dim, scale=scale)
+    # every tile of heads read the own rows; the ring is the agent's
+    # state: nothing differentiates it
+    own = jnp.swapaxes(jnp.sum(dl, axis=1)[..., :queries], 1, 2)
+    return (_from_lanes(dq, queries, group),
+            own.astype(dtype)) + (None,) * 5
+
+
+_latent_blockwise.defvjp(_latent_blockwise_fwd, _latent_blockwise_bwd)
+
+
+def _latent_decode_kernel(order_ref, visit_ref, low_ref, high_ref, steps_ref,
+                          q_ref, own_ref, ring_ref, ri_ref, out_ref, m_ref,
+                          l_ref, acc_ref, *, scale, blocks, value_dim):
+    """``_decode_kernel`` over latent rows: every query head of an env
+    is a row of q [R, D], a block of the ring [D, K] is the keys and, in
+    its first numbers, the values."""
+    i = pl.program_id(0)
+    pair = order_ref[i]
+    env = pair // blocks
+    first = (i == 0) | (order_ref[jnp.maximum(i - 1, 0)] // blocks != env)
+    last = ((i == steps_ref[0] - 1)
+            | (order_ref[jnp.minimum(i + 1, steps_ref[0] - 1)] // blocks
+               != env))
+
+    @pl.when(first)
+    def _():
+        q = q_ref[...].astype(jnp.float32)
+        own = own_ref[...]                                   # [1, D]
+        m_ref[...] = jnp.sum(q * own, axis=-1, keepdims=True) * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(own[:, :value_dim], acc_ref.shape)
+
+    @pl.when(visit_ref[pair] == 1)
+    def _():
+        rows = ring_ref[...]                                 # [D, K]
+        s = jax.lax.dot_general(q_ref[...], rows, _NN,
+                                preferred_element_type=jnp.float32) * scale
+        key_index = ri_ref[...]                              # [1, K]
+        seen = (key_index >= low_ref[env]) & (key_index <= high_ref[0])
+        s = jnp.where(seen, s, _MASKED)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)          # 0 where masked: m is a real score
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:value_dim], _NT,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(last)
+    def _():
+        out_ref[...] = acc_ref[...] / l_ref[...]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "value_dim", "scale"))
+def _latent_decode(query, latent, ring, ring_index, index, episode_start, *,
+                   interpret, value_dim, scale):
+    """Latent attention for one query an env (query [B, 1, H, D]),
+    reading only the ring blocks ``decode_visits`` names, each once."""
+    batch, _, heads, dim = query.shape
+    slots = ring.shape[2]
+    dtype = ring.dtype
+    block = _decode_block(slots, dim * dtype.itemsize)
+    blocks = slots // block
+    visit = decode_visits(ring_index, index, episode_start, None,
+                          block)[:, 0]
+    order, steps = _decode_order(visit)
+    low, high = _bounds(index, episode_start, None)
+    rows = _round_up(heads, 32 // dtype.itemsize)    # whole sublane tiles
+    q = jnp.pad(query[:, 0], ((0, 0), (0, rows - heads), (0, 0)))
+
+    def per_env(*shape):
+        return pl.BlockSpec(
+            (None,) + shape,
+            lambda i, order, *_: (order[i] // blocks,) + (0,) * len(shape))
+
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, blocks=blocks, scale=scale,
+                          value_dim=value_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(steps[0],),
+            in_specs=[per_env(rows, dim), per_env(1, dim),
+                      pl.BlockSpec((None, dim, block), lambda i, order, *_:
+                                   (order[i] // blocks, 0,
+                                    order[i] % blocks)),
+                      pl.BlockSpec((1, block), lambda i, order, *_:
+                                   (0, order[i] % blocks))],
+            out_specs=per_env(rows, value_dim),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, value_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((batch, rows, value_dim),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="latent_decode")(
+            order, visit.astype(jnp.int32).reshape(-1), low[:, 0], high,
+            steps, q, latent.astype(jnp.float32), ring, ring_index[None, :])
+    return out[:, None, :heads]
+
+
+def latent_ring_slots(needed: int, slot_bytes: int) -> int:
+    """The slots of a latent ring that has to hold ``needed``: the next
+    multiple of the most lane tiles a decode grid step may bring
+    (``_DECODE_BLOCK_BYTES``), so that a step brings that many.  A row
+    of 1,152 bytes has no power of two of them in a MiB, and a ring of
+    just ``needed`` slots may share few lane tiles with any block (82
+    tiles: blocks of 2), each grid step at its fixed cost.  A ring one
+    step brings whole stays as it is."""
+    most = _DECODE_BLOCK_BYTES // slot_bytes // _LANES * _LANES
+    return needed if needed <= most else _round_up(needed, most)
+
+
+def latent_attention(query, latent, ring, ring_index, index, episode_start,
+                     value_dim: int, scale: float):
+    """``cached_attention`` through a cache of latent rows: ``query`` [B,
+    T, heads, D] (a head's up-projection absorbed: its part over the
+    row's first ``value_dim`` numbers, its rotated part over the rest)
+    and this call's own rows ``latent`` [B, T, D] against themselves and
+    the ring [B, D, S] (a token a column) -> ([B, T, heads, value_dim]:
+    each head's weighted sum of rows' first ``value_dim`` numbers, for
+    the caller to up-project — float32 from the decode, the compute
+    dtype from the update, whose caller rounds it at once; the pass's
+    visited shares, as ``cached_attention`` gives them).  Every layer of
+    such a cache is a full one (no window).  ``scale`` multiplies the
+    scores (the model's own key width's, which no width here is).  The
+    own rows get the cotangent of both their uses; the ring's are
+    constants."""
+    from scalable_agent_tpu.parallel.mesh import pallas_interpret
+
+    batch, queries, heads, dim = query.shape
+    slots = ring.shape[2]
+    if queries == 1:
+        return _latent_decode(
+            query, latent, ring, ring_index, index, episode_start,
+            interpret=pallas_interpret(), value_dim=value_dim,
+            scale=scale), {}
+    visit = visited_blocks(ring_index, index, episode_start, None,
+                           _key_block(slots))
+    tile = _head_tile(heads, queries)
+    out = _latent_blockwise(
+        query.reshape(batch, queries, heads // tile, tile, dim), latent,
+        jax.lax.stop_gradient(ring), ring_index, index, episode_start,
+        visit, pallas_interpret(), value_dim, scale)
+    decode = decode_visits(
+        ring_index, index, episode_start, None,
+        _decode_block(slots, dim * ring.dtype.itemsize))
+    return (out.reshape(batch, queries, heads, value_dim),
+            {"key_blocks_visited_share": _visited_share(visit),
+             "decode_key_blocks_visited_share": _visited_share(decode)})
+
+
+def latent_ring_write(ring, rows, written):
+    """``ring_write`` into a latent ring [B, D, S]: ``rows`` [B, T, D] in
+    the columns of stream indices ``written .. written + T - 1``."""
+    slots = ring.shape[2]
+    count = rows.shape[1]
+    columns = jnp.swapaxes(round_to(rows, ring.dtype), 1, 2)
+    if count == 1:
+        return jax.lax.dynamic_update_slice_in_dim(
+            ring, columns, written % slots, axis=2)
+    at = (written + jnp.arange(count, dtype=jnp.int32)) % slots
+    return ring.at[:, :, at].set(columns)
+

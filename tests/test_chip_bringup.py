@@ -650,6 +650,54 @@ class TestAotCompileForV5e:
         assert largest < envs * slots * min(heads * streams, kv * dim), (
             largest, slots)
 
+    @pytest.mark.parametrize("queries,calls", [(257, 2), (1, 1)],
+                             ids=["update", "decode"])
+    def test_latent_attention_compiles_with_no_whole_key_in_hbm(
+            self, monkeypatch, v5e_topology, queries, calls):
+        """ISSUE 38: the latent kernels (ops/attention.py
+        ``latent_attention``) at ``kanana2.ingraph``'s widths — 32 query
+        heads over a ring of 10,752 rows of 512 + 64 numbers, 257
+        queries an env forward and backward (two Mosaic calls) and one
+        query of 32 envs (one) — compiled alone for a v5e (a row is no
+        whole number of lanes: the interpreter never sees the tiling
+        rule): no float32 result as large as one env's scores (the envs' at one
+        query), and nothing as large as the envs' up-projected keys (slots x 32
+        heads x 128 an env) in any dtype."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import attention
+
+        _as_tpu(monkeypatch)
+        envs = 2 if queries > 1 else 32
+        heads, dim, value_dim = 32, 576, 512
+        slots = attention.latent_ring_slots(10240 + 256, dim * 2)
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def attend(query, latent, *cache):
+            out, _ = attention.latent_attention(
+                query, latent, *cache, value_dim, 192 ** -0.5)
+            return jnp.sum(out) if queries > 1 else out
+
+        fn = jax.grad(attend, (0, 1)) if queries > 1 else attend
+        text = jax.jit(fn).lower(
+            operand((envs, queries, heads, dim)),
+            operand((envs, queries, dim)), operand((envs, dim, slots)),
+            operand((slots,), jnp.int32), operand((queries,), jnp.int32),
+            operand((envs, queries), jnp.int32)).compile().as_text()
+        assert text.count("tpu_custom_call") == calls
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        scores = (envs if queries == 1 else 1) * queries * heads * slots
+        assert largest < scores, (largest, scores)
+        anything = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= \w+\[([\d,]+)\]", text))
+        assert anything < envs * slots * heads * 128, anything
+
     def test_selective_scan_compiles_with_no_state_a_token_in_hbm(
             self, monkeypatch, v5e_topology):
         """ISSUE 34: the selective-scan kernels (ops/ssm.py, T > 1) at

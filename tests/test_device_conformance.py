@@ -33,6 +33,7 @@ CONFORMANCE_LEVELS = (
     "fake_memory",
     "fake_small",
     "token_recall",
+    "token_recall_10k",
     "token_recall_long",
     "token_recall_small",
 )

@@ -1,0 +1,435 @@
+"""The token policy of the ``deepseek_v3`` family (models/token_policy.py:
+latent attention through a ring of one compressed row a token, the
+up-projection absorbed; shared experts and routed ones behind a leading
+dense layer) against its plain reference
+(benchmark/references/deepseek_v3_token.py: whole keys and values
+up-projected for every token), at a tiny preset: hidden 64, 4 heads of
+8 + 4 (a row of 16 + 4), values of 8, 8 experts of which 2 are held, 2
+a token, 2 shared, vocabulary 64, unroll 6, episodes of 16, seeded
+weights, one dense layer and two expert layers.
+
+(a) one T = unroll forward, the loss and every leaf's gradient against
+    the reference in float32 (1e-5), and in bfloat16 inside a band an
+    fp8 cast falls out of; the planted fault moves the loss;
+(b) acting step by step through the rings gives the logits of a whole
+    forward, across episode ends and the rings' wrap, and of chunked
+    forwards;
+(c) the state is a ring of rows a layer, and nothing as large as a
+    past token's whole keys is in the lowered update or decode step;
+    ``unroll_state``: the rings of the unroll's end;
+(d) interleaved rotary pairs are the half-split ones on permuted
+    weights;
+(e) the share tied to the model: the four shares' routed parts and the
+    shared experts once are the uncut layer (the test is
+    tests/test_token_policy.py's, a case a family).
+
+The driver, the world, the configuration file and the benchmark's
+harness at this preset are in tests/test_kanana_harness.py.
+"""
+
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for path in (ROOT, os.path.join(ROOT, "tests")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+from benchmark.lib import manifest  # noqa: E402
+from scalable_agent_tpu.models import token_policy  # noqa: E402
+from scalable_agent_tpu.models.token_policy import (  # noqa: E402
+    TokenModelConfig,
+    TokenPolicy,
+)
+from scalable_agent_tpu.runtime.learner import Trajectory  # noqa: E402
+from scalable_agent_tpu.types import AgentOutput  # noqa: E402
+from test_sambay_policy import env_outputs, learner_of, rel  # noqa: E402
+
+ref = manifest.load_module(
+    os.path.join(ROOT, "benchmark", "references", "deepseek_v3_token.py"),
+    "reference_deepseek_v3_token_tests")
+
+UNROLL, EPISODE, BATCH, VOCAB = 6, 16, 4, 64
+TINY = {
+    "model_type": "deepseek_v3", "hidden_act": "silu",
+    "scoring_func": "sigmoid", "rope_scaling": None, "q_lora_rank": None,
+    "tie_word_embeddings": False, "attention_bias": False,
+    "vocab_size": VOCAB, "hidden_size": 64, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "head_dim": 4, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "qk_head_dim": 12,
+    "v_head_dim": 8, "intermediate_size": 96, "moe_intermediate_size": 32,
+    "n_routed_experts": 8, "num_experts_per_tok": 2, "n_shared_experts": 2,
+    "first_k_dense_replace": 1, "moe_layer_freq": 1, "n_group": 1,
+    "topk_group": 1, "topk_method": "noaux_tc", "norm_topk_prob": True,
+    "routed_scaling_factor": 2.448, "num_hidden_layers": 3,
+    "rope_theta": 1000000, "rope_interleave": True, "rms_norm_eps": 1e-06,
+    "experts_held": 2, "first_expert": 0,
+    "reference": "deepseek_v3_token", "reference_block": 2,
+    "mean_context": 8,
+    "loss": {"name": "vtrace", "entropy_cost": 0.00025,
+             "baseline_cost": 0.5, "discounting": 0.99,
+             "reward_clipping": "abs_one", "clip_rho_threshold": 1.0,
+             "clip_pg_rho_threshold": 1.0},
+    "optimizer": {"name": "rmsprop", "learning_rate": 0.00048,
+                  "rmsprop_decay": 0.99, "rmsprop_momentum": 0.0,
+                  "rmsprop_epsilon": 0.1, "initial_mean_square": 1.0,
+                  "total_environment_frames": 1e9},
+}
+MODEL = TokenModelConfig.from_dict(TINY)
+ROW = TINY["kv_lora_rank"] + TINY["qk_rope_head_dim"]
+
+
+def policy(dtype=jnp.float32, model=MODEL):
+    return TokenPolicy(model=model, unroll_length=UNROLL,
+                       episode_length=EPISODE, compute_dtype=dtype)
+
+
+def weights(seed=5, cfg=TINY):
+    return {"params": ref.to_tree(ref.make_weights(cfg, seed))}
+
+
+def trajectory(agent, params, seed=3):
+    """One unroll as the fused rollout lays it out, made by hand, with
+    an episode's end inside it for two of the four envs; behaviour
+    log-probabilities from the policy's own logits moved a little off."""
+    rng = np.random.default_rng(seed)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
+                         jnp.int32)
+    done = np.zeros((UNROLL + 1, BATCH), bool)
+    done[0] = True
+    done[3, 1] = done[5, 2] = True
+    done = jnp.asarray(done)
+    actions = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
+                          jnp.int32)
+    reward = jnp.asarray(rng.integers(0, 2, (UNROLL + 1, BATCH)),
+                         jnp.float32)
+    state = agent.initial_state(BATCH)
+    (logits, _), _ = agent.apply(
+        params, actions, env_outputs(tokens, done, reward), state)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    taken = jnp.take_along_axis(logp[:-1], actions[1:, :, None],
+                                -1)[..., 0]
+    noise = jnp.asarray(rng.normal(0, 0.2, taken.shape), jnp.float32)
+    behaviour = jnp.concatenate([jnp.zeros((1, BATCH)), taken + noise])
+    traj = Trajectory(
+        agent_state=state,
+        env_outputs=env_outputs(tokens, done, reward),
+        agent_outputs=AgentOutput(
+            action=actions, policy_logits=behaviour[..., None],
+            baseline=jnp.zeros((UNROLL + 1, BATCH))))
+    batch = ref.Batch(actions, behaviour, reward, done, tokens,
+                      ref.empty_history(TINY, BATCH))
+    return traj, batch
+
+
+# -- (a) forward, loss and gradients against the reference --------------------
+
+@pytest.fixture(scope="module")
+def float32_pair():
+    agent, params = policy(), weights()
+    traj, batch = trajectory(agent, params)
+    learner = learner_of(agent)
+    (loss, _), grads = jax.value_and_grad(
+        learner._loss, has_aux=True)(params, traj, None)
+    ref_loss, ref_grads = jax.value_and_grad(
+        lambda p: ref.loss(TINY, p, batch))(params["params"])
+    (logits, baseline), _ = agent.apply(
+        params, traj.agent_outputs.action, traj.env_outputs,
+        traj.agent_state)
+    ref_logits, ref_baseline, _ = ref.forward(
+        TINY, params["params"], batch.token, batch.done, batch.history)
+    return dict(loss=(loss, ref_loss), logits=(logits, ref_logits),
+                baseline=(baseline, ref_baseline),
+                grads=(ref.from_tree(grads["params"]),
+                       ref.from_tree(ref_grads)))
+
+
+@pytest.mark.parametrize("what", ["logits", "baseline", "loss"])
+def test_float32_forward_and_loss_are_the_references(float32_pair, what):
+    """1e-5: both are float32 sums of the same terms in another order
+    (the program scores ``q Wkvb_k^T`` against the row, the reference
+    ``q`` against the row's up-projection)."""
+    got, want = float32_pair[what]
+    assert rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("leaf", sorted(
+    "/".join(path) for path in ref.weight_shapes(TINY)))
+def test_float32_gradient_is_the_references(float32_pair, leaf):
+    got, want = float32_pair["grads"]
+    path = tuple(leaf.split("/"))
+    scale = max(float(np.max(np.abs(v))) for v in want.values())
+    gap = float(np.max(np.abs(np.asarray(got[path], np.float64)
+                              - np.asarray(want[path], np.float64))))
+    assert gap <= 1e-5 * scale, (leaf, gap, scale)
+    assert float(np.max(np.abs(want[path]))) > 0.0, leaf
+
+
+def test_the_program_has_the_references_leaves_and_no_other():
+    agent = policy()
+    traj, _ = trajectory(agent, weights())
+    made = jax.eval_shape(
+        agent.init, jax.random.key(0), traj.agent_outputs.action,
+        traj.env_outputs, traj.agent_state)["params"]
+    shapes = {path: leaf.shape for path, leaf in ref.from_tree(made).items()}
+    assert shapes == {path: tuple(shape) for path, shape
+                      in ref.weight_shapes(TINY).items()}
+
+
+# The loss against the float32 reference's.  bfloat16 reads 1e-3 here and
+# fp8 0.05 or more: the band lies between.
+BFLOAT16_BAND = 0.01
+
+
+def test_bfloat16_loss_is_inside_a_band_fp8_falls_out_of():
+    params = weights()
+    agent = policy(jnp.bfloat16)
+    traj, batch = trajectory(policy(), params)
+    traj = traj._replace(agent_state=agent.initial_state(BATCH))
+    loss, _ = learner_of(agent)._loss(params, traj, None)
+    want = float(ref.loss(TINY, params["params"], batch))
+    fp8 = float(ref.loss(TINY, params["params"], batch, quant="fp8"))
+    assert abs(float(loss) - want) / abs(want) < BFLOAT16_BAND
+    assert abs(fp8 - want) / abs(want) > BFLOAT16_BAND
+
+
+def test_the_references_planted_fault_moves_its_loss():
+    """``quant="no_rope_on_shared_key"`` (the limits file's own fault):
+    the shared key is never rotated, and the loss moves by far more than
+    float32's rounding."""
+    agent, params = policy(), weights()
+    _, batch = trajectory(agent, params)
+    want = float(ref.loss(TINY, params["params"], batch))
+    planted = float(ref.loss(TINY, params["params"], batch,
+                             quant=ref.NO_ROPE_ON_SHARED_KEY))
+    assert abs(planted - want) / abs(want) > 1e-4
+
+
+# -- (b) acting through the rings is the whole forward ------------------------
+
+@pytest.fixture(scope="module")
+def forty_steps():
+    """40 steps of 4 envs in episodes of 16, staggered: every env
+    crosses two episode ends and the ring (16 + 6 rows) wraps once."""
+    steps = 40
+    rng = np.random.default_rng(11)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (steps, BATCH)), jnp.int32)
+    offset = np.arange(BATCH) * (EPISODE // BATCH)
+    done = (np.arange(steps)[:, None] + offset[None, :]) % EPISODE == 0
+    done[0] = True
+    done = jnp.asarray(done)
+    agent, params = policy(), weights(9)
+    step = jax.jit(lambda p, e, s: agent.apply(
+        p, jnp.zeros(e.done.shape, jnp.int32), e, s))
+    state, logits, values = agent.initial_state(BATCH), [], []
+    for t in range(steps):
+        (row, value), state = step(
+            params, env_outputs(tokens[t:t + 1], done[t:t + 1]), state)
+        logits.append(row[0])
+        values.append(value[0])
+    return (agent, params, tokens, done, jnp.stack(logits),
+            jnp.stack(values), state)
+
+
+@pytest.mark.parametrize("what", ["logits", "baseline"])
+def test_stepwise_outputs_are_the_references_whole_forward(
+        forty_steps, what):
+    _, params, tokens, done, logits, values, _ = forty_steps
+    whole, baseline, _ = ref.forward(TINY, params["params"], tokens, done,
+                                     ref.empty_history(TINY, BATCH))
+    got, want = ((logits, whole) if what == "logits"
+                 else (values, baseline))
+    assert rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("chunk", [2, 5, 7])
+def test_stepwise_logits_are_the_chunked_forwards(forty_steps, chunk):
+    agent, params, tokens, done, stepwise, _, last = forty_steps
+    state, rows = agent.initial_state(BATCH), []
+    for t in range(0, tokens.shape[0], chunk):
+        (logits, _), state = agent.apply(
+            params, jnp.zeros((chunk, BATCH), jnp.int32),
+            env_outputs(tokens[t:t + chunk], done[t:t + chunk]), state)
+        rows.append(logits)
+    got = jnp.concatenate(rows)
+    assert rel(got, stepwise[:got.shape[0]]) < 1e-5
+    if got.shape[0] == stepwise.shape[0]:
+        for a, b in zip(jax.tree_util.tree_leaves(state),
+                        jax.tree_util.tree_leaves(last)):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       atol=1e-5)
+
+
+# -- (c) the state, and what the update unrolls from --------------------------
+
+def test_the_state_is_one_ring_of_rows_a_layer():
+    agent = policy(jnp.bfloat16)
+    state = agent.initial_state(BATCH)
+    assert state.values == ()       # nothing beside the compressed rows
+    assert [r.shape for r in state.keys] == [
+        (BATCH, ROW, EPISODE + UNROLL)] * 3                 # a token a column
+    assert all(r.dtype == jnp.bfloat16 for r in state.keys)
+    assert state.full_index.shape == (EPISODE + UNROLL,)
+    # the gauge reads the state's own arrays: whole values kept beside
+    # the rows would count
+    assert agent.latent_bytes_per_token == 2 * ROW == sum(
+        r.nbytes for r in state.keys) // (3 * BATCH * (EPISODE + UNROLL))
+    assert agent.ring_bytes(BATCH) == BATCH * (EPISODE + UNROLL) * ROW * 2
+    assert agent.cache_bytes(BATCH) == 3 * agent.ring_bytes(BATCH)
+    assert agent.ring_readers == 1
+    # the published sizes: a row of 512 + 64 in bfloat16, rings of whole
+    # decode blocks
+    big = TokenPolicy(
+        model=TokenModelConfig.from_dict(dict(
+            TINY, kv_lora_rank=512, qk_rope_head_dim=64)),
+        unroll_length=256, episode_length=10240,
+        compute_dtype=jnp.bfloat16)
+    assert big.latent_bytes_per_token == 1152
+    assert big.full_slots == 10752
+
+
+@pytest.mark.parametrize("steps", [UNROLL + 1, 1], ids=["update", "decode"])
+def test_no_past_tokens_whole_keys_are_in_the_lowered_step(steps):
+    """The ring holds rows and both passes score rows: no array that
+    has a ring's slots beside the heads' keys or values (heads x 8, or
+    both: 32 or 64 numbers a slot), in any dtype, anywhere in the
+    update's gradient or the decode step — the interpreter's kernel
+    bodies included, which are part of the text here."""
+    agent, params = policy(), weights()
+    state = agent.initial_state(BATCH)
+    tokens = jnp.zeros((steps, BATCH), jnp.int32)
+    outputs = env_outputs(tokens, jnp.zeros((steps, BATCH), bool))
+
+    def run(p):
+        (logits, baseline), new = agent.apply(p, tokens, outputs, state)
+        return jnp.sum(logits) + jnp.sum(baseline), new
+
+    fn = jax.grad(run, has_aux=True) if steps > 1 else run
+    text = jax.jit(fn).lower(params).as_text()
+    slots, heads = EPISODE + UNROLL, TINY["num_attention_heads"]
+    wide = TINY["qk_nope_head_dim"]             # = v_head_dim
+    shapes = {tuple(int(n) for n in dims.rstrip("x").split("x"))
+              for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text)}
+    with_slots = [shape for shape in shapes
+                  if slots in shape or slots + steps in shape]
+    assert (BATCH, ROW, slots) in with_slots                # the rings
+    for shape in with_slots:
+        rest = list(shape)
+        rest.remove(slots if slots in shape else slots + steps)
+        assert not ({heads * wide, 2 * heads * wide} & set(rest)
+                    or (rest.count(heads) > (BATCH in shape)
+                        and wide in rest)), shape
+
+
+@pytest.mark.parametrize("what", ["forward", "rings"])
+def test_the_update_unrolls_from_the_ends_rings(forty_steps, what):
+    agent, params, tokens, done, *_ = forty_steps
+    state = agent.initial_state(BATCH)
+    zeros = jnp.zeros((UNROLL, BATCH), jnp.int32)
+    for t in range(0, 30, UNROLL):
+        start = state
+        (_, _), state = agent.apply(
+            params, zeros, env_outputs(tokens[t:t + UNROLL],
+                                       done[t:t + UNROLL]), state)
+    handed = agent.unroll_state(start, state)
+    if what == "rings":
+        for got, want in zip(handed.keys, state.keys):
+            assert got is want
+        assert handed.written is start.written
+        assert handed.episode_start is start.episode_start
+    else:
+        t = 30 - UNROLL
+        again = env_outputs(tokens[t:t + UNROLL + 1],
+                            done[t:t + UNROLL + 1])
+        actions = jnp.zeros((UNROLL + 1, BATCH), jnp.int32)
+        (want, _), _ = agent.apply(params, actions, again, start)
+        (got, _), _ = agent.apply(params, actions, again, handed)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# -- (d) the rotation ---------------------------------------------------------
+
+def test_interleaved_pairs_are_the_half_split_ones_on_permuted_weights():
+    """``rope_interleave`` turns numbers (2i, 2i + 1) where the other
+    rotation turns (i, i + D / 2): a model whose rotated columns of Wq
+    and Wkva are permuted accordingly, run with the half-split rotation,
+    is the same function."""
+    turned, nope = TINY["qk_rope_head_dim"], TINY["qk_nope_head_dim"]
+    rank, heads = TINY["kv_lora_rank"], TINY["num_attention_heads"]
+    # half-split position j holds interleaved position order[j]
+    order = np.concatenate([np.arange(0, turned, 2),
+                            np.arange(1, turned, 2)])
+    params = weights(21)
+    permuted = jax.tree_util.tree_map(lambda x: x, params)
+    for layer in range(TINY["num_hidden_layers"]):
+        attn = permuted["params"][f"layer_{layer}"]["attention"]
+        wq = np.asarray(attn["q_proj"]["kernel"]).reshape(
+            -1, heads, nope + turned)
+        wq = np.concatenate([wq[..., :nope], wq[..., nope:][..., order]], -1)
+        attn["q_proj"] = {"kernel": jnp.asarray(wq.reshape(-1, heads * (
+            nope + turned)))}
+        wa = np.asarray(attn["kv_a_proj"]["kernel"])
+        attn["kv_a_proj"] = {"kernel": jnp.asarray(np.concatenate(
+            [wa[:, :rank], wa[:, rank:][:, order]], -1))}
+    tokens = jnp.asarray(np.random.default_rng(8).integers(
+        0, VOCAB, (UNROLL, BATCH)), jnp.int32)
+    done = jnp.zeros((UNROLL, BATCH), bool).at[0].set(True)
+    outputs = env_outputs(tokens, done)
+    pairs, split = policy(), policy(model=TokenModelConfig.from_dict(
+        dict(TINY, rope_interleave=False)))
+    (want, _), _ = pairs.apply(params, tokens, outputs,
+                               pairs.initial_state(BATCH))
+    (got, _), _ = split.apply(permuted, tokens, outputs,
+                              split.initial_state(BATCH))
+    assert rel(got, want) < 1e-5
+    (other, _), _ = split.apply(params, tokens, outputs,
+                                split.initial_state(BATCH))
+    assert rel(other, want) > 1e-3
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 3, 1, turned)),
+                    jnp.float32)
+    position = jnp.asarray([[0, 5, 9], [2, 0, 1]], jnp.int32)
+    np.testing.assert_allclose(
+        np.asarray(token_policy.rope_interleaved(x, position, 1e6))[
+            ..., order],
+        np.asarray(token_policy.rope(x[..., order], position, 1e6)),
+        atol=1e-6)
+
+
+# -- (e) the share tied to the model ------------------------------------------
+
+def shares_of_the_layer():
+    """(the four shares' sum, the uncut layer, each share's (routed
+    part, load)) — the body of ``test_the_shares_sum_to_the_uncut_layer``
+    for this family (tests/test_token_policy.py has the test, a case a
+    family).  Four chips hold two of the eight experts each.  A chip's
+    policy layer gives the shared experts' result plus its own experts'
+    part of the routed sum (the router over all eight, two a token, the
+    chosen scores normalised and scaled); the four routed parts and the
+    two shared experts counted once are the reference's layer over all
+    eight."""
+    shares, held = 4, 2
+    cfg = dict(TINY, experts_held=8, first_expert=0)
+    whole = ref.to_tree(ref.make_weights(cfg, 17))["layer_1"]["moe"]
+    m = jnp.asarray(np.random.default_rng(5).normal(size=(24, 64)),
+                    jnp.float32)
+    want = ref.expert_layer(cfg, whole, m, lambda x: x)
+    shared = ref.gated_mlp(whole["shared"], m, lambda x: x)
+    assert float(jnp.max(jnp.abs(shared))) > 0.0
+    parts = []
+    for share in range(shares):
+        model = TokenModelConfig.from_dict(dict(
+            TINY, experts_held=held, first_expert=share * held))
+        mine = dict(whole, experts={
+            name: stack[share * held:(share + 1) * held]
+            for name, stack in whole["experts"].items()})
+        got, stats = token_policy._MoE(model, jnp.float32).apply(
+            {"params": mine}, m)
+        parts.append((got - shared, stats))
+    return shared + sum(part for part, _ in parts), want, parts

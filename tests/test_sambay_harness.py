@@ -170,7 +170,8 @@ def test_the_first_family_builds_and_steps_as_before():
     (logits, _), new = agent.apply(params, tokens, outputs, state)
     assert logits.shape == (1, BATCH, VOCAB)
     assert int(new.written) == 1
-    assert token_policy.FAMILIES == ("afmoe", "phi4flash")
+    # later families come after these two, which keep their places
+    assert token_policy.FAMILIES[:2] == ("afmoe", "phi4flash")
 
 
 # -- the world, and the harness at the tiny preset ----------------------------

@@ -406,10 +406,16 @@ def test_a_share_is_the_references_share(expert_layer_inputs, first):
     assert rel(got, want) < 1e-5
 
 
-def test_the_shares_sum_to_the_uncut_layer(expert_layer_inputs):
-    x, p, whole = expert_layer_inputs
-    parts = [held_share(x, p, first) for first in (0, 2, 4, 6)]
-    total = sum(part for part, _ in parts)       # the shared expert is 0
+@pytest.mark.parametrize("family", ["afmoe", "deepseek_v3"])
+def test_the_shares_sum_to_the_uncut_layer(expert_layer_inputs, family):
+    if family == "afmoe":
+        x, p, whole = expert_layer_inputs
+        parts = [held_share(x, p, first) for first in (0, 2, 4, 6)]
+        total = sum(part for part, _ in parts)   # the shared expert is 0
+    else:       # two shared experts, counted once; its own router's rule
+        from test_kanana_policy import shares_of_the_layer
+
+        total, whole, parts = shares_of_the_layer()
     assert rel(total, whole) < 1e-5
     # every pair lands on exactly one share
     assert sum(float(stats["pairs_here_share"])
