@@ -448,7 +448,7 @@ class _MoE(nn.Module):
             name="experts")(hidden)
         routed, stats = moe.held_experts(
             x, routing, gate_proj, up_proj, down_proj, model.first_expert,
-            self.dtype,
+            model.num_experts, self.dtype,
             every_expert=decode and x.shape[0] <= moe.EVERY_EXPERT_MAX_ROWS)
         with jax.named_scope("shared"):
             shared = _GatedMLP(
@@ -844,7 +844,7 @@ _TELEMETRY = {
     "afmoe": (
         ("embedding", "attention", "experts", "mlp", "norms", "heads"),
         ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
-         "moe/expert_load_max_over_mean",
+         "moe/expert_load_max_over_mean", "moe/compact_share",
          "attention/key_blocks_visited_share",
          "attention/decode_key_blocks_visited_share")),
     # the tied table is the head too, and is counted as the embedding

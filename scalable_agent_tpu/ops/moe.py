@@ -12,11 +12,37 @@ No capacity and no dropped token.  The pairs that land here are sorted
 by expert and run as one grouped matrix product
 (``jax.lax.ragged_dot``: on a TPU a Mosaic kernel of XLA's own that
 visits only the row tiles its groups cover; operands in the compute
-dtype, products in float32), gathered back and weighted.
-The sorted buffer has room for every pair, since a routing may send
-them all here; rows past the pairs that did land here belong to no group,
-are not computed, and are masked out of both passes.  Shapes are fixed:
-how the load falls changes group sizes, never a shape.
+dtype, products in float32), and each token sums its pairs' rows,
+weighted, straight out of the sorted buffer.
+
+The sorted pairs are walked a chunk of ``C`` rows at a time, and every
+gather, mask, product and sum of the walk has ``C`` rows: ``C`` is twice
+the share of the pairs that an even routing sends to the held experts,
+``2 * pairs * experts_held / num_experts`` rounded up to the grouped
+product's row tile
+(``compact_rows``: 16,640 of 65,792 pairs where a chip holds 16 of 128
+experts).  The ops round the product do not skip a row as the product
+does, so they cost what their buffer holds, not what landed; a buffer
+with room for every pair, which a routing may need, cost eight times
+the common load in every pass.  The first chunk is straight-line code
+and is all there is when what landed fits it (``compact_share`` in the
+layer's numbers: 1.0 for such a pass); a routing that sends more here
+is walked on, chunk by chunk, by a loop that stops at the last pair
+that landed, so any routing is exact, the work follows ``landed`` in
+steps of ``C``, and no tensor of the program has a row a pair.  Where
+the chip holds half the experts or more there is nothing to compact and
+the one chunk holds every pair.  Rows of a chunk past the pairs that
+landed belong to no group, are not computed, and are masked out of
+both passes.  Shapes are fixed: how the load falls changes group sizes
+and the loop's length, never a shape.
+
+A loop that stops where the input says is not differentiable by
+tracing, and a layer under ``nn.remat`` may keep only what its
+backward reads: the grouped path is a ``custom_vjp`` (``_grouped``)
+whose forward keeps the first chunk's stage inputs (``_stages``) and
+whose backward pulls the cotangent back through them stage by stage,
+then walks the later chunks again, each one's forward inside the loop's
+body.
 
 A decode step (``every_expert``: a few rows, one token an env) runs
 EVERY held expert over every row instead and weights by the routing (0
@@ -57,44 +83,92 @@ def route(x, router_kernel, expert_bias, top_k: int, route_scale: float,
     return Routing(chosen.astype(jnp.int32), picked * route_scale)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_pairs(x, order, inverse, top_k: int):
+# The grouped product's row tile.  XLA's kernel takes the largest power of
+# two that divides its buffer's rows, up to 256, at a time
+# (``ragged_dot_tiling`` in the compiled text): a buffer of 16,448 rows
+# (64 x 257) is multiplied 64 rows at a time, and every product took 1.5
+# times what it took over the every-pair buffer's 256 (my chip runs, PR
+# 41).  A chunk is a whole number of full tiles.
+_ROW_TILE = 256
+
+
+def compact_rows(pairs: int, held: int, num_experts: int) -> int:
+    """Rows of a chunk of the sorted pairs (the module's docstring);
+    ``pairs`` where there is nothing to compact."""
+    if 2 * held >= num_experts:
+        return pairs
+    rows = -(-2 * pairs * held // num_experts)
+    return min(pairs, -(-rows // _ROW_TILE) * _ROW_TILE)
+
+
+class _Dispatch(NamedTuple):
+    """Where the sort put each pair.  Pairs count ``token * top_k +
+    choice``; the pairs that landed here come first, by expert."""
+    order: jax.Array       # i32 [pairs, padded to whole chunks] the pair
+                           # in each sorted row
+    slot: jax.Array        # i32 [pairs] the sorted row of each pair
+    here: jax.Array        # bool [N, k] the pair landed on a held expert
+    sizes: jax.Array       # i32 [held] pairs on each held expert
+
+
+def _slot_sum(rows, weights, slot):
+    """``sum_k weights[:, k] * rows[slot[:, k]]``: each token's pairs'
+    rows out of the sorted buffer, summed in float32 in the order of its
+    choices: ``k`` gathers of [N, width], never one of [pairs, width].
+    ``slot`` [N, k] lies inside ``rows``; a pair with no row of its own
+    has weight 0."""
+    total = jnp.zeros((slot.shape[0], rows.shape[1]), jnp.float32)
+    for choice in range(slot.shape[1]):
+        total = total + (weights[:, choice, None]
+                         * rows[slot[:, choice]].astype(jnp.float32))
+    return total
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_tokens(x, order, slot, here, top_k: int):
     """Row i of the result is the token of sorted pair i: ``x[order[i]
-    // top_k]``.  Its cotangent comes back by the inverse permutation
-    (a gather and a sum over a token's pairs), not by a scatter-add."""
-    del inverse
+    // top_k]``.  Its cotangent comes back as a token's sum over the
+    rows of its pairs that landed (``_slot_sum``), not by a
+    scatter-add."""
+    del slot, here
     return x[order // top_k]
 
 
-def _gather_pairs_fwd(x, order, inverse, top_k):
-    return x[order // top_k], (inverse, x.shape[0])
+def _take_tokens_fwd(x, order, slot, here, top_k):
+    return x[order // top_k], (slot, here)
 
 
-def _gather_pairs_bwd(top_k, residuals, g):
-    inverse, tokens = residuals
-    return g[inverse].reshape(tokens, top_k, -1).sum(axis=1), None, None
+def _take_tokens_bwd(top_k, residuals, g):
+    slot, here = residuals
+    return (_slot_sum(g, here.astype(jnp.float32), slot).astype(g.dtype),
+            None, None, None)
 
 
-_gather_pairs.defvjp(_gather_pairs_fwd, _gather_pairs_bwd)
+_take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
 
 
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation; the cotangent is ``g[inverse]``."""
-    del inverse
-    return x[perm]
+def _combine(out, weights, order, slot):
+    """``_slot_sum(out, weights, slot)``; the cotangents are taken on
+    the sorted side, a row a pair of the buffer: ``out``'s is the
+    token's times the pair's weight, a weight's the dot of the two."""
+    del order
+    return _slot_sum(out, weights, slot)
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
+def _combine_fwd(out, weights, order, slot):
+    return _slot_sum(out, weights, slot), (out, weights, order, slot)
 
 
-def _permute_bwd(residuals, g):
-    _, inverse = residuals
-    return g[inverse], None, None
+def _combine_bwd(residuals, g):
+    out, weights, order, slot = residuals
+    of_row = g[order // weights.shape[1]]
+    d_out = of_row * weights.reshape(-1)[order][:, None]
+    d_weight = jnp.sum(of_row * out, axis=-1)
+    return d_out, d_weight[slot], None, None
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # Rows up to which a decode step may run every held expert: under ~240
@@ -130,14 +204,168 @@ def _every_expert(x, routing: Routing, gate_proj, up_proj, down_proj,
     return y, sizes
 
 
+def _stages(dispatch: _Dispatch, dtype, rows: int, chunk):
+    """The grouped path over rows ``[chunk * rows, (chunk + 1) * rows)``
+    of the sorted pairs as a chain of stages: each takes the stage
+    before's result, then the layer's inputs it reads
+    (``_stage_inputs``), so a backward pass can start from any stage's
+    kept input.  The last stage's result is the chunk's part of every
+    token's sum."""
+    tokens, top_k = dispatch.here.shape
+    first = chunk * rows
+    order = jax.lax.dynamic_slice_in_dim(dispatch.order, first, rows)
+    live = (first + jnp.arange(rows, dtype=jnp.int32)
+            < jnp.sum(dispatch.sizes))[:, None]
+    # the chunk's rows of each held expert's run of the sorted pairs
+    ends = jnp.cumsum(dispatch.sizes)
+    sizes = (jnp.clip(ends, first, first + rows)
+             - jnp.clip(ends - dispatch.sizes, first, first + rows))
+    local = dispatch.slot.reshape(tokens, top_k) - first
+    mine = dispatch.here & (local >= 0) & (local < rows)
+    # a pair with no row in the chunk: any row does, at weight 0
+    slot = jnp.clip(local, 0, rows - 1)
+
+    def grouped(lhs, rhs):
+        # Rows past the pairs that landed are in no group: the product
+        # leaves them as it found the buffer, in this pass and in the
+        # cotangent it hands back.  Every operand and every result goes
+        # through ``live``, so neither pass reads one.
+        return jnp.where(live, jax.lax.ragged_dot(
+            round_to(lhs, dtype), rhs, sizes,
+            preferred_element_type=jnp.float32), 0)
+
+    def take(x):
+        with jax.named_scope("dispatch"):
+            return jnp.where(
+                live, _take_tokens(round_to(x, dtype), order, slot, mine,
+                                   top_k), 0)
+
+    def project(taken, gate_proj, up_proj):
+        with jax.named_scope("experts"):
+            return grouped(taken, gate_proj), grouped(taken, up_proj)
+
+    def gate(projected):
+        with jax.named_scope("experts"):
+            return round_to(jnp.where(
+                live, jax.nn.silu(projected[0]) * projected[1], 0), dtype)
+
+    def down(hidden, down_proj):
+        with jax.named_scope("experts"):
+            return grouped(hidden, down_proj)
+
+    def combine(out, weights):
+        with jax.named_scope("combine"):
+            # a float32 sum, not a dot: a dot would round both to
+            # bfloat16
+            return _combine(out, jnp.where(mine, weights, 0.0), order, slot)
+
+    return take, project, gate, down, combine
+
+
+def _stage_inputs(weights, gate_proj, up_proj, down_proj):
+    """What each of ``_stages`` reads beside the stage before's
+    result."""
+    return (), (gate_proj, up_proj), (), (down_proj,), (weights,)
+
+
+# One chunk's two passes are jitted so that they are traced and lowered
+# once for a whole model: every expert layer, its rematerialized twin and
+# both loops' bodies call the same two functions at the same shapes.
+
+@partial(jax.jit, static_argnames=("dtype", "rows"))
+def _forward(x, inputs, dispatch, chunk, *, dtype, rows):
+    """The chain's result for one chunk, and the input of each stage
+    after the first."""
+    kept = []
+    for stage, more in zip(_stages(dispatch, dtype, rows, chunk), inputs):
+        x = stage(x, *more)
+        kept.append(x)
+    return kept.pop(), kept
+
+
+@partial(jax.jit, static_argnames=("dtype", "rows"))
+def _backward(x, kept, inputs, dispatch, chunk, g, *, dtype, rows):
+    """``g`` pulled back through one chunk's chain from each stage's
+    kept input: the cotangent of ``x`` and, a stage a tuple, of
+    ``inputs``.  A stage's own result is not computed again unless its
+    cotangent needs it (a product's needs its operands alone)."""
+    d_inputs = []
+    for stage, at, more in reversed(list(zip(
+            _stages(dispatch, dtype, rows, chunk), [x, *kept], inputs))):
+        g, *d_more = jax.vjp(stage, at, *more)[1](g)
+        d_inputs.insert(0, tuple(d_more))
+    return g, tuple(d_inputs)
+
+
+def _chunks(dispatch: _Dispatch, rows: int):
+    """How many chunks of ``rows`` rows hold a pair that landed: a
+    traced number, or 1 where the one chunk holds every pair."""
+    if rows == dispatch.slot.shape[0]:
+        return 1
+    return -(-jnp.sum(dispatch.sizes) // rows)
+
+
+def _walk_on(chunks, one_chunk, total):
+    """``total`` plus ``one_chunk(chunk)`` for every chunk after the
+    first: no pass of the loop when what landed fits the first."""
+    if isinstance(chunks, int):
+        return total
+    return jax.lax.fori_loop(
+        1, chunks, lambda chunk, total: jax.tree_util.tree_map(
+            jnp.add, total, one_chunk(chunk)), total)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _grouped(x, weights, dispatch: _Dispatch, gate_proj, up_proj, down_proj,
+             dtype, rows: int):
+    """The weighted sum over the held experts, the sorted pairs walked
+    ``rows`` rows at a time (the module's docstring).  The stacks come
+    in the compute dtype: cast outside, the update shares the step's
+    one cast of them with the decode."""
+    return _grouped_fwd(x, weights, dispatch, gate_proj, up_proj, down_proj,
+                        dtype, rows)[0]
+
+
+def _grouped_fwd(x, weights, dispatch, gate_proj, up_proj, down_proj, dtype,
+                 rows):
+    inputs = _stage_inputs(weights, gate_proj, up_proj, down_proj)
+    forward = partial(_forward, x, inputs, dispatch, dtype=dtype, rows=rows)
+    y, kept = forward(jnp.int32(0))
+    y = _walk_on(_chunks(dispatch, rows), lambda chunk: forward(chunk)[0], y)
+    return y, (x, kept, inputs, dispatch)
+
+
+def _grouped_bwd(dtype, rows, residuals, g):
+    x, kept, inputs, dispatch = residuals
+
+    def backward(chunk, kept):
+        return _backward(x, kept, inputs, dispatch, chunk, g, dtype=dtype,
+                         rows=rows)
+
+    def later(chunk):
+        # a later chunk kept nothing: its forward again, in the loop
+        return backward(chunk, _forward(x, inputs, dispatch, chunk,
+                                        dtype=dtype, rows=rows)[1])
+
+    d_x, ((), (d_gate_proj, d_up_proj), (), (d_down_proj,), (d_weights,)) = (
+        _walk_on(_chunks(dispatch, rows), later,
+                 backward(jnp.int32(0), kept)))
+    return d_x, d_weights, None, d_gate_proj, d_up_proj, d_down_proj
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def held_experts(x, routing: Routing, gate_proj, up_proj, down_proj,
-                 first_expert: int, dtype, every_expert: bool = False
+                 first_expert: int, num_experts: int, dtype,
+                 every_expert: bool = False
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """``sum_{e chosen and held here} w_e * expert_e(x)`` for x [N,
     hidden] and the held experts' stacked silu-gated MLPs
     (``gate_proj`` / ``up_proj`` [held, hidden, width], ``down_proj``
-    [held, width, hidden]), with the load's numbers.  ``every_expert``:
-    the decode step's form (the module's docstring)."""
+    [held, width, hidden]) of ``num_experts`` in all, with the load's
+    numbers.  ``every_expert``: the decode step's form (the module's
+    docstring)."""
     tokens, top_k = routing.chosen.shape
     held = gate_proj.shape[0]
     pairs = tokens * top_k
@@ -151,36 +379,21 @@ def held_experts(x, routing: Routing, gate_proj, up_proj, down_proj,
         # elsewhere sorts behind every held expert
         group = jnp.where(here, local, held).reshape(pairs)
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        slot = jnp.zeros((pairs,), jnp.int32).at[order].set(
             jnp.arange(pairs, dtype=jnp.int32))
         sizes = jnp.sum(
             group[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
-        landed = jnp.sum(sizes)
-        live = (jnp.arange(pairs, dtype=jnp.int32) < landed)[:, None]
-        rows = jnp.where(
-            live, _gather_pairs(round_to(x, dtype), order, inverse, top_k),
-            0)
+    rows = compact_rows(pairs, held, num_experts)
     with jax.named_scope("experts"):
-        def grouped(lhs, rhs):
-            # Rows past ``landed`` are in no group: the product leaves
-            # them as it found the buffer, in this pass and in the
-            # cotangent it hands back.  Every operand and every result
-            # goes through ``live``, so neither pass reads one.
-            return jnp.where(live, jax.lax.ragged_dot(
-                round_to(lhs, dtype), rhs.astype(dtype), sizes,
-                preferred_element_type=jnp.float32), 0)
-
-        hidden = jnp.where(
-            live, jax.nn.silu(grouped(rows, gate_proj))
-            * grouped(rows, up_proj), 0)
-        out = grouped(hidden, down_proj)
-    with jax.named_scope("combine"):
-        back = _permute(out, inverse, order).reshape(tokens, top_k, -1)
-        weights = jnp.where(here, routing.weights, 0.0)
-        # a float32 sum, not a dot: a dot would round both to bfloat16
-        y = jnp.sum(back * weights[..., None], axis=1)
-    return y, _load(sizes, pairs)
+        stacks = [stack.astype(dtype)
+                  for stack in (gate_proj, up_proj, down_proj)]
+    y = _grouped(
+        x, routing.weights, _Dispatch(
+            jnp.pad(order, (0, -pairs % rows)), slot, here, sizes),
+        *stacks, dtype, rows)
+    return y, dict(_load(sizes, pairs), compact_share=jnp.float32(
+        (jnp.sum(sizes) <= rows) if rows < pairs else 0.0))
 
 
 def _load(sizes, pairs: int) -> Dict[str, jax.Array]:

@@ -404,20 +404,23 @@ def test_interleaved_pairs_are_the_half_split_ones_on_permuted_weights():
 
 # -- (e) the share tied to the model ------------------------------------------
 
-def shares_of_the_layer():
-    """(the four shares' sum, the uncut layer, each share's (routed
+def shares_of_the_layer(held=2):
+    """(the shares' sum, the uncut layer, each share's (routed
     part, load)) — the body of ``test_the_shares_sum_to_the_uncut_layer``
     for this family (tests/test_token_policy.py has the test, a case a
-    family).  Four chips hold two of the eight experts each.  A chip's
+    family).  ``8 / held`` chips hold ``held`` of the eight experts each
+    (at four there is nothing for ops/moe.py to compact).  A chip's
     policy layer gives the shared experts' result plus its own experts'
     part of the routed sum (the router over all eight, two a token, the
-    chosen scores normalised and scaled); the four routed parts and the
+    chosen scores normalised and scaled); the routed parts and the
     two shared experts counted once are the reference's layer over all
     eight."""
-    shares, held = 4, 2
+    shares = 8 // held
     cfg = dict(TINY, experts_held=8, first_expert=0)
     whole = ref.to_tree(ref.make_weights(cfg, 17))["layer_1"]["moe"]
-    m = jnp.asarray(np.random.default_rng(5).normal(size=(24, 64)),
+    # 320 tokens: enough pairs for a chunk of ops/moe.py's walk (512
+    # rows) to be less than all 640
+    m = jnp.asarray(np.random.default_rng(5).normal(size=(320, 64)),
                     jnp.float32)
     want = ref.expert_layer(cfg, whole, m, lambda x: x)
     shared = ref.gated_mlp(whole["shared"], m, lambda x: x)

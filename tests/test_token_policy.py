@@ -340,7 +340,9 @@ def expert_layer_inputs():
     rng = jax.random.key(2)
     hidden, width, experts = 64, 32, 8
     keys = jax.random.split(rng, 6)
-    x = jax.random.normal(keys[0], (24, hidden), jnp.float32)
+    # enough pairs (640) for a chunk of the grouped path (512 rows, two
+    # of the product's row tiles) to be less than all of them
+    x = jax.random.normal(keys[0], (320, hidden), jnp.float32)
     p = {"router": {"kernel": jax.random.normal(
             keys[1], (hidden, experts)) / 8.0},
          "experts": {
@@ -358,12 +360,19 @@ def expert_layer_inputs():
     return x, p, whole
 
 
+# The grouped path's chunk (ops/moe.py), by how many of the eight experts
+# a chip holds: two, and the sorted pairs are walked 512 rows at a time
+# (of 640 pairs); four, and there is nothing to compact: one chunk holds
+# every pair.
+HELD = pytest.mark.parametrize("held", [2, 4], ids=["compact", "every_pair"])
+
+
 def held_share(x, p, first, held=2, every_expert=False):
     routing = moe.route(x, p["router"]["kernel"], jnp.zeros((8,)), 2,
                         TINY["route_scale"], True)
     stack = {k: v[first:first + held] for k, v in p["experts"].items()}
     return moe.held_experts(x, routing, stack["gate_proj"],
-                            stack["up_proj"], stack["down_proj"], first,
+                            stack["up_proj"], stack["down_proj"], first, 8,
                             jnp.float32, every_expert=every_expert)
 
 
@@ -371,14 +380,15 @@ def held_share(x, p, first, held=2, every_expert=False):
 def test_a_decode_steps_share_is_the_grouped_products(
         expert_layer_inputs, first):
     """A decode step runs every held expert over every row and weights
-    by the routing: the grouped product's sum and the same load."""
+    by the routing: the grouped product's sum and the same load (it
+    sorts into no buffer, and says nothing of one)."""
     x, p, _ = expert_layer_inputs
     want, want_stats = held_share(x, p, first)
     got, stats = held_share(x, p, first, every_expert=True)
     assert rel(got, want) < 1e-5
-    assert sorted(stats) == sorted(want_stats)
-    for name, value in want_stats.items():
-        assert float(stats[name]) == pytest.approx(float(value)), name
+    assert sorted(stats) == sorted(set(want_stats) - {"compact_share"})
+    for name, value in stats.items():
+        assert float(want_stats[name]) == pytest.approx(float(value)), name
 
 
 def test_a_decode_step_of_the_policy_runs_every_expert():
@@ -396,26 +406,31 @@ def test_a_decode_step_of_the_policy_runs_every_expert():
         assert ("ragged_dot" in text) == grouped, steps
 
 
-@pytest.mark.parametrize("first", [0, 2, 4, 6])
-def test_a_share_is_the_references_share(expert_layer_inputs, first):
+@pytest.mark.parametrize("first,held", [(0, 2), (2, 2), (4, 2), (6, 2),
+                                        (0, 4), (4, 4)])
+def test_a_share_is_the_references_share(expert_layer_inputs, first, held):
     x, p, _ = expert_layer_inputs
-    got, _ = held_share(x, p, first)
-    stack = {k: v[first:first + 2] for k, v in p["experts"].items()}
+    got, stats = held_share(x, p, first, held)
+    stack = {k: v[first:first + held] for k, v in p["experts"].items()}
     want = ref.expert_layer(TINY, dict(p, experts=stack), x,
-                            lambda v: v, experts=(first, 2))
+                            lambda v: v, experts=(first, held))
     assert rel(got, want) < 1e-5
+    assert float(stats["compact_share"]) == (held == 2)
 
 
+@HELD
 @pytest.mark.parametrize("family", ["afmoe", "deepseek_v3"])
-def test_the_shares_sum_to_the_uncut_layer(expert_layer_inputs, family):
+def test_the_shares_sum_to_the_uncut_layer(expert_layer_inputs, family,
+                                           held):
     if family == "afmoe":
         x, p, whole = expert_layer_inputs
-        parts = [held_share(x, p, first) for first in (0, 2, 4, 6)]
+        parts = [held_share(x, p, first, held)
+                 for first in range(0, 8, held)]
         total = sum(part for part, _ in parts)   # the shared expert is 0
     else:       # two shared experts, counted once; its own router's rule
         from test_kanana_policy import shares_of_the_layer
 
-        total, whole, parts = shares_of_the_layer()
+        total, whole, parts = shares_of_the_layer(held)
     assert rel(total, whole) < 1e-5
     # every pair lands on exactly one share
     assert sum(float(stats["pairs_here_share"])
@@ -424,30 +439,35 @@ def test_the_shares_sum_to_the_uncut_layer(expert_layer_inputs, family):
 
 # -- (d) no token is dropped --------------------------------------------------
 
+@HELD
 @pytest.mark.parametrize("every_expert", [False, True])
 @pytest.mark.parametrize("held_expert", [0, 1])
 def test_no_pair_is_dropped_when_all_land_on_one_expert(
-        expert_layer_inputs, held_expert, every_expert):
+        expert_layer_inputs, held_expert, every_expert, held):
+    """All 640 pairs land here: more than a chunk's 512 rows, so where
+    there is something to compact the walk goes on to a second chunk."""
     x, p, _ = expert_layer_inputs
     tokens = x.shape[0]
     routing = moe.Routing(
         jnp.full((tokens, 2), held_expert, jnp.int32),
         jnp.tile(jnp.asarray([[0.7, 0.4]], jnp.float32), (tokens, 1)))
-    stack = {k: v[:2] for k, v in p["experts"].items()}
+    stack = {k: v[:held] for k, v in p["experts"].items()}
     got, stats = moe.held_experts(
         x, routing, stack["gate_proj"], stack["up_proj"],
-        stack["down_proj"], 0, jnp.float32, every_expert=every_expert)
+        stack["down_proj"], 0, 8, jnp.float32, every_expert=every_expert)
     one = (jax.nn.silu(x @ stack["gate_proj"][held_expert])
            * (x @ stack["up_proj"][held_expert])
            ) @ stack["down_proj"][held_expert]
     assert rel(got, 1.1 * one) < 1e-5
     assert float(stats["pairs_here_share"]) == 1.0
-    assert float(stats["tokens_per_expert_mean"]) == tokens
-    assert float(stats["expert_load_max_over_mean"]) == 2.0
+    assert every_expert or float(stats["compact_share"]) == 0.0
+    assert float(stats["tokens_per_expert_mean"]) == 2 * tokens / held
+    assert float(stats["expert_load_max_over_mean"]) == held
 
 
+@HELD
 def test_the_expert_layers_gradient_ignores_rows_no_pair_holds(
-        expert_layer_inputs):
+        expert_layer_inputs, held):
     """Rows of the sorted buffer past the pairs that landed here are in
     no group; neither pass may read them."""
     x, p, _ = expert_layer_inputs
@@ -455,16 +475,17 @@ def test_the_expert_layers_gradient_ignores_rows_no_pair_holds(
     def total(x, experts):
         routing = moe.route(x, p["router"]["kernel"], jnp.zeros((8,)), 2,
                             1.0, True)
-        y, _ = moe.held_experts(x, routing, experts["gate_proj"][:2],
-                                experts["up_proj"][:2],
-                                experts["down_proj"][:2], 0, jnp.float32)
+        y, _ = moe.held_experts(x, routing, experts["gate_proj"][:held],
+                                experts["up_proj"][:held],
+                                experts["down_proj"][:held], 0, 8,
+                                jnp.float32)
         return jnp.sum(jnp.square(y))
 
     def want(x, experts):
-        stack = {k: v[:2] for k, v in experts.items()}
+        stack = {k: v[:held] for k, v in experts.items()}
         y = ref.expert_layer(
             dict(TINY, route_scale=1.0), dict(p, experts=stack), x,
-            lambda v: v, experts=(0, 2))
+            lambda v: v, experts=(0, held))
         return jnp.sum(jnp.square(y))
 
     got = jax.grad(total, argnums=(0, 1))(x, p["experts"])
@@ -473,6 +494,70 @@ def test_the_expert_layers_gradient_ignores_rows_no_pair_holds(
                     jax.tree_util.tree_leaves(ref_grads)):
         assert np.isfinite(np.asarray(a)).all()
         assert rel(a, b) < 1e-4
+
+
+def shapes_in(jaxpr):
+    """The shape of every array a jaxpr makes, its sub-jaxprs' too."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(var.aval.shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from shapes_in(sub)
+
+
+def test_the_walk_agrees_with_one_chunk_on_either_side_of_a_chunks_rows(
+        expert_layer_inputs):
+    """512 pairs land on the two held experts and fill the first chunk
+    to its last row; 513 need a second.  Either way the layer's value and
+    every gradient are those of one chunk with room for every pair (the
+    same two experts told they are two of four: nothing to compact),
+    ``compact_share`` says whether the first chunk held the pass, and
+    no activation of the walk, forward or backward, has a row a pair
+    (the sort's own index arrays do, a few numbers wide)."""
+    x, p, _ = expert_layer_inputs
+    tokens, pairs, rows = x.shape[0], 2 * x.shape[0], 512
+    assert moe.compact_rows(pairs, 2, 8) == rows
+    assert moe.compact_rows(pairs, 2, 4) == pairs
+    stacks = [p["experts"][name][:2]
+              for name in ("gate_proj", "up_proj", "down_proj")]
+    weights = jax.random.uniform(jax.random.key(3), (tokens, 2),
+                                 jnp.float32, 0.1, 1.0)
+
+    def layer(num_experts, chosen):
+        def value(x, weights, *stacks):
+            y, stats = moe.held_experts(
+                x, moe.Routing(chosen, weights), *stacks, 0, num_experts,
+                jnp.float32)
+            return jnp.sum(jnp.square(y)), (y, stats)
+        return jax.value_and_grad(value, argnums=range(5), has_aux=True)
+
+    for landed in (rows, rows + 1):
+        # pair i lands on held expert i % 2 if i is among the first
+        # ``landed`` of a shuffle, else on one of the six held elsewhere
+        at = jax.random.permutation(jax.random.key(landed), pairs)
+        chosen = jnp.where(at < landed, at % 2, 2 + at % 6).astype(
+            jnp.int32).reshape(tokens, 2)
+        (_, (got, stats)), grads = layer(8, chosen)(x, weights, *stacks)
+        (_, (want, want_stats)), want_grads = layer(4, chosen)(
+            x, weights, *stacks)
+        assert float(stats["compact_share"]) == (landed == rows)
+        assert float(want_stats["compact_share"]) == 0.0
+        assert float(stats["pairs_here_share"]) == pytest.approx(
+            landed / pairs)
+        assert float(jnp.max(jnp.abs(want))) > 0.0
+        assert rel(got, want) < 1e-6
+        for a, b in zip(grads, want_grads):
+            assert float(jnp.max(jnp.abs(b))) > 0.0
+            assert rel(a, b) < 1e-6
+
+    def wide(num_experts):
+        return {shape for shape in shapes_in(jax.make_jaxpr(
+            layer(num_experts, chosen))(x, weights, *stacks).jaxpr)
+                if len(shape) == 2 and shape[0] >= pairs
+                and shape[1] >= stacks[0].shape[-1]}
+
+    assert not wide(8)
+    assert (pairs, x.shape[1]) in wide(4)
 
 
 # -- (e) the world ------------------------------------------------------------
