@@ -298,7 +298,7 @@ def _token_policy_refusals(config: Config):
 def build_token_policy(config: Config, action_space, frame_shape=None):
     """The token policy (models/token_policy.py) from the file
     ``--model_config`` names (its ``model_type`` says which of the
-    policy's families), its rings sized for this run's unroll and its
+    policy's families: ``token_policy.FAMILIES``), its rings sized for this run's unroll and its
     world's episodes.  Every combination it is not built for is refused
     here, at configuration time, by name."""
     from scalable_agent_tpu.envs.device import DEVICE_LEVELS
@@ -354,6 +354,11 @@ def build_token_policy(config: Config, action_space, frame_shape=None):
             ("ssm/state_bytes", agent.ssm_state_bytes(config.batch_size),
              "bytes of the state-space layers' recurrent states and "
              "convolution tails the rollout carries (float32)"),
+            ("ssd/state_bytes_per_env",
+             agent.ssm_state_bytes(1) if model.mamba_num_heads else 0,
+             "bytes an env of the Mamba-2 layers' matrix states and "
+             "convolution tails, over the layers, read off the state's "
+             "own arrays; 0 where no layer is a Mamba-2 scan"),
             ("policy/vocab_slice", model.vocab_size,
              "tokens of the vocabulary this chip's head and embedding "
              "hold")):

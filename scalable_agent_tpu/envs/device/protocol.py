@@ -243,6 +243,14 @@ register_device_level(
                 "tokens that repeat after 6,144 positions, from an "
                 "eighth of a 128,256-token vocabulary")
 register_device_level(
+    "token_recall_14k",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=16384, episode_length=14336, period=8192),
+    description="the same world for a policy that keeps the whole "
+                "episode in one full-attention ring among scans: "
+                "episodes of 14,336 tokens that repeat after 8,192 "
+                "positions, from an eighth of a 131,072-token vocabulary")
+register_device_level(
     "token_recall_small",
     "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
     dict(num_actions=64, episode_length=16, period=10),

@@ -1,15 +1,19 @@
 """A token policy: a decoder behind the agent's calling contract, its
 layers a mixer (attention through a ring of its own, attention into
-another layer's ring, a state-space scan, a gated memory unit) and an
-MLP (dense or experts), picked by the configuration file.  Three families
+another layer's ring, a state-space scan, a gated memory unit, a
+mixture of experts) and, where the family has one, an MLP (dense or
+experts) behind it, picked by the configuration file.  Four families
 (``FAMILIES``): ``afmoe`` (window and full attention mixed, a mixture of
 experts with a shared expert), ``phi4flash`` (the decoder-hybrid-
 decoder: state-space and window layers, one full-attention layer whose
 cache every later cross layer reads, memory units gated by the last
-state-space layer's output) and ``deepseek_v3`` (latent attention: the
+state-space layer's output), ``deepseek_v3`` (latent attention: the
 cache holds one compressed row a token, which every head's key and
 value are up-projections of; a mixture of experts with shared experts
-behind leading dense layers).
+behind leading dense layers) and ``nemotron_h`` (every layer ONE mixer
+alone, by the file's ``hybrid_override_pattern``: a Mamba-2 scan with a
+matrix state a head, a mixture of experts whose experts have no gate,
+or plain grouped-query attention).
 
 ``__call__(actions, env_outputs, state) -> ((policy_logits, baseline),
 state)`` over time-major ``[T, B]`` inputs, as ``ImpalaAgent`` has it:
@@ -19,13 +23,14 @@ token of the same vocabulary, and the agent's state is its attention
 cache (``TokenCache``): per layer that makes keys a ring of keys and
 values (of latent rows where the family's attention is latent), each
 slot's index in the env's token stream beside it, where
-each env's episode began, and per state-space layer its recurrent state
-and the last inputs of its short convolution.  An episode's end clears
+each env's episode began, and per state-space layer (Mamba-1's or
+Mamba-2's) its recurrent state and the last inputs of its short
+convolution.  An episode's end clears
 no ring: a query sees a key of its own episode only (ops/attention.py),
 so ``done`` moves ``episode_start`` and the stale slots fall out of
 every mask; a recurrence cannot be masked after the fact, so the scan
-zeroes its state at an episode's first token (ops/ssm.py) and the
-convolution drops the taps that reach before it.
+zeroes its state at an episode's first token (ops/ssm.py, ops/ssd.py)
+and the convolution drops the taps that reach before it.
 
 The ``afmoe`` layer, for token ids ``x`` (sizes under the source's key names,
 ``TokenModelConfig``; benchmark/references/afmoe_token.py is the plain
@@ -77,14 +82,28 @@ Wkvb_v,h``, so the query takes the up-projection in before the cache is
 read and the weighted sum of rows takes it after
 (``ops/attention.py latent_attention``).
 
+The ``nemotron_h`` layer (benchmark/references/nemotron_h_token.py;
+no embedding scale, no position encoding anywhere, RMSNorm)::
+
+    every layer:  h = h + Mixer(RMSNorm_in(h))      one sub-block, no MLP
+    M (Mamba-2):  [z | xBC | dt] = a W_in;  xBC = silu(conv_4(xBC) + b)
+                  [x | B | C] = xBC     x [heads, head_dim]; B, C [groups, N]
+                  delta = softplus(dt + dt_bias);  A = -exp(A_log)    a head
+                  y = scan(x, delta, A, B, C) + D x           (ops/ssd.py)
+                  out = (RMSNorm_group(y * silu(z)) * w) W_out
+    * (attention): softmax(q k / sqrt(head_dim)) v Wo   no rotation or gate
+    E (experts):  shared(a) + the held experts' part, an expert
+                  relu(a Wu)^2 Wd: two matrices, no gate       (ops/moe.py)
+
 The rings are sized so that ONE buffer serves the rollout and the
 update: ``window + unroll`` slots (``episode_length + unroll`` on a full
 layer) still hold, when an unroll ends, everything its first query may
 see, so the update attends into the cache as the rollout left it and
 masks the unroll's own slots by their index (``unroll_state``); no copy
 of the rings is kept from the unroll's start.  The recurrent state and
-the convolution's tail are kept from the start (0.8 MB an env at the
-published widths): the update scans again from them.
+the convolution's tail are kept from the start (0.8 MB an env at
+``phi4flash``'s published widths, 8.7 MB at ``nemotron_h``'s, whose
+state is a matrix a head): the update scans again from them.
 """
 
 import dataclasses
@@ -98,7 +117,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from scalable_agent_tpu.ops import attention as attention_lib
-from scalable_agent_tpu.ops import distributions, moe, ssm
+from scalable_agent_tpu.ops import distributions, moe, ssd, ssm
 from scalable_agent_tpu.types import StepOutput
 
 SLIDING = "sliding_attention"
@@ -107,7 +126,16 @@ CROSS = "cross_attention"       # queries only, into the last full layer's ring
 STATE_SPACE = "state_space"
 MEMORY_UNIT = "memory_unit"     # gated by the last state-space layer's output
 LATENT = "latent_attention"     # full attention through a ring of latent rows
-FAMILIES = ("afmoe", "phi4flash", "deepseek_v3")
+MAMBA2 = "mamba2"               # a matrix-state scan (ops/ssd.py)
+EXPERTS = "experts"             # the expert layer as the layer's one mixer
+FAMILIES = ("afmoe", "phi4flash", "deepseek_v3", "nemotron_h")
+_SCANS = (STATE_SPACE, MAMBA2)
+# ``hybrid_override_pattern``'s letters ("-", a dense MLP alone, is not
+# built)
+_PATTERN = {"M": MAMBA2, "E": EXPERTS, "*": FULL}
+# an expert's form by the file's activation (ops/moe.py): a silu expert
+# is gated (three matrices), a relu2 one is not (two)
+_GATED = {"silu": True, "relu2": False}
 _OWN_RING = (SLIDING, FULL, LATENT)
 # the keys a family's file must have (the rest of the fields default)
 _ALWAYS = ("vocab_size", "hidden_size", "num_attention_heads",
@@ -128,6 +156,14 @@ _REQUIRED = {
         "num_experts_per_tok", "first_k_dense_replace",
         "routed_scaling_factor", "norm_topk_prob", "rope_interleave",
         "rope_theta", "rms_norm_eps", "experts_held"),
+    "nemotron_h": _ALWAYS + (
+        "num_key_value_heads", "head_dim", "hybrid_override_pattern",
+        "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
+        "conv_kernel", "chunk_size", "moe_intermediate_size",
+        "moe_shared_expert_intermediate_size", "n_routed_experts",
+        "n_shared_experts", "num_experts_per_tok", "routed_scaling_factor",
+        "norm_topk_prob", "mlp_hidden_act", "layer_norm_epsilon",
+        "experts_held"),
 }
 # the source's key for what another family's file calls otherwise: the
 # expert layer is one (ops/moe.py), and reads one set of names
@@ -137,6 +173,11 @@ _SAID_AS = {
                     ("first_k_dense_replace", "num_dense_layers"),
                     ("routed_scaling_factor", "route_scale"),
                     ("norm_topk_prob", "route_norm")),
+    "nemotron_h": (("n_routed_experts", "num_experts"),
+                   ("n_shared_experts", "num_shared_experts"),
+                   ("routed_scaling_factor", "route_scale"),
+                   ("norm_topk_prob", "route_norm"),
+                   ("layer_norm_epsilon", "rms_norm_eps")),
 }
 # what a family's file may not say otherwise
 _ONLY = {
@@ -151,6 +192,14 @@ _ONLY = {
                     ("tie_word_embeddings", False),
                     ("attention_bias", False), ("n_group", 1),
                     ("topk_group", 1), ("moe_layer_freq", 1)),
+    # experts of two matrices under relu2, one group to choose from, no
+    # bias but the convolution's, no window
+    "nemotron_h": (("mlp_hidden_act", "relu2"),
+                   ("mamba_hidden_act", "silu"), ("n_group", 1),
+                   ("topk_group", 1), ("tie_word_embeddings", False),
+                   ("attention_bias", False), ("mamba_proj_bias", False),
+                   ("mlp_bias", False), ("use_bias", False),
+                   ("use_conv_bias", True), ("sliding_window", None)),
 }
 
 
@@ -198,10 +247,43 @@ class TokenModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    # nemotron_h
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 0
+    conv_kernel: int = 0
+    chunk_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    mlp_hidden_act: str = "silu"        # the experts' (``_GATED``)
 
     @property
     def d_inner(self) -> int:
+        if self.mamba_num_heads:
+            return self.mamba_num_heads * self.mamba_head_dim
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def conv_width(self) -> int:
+        """Channels a scan layer's short convolution runs over: Mamba-1's
+        ``x``, Mamba-2's ``x | B | C``."""
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def conv_taps(self) -> int:
+        return self.conv_kernel or self.mamba_d_conv
+
+    @property
+    def mixer_alone(self) -> bool:
+        """Every layer is its mixer and nothing else: no MLP behind it
+        (an expert layer is then a MIXER, by the file's pattern)."""
+        return self.model_type == "nemotron_h"
+
+    @property
+    def shared_expert_width(self) -> int:
+        return self.num_shared_experts * (
+            self.moe_shared_expert_intermediate_size
+            or self.moe_intermediate_size)
 
     @property
     def latent_dim(self) -> int:
@@ -210,6 +292,8 @@ class TokenModelConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     def is_expert_layer(self, layer: int) -> bool:
+        if self.mixer_alone:
+            return self.layer_types[layer] == EXPERTS
         return self.num_experts > 0 and layer >= self.num_dense_layers
 
     def ring_of(self, layer: int) -> Optional[int]:
@@ -261,6 +345,17 @@ class TokenModelConfig:
             values["layer_types"] = [LATENT] * raw["num_hidden_layers"]
             values.update(sliding_window=0, num_key_value_heads=0,
                           head_dim=0)
+        if family == "nemotron_h":
+            unknown = sorted(set(raw["hybrid_override_pattern"])
+                             - set(_PATTERN))
+            if unknown:
+                raise ValueError(
+                    f"token policy: hybrid_override_pattern letters "
+                    f"{unknown} are not built (only "
+                    f"{', '.join(map(repr, _PATTERN))})")
+            values["layer_types"] = [
+                _PATTERN[letter] for letter in raw["hybrid_override_pattern"]]
+            values["sliding_window"] = 0
         if family == "phi4flash":
             values["layer_types"] = [k["kind"] for k in raw["layer_kinds"]]
             values["layer_index"] = tuple(
@@ -269,7 +364,8 @@ class TokenModelConfig:
                               // raw["num_attention_heads"])
         values["layer_types"] = tuple(values["layer_types"])
         model = cls(**values)
-        kinds = {"afmoe": (SLIDING, FULL), "deepseek_v3": (LATENT,)}.get(
+        kinds = {"afmoe": (SLIDING, FULL), "deepseek_v3": (LATENT,),
+                 "nemotron_h": tuple(_PATTERN.values())}.get(
             family, (SLIDING, FULL, CROSS, STATE_SPACE, MEMORY_UNIT))
         if len(model.layer_types) != model.num_hidden_layers or any(
                 kind not in kinds for kind in model.layer_types):
@@ -277,6 +373,10 @@ class TokenModelConfig:
                 raise ValueError(
                     "token policy: layer_types must name sliding_attention "
                     "or full_attention for each of num_hidden_layers")
+            if family == "nemotron_h":
+                raise ValueError(
+                    "token policy: hybrid_override_pattern must have a "
+                    "letter for each of num_hidden_layers")
             raise ValueError(
                 f"token policy: layer_kinds must name one of {kinds} for "
                 f"each of num_hidden_layers")
@@ -295,6 +395,14 @@ class TokenModelConfig:
                     f"token policy: layer {layer} ({kind}) has no "
                     f"{FULL if kind == CROSS else STATE_SPACE} layer "
                     f"before it to read")
+        if family == "nemotron_h" and (
+                model.mamba_num_heads % model.n_groups
+                or model.d_inner % model.n_groups
+                or model.num_attention_heads % model.num_key_value_heads):
+            raise ValueError(
+                "token policy: the scan's heads and channels come in "
+                "n_groups equal groups, the query heads in "
+                "num_key_value_heads")
         if family == "phi4flash" and (
                 model.num_attention_heads % 2 or model.num_key_value_heads % 2
                 or (model.num_attention_heads // 2)
@@ -330,8 +438,11 @@ class TokenCache(NamedTuple):
     full_index: Any             # i32 [full slots]
     written: Any                # i32 []: tokens in every env's stream
     episode_start: Any          # i32 [B]: index at which the episode began
-    # per state-space layer, in order (ops/ssm.py has why states lie
-    # down the sublanes): f32 [B, d_state, d_inner], [B, d_conv - 1, d_inner]
+    # per state-space layer, in order.  Mamba-1 (ops/ssm.py has why
+    # states lie down the sublanes): f32 [B, d_state, d_inner], [B,
+    # d_conv - 1, d_inner].  Mamba-2 (ops/ssd.py), a matrix a head: f32
+    # [B, heads, head_dim, d_state], and the tail over ``x | B | C``:
+    # [B, conv_kernel - 1, d_inner + 2 * n_groups * d_state]
     ssm_state: Tuple[Any, ...] = ()
     conv_tail: Tuple[Any, ...] = ()
 
@@ -395,31 +506,39 @@ class _LayerNorm(nn.Module):
         return y * scale + bias
 
 
-class _GatedMLP(nn.Module):
+class _MLP(nn.Module):
+    """``(act(x Wg) * (x Wu)) Wd``, or without a gate ``act(x Wu) Wd``
+    (``ops/moe.py`` has the two forms)."""
+
     width: int
     dtype: Any
+    act: str = "silu"
 
     @nn.compact
     def __call__(self, x):
         hidden = x.shape[-1]
-        gate = _Linear(self.width, self.dtype, name="gate_proj")(x)
-        up = _Linear(self.width, self.dtype, name="up_proj")(x)
+        projected = [_Linear(self.width, self.dtype, name=name)(x)
+                     for name in (("gate_proj", "up_proj")
+                                  if _GATED[self.act] else ("up_proj",))]
         return _Linear(hidden, self.dtype, name="down_proj")(
-            jax.nn.silu(gate) * up)
+            moe.activate(projected, self.act))
 
 
 class _Experts(nn.Module):
-    """The held experts' stacked weights."""
+    """The held experts' stacked weights; no ``gate_proj`` (None) where
+    an expert has no gate."""
 
     held: int
     width: int
+    gated: bool = True
 
     @nn.compact
     def __call__(self, hidden: int):
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         return (self.param("gate_proj", init,
-                           (self.held, hidden, self.width)),
+                           (self.held, hidden, self.width))
+                if self.gated else None,
                 self.param("up_proj", init,
                            (self.held, hidden, self.width)),
                 self.param("down_proj", init,
@@ -443,17 +562,18 @@ class _MoE(nn.Module):
                 x, kernel, jnp.zeros((model.num_experts,), jnp.float32),
                 model.num_experts_per_tok, model.route_scale,
                 model.route_norm)
+        act = model.mlp_hidden_act
         gate_proj, up_proj, down_proj = _Experts(
-            model.experts_held, model.moe_intermediate_size,
+            model.experts_held, model.moe_intermediate_size, _GATED[act],
             name="experts")(hidden)
         routed, stats = moe.held_experts(
             x, routing, gate_proj, up_proj, down_proj, model.first_expert,
             model.num_experts, self.dtype,
-            every_expert=decode and x.shape[0] <= moe.EVERY_EXPERT_MAX_ROWS)
+            every_expert=decode and x.shape[0] <= moe.EVERY_EXPERT_MAX_ROWS,
+            act=act)
         with jax.named_scope("shared"):
-            shared = _GatedMLP(
-                model.moe_intermediate_size * model.num_shared_experts,
-                self.dtype, name="shared")(x)
+            shared = _MLP(model.shared_expert_width, self.dtype, act,
+                          name="shared")(x)
         return shared + routed, stats
 
 
@@ -534,6 +654,39 @@ class _Attention(nn.Module):
             ring_values = attention_lib.ring_write(ring_values, value,
                                                    written)
         out = out * jax.nn.sigmoid(gate)
+        return (_Linear(model.hidden_size, dtype, name="o_proj")(out),
+                ring_keys, ring_values, stats)
+
+
+class _PlainAttention(nn.Module):
+    """Grouped-query attention over the whole episode and nothing else:
+    no rotation, no bias, no gate, no head norm (``nemotron_h``)."""
+
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, index, episode_start, ring_keys, ring_values,
+                 ring_index, written):
+        model, dtype = self.model, self.dtype
+        batch, count, _ = a.shape
+        dim = model.head_dim
+
+        def project(name, heads):
+            return attention_lib.round_to(
+                _Linear(heads * dim, dtype, name=name)(a).reshape(
+                    batch, count, heads, dim), dtype)
+
+        query = project("q_proj", model.num_attention_heads)
+        key = project("k_proj", model.num_key_value_heads)
+        value = project("v_proj", model.num_key_value_heads)
+        with jax.named_scope("full"):
+            out, stats = attention_lib.cached_attention(
+                query, key, value, ring_keys, ring_values, ring_index,
+                index, episode_start, window=None)
+            ring_keys = attention_lib.ring_write(ring_keys, key, written)
+            ring_values = attention_lib.ring_write(ring_values, value,
+                                                   written)
         return (_Linear(model.hidden_size, dtype, name="o_proj")(out),
                 ring_keys, ring_values, stats)
 
@@ -676,6 +829,26 @@ def _family_dt_bias(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def _short_conv(module: nn.Module, x, position, tail, taps: int):
+    """``silu(conv_taps(x) + b)``, depthwise and causal, of ``module``'s
+    ``conv_kernel`` and ``conv_bias``: tap k reaches k tokens back (into
+    ``tail``, the call before's last inputs) and not before the episode.
+    -> (the result, the tail the next call continues from)."""
+    count, width = x.shape[1], x.shape[2]
+    with jax.named_scope("conv"):
+        kernel = module.param(
+            "conv_kernel", nn.initializers.normal(1.0 / math.sqrt(taps)),
+            (taps, width))
+        bias = module.param("conv_bias", nn.initializers.zeros_init(),
+                            (width,))
+        seen = jnp.concatenate([tail, x], axis=1)
+        x = bias + sum(
+            kernel[taps - 1 - back]
+            * seen[:, taps - 1 - back:taps - 1 - back + count]
+            * (position >= back)[..., None] for back in range(taps))
+        return jax.nn.silu(x), seen[:, count:]
+
+
 class _StateSpace(nn.Module):
     """The selective state-space mixer: a short causal convolution and
     the scan of ``ops/ssm.py``, both of which start afresh at an
@@ -687,25 +860,11 @@ class _StateSpace(nn.Module):
     @nn.compact
     def __call__(self, a, position, state, tail):
         model, dtype = self.model, self.dtype
-        count = a.shape[1]
         width, states = model.d_inner, model.mamba_d_state
-        taps, rank = model.mamba_d_conv, model.mamba_dt_rank
+        rank = model.mamba_dt_rank
         x, z = jnp.split(_Linear(2 * width, dtype, name="in_proj")(a), 2,
                          axis=-1)
-        with jax.named_scope("conv"):
-            kernel = self.param(
-                "conv_kernel", nn.initializers.normal(1.0 / math.sqrt(taps)),
-                (taps, width))
-            bias = self.param("conv_bias", nn.initializers.zeros_init(),
-                              (width,))
-            # tap k reaches k tokens back, and not before the episode
-            seen = jnp.concatenate([tail, x], axis=1)
-            x = bias + sum(
-                kernel[taps - 1 - back]
-                * seen[:, taps - 1 - back:taps - 1 - back + count]
-                * (position >= back)[..., None] for back in range(taps))
-            x = jax.nn.silu(x)
-            tail = seen[:, count:]
+        x, tail = _short_conv(self, x, position, tail, model.mamba_d_conv)
         chosen = _Linear(rank + 2 * states, dtype, name="x_proj")(x)
         delta = jax.nn.softplus(
             _Linear(width, dtype, name="dt_proj")(chosen[..., :rank])
@@ -725,6 +884,55 @@ class _StateSpace(nn.Module):
                 state, tail)
 
 
+class _Mamba2(nn.Module):
+    """The Mamba-2 mixer: one projection into gate, ``x | B | C`` and a
+    step a head, the short convolution over ``x | B | C``, the scan of
+    ``ops/ssd.py`` (a matrix state a head, one decay a head a token; both
+    start afresh at an episode's first token), and a norm over each
+    group's channels AFTER the gate."""
+
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, position, state, tail):
+        model, dtype = self.model, self.dtype
+        batch, count, _ = a.shape
+        heads, dim = model.mamba_num_heads, model.mamba_head_dim
+        groups, states = model.n_groups, model.ssm_state_size
+        width = model.d_inner
+        z, mixed, dt = jnp.split(
+            _Linear(width + model.conv_width + heads, dtype,
+                    name="in_proj")(a),
+            [width, width + model.conv_width], axis=-1)
+        mixed, tail = _short_conv(self, mixed, position, tail,
+                                  model.conv_kernel)
+        x, b, c = jnp.split(mixed, [width, width + groups * states], axis=-1)
+        delta = jax.nn.softplus(
+            dt + self.param("dt_bias", _family_dt_bias, (heads,)))
+        # the family's start: A uniform in [1, 16], one a head
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, 1.0, 16.0)), (heads,))
+        skip = self.param("D", nn.initializers.ones_init(), (heads,))
+        with jax.named_scope("scan"):
+            y, state = ssd.ssd_scan(
+                x.reshape(batch, count, heads, dim), delta, -jnp.exp(a_log),
+                skip, b.reshape(batch, count, groups, states),
+                c.reshape(batch, count, groups, states), position == 0,
+                state, chunk=model.chunk_size, dtype=dtype)
+        with jax.named_scope("norm_gate"):
+            gated = (y.reshape(batch, count, width)
+                     * jax.nn.silu(z)).reshape(batch, count, groups, -1)
+            gated = gated * jax.lax.rsqrt(
+                jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+                + model.rms_norm_eps)
+            gated = gated.reshape(batch, count, width) * self.param(
+                "norm_scale", nn.initializers.ones_init(), (width,))
+        return (_Linear(model.hidden_size, dtype, name="out_proj")(gated),
+                state, tail)
+
+
 class _MemoryUnit(nn.Module):
     model: TokenModelConfig
     dtype: Any
@@ -737,10 +945,12 @@ class _MemoryUnit(nn.Module):
 
 
 class _Layer(nn.Module):
-    """A mixer and an MLP between residual adds, as the family places
-    its norms.  ``ring_*``: the ring the mixer attends into (None where
-    it attends into none); ``state`` / ``tail``: a state-space mixer's;
-    ``handed``: what earlier layers of this call left."""
+    """A mixer, by the layer's kind in the file's pattern, and (unless
+    the model's layers are a mixer alone) an MLP, between residual adds,
+    as the family places its norms.  ``ring_*``: the ring the mixer
+    attends into (None where it attends into none); ``state`` / ``tail``:
+    a state-space mixer's; ``handed``: what earlier layers of this call
+    left."""
 
     model: TokenModelConfig
     layer: int
@@ -759,9 +969,28 @@ class _Layer(nn.Module):
                 return _LayerNorm(model.layer_norm_eps, name=name)
             return _RMSNorm(model.rms_norm_eps, name=name)
 
+        def experts(m):
+            flat = m.reshape(-1, m.shape[-1])
+            # one token an env: a decode step
+            f, routed = _MoE(model, dtype, name="moe")(
+                flat, decode=m.shape[1] == 1)
+            stats.update({f"moe/{name}": x for name, x in routed.items()})
+            return f.reshape(m.shape)
+
         seen = {}                   # what an attention pass says of itself
+        stats = {}
         a = norm("input_norm")(h)
-        if afmoe:
+        if kind == EXPERTS:
+            mixed = experts(a)
+        elif kind == MAMBA2:
+            mixed, state, tail = _Mamba2(model, dtype, name="ssd")(
+                a, position, state, tail)
+        elif model.mixer_alone:
+            mixed, ring_keys, ring_values, seen = _PlainAttention(
+                model, dtype, name="attention")(
+                    a, index, episode_start, ring_keys, ring_values,
+                    ring_index, written)
+        elif afmoe:
             mixed, ring_keys, ring_values, seen = _Attention(
                 model, kind == SLIDING, dtype, name="attention")(
                     a, position, index, episode_start, ring_keys,
@@ -785,18 +1014,16 @@ class _Layer(nn.Module):
                     name="attention")(
                         a, index, episode_start, ring_keys, ring_values,
                         ring_index, written, handed))
-        stats = {f"attention/{name}": x for name, x in seen.items()}
+        stats.update({f"attention/{name}": x for name, x in seen.items()})
         h = h + (norm("post_attn_norm")(mixed) if afmoe else mixed)
+        if model.mixer_alone:
+            return h, ring_keys, ring_values, handed, state, tail, stats
         m = norm("pre_mlp_norm")(h)
-        flat = m.reshape(-1, m.shape[-1])
         if model.is_expert_layer(self.layer):
-            # one token an env: a decode step
-            f, routed = _MoE(model, dtype, name="moe")(
-                flat, decode=m.shape[1] == 1)
-            stats.update({f"moe/{name}": x for name, x in routed.items()})
+            f = experts(m)
         else:
-            f = _GatedMLP(model.intermediate_size, dtype, name="mlp")(flat)
-        f = f.reshape(m.shape)
+            f = _MLP(model.intermediate_size, dtype, name="mlp")(
+                m.reshape(-1, m.shape[-1])).reshape(m.shape)
         h = h + (norm("post_mlp_norm")(f) if afmoe else f)
         return h, ring_keys, ring_values, handed, state, tail, stats
 
@@ -854,6 +1081,10 @@ _TELEMETRY = {
          "attention/decode_key_blocks_visited_share")),
 }
 _TELEMETRY["deepseek_v3"] = _TELEMETRY["afmoe"]
+# a shared expert's matrices count as the MLP, as in the first family
+_TELEMETRY["nemotron_h"] = (
+    ("embedding", "attention", "ssd", "experts", "mlp", "norms", "heads"),
+    _TELEMETRY["afmoe"][1])
 
 
 class TokenPolicy(nn.Module):
@@ -915,9 +1146,9 @@ class TokenPolicy(nn.Module):
             return "heads"
         if "embed" in keys:
             return "embedding"
-        if keys[-1] == "scale" or keys[-2].endswith("norm"):
+        if keys[-1].endswith("scale") or keys[-2].endswith("norm"):
             return "norms"
-        for group in ("attention", "ssm", "gmu"):
+        for group in ("attention", "ssm", "ssd", "gmu"):
             if group in keys:
                 return group
         if "experts" in keys or "router" in keys:
@@ -994,7 +1225,7 @@ class TokenPolicy(nn.Module):
     def _scan_layers(self) -> Tuple[int, ...]:
         return tuple(layer for layer, kind
                      in enumerate(self.model.layer_types)
-                     if kind == STATE_SPACE)
+                     if kind in _SCANS)
 
     @property
     def ring_readers(self) -> int:
@@ -1022,9 +1253,15 @@ class TokenPolicy(nn.Module):
 
         rings = tuple(ring(layer) for layer in self._ring_layers)
 
-        def per_scan(rows):
-            return tuple(jnp.zeros((batch, rows, model.d_inner), jnp.float32)
+        def per_scan(*shape):
+            return tuple(jnp.zeros((batch,) + shape, jnp.float32)
                          for _ in self._scan_layers)
+
+        if model.mamba_num_heads:       # Mamba-2: a matrix a head
+            scan_state = per_scan(model.mamba_num_heads, model.mamba_head_dim,
+                                  model.ssm_state_size)
+        else:
+            scan_state = per_scan(model.mamba_d_state, model.d_inner)
 
         return TokenCache(
             keys=rings,
@@ -1036,8 +1273,8 @@ class TokenPolicy(nn.Module):
                                 attention_lib.NO_KEY, jnp.int32),
             written=jnp.zeros((), jnp.int32),
             episode_start=jnp.zeros((batch,), jnp.int32),
-            ssm_state=per_scan(model.mamba_d_state),
-            conv_tail=per_scan(model.mamba_d_conv - 1))
+            ssm_state=scan_state,
+            conv_tail=per_scan(model.conv_taps - 1, model.conv_width))
 
     def unroll_state(self, start: TokenCache, end: TokenCache) -> TokenCache:
         """The state the update unrolls from, without a copy of the
@@ -1069,10 +1306,12 @@ class TokenPolicy(nn.Module):
         return batch * per_slot * self._slots(layer)
 
     def ssm_state_bytes(self, batch: int) -> int:
-        """The scans' states and the convolutions' tails, float32."""
-        model = self.model
-        return (batch * 4 * model.d_inner * len(self._scan_layers)
-                * (model.mamba_d_state + model.mamba_d_conv - 1))
+        """The scans' states and the convolutions' tails, read off the
+        state's own arrays (over the scan layers): a state kept a token
+        or a second copy beside the carried one would show."""
+        state = jax.eval_shape(lambda: self.initial_state(batch))
+        return sum(x.size * x.dtype.itemsize
+                   for x in state.ssm_state + state.conv_tail)
 
     def acting_params(self, params):
         """The parameters as acting reads them: cast once to the compute
@@ -1140,7 +1379,7 @@ class TokenPolicy(nn.Module):
             ring = (self._ring_layers.index(reads)
                     if reads is not None else None)
             scan = (self._scan_layers.index(layer)
-                    if kind == STATE_SPACE else None)
+                    if kind in _SCANS else None)
             h, ring_keys, ring_values, handed, scanned, tail, layer_stats = (
                 layer_cls(model, layer, dtype, name=f"layer_{layer}")(
                     h, position, index, start,

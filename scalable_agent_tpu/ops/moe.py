@@ -34,7 +34,10 @@ the chip holds half the experts or more there is nothing to compact and
 the one chunk holds every pair.  Rows of a chunk past the pairs that
 landed belong to no group, are not computed, and are masked out of
 both passes.  Shapes are fixed: how the load falls changes group sizes
-and the loop's length, never a shape.
+and the loop's length, never a shape.  The walk's stacks and rows are as
+wide as the grouped product's tiles want them (``lane_padded``:
+``nemotron_h``'s 2,688 x 1,856 walk as 3,072 x 2,048, zeros beyond; the
+decode's form takes the stacks as they are).
 
 A loop that stops where the input says is not differentiable by
 tracing, and a layer under ``nn.remat`` may keep only what its
@@ -43,6 +46,13 @@ whose forward keeps the first chunk's stage inputs (``_stages``) and
 whose backward pulls the cotangent back through them stage by stage,
 then walks the later chunks again, each one's forward inside the loop's
 body.
+
+An expert is one of two forms, which the caller's configuration
+names (``act``): gated, ``(act(x Wg) * (x Wu)) Wd`` (three matrices,
+``silu``: the first families'), or not, ``act(x Wu) Wd`` (two matrices,
+``relu2``, ``relu(.)^2``: ``nemotron_h``'s; ``gate_proj`` is None).  The
+walk, its stages and the decode's form are the same for both: the stage
+that projects takes one product or two.
 
 A decode step (``every_expert``: a few rows, one token an env) runs
 EVERY held expert over every row instead and weights by the routing (0
@@ -62,6 +72,24 @@ import jax
 import jax.numpy as jnp
 
 from scalable_agent_tpu.ops.attention import round_to
+
+
+ACTIVATIONS = {
+    "silu": jax.nn.silu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
+}
+
+
+def activate(projected, act: str):
+    """An expert's hidden row from its projections, (gate, up) or (up,):
+    ``act(gate) * up`` or ``act(up)``.  The projections are taken one at
+    a time, so a caller that makes them as they are asked for has the
+    gate's activation traced before the second product."""
+    projected = iter(projected)
+    hidden = ACTIVATIONS[act](next(projected))
+    for up in projected:
+        hidden = hidden * up
+    return hidden
 
 
 class Routing(NamedTuple):
@@ -99,6 +127,36 @@ def compact_rows(pairs: int, held: int, num_experts: int) -> int:
         return pairs
     rows = -(-2 * pairs * held // num_experts)
     return min(pairs, -(-rows // _ROW_TILE) * _ROW_TILE)
+
+
+# The grouped product's other two tiles.  XLA's kernel likewise takes, of
+# the contracted and of the result's width, the largest power of two up
+# to 512 that divides it (``ragged_dot_tiling="256,128,128"`` for
+# ``nemotron_h``'s 2,688 x 1,856 where the first families' 2,048 x 1,024
+# and 2,048 x 768 read ``"256,512,512"`` and ``"256,512,256"``; compiled
+# for a v5e, PR 42).  A width of more than one tile that is not whole
+# tiles of 256 is padded with zeros up to whole tiles of 512 for the walk:
+# zero columns of a projection give ``act(0) = 0`` (both forms'
+# activations), zero rows of the down projection add nothing, so the sums
+# are the unpadded ones.
+_LANE_TILE = 256
+_LANE_PAD = 512
+
+
+def lane_padded(size: int) -> int:
+    """``size`` as the walk's products see it (the comment above)."""
+    if size % _LANE_TILE == 0 or size < _LANE_PAD:
+        return size
+    return -(-size // _LANE_PAD) * _LANE_PAD
+
+
+def _pad_last(stack, *sizes: int):
+    """``stack`` with its last ``len(sizes)`` axes zero-padded to
+    ``sizes``; the stack itself where they are its own."""
+    pads = [(0, 0)] * (stack.ndim - len(sizes)) + [
+        (0, size - have)
+        for size, have in zip(sizes, stack.shape[stack.ndim - len(sizes):])]
+    return jnp.pad(stack, pads) if any(hi for _, hi in pads) else stack
 
 
 class _Dispatch(NamedTuple):
@@ -177,12 +235,12 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 EVERY_EXPERT_MAX_ROWS = 128
 
 
-def _every_expert(x, routing: Routing, gate_proj, up_proj, down_proj,
-                  first_expert: int, dtype):
+def _every_expert(x, routing: Routing, projections, down_proj,
+                  first_expert: int, dtype, act: str):
     """Every held expert over every row, weighted by the routing: the
     roundings and the float32 sums of the grouped path, in another
     order."""
-    held = gate_proj.shape[0]
+    held = down_proj.shape[0]
     with jax.named_scope("dispatch"):
         local = routing.chosen - first_expert
         chose = local[..., None] == jnp.arange(held, dtype=jnp.int32)
@@ -195,8 +253,8 @@ def _every_expert(x, routing: Routing, gate_proj, up_proj, down_proj,
             return jnp.einsum(spec, round_to(lhs, dtype), rhs.astype(dtype),
                               preferred_element_type=jnp.float32)
 
-        hidden = (jax.nn.silu(product(x, gate_proj, "nh,ehw->enw"))
-                  * product(x, up_proj, "nh,ehw->enw"))
+        hidden = activate((product(x, stack, "nh,ehw->enw")
+                           for stack in projections), act)
         out = product(hidden, down_proj, "enw,ewh->enh")
     with jax.named_scope("combine"):
         # a float32 sum, not a dot: a dot would round both to bfloat16
@@ -204,7 +262,7 @@ def _every_expert(x, routing: Routing, gate_proj, up_proj, down_proj,
     return y, sizes
 
 
-def _stages(dispatch: _Dispatch, dtype, rows: int, chunk):
+def _stages(dispatch: _Dispatch, dtype, rows: int, chunk, act: str):
     """The grouped path over rows ``[chunk * rows, (chunk + 1) * rows)``
     of the sorted pairs as a chain of stages: each takes the stage
     before's result, then the layer's inputs it reads
@@ -240,14 +298,14 @@ def _stages(dispatch: _Dispatch, dtype, rows: int, chunk):
                 live, _take_tokens(round_to(x, dtype), order, slot, mine,
                                    top_k), 0)
 
-    def project(taken, gate_proj, up_proj):
+    def project(taken, *projections):
         with jax.named_scope("experts"):
-            return grouped(taken, gate_proj), grouped(taken, up_proj)
+            return tuple(grouped(taken, stack) for stack in projections)
 
     def gate(projected):
         with jax.named_scope("experts"):
-            return round_to(jnp.where(
-                live, jax.nn.silu(projected[0]) * projected[1], 0), dtype)
+            return round_to(jnp.where(live, activate(projected, act), 0),
+                            dtype)
 
     def down(hidden, down_proj):
         with jax.named_scope("experts"):
@@ -262,36 +320,39 @@ def _stages(dispatch: _Dispatch, dtype, rows: int, chunk):
     return take, project, gate, down, combine
 
 
-def _stage_inputs(weights, gate_proj, up_proj, down_proj):
+def _stage_inputs(weights, projections, down_proj):
     """What each of ``_stages`` reads beside the stage before's
     result."""
-    return (), (gate_proj, up_proj), (), (down_proj,), (weights,)
+    return (), tuple(projections), (), (down_proj,), (weights,)
 
 
 # One chunk's two passes are jitted so that they are traced and lowered
 # once for a whole model: every expert layer, its rematerialized twin and
 # both loops' bodies call the same two functions at the same shapes.
 
-@partial(jax.jit, static_argnames=("dtype", "rows"))
-def _forward(x, inputs, dispatch, chunk, *, dtype, rows):
+@partial(jax.jit, static_argnames=("dtype", "rows", "act"))
+def _forward(x, inputs, dispatch, chunk, *, dtype, rows, act="silu"):
     """The chain's result for one chunk, and the input of each stage
     after the first."""
     kept = []
-    for stage, more in zip(_stages(dispatch, dtype, rows, chunk), inputs):
+    for stage, more in zip(_stages(dispatch, dtype, rows, chunk, act),
+                           inputs):
         x = stage(x, *more)
         kept.append(x)
     return kept.pop(), kept
 
 
-@partial(jax.jit, static_argnames=("dtype", "rows"))
-def _backward(x, kept, inputs, dispatch, chunk, g, *, dtype, rows):
+@partial(jax.jit, static_argnames=("dtype", "rows", "act"))
+def _backward(x, kept, inputs, dispatch, chunk, g, *, dtype, rows,
+              act="silu"):
     """``g`` pulled back through one chunk's chain from each stage's
     kept input: the cotangent of ``x`` and, a stage a tuple, of
     ``inputs``.  A stage's own result is not computed again unless its
     cotangent needs it (a product's needs its operands alone)."""
     d_inputs = []
     for stage, at, more in reversed(list(zip(
-            _stages(dispatch, dtype, rows, chunk), [x, *kept], inputs))):
+            _stages(dispatch, dtype, rows, chunk, act), [x, *kept],
+            inputs))):
         g, *d_more = jax.vjp(stage, at, *more)[1](g)
         d_inputs.insert(0, tuple(d_more))
     return g, tuple(d_inputs)
@@ -315,42 +376,44 @@ def _walk_on(chunks, one_chunk, total):
             jnp.add, total, one_chunk(chunk)), total)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _grouped(x, weights, dispatch: _Dispatch, gate_proj, up_proj, down_proj,
-             dtype, rows: int):
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _grouped(x, weights, dispatch: _Dispatch, projections, down_proj,
+             dtype, rows: int, act: str):
     """The weighted sum over the held experts, the sorted pairs walked
-    ``rows`` rows at a time (the module's docstring).  The stacks come
-    in the compute dtype: cast outside, the update shares the step's
-    one cast of them with the decode."""
-    return _grouped_fwd(x, weights, dispatch, gate_proj, up_proj, down_proj,
-                        dtype, rows)[0]
+    ``rows`` rows at a time (the module's docstring).  The stacks
+    (``projections``: (gate, up) or (up,)) come in the compute dtype:
+    cast outside, the update shares the step's one cast of them with
+    the decode."""
+    return _grouped_fwd(x, weights, dispatch, projections, down_proj, dtype,
+                        rows, act)[0]
 
 
-def _grouped_fwd(x, weights, dispatch, gate_proj, up_proj, down_proj, dtype,
-                 rows):
-    inputs = _stage_inputs(weights, gate_proj, up_proj, down_proj)
-    forward = partial(_forward, x, inputs, dispatch, dtype=dtype, rows=rows)
+def _grouped_fwd(x, weights, dispatch, projections, down_proj, dtype, rows,
+                 act):
+    inputs = _stage_inputs(weights, projections, down_proj)
+    forward = partial(_forward, x, inputs, dispatch, dtype=dtype, rows=rows,
+                      act=act)
     y, kept = forward(jnp.int32(0))
     y = _walk_on(_chunks(dispatch, rows), lambda chunk: forward(chunk)[0], y)
     return y, (x, kept, inputs, dispatch)
 
 
-def _grouped_bwd(dtype, rows, residuals, g):
+def _grouped_bwd(dtype, rows, act, residuals, g):
     x, kept, inputs, dispatch = residuals
 
     def backward(chunk, kept):
         return _backward(x, kept, inputs, dispatch, chunk, g, dtype=dtype,
-                         rows=rows)
+                         rows=rows, act=act)
 
     def later(chunk):
         # a later chunk kept nothing: its forward again, in the loop
         return backward(chunk, _forward(x, inputs, dispatch, chunk,
-                                        dtype=dtype, rows=rows)[1])
+                                        dtype=dtype, rows=rows, act=act)[1])
 
-    d_x, ((), (d_gate_proj, d_up_proj), (), (d_down_proj,), (d_weights,)) = (
+    d_x, ((), d_projections, (), (d_down_proj,), (d_weights,)) = (
         _walk_on(_chunks(dispatch, rows), later,
                  backward(jnp.int32(0), kept)))
-    return d_x, d_weights, None, d_gate_proj, d_up_proj, d_down_proj
+    return d_x, d_weights, None, d_projections, d_down_proj
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
@@ -358,20 +421,22 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 def held_experts(x, routing: Routing, gate_proj, up_proj, down_proj,
                  first_expert: int, num_experts: int, dtype,
-                 every_expert: bool = False
+                 every_expert: bool = False, act: str = "silu"
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """``sum_{e chosen and held here} w_e * expert_e(x)`` for x [N,
-    hidden] and the held experts' stacked silu-gated MLPs
-    (``gate_proj`` / ``up_proj`` [held, hidden, width], ``down_proj``
-    [held, width, hidden]) of ``num_experts`` in all, with the load's
-    numbers.  ``every_expert``: the decode step's form (the module's
+    hidden] and the held experts' stacked MLPs (``gate_proj`` /
+    ``up_proj`` [held, hidden, width], ``down_proj`` [held, width,
+    hidden]; ``gate_proj`` None: experts without a gate, ``act(x Wu)
+    Wd``) of ``num_experts`` in all, with the load's numbers.
+    ``every_expert``: the decode step's form (the module's
     docstring)."""
     tokens, top_k = routing.chosen.shape
-    held = gate_proj.shape[0]
+    held = down_proj.shape[0]
     pairs = tokens * top_k
+    projections = (up_proj,) if gate_proj is None else (gate_proj, up_proj)
     if every_expert:
-        y, sizes = _every_expert(x, routing, gate_proj, up_proj, down_proj,
-                                 first_expert, dtype)
+        y, sizes = _every_expert(x, routing, projections, down_proj,
+                                 first_expert, dtype, act)
         return y, _load(sizes, pairs)
     with jax.named_scope("dispatch"):
         local = routing.chosen - first_expert
@@ -385,13 +450,18 @@ def held_experts(x, routing: Routing, gate_proj, up_proj, down_proj,
             group[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
     rows = compact_rows(pairs, held, num_experts)
+    hidden, width = x.shape[1], down_proj.shape[1]
+    wide, deep = lane_padded(hidden), lane_padded(width)
     with jax.named_scope("experts"):
-        stacks = [stack.astype(dtype)
-                  for stack in (gate_proj, up_proj, down_proj)]
+        projections = [_pad_last(stack.astype(dtype), wide, deep)
+                       for stack in projections]
+        down_proj = _pad_last(down_proj.astype(dtype), deep, wide)
     y = _grouped(
-        x, routing.weights, _Dispatch(
+        _pad_last(x, wide), routing.weights, _Dispatch(
             jnp.pad(order, (0, -pairs % rows)), slot, here, sizes),
-        *stacks, dtype, rows)
+        tuple(projections), down_proj, dtype, rows, act)
+    if wide != hidden:
+        y = y[:, :hidden]
     return y, dict(_load(sizes, pairs), compact_share=jnp.float32(
         (jnp.sum(sizes) <= rows) if rows < pairs else 0.0))
 

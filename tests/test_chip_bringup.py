@@ -733,6 +733,44 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= f32\[([\d,]+)\]", text))
         assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
 
+    def test_ssd_scan_compiles_with_no_state_a_token_in_hbm(
+            self, monkeypatch, v5e_topology):
+        """ISSUE 42: the chunked Mamba-2 scan's kernels (ops/ssd.py, T >
+        1) at ``nemotron3.ingraph``'s widths — 257 tokens, 64 heads of 64
+        channels, 8 groups of 128 states, chunks of 128, bfloat16
+        operands — compiled alone for a v5e, forward and backward: two
+        Mosaic calls, and no float32 result as large as a state a token
+        (the forward keeps a state a chunk: three of them)."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import ssd
+
+        _as_tpu(monkeypatch)
+        envs, tokens, heads, dim, groups, states = 2, 257, 64, 64, 8, 128
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(x, delta, a, d, b, c, state, reset):
+            y, last = ssd.ssd_scan(x, delta, a, d, b, c, reset, state,
+                                   chunk=128, dtype=jnp.bfloat16)
+            return jnp.sum(y) + jnp.sum(last)
+
+        text = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+            operand((envs, tokens, heads, dim)),
+            operand((envs, tokens, heads)), operand((heads,)),
+            operand((heads,)), operand((envs, tokens, groups, states)),
+            operand((envs, tokens, groups, states)),
+            operand((envs, heads, dim, states)),
+            operand((envs, tokens), jnp.bool_)).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        a_state_a_token = envs * tokens * heads * dim * states
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
+
     @pytest.mark.parametrize("devices,overrides,merged,handed", [
         (1, {}, 101 * 256, True),
         (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128, False),

@@ -34,6 +34,7 @@ CONFORMANCE_LEVELS = (
     "fake_small",
     "token_recall",
     "token_recall_10k",
+    "token_recall_14k",
     "token_recall_long",
     "token_recall_small",
 )

@@ -108,7 +108,8 @@ def test_a_family_the_policy_does_not_build_is_refused_with_the_list(
     with pytest.raises(ValueError,
                        match="afmoe.*phi4flash.*deepseek_v3"):
         driver.main(argv)
-    assert token_policy.FAMILIES == ("afmoe", "phi4flash", "deepseek_v3")
+    # later families come after these three, which keep their places
+    assert token_policy.FAMILIES[:3] == ("afmoe", "phi4flash", "deepseek_v3")
 
 
 @pytest.mark.parametrize("flags, names", [
@@ -248,7 +249,6 @@ def test_the_cells_entry_names_its_traffic_and_its_metrics():
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         "kanana2_30b_ep8", "fused_token_recall_u256_e10240", 1)
     assert "384" in entry["why"] and "8x" in entry["why"]
-    assert bench["workloads"][-1] is entry
     cell = manifest.load_cell("kanana2.ingraph")
     flags = manifest.driver_flags(cell)
     assert (flags["batch_size"], flags["unroll_length"],

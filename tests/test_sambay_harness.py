@@ -75,7 +75,13 @@ def driver_argv(tmp_path, cfg, *more):
         "--log_interval_s=0.2", *more]
 
 
-def test_three_updates_through_the_driver(tmp_path):
+def test_three_updates_through_the_driver(tmp_path, monkeypatch):
+    from scalable_agent_tpu.obs import registry
+
+    # a registry of this run's own: the process's one holds whatever an
+    # earlier file's driver run in the same worker left (an expert
+    # family's groups), and a group is asserted ABSENT below
+    monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
     final = driver.main(driver_argv(tmp_path, TINY))
     assert final["env_frames"] == 3 * BATCH * UNROLL
     assert np.isfinite(final["total_loss"])
