@@ -762,6 +762,19 @@ def index_write(ring_index, written, count: int):
 # wants it rows-major pays a copy of every ring into the step and one
 # out of it (2 GB more at the peak; AOT for a v5e, PR 38).  Keys along
 # the lanes is also how the decode's scores lie.
+#
+# The layout's price is the acting step's write: a token's 576 numbers
+# lie one in each of 576 rows, so as a slice update they are 18,432
+# elements an env batch of 32, each in a tile of its own, and XLA's took
+# 0.081 ms a layer a decode step for 36 KB of new data (PR 38's trace).
+# The chip moves whole ``(sublanes, 128)`` tiles at its memory's rate,
+# so ``latent_ring_write`` moves the ONE lane tile that holds the slot:
+# ``_latent_slot_write`` brings ``[envs, D, 128]`` (36 bfloat16 tiles an
+# env, 147 KB), replaces lane ``slot % 128`` by a select against a lane
+# iota and writes the block back into the same buffer (the ring aliased
+# to the kernel's result): 9.4 MB a call in place of 18,432 elements one
+# at a time, the same bytes in the same slots.  More tokens than one
+# (the update) keep the scatter.
 
 _LATENT_LANES = 2304            # query lanes (heads x queries) a grid step of
                                 # the update: its scores are [block, lanes]
@@ -1128,15 +1141,101 @@ def latent_attention(query, latent, ring, ring_index, index, episode_start,
              "decode_key_blocks_visited_share": _visited_share(decode)})
 
 
+_SLOT_BLOCK_BYTES = 5 * 2 ** 18    # of a ring a grid step of the slot write
+# brings and writes back: 8 envs' tiles of 147 KB at the cell's widths,
+# four steps over 32 envs.  On a v5e the write alone reads 18.5 / 17.5 /
+# 16.4 / 20.0 us at 4 / 8 / 16 / 32 envs a step (XLA's slice update
+# of this layout took 80, in the step; PR 43): 8 and 16 are within 1.4 ms
+# of a 1,543 ms step, and 8 is the form the cell was measured with.
+
+
+def _latent_slot_write_kernel(slot_ref, columns_ref, ring_ref, out_ref):
+    """The lane tile of a block of envs with one lane replaced:
+    ``ring_ref`` / ``out_ref`` [b, D, 128] (the same memory),
+    ``columns_ref`` [D, b] the envs' new rows, each a column."""
+    here = jax.lax.broadcasted_iota(
+        jnp.int32, ring_ref.shape[1:], 1) == slot_ref[0] % _LANES
+    for env in range(ring_ref.shape[0]):
+        out_ref[env] = jnp.where(here, columns_ref[:, env:env + 1],
+                                 ring_ref[env])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _latent_slot_write(ring, rows, slot, *, interpret):
+    """``ring`` [B, D, S] with ``rows`` [B, D] (the ring's dtype) in
+    column ``slot``, in place: the kernel's result is the ring's own
+    buffer, of which it touches lane tile ``slot // 128`` alone."""
+    batch, dim, _ = ring.shape
+    tile = dim * _LANES * ring.dtype.itemsize
+    envs = max((envs for envs in range(1, batch + 1) if batch % envs == 0
+                and envs * tile <= _SLOT_BLOCK_BYTES), default=1)
+    steps = batch // envs
+
+    def lane_tile(step, slot):
+        return step, 0, slot[0] // _LANES
+
+    return pl.pallas_call(
+        _latent_slot_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,),
+            in_specs=[pl.BlockSpec((None, dim, envs),
+                                   lambda step, slot: (step, 0, 0)),
+                      pl.BlockSpec((envs, dim, _LANES), lane_tile)],
+            out_specs=pl.BlockSpec((envs, dim, _LANES), lane_tile)),
+        out_shape=jax.ShapeDtypeStruct(ring.shape, ring.dtype),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="latent_slot_write")(
+            slot.astype(jnp.int32).reshape(1),
+            jnp.swapaxes(rows.reshape(steps, envs, dim), 1, 2), ring)
+
+
+def _moves_whole_tiles(ring) -> bool:
+    """Whether a column of ``ring`` [B, D, S] lies in whole tiles of one
+    lane tile: the slots whole lane tiles, the rows whole sublane tiles
+    of the dtype (8 rows of 32 bits, 16 of 16)."""
+    return (ring.shape[2] % _LANES == 0
+            and ring.shape[1] % (32 // ring.dtype.itemsize) == 0)
+
+
+_slot_writes = [0, 0]    # one-token latent writes traced; those in the kernel
+
+
+def _count_slot_write(kernel: bool):
+    """Trace time: one more T = 1 latent write, through the kernel or
+    not (``attention/latent_slot_kernel_share``)."""
+    from scalable_agent_tpu.obs.registry import get_registry
+
+    _slot_writes[0] += 1
+    _slot_writes[1] += kernel
+    get_registry().gauge(
+        "attention/latent_slot_kernel_share",
+        "share of the one-token latent ring writes traced that move the "
+        "slot's one lane tile in place (ops/attention.py "
+        "_latent_slot_write) and not XLA's element-wise slice update: 1.0 "
+        "where every ring's shape fits the kernel").set(
+            _slot_writes[1] / _slot_writes[0])
+
+
 def latent_ring_write(ring, rows, written):
     """``ring_write`` into a latent ring [B, D, S]: ``rows`` [B, T, D] in
-    the columns of stream indices ``written .. written + T - 1``."""
+    the columns of stream indices ``written .. written + T - 1``.  One
+    token into a ring of whole tiles moves the slot's lane tile
+    (``_latent_slot_write``); one into any other ring is a slice update,
+    more are a scatter.  The same bytes in the same slots each way."""
+    from scalable_agent_tpu.parallel.mesh import pallas_interpret
+
     slots = ring.shape[2]
     count = rows.shape[1]
-    columns = jnp.swapaxes(round_to(rows, ring.dtype), 1, 2)
+    rows = round_to(rows, ring.dtype)
     if count == 1:
+        kernel = _moves_whole_tiles(ring)
+        _count_slot_write(kernel)
+        if kernel:
+            return _latent_slot_write(ring, rows[:, 0], written % slots,
+                                      interpret=pallas_interpret())
         return jax.lax.dynamic_update_slice_in_dim(
-            ring, columns, written % slots, axis=2)
+            ring, jnp.swapaxes(rows, 1, 2), written % slots, axis=2)
     at = (written + jnp.arange(count, dtype=jnp.int32)) % slots
-    return ring.at[:, :, at].set(columns)
-
+    return ring.at[:, :, at].set(jnp.swapaxes(rows, 1, 2))

@@ -698,6 +698,60 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= \w+\[([\d,]+)\]", text))
         assert anything < envs * slots * heads * 128, anything
 
+    def test_latent_slot_write_compiles_in_place_in_a_scanned_decode(
+            self, monkeypatch, v5e_topology):
+        """ISSUE 43: a scanned decode step at ``kanana2.ingraph``'s
+        widths (32 envs, rows of 576, ``latent_ring_slots(10240 + 256,
+        1152)`` slots, the ring in the carry): the decode kernel reads
+        the ring and ``latent_ring_write`` then moves the slot's lane
+        tile in a Mosaic call of its own with the ring aliased to its
+        result.  The compiled text holds no ``dynamic-update-slice`` of
+        the ring and no ring-sized ``copy`` (an aliased call XLA cannot
+        order after the reader gets one: 396 MB a ring a decode step),
+        and the program's temporaries are far under one ring."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import attention
+
+        _as_tpu(monkeypatch)
+        envs, heads, dim, value_dim, steps = 32, 32, 576, 512, 4
+        slots = attention.latent_ring_slots(10240 + 256, dim * 2)
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def act(ring, ring_index, written, queries, latents, episode_start):
+            def step(carry, new):
+                ring, ring_index, written = carry
+                query, latent = new
+                out, _ = attention.latent_attention(
+                    query, latent, ring, ring_index, written[None],
+                    episode_start, value_dim, 192 ** -0.5)
+                ring = attention.latent_ring_write(ring, latent, written)
+                ring_index = attention.index_write(ring_index, written, 1)
+                return (ring, ring_index, written + 1), out
+
+            return jax.lax.scan(step, (ring, ring_index, written),
+                                (queries, latents))
+
+        compiled = jax.jit(act, donate_argnums=(0, 1)).lower(
+            operand((envs, dim, slots)), operand((slots,), jnp.int32),
+            operand((), jnp.int32), operand((steps, envs, 1, heads, dim)),
+            operand((steps, envs, 1, dim)),
+            operand((envs, 1), jnp.int32)).compile()
+        text = compiled.as_text()
+        ring = re.escape(f"bf16[{envs},{dim},{slots}]")
+        made = re.findall(
+            rf"^\s*(?:ROOT )?%\S+ = {ring}\S* ([\w\-]+)\(", text, re.M)
+        assert set(made) <= {"parameter", "get-tuple-element",
+                             "custom-call", "bitcast"}, made
+        assert made.count("custom-call") == 1       # the write, aliased
+        assert text.count("tpu_custom_call") == 2   # and the decode
+        assert "output_to_operand_aliasing" in text
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < envs * dim * slots * 2 // 100)
+
     def test_selective_scan_compiles_with_no_state_a_token_in_hbm(
             self, monkeypatch, v5e_topology):
         """ISSUE 34: the selective-scan kernels (ops/ssm.py, T > 1) at

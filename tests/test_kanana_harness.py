@@ -260,7 +260,7 @@ def test_the_cells_entry_names_its_traffic_and_its_metrics():
     assert sorted(mine) == [
         "latent_attention_device_share.fused",
         "latent_cache_bytes_per_token", "latent_decode_roofline.fused",
-        "latent_update_roofline.fused"]
+        "latent_slot_kernel_share", "latent_update_roofline.fused"]
     assert all(e["moves"] == "fused_env_frames_per_s"
                for e in mine.values())
     assert {"device_mfu.fused", "fused_step_device_ms"} <= {

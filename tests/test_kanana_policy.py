@@ -267,6 +267,53 @@ def test_stepwise_logits_are_the_chunked_forwards(forty_steps, chunk):
                                        atol=1e-5)
 
 
+def test_acting_through_the_slot_kernel_is_the_chunked_forward(monkeypatch):
+    """Rows of 20 + 4 numbers in float32 and rings of 122 + 6 slots: whole
+    sublane tiles and one whole lane tile, so every acting step's write
+    moves the slot's lane tile in the kernel (``ops/attention.py
+    _latent_slot_write``; the preset's rings of 22 slots keep the slice
+    update).  Fourteen steps across two episode ends a step at a time
+    give the logits and the rings of two chunks of seven, whose writes
+    are the scatter (float32's rounding apart: the rows come from
+    products of other shapes)."""
+    from scalable_agent_tpu.obs import registry
+    from scalable_agent_tpu.ops import attention
+
+    monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
+    monkeypatch.setattr(attention, "_slot_writes", [0, 0])
+    cfg = dict(TINY, kv_lora_rank=20)
+    agent = TokenPolicy(model=TokenModelConfig.from_dict(cfg),
+                        unroll_length=UNROLL, episode_length=122,
+                        compute_dtype=jnp.float32)
+    params, steps, chunk = weights(4, cfg), 14, 7
+    rng = np.random.default_rng(13)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (steps, BATCH)), jnp.int32)
+    done = np.zeros((steps, BATCH), bool)
+    done[0] = True
+    done[5, 1] = done[9, 3] = True
+    done = jnp.asarray(done)
+    step = jax.jit(lambda p, e, s: agent.apply(
+        p, jnp.zeros(e.done.shape, jnp.int32), e, s))
+    state, stepwise = agent.initial_state(BATCH), []
+    assert [r.shape for r in state.keys] == [(BATCH, 24, 128)] * 3
+    for t in range(steps):
+        (row, _), state = step(
+            params, env_outputs(tokens[t:t + 1], done[t:t + 1]), state)
+        stepwise.append(row[0])
+    assert registry.get_registry().snapshot()[
+        "attention/latent_slot_kernel_share"] == 1.0
+    chunked, rows = agent.initial_state(BATCH), []
+    for t in range(0, steps, chunk):
+        (logits, _), chunked = agent.apply(
+            params, jnp.zeros((chunk, BATCH), jnp.int32),
+            env_outputs(tokens[t:t + chunk], done[t:t + chunk]), chunked)
+        rows.append(logits)
+    assert rel(jnp.stack(stepwise), jnp.concatenate(rows)) < 1e-5
+    for a, b in zip(state.keys, chunked.keys):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        assert float(jnp.max(jnp.abs(a))) > 0.0
+
+
 # -- (c) the state, and what the update unrolls from --------------------------
 
 def test_the_state_is_one_ring_of_rows_a_layer():

@@ -204,3 +204,111 @@ def test_bfloat16_rows_stay_within_their_rounding():
         got, _ = absorbed(a)
         assert rel(got, up_projected(b)) < 0.03
     del want
+
+
+# -- the ring's write: one token moves the slot's lane tile -------------------
+
+SLOTS, ROWS = 256, 32           # two lane tiles; whole sublane tiles of both
+
+
+def slice_update(ring, rows, written):
+    """What ``latent_ring_write`` was before the kernel: XLA's slice
+    update of one column, a scatter of more."""
+    slots, count = ring.shape[2], rows.shape[1]
+    columns = jnp.swapaxes(A.round_to(rows, ring.dtype), 1, 2)
+    if count == 1:
+        return jax.lax.dynamic_update_slice_in_dim(
+            ring, columns, written % slots, axis=2)
+    at = (written + jnp.arange(count, dtype=jnp.int32)) % slots
+    return ring.at[:, :, at].set(columns)
+
+
+
+WRITES = {
+    **{f"{dtype} written {written}": dict(dtype=dtype, written=written)
+       for dtype in ("bfloat16", "float32")
+       for written in (0, 127, 128, SLOTS - 1, SLOTS, SLOTS + 129)},
+    "two writes in a row": dict(written=127, writes=2),
+    "under scan, the ring in the carry": dict(written=250, writes=9,
+                                              scan=True),
+    "four grid steps of two envs": dict(written=131, envs=8, block_envs=2),
+    "envs no block divides go one a step": dict(written=5, envs=3,
+                                                block_envs=2),
+    "more tokens than one keep the scatter": dict(
+        written=250, tokens=7, kernel=None),
+    "slots that are no whole lane tiles keep the slice update": dict(
+        written=13, slots=12, kernel=False),
+    "rows that are no whole sublane tiles keep the slice update": dict(
+        written=129, rows=RANK + ROPE, kernel=False),
+    "bfloat16 rows of 8 are half a sublane tile": dict(
+        written=129, rows=8, kernel=False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRITES))
+def test_the_slot_write_is_the_slice_update_bit_for_bit(name, monkeypatch):
+    """``latent_ring_write``: one token into a ring of whole tiles goes
+    through ``_latent_slot_write`` (the lane tile that holds the slot
+    brought, one lane replaced, written back in place) and gives the
+    slice update's bytes, wrapped slots and all; any other shape keeps
+    the slice update or the scatter.  ``kernel``: what the trace-time
+    gauge has to say of the case (None: it counts one-token writes
+    only)."""
+    from scalable_agent_tpu.obs import registry
+
+    monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
+    monkeypatch.setattr(A, "_slot_writes", [0, 0])
+    spec = dict(dtype="bfloat16", writes=1, scan=False, envs=2, tokens=1,
+                slots=SLOTS, rows=ROWS, kernel=True, block_envs=None)
+    spec.update(WRITES[name])
+    dtype = jnp.dtype(spec["dtype"])
+    if spec["block_envs"]:      # read where the call is traced: a shape
+        monkeypatch.setattr(    # of the case's own, so nothing cached
+            A, "_SLOT_BLOCK_BYTES",
+            spec["block_envs"] * spec["rows"] * 128 * dtype.itemsize)
+    rng = np.random.default_rng(len(name))
+    ring = jnp.asarray(rng.normal(size=(spec["envs"], spec["rows"],
+                                        spec["slots"])), dtype)
+    rows = jnp.asarray(rng.normal(size=(
+        spec["writes"], spec["envs"], spec["tokens"], spec["rows"])),
+        jnp.float32)
+    written = jnp.int32(spec["written"])
+
+    def both(write):
+        if spec["scan"]:
+            def step(carry, new):
+                ring, at = carry
+                return (write(ring, new, at), at + 1), ()
+            return jax.jit(lambda: jax.lax.scan(
+                step, (ring, written), rows)[0][0])()
+        out = ring
+        for i in range(spec["writes"]):
+            out = write(out, rows[i], written + i)
+        return out
+
+    got, want = both(A.latent_ring_write), both(slice_update)
+    assert got.dtype == want.dtype == dtype and got.shape == ring.shape
+    width = np.uint16 if dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(np.asarray(got).view(width),
+                                  np.asarray(want).view(width))
+    assert not np.array_equal(np.asarray(got).view(width),
+                              np.asarray(ring).view(width))
+    share = registry.get_registry().snapshot().get(
+        "attention/latent_slot_kernel_share")
+    assert share == (None if spec["kernel"] is None
+                     else float(spec["kernel"]))
+
+
+def test_the_gauge_is_the_share_of_the_one_token_writes_traced(monkeypatch):
+    from scalable_agent_tpu.obs import registry
+
+    monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
+    monkeypatch.setattr(A, "_slot_writes", [0, 0])
+    whole = jnp.zeros((2, ROWS, SLOTS), jnp.bfloat16)
+    ragged = jnp.zeros((2, ROWS, 12), jnp.bfloat16)
+    row = jnp.ones((2, 1, ROWS), jnp.float32)
+    for ring in (whole, whole, ragged, whole):
+        A.latent_ring_write(ring, row, jnp.int32(3))
+    A.latent_ring_write(whole, jnp.ones((2, 5, ROWS)), jnp.int32(3))
+    assert registry.get_registry().snapshot()[
+        "attention/latent_slot_kernel_share"] == 0.75
