@@ -1,8 +1,11 @@
 """The selective-scan kernel (ops/ssm.py) under the Pallas interpreter
 against a ``lax.scan`` over time, float32: the forward, the gradient of
 every operand, ``done`` at step 0, mid-unroll, on a chunk's edges and
-twice in one unroll, unrolls of one chunk, of whole chunks and with a
-ragged last one, and the state carried from one call into the next.
+twice in one unroll, at every row of one 8-token block and on a ragged
+tail's first and last token, unrolls of one chunk, of whole chunks, with
+a ragged last one and of whole chunks and one token (the cell's 257 in
+small), widths of one lane tile and of two, and the state carried from
+one call into the next, across a ragged tail too.
 The interpreter proves the arithmetic and the custom VJP's plumbing;
 tests/test_chip_bringup.py compiles both kernels for a v5e at the
 cell's widths.
@@ -19,6 +22,8 @@ BATCH, WIDTH, STATES = 2, 256, 16
 OPERANDS = ("x", "delta", "a", "dp", "b", "c", "state")
 CHUNK = ssm._CHUNK
 
+TWO_TILES = 768             # channels the kernels take in two lane tiles
+
 # name -> (steps, [(env, step) that begins an episode])
 CASES = {
     "one_chunk_done_at_0": (24, [(0, 0), (1, 0)]),
@@ -29,7 +34,16 @@ CASES = {
     "whole_chunks": (2 * CHUNK, [(1, 77)]),
     "ragged_last_chunk_of_one": (CHUNK + 1, [(0, CHUNK)]),
     "no_done": (40, []),
+    # the bulk passes take the tokens' rows eight at a time
+    "every_row_of_a_block": (CHUNK + 37, [(0, step) for step in range(16, 24)]
+                             + [(1, 19)]),
+    "tail_first_and_last": (CHUNK + 37, [(0, CHUNK), (0, CHUNK + 36),
+                                         (1, CHUNK + 36)]),
+    # phi4flash.ingraph's 257 = 4 x 64 + 1 in small
+    "whole_chunks_and_one": (2 * CHUNK + 1, [(0, 2 * CHUNK), (1, 70)]),
+    "two_lane_tiles": (CHUNK + 5, [(0, 0), (1, CHUNK + 2)]),
 }
+WIDTHS = {"two_lane_tiles": TWO_TILES}      # the other cases': WIDTH, one tile
 
 
 def scan_over_time(x, delta, a, dp, b, c, reset, state):
@@ -44,18 +58,18 @@ def scan_over_time(x, delta, a, dp, b, c, reset, state):
     return jnp.swapaxes(y, 0, 1), state
 
 
-def operands(steps, seed=0):
+def operands(steps, seed=0, width=WIDTH):
     rng = np.random.default_rng(seed)
 
     def normal(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
 
     return dict(
-        x=normal(BATCH, steps, WIDTH),
-        delta=jax.nn.softplus(normal(BATCH, steps, WIDTH) - 1.0),
-        a=-jnp.exp(0.3 * normal(STATES, WIDTH)), dp=normal(WIDTH),
+        x=normal(BATCH, steps, width),
+        delta=jax.nn.softplus(normal(BATCH, steps, width) - 1.0),
+        a=-jnp.exp(0.3 * normal(STATES, width)), dp=normal(width),
         b=normal(BATCH, steps, STATES), c=normal(BATCH, steps, STATES),
-        state=normal(BATCH, STATES, WIDTH))
+        state=normal(BATCH, STATES, width))
 
 
 def resets(steps, at):
@@ -65,9 +79,9 @@ def resets(steps, at):
     return jnp.asarray(reset)
 
 
-def scalar(fn, reset):
+def scalar(fn, reset, width=WIDTH):
     """A number that weighs every output, the last state among them."""
-    weights = jnp.cos(jnp.arange(WIDTH, dtype=jnp.float32))
+    weights = jnp.cos(jnp.arange(width, dtype=jnp.float32))
 
     def total(*values):
         y, last = fn(*values[:6], reset, values[6])
@@ -79,13 +93,15 @@ def scalar(fn, reset):
 @pytest.fixture(scope="module", params=sorted(CASES))
 def case(request):
     steps, at = CASES[request.param]
-    values, reset = operands(steps), resets(steps, at)
+    width = WIDTHS.get(request.param, WIDTH)
+    values, reset = operands(steps, width=width), resets(steps, at)
     ordered = [values[name] for name in OPERANDS]
     run = {}
     for name, fn in (("kernel", ssm.selective_scan),
                      ("scan", scan_over_time)):
         y, last = fn(*ordered[:6], reset, ordered[6])
-        grads = jax.grad(scalar(fn, reset), argnums=range(7))(*ordered)
+        grads = jax.grad(scalar(fn, reset, width),
+                         argnums=range(7))(*ordered)
         run[name] = dict(y=y, last=last, **dict(zip(OPERANDS, grads)))
     return run
 
@@ -93,6 +109,11 @@ def case(request):
 def gap(got, want):
     return float(jnp.max(jnp.abs(got - want))
                  / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def test_the_cases_widths_are_one_lane_tile_and_two():
+    assert WIDTH // ssm._lanes(WIDTH, ssm._LANES) == 1
+    assert TWO_TILES // ssm._lanes(TWO_TILES, ssm._LANES) == 2
 
 
 @pytest.mark.parametrize("what", ["y", "last"])
@@ -105,13 +126,15 @@ def test_the_gradient_of_every_operand_is_the_scans(case, operand):
     assert gap(case["kernel"][operand], case["scan"][operand]) < 1e-5
 
 
-def test_the_state_carries_from_one_call_into_the_next():
-    steps = CHUNK + 20
+@pytest.mark.parametrize("steps,cut", [
+    (CHUNK + 20, 29),               # the second call begins before a reset
+    (3 * CHUNK + 2, 2 * CHUNK + 1),   # the first ends on a ragged tail of one
+], ids=["before_a_reset", "across_a_ragged_tail"])
+def test_the_state_carries_from_one_call_into_the_next(steps, cut):
     values = operands(steps, seed=3)
     reset = resets(steps, [(0, 0), (1, 30)])
     ordered = [values[name] for name in OPERANDS]
     whole, last = ssm.selective_scan(*ordered[:6], reset, ordered[6])
-    cut = 29                       # the second call begins before a reset
     state, parts = ordered[6], []
     for part in (slice(0, cut), slice(cut, steps)):
         y, state = ssm.selective_scan(
