@@ -69,8 +69,7 @@ class Config:
     # that the named JSON file describes under the source's own key
     # names (model_type, hidden_size, num_experts, layer_types, ...; the
     # benchmark's configuration files are such files; ``model_type``
-    # says which decoder family: ``afmoe``, ``phi4flash``,
-    # ``deepseek_v3`` or ``nemotron_h``).  The
+    # says which decoder family, one of ``token_policy.FAMILIES``).  The
     # token policy runs on the fused loop (``--train_backend=ingraph``)
     # in a token world (``--level_name=token_recall``), one chip, V-trace.
     model_config: str = ""

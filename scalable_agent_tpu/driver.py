@@ -335,33 +335,7 @@ def build_token_policy(config: Config, action_space, frame_shape=None):
         episode_length=int(level.defaults["episode_length"]),
         compute_dtype=jnp.dtype(config.compute_dtype))
     registry = get_registry()
-    for name, value, text in (
-            ("cache/bytes", agent.cache_bytes(config.batch_size),
-             "bytes of the attention cache the rollout carries"),
-            ("cache/window_slots", agent.window_slots,
-             "slots of a window layer's ring (window + unroll)"),
-            ("cache/full_slots", agent.full_slots,
-             "slots of a full layer's ring (episode + unroll)"),
-            ("cache/ring_readers", agent.ring_readers,
-             "the most layers that read one ring: its own layer and the "
-             "cross layers into it"),
-            ("cache/ring_bytes", agent.ring_bytes(config.batch_size),
-             "bytes of the largest one layer's ring"),
-            ("cache/latent_bytes_per_token", agent.latent_bytes_per_token,
-             "bytes a token a layer the rings hold where attention is "
-             "latent (one compressed row, every head's key and value); "
-             "0 where they hold whole keys and values"),
-            ("ssm/state_bytes", agent.ssm_state_bytes(config.batch_size),
-             "bytes of the state-space layers' recurrent states and "
-             "convolution tails the rollout carries (float32)"),
-            ("ssd/state_bytes_per_env",
-             agent.ssm_state_bytes(1) if model.mamba_num_heads else 0,
-             "bytes an env of the Mamba-2 layers' matrix states and "
-             "convolution tails, over the layers, read off the state's "
-             "own arrays; 0 where no layer is a Mamba-2 scan"),
-            ("policy/vocab_slice", model.vocab_size,
-             "tokens of the vocabulary this chip's head and embedding "
-             "hold")):
+    for name, value, text in agent.gauges(config.batch_size):
         registry.gauge(name, text).set(value)
     log.info(
         "kernel policy: backend=%s mesh_devices=%d policy=token family=%s "

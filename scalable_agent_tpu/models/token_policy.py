@@ -2,7 +2,14 @@
 layers a mixer (attention through a ring of its own, attention into
 another layer's ring, a state-space scan, a gated memory unit, a
 mixture of experts) and, where the family has one, an MLP (dense or
-experts) behind it, picked by the configuration file.  Four families
+experts) behind it, picked by the configuration file.  What a family is
+made of is declared once, in its record of ``_FAMILY`` (the keys its
+file must have, where its layers' kinds are written, which module a
+layer of each kind is, its norms, its head, its telemetry); nothing
+else in the program asks a family's name, and the record is private,
+not an extension point: a family is added by adding a record, the
+mechanisms it lacks, a preset for the family suite
+(tests/family_suite.py) and the benchmark's files.  Four families
 (``FAMILIES``): ``afmoe`` (window and full attention mixed, a mixture of
 experts with a shared expert), ``phi4flash`` (the decoder-hybrid-
 decoder: state-space and window layers, one full-attention layer whose
@@ -110,7 +117,7 @@ import dataclasses
 import json
 import math
 import os
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,7 +135,6 @@ MEMORY_UNIT = "memory_unit"     # gated by the last state-space layer's output
 LATENT = "latent_attention"     # full attention through a ring of latent rows
 MAMBA2 = "mamba2"               # a matrix-state scan (ops/ssd.py)
 EXPERTS = "experts"             # the expert layer as the layer's one mixer
-FAMILIES = ("afmoe", "phi4flash", "deepseek_v3", "nemotron_h")
 _SCANS = (STATE_SPACE, MAMBA2)
 # ``hybrid_override_pattern``'s letters ("-", a dense MLP alone, is not
 # built)
@@ -137,70 +143,11 @@ _PATTERN = {"M": MAMBA2, "E": EXPERTS, "*": FULL}
 # is gated (three matrices), a relu2 one is not (two)
 _GATED = {"silu": True, "relu2": False}
 _OWN_RING = (SLIDING, FULL, LATENT)
-# the keys a family's file must have (the rest of the fields default)
+# the keys every family's file must have (the rest of the fields default;
+# ``_Family.required`` has a family's own)
 _ALWAYS = ("vocab_size", "hidden_size", "num_attention_heads",
            "intermediate_size", "num_hidden_layers")
 _WINDOWED = _ALWAYS + ("num_key_value_heads", "sliding_window")
-_REQUIRED = {
-    "afmoe": _WINDOWED + (
-        "head_dim", "layer_types", "moe_intermediate_size", "num_experts",
-        "num_experts_per_tok", "num_shared_experts", "num_dense_layers",
-        "route_scale", "route_norm", "rope_theta", "rms_norm_eps",
-        "mup_enabled", "experts_held"),
-    "phi4flash": _WINDOWED + (
-        "layer_kinds", "layer_norm_eps", "mamba_d_state", "mamba_d_conv",
-        "mamba_expand", "mamba_dt_rank"),
-    "deepseek_v3": _ALWAYS + (
-        "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
-        "moe_intermediate_size", "n_routed_experts", "n_shared_experts",
-        "num_experts_per_tok", "first_k_dense_replace",
-        "routed_scaling_factor", "norm_topk_prob", "rope_interleave",
-        "rope_theta", "rms_norm_eps", "experts_held"),
-    "nemotron_h": _ALWAYS + (
-        "num_key_value_heads", "head_dim", "hybrid_override_pattern",
-        "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
-        "conv_kernel", "chunk_size", "moe_intermediate_size",
-        "moe_shared_expert_intermediate_size", "n_routed_experts",
-        "n_shared_experts", "num_experts_per_tok", "routed_scaling_factor",
-        "norm_topk_prob", "mlp_hidden_act", "layer_norm_epsilon",
-        "experts_held"),
-}
-# the source's key for what another family's file calls otherwise: the
-# expert layer is one (ops/moe.py), and reads one set of names
-_SAID_AS = {
-    "deepseek_v3": (("n_routed_experts", "num_experts"),
-                    ("n_shared_experts", "num_shared_experts"),
-                    ("first_k_dense_replace", "num_dense_layers"),
-                    ("routed_scaling_factor", "route_scale"),
-                    ("norm_topk_prob", "route_norm")),
-    "nemotron_h": (("n_routed_experts", "num_experts"),
-                   ("n_shared_experts", "num_shared_experts"),
-                   ("routed_scaling_factor", "route_scale"),
-                   ("norm_topk_prob", "route_norm"),
-                   ("layer_norm_epsilon", "rms_norm_eps")),
-}
-# what a family's file may not say otherwise
-_ONLY = {
-    "afmoe": (("hidden_act", "silu"), ("score_func", "sigmoid"),
-              ("rope_scaling", None)),
-    "phi4flash": (("hidden_act", "silu"), ("tie_word_embeddings", True),
-                  ("mlp_bias", False), ("lm_head_bias", False)),
-    # the group limit of the choice is a no-op at one group, which is all
-    # that is built; a compressed query (q_lora_rank) is not
-    "deepseek_v3": (("hidden_act", "silu"), ("scoring_func", "sigmoid"),
-                    ("rope_scaling", None), ("q_lora_rank", None),
-                    ("tie_word_embeddings", False),
-                    ("attention_bias", False), ("n_group", 1),
-                    ("topk_group", 1), ("moe_layer_freq", 1)),
-    # experts of two matrices under relu2, one group to choose from, no
-    # bias but the convolution's, no window
-    "nemotron_h": (("mlp_hidden_act", "relu2"),
-                   ("mamba_hidden_act", "silu"), ("n_group", 1),
-                   ("topk_group", 1), ("tie_word_embeddings", False),
-                   ("attention_bias", False), ("mamba_proj_bias", False),
-                   ("mlp_bias", False), ("use_bias", False),
-                   ("use_conv_bias", True), ("sliding_window", None)),
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,7 +168,7 @@ class TokenModelConfig:
     layer_types: Tuple[str, ...]        # each layer's mixer
     sliding_window: int
     model_type: str = "afmoe"
-    # afmoe, and deepseek_v3 under its own names (``_SAID_AS``)
+    # afmoe, and deepseek_v3 under its own names (``_Family.said_as``)
     moe_intermediate_size: int = 0
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -277,7 +224,7 @@ class TokenModelConfig:
     def mixer_alone(self) -> bool:
         """Every layer is its mixer and nothing else: no MLP behind it
         (an expert layer is then a MIXER, by the file's pattern)."""
-        return self.model_type == "nemotron_h"
+        return _FAMILY[self.model_type].mixer_alone
 
     @property
     def shared_expert_width(self) -> int:
@@ -319,67 +266,31 @@ class TokenModelConfig:
 
     @classmethod
     def from_dict(cls, raw: Dict[str, Any]) -> "TokenModelConfig":
-        family = raw.get("model_type", "afmoe")
-        if family not in FAMILIES:
+        name = raw.get("model_type", cls.model_type)
+        if name not in _FAMILY:
             raise ValueError(
-                f"token policy: model_type={family!r} is not built "
+                f"token policy: model_type={name!r} is not built "
                 f"(only {', '.join(map(repr, FAMILIES))})")
-        for key, want in _ONLY[family]:
+        family = _FAMILY[name]
+        for key, want in family.only:
             if raw.get(key, want) != want:
                 raise ValueError(
                     f"token policy: {key}={raw[key]!r} is not built "
                     f"(only {want!r})")
-        missing = [n for n in _REQUIRED[family] if n not in raw]
+        missing = [n for n in family.required if n not in raw]
         if missing:
             raise ValueError(
                 f"token policy: the model configuration lacks {missing}")
         names = [f.name for f in dataclasses.fields(cls)]
         values = {n: raw[n] for n in names if n in raw}
-        values["model_type"] = family
-        values.update((field, raw[said])
-                      for said, field in _SAID_AS.get(family, ()))
-        if family == "deepseek_v3":
-            # every layer attends through its own latent ring, over the
-            # whole episode; whole keys and values exist nowhere, so
-            # their head count and width size nothing
-            values["layer_types"] = [LATENT] * raw["num_hidden_layers"]
-            values.update(sliding_window=0, num_key_value_heads=0,
-                          head_dim=0)
-        if family == "nemotron_h":
-            unknown = sorted(set(raw["hybrid_override_pattern"])
-                             - set(_PATTERN))
-            if unknown:
-                raise ValueError(
-                    f"token policy: hybrid_override_pattern letters "
-                    f"{unknown} are not built (only "
-                    f"{', '.join(map(repr, _PATTERN))})")
-            values["layer_types"] = [
-                _PATTERN[letter] for letter in raw["hybrid_override_pattern"]]
-            values["sliding_window"] = 0
-        if family == "phi4flash":
-            values["layer_types"] = [k["kind"] for k in raw["layer_kinds"]]
-            values["layer_index"] = tuple(
-                int(k["published_index"]) for k in raw["layer_kinds"])
-            values.setdefault("head_dim", raw["hidden_size"]
-                              // raw["num_attention_heads"])
+        values["model_type"] = name
+        values.update((field, raw[said]) for said, field in family.said_as)
+        values.update(family.layers(raw))
         values["layer_types"] = tuple(values["layer_types"])
         model = cls(**values)
-        kinds = {"afmoe": (SLIDING, FULL), "deepseek_v3": (LATENT,),
-                 "nemotron_h": tuple(_PATTERN.values())}.get(
-            family, (SLIDING, FULL, CROSS, STATE_SPACE, MEMORY_UNIT))
         if len(model.layer_types) != model.num_hidden_layers or any(
-                kind not in kinds for kind in model.layer_types):
-            if family == "afmoe":
-                raise ValueError(
-                    "token policy: layer_types must name sliding_attention "
-                    "or full_attention for each of num_hidden_layers")
-            if family == "nemotron_h":
-                raise ValueError(
-                    "token policy: hybrid_override_pattern must have a "
-                    "letter for each of num_hidden_layers")
-            raise ValueError(
-                f"token policy: layer_kinds must name one of {kinds} for "
-                f"each of num_hidden_layers")
+                kind not in family.mixers for kind in model.layer_types):
+            raise ValueError(family.layers_refusal)
         if model.num_experts and not (
                 0 <= model.first_expert and model.first_expert
                 + model.experts_held <= model.num_experts):
@@ -395,21 +306,9 @@ class TokenModelConfig:
                     f"token policy: layer {layer} ({kind}) has no "
                     f"{FULL if kind == CROSS else STATE_SPACE} layer "
                     f"before it to read")
-        if family == "nemotron_h" and (
-                model.mamba_num_heads % model.n_groups
-                or model.d_inner % model.n_groups
-                or model.num_attention_heads % model.num_key_value_heads):
-            raise ValueError(
-                "token policy: the scan's heads and channels come in "
-                "n_groups equal groups, the query heads in "
-                "num_key_value_heads")
-        if family == "phi4flash" and (
-                model.num_attention_heads % 2 or model.num_key_value_heads % 2
-                or (model.num_attention_heads // 2)
-                % (model.num_key_value_heads // 2)):
-            raise ValueError(
-                "token policy: differential attention takes heads in "
-                "pairs, the query pairs a multiple of the key pairs")
+        refused = family.check and family.check(model)
+        if refused:
+            raise ValueError(refused)
         return model
 
     @classmethod
@@ -944,88 +843,147 @@ class _MemoryUnit(nn.Module):
             jax.nn.silu(gate) * memory)
 
 
+class _Held(NamedTuple):
+    """What a layer's mixer may read, and hands back with what it
+    changed.  ``ring_*``: the ring it attends into (None where it attends
+    into none); ``state`` / ``tail``: a scan's; ``handed``: what earlier
+    layers of this call left."""
+
+    position: Any
+    index: Any
+    episode_start: Any
+    ring_keys: Any
+    ring_values: Any
+    ring_index: Any
+    written: Any
+    handed: _Handed
+    state: Any
+    tail: Any
+
+
+def _expert_layer(layer: "_Layer", m):
+    """-> (the expert layer's result, what it says of itself)."""
+    flat = m.reshape(-1, m.shape[-1])
+    # one token an env: a decode step
+    f, routed = _MoE(layer.model, layer.dtype, name="moe")(
+        flat, decode=m.shape[1] == 1)
+    return f.reshape(m.shape), {f"moe/{name}": x
+                                for name, x in routed.items()}
+
+
+def _attends(held: _Held, ring_keys, ring_values, seen, **more):
+    """An attention mixer's rings written, and what the pass says of
+    itself."""
+    return (held._replace(ring_keys=ring_keys, ring_values=ring_values,
+                          **more),
+            {f"attention/{name}": x for name, x in seen.items()})
+
+
+# A mixer by ``_Family.mixers``: (the layer, the normed residual, what it
+# may read) -> (its result, what it hands back, its numbers).  Each maps
+# ``_Layer``'s arguments onto a module that keeps a signature of its own,
+# under the module's name in the parameter tree.
+
+def _experts_mixer(layer, a, held):
+    mixed, stats = _expert_layer(layer, a)
+    return mixed, held, stats
+
+
+def _mamba2_mixer(layer, a, held):
+    mixed, state, tail = _Mamba2(layer.model, layer.dtype, name="ssd")(
+        a, held.position, held.state, held.tail)
+    return mixed, held._replace(state=state, tail=tail), {}
+
+
+def _state_space_mixer(layer, a, held):
+    mixed, memory, state, tail = _StateSpace(
+        layer.model, layer.dtype, name="ssm")(
+            a, held.position, held.state, held.tail)
+    handed = held.handed
+    if layer.layer == layer.model.memory_from:
+        handed = handed._replace(memory=memory)
+    return mixed, held._replace(handed=handed, state=state, tail=tail), {}
+
+
+def _memory_unit_mixer(layer, a, held):
+    return (_MemoryUnit(layer.model, layer.dtype, name="gmu")(
+        a, held.handed.memory), held, {})
+
+
+def _plain_attention_mixer(layer, a, held):
+    mixed, ring_keys, ring_values, seen = _PlainAttention(
+        layer.model, layer.dtype, name="attention")(
+            a, held.index, held.episode_start, held.ring_keys,
+            held.ring_values, held.ring_index, held.written)
+    return (mixed,) + _attends(held, ring_keys, ring_values, seen)
+
+
+def _gated_attention_mixer(layer, a, held):
+    sliding = layer.model.layer_types[layer.layer] == SLIDING
+    mixed, ring_keys, ring_values, seen = _Attention(
+        layer.model, sliding, layer.dtype, name="attention")(
+            a, held.position, held.index, held.episode_start,
+            held.ring_keys, held.ring_values, held.ring_index, held.written)
+    return (mixed,) + _attends(held, ring_keys, ring_values, seen)
+
+
+def _latent_attention_mixer(layer, a, held):
+    mixed, ring, seen = _LatentAttention(
+        layer.model, layer.dtype, name="attention")(
+            a, held.position, held.index, held.episode_start,
+            held.ring_keys, held.ring_index, held.written)
+    return (mixed,) + _attends(held, ring, held.ring_values, seen)
+
+
+def _differential_attention_mixer(layer, a, held):
+    model = layer.model
+    mixed, ring_keys, ring_values, handed, seen = _DifferentialAttention(
+        model, model.layer_types[layer.layer],
+        model.layer_index[layer.layer], layer.dtype, name="attention")(
+            a, held.index, held.episode_start, held.ring_keys,
+            held.ring_values, held.ring_index, held.written, held.handed)
+    return (mixed,) + _attends(held, ring_keys, ring_values, seen,
+                               handed=handed)
+
+
 class _Layer(nn.Module):
-    """A mixer, by the layer's kind in the file's pattern, and (unless
-    the model's layers are a mixer alone) an MLP, between residual adds,
-    as the family places its norms.  ``ring_*``: the ring the mixer
-    attends into (None where it attends into none); ``state`` / ``tail``:
-    a state-space mixer's; ``handed``: what earlier layers of this call
-    left."""
+    """A mixer, by the layer's kind in the file's pattern
+    (``_Family.mixers``), and (unless the model's layers are a mixer
+    alone) an MLP, between residual adds, as the family places its
+    norms.  The arguments after ``h`` are ``_Held``'s, and what comes back
+    after ``h`` is what of them a mixer may change, then the layer's
+    numbers."""
 
     model: TokenModelConfig
     layer: int
     dtype: Any
 
     @nn.compact
-    def __call__(self, h, position, index, episode_start, ring_keys,
-                 ring_values, ring_index, written, handed, state, tail):
+    def __call__(self, h, *held):
         model, dtype = self.model, self.dtype
-        kind = model.layer_types[self.layer]
-        # the first family norms each branch's result too
-        afmoe = model.model_type == "afmoe"
+        family = _FAMILY[model.model_type]
 
         def norm(name):
-            if model.model_type == "phi4flash":
-                return _LayerNorm(model.layer_norm_eps, name=name)
-            return _RMSNorm(model.rms_norm_eps, name=name)
+            return family.norm(getattr(model, family.norm_eps), name=name)
 
-        def experts(m):
-            flat = m.reshape(-1, m.shape[-1])
-            # one token an env: a decode step
-            f, routed = _MoE(model, dtype, name="moe")(
-                flat, decode=m.shape[1] == 1)
-            stats.update({f"moe/{name}": x for name, x in routed.items()})
-            return f.reshape(m.shape)
+        def result(name, x):
+            return norm(name)(x) if family.result_norms else x
 
-        seen = {}                   # what an attention pass says of itself
-        stats = {}
         a = norm("input_norm")(h)
-        if kind == EXPERTS:
-            mixed = experts(a)
-        elif kind == MAMBA2:
-            mixed, state, tail = _Mamba2(model, dtype, name="ssd")(
-                a, position, state, tail)
-        elif model.mixer_alone:
-            mixed, ring_keys, ring_values, seen = _PlainAttention(
-                model, dtype, name="attention")(
-                    a, index, episode_start, ring_keys, ring_values,
-                    ring_index, written)
-        elif afmoe:
-            mixed, ring_keys, ring_values, seen = _Attention(
-                model, kind == SLIDING, dtype, name="attention")(
-                    a, position, index, episode_start, ring_keys,
-                    ring_values, ring_index, written)
-        elif kind == LATENT:
-            mixed, ring_keys, seen = _LatentAttention(
-                model, dtype, name="attention")(
-                    a, position, index, episode_start, ring_keys, ring_index,
-                    written)
-        elif kind == STATE_SPACE:
-            mixed, memory, state, tail = _StateSpace(
-                model, dtype, name="ssm")(a, position, state, tail)
-            if self.layer == model.memory_from:
-                handed = handed._replace(memory=memory)
-        elif kind == MEMORY_UNIT:
-            mixed = _MemoryUnit(model, dtype, name="gmu")(a, handed.memory)
-        else:
-            mixed, ring_keys, ring_values, handed, seen = (
-                _DifferentialAttention(
-                    model, kind, model.layer_index[self.layer], dtype,
-                    name="attention")(
-                        a, index, episode_start, ring_keys, ring_values,
-                        ring_index, written, handed))
-        stats.update({f"attention/{name}": x for name, x in seen.items()})
-        h = h + (norm("post_attn_norm")(mixed) if afmoe else mixed)
-        if model.mixer_alone:
-            return h, ring_keys, ring_values, handed, state, tail, stats
-        m = norm("pre_mlp_norm")(h)
-        if model.is_expert_layer(self.layer):
-            f = experts(m)
-        else:
-            f = _MLP(model.intermediate_size, dtype, name="mlp")(
-                m.reshape(-1, m.shape[-1])).reshape(m.shape)
-        h = h + (norm("post_mlp_norm")(f) if afmoe else f)
-        return h, ring_keys, ring_values, handed, state, tail, stats
+        mixed, held, stats = family.mixers[model.layer_types[self.layer]](
+            self, a, _Held(*held))
+        h = h + result("post_attn_norm", mixed)
+        if not family.mixer_alone:
+            m = norm("pre_mlp_norm")(h)
+            if model.is_expert_layer(self.layer):
+                f, said = _expert_layer(self, m)
+                stats = dict(stats, **said)
+            else:
+                f = _MLP(model.intermediate_size, dtype, name="mlp")(
+                    m.reshape(-1, m.shape[-1])).reshape(m.shape)
+            h = h + result("post_mlp_norm", f)
+        return (h, held.ring_keys, held.ring_values, held.handed,
+                held.state, held.tail, stats)
 
 
 class _Baseline(nn.Module):
@@ -1065,26 +1023,220 @@ def tied_logits(z, table, dtype):
         (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-# family -> (learning-dynamics telemetry's parameter groups, the numbers
-# the forward pass leaves in the ``stats`` collection)
-_TELEMETRY = {
-    "afmoe": (
-        ("embedding", "attention", "experts", "mlp", "norms", "heads"),
-        ("moe/pairs_here_share", "moe/tokens_per_expert_mean",
-         "moe/expert_load_max_over_mean", "moe/compact_share",
-         "attention/key_blocks_visited_share",
-         "attention/decode_key_blocks_visited_share")),
-    # the tied table is the head too, and is counted as the embedding
-    "phi4flash": (
-        ("embedding", "attention", "ssm", "gmu", "mlp", "norms", "heads"),
-        ("attention/key_blocks_visited_share",
-         "attention/decode_key_blocks_visited_share")),
+@dataclasses.dataclass(frozen=True)
+class _Family:
+    """What a decoder family is made of: ``_FAMILY`` holds one record a
+    ``model_type`` and is the one place that knows a family by its name.
+    ``TokenModelConfig.from_dict`` reads the file by the first block of
+    fields, ``_Layer`` and ``TokenPolicy`` build by the second."""
+
+    # -- the file
+    required: Tuple[str, ...]           # keys the file must have
+    # (key, value): what the file may not say otherwise
+    only: Tuple[Tuple[str, Any], ...]
+    # raw -> the fields the file does not state under their own names:
+    # ``layer_types`` from wherever the family writes its layers, and
+    # what it overrides beside them
+    layers: Callable[[Dict[str, Any]], Dict[str, Any]]
+    # kind -> the mixer a layer of that kind is (the ``_*_mixer``
+    # functions); its keys are the kinds the family's layers may be
+    mixers: Dict[str, Callable]
+    # what a file whose layers are of another kind, or not one a layer,
+    # is told
+    layers_refusal: str
+    # learning-dynamics telemetry's parameter groups, and the numbers the
+    # forward pass leaves in the ``stats`` collection
+    groups: Tuple[str, ...]
+    stats: Tuple[str, ...]
+    # (the source's key, the field): what another family's file calls
+    # otherwise; the expert layer is one (ops/moe.py), and reads one set
+    # of names
+    said_as: Tuple[Tuple[str, str], ...] = ()
+    # model -> the sentence that refuses it (the family's own
+    # divisibility rule), or None
+    check: Optional[Callable[["TokenModelConfig"], Optional[str]]] = None
+    # -- the layers
+    norm: Any = _RMSNorm                # every norm but a mixer's own
+    norm_eps: str = "rms_norm_eps"      # the field that holds its epsilon
+    result_norms: bool = False          # a branch's RESULT is normed too
+    mixer_alone: bool = False           # no MLP behind the mixer
+    paired_heads: bool = False          # a ring head is a pair, twice as wide
+    tied_head: bool = False             # the head is the embedding's table
+
+
+def _verbatim_layers(raw):
+    """The file's ``layer_types`` are the layers' kinds."""
+    return {}
+
+
+def _layer_kinds(raw):
+    """``phi4flash``'s file names each layer's kind beside its index in
+    the published model (the start of a differential layer's lambda
+    follows it); a head's width is the hidden size's share."""
+    return dict(
+        layer_types=[k["kind"] for k in raw["layer_kinds"]],
+        layer_index=tuple(int(k["published_index"])
+                          for k in raw["layer_kinds"]),
+        head_dim=raw.get("head_dim", raw["hidden_size"]
+                         // raw["num_attention_heads"]))
+
+
+def _latent_layers(raw):
+    """Every layer attends through its own latent ring, over the whole
+    episode; whole keys and values exist nowhere, so their head count
+    and width size nothing."""
+    return dict(layer_types=[LATENT] * raw["num_hidden_layers"],
+                sliding_window=0, num_key_value_heads=0, head_dim=0)
+
+
+def _pattern_layers(raw):
+    """A layer a letter of ``hybrid_override_pattern``."""
+    unknown = sorted(set(raw["hybrid_override_pattern"]) - set(_PATTERN))
+    if unknown:
+        raise ValueError(
+            f"token policy: hybrid_override_pattern letters "
+            f"{unknown} are not built (only "
+            f"{', '.join(map(repr, _PATTERN))})")
+    return dict(
+        layer_types=[_PATTERN[letter]
+                     for letter in raw["hybrid_override_pattern"]],
+        sliding_window=0)
+
+
+def _heads_in_pairs(model):
+    if (model.num_attention_heads % 2 or model.num_key_value_heads % 2
+            or (model.num_attention_heads // 2)
+            % (model.num_key_value_heads // 2)):
+        return ("token policy: differential attention takes heads in "
+                "pairs, the query pairs a multiple of the key pairs")
+    return None
+
+
+def _equal_groups(model):
+    if (model.mamba_num_heads % model.n_groups
+            or model.d_inner % model.n_groups
+            or model.num_attention_heads % model.num_key_value_heads):
+        return ("token policy: the scan's heads and channels come in "
+                "n_groups equal groups, the query heads in "
+                "num_key_value_heads")
+    return None
+
+
+_EXPERT_STATS = (
+    "moe/pairs_here_share", "moe/tokens_per_expert_mean",
+    "moe/expert_load_max_over_mean", "moe/compact_share",
+    "attention/key_blocks_visited_share",
+    "attention/decode_key_blocks_visited_share")
+# the source's keys of the DeepSeek-V3 line for what the expert layer
+# (ops/moe.py) reads under the first family's names
+_ROUTED_SAID_AS = (("n_routed_experts", "num_experts"),
+                   ("n_shared_experts", "num_shared_experts"),
+                   ("routed_scaling_factor", "route_scale"),
+                   ("norm_topk_prob", "route_norm"))
+_DIFFERENTIAL = {
+    SLIDING: _differential_attention_mixer,
+    FULL: _differential_attention_mixer,
+    CROSS: _differential_attention_mixer,
+    STATE_SPACE: _state_space_mixer, MEMORY_UNIT: _memory_unit_mixer}
+# A family is added by adding a record here, the mechanisms it lacks (a
+# mixer module and its ``_*_mixer``, an op), a preset for the family suite
+# (tests/family_suite.py) and the benchmark's files.
+_FAMILY: Dict[str, _Family] = {
+    "afmoe": _Family(
+        required=_WINDOWED + (
+            "head_dim", "layer_types", "moe_intermediate_size",
+            "num_experts", "num_experts_per_tok", "num_shared_experts",
+            "num_dense_layers", "route_scale", "route_norm", "rope_theta",
+            "rms_norm_eps", "mup_enabled", "experts_held"),
+        only=(("hidden_act", "silu"), ("score_func", "sigmoid"),
+              ("rope_scaling", None)),
+        layers=_verbatim_layers,
+        mixers={SLIDING: _gated_attention_mixer,
+                FULL: _gated_attention_mixer},
+        layers_refusal=(
+            "token policy: layer_types must name sliding_attention "
+            "or full_attention for each of num_hidden_layers"),
+        groups=("embedding", "attention", "experts", "mlp", "norms",
+                "heads"),
+        stats=_EXPERT_STATS,
+        result_norms=True),
+    "phi4flash": _Family(
+        required=_WINDOWED + (
+            "layer_kinds", "layer_norm_eps", "mamba_d_state",
+            "mamba_d_conv", "mamba_expand", "mamba_dt_rank"),
+        only=(("hidden_act", "silu"), ("tie_word_embeddings", True),
+              ("mlp_bias", False), ("lm_head_bias", False)),
+        layers=_layer_kinds,
+        mixers=_DIFFERENTIAL,
+        layers_refusal=(
+            f"token policy: layer_kinds must name one of "
+            f"{tuple(_DIFFERENTIAL)} for each of num_hidden_layers"),
+        # the tied table is the head too, and is counted as the embedding
+        groups=("embedding", "attention", "ssm", "gmu", "mlp", "norms",
+                "heads"),
+        stats=("attention/key_blocks_visited_share",
+               "attention/decode_key_blocks_visited_share"),
+        check=_heads_in_pairs,
+        norm=_LayerNorm, norm_eps="layer_norm_eps",
+        paired_heads=True, tied_head=True),
+    "deepseek_v3": _Family(
+        required=_ALWAYS + (
+            "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "moe_intermediate_size", "n_routed_experts",
+            "n_shared_experts", "num_experts_per_tok",
+            "first_k_dense_replace", "routed_scaling_factor",
+            "norm_topk_prob", "rope_interleave", "rope_theta",
+            "rms_norm_eps", "experts_held"),
+        # the group limit of the choice is a no-op at one group, which is
+        # all that is built; a compressed query (q_lora_rank) is not
+        only=(("hidden_act", "silu"), ("scoring_func", "sigmoid"),
+              ("rope_scaling", None), ("q_lora_rank", None),
+              ("tie_word_embeddings", False), ("attention_bias", False),
+              ("n_group", 1), ("topk_group", 1), ("moe_layer_freq", 1)),
+        layers=_latent_layers,
+        mixers={LATENT: _latent_attention_mixer},
+        layers_refusal=(
+            f"token policy: layer_kinds must name one of {(LATENT,)} for "
+            f"each of num_hidden_layers"),
+        groups=("embedding", "attention", "experts", "mlp", "norms",
+                "heads"),
+        stats=_EXPERT_STATS,
+        said_as=_ROUTED_SAID_AS + (
+            ("first_k_dense_replace", "num_dense_layers"),)),
+    "nemotron_h": _Family(
+        required=_ALWAYS + (
+            "num_key_value_heads", "head_dim", "hybrid_override_pattern",
+            "mamba_num_heads", "mamba_head_dim", "ssm_state_size",
+            "n_groups", "conv_kernel", "chunk_size",
+            "moe_intermediate_size", "moe_shared_expert_intermediate_size",
+            "n_routed_experts", "n_shared_experts", "num_experts_per_tok",
+            "routed_scaling_factor", "norm_topk_prob", "mlp_hidden_act",
+            "layer_norm_epsilon", "experts_held"),
+        # experts of two matrices under relu2, one group to choose from,
+        # no bias but the convolution's, no window
+        only=(("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+              ("n_group", 1), ("topk_group", 1),
+              ("tie_word_embeddings", False), ("attention_bias", False),
+              ("mamba_proj_bias", False), ("mlp_bias", False),
+              ("use_bias", False), ("use_conv_bias", True),
+              ("sliding_window", None)),
+        layers=_pattern_layers,
+        mixers={MAMBA2: _mamba2_mixer, EXPERTS: _experts_mixer,
+                FULL: _plain_attention_mixer},
+        layers_refusal=(
+            "token policy: hybrid_override_pattern must have a "
+            "letter for each of num_hidden_layers"),
+        # a shared expert's matrices count as the MLP, as in the first
+        # family
+        groups=("embedding", "attention", "ssd", "experts", "mlp", "norms",
+                "heads"),
+        stats=_EXPERT_STATS,
+        said_as=_ROUTED_SAID_AS + (
+            ("layer_norm_epsilon", "rms_norm_eps"),),
+        mixer_alone=True),
 }
-_TELEMETRY["deepseek_v3"] = _TELEMETRY["afmoe"]
-# a shared expert's matrices count as the MLP, as in the first family
-_TELEMETRY["nemotron_h"] = (
-    ("embedding", "attention", "ssd", "experts", "mlp", "norms", "heads"),
-    _TELEMETRY["afmoe"][1])
+FAMILIES = tuple(_FAMILY)
+_FIRST = _FAMILY[TokenModelConfig.model_type]
 
 
 class TokenPolicy(nn.Module):
@@ -1128,15 +1280,15 @@ class TokenPolicy(nn.Module):
     init_steps = 1
 
     # the parameter groups and what the forward pass says of itself
-    # (``_TELEMETRY``): the first family's here, the model's own once
-    # the policy is made
-    layer_groups: Tuple[str, ...] = _TELEMETRY["afmoe"][0]
-    STATS: Tuple[str, ...] = _TELEMETRY["afmoe"][1]
+    # (``_Family.groups``, ``.stats``): the default family's here, the
+    # model's own once the policy is made
+    layer_groups: Tuple[str, ...] = _FIRST.groups
+    STATS: Tuple[str, ...] = _FIRST.stats
 
     def __post_init__(self):
-        groups, stats = _TELEMETRY[self.model.model_type]
-        object.__setattr__(self, "layer_groups", groups)
-        object.__setattr__(self, "STATS", stats)
+        family = _FAMILY[self.model.model_type]
+        object.__setattr__(self, "layer_groups", family.groups)
+        object.__setattr__(self, "STATS", family.stats)
         super().__post_init__()
 
     @staticmethod
@@ -1239,11 +1391,10 @@ class TokenPolicy(nn.Module):
     def initial_state(self, batch: int) -> TokenCache:
         model = self.model
         latent = bool(model.latent_dim)
-        if model.model_type == "phi4flash":
+        heads, dim = model.num_key_value_heads, model.head_dim
+        if _FAMILY[model.model_type].paired_heads:
             # a pair of heads is one of twice the width
-            heads, dim = model.num_key_value_heads // 2, 2 * model.head_dim
-        else:
-            heads, dim = model.num_key_value_heads, model.head_dim
+            heads, dim = heads // 2, 2 * dim
 
         def ring(layer):
             slots = self._slots(layer)
@@ -1312,6 +1463,39 @@ class TokenPolicy(nn.Module):
         state = jax.eval_shape(lambda: self.initial_state(batch))
         return sum(x.size * x.dtype.itemsize
                    for x in state.ssm_state + state.conv_tail)
+
+    def gauges(self, batch: int):
+        """(name, value, help text) of what the driver sets once a run
+        (``driver.build_token_policy``): the sizes of the state the
+        rollout carries at ``batch`` envs."""
+        return (
+            ("cache/bytes", self.cache_bytes(batch),
+             "bytes of the attention cache the rollout carries"),
+            ("cache/window_slots", self.window_slots,
+             "slots of a window layer's ring (window + unroll)"),
+            ("cache/full_slots", self.full_slots,
+             "slots of a full layer's ring (episode + unroll)"),
+            ("cache/ring_readers", self.ring_readers,
+             "the most layers that read one ring: its own layer and the "
+             "cross layers into it"),
+            ("cache/ring_bytes", self.ring_bytes(batch),
+             "bytes of the largest one layer's ring"),
+            ("cache/latent_bytes_per_token", self.latent_bytes_per_token,
+             "bytes a token a layer the rings hold where attention is "
+             "latent (one compressed row, every head's key and value); "
+             "0 where they hold whole keys and values"),
+            ("ssm/state_bytes", self.ssm_state_bytes(batch),
+             "bytes of the state-space layers' recurrent states and "
+             "convolution tails the rollout carries (float32)"),
+            ("ssd/state_bytes_per_env",
+             self.ssm_state_bytes(1)
+             if MAMBA2 in self.model.layer_types else 0,
+             "bytes an env of the Mamba-2 layers' matrix states and "
+             "convolution tails, over the layers, read off the state's "
+             "own arrays; 0 where no layer is a Mamba-2 scan"),
+            ("policy/vocab_slice", self.model.vocab_size,
+             "tokens of the vocabulary this chip's head and embedding "
+             "hold"))
 
     def acting_params(self, params):
         """The parameters as acting reads them: cast once to the compute
@@ -1408,13 +1592,11 @@ class TokenPolicy(nn.Module):
                          jnp.mean(jnp.stack(said)),
                          init_fn=lambda: 0.0, reduce_fn=lambda _, new: new)
 
-        tied = model.model_type == "phi4flash"
-        if tied:
-            z = _LayerNorm(model.layer_norm_eps, name="final_norm")(h)
-        else:
-            z = _RMSNorm(model.rms_norm_eps, name="final_norm")(h)
+        family = _FAMILY[model.model_type]
+        z = family.norm(getattr(model, family.norm_eps),
+                        name="final_norm")(h)
         z = jnp.swapaxes(z, 0, 1)                         # [T, B, hidden]
-        if tied:
+        if family.tied_head:
             with jax.named_scope("policy_logits"):
                 policy_logits = tied_logits(z, table, dtype)
         else:

@@ -45,6 +45,15 @@ def bench_history(tmp_path_factory):
     return write_history(tmp_path_factory.mktemp("bench_history"))
 
 
+def pytest_generate_tests(metafunc):
+    """A family suite's cases that follow the family (a gradient leaf a
+    case; tests/family_suite.py ``per_preset``) come from the preset of
+    the class that inherits the test."""
+    for argname, field in getattr(metafunc.function, "per_preset", ()):
+        metafunc.parametrize(argname,
+                             list(getattr(metafunc.cls.preset, field)))
+
+
 # -- smoke tier ------------------------------------------------------------
 # `pytest -m smoke` is the time-boxed CI selection (< 2 min on one core):
 # the pure-math and protocol modules below, minus anything marked slow.

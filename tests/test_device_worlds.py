@@ -13,6 +13,11 @@ Three layers of proof for ``device_grid_*`` / ``device_minatar_*``:
    ``--train_backend=ingraph`` (complete conservation-checked ledger
    artifact, ``devtel/env/*`` episodes > 0), and a short real training
    run IMPROVES return on ``device_grid_small``.
+
+And the token world (``token_recall*``, what the token policy acts in):
+its stream ignores the action and repeats past its period, and the
+reference's copy of it emits the program's tokens under the program's
+keys.
 """
 
 import glob
@@ -601,3 +606,65 @@ def test_device_grid_learning_improves():
         f"{early:.3f} late {late:.3f}")
     assert late >= 0.55, (
         f"final return {late:.3f} stayed near the random policy's")
+
+
+# -- the token world (``token_recall*``) ---------------------------------------
+
+TOKEN_ENVS = 4
+
+
+def roll(env, actions, seeds):
+    state, first = env.initial(seeds)
+    _, outs = jax.lax.scan(env.step, state, actions)
+    return first, outs
+
+
+def test_the_stream_ignores_the_action():
+    env = make_device_env("token_recall_small")
+    seeds = np.arange(TOKEN_ENVS, dtype=np.int32) + 1
+    rng = np.random.default_rng(0)
+    a = jnp.asarray(rng.integers(0, 64, (40, TOKEN_ENVS)), jnp.int32)
+    b = jnp.asarray(rng.integers(0, 64, (40, TOKEN_ENVS)), jnp.int32)
+    _, outs_a = roll(env, a, seeds)
+    _, outs_b = roll(env, b, seeds)
+    np.testing.assert_array_equal(outs_a.observation.frame,
+                                  outs_b.observation.frame)
+    np.testing.assert_array_equal(outs_a.done, outs_b.done)
+    assert not np.array_equal(outs_a.reward, outs_b.reward)
+
+
+def test_a_position_past_the_period_repeats():
+    env = make_device_env("token_recall_small")
+    seeds = np.zeros((1,), np.int32) + 7
+    first, outs = roll(env, jnp.zeros((15, 1), jnp.int32), seeds)
+    tokens = np.concatenate([np.asarray(first.observation.frame)[None],
+                             np.asarray(outs.observation.frame)])[:, 0]
+    np.testing.assert_array_equal(tokens[10:16], tokens[0:6])
+
+
+def test_the_references_world_emits_the_programs_tokens():
+    """The tiny world every family's preset acts in, against the first
+    family's reference's copy (the cells' own worlds, a case a family:
+    tests/family_suite.py)."""
+    from benchmark.lib import manifest
+
+    ref = manifest.load_module(
+        os.path.join(manifest.BENCH_DIR, "references", "afmoe_token.py"),
+        "reference_afmoe_token_worlds")
+    world = {"name": "token_recall_small", "vocab_size": 64,
+             "episode_length": 16, "period": 10}
+    env = make_device_env(world["name"])
+    seeds = np.arange(TOKEN_ENVS, dtype=np.int32) + 1
+    rng = np.random.default_rng(4)
+    actions = jnp.asarray(
+        rng.integers(0, world["vocab_size"], (40, TOKEN_ENVS)), jnp.int32)
+    first, outs = roll(env, actions, seeds)
+    state, (reward, done, token) = ref.world_initial(world, seeds)
+    np.testing.assert_array_equal(first.observation.frame, token)
+    np.testing.assert_array_equal(first.done, done)
+    for t in range(actions.shape[0]):
+        state, (reward, done, token) = ref.world_step(
+            world, state, actions[t])
+        np.testing.assert_array_equal(outs.observation.frame[t], token)
+        np.testing.assert_array_equal(outs.reward[t], reward)
+        np.testing.assert_array_equal(outs.done[t], done)

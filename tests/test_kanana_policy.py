@@ -8,20 +8,17 @@ up-projected for every token), at a tiny preset: hidden 64, 4 heads of
 a token, 2 shared, vocabulary 64, unroll 6, episodes of 16, seeded
 weights, one dense layer and two expert layers.
 
-(a) one T = unroll forward, the loss and every leaf's gradient against
-    the reference in float32 (1e-5), and in bfloat16 inside a band an
-    fp8 cast falls out of; the planted fault moves the loss;
-(b) acting step by step through the rings gives the logits of a whole
-    forward, across episode ends and the rings' wrap, and of chunked
-    forwards;
+(a, b) ``TestPolicy``: the suite every family inherits
+    (tests/family_suite.py ``PolicyConformance``) at this preset, the
+    planted fault the shared key left unrotated; acting through the slot
+    kernel is the chunked forward;
 (c) the state is a ring of rows a layer, and nothing as large as a
     past token's whole keys is in the lowered update or decode step;
-    ``unroll_state``: the rings of the unroll's end;
 (d) interleaved rotary pairs are the half-split ones on permuted
     weights;
 (e) the share tied to the model: the four shares' routed parts and the
     shared experts once are the uncut layer (the test is
-    tests/test_token_policy.py's, a case a family).
+    tests/test_moe.py's, a case a family).
 
 The driver, the world, the configuration file and the benchmark's
 harness at this preset are in tests/test_kanana_harness.py.
@@ -41,19 +38,19 @@ for path in (ROOT, os.path.join(ROOT, "tests")):
     if path not in sys.path:
         sys.path.insert(0, path)
 
-from benchmark.lib import manifest  # noqa: E402
+from family_suite import (  # noqa: E402
+    LOSS,
+    OPTIMIZER,
+    PolicyConformance,
+    Preset,
+    env_outputs,
+    rel,
+)
 from scalable_agent_tpu.models import token_policy  # noqa: E402
 from scalable_agent_tpu.models.token_policy import (  # noqa: E402
     TokenModelConfig,
     TokenPolicy,
 )
-from scalable_agent_tpu.runtime.learner import Trajectory  # noqa: E402
-from scalable_agent_tpu.types import AgentOutput  # noqa: E402
-from test_sambay_policy import env_outputs, learner_of, rel  # noqa: E402
-
-ref = manifest.load_module(
-    os.path.join(ROOT, "benchmark", "references", "deepseek_v3_token.py"),
-    "reference_deepseek_v3_token_tests")
 
 UNROLL, EPISODE, BATCH, VOCAB = 6, 16, 4, 64
 TINY = {
@@ -71,200 +68,64 @@ TINY = {
     "rope_theta": 1000000, "rope_interleave": True, "rms_norm_eps": 1e-06,
     "experts_held": 2, "first_expert": 0,
     "reference": "deepseek_v3_token", "reference_block": 2,
-    "mean_context": 8,
-    "loss": {"name": "vtrace", "entropy_cost": 0.00025,
-             "baseline_cost": 0.5, "discounting": 0.99,
-             "reward_clipping": "abs_one", "clip_rho_threshold": 1.0,
-             "clip_pg_rho_threshold": 1.0},
-    "optimizer": {"name": "rmsprop", "learning_rate": 0.00048,
-                  "rmsprop_decay": 0.99, "rmsprop_momentum": 0.0,
-                  "rmsprop_epsilon": 0.1, "initial_mean_square": 1.0,
-                  "total_environment_frames": 1e9},
+    "mean_context": 8, "loss": LOSS, "optimizer": OPTIMIZER,
 }
-MODEL = TokenModelConfig.from_dict(TINY)
 ROW = TINY["kv_lora_rank"] + TINY["qk_rope_head_dim"]
+PRESET = Preset(
+    tiny=TINY, reference="deepseek_v3_token",
+    cell="kanana2.ingraph", config_file="kanana2_30b_ep8",
+    traffic_file="fused_token_recall_u256_e10240",
+    level="token_recall_10k", world=(16032, 10240, 6144),
+    why_says=("384", "8x"),
+    own_metrics=(
+        "latent_attention_device_share.fused",
+        "latent_cache_bytes_per_token", "latent_decode_roofline.fused",
+        "latent_slot_kernel_share", "latent_update_roofline.fused"),
+    groups=("embedding", "attention", "experts", "mlp", "norms", "heads"),
+    kernel_policy_says=("3 latent_attention",
+                        f"latent_bytes_per_token={4 * ROW}",
+                        "experts_held=2/8"),
+    lacking=("kv_lora_rank", "qk_rope_head_dim", "v_head_dim",
+             "n_routed_experts", "first_k_dense_replace",
+             "routed_scaling_factor", "rope_interleave", "experts_held"),
+    published={
+        "attention_bias": False, "first_k_dense_replace": 1, "head_dim": 64,
+        "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 6144, "kv_lora_rank": 512,
+        "max_position_embeddings": 32768, "model_type": "deepseek_v3",
+        "moe_intermediate_size": 768, "moe_layer_freq": 1, "n_group": 1,
+        "n_routed_experts": 128, "n_shared_experts": 2,
+        "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_experts_per_tok": 6, "num_hidden_layers": 48,
+        "num_key_value_heads": 32, "q_lora_rank": None, "qk_head_dim": 192,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+        "rms_norm_eps": 1e-06, "rope_interleave": True,
+        "rope_scaling": None, "rope_theta": 1000000,
+        "routed_scaling_factor": 2.448, "scoring_func": "sigmoid",
+        "tie_word_embeddings": False, "topk_group": 1,
+        "topk_method": "noaux_tc", "v_head_dim": 128, "vocab_size": 128256},
+    reduced_numbers=("num_hidden_layers", "vocab_size"),
+    prints=("latent_cache_bytes_per_token",),
+    does_not_print=("expert_load_max_over_mean",),
+    # The loss against the float32 reference's.  bfloat16 reads 1e-3 here
+    # and fp8 0.05 or more: the band lies between.
+    bfloat16_band=0.01,
+    # the shared key is never rotated
+    fault="no_rope_on_shared_key",
+    rehearse_flags=("--control", "1"),
+    fp8_moves=0.02)
+MODEL = PRESET.model
+ref = PRESET.ref
+policy, weights = PRESET.policy, PRESET.weights
 
 
-def policy(dtype=jnp.float32, model=MODEL):
-    return TokenPolicy(model=model, unroll_length=UNROLL,
-                       episode_length=EPISODE, compute_dtype=dtype)
+class TestPolicy(PolicyConformance):
+    """(a, b): the suite at this preset.  1e-5 in float32: the program
+    scores ``q Wkvb_k^T`` against the row, the reference ``q`` against
+    the row's up-projection.  Forty steps: the ring (16 + 6 rows) wraps
+    once."""
 
-
-def weights(seed=5, cfg=TINY):
-    return {"params": ref.to_tree(ref.make_weights(cfg, seed))}
-
-
-def trajectory(agent, params, seed=3):
-    """One unroll as the fused rollout lays it out, made by hand, with
-    an episode's end inside it for two of the four envs; behaviour
-    log-probabilities from the policy's own logits moved a little off."""
-    rng = np.random.default_rng(seed)
-    tokens = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
-                         jnp.int32)
-    done = np.zeros((UNROLL + 1, BATCH), bool)
-    done[0] = True
-    done[3, 1] = done[5, 2] = True
-    done = jnp.asarray(done)
-    actions = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
-                          jnp.int32)
-    reward = jnp.asarray(rng.integers(0, 2, (UNROLL + 1, BATCH)),
-                         jnp.float32)
-    state = agent.initial_state(BATCH)
-    (logits, _), _ = agent.apply(
-        params, actions, env_outputs(tokens, done, reward), state)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    taken = jnp.take_along_axis(logp[:-1], actions[1:, :, None],
-                                -1)[..., 0]
-    noise = jnp.asarray(rng.normal(0, 0.2, taken.shape), jnp.float32)
-    behaviour = jnp.concatenate([jnp.zeros((1, BATCH)), taken + noise])
-    traj = Trajectory(
-        agent_state=state,
-        env_outputs=env_outputs(tokens, done, reward),
-        agent_outputs=AgentOutput(
-            action=actions, policy_logits=behaviour[..., None],
-            baseline=jnp.zeros((UNROLL + 1, BATCH))))
-    batch = ref.Batch(actions, behaviour, reward, done, tokens,
-                      ref.empty_history(TINY, BATCH))
-    return traj, batch
-
-
-# -- (a) forward, loss and gradients against the reference --------------------
-
-@pytest.fixture(scope="module")
-def float32_pair():
-    agent, params = policy(), weights()
-    traj, batch = trajectory(agent, params)
-    learner = learner_of(agent)
-    (loss, _), grads = jax.value_and_grad(
-        learner._loss, has_aux=True)(params, traj, None)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss(TINY, p, batch))(params["params"])
-    (logits, baseline), _ = agent.apply(
-        params, traj.agent_outputs.action, traj.env_outputs,
-        traj.agent_state)
-    ref_logits, ref_baseline, _ = ref.forward(
-        TINY, params["params"], batch.token, batch.done, batch.history)
-    return dict(loss=(loss, ref_loss), logits=(logits, ref_logits),
-                baseline=(baseline, ref_baseline),
-                grads=(ref.from_tree(grads["params"]),
-                       ref.from_tree(ref_grads)))
-
-
-@pytest.mark.parametrize("what", ["logits", "baseline", "loss"])
-def test_float32_forward_and_loss_are_the_references(float32_pair, what):
-    """1e-5: both are float32 sums of the same terms in another order
-    (the program scores ``q Wkvb_k^T`` against the row, the reference
-    ``q`` against the row's up-projection)."""
-    got, want = float32_pair[what]
-    assert rel(got, want) < 1e-5
-
-
-@pytest.mark.parametrize("leaf", sorted(
-    "/".join(path) for path in ref.weight_shapes(TINY)))
-def test_float32_gradient_is_the_references(float32_pair, leaf):
-    got, want = float32_pair["grads"]
-    path = tuple(leaf.split("/"))
-    scale = max(float(np.max(np.abs(v))) for v in want.values())
-    gap = float(np.max(np.abs(np.asarray(got[path], np.float64)
-                              - np.asarray(want[path], np.float64))))
-    assert gap <= 1e-5 * scale, (leaf, gap, scale)
-    assert float(np.max(np.abs(want[path]))) > 0.0, leaf
-
-
-def test_the_program_has_the_references_leaves_and_no_other():
-    agent = policy()
-    traj, _ = trajectory(agent, weights())
-    made = jax.eval_shape(
-        agent.init, jax.random.key(0), traj.agent_outputs.action,
-        traj.env_outputs, traj.agent_state)["params"]
-    shapes = {path: leaf.shape for path, leaf in ref.from_tree(made).items()}
-    assert shapes == {path: tuple(shape) for path, shape
-                      in ref.weight_shapes(TINY).items()}
-
-
-# The loss against the float32 reference's.  bfloat16 reads 1e-3 here and
-# fp8 0.05 or more: the band lies between.
-BFLOAT16_BAND = 0.01
-
-
-def test_bfloat16_loss_is_inside_a_band_fp8_falls_out_of():
-    params = weights()
-    agent = policy(jnp.bfloat16)
-    traj, batch = trajectory(policy(), params)
-    traj = traj._replace(agent_state=agent.initial_state(BATCH))
-    loss, _ = learner_of(agent)._loss(params, traj, None)
-    want = float(ref.loss(TINY, params["params"], batch))
-    fp8 = float(ref.loss(TINY, params["params"], batch, quant="fp8"))
-    assert abs(float(loss) - want) / abs(want) < BFLOAT16_BAND
-    assert abs(fp8 - want) / abs(want) > BFLOAT16_BAND
-
-
-def test_the_references_planted_fault_moves_its_loss():
-    """``quant="no_rope_on_shared_key"`` (the limits file's own fault):
-    the shared key is never rotated, and the loss moves by far more than
-    float32's rounding."""
-    agent, params = policy(), weights()
-    _, batch = trajectory(agent, params)
-    want = float(ref.loss(TINY, params["params"], batch))
-    planted = float(ref.loss(TINY, params["params"], batch,
-                             quant=ref.NO_ROPE_ON_SHARED_KEY))
-    assert abs(planted - want) / abs(want) > 1e-4
-
-
-# -- (b) acting through the rings is the whole forward ------------------------
-
-@pytest.fixture(scope="module")
-def forty_steps():
-    """40 steps of 4 envs in episodes of 16, staggered: every env
-    crosses two episode ends and the ring (16 + 6 rows) wraps once."""
-    steps = 40
-    rng = np.random.default_rng(11)
-    tokens = jnp.asarray(rng.integers(0, VOCAB, (steps, BATCH)), jnp.int32)
-    offset = np.arange(BATCH) * (EPISODE // BATCH)
-    done = (np.arange(steps)[:, None] + offset[None, :]) % EPISODE == 0
-    done[0] = True
-    done = jnp.asarray(done)
-    agent, params = policy(), weights(9)
-    step = jax.jit(lambda p, e, s: agent.apply(
-        p, jnp.zeros(e.done.shape, jnp.int32), e, s))
-    state, logits, values = agent.initial_state(BATCH), [], []
-    for t in range(steps):
-        (row, value), state = step(
-            params, env_outputs(tokens[t:t + 1], done[t:t + 1]), state)
-        logits.append(row[0])
-        values.append(value[0])
-    return (agent, params, tokens, done, jnp.stack(logits),
-            jnp.stack(values), state)
-
-
-@pytest.mark.parametrize("what", ["logits", "baseline"])
-def test_stepwise_outputs_are_the_references_whole_forward(
-        forty_steps, what):
-    _, params, tokens, done, logits, values, _ = forty_steps
-    whole, baseline, _ = ref.forward(TINY, params["params"], tokens, done,
-                                     ref.empty_history(TINY, BATCH))
-    got, want = ((logits, whole) if what == "logits"
-                 else (values, baseline))
-    assert rel(got, want) < 1e-5
-
-
-@pytest.mark.parametrize("chunk", [2, 5, 7])
-def test_stepwise_logits_are_the_chunked_forwards(forty_steps, chunk):
-    agent, params, tokens, done, stepwise, _, last = forty_steps
-    state, rows = agent.initial_state(BATCH), []
-    for t in range(0, tokens.shape[0], chunk):
-        (logits, _), state = agent.apply(
-            params, jnp.zeros((chunk, BATCH), jnp.int32),
-            env_outputs(tokens[t:t + chunk], done[t:t + chunk]), state)
-        rows.append(logits)
-    got = jnp.concatenate(rows)
-    assert rel(got, stepwise[:got.shape[0]]) < 1e-5
-    if got.shape[0] == stepwise.shape[0]:
-        for a, b in zip(jax.tree_util.tree_leaves(state),
-                        jax.tree_util.tree_leaves(last)):
-            np.testing.assert_allclose(np.asarray(a, np.float32),
-                                       np.asarray(b, np.float32),
-                                       atol=1e-5)
+    preset = PRESET
 
 
 def test_acting_through_the_slot_kernel_is_the_chunked_forward(monkeypatch):
@@ -373,32 +234,6 @@ def test_no_past_tokens_whole_keys_are_in_the_lowered_step(steps):
         assert not ({heads * wide, 2 * heads * wide} & set(rest)
                     or (rest.count(heads) > (BATCH in shape)
                         and wide in rest)), shape
-
-
-@pytest.mark.parametrize("what", ["forward", "rings"])
-def test_the_update_unrolls_from_the_ends_rings(forty_steps, what):
-    agent, params, tokens, done, *_ = forty_steps
-    state = agent.initial_state(BATCH)
-    zeros = jnp.zeros((UNROLL, BATCH), jnp.int32)
-    for t in range(0, 30, UNROLL):
-        start = state
-        (_, _), state = agent.apply(
-            params, zeros, env_outputs(tokens[t:t + UNROLL],
-                                       done[t:t + UNROLL]), state)
-    handed = agent.unroll_state(start, state)
-    if what == "rings":
-        for got, want in zip(handed.keys, state.keys):
-            assert got is want
-        assert handed.written is start.written
-        assert handed.episode_start is start.episode_start
-    else:
-        t = 30 - UNROLL
-        again = env_outputs(tokens[t:t + UNROLL + 1],
-                            done[t:t + UNROLL + 1])
-        actions = jnp.zeros((UNROLL + 1, BATCH), jnp.int32)
-        (want, _), _ = agent.apply(params, actions, again, start)
-        (got, _), _ = agent.apply(params, actions, again, handed)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- (d) the rotation ---------------------------------------------------------

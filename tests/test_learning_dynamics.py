@@ -730,3 +730,16 @@ class TestChaosEntropyCollapse:
                     if r.get("detector") in ("entropy_collapse",
                                              "clip_saturation")]
         assert diagnose_main([sane.logdir]) == 0
+
+
+# -- the learner's telemetry follows the agent (the token policy declares its
+# own groups: models/token_policy.py ``_Family.groups``) ------------------------
+
+def test_the_impala_agents_report_what_they_reported():
+    from scalable_agent_tpu.runtime.learner import learning_telemetry_spec
+
+    gauges = learning_telemetry_spec().gauges()
+    for group in ("torso", "core", "heads"):
+        assert f"grad_norm_{group}" in gauges
+    assert not [g for g in gauges if "attention" in g or "experts" in g]
+    assert not learning_telemetry_spec().histograms()

@@ -6,13 +6,11 @@ heads / 2 kv heads of 16 (2 query pairs over 1 key pair), d_inner 128,
 state 8, conv 4, window 8, vocabulary 64, unroll 6, episodes of 16,
 seeded weights, one layer of each kind in the model's order.
 
-(a) one T = unroll forward, the loss and its gradients against the
-    reference in float32 (1e-5), and in bfloat16 inside a band an fp8
-    cast falls out of;
-(b) acting step by step through the state gives the logits of a whole
-    forward, across episode ends and the rings' wrap;
-(c) ``unroll_state``: the rings of the unroll's end, the recurrent state
-    and the convolution's tail of its start;
+(a-c) ``TestPolicy``: the suite every family inherits
+    (tests/family_suite.py ``PolicyConformance``) at this preset, the
+    planted fault the scan's state carried over an episode's end;
+    ``unroll_state`` hands on the recurrent state and the convolution's
+    tail of the unroll's start; the state's shapes;
 (d) differential attention, one query an env and many, against the
     reference's, through an own ring and through a ring two layers read
     (the owner's keys get each reader's cotangent);
@@ -33,32 +31,23 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+for path in (ROOT, os.path.join(ROOT, "tests")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
 
-from benchmark.lib import manifest  # noqa: E402
+from family_suite import (  # noqa: E402
+    LOSS,
+    OPTIMIZER,
+    PolicyConformance,
+    Preset,
+    env_outputs,
+    rel,
+)
 from scalable_agent_tpu.models import token_policy  # noqa: E402
 from scalable_agent_tpu.models.token_policy import (  # noqa: E402
     TokenModelConfig,
-    TokenPolicy,
 )
 from scalable_agent_tpu.ops import attention as attention_lib  # noqa: E402
-from scalable_agent_tpu.parallel import MeshSpec, make_mesh  # noqa: E402
-from scalable_agent_tpu.runtime.learner import (  # noqa: E402
-    Learner,
-    LearnerHyperparams,
-    Trajectory,
-)
-from scalable_agent_tpu.types import (  # noqa: E402
-    AgentOutput,
-    Observation,
-    StepOutput,
-    StepOutputInfo,
-)
-
-ref = manifest.load_module(
-    os.path.join(ROOT, "benchmark", "references", "sambay_token.py"),
-    "reference_sambay_token_tests")
 
 UNROLL, EPISODE, BATCH, VOCAB = 6, 16, 4, 64
 TINY = {
@@ -77,218 +66,58 @@ TINY = {
         {"kind": "memory_unit", "published_index": 18},
         {"kind": "cross_attention", "published_index": 19}],
     "reference": "sambay_token", "reference_block": 2,
-    "loss": {"name": "vtrace", "entropy_cost": 0.00025,
-             "baseline_cost": 0.5, "discounting": 0.99,
-             "reward_clipping": "abs_one", "clip_rho_threshold": 1.0,
-             "clip_pg_rho_threshold": 1.0},
-    "optimizer": {"name": "rmsprop", "learning_rate": 0.00048,
-                  "rmsprop_decay": 0.99, "rmsprop_momentum": 0.0,
-                  "rmsprop_epsilon": 0.1, "initial_mean_square": 1.0,
-                  "total_environment_frames": 1e9},
+    "loss": LOSS, "optimizer": OPTIMIZER,
 }
-MODEL = TokenModelConfig.from_dict(TINY)
+PRESET = Preset(
+    tiny=TINY, reference="sambay_token",
+    cell="phi4flash.ingraph", config_file="phi4_mini_flash_vp8",
+    traffic_file="fused_token_recall_u256_e6144",
+    level="token_recall_long", world=(25008, 6144, 3584),
+    why_says=("6,144", "2 of 6 layers"),
+    own_metrics=(
+        "diff_attention_device_share.fused",
+        "diff_attention_update_roofline.fused", "gmu_device_share.fused",
+        "ssm_device_share.fused", "ssm_scan_roofline.fused"),
+    groups=("embedding", "attention", "ssm", "gmu", "mlp", "norms", "heads"),
+    kernel_policy_says=("2 state_space", "1 cross_attention",
+                        "ring_readers=2"),
+    lacking=("layer_kinds", "mamba_d_state", "layer_norm_eps",
+             "sliding_window"),
+    published={
+        "embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560,
+        "intermediate_size": 10240, "layer_norm_eps": 1e-05,
+        "max_position_embeddings": 262144, "mb_per_layer": 2,
+        "model_type": "phi4flash", "num_attention_heads": 40,
+        "num_hidden_layers": 32, "num_key_value_heads": 20,
+        "resid_pdrop": 0, "sliding_window": 512,
+        "tie_word_embeddings": True, "mlp_bias": False,
+        "lm_head_bias": False, "vocab_size": 200064},
+    reduced_numbers=("num_hidden_layers", "vocab_size"),
+    prints=(),
+    # the expert layer's counter is another cell's
+    does_not_print=("expert_load_max_over_mean",),
+    # The loss against the float32 reference's.  bfloat16 reads 2e-3 here
+    # and fp8 0.2: the band lies a decade from each.
+    bfloat16_band=0.02,
+    # the scan's state is carried over an episode's end
+    fault="no_reset",
+    unrolls_from=("forward", "rings", "recurrent"),
+    # the cell's file states a head's width; this family's tiny one
+    # leaves it to the hidden size's share
+    not_in_tiny=("head_dim",))
+MODEL = PRESET.model
+ref = PRESET.ref
+policy, weights = PRESET.policy, PRESET.weights
 
 
-def policy(dtype=jnp.float32, model=MODEL):
-    return TokenPolicy(model=model, unroll_length=UNROLL,
-                       episode_length=EPISODE, compute_dtype=dtype)
+class TestPolicy(PolicyConformance):
+    """(a-c): the suite at this preset.  Forty steps: the window ring
+    (8 + 6 slots) wraps twice and the full ring (16 + 6) once."""
+
+    preset = PRESET
 
 
-def weights(seed=5, cfg=TINY):
-    return {"params": ref.to_tree(ref.make_weights(cfg, seed))}
-
-
-def env_outputs(tokens, done, reward=None):
-    zeros = jnp.zeros(tokens.shape, jnp.float32)
-    return StepOutput(
-        reward=zeros if reward is None else reward,
-        info=StepOutputInfo(zeros, jnp.zeros(tokens.shape, jnp.int32)),
-        done=done, observation=Observation(frame=tokens))
-
-
-def learner_of(agent):
-    mesh = make_mesh(MeshSpec(data=1), devices=jax.devices()[:1])
-    return Learner(agent, LearnerHyperparams(), mesh,
-                   frames_per_update=BATCH * UNROLL)
-
-
-def trajectory(agent, params, seed=3):
-    """One unroll as the fused rollout lays it out, made by hand, with
-    an episode's end inside it for two of the four envs; behaviour
-    log-probabilities from the policy's own logits moved a little off."""
-    rng = np.random.default_rng(seed)
-    tokens = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
-                         jnp.int32)
-    done = np.zeros((UNROLL + 1, BATCH), bool)
-    done[0] = True
-    done[3, 1] = done[5, 2] = True
-    done = jnp.asarray(done)
-    actions = jnp.asarray(rng.integers(0, VOCAB, (UNROLL + 1, BATCH)),
-                          jnp.int32)
-    reward = jnp.asarray(rng.integers(0, 2, (UNROLL + 1, BATCH)),
-                         jnp.float32)
-    state = agent.initial_state(BATCH)
-    (logits, _), _ = agent.apply(
-        params, actions, env_outputs(tokens, done, reward), state)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    taken = jnp.take_along_axis(logp[:-1], actions[1:, :, None],
-                                -1)[..., 0]
-    noise = jnp.asarray(rng.normal(0, 0.2, taken.shape), jnp.float32)
-    behaviour = jnp.concatenate([jnp.zeros((1, BATCH)), taken + noise])
-    traj = Trajectory(
-        agent_state=state,
-        env_outputs=env_outputs(tokens, done, reward),
-        agent_outputs=AgentOutput(
-            action=actions, policy_logits=behaviour[..., None],
-            baseline=jnp.zeros((UNROLL + 1, BATCH))))
-    batch = ref.Batch(actions, behaviour, reward, done, tokens,
-                      ref.empty_history(TINY, BATCH))
-    return traj, batch
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-# -- (a) forward, loss and gradients against the reference --------------------
-
-@pytest.fixture(scope="module")
-def float32_pair():
-    agent, params = policy(), weights()
-    traj, batch = trajectory(agent, params)
-    learner = learner_of(agent)
-    (loss, _), grads = jax.value_and_grad(
-        learner._loss, has_aux=True)(params, traj, None)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss(TINY, p, batch))(params["params"])
-    (logits, baseline), _ = agent.apply(
-        params, traj.agent_outputs.action, traj.env_outputs,
-        traj.agent_state)
-    ref_logits, ref_baseline, _ = ref.forward(
-        TINY, params["params"], batch.token, batch.done, batch.history)
-    return dict(loss=(loss, ref_loss), logits=(logits, ref_logits),
-                baseline=(baseline, ref_baseline),
-                grads=(ref.from_tree(grads["params"]),
-                       ref.from_tree(ref_grads)))
-
-
-@pytest.mark.parametrize("what", ["logits", "baseline", "loss"])
-def test_float32_forward_and_loss_are_the_references(float32_pair, what):
-    got, want = float32_pair[what]
-    assert rel(got, want) < 1e-5
-
-
-@pytest.mark.parametrize("leaf", sorted(
-    "/".join(path) for path in ref.weight_shapes(TINY)))
-def test_float32_gradient_is_the_references(float32_pair, leaf):
-    got, want = float32_pair["grads"]
-    path = tuple(leaf.split("/"))
-    scale = max(float(np.max(np.abs(v))) for v in want.values())
-    gap = float(np.max(np.abs(np.asarray(got[path], np.float64)
-                              - np.asarray(want[path], np.float64))))
-    assert gap <= 1e-5 * scale, (leaf, gap, scale)
-    assert float(np.max(np.abs(want[path]))) > 0.0, leaf
-
-
-def test_the_program_has_the_references_leaves_and_no_other():
-    agent = policy()
-    traj, _ = trajectory(agent, weights())
-    made = jax.eval_shape(
-        agent.init, jax.random.key(0), traj.agent_outputs.action,
-        traj.env_outputs, traj.agent_state)["params"]
-    shapes = {path: leaf.shape for path, leaf in ref.from_tree(made).items()}
-    assert shapes == {path: tuple(shape) for path, shape
-                      in ref.weight_shapes(TINY).items()}
-
-
-# The loss against the float32 reference's.  bfloat16 reads 2e-3 here and
-# fp8 0.2: the band lies a decade from each.
-BFLOAT16_BAND = 0.02
-
-
-def test_bfloat16_loss_is_inside_a_band_fp8_falls_out_of():
-    params = weights()
-    agent = policy(jnp.bfloat16)
-    traj, batch = trajectory(policy(), params)
-    traj = traj._replace(agent_state=agent.initial_state(BATCH))
-    loss, _ = learner_of(agent)._loss(params, traj, None)
-    want = float(ref.loss(TINY, params["params"], batch))
-    fp8 = float(ref.loss(TINY, params["params"], batch, quant="fp8"))
-    assert abs(float(loss) - want) / abs(want) < BFLOAT16_BAND
-    assert abs(fp8 - want) / abs(want) > BFLOAT16_BAND
-
-
-def test_the_references_planted_fault_moves_its_loss():
-    """``quant="no_reset"`` (the limits file's own fault): the scan's
-    state is carried over an episode's end, and the loss moves by far
-    more than float32's rounding."""
-    agent, params = policy(), weights()
-    _, batch = trajectory(agent, params)
-    want = float(ref.loss(TINY, params["params"], batch))
-    planted = float(ref.loss(TINY, params["params"], batch,
-                             quant=ref.NO_RESET))
-    assert abs(planted - want) / abs(want) > 1e-4
-
-
-# -- (b) acting through the state is the whole forward ------------------------
-
-@pytest.fixture(scope="module")
-def forty_steps():
-    """40 steps of 4 envs in episodes of 16, staggered: every env
-    crosses two episode ends, the window ring (8 + 6 slots) wraps twice
-    and the full ring (16 + 6) once."""
-    steps = 40
-    rng = np.random.default_rng(11)
-    tokens = jnp.asarray(rng.integers(0, VOCAB, (steps, BATCH)), jnp.int32)
-    offset = np.arange(BATCH) * (EPISODE // BATCH)
-    done = (np.arange(steps)[:, None] + offset[None, :]) % EPISODE == 0
-    done[0] = True
-    done = jnp.asarray(done)
-    agent, params = policy(), weights(9)
-    step = jax.jit(lambda p, e, s: agent.apply(
-        p, jnp.zeros(e.done.shape, jnp.int32), e, s))
-    state, logits, values = agent.initial_state(BATCH), [], []
-    for t in range(steps):
-        (row, value), state = step(
-            params, env_outputs(tokens[t:t + 1], done[t:t + 1]), state)
-        logits.append(row[0])
-        values.append(value[0])
-    return (agent, params, tokens, done, jnp.stack(logits),
-            jnp.stack(values), state)
-
-
-@pytest.mark.parametrize("what", ["logits", "baseline"])
-def test_stepwise_outputs_are_the_references_whole_forward(
-        forty_steps, what):
-    _, params, tokens, done, logits, values, _ = forty_steps
-    whole, baseline, _ = ref.forward(TINY, params["params"], tokens, done,
-                                     ref.empty_history(TINY, BATCH))
-    got, want = ((logits, whole) if what == "logits"
-                 else (values, baseline))
-    assert rel(got, want) < 1e-5
-
-
-@pytest.mark.parametrize("chunk", [2, 5, 7])
-def test_stepwise_logits_are_the_chunked_forwards(forty_steps, chunk):
-    agent, params, tokens, done, stepwise, _, last = forty_steps
-    state, rows = agent.initial_state(BATCH), []
-    for t in range(0, tokens.shape[0], chunk):
-        (logits, _), state = agent.apply(
-            params, jnp.zeros((chunk, BATCH), jnp.int32),
-            env_outputs(tokens[t:t + chunk], done[t:t + chunk]), state)
-        rows.append(logits)
-    got = jnp.concatenate(rows)
-    assert rel(got, stepwise[:got.shape[0]]) < 1e-5
-    if got.shape[0] == stepwise.shape[0]:
-        for a, b in zip(jax.tree_util.tree_leaves(state),
-                        jax.tree_util.tree_leaves(last)):
-            np.testing.assert_allclose(np.asarray(a, np.float32),
-                                       np.asarray(b, np.float32),
-                                       atol=1e-5)
-
-
-# -- (c) what the update unrolls from -----------------------------------------
+# -- (c) the state ------------------------------------------------------------
 
 def test_the_state_holds_rings_for_the_layers_that_make_keys():
     state = policy().initial_state(BATCH)
@@ -299,39 +128,6 @@ def test_the_state_holds_rings_for_the_layers_that_make_keys():
     assert [s.shape for s in state.conv_tail] == [(BATCH, 3, 128)] * 2
     assert all(s.dtype == jnp.float32 for s in state.ssm_state)
     assert policy().ring_readers == 2
-
-
-@pytest.mark.parametrize("what", ["forward", "rings", "recurrent"])
-def test_the_update_unrolls_from_the_ends_rings_and_the_starts_state(
-        forty_steps, what):
-    agent, params, tokens, done, *_ = forty_steps
-    state = agent.initial_state(BATCH)
-    zeros = jnp.zeros((UNROLL, BATCH), jnp.int32)
-    for t in range(0, 30, UNROLL):
-        start = state
-        (_, _), state = agent.apply(
-            params, zeros, env_outputs(tokens[t:t + UNROLL],
-                                       done[t:t + UNROLL]), state)
-    handed = agent.unroll_state(start, state)
-    if what == "rings":
-        for got, want in zip(handed.keys + handed.values,
-                             state.keys + state.values):
-            assert got is want
-        assert handed.written is start.written
-    elif what == "recurrent":
-        for got, want in zip(handed.ssm_state + handed.conv_tail,
-                             start.ssm_state + start.conv_tail):
-            assert got is want
-        assert float(jnp.max(jnp.abs(
-            state.ssm_state[0] - start.ssm_state[0]))) > 0.0
-    else:
-        t = 30 - UNROLL
-        again = env_outputs(tokens[t:t + UNROLL + 1],
-                            done[t:t + UNROLL + 1])
-        actions = jnp.zeros((UNROLL + 1, BATCH), jnp.int32)
-        (want, _), _ = agent.apply(params, actions, again, start)
-        (got, _), _ = agent.apply(params, actions, again, handed)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- (d) differential attention -----------------------------------------------

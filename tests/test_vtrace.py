@@ -276,3 +276,83 @@ def test_vtrace_inside_jit_and_grad_stopped():
 
     g = jax.jit(jax.grad(loss_fn))(jnp.asarray(inputs["values"]))
     np.testing.assert_allclose(np.zeros_like(inputs["values"]), np.asarray(g))
+
+
+# -- V-trace from the stored log-probability ----------------------------------
+
+@pytest.mark.parametrize("field", ["vs", "pg_advantages", "log_rhos"])
+def test_vtrace_from_log_probs_is_vtrace_from_logits(field):
+    rng = np.random.default_rng(0)
+    shape = (7, 3)
+    behaviour = jnp.asarray(rng.normal(size=shape + (9,)), jnp.float32)
+    target = jnp.asarray(rng.normal(size=shape + (9,)), jnp.float32)
+    actions = jnp.asarray(rng.integers(0, 9, shape), jnp.int32)
+    rest = dict(
+        discounts=jnp.full(shape, 0.99), rewards=jnp.asarray(
+            rng.normal(size=shape), jnp.float32),
+        values=jnp.asarray(rng.normal(size=shape), jnp.float32),
+        bootstrap_value=jnp.asarray(rng.normal(size=shape[1:]),
+                                    jnp.float32))
+    want = vtrace.from_logits(behaviour, target, actions, **rest)
+    got = vtrace.from_behaviour_log_probs(
+        vtrace.log_probs_from_logits_and_actions(behaviour, actions),
+        target, actions, **rest)
+    np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                  np.asarray(getattr(want, field)))
+
+
+def _vtrace_inputs(shape=(40, 3), actions=64, seed=0):
+    rng = np.random.default_rng(seed)
+    target = jnp.asarray(rng.normal(size=shape + (actions,)), jnp.float32)
+    taken = jnp.asarray(rng.integers(0, actions, shape), jnp.int32)
+    rest = dict(
+        discounts=jnp.full(shape, 0.99), rewards=jnp.asarray(
+            rng.integers(0, 2, shape), jnp.float32),
+        values=jnp.asarray(rng.normal(size=shape), jnp.float32),
+        bootstrap_value=jnp.asarray(rng.normal(size=shape[1:]),
+                                    jnp.float32))
+    return rng, target, taken, rest
+
+
+@pytest.mark.parametrize("field", ["vs", "pg_advantages"])
+def test_on_policy_vtrace_is_vtrace_at_ratios_of_one(field):
+    """What acting and learning round differently is not a second
+    policy: told that the data is on policy, the targets are those of
+    ratios of exactly 1, whatever the stored log-probabilities say, and
+    the diagnostics still carry what was measured."""
+    rng, target, taken, rest = _vtrace_inputs()
+    exact = vtrace.log_probs_from_logits_and_actions(target, taken)
+    noisy = exact + jnp.asarray(rng.normal(0, 5e-3, exact.shape),
+                                jnp.float32)
+    want = vtrace.from_behaviour_log_probs(exact, target, taken, **rest)
+    got = vtrace.from_behaviour_log_probs(noisy, target, taken,
+                                          on_policy=True, **rest)
+    np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                  np.asarray(getattr(want, field)))
+    np.testing.assert_array_equal(np.asarray(got.log_rhos),
+                                  np.asarray(exact - noisy))
+    assert float(got.diagnostics.log_rho_p95) > 1e-3
+    assert float(want.diagnostics.log_rho_p95) == 0.0
+
+
+def test_the_clip_at_one_turns_rounding_into_a_trace_cut_short():
+    """Why the fused loop tells the learner it is on policy: a scatter
+    of 4.5e-3 round a log-ratio of 0 (what bfloat16 leaves between
+    T = 1 and T = unroll on the chip) shortens every trace through
+    ``min(1, rho)``, and at a discount of 0.99 the targets of a world
+    that pays 1 a step read percents low."""
+    rng, target, taken, rest = _vtrace_inputs(shape=(256, 8), seed=1)
+    rest["rewards"] = jnp.ones_like(rest["rewards"])
+    rest["values"] = jnp.zeros_like(rest["values"])
+    rest["bootstrap_value"] = jnp.zeros_like(rest["bootstrap_value"])
+    exact = vtrace.log_probs_from_logits_and_actions(target, taken)
+    noisy = exact + jnp.asarray(rng.normal(0, 4.5e-3, exact.shape),
+                                jnp.float32)
+    clean = vtrace.from_behaviour_log_probs(exact, target, taken, **rest)
+    stored = vtrace.from_behaviour_log_probs(noisy, target, taken, **rest)
+    told = vtrace.from_behaviour_log_probs(noisy, target, taken,
+                                           on_policy=True, **rest)
+    low = 1.0 - float(jnp.mean(stored.vs[0]) / jnp.mean(clean.vs[0]))
+    assert 0.05 < low < 0.25, low
+    np.testing.assert_array_equal(np.asarray(told.vs),
+                                  np.asarray(clean.vs))
