@@ -251,6 +251,14 @@ register_device_level(
                 "episodes of 14,336 tokens that repeat after 8,192 "
                 "positions, from an eighth of a 131,072-token vocabulary")
 register_device_level(
+    "token_recall_8k",
+    "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
+    dict(num_actions=12544, episode_length=7936, period=4096),
+    description="the same world for a policy that keeps the whole "
+                "episode in one full-attention ring among delta-rule "
+                "scans: episodes of 7,936 tokens that repeat after 4,096 "
+                "positions, from an eighth of a 100,352-token vocabulary")
+register_device_level(
     "token_recall_small",
     "scalable_agent_tpu.envs.device.token_recall:DeviceTokenRecall",
     dict(num_actions=64, episode_length=16, period=10),

@@ -1,15 +1,15 @@
 """A token policy: a decoder behind the agent's calling contract, its
 layers a mixer (attention through a ring of its own, attention into
-another layer's ring, a state-space scan, a gated memory unit, a
-mixture of experts) and, where the family has one, an MLP (dense or
-experts) behind it, picked by the configuration file.  What a family is
-made of is declared once, in its record of ``_FAMILY`` (the keys its
+another layer's ring, a state-space scan, a delta-rule scan, a gated
+memory unit, a mixture of experts) and, where the family has one, an MLP
+(dense or experts) behind it, picked by the configuration file.  What a
+family is made of is declared once, in its record of ``_FAMILY`` (the keys its
 file must have, where its layers' kinds are written, which module a
 layer of each kind is, its norms, its head, its telemetry); nothing
 else in the program asks a family's name, and the record is private,
 not an extension point: a family is added by adding a record, the
 mechanisms it lacks, a preset for the family suite
-(tests/family_suite.py) and the benchmark's files.  Four families
+(tests/family_suite.py) and the benchmark's files.  Five families
 (``FAMILIES``): ``afmoe`` (window and full attention mixed, a mixture of
 experts with a shared expert), ``phi4flash`` (the decoder-hybrid-
 decoder: state-space and window layers, one full-attention layer whose
@@ -17,10 +17,13 @@ cache every later cross layer reads, memory units gated by the last
 state-space layer's output), ``deepseek_v3`` (latent attention: the
 cache holds one compressed row a token, which every head's key and
 value are up-projections of; a mixture of experts with shared experts
-behind leading dense layers) and ``nemotron_h`` (every layer ONE mixer
+behind leading dense layers), ``nemotron_h`` (every layer ONE mixer
 alone, by the file's ``hybrid_override_pattern``: a Mamba-2 scan with a
 matrix state a head, a mixture of experts whose experts have no gate,
-or plain grouped-query attention).
+or plain grouped-query attention) and ``olmo_hybrid`` (Gated-DeltaNet
+layers, a matrix state a head that a token CORRECTS before it writes,
+to one full-attention layer in four, a dense MLP behind each; a
+branch's result normed, its input not).
 
 ``__call__(actions, env_outputs, state) -> ((policy_logits, baseline),
 state)`` over time-major ``[T, B]`` inputs, as ``ImpalaAgent`` has it:
@@ -30,14 +33,15 @@ token of the same vocabulary, and the agent's state is its attention
 cache (``TokenCache``): per layer that makes keys a ring of keys and
 values (of latent rows where the family's attention is latent), each
 slot's index in the env's token stream beside it, where
-each env's episode began, and per state-space layer (Mamba-1's or
-Mamba-2's) its recurrent state and the last inputs of its short
-convolution.  An episode's end clears
+each env's episode began, and per scan layer (Mamba-1's, Mamba-2's or
+a delta-rule layer's) its recurrent state and the last inputs of its
+short convolution.  An episode's end clears
 no ring: a query sees a key of its own episode only (ops/attention.py),
 so ``done`` moves ``episode_start`` and the stale slots fall out of
 every mask; a recurrence cannot be masked after the fact, so the scan
-zeroes its state at an episode's first token (ops/ssm.py, ops/ssd.py)
-and the convolution drops the taps that reach before it.
+zeroes its state at an episode's first token (ops/ssm.py, ops/ssd.py,
+ops/gated_delta.py) and the convolution drops the taps that reach
+before it.
 
 The ``afmoe`` layer, for token ids ``x`` (sizes under the source's key names,
 ``TokenModelConfig``; benchmark/references/afmoe_token.py is the plain
@@ -102,6 +106,23 @@ no embedding scale, no position encoding anywhere, RMSNorm)::
     E (experts):  shared(a) + the held experts' part, an expert
                   relu(a Wu)^2 Wd: two matrices, no gate       (ops/moe.py)
 
+The ``olmo_hybrid`` layers (benchmark/references/olmo_hybrid_token.py;
+no embedding scale, no position encoding anywhere, RMSNorm; the Olmo 2/3
+order: a branch's RESULT is normed, its input is not)::
+
+    every layer:  h = h + RMSNorm(Mixer(h));  h = h + RMSNorm(MLP(h))
+    linear_attention (Gated DeltaNet), H heads, keys of K, values of V:
+        [q | k | v | z | a | b] = h W_in
+        [q | k | v] = silu(conv_4([q | k | v]))               no bias
+        q = q / |q| / sqrt(K);  k = k / |k|                       a head
+        b_t = 2 sigmoid(b);  a_t = exp(-exp(A_log) softplus(a + dt_bias))
+        S_t = a_t S_(t-1) + b_t (v_t - a_t S_(t-1) k_t) k_t^T      [H, V, K]
+        o_t = S_t q_t                                (ops/gated_delta.py)
+        out = (RMSNorm_V(o_t) w * silu(z_t)) W_out    the norm BEFORE the gate
+    full_attention: q = RMSNorm(h Wq), k = RMSNorm(h Wk) over the WHOLE
+        projection, before the heads are split; as many key/value heads
+        as query heads; softmax(q k / sqrt(D)) v Wo, no rotation or gate
+
 The rings are sized so that ONE buffer serves the rollout and the
 update: ``window + unroll`` slots (``episode_length + unroll`` on a full
 layer) still hold, when an unroll ends, everything its first query may
@@ -109,11 +130,13 @@ see, so the update attends into the cache as the rollout left it and
 masks the unroll's own slots by their index (``unroll_state``); no copy
 of the rings is kept from the unroll's start.  The recurrent state and
 the convolution's tail are kept from the start (0.8 MB an env at
-``phi4flash``'s published widths, 8.7 MB at ``nemotron_h``'s, whose
-state is a matrix a head): the update scans again from them.
+``phi4flash``'s published widths, 8.7 MB at ``nemotron_h``'s and 7.1 MB
+at ``olmo_hybrid``'s, whose states are a matrix a head): the update
+scans again from them.
 """
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -124,7 +147,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from scalable_agent_tpu.ops import attention as attention_lib
-from scalable_agent_tpu.ops import distributions, moe, ssd, ssm
+from scalable_agent_tpu.ops import distributions, gated_delta, moe, ssd, ssm
 from scalable_agent_tpu.types import StepOutput
 
 SLIDING = "sliding_attention"
@@ -135,7 +158,8 @@ MEMORY_UNIT = "memory_unit"     # gated by the last state-space layer's output
 LATENT = "latent_attention"     # full attention through a ring of latent rows
 MAMBA2 = "mamba2"               # a matrix-state scan (ops/ssd.py)
 EXPERTS = "experts"             # the expert layer as the layer's one mixer
-_SCANS = (STATE_SPACE, MAMBA2)
+LINEAR = "linear_attention"     # a delta-rule scan (ops/gated_delta.py)
+_SCANS = (STATE_SPACE, MAMBA2, LINEAR)
 # ``hybrid_override_pattern``'s letters ("-", a dense MLP alone, is not
 # built)
 _PATTERN = {"M": MAMBA2, "E": EXPERTS, "*": FULL}
@@ -203,6 +227,12 @@ class TokenModelConfig:
     chunk_size: int = 0
     moe_shared_expert_intermediate_size: int = 0
     mlp_hidden_act: str = "silu"        # the experts' (``_GATED``)
+    # olmo_hybrid (its convolution's taps are ``conv_kernel``)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_allow_neg_eigval: bool = True    # b in (0, 2), not (0, 1)
 
     @property
     def d_inner(self) -> int:
@@ -215,6 +245,13 @@ class TokenModelConfig:
         """Channels a scan layer's short convolution runs over: Mamba-1's
         ``x``, Mamba-2's ``x | B | C``."""
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def linear_widths(self) -> Tuple[int, int]:
+        """Numbers a token in a delta-rule layer's keys (its queries
+        too) and in its values, over the heads."""
+        return (self.linear_num_key_heads * self.linear_key_head_dim,
+                self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def conv_taps(self) -> int:
@@ -341,7 +378,11 @@ class TokenCache(NamedTuple):
     # states lie down the sublanes): f32 [B, d_state, d_inner], [B,
     # d_conv - 1, d_inner].  Mamba-2 (ops/ssd.py), a matrix a head: f32
     # [B, heads, head_dim, d_state], and the tail over ``x | B | C``:
-    # [B, conv_kernel - 1, d_inner + 2 * n_groups * d_state]
+    # [B, conv_kernel - 1, d_inner + 2 * n_groups * d_state].  A
+    # delta-rule layer (ops/gated_delta.py), a matrix a head too: f32
+    # [B, heads, value_dim, key_dim], and the tail over ``q | k | v``:
+    # [B, conv_kernel - 1, 2 * heads * key_dim + heads * value_dim].
+    # (``_Family.scan_state`` is where a family states the two.)
     ssm_state: Tuple[Any, ...] = ()
     conv_tail: Tuple[Any, ...] = ()
 
@@ -559,10 +600,14 @@ class _Attention(nn.Module):
 
 class _PlainAttention(nn.Module):
     """Grouped-query attention over the whole episode and nothing else:
-    no rotation, no bias, no gate, no head norm (``nemotron_h``)."""
+    no rotation, no bias, no gate, no head norm (``nemotron_h``).
+    ``whole_norms`` (``olmo_hybrid``): the query and key projections are
+    normed over their WHOLE width, every head's numbers in one mean
+    square, before the heads are split."""
 
     model: TokenModelConfig
     dtype: Any
+    whole_norms: bool = False
 
     @nn.compact
     def __call__(self, a, index, episode_start, ring_keys, ring_values,
@@ -571,13 +616,15 @@ class _PlainAttention(nn.Module):
         batch, count, _ = a.shape
         dim = model.head_dim
 
-        def project(name, heads):
+        def project(name, heads, norm=None):
+            x = _Linear(heads * dim, dtype, name=name)(a)
+            if norm and self.whole_norms:
+                x = _RMSNorm(model.rms_norm_eps, name=norm)(x)
             return attention_lib.round_to(
-                _Linear(heads * dim, dtype, name=name)(a).reshape(
-                    batch, count, heads, dim), dtype)
+                x.reshape(batch, count, heads, dim), dtype)
 
-        query = project("q_proj", model.num_attention_heads)
-        key = project("k_proj", model.num_key_value_heads)
+        query = project("q_proj", model.num_attention_heads, "q_norm")
+        key = project("k_proj", model.num_key_value_heads, "k_norm")
         value = project("v_proj", model.num_key_value_heads)
         with jax.named_scope("full"):
             out, stats = attention_lib.cached_attention(
@@ -728,18 +775,20 @@ def _family_dt_bias(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def _short_conv(module: nn.Module, x, position, tail, taps: int):
+def _short_conv(module: nn.Module, x, position, tail, taps: int,
+                biased: bool = True):
     """``silu(conv_taps(x) + b)``, depthwise and causal, of ``module``'s
-    ``conv_kernel`` and ``conv_bias``: tap k reaches k tokens back (into
-    ``tail``, the call before's last inputs) and not before the episode.
-    -> (the result, the tail the next call continues from)."""
+    ``conv_kernel`` and (where ``biased``) ``conv_bias``: tap k reaches k
+    tokens back (into ``tail``, the call before's last inputs) and not
+    before the episode.  -> (the result, the tail the next call
+    continues from)."""
     count, width = x.shape[1], x.shape[2]
     with jax.named_scope("conv"):
         kernel = module.param(
             "conv_kernel", nn.initializers.normal(1.0 / math.sqrt(taps)),
             (taps, width))
         bias = module.param("conv_bias", nn.initializers.zeros_init(),
-                            (width,))
+                            (width,)) if biased else 0.0
         seen = jnp.concatenate([tail, x], axis=1)
         x = bias + sum(
             kernel[taps - 1 - back]
@@ -832,6 +881,67 @@ class _Mamba2(nn.Module):
                 state, tail)
 
 
+class _GatedDeltaNet(nn.Module):
+    """The Gated-DeltaNet mixer: one projection into ``q | k | v``, the
+    output's gate and a decay and a write strength a head; the short
+    convolution over ``q | k | v`` (no bias); queries and keys of unit
+    length a head; the delta-rule scan of ``ops/gated_delta.py`` (a
+    matrix state a head; it and the convolution start afresh at an
+    episode's first token); a norm over each head's values BEFORE the
+    gate."""
+
+    model: TokenModelConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, a, position, state, tail):
+        model, dtype = self.model, self.dtype
+        batch, count, _ = a.shape
+        heads = model.linear_num_key_heads
+        key_dim, value_dim = (model.linear_key_head_dim,
+                              model.linear_value_head_dim)
+        keys, values = model.linear_widths
+        mixed, z, decay, write = jnp.split(
+            _Linear(2 * keys + 2 * values + 2 * heads, dtype,
+                    name="in_proj")(a),
+            [2 * keys + values, 2 * keys + 2 * values,
+             2 * keys + 2 * values + heads], axis=-1)
+        mixed, tail = _short_conv(self, mixed, position, tail,
+                                  model.conv_kernel, biased=False)
+
+        def unit(x):        # [B, T, heads * key_dim] -> unit length a head
+            x = x.reshape(batch, count, heads, key_dim)
+            return x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+        query = unit(mixed[..., :keys]) * (1.0 / math.sqrt(key_dim))
+        key = unit(mixed[..., keys:2 * keys])
+        value = mixed[..., 2 * keys:].reshape(batch, count, heads, value_dim)
+        # b in (0, 2) lets a transition's eigenvalue along its key be
+        # negative (``linear_allow_neg_eigval``); in (0, 1) it cannot
+        write = jax.nn.sigmoid(write) * (
+            2.0 if model.linear_allow_neg_eigval else 1.0)
+        # the family's start: A uniform in (0, 16], one a head
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(16.0 - jax.random.uniform(
+                key, shape, jnp.float32, 0.0, 16.0)), (heads,))
+        log_decay = -jnp.exp(a_log) * jax.nn.softplus(
+            decay + self.param("dt_bias", _family_dt_bias, (heads,)))
+        with jax.named_scope("scan"):
+            out, state = gated_delta.gated_delta_scan(
+                query, key, value, write, log_decay, position == 0, state,
+                chunk=model.chunk_size, dtype=dtype)
+        with jax.named_scope("norm_gate"):
+            out = out * jax.lax.rsqrt(
+                jnp.mean(jnp.square(out), axis=-1, keepdims=True)
+                + model.rms_norm_eps)
+            out = out * self.param("norm_scale", nn.initializers.ones_init(),
+                                   (value_dim,))
+            gated = out.reshape(batch, count, values) * jax.nn.silu(z)
+        return (_Linear(model.hidden_size, dtype, name="out_proj")(gated),
+                state, tail)
+
+
 class _MemoryUnit(nn.Module):
     model: TokenModelConfig
     dtype: Any
@@ -895,6 +1005,12 @@ def _mamba2_mixer(layer, a, held):
     return mixed, held._replace(state=state, tail=tail), {}
 
 
+def _gated_delta_mixer(layer, a, held):
+    mixed, state, tail = _GatedDeltaNet(layer.model, layer.dtype, name="gdn")(
+        a, held.position, held.state, held.tail)
+    return mixed, held._replace(state=state, tail=tail), {}
+
+
 def _state_space_mixer(layer, a, held):
     mixed, memory, state, tail = _StateSpace(
         layer.model, layer.dtype, name="ssm")(
@@ -910,9 +1026,9 @@ def _memory_unit_mixer(layer, a, held):
         a, held.handed.memory), held, {})
 
 
-def _plain_attention_mixer(layer, a, held):
+def _plain_attention_mixer(layer, a, held, whole_norms=False):
     mixed, ring_keys, ring_values, seen = _PlainAttention(
-        layer.model, layer.dtype, name="attention")(
+        layer.model, layer.dtype, whole_norms, name="attention")(
             a, held.index, held.episode_start, held.ring_keys,
             held.ring_values, held.ring_index, held.written)
     return (mixed,) + _attends(held, ring_keys, ring_values, seen)
@@ -969,12 +1085,15 @@ class _Layer(nn.Module):
         def result(name, x):
             return norm(name)(x) if family.result_norms else x
 
-        a = norm("input_norm")(h)
+        def entering(name, x):
+            return norm(name)(x) if family.input_norms else x
+
+        a = entering("input_norm", h)
         mixed, held, stats = family.mixers[model.layer_types[self.layer]](
             self, a, _Held(*held))
         h = h + result("post_attn_norm", mixed)
         if not family.mixer_alone:
-            m = norm("pre_mlp_norm")(h)
+            m = entering("pre_mlp_norm", h)
             if model.is_expert_layer(self.layer):
                 f, said = _expert_layer(self, m)
                 stats = dict(stats, **said)
@@ -1059,6 +1178,11 @@ class _Family:
     norm: Any = _RMSNorm                # every norm but a mixer's own
     norm_eps: str = "rms_norm_eps"      # the field that holds its epsilon
     result_norms: bool = False          # a branch's RESULT is normed too
+    input_norms: bool = True            # a branch's INPUT is normed
+    # model -> (the shape an env of a scan layer's state, the width of
+    # its convolution's tail); None where no layer is a scan
+    scan_state: Optional[Callable[["TokenModelConfig"],
+                                  Tuple[Tuple[int, ...], int]]] = None
     mixer_alone: bool = False           # no MLP behind the mixer
     paired_heads: bool = False          # a ring head is a pair, twice as wide
     tied_head: bool = False             # the head is the embedding's table
@@ -1103,6 +1227,31 @@ def _pattern_layers(raw):
         sliding_window=0)
 
 
+def _linear_layers(raw):
+    """``olmo_hybrid``'s file names each layer's kind in ``layer_types``;
+    a head's width is the hidden size's share, and no layer has a
+    window."""
+    return dict(
+        head_dim=raw.get("head_dim", raw["hidden_size"]
+                         // raw["num_attention_heads"]),
+        sliding_window=0)
+
+
+def _mamba1_state(model):
+    return (model.mamba_d_state, model.d_inner), model.d_inner
+
+
+def _mamba2_state(model):
+    return ((model.mamba_num_heads, model.mamba_head_dim,
+             model.ssm_state_size), model.conv_width)
+
+
+def _delta_state(model):
+    keys, values = model.linear_widths
+    return ((model.linear_num_value_heads, model.linear_value_head_dim,
+             model.linear_key_head_dim), 2 * keys + values)
+
+
 def _heads_in_pairs(model):
     if (model.num_attention_heads % 2 or model.num_key_value_heads % 2
             or (model.num_attention_heads // 2)
@@ -1119,6 +1268,16 @@ def _equal_groups(model):
         return ("token policy: the scan's heads and channels come in "
                 "n_groups equal groups, the query heads in "
                 "num_key_value_heads")
+    return None
+
+
+def _a_key_head_a_value_head(model):
+    if (model.linear_num_key_heads != model.linear_num_value_heads
+            or model.num_attention_heads % model.num_key_value_heads
+            or model.hidden_size % model.num_attention_heads):
+        return ("token policy: a delta-rule layer has a key head a value "
+                "head, and the query heads come in num_key_value_heads "
+                "equal groups that divide hidden_size")
     return None
 
 
@@ -1178,7 +1337,7 @@ _FAMILY: Dict[str, _Family] = {
                "attention/decode_key_blocks_visited_share"),
         check=_heads_in_pairs,
         norm=_LayerNorm, norm_eps="layer_norm_eps",
-        paired_heads=True, tied_head=True),
+        paired_heads=True, tied_head=True, scan_state=_mamba1_state),
     "deepseek_v3": _Family(
         required=_ALWAYS + (
             "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
@@ -1233,7 +1392,33 @@ _FAMILY: Dict[str, _Family] = {
         stats=_EXPERT_STATS,
         said_as=_ROUTED_SAID_AS + (
             ("layer_norm_epsilon", "rms_norm_eps"),),
-        mixer_alone=True),
+        mixer_alone=True, scan_state=_mamba2_state),
+    "olmo_hybrid": _Family(
+        required=_ALWAYS + (
+            "num_key_value_heads", "layer_types", "linear_num_key_heads",
+            "linear_num_value_heads", "linear_key_head_dim",
+            "linear_value_head_dim", "linear_conv_kernel_dim",
+            "linear_allow_neg_eigval", "rms_norm_eps", "chunk_size"),
+        # a gated silu MLP, no bias, an untied head, and no rotation: the
+        # scans and the convolutions carry order
+        only=(("hidden_act", "silu"), ("attention_bias", False),
+              ("tie_word_embeddings", False),
+              ("rope_parameters", {"rope_theta": None}),
+              ("sliding_window", None)),
+        layers=_linear_layers,
+        mixers={LINEAR: _gated_delta_mixer,
+                FULL: functools.partial(_plain_attention_mixer,
+                                        whole_norms=True)},
+        layers_refusal=(
+            f"token policy: layer_types must name one of "
+            f"{(LINEAR, FULL)} for each of num_hidden_layers"),
+        groups=("embedding", "attention", "gdn", "mlp", "norms", "heads"),
+        stats=("attention/key_blocks_visited_share",
+               "attention/decode_key_blocks_visited_share"),
+        said_as=(("linear_conv_kernel_dim", "conv_kernel"),),
+        check=_a_key_head_a_value_head,
+        # the Olmo 2/3 order: a branch's result is normed, its input not
+        result_norms=True, input_norms=False, scan_state=_delta_state),
 }
 FAMILIES = tuple(_FAMILY)
 _FIRST = _FAMILY[TokenModelConfig.model_type]
@@ -1300,7 +1485,7 @@ class TokenPolicy(nn.Module):
             return "embedding"
         if keys[-1].endswith("scale") or keys[-2].endswith("norm"):
             return "norms"
-        for group in ("attention", "ssm", "ssd", "gmu"):
+        for group in ("attention", "ssm", "ssd", "gdn", "gmu"):
             if group in keys:
                 return group
         if "experts" in keys or "router" in keys:
@@ -1408,11 +1593,10 @@ class TokenPolicy(nn.Module):
             return tuple(jnp.zeros((batch,) + shape, jnp.float32)
                          for _ in self._scan_layers)
 
-        if model.mamba_num_heads:       # Mamba-2: a matrix a head
-            scan_state = per_scan(model.mamba_num_heads, model.mamba_head_dim,
-                                  model.ssm_state_size)
-        else:
-            scan_state = per_scan(model.mamba_d_state, model.d_inner)
+        scan_state, tail_width = (), 0
+        if self._scan_layers:
+            scan_state, tail_width = _FAMILY[model.model_type].scan_state(
+                model)
 
         return TokenCache(
             keys=rings,
@@ -1424,8 +1608,8 @@ class TokenPolicy(nn.Module):
                                 attention_lib.NO_KEY, jnp.int32),
             written=jnp.zeros((), jnp.int32),
             episode_start=jnp.zeros((batch,), jnp.int32),
-            ssm_state=scan_state,
-            conv_tail=per_scan(model.conv_taps - 1, model.conv_width))
+            ssm_state=per_scan(*scan_state),
+            conv_tail=per_scan(model.conv_taps - 1, tail_width))
 
     def unroll_state(self, start: TokenCache, end: TokenCache) -> TokenCache:
         """The state the update unrolls from, without a copy of the
@@ -1493,6 +1677,12 @@ class TokenPolicy(nn.Module):
              "bytes an env of the Mamba-2 layers' matrix states and "
              "convolution tails, over the layers, read off the state's "
              "own arrays; 0 where no layer is a Mamba-2 scan"),
+            ("gdn/state_bytes_per_env",
+             self.ssm_state_bytes(1)
+             if LINEAR in self.model.layer_types else 0,
+             "bytes an env of the delta-rule layers' matrix states and "
+             "convolution tails, over the layers, read off the state's "
+             "own arrays; 0 where no layer is a delta-rule layer"),
             ("policy/vocab_slice", self.model.vocab_size,
              "tokens of the vocabulary this chip's head and embedding "
              "hold"))

@@ -32,7 +32,11 @@ block none of an env's queries can see is neither fetched nor scored
 (``attention/key_blocks_visited_share`` counts the rest, and
 ``attention/decode_key_blocks_visited_share`` what the unroll's decode
 steps visited).  Grouped queries: the ``heads // kv_heads`` query heads
-that share a key/value head are one matmul's columns.  Inside the
+that share a key/value head are one matmul's columns (16, 8 or 2 in the
+first four families; ONE in ``olmo_hybrid``, whose 30 key/value heads
+make a ring slot of 15,360 bytes a token: the decode pads the one query
+row a head to a sublane tile, and a block of the ring is then every
+head's keys of that many slots).  Inside the
 update's kernels a block's scores lie keys down, queries across
 (``[K, R]``): the maximum and the sum over keys are then elementwise
 over vregs, and what a query carries (its bounds, maximum, sum) is a

@@ -825,6 +825,48 @@ class TestAotCompileForV5e:
             for dims in re.findall(r"= f32\[([\d,]+)\]", text))
         assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
 
+    def test_gated_delta_scan_compiles_with_no_state_a_token_in_hbm(
+            self, monkeypatch, v5e_topology):
+        """ISSUE 46: the chunked delta-rule scan's kernels
+        (ops/gated_delta.py, T > 1) at ``olmohybrid.ingraph``'s widths —
+        257 tokens (two chunks of 128 and one token as a step), 30 heads
+        with keys of 96 and values of 192, bfloat16 operands — compiled
+        alone for a v5e, forward and backward: two Mosaic calls (a chunk
+        of 128 tokens along the lanes of V^T, keys padded to a lane tile,
+        the solve's float32 products), and no float32 result as large as
+        a state a token (the forward keeps a state a chunk: two of
+        them)."""
+        from jax.sharding import SingleDeviceSharding
+
+        from scalable_agent_tpu.ops import gated_delta
+
+        _as_tpu(monkeypatch)
+        envs, tokens, heads, keys, values = 2, 257, 30, 96, 192
+        one_chip = SingleDeviceSharding(v5e_topology.devices[0])
+
+        def operand(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(q, k, v, beta, log_decay, state, reset):
+            o, last = gated_delta.gated_delta_scan(
+                q, k, v, beta, log_decay, reset, state, chunk=128,
+                dtype=jnp.bfloat16)
+            return jnp.sum(o) + jnp.sum(last)
+
+        text = jax.jit(jax.grad(loss, tuple(range(6)))).lower(
+            operand((envs, tokens, heads, keys)),
+            operand((envs, tokens, heads, keys)),
+            operand((envs, tokens, heads, values)),
+            operand((envs, tokens, heads)), operand((envs, tokens, heads)),
+            operand((envs, heads, values, keys)),
+            operand((envs, tokens), jnp.bool_)).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        a_state_a_token = envs * tokens * heads * values * keys
+        largest = max(
+            math.prod(int(n) for n in dims.split(","))
+            for dims in re.findall(r"= f32\[([\d,]+)\]", text))
+        assert largest * 8 < a_state_a_token, (largest, a_state_a_token)
+
     @pytest.mark.parametrize("devices,overrides,merged,handed", [
         (1, {}, 101 * 256, True),
         (1, {"torso_type": "resnet", "batch_size": 128}, 101 * 128, False),

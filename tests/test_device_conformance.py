@@ -35,6 +35,7 @@ CONFORMANCE_LEVELS = (
     "token_recall",
     "token_recall_10k",
     "token_recall_14k",
+    "token_recall_8k",
     "token_recall_long",
     "token_recall_small",
 )
